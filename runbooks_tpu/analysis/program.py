@@ -40,6 +40,7 @@ have a concrete integer shape (no dynamic dims).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -83,11 +84,11 @@ class AuditSettings:
 # ---------------------------------------------------------------------------
 
 def _sub_jaxprs(value: Any):
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr, list(value.consts)
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         yield value, []
     elif isinstance(value, (list, tuple)):
         for v in value:
@@ -153,7 +154,9 @@ def audit_jaxpr(closed, program: str,
                             "an argument"))
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
-            if "callback" in name:
+            # jax.debug.print is its own primitive (debug_print), not a
+            # *_callback one.
+            if "callback" in name or name == "debug_print":
                 callbacks += 1
                 findings.append(Finding(
                     rule="program-callback", path=path, line=0,
@@ -537,12 +540,6 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
         mesh = make_mesh(MeshConfig(data=1, fsdp=-1, tensor=2))
         cfg_tp = _dc.replace(cfg, collective_matmul="auto")
 
-        def under_mesh(fn):
-            def wrapped(*args):
-                with jax.set_mesh(mesh):
-                    return fn(*args)
-            return wrapped
-
         prefill_tp = make_prefill_fn(cfg_tp, cache_len)
         decode_tp = make_decode_fn(cfg_tp, settings.decode_chunk,
                                    max_seq_len, max_seq_len, views[-1])
@@ -561,27 +558,27 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
 
         specs += [
             {"component": "serve", "name": "prefill_sharded",
-             "fn": under_mesh(prefill_tp),
+             "fn": prefill_tp, "mesh": mesh,
              "args": prefill_args(rows_set[-1], buckets[-1]),
              "signatures": len(buckets) * len(rows_set)},
             {"component": "serve", "name": "decode_sharded",
-             "fn": under_mesh(decode_tp), "args": decode_args,
+             "fn": decode_tp, "mesh": mesh, "args": decode_args,
              "signatures": len(views)},
             {"component": "serve", "name": "verify_sharded",
-             "fn": under_mesh(verify_tp), "args": verify_args,
+             "fn": verify_tp, "mesh": mesh, "args": verify_args,
              "signatures": len(views)},
             {"component": "serve", "name": "paged_prefill_sharded",
-             "fn": under_mesh(paged_prefill_tp),
+             "fn": paged_prefill_tp, "mesh": mesh,
              "args": paged_prefill_args,
              "signatures": len(pshapes) * len(rows_set)},
             {"component": "serve", "name": "paged_decode_sharded",
-             "fn": under_mesh(paged_decode_tp),
+             "fn": paged_decode_tp, "mesh": mesh,
              "args": paged_decode_args, "signatures": len(vp_buckets)},
             {"component": "serve", "name": "paged_verify_sharded",
-             "fn": under_mesh(paged_verify_tp),
+             "fn": paged_verify_tp, "mesh": mesh,
              "args": paged_verify_args, "signatures": len(vp_buckets)},
             {"component": "serve", "name": "adapter_decode_sharded",
-             "fn": under_mesh(adapter_decode_tp),
+             "fn": adapter_decode_tp, "mesh": mesh,
              "args": ([params, pool, apool, aslots_sds(slots)]
                       + decode_args[2:]),
              "signatures": len(views)},
@@ -666,7 +663,11 @@ def audit_programs(
     for spec in _engine_specs(settings) + _train_specs(settings):
         program = f"{spec['component']}/{spec['name']}"
         try:
-            closed = jax.make_jaxpr(spec["fn"])(*spec["args"])
+            # Sharded specs trace under their mesh; set_mesh must wrap
+            # the trace (it cannot be entered inside one).
+            with (jax.set_mesh(spec["mesh"]) if "mesh" in spec
+                  else contextlib.nullcontext()):
+                closed = jax.make_jaxpr(spec["fn"])(*spec["args"])
         except Exception as exc:  # noqa: BLE001 — surface, don't crash
             findings.append(Finding(
                 rule="program-trace", path=f"program:{program}", line=0,

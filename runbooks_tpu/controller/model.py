@@ -244,6 +244,14 @@ class ModelReconciler:
         }
         container["env"].append({"name": "RBT_METRICS_PORT",
                                  "value": str(METRICS_PORT)})
+        if "JAX_COMPILATION_CACHE_DIR" not in model.env:
+            # The compile cache goes on the durable artifacts mount, so a
+            # restarted Job (preemption, slice restart) skips the XLA
+            # recompile. Placed here, from outside: the workload derives no
+            # cache path of its own (utils/jax_cache.py).
+            container["env"].append({
+                "name": "JAX_COMPILATION_CACHE_DIR",
+                "value": "/content/artifacts/jax_cache"})
         if model.command:
             container["command"] = list(model.command)
         pod_spec = {

@@ -41,6 +41,7 @@ from runbooks_tpu.ops.quantization import (
 )
 from runbooks_tpu.ops.rotary import apply_rope
 from runbooks_tpu.parallel.sharding import with_logical_constraint
+from runbooks_tpu.utils.hw import on_tpu
 
 Params = Dict[str, Any]
 
@@ -89,9 +90,8 @@ def resolve_collective_matmul(cfg: ModelConfig) -> bool:
     ("auto" and "ring" are equivalent today — "ring" states intent, "auto"
     may later grow heuristics). The pipeline (stage > 1) path keeps GSPMD
     tensor parallelism: its blocks already run inside a stage-manual
-    shard_map, and nesting the ring's manual region there trips the pinned
-    jaxlib's partial-manual SPMD limitation (see tests/conftest.py
-    probe)."""
+    shard_map, and the ring's region is written manual over ALL mesh axes
+    (ops/collective_matmul.py), which cannot nest there."""
     from runbooks_tpu.models.config import check_collective_matmul
 
     mode = check_collective_matmul(cfg.collective_matmul)
@@ -382,7 +382,7 @@ def resolve_attention_impl(cfg: ModelConfig) -> str:
         mesh = _current_mesh()
         if mesh is not None and mesh.shape.get("sequence", 1) > 1:
             impl = "ring"
-        elif "tpu" in jax.default_backend().lower():
+        elif on_tpu():
             impl = "flash"
         else:
             impl = "xla"
@@ -408,9 +408,7 @@ def use_flash_cached_prefill(cfg: ModelConfig, q_len: int) -> bool:
         return True
     if impl != "auto":
         return False
-    from runbooks_tpu.ops.flash_attention import is_tpu_backend
-
-    return is_tpu_backend()
+    return on_tpu()
 
 
 def _dispatch_attention(cfg: ModelConfig, q, k, v, positions, segment_ids,
@@ -795,10 +793,12 @@ def forward(
     # Deliberately the DEFAULT (replicated-h) constraint even when the
     # ring path tensor-shards the residual stream: constraining the
     # one-hot embed einsum's output tensor-sharded while its vocab
-    # contraction is also tensor-sharded miscompiles on the pinned
-    # jaxlib's SPMD partitioner (wrong VALUES, reproduced and bisected —
-    # not just a slow reshard). The first block's constraint shards the
-    # stream one op later, which the partitioner handles correctly.
+    # contraction is also tensor-sharded makes the SPMD partitioner
+    # produce wrong VALUES in the train step. Still so on jaxlib 0.9.0:
+    # with the ring rules here, tests/test_collective_matmul.py::
+    # test_train_step_matches_gspmd[plain] fails (loss off in the first
+    # digit). The first block's constraint shards the stream one op
+    # later, which the partitioner handles correctly.
     x = with_logical_constraint(x, ("batch", "seq", "act_embed"))
 
     # Mask & bias over the full kv extent (or the static read view).

@@ -48,15 +48,17 @@ A dequant-fused variant accepts ``QuantizedArray`` int8/int4 weight shards
 and the blockwise scales apply post-dot, so the quantized serving tier
 overlaps too (forward-only — quantized weights are a serving artifact).
 
-Implementation note (pinned jaxlib 0.4.36): *partial*-manual shard_map
-(manual over tensor only, GSPMD elsewhere) crashes the SPMD partitioner
-(the same PartitionId-era limitation that skips the partial-manual
-pipeline tests), so the region is manual over ALL mesh axes: activations
+Implementation note: the region is manual over ALL mesh axes: activations
 enter sharded batch-over-(data, fsdp) / seq-over-sequence exactly as GSPMD
 lays them out (specs via parallel/sharding.spec_for_array, so mesh axes
 the array doesn't divide degrade to replicated at the boundary), and the
 fsdp (ZeRO-3) weight gather happens at the shard_map boundary exactly
-where GSPMD would have placed it.
+where GSPMD would have placed it. It was written full-manual because
+partial-manual shard_map crashed the partitioner of jaxlib 0.4.x; on
+jaxlib 0.9.0 partial-manual works (the pipeline's stage-manual region
+runs), so manual-over-tensor-only is now possible. Which layout to keep
+is part of the ring-vs-GSPMD race (ROADMAP Speed carry / D6), not decided
+here.
 
 The GSPMD path stays the default reference; ``ring_supported`` is the
 per-weight gate (falls back on any divisibility mismatch) and tests assert

@@ -25,13 +25,16 @@ position layouts.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from runbooks_tpu.utils.hw import on_tpu
 
 NEG_INF = -1e30
 PAD_POS = 2 ** 30  # kv-position sentinel for padding; always masked
@@ -59,22 +62,10 @@ def _bcast_sublanes(x):  # [b, s] -> [b, SUBLANES, s]
                                     (0, 2))
 
 
-def is_tpu_backend() -> bool:
-    """Shared TPU detection: PJRT plugin backends may report a vendor name
-    rather than "tpu", so check the device string too. Used both for the
-    Mosaic-vs-interpret choice here and for ring attention's auto inner —
-    the two must agree or a TPU could silently get the slow XLA ring."""
-    if "tpu" in jax.default_backend().lower():
-        return True
-    try:
-        return "TPU" in str(jax.devices()[0])
-    except RuntimeError:
-        return False
-
-
 def _interpret() -> bool:
-    # Compile via Mosaic only on real TPU backends.
-    return not is_tpu_backend()
+    # Mosaic on a TPU, the Pallas interpreter everywhere else; a function
+    # of the one probe (utils/hw.on_tpu), so nothing on a TPU interprets.
+    return not on_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -388,6 +379,61 @@ def _bwd_dkv_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
 
 
 # ---------------------------------------------------------------------------
+# Running the kernels under a multi-device mesh
+# ---------------------------------------------------------------------------
+
+class _ShardPlan(NamedTuple):
+    mesh: Any
+    batch: Any                 # mesh axes the batch dim shards over, or None
+    heads: Optional[str]       # mesh axis the query heads shard over
+    kv_heads: Optional[str]    # ... and the kv heads (None: replicated)
+
+
+def _shard_plan(q, k) -> Optional[_ShardPlan]:
+    """How to launch the kernels under the ambient mesh; None on a single
+    device. Mosaic kernels cannot be partitioned by GSPMD ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map"), so on a multi-device mesh each device runs the kernel on
+    its own shard: batch over (data, fsdp) and heads over tensor — the
+    layout the surrounding projections already produce, so no resharding.
+    Heads shard only when the GQA grouping survives it: kv heads divide
+    too, or there is a single kv head every shard reads whole."""
+    from runbooks_tpu.parallel.sharding import _current_mesh, spec_for_array
+
+    mesh = _current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    batch = spec_for_array(q.shape[:1], ("batch",), mesh)[0]
+    tp = int(mesh.shape.get("tensor", 1))
+    h, kv_h = q.shape[2], k.shape[2]
+    heads = kv_heads = None
+    if tp > 1 and h % tp == 0 and kv_h % tp == 0:
+        heads = kv_heads = "tensor"
+    elif tp > 1 and h % tp == 0 and kv_h == 1:
+        heads = "tensor"
+    return _ShardPlan(mesh, batch, heads, kv_heads)
+
+
+def _per_shard(fn, plan: Optional[_ShardPlan], in_kinds, out_kinds):
+    """fn as a shard_map over every (not already manual) mesh axis, its
+    operands laid out by kind: "q" [b, s, h, d], "kv" [b, s, kv_h, d],
+    "row" [b, s] (None operands pass through), "lse" [b, h, s]."""
+    if plan is None:
+        return fn
+    spec = {"q": P(plan.batch, None, plan.heads, None),
+            "kv": P(plan.batch, None, plan.kv_heads, None),
+            "row": P(plan.batch, None),
+            "lse": P(plan.batch, plan.heads, None)}
+    return jax.shard_map(
+        fn, mesh=plan.mesh,
+        in_specs=tuple(spec[kind] for kind in in_kinds),
+        out_specs=tuple(spec[kind] for kind in out_kinds),
+        axis_names=(frozenset(plan.mesh.axis_names)
+                    - frozenset(plan.mesh.manual_axes)),
+        check_vma=False)
+
+
+# ---------------------------------------------------------------------------
 # Public op with custom VJP
 # ---------------------------------------------------------------------------
 
@@ -426,11 +472,16 @@ def flash_attention(
     # producing kernel as a constant (the pallas call has no JVP rule);
     # the differentiable path runs through _flash_core's custom vjp, whose
     # q/k/v args carry the real tangents.
-    out, lse = _flash_fwd(
+    def fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg):
+        return _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg,
+                          scale_v, causal, block_q, block_k, block_skip)
+
+    out, lse = _per_shard(
+        fwd, _shard_plan(q, k), ("q", "kv", "kv", "row", "row", "row", "row"),
+        ("q", "lse"))(
         jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
         jax.lax.stop_gradient(v), q_positions, kv_positions,
-        q_segment_ids, kv_segment_ids,
-        scale_v, causal, block_q, block_k, block_skip)
+        q_segment_ids, kv_segment_ids)
     out = checkpoint_name(out, "attn_context")
     lse = checkpoint_name(lse, "attn_lse")
     return _flash_core(
@@ -451,10 +502,23 @@ def _vjp_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse,
 
 def _vjp_bwd(causal, scale, block_q, block_k, block_skip, res, g):
     q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse = res
-    dq, dk, dv = flash_attention_bwd(
-        q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
-        causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        block_skip=block_skip)
+    plan = _shard_plan(q, k)
+
+    def bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g):
+        dq, dk, dv = flash_attention_bwd(
+            q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
+            causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+            block_skip=block_skip)
+        if plan is not None and plan.heads != plan.kv_heads:
+            # Multi-query under head sharding: each shard holds its query
+            # heads' share of the one kv head's gradient.
+            dk, dv = jax.lax.psum((dk, dv), plan.heads)
+        return dq, dk, dv
+
+    dq, dk, dv = _per_shard(
+        bwd, plan, ("q", "kv", "kv", "row", "row", "row", "row", "q", "lse",
+                    "q"), ("q", "kv", "kv"))(
+        q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g)
     # Zero cotangents for the hoisted residual args (out, lse): the real
     # attention gradient routes entirely through q/k/v, and the producers
     # are stop_gradient'ed at the call site so these zeros are dropped.

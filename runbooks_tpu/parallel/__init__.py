@@ -1,7 +1,3 @@
-from runbooks_tpu.parallel import compat as _compat
-
-_compat.install()  # legacy-JAX alias for jax.set_mesh; no-op on modern JAX
-
 from runbooks_tpu.parallel.distributed import initialize, is_primary
 from runbooks_tpu.parallel.mesh import (
     MESH_AXES,

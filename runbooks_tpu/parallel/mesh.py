@@ -25,7 +25,7 @@ import math
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 MESH_AXES = ("data", "stage", "expert", "fsdp", "sequence", "tensor")
 
@@ -79,23 +79,11 @@ def make_mesh(
     config = (config or MeshConfig()).resolve(len(devices))
     shape = tuple(getattr(config, a) for a in MESH_AXES)
     # Auto axis types = classic GSPMD: XLA propagates shardings from the
-    # in/out_shardings + with_sharding_constraint hints. (JAX 0.9's default
-    # under jax.set_mesh is the explicit sharding-in-types mode, which would
-    # require out_sharding annotations on every gather/einsum.) On legacy
-    # JAX (no AxisType) every mesh is GSPMD-auto already.
-    from runbooks_tpu.parallel.compat import mesh_axis_types
-
-    axis_types = mesh_axis_types(len(MESH_AXES))
-    try:
-        if axis_types is not None:
-            return jax.make_mesh(shape, MESH_AXES, devices=devices,
-                                 axis_types=axis_types)
-        return jax.make_mesh(shape, MESH_AXES, devices=devices)
-    except TypeError:
-        # Older jax.make_mesh lacks devices=/axis_types=; manual reshape.
-        import numpy as np
-
-        return Mesh(np.asarray(devices).reshape(shape), MESH_AXES)
+    # in/out_shardings + with_sharding_constraint hints. (The default under
+    # jax.set_mesh is the explicit sharding-in-types mode, which would
+    # require out_sharding annotations on every gather/einsum.)
+    return jax.make_mesh(shape, MESH_AXES, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(MESH_AXES))
 
 
 def single_device_mesh() -> Mesh:

@@ -47,10 +47,12 @@ from jax.sharding import PartitionSpec as P
 
 def _psum(x, axis):
     """psum that survives the CPU backend: XLA CPU's AllReducePromotion
-    pass crashes on bf16 all-reduces ("Invalid binary instruction opcode
-    copy" CHECK, observed on this jaxlib) — upcast around the collective
-    there. On TPU the native bf16 all-reduce is kept (half the ICI
-    bytes)."""
+    pass aborts on the bf16 all-reduces of the stage-manual region
+    ("Invalid binary instruction opcode copy" CHECK) — upcast around the
+    collective there. Still so on jaxlib 0.9.0: without the upcast
+    tests/test_pipeline.py::test_1f1b_bf16_activations_compile_on_cpu
+    aborts the process. On TPU the native bf16 all-reduce is kept (half
+    the ICI bytes)."""
     if x.dtype == jnp.bfloat16 and jax.default_backend() == "cpu":
         return jax.lax.psum(x.astype(jnp.float32),
                             axis).astype(jnp.bfloat16)

@@ -44,15 +44,12 @@ NEG_INF = -1e30
 
 def use_flash_inner_default() -> bool:
     """Auto rule for the ring inner: flash on TPU, XLA elsewhere (CPU
-    interpret-mode kernels are for tests, not the default path). Shares
-    flash_attention's detection — PJRT plugin backends may report a vendor
-    name instead of "tpu", and the two decisions must agree."""
-    from runbooks_tpu.ops.flash_attention import is_tpu_backend
+    interpret-mode kernels are for tests, not the default path). The same
+    probe as flash_attention's Mosaic-vs-interpret choice, so a TPU cannot
+    silently get the slow XLA ring."""
+    from runbooks_tpu.utils.hw import on_tpu
 
-    try:
-        return is_tpu_backend()
-    except Exception:  # noqa: BLE001 — backend init unavailable
-        return False
+    return on_tpu()
 
 
 def ring_attention(

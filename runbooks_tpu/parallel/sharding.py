@@ -156,17 +156,6 @@ def with_logical_constraint(x: jax.Array, logical: LogicalSpec,
 
 
 def _current_mesh():
-    """The innermost mesh context: jax.set_mesh first, legacy pjit second."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass  # API absent on older jax; fall through to the legacy probe
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
+    """The innermost ``jax.set_mesh`` context, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh

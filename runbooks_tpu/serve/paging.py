@@ -1409,6 +1409,7 @@ class PagedInferenceEngine(InferenceEngine):
         sentinel = obs_device.SENTINEL
         compiles_before = sentinel.total
         seconds_before = sentinel.compile_seconds
+        hits_before = sentinel.cache_hits
         t_warm = time.perf_counter()
         shapes = paged_prefill_shapes(self.prefill_buckets,
                                       self.pages_per_slot, self.page_size,
@@ -1541,6 +1542,9 @@ class PagedInferenceEngine(InferenceEngine):
             "compiles": sentinel.total - compiles_before,
             "compile_seconds": round(
                 sentinel.compile_seconds - seconds_before, 3),
+            # Compile requests the persistent cache answered: > 0 on a
+            # warm restart (utils/jax_cache.py), 0 on a cold one.
+            "cache_hits": sentinel.cache_hits - hits_before,
             "warmup_seconds": round(time.perf_counter() - t_warm, 3),
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
@@ -1553,7 +1557,9 @@ class PagedInferenceEngine(InferenceEngine):
             f"{self.num_pages}x{self.page_size} pool, "
             f"{n_verify} verify programs, {n_swap} swap programs; "
             f"{self.warmup_census['compiles']} compiles in "
-            f"{self.warmup_census['compile_seconds']}s", flush=True)
+            f"{self.warmup_census['compile_seconds']}s, "
+            f"{self.warmup_census['cache_hits']} from the persistent "
+            "cache", flush=True)
         if not self._marked_steady:
             self._marked_steady = True
             sentinel.mark_steady("serve")

@@ -26,10 +26,10 @@ class OptimizerConfig:
     weight_decay: float = 0.0
     grad_clip_norm: Optional[float] = 1.0
     # Dtype for the adam first moment (mu). None keeps the param dtype
-    # (f32 masters -> f32 mu). "bfloat16" halves mu bytes — measured on the
-    # bench-410m shapes the f32 masters+moments are the 5 GB that force
-    # full remat (BENCH_NOTES r3); bf16 mu is the first of the three
-    # state-memory levers (mu dtype, param dtype, state sharding).
+    # (f32 masters -> f32 mu). "bfloat16" halves mu bytes — f32 masters +
+    # moments are what forces full remat (ROADMAP S8); bf16 mu is the first
+    # of the three state-memory levers (mu dtype, param dtype, state
+    # sharding).
     mu_dtype: Optional[str] = None
 
 

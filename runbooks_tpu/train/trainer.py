@@ -256,11 +256,23 @@ def run_training(job: TrainJobConfig,
     # live spans there, and tail-sampling/incident promotion needs the
     # same per-run destination even when live tracing is off.
     obs_trace.configure(os.path.join(artifacts, "trace.jsonl"))
-    # Persistent compile cache in the durable artifacts mount: a restarted
-    # Job (slice restart / resume) skips the full XLA recompile.
+    # Persistent compile cache (placed from outside: utils/jax_cache.py):
+    # a restarted Job (slice restart / resume) skips the XLA recompile.
+    from runbooks_tpu.models.transformer import resolve_attention_impl
+    from runbooks_tpu.utils.hw import chip_peaks, device_identity
     from runbooks_tpu.utils.jax_cache import enable_compilation_cache
 
-    enable_compilation_cache(os.path.join(artifacts, "jax_cache"))
+    with jax.set_mesh(mesh):
+        attention_impl = resolve_attention_impl(model_cfg)
+    # What this run executes on — the start-up line every log carries, and
+    # the same fields the final summary repeats.
+    identity = {
+        **device_identity(mesh),
+        "compile_cache_dir": enable_compilation_cache(),
+        "attention_impl": attention_impl,
+    }
+    print(json.dumps({"startup": "train", "model": job.model, **identity}),
+          flush=True)
     ckpt = CheckpointManager(artifacts)
 
     rng = jax.random.key(job.seed)
@@ -325,9 +337,9 @@ def run_training(job: TrainJobConfig,
     history = []
     tokens_per_step = job.batch_size * job.seq_len
     flops_per_token = 3.0 * model_cfg.flops_per_token(job.seq_len)
-    from runbooks_tpu.utils.hw import chip_peak_flops
-
-    peak_flops = chip_peak_flops(jax.devices()[0]) * len(jax.devices())
+    # None off-TPU: the log lines then carry no mfu/analytic_mfu at all.
+    chip = chip_peaks(jax.devices()[0])
+    peak_flops = chip[0] * len(jax.devices()) if chip else None
     tokens_done = 0
     compile_time_s = None
 
@@ -342,6 +354,7 @@ def run_training(job: TrainJobConfig,
     compiles_before = obs_device.SENTINEL.total
     unexpected_before = obs_device.SENTINEL.unexpected
     hbm_peak_bytes = 0
+    hbm_per_device = None  # bytes in use on each device at the last log
 
     # Goodput accounting (obs/goodput.py): productive step time ÷ wall
     # clock, with restart overhead (restore + compile) excluded so a
@@ -362,6 +375,7 @@ def run_training(job: TrainJobConfig,
             "restore_time_s": restore_time_s,
             "accumulate_steps": job.accumulate_steps,
             "model": job.model,
+            **identity,
             "lora": lora_mode,
             "exit_reason": exit_reason,
             "nonfinite_steps": nonfinite_steps,
@@ -378,6 +392,7 @@ def run_training(job: TrainJobConfig,
                 "unexpected_compiles":
                     obs_device.SENTINEL.unexpected - unexpected_before,
                 "hbm_peak_bytes": hbm_peak_bytes or None,
+                "hbm_bytes_in_use_per_device": hbm_per_device,
             },
             "history": history,
         }
@@ -680,13 +695,13 @@ def run_training(job: TrainJobConfig,
                     # absent on CPU where memory_stats() is None) and the
                     # analytic-MFU cross-check from the step program's
                     # cost_analysis.
-                    hbm_now = max(
-                        (m.get("bytes_in_use", 0)
-                         for m in obs_device.set_memory_gauges()),
-                        default=0)
-                    if hbm_now:
-                        entry["hbm_used_bytes"] = hbm_now
-                        hbm_peak_bytes = max(hbm_peak_bytes, hbm_now)
+                    hbm = [m["bytes_in_use"]
+                           for m in obs_device.set_memory_gauges()
+                           if "bytes_in_use" in m]
+                    if hbm:
+                        hbm_per_device = hbm
+                        entry["hbm_used_bytes"] = max(hbm)
+                        hbm_peak_bytes = max(hbm_peak_bytes, max(hbm))
                     if device_cost and win["steps"] and peak_flops:
                         entry["analytic_mfu"] = round(
                             device_cost["flops"]

@@ -1,23 +1,24 @@
-"""Persistent JAX compilation cache under the artifacts dir.
+"""Persistent JAX compilation cache, placed from outside.
 
-A restarted trainer Job (slice restart with resume — controller/model.py)
-or serve worker otherwise pays the full XLA compile again; pointing JAX's
-persistent compilation cache at the durable artifacts mount
-(/content/artifacts per the container contract) makes restarts start
-stepping in seconds instead of minutes. Worth real money on TPU: the chips
-idle for the whole recompile.
+A restarted trainer Job or serve worker — and every cold call on a chip
+machine — otherwise pays the full XLA compile again while the chips idle.
 
-Env knobs:
-  RBT_JAX_CACHE=0                disable entirely
-  RBT_JAX_CACHE=1                force-enable (including on CPU, see below)
-  JAX_COMPILATION_CACHE_DIR      override the cache location
+Where it lives is the deployment's decision, not the workload's:
 
-CPU is opt-in only: deserializing a warm cache entry on the CPU backend of
-older jaxlib (0.4.x) corrupts the heap ("corrupted double-linked list" /
-segfault on the run AFTER the one that wrote the cache — reproduced with a
-two-process resume against one artifacts dir). The accelerator backends,
-where the recompile actually costs money, are the production contract and
-stay enabled by default.
+  JAX_COMPILATION_CACHE_DIR   set: JAX reads it itself; this module sets no
+                              directory in code. The operator exports it to
+                              the durable artifacts mount for train Jobs
+                              (controller/model.py).
+  (unset)                     <checkout>/.jax_cache, one fixed git-ignored
+                              path. The directory is part of the cache key,
+                              so it is never derived from a temp dir, a pid
+                              or the clock.
+  RBT_JAX_CACHE=0             disable entirely (the test suite does, so
+                              tier-1 neither writes into the checkout nor
+                              depends on what a previous run left there).
+
+Call before the process's first compile: JAX decides once, at the first
+compile, whether a cache is in use.
 """
 
 from __future__ import annotations
@@ -25,40 +26,26 @@ from __future__ import annotations
 import os
 from typing import Optional
 
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (default:
-    $JAX_COMPILATION_CACHE_DIR, else <artifacts>/jax_cache). Returns the
-    directory in use, or None when disabled/unavailable. Safe to call more
-    than once and before/after other jax.config use; never raises — a
-    missing cache is a perf bug, not a correctness one."""
-    force = os.environ.get("RBT_JAX_CACHE")
-    if force == "0":
+
+def enable_compilation_cache() -> Optional[str]:
+    """Turn the persistent compilation cache on and return its directory
+    (None only under RBT_JAX_CACHE=0). Errors propagate: a cache that
+    silently fails costs minutes of chip time per start."""
+    if os.environ.get("RBT_JAX_CACHE") == "0":
         return None
-    try:
-        import jax
+    import jax
 
-        if force != "1" and jax.default_backend() == "cpu":
-            return None  # known-crashy warm-read path (module docstring)
-
-        if cache_dir is None:
-            cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        if cache_dir is None:
-            from runbooks_tpu.utils import contract
-
-            cache_dir = os.path.join(contract.artifacts_dir(), "jax_cache")
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # Cache every compile that takes noticeable time: the default
-        # 1s floor skips the many small serve/trainer helper jits whose
-        # compiles still add up across a restart.
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.2)
-        except Exception:
-            pass  # knob renamed/absent on some versions; dir alone works
-        return cache_dir
-    except Exception as exc:
-        print(f"jax_cache: persistent compilation cache disabled ({exc!r})",
-              flush=True)
-        return None
+    # Cache every compile that takes noticeable time: the default 1 s floor
+    # skips the many small serve/trainer helper jits whose compiles still
+    # add up across a restart.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    return cache_dir

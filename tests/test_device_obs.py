@@ -229,6 +229,36 @@ def test_device_memory_stats_cpu_degradation():
 # Roofline
 # ---------------------------------------------------------------------------
 
+V5E_PEAKS = (197e12, 819e9)
+
+
+@pytest.fixture
+def v5e_peaks(monkeypatch):
+    """Classify against a v5e's peaks: the CPU the suite runs on has none
+    (see test_no_peaks_off_tpu), so the fields that need one are absent
+    unless a test supplies them."""
+    monkeypatch.setattr(obs_device, "device_peaks", lambda: V5E_PEAKS)
+
+
+def test_no_peaks_off_tpu():
+    """A CPU has no device peak: nothing is computed from a stand-in, the
+    peak-derived fields are absent, and an unknown TPU raises."""
+    from types import SimpleNamespace
+
+    from runbooks_tpu.utils import hw
+
+    assert hw.chip_peaks(jax.devices()[0]) is None
+    assert obs_device.device_peaks() is None
+    roof = obs_device.classify_roofline(1e9, 1e6)
+    assert roof == {"arithmetic_intensity": 1000.0}
+    assert hw.chip_peaks(SimpleNamespace(
+        platform="tpu", device_kind="TPU v5 lite")) == V5E_PEAKS
+    with pytest.raises(ValueError, match="TPU v99"):
+        hw.chip_peaks(SimpleNamespace(platform="tpu",
+                                      device_kind="TPU v99"))
+    assert not hasattr(obs_device, "NOMINAL_PEAK_FLOPS")
+
+
 def test_roofline_classification_on_known_matmuls():
     # Square matmul: AI = 2n^3 / (3 * 4n^2) = n/6 flops/byte — far right
     # of a ridge of 10 at n=1024.
@@ -255,7 +285,7 @@ def test_roofline_classification_on_known_matmuls():
     assert roof_v["arithmetic_intensity"] < 10.0
 
 
-def test_engine_decode_measures_bandwidth_bound():
+def test_engine_decode_measures_bandwidth_bound(v5e_peaks):
     """The engine's 'decode is HBM-bound' analysis (serve/engine.py) is
     now a recorded cost: warmup captures per-program roofline costs and
     the decode program classifies bandwidth-bound."""
@@ -313,7 +343,7 @@ def test_http_debug_memory_endpoint():
     asyncio.run(drive())
 
 
-def test_http_debug_programs_endpoint_and_metrics_families():
+def test_http_debug_programs_endpoint_and_metrics_families(v5e_peaks):
     from aiohttp.test_utils import TestClient, TestServer
 
     from runbooks_tpu.serve.api import create_server
@@ -383,7 +413,7 @@ def test_debug_profile_bundles_memory_snapshot(tmp_path, monkeypatch):
 # Trainer integration
 # ---------------------------------------------------------------------------
 
-def test_trainer_device_obs_summary(tmp_path):
+def test_trainer_device_obs_summary(tmp_path, v5e_peaks):
     from runbooks_tpu.parallel.mesh import MeshConfig
     from runbooks_tpu.train.optimizer import OptimizerConfig
     from runbooks_tpu.train.trainer import TrainJobConfig, run_training
@@ -543,18 +573,23 @@ def test_catalog_covers_device_obs_families():
 
 def test_bench_device_obs_axis(monkeypatch, capsys):
     """RBT_BENCH_DEVICE_OBS=1 runs the steady-loop compile gate and
-    reports analytic vs formula MFU side by side."""
+    cross-checks analytic vs formula FLOPs. It refuses a CPU unless
+    forced, and a forced CPU run reports no MFU and nests its numbers."""
     import bench
 
     monkeypatch.setenv("RBT_BENCH_DEVICE_OBS", "1")
+    with pytest.raises(SystemExit, match="not a TPU"):
+        bench.inner()
+    monkeypatch.setenv("RBT_BENCH_FORCE_CPU", "1")
     monkeypatch.setenv("RBT_BENCH_BS", "2")
     monkeypatch.setenv("RBT_BENCH_SEQ", "64")
     bench.inner()
     line = [ln for ln in capsys.readouterr().out.splitlines()
             if ln.startswith("{")][-1]
-    out = json.loads(line)
+    line = json.loads(line)
+    assert line["platform"] == "cpu" and "value" not in line
+    out = line["cpu_functional_run"]
     assert out["value"] == 0                 # zero unexpected compiles
     assert out["vs_baseline"] == 1.0
-    assert out["mfu_analytic"] > 0 and out["mfu_formula"] > 0
     assert 0.3 < out["flops_ratio"] < 3.0
-    assert out["bound"] in ("compute", "bandwidth")
+    assert not {"mfu_analytic", "mfu_formula", "bound"} & set(out)

@@ -103,6 +103,44 @@ def test_gqa_forward_and_grads():
                                    err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize("kv_h,mesh_axes,plan", [
+    (2, dict(data=2, fsdp=2, tensor=2), ("tensor", "tensor")),
+    # Multi-query (Falcon-7B's shape): query heads shard, the one kv head
+    # is read whole by every shard and its gradient is summed over them.
+    (1, dict(fsdp=2, tensor=4), ("tensor", None)),
+    # A GQA grouping the tensor axis would break stays replicated.
+    (3, dict(fsdp=4, tensor=2), (None, None)),
+], ids=["gqa", "mqa", "indivisible"])
+def test_kernels_run_per_shard_under_a_mesh(kv_h, mesh_axes, plan):
+    """On a multi-device mesh the kernels launch inside a shard_map (a TPU
+    refuses to partition a Mosaic kernel); value and gradients must still
+    match the oracle for every head layout."""
+    from runbooks_tpu.ops.flash_attention import _shard_plan
+    from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    h = 6 if kv_h == 3 else 8
+    q, _, _, q_pos, kv_pos = make_inputs(b=4, sq=64, sk=64, h=h, d=16)
+    k, v = (jax.random.normal(jax.random.key(i), (4, 64, kv_h, 16))
+            for i in (7, 8))
+
+    def loss(fn, q, k, v):
+        return (fn(q, k, v) ** 2).sum()
+
+    want = jax.value_and_grad(
+        lambda q, k, v: loss(lambda q, k, v: oracle(
+            q, jnp.repeat(k, h // kv_h, axis=2),
+            jnp.repeat(v, h // kv_h, axis=2), q_pos, kv_pos), q, k, v),
+        argnums=(0, 1, 2))(q, k, v)
+    with jax.set_mesh(make_mesh(MeshConfig(**mesh_axes))):
+        assert (_shard_plan(q, k).heads, _shard_plan(q, k).kv_heads) == plan
+        got = jax.jit(jax.value_and_grad(
+            lambda q, k, v: loss(lambda q, k, v: flash_attention(
+                q, k, v, q_pos, kv_pos, None, None, True, None, 32, 32),
+                q, k, v), argnums=(0, 1, 2)))(q, k, v)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+
 def test_gradients_match_oracle():
     q, k, v, q_pos, kv_pos = make_inputs(b=1, sq=96, sk=96, h=2, d=16)
 

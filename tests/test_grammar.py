@@ -407,7 +407,7 @@ def test_zero_unexpected_compiles_mixed_grammar_lora_loop(
 
     Dense engine only: a full paged warmup costs ~30 s of compiles on
     CPU and the paged grammar dispatch is already covered by the parity
-    and preemption tests here plus the bench gate (bench_sweep §4a8);
+    and preemption tests here plus the RBT_BENCH_GRAMMAR bench axis;
     the mixed-traffic zero-compile property itself is engine-agnostic."""
     engine_cls = "dense"
     from runbooks_tpu.obs import device as obs_device

@@ -174,6 +174,30 @@ def test_mesh_collective_matmul_auto(model, mesh):
     assert all(len(r.output_tokens) == 6 for r in reqs)
 
 
+def test_load_model_lays_weights_out_on_the_mesh(mesh, monkeypatch,
+                                                tmp_path):
+    """serve/api.load_model with a serving mesh returns the weights already
+    sharded the way the engine shards them, with the same values as the
+    unsharded load, and leaves no unsharded copy behind (on four chips that
+    copy was 9.2 GB on device 0 and failed the first large prefill)."""
+    from runbooks_tpu.serve.api import load_model
+
+    monkeypatch.setenv("RBT_CONTENT_DIR", str(tmp_path))
+    spec = {"model": "debug", "seed": 3, "model_overrides": {
+        "num_layers": 2, "hidden_size": 64, "num_heads": 4,
+        "num_kv_heads": 2, "head_dim": 16, "vocab_size": 128}}
+    _, plain = load_model(spec)
+    cfg, sharded = load_model(spec, mesh)
+    wq = sharded["layers"]["attn"]["wq"]
+    assert wq.sharding.mesh.shape == mesh.shape
+    assert "tensor" in jax.tree.leaves(tuple(wq.sharding.spec))
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(sharded)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # The engine's own placement is then a no-op: same buffers.
+    engine = InferenceEngine(cfg, sharded, max_slots=2, mesh=mesh)
+    assert engine.params["layers"]["attn"]["wq"] is wq
+
+
 # ---------------------------------------------------------------------------
 # Compile discipline under the mesh
 # ---------------------------------------------------------------------------
@@ -200,6 +224,26 @@ def test_mesh_zero_unexpected_compiles_in_steady_loop(model, mesh):
             engine.step()
         assert all(r.finished for r in reqs)
         assert sentinel.unexpected == before, sentinel.recent_unexpected()
+    finally:
+        engine.release_steady()
+
+
+def test_mesh_dense_engine_zero_unexpected_compiles(model, mesh):
+    """The dense engine under a mesh: the first prefill after warmup (which
+    ends in reset(), i.e. a FRESH pool) must hit a warmup-compiled program.
+    A pool whose index scalar was left uncommitted keyed a second jit
+    entry and recompiled under traffic (found by chip_smoke's four-chip
+    dry run)."""
+    from runbooks_tpu.obs import device as obs_device
+
+    cfg, params = model
+    engine = InferenceEngine(cfg, params, max_slots=2, mesh=mesh)
+    try:
+        engine.warmup()
+        before = obs_device.SENTINEL.unexpected
+        engine.generate(greedy_reqs(PROMPTS, max_tokens=3))
+        assert obs_device.SENTINEL.unexpected == before, \
+            obs_device.SENTINEL.recent_unexpected()
     finally:
         engine.release_steady()
 

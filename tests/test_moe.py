@@ -11,13 +11,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from runbooks_tpu.models.config import get_config
 from runbooks_tpu.models.moe import moe_capacity
 from runbooks_tpu.models.transformer import forward, init_params
 from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
-from tests.conftest import partial_manual_shard_map_broken
 
 
 def moe_cfg(**over):
@@ -135,10 +133,6 @@ def test_moe_train_step_learns_and_balances():
     assert losses[-1] < losses[0]
 
 
-@pytest.mark.skipif(
-    partial_manual_shard_map_broken(),
-    reason="old-jaxlib SPMD PartitionId limitation: partial-manual "
-           "(stage) shard_map cannot be partitioned")
 def test_moe_composes_with_pipeline():
     cfg = moe_cfg(num_layers=4)
     params = init_params(cfg, jax.random.key(0))

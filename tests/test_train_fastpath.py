@@ -129,7 +129,7 @@ def test_chunked_ce_matches_with_uniform_weights_and_exact_chunks():
 def _iter_avals(jaxpr):
     """All input/output avals in a jaxpr, recursing into sub-jaxprs
     (scan/checkpoint/pjit bodies)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subjaxprs(p):
         vals = p if isinstance(p, (tuple, list)) else (p,)
@@ -428,28 +428,3 @@ def test_device_placer_shards_batches_on_mesh(devices):
     toks = placed["tokens"]
     assert isinstance(toks, jax.Array) and toks.shape == (8, 16)
     assert len({s.device for s in toks.addressable_shards}) == 8
-
-
-# ---------------------------------------------------------------------------
-# Compilation cache helper
-# ---------------------------------------------------------------------------
-
-def test_enable_compilation_cache(tmp_path, monkeypatch):
-    from runbooks_tpu.utils.jax_cache import enable_compilation_cache
-
-    # CPU backend (this suite) is opt-in only: warm-cache reads corrupt
-    # the heap on older CPU jaxlib (see utils/jax_cache.py docstring).
-    target = str(tmp_path / "jax_cache")
-    assert enable_compilation_cache(target) is None
-
-    monkeypatch.setenv("RBT_JAX_CACHE", "1")  # force (the TPU default path)
-    before = jax.config.jax_compilation_cache_dir
-    try:
-        assert enable_compilation_cache(target) == target
-        assert jax.config.jax_compilation_cache_dir == target
-    finally:
-        # Restore so later tests in this process never hit a warm read.
-        jax.config.update("jax_compilation_cache_dir", before)
-
-    monkeypatch.setenv("RBT_JAX_CACHE", "0")
-    assert enable_compilation_cache(str(tmp_path / "other")) is None

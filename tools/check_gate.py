@@ -8,8 +8,7 @@ over ShapeDtypeStructs; a compile means real execution snuck in,
 verified via the PR-7 compile sentinel) and a wall-time budget
 (default 30 s on CPU — the audit must stay cheap enough to gate every
 CI run). The printed value is the audit wall seconds, so a creeping
-audit shows in the `bench_sweep.sh` transcript before it becomes a
-gate people skip.
+audit shows before it becomes a gate people skip.
 
 Run: ``python tools/check_gate.py [budget_seconds]``
 """

@@ -5,10 +5,9 @@ Spins N fake Server replicas (real HTTP /metrics endpoints rendering
 real registries with latency histograms), registers them as Running
 pods in the in-memory cluster, runs `FleetScraper.scrape_once`, and
 verifies the controller-side exposition carries every replica's series
-plus the freshness gauges. The printed value is the sweep wall time —
-the number `bench_sweep.sh` tracks so a scrape sweep that starts taking
-seconds (it must stay tens of ms at this scale) is visible in the
-transcript.
+plus the freshness gauges. The printed value is the sweep wall time, so
+a scrape sweep that starts taking seconds (it must stay tens of ms at this
+scale) is visible.
 
 Run: ``python tools/fleet_smoke.py [replicas]``
 """

@@ -1,6 +1,6 @@
 """Remat-policy x state-dtype memory frontier on the virtual CPU mesh.
 
-Repeatable source of the BENCH_NOTES frontier tables: compiles the full
+Repeatable source of the memory-frontier tables: compiles the full
 train step for each (remat_policy, param/mu dtype) combination and prints
 ``compiled.memory_analysis()`` temp + argument bytes. No TPU needed — XLA's
 buffer assignment on CPU gives the relative ordering the policies will show

@@ -5,7 +5,7 @@ microbatch count (residual ring of min(M, 2S-1) block inputs), while the
 gpipe/autodiff schedule keeps O(M) microbatch activations live. CPU
 ``memory_analysis()`` cannot model cross-tick buffer reuse exactly, but the
 M-scaling DIRECTION is visible in temp bytes: gpipe temp should grow with
-M, 1F1B should stay ~flat. Records the trail queued in BENCH_NOTES r3.
+M, 1F1B should stay ~flat.
 
 Usage: python tools/pipeline_memory.py [--stages 4] [--layers 8]
 """
