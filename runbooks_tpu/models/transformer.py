@@ -521,102 +521,126 @@ def _attention_block(
             y = y + p[bname].astype(ad)
         return y
 
-    q = proj(p["wq"], "bq", "wq").reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = proj(p["wk"], "bk", "wk").reshape(b, s, cfg.num_kv_heads,
-                                          cfg.head_dim)
-    v = proj(p["wv"], "bv", "wv").reshape(b, s, cfg.num_kv_heads,
-                                          cfg.head_dim)
-    q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
-    k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
-    v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
-
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    # The named scopes are metadata only (op names in the HLO and in a
+    # profiler capture; docs/observability.md): the compiled program and
+    # its compile-cache key do not change.
+    with jax.named_scope("attn.qkv"):
+        q = proj(p["wq"], "bq", "wq").reshape(b, s, cfg.num_heads,
+                                              cfg.head_dim)
+        k = proj(p["wk"], "bk", "wk").reshape(b, s, cfg.num_kv_heads,
+                                              cfg.head_dim)
+        v = proj(p["wv"], "bv", "wv").reshape(b, s, cfg.num_kv_heads,
+                                              cfg.head_dim)
+        q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
+        k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
+        v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.position_type == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("attn.rope"):
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
 
     new_layer_cache = None
     if layer_cache is not None:
-        ck, cv, ck_s, cv_s, index, view = layer_cache
-        quantized = ck.dtype == jnp.int8
-        if quantized:
-            # int8 KV: one f32 scale per (row, slot, kv-head) rides next to
-            # the int8 values; both scatter with the same indices.
-            k_w, k_s = quantize_kv(k)
-            v_w, v_s = quantize_kv(v)
-        else:
-            k_w, v_w, k_s, v_s = k, v, None, None
-        if index is None:
-            # Position-scatter mode: row b token j -> slot positions[b, j].
-            cache_len = ck.shape[1]
-            slot = jnp.clip(positions, 0, cache_len - 1)
-            b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            ck = ck.at[b_idx, slot].set(k_w)
-            cv = cv.at[b_idx, slot].set(v_w)
-            if quantized:
-                ck_s = ck_s.at[b_idx, slot].set(k_s)
-                cv_s = cv_s.at[b_idx, slot].set(v_s)
-        else:
-            ck = jax.lax.dynamic_update_slice(ck, k_w, (0, index, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v_w, (0, index, 0, 0))
-            if quantized:
-                ck_s = jax.lax.dynamic_update_slice(ck_s, k_s, (0, index, 0))
-                cv_s = jax.lax.dynamic_update_slice(cv_s, v_s, (0, index, 0))
-        # Writes go to the FULL cache; attention READS only [0, view).
-        # Exact for any view > max query position: slot s is attended only
-        # by queries at positions >= s, so slots beyond the view hold
-        # nothing a masked-in query could see. Serving uses this to stop
-        # decode from streaming the whole max-length cache through HBM
-        # when occupancy is low (the decode step is bandwidth-bound).
-        if view is None:
-            k, v = ck, cv
-            rk_s, rv_s = ck_s, cv_s
-        else:
-            k, v = ck[:, :view], cv[:, :view]
-            rk_s = ck_s[:, :view] if quantized else None
-            rv_s = cv_s[:, :view] if quantized else None
-        if quantized:
-            # Dequantize at the read: the scale multiply fuses into the
-            # attention contraction, so HBM streams int8 + one scale per
-            # row — half the bytes of the bf16 cache the decode step is
-            # bound on.
-            k = dequantize_kv(k, rk_s, ad)
-            v = dequantize_kv(v, rv_s, ad)
-        new_layer_cache = (ck, cv, ck_s, cv_s)
-        if mask is None:
-            # Flash cached-prefill (forward() skipped the O(s*kv) mask
-            # build): cache slot i holds absolute position i by
-            # construction, so the kernel's causal-by-absolute-position
-            # masking reproduces the XLA path's mask exactly — unwritten
-            # or future slots are never attended. block_skip stays off:
-            # query rows start at position cache.index, not 0, so grid
-            # index alignment does not hold.
-            from runbooks_tpu.ops.flash_attention import flash_attention
-
-            kv_pos = jnp.broadcast_to(
-                jnp.arange(k.shape[1], dtype=jnp.int32)[None, :],
-                (b, k.shape[1]))
-            out = flash_attention(
-                q, k, v, positions, kv_pos, None, None, True, None,
-                cfg.flash_block_q, cfg.flash_block_k, block_skip=False)
-        else:
-            # Decode (s=1) keeps the XLA path: a one-row query block has no
-            # O(s^2) term and the step is bandwidth-bound anyway.
-            out = dot_product_attention(
-                q, k, v, mask=mask, bias=bias,
-                logit_softcap=cfg.logit_softcap)
+        with jax.named_scope("attn.kv_write"):
+            k, v, new_layer_cache = _write_layer_cache(k, v, positions,
+                                                       layer_cache, ad)
+        with jax.named_scope("attn.core"):
+            out = _cached_attention(cfg, q, k, v, positions, mask, bias)
     else:
-        out = _dispatch_attention(cfg, q, k, v, positions, segment_ids,
-                                  mask, bias)
-    out = out.reshape(b, s, cfg.q_dim)
-    attn_ctx = out
-    out = _matmul(out, p["wo"], ad, ring=ring_row, ring_bidir=bidir)
-    out = _adapter_delta(adapter, "wo", attn_ctx, out, ad)
-    if "bo" in p:
-        out = out + p["bo"].astype(ad)
+        with jax.named_scope("attn.core"):
+            out = _dispatch_attention(cfg, q, k, v, positions, segment_ids,
+                                      mask, bias)
+    with jax.named_scope("attn.out"):
+        out = out.reshape(b, s, cfg.q_dim)
+        attn_ctx = out
+        out = _matmul(out, p["wo"], ad, ring=ring_row, ring_bidir=bidir)
+        out = _adapter_delta(adapter, "wo", attn_ctx, out, ad)
+        if "bo" in p:
+            out = out + p["bo"].astype(ad)
     return out, new_layer_cache
+
+
+def _write_layer_cache(k, v, positions, layer_cache, ad):
+    """Write this call's K/V into one layer's cache and return what
+    attention reads: (k, v, new layer cache)."""
+    b = k.shape[0]
+    ck, cv, ck_s, cv_s, index, view = layer_cache
+    quantized = ck.dtype == jnp.int8
+    if quantized:
+        # int8 KV: one f32 scale per (row, slot, kv-head) rides next to
+        # the int8 values; both scatter with the same indices.
+        k_w, k_s = quantize_kv(k)
+        v_w, v_s = quantize_kv(v)
+    else:
+        k_w, v_w, k_s, v_s = k, v, None, None
+    if index is None:
+        # Position-scatter mode: row b token j -> slot positions[b, j].
+        cache_len = ck.shape[1]
+        slot = jnp.clip(positions, 0, cache_len - 1)
+        b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+        ck = ck.at[b_idx, slot].set(k_w)
+        cv = cv.at[b_idx, slot].set(v_w)
+        if quantized:
+            ck_s = ck_s.at[b_idx, slot].set(k_s)
+            cv_s = cv_s.at[b_idx, slot].set(v_s)
+    else:
+        ck = jax.lax.dynamic_update_slice(ck, k_w, (0, index, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v_w, (0, index, 0, 0))
+        if quantized:
+            ck_s = jax.lax.dynamic_update_slice(ck_s, k_s, (0, index, 0))
+            cv_s = jax.lax.dynamic_update_slice(cv_s, v_s, (0, index, 0))
+    # Writes go to the FULL cache; attention READS only [0, view).
+    # Exact for any view > max query position: slot s is attended only
+    # by queries at positions >= s, so slots beyond the view hold
+    # nothing a masked-in query could see. Serving uses this to stop
+    # decode from streaming the whole max-length cache through HBM
+    # when occupancy is low (the decode step is bandwidth-bound).
+    if view is None:
+        k, v = ck, cv
+        rk_s, rv_s = ck_s, cv_s
+    else:
+        k, v = ck[:, :view], cv[:, :view]
+        rk_s = ck_s[:, :view] if quantized else None
+        rv_s = cv_s[:, :view] if quantized else None
+    if quantized:
+        # Dequantize at the read: the scale multiply fuses into the
+        # attention contraction, so HBM streams int8 + one scale per
+        # row — half the bytes of the bf16 cache the decode step is
+        # bound on.
+        k = dequantize_kv(k, rk_s, ad)
+        v = dequantize_kv(v, rv_s, ad)
+    return k, v, (ck, cv, ck_s, cv_s)
+
+
+def _cached_attention(cfg: ModelConfig, q, k, v, positions, mask, bias):
+    """Attention of q against one layer's cache view."""
+    b = q.shape[0]
+    if mask is None:
+        # Flash cached-prefill (forward() skipped the O(s*kv) mask
+        # build): cache slot i holds absolute position i by
+        # construction, so the kernel's causal-by-absolute-position
+        # masking reproduces the XLA path's mask exactly — unwritten
+        # or future slots are never attended. block_skip stays off:
+        # query rows start at position cache.index, not 0, so grid
+        # index alignment does not hold.
+        from runbooks_tpu.ops.flash_attention import flash_attention
+
+        kv_pos = jnp.broadcast_to(
+            jnp.arange(k.shape[1], dtype=jnp.int32)[None, :],
+            (b, k.shape[1]))
+        out = flash_attention(
+            q, k, v, positions, kv_pos, None, None, True, None,
+            cfg.flash_block_q, cfg.flash_block_k, block_skip=False)
+    else:
+        # Decode (s=1) keeps the XLA path: a one-row query block has no
+        # O(s^2) term and the step is bandwidth-bound anyway.
+        out = dot_product_attention(
+            q, k, v, mask=mask, bias=bias,
+            logit_softcap=cfg.logit_softcap)
+    return out
 
 
 def _mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -679,32 +703,47 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
     """One transformer block. x: [b, s, h]. Returns (x, cache, aux).
     ``adapter``: None or (per-layer adapter-pool slice, lane indices) —
     the grouped LoRA injection (docs/multi-tenant-lora.md)."""
-    act_rules = _act_embed_rules(resolve_collective_matmul(cfg))
-    x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
-                                rules=act_rules)
-    h1 = _norm(cfg, layer["ln1"], x)
-    attn_out, new_cache = _attention_block(
-        cfg, layer["attn"], h1, positions, segment_ids, mask, bias,
-        layer_cache, adapter=_adapter_group(adapter, "attn"))
-    # Named checkpoint for selective remat: remat_policy="save_attn_out"
-    # saves this [b, s, h] tensor (plus the flash kernel's hoisted
-    # "attn_context"/"attn_lse" residuals — see ops/flash_attention.py) so
-    # the backward never re-runs the O(s^2) flash fwd kernel, while
-    # activations stay O(layers * b * s * h) instead of the dots_saveable
-    # blow-up.
-    attn_out = checkpoint_name(attn_out, "attn_out")
-    mlp_adapter = _adapter_group(adapter, "mlp")
-    if cfg.parallel_block:
-        h2 = h1 if cfg.shared_layer_norm else _norm(cfg, layer["ln2"], x)
-        mlp_out, aux = _ffn_block(cfg, layer, h2, adapter=mlp_adapter)
-        x = x + attn_out + mlp_out
-    else:
-        x = x + attn_out
-        h2 = _norm(cfg, layer["ln2"], x)
-        ffn_out, aux = _ffn_block(cfg, layer, h2, adapter=mlp_adapter)
-        x = x + ffn_out
-    x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
-                                rules=act_rules)
+    # Scopes: everything a layer does is under `block`; inside it `norm`,
+    # `attn` (with its attn.* parts) and `ffn`; what is left directly
+    # under `block` is the residual adds.
+    with jax.named_scope("block"):
+        act_rules = _act_embed_rules(resolve_collective_matmul(cfg))
+        x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
+                                    rules=act_rules)
+        with jax.named_scope("norm"):
+            h1 = _norm(cfg, layer["ln1"], x)
+        with jax.named_scope("attn"):
+            attn_out, new_cache = _attention_block(
+                cfg, layer["attn"], h1, positions, segment_ids, mask, bias,
+                layer_cache, adapter=_adapter_group(adapter, "attn"))
+        # Named checkpoint for selective remat: remat_policy=
+        # "save_attn_out" saves this [b, s, h] tensor (plus the flash
+        # kernel's hoisted "attn_context"/"attn_lse" residuals — see
+        # ops/flash_attention.py) so the backward never re-runs the
+        # O(s^2) flash fwd kernel, while activations stay
+        # O(layers * b * s * h) instead of the dots_saveable blow-up.
+        attn_out = checkpoint_name(attn_out, "attn_out")
+        mlp_adapter = _adapter_group(adapter, "mlp")
+        if cfg.parallel_block:
+            if cfg.shared_layer_norm:
+                h2 = h1
+            else:
+                with jax.named_scope("norm"):
+                    h2 = _norm(cfg, layer["ln2"], x)
+            with jax.named_scope("ffn"):
+                mlp_out, aux = _ffn_block(cfg, layer, h2,
+                                          adapter=mlp_adapter)
+            x = x + attn_out + mlp_out
+        else:
+            x = x + attn_out
+            with jax.named_scope("norm"):
+                h2 = _norm(cfg, layer["ln2"], x)
+            with jax.named_scope("ffn"):
+                ffn_out, aux = _ffn_block(cfg, layer, h2,
+                                          adapter=mlp_adapter)
+            x = x + ffn_out
+        x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
+                                    rules=act_rules)
     return x, new_cache, aux
 
 
@@ -780,16 +819,18 @@ def forward(
                                          (b, s))
 
     use_one_hot = _auto_embed_one_hot(cfg, has_cache=cache is not None)
-    if use_one_hot:
-        one_hot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=ad)
-        x = jnp.einsum("bsv,vh->bsh", one_hot, params["embed"].astype(ad),
-                       preferred_element_type=jnp.float32).astype(ad)
-    else:
-        x = params["embed"].astype(ad)[tokens]
-    if cfg.embed_scale:
-        x = x * (cfg.hidden_size ** 0.5)
-    if cfg.position_type == "learned":
-        x = x + params["pos_embed"].astype(ad)[positions]
+    with jax.named_scope("embed"):
+        if use_one_hot:
+            one_hot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=ad)
+            x = jnp.einsum("bsv,vh->bsh", one_hot,
+                           params["embed"].astype(ad),
+                           preferred_element_type=jnp.float32).astype(ad)
+        else:
+            x = params["embed"].astype(ad)[tokens]
+        if cfg.embed_scale:
+            x = x * (cfg.hidden_size ** 0.5)
+        if cfg.position_type == "learned":
+            x = x + params["pos_embed"].astype(ad)[positions]
     # Deliberately the DEFAULT (replicated-h) constraint even when the
     # ring path tensor-shards the residual stream: constraining the
     # one-hot embed einsum's output tensor-sharded while its vocab
@@ -802,32 +843,38 @@ def forward(
     x = with_logical_constraint(x, ("batch", "seq", "act_embed"))
 
     # Mask & bias over the full kv extent (or the static read view).
-    if cache is not None:
-        max_kv = cache_view if cache_view is not None else cache.k.shape[2]
-        kv_positions = jnp.broadcast_to(
-            jnp.arange(max_kv, dtype=jnp.int32)[None, :], (b, max_kv))
-        if use_flash_cached_prefill(cfg, s):
-            # Flash cached-prefill: the kernel masks causally from absolute
-            # positions; no O(s*kv) mask tensor (see _attention_block).
-            mask = None
+    with jax.named_scope("attn.mask"):
+        if cache is not None:
+            max_kv = (cache_view if cache_view is not None
+                      else cache.k.shape[2])
+            kv_positions = jnp.broadcast_to(
+                jnp.arange(max_kv, dtype=jnp.int32)[None, :], (b, max_kv))
+            if use_flash_cached_prefill(cfg, s):
+                # Flash cached-prefill: the kernel masks causally from
+                # absolute positions; no O(s*kv) mask tensor (see
+                # _attention_block).
+                mask = None
+            else:
+                # Slots at arange > q position are either future or
+                # unwritten: the causal comparison masks both, so no
+                # separate validity mask needed.
+                mask = make_attention_mask(positions, kv_positions,
+                                           causal=True)
         else:
-            # Slots at arange > q position are either future or unwritten:
-            # the causal comparison masks both, so no separate validity
-            # mask needed.
-            mask = make_attention_mask(positions, kv_positions, causal=True)
-    else:
-        kv_positions = positions
-        if resolve_attention_impl(cfg) == "flash":
-            mask = None  # the kernel masks from positions/segments directly
-        else:
-            mask = make_attention_mask(
-                positions, kv_positions, segment_ids, segment_ids, causal=True)
+            kv_positions = positions
+            if resolve_attention_impl(cfg) == "flash":
+                mask = None  # the kernel masks from positions/segments
+            else:
+                mask = make_attention_mask(
+                    positions, kv_positions, segment_ids, segment_ids,
+                    causal=True)
 
-    bias = None
-    if cfg.position_type == "alibi":
-        slopes = alibi_slopes(cfg.num_heads)  # [h]
-        rel = (kv_positions[:, None, :] - positions[:, :, None]).astype(jnp.float32)
-        bias = slopes[None, :, None, None] * rel[:, None, :, :]
+        bias = None
+        if cfg.position_type == "alibi":
+            slopes = alibi_slopes(cfg.num_heads)  # [h]
+            rel = (kv_positions[:, None, :]
+                   - positions[:, :, None]).astype(jnp.float32)
+            bias = slopes[None, :, None, None] * rel[:, None, :, :]
 
     block = _block
     if remat and cfg.remat_policy != "none":
@@ -870,8 +917,11 @@ def forward(
               cache.k_scale, cache.v_scale)
         if apool is not None:
             xs = xs + (apool,)
-        (x, aux_total), (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            scan_body, (x, aux_total), xs)
+        # `layers`: the scan itself (slices of the stacked weights, what
+        # the compiler hoists out of the loop); each layer is a `block`.
+        with jax.named_scope("layers"):
+            (x, aux_total), (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
+                scan_body, (x, aux_total), xs)
         new_index = cache.index if scatter_mode else cache.index + s
         new_cache = KVCache(k=new_k, v=new_v, index=new_index,
                             k_scale=new_ks, v_scale=new_vs)
@@ -897,19 +947,22 @@ def forward(
                 y, _, aux = block(cfg, layer, xx, pos, seg, mk, bs, None)
                 return y, aux
 
-            x, aux_total = pipeline_apply(
-                pipe_block, params["layers"], x,
-                (positions, segment_ids, mask, bias),
-                mesh=mesh, n_stages=n_stages,
-                n_microbatches=cfg.pipeline_microbatches or None)
+            with jax.named_scope("layers"):
+                x, aux_total = pipeline_apply(
+                    pipe_block, params["layers"], x,
+                    (positions, segment_ids, mask, bias),
+                    mesh=mesh, n_stages=n_stages,
+                    n_microbatches=cfg.pipeline_microbatches or None)
         else:
             xs = (params["layers"] if apool is None
                   else (params["layers"], apool))
-            (x, aux_total), _ = jax.lax.scan(
-                scan_body, (x, aux_total), xs)
+            with jax.named_scope("layers"):
+                (x, aux_total), _ = jax.lax.scan(
+                    scan_body, (x, aux_total), xs)
         new_cache = None
 
-    x = _norm(cfg, params["final_norm"], x)
+    with jax.named_scope("head"):
+        x = _norm(cfg, params["final_norm"], x)
     if return_activations:
         act_rules = _act_embed_rules(resolve_collective_matmul(cfg))
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
@@ -920,10 +973,11 @@ def forward(
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     # bf16 operands + f32 accumulation: the MXU accumulates in f32 either
     # way, but f32 operands run at 1/4 the bf16 MXU rate on v5e/v5p.
-    logits = jnp.einsum("bsh,hv->bsv", x.astype(cfg.activation_dtype),
-                        head.astype(cfg.activation_dtype),
-                        preferred_element_type=jnp.float32)
-    logits = with_logical_constraint(logits, ("batch", "seq", None))
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bsh,hv->bsv", x.astype(cfg.activation_dtype),
+                            head.astype(cfg.activation_dtype),
+                            preferred_element_type=jnp.float32)
+        logits = with_logical_constraint(logits, ("batch", "seq", None))
     if with_aux:
         return logits, new_cache, aux_total
     return logits, new_cache

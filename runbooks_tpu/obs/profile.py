@@ -41,8 +41,15 @@ class Profiler:
         with self._lock:
             return self._active_dir
 
-    def start(self, log_dir: str) -> str:
+    def start(self, log_dir: str, python_tracer: bool = False) -> str:
+        """Begin a capture. While it runs, every obs.trace span is also a
+        TraceAnnotation in it (host spans on the profiler's clock, beside
+        the device planes). The Python tracer is off unless asked for:
+        with the program's own spans in the trace its frames are no longer
+        the only host names, and it slows the threads it instruments."""
         import jax
+
+        from runbooks_tpu.obs import trace as obs_trace
 
         with self._lock:
             if self._active_dir is not None:
@@ -50,25 +57,31 @@ class Profiler:
                     f"a profile capture is already writing to "
                     f"{self._active_dir}")
             os.makedirs(log_dir, exist_ok=True)
-            jax.profiler.start_trace(log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python_tracer else 0
+            jax.profiler.start_trace(log_dir, profiler_options=options)
             self._active_dir = log_dir
-        from runbooks_tpu.obs import trace as obs_trace
-
-        obs_trace.instant("profile.start", dir=log_dir)
+            obs_trace.set_annotator(jax.profiler.TraceAnnotation)
+        # Inside the capture and in the ring / trace.jsonl at once: the one
+        # event that carries both clocks, so an operator can lay the
+        # wall-clock events of those sinks over this capture.
+        obs_trace.instant("profile.start", dir=log_dir,
+                          unix_ns=time.time_ns())
         return log_dir
 
     def stop(self) -> Optional[str]:
         import jax
 
+        from runbooks_tpu.obs import trace as obs_trace
+
         with self._lock:
             if self._active_dir is None:
                 return None
+            obs_trace.set_annotator(None)
             try:
                 jax.profiler.stop_trace()
             finally:
                 log_dir, self._active_dir = self._active_dir, None
-        from runbooks_tpu.obs import trace as obs_trace
-
         obs_trace.instant("profile.stop", dir=log_dir)
         # Self-contained bundle: snapshot the device memory state
         # (memory_stats() + live-array census) beside the XLA trace, so
@@ -85,10 +98,11 @@ class Profiler:
             print(f"profile: memory snapshot failed: {exc!r}", flush=True)
         return log_dir
 
-    def capture(self, log_dir: str, seconds: float) -> str:
+    def capture(self, log_dir: str, seconds: float,
+                python_tracer: bool = False) -> str:
         """Blocking timed capture: start, sleep, stop. Call off the event
         loop (the serve API runs it in an executor)."""
-        self.start(log_dir)
+        self.start(log_dir, python_tracer)
         try:
             time.sleep(max(seconds, 0.0))
         finally:

@@ -1,13 +1,22 @@
 """Lightweight JSONL trace spans (Chrome ``trace_event`` compatible).
 
-``RBT_TRACE=1`` turns FILE emission on; independent of that switch,
-every event built here also tees into the in-memory flight-recorder
-ring (obs/flight.py, always on unless ``RBT_FLIGHT=0``) so the recent
-timeline survives for ``/debug/flight``, tail sampling, and incident
-bundles. With both switches off a span is a near-zero-cost no-op (one
-env lookup + one shared null context manager per span, so the
+One span mechanism, three sinks. ``RBT_TRACE=1`` turns FILE emission on;
+independent of that switch, every ``span`` also tees into the in-memory
+flight-recorder ring (obs/flight.py, always on unless ``RBT_FLIGHT=0``)
+so the recent timeline survives for ``/debug/flight``, tail sampling, and
+incident bundles; and while a profiler capture of ``obs.profile.PROFILER``
+is in flight every span also opens a ``jax.profiler.TraceAnnotation``, so
+the capture holds the program's own spans on the profiler's clock beside
+the device planes. ``obs/profile.py`` arms and disarms that sink through
+``set_annotator`` — this module imports no JAX (the gateway imports it).
+With every sink off a span is a near-zero-cost no-op (one attribute test,
+one env lookup + one shared null context manager per span, so the
 instrumented hot loops — trainer steps, engine ticks, reconciles — pay
 nothing when recording is off).
+
+``fine`` is ``span`` for phases too frequent for the ring (the children of
+an engine tick): a capture and ``RBT_TRACE=1`` record them, the ring never
+does, so its depth in minutes stays what it was.
 
 File format: the Chrome/Perfetto "JSON Array Format" with one event per
 line — an opening ``[`` line, then ``{...},`` per event. The spec allows
@@ -37,6 +46,7 @@ generations stay independently Perfetto-loadable and line-parseable.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -56,11 +66,47 @@ def trace_enabled() -> bool:
     return os.environ.get("RBT_TRACE", "") == "1"
 
 
+# jax.profiler.TraceAnnotation while a capture of obs.profile.PROFILER is
+# in flight, None otherwise. Set and cleared by Profiler.start/stop only.
+_ANNOTATE = None
+
+
+def set_annotator(factory) -> None:
+    """Arm (a ``TraceAnnotation``-like class) or disarm (None) the
+    profiler sink. obs/profile.py owns the one call site of each."""
+    global _ANNOTATE
+    _ANNOTATE = factory
+
+
 def record_enabled() -> bool:
-    """True when span events go ANYWHERE (trace file or flight ring) —
-    the gate hot paths use before materializing span attributes
-    (request-id lists etc.)."""
-    return trace_enabled() or flight.recording()
+    """True when span events go ANYWHERE (profiler capture, trace file or
+    flight ring) — the gate hot paths use before materializing span
+    attributes (request-id lists etc.)."""
+    return (_ANNOTATE is not None or trace_enabled()
+            or flight.recording())
+
+
+def fine_enabled() -> bool:
+    """The same gate for ``fine`` spans, which never enter the ring."""
+    return _ANNOTATE is not None or trace_enabled()
+
+
+def _annotation(name: str, args: dict):
+    """An entered TraceAnnotation for the capture in flight, or None.
+    Its arguments become event stats: lists are joined with spaces (the
+    annotation encodes ``name#k=v,k=v#``, so a comma would split a
+    value)."""
+    factory = _ANNOTATE
+    if factory is None:
+        return None
+    flat = {k: (" ".join(map(str, v)) if isinstance(v, (list, tuple))
+                else v) for k, v in args.items()}
+    try:
+        ann = factory(name, **flat)
+        ann.__enter__()
+    except Exception:  # noqa: BLE001 — tracing never takes the work down
+        return None
+    return ann
 
 
 class _NullSpan:
@@ -73,6 +119,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **args) -> None:
+        pass
 
 
 _NULL = _NullSpan()
@@ -257,32 +306,49 @@ def write_event(event: dict) -> None:
     _WRITER.write(event)
 
 
-def _emit(event: dict) -> None:
+def _emit(event: dict, ring: bool = True) -> None:
     """Route one event: the trace file when file tracing is on, the
-    flight ring whenever the recorder is."""
+    flight ring whenever the recorder is (``fine`` spans skip it)."""
     if trace_enabled():
         _WRITER.write(event)
-    if flight.recording():
+    if ring and flight.recording():
         flight.RING.record(event)
 
 
 class _Span:
     """One complete event (``ph: "X"``): records wall-clock start and
-    monotonic duration, emitted at exit."""
+    monotonic duration, emitted at exit; inside a profiler capture the
+    same interval is a TraceAnnotation on this thread's line."""
 
-    __slots__ = ("name", "args", "_ts", "_t0")
+    __slots__ = ("name", "args", "_ring", "_ts", "_t0", "_ann")
 
-    def __init__(self, name: str, args: dict):
+    def __init__(self, name: str, args: dict, ring: bool = True):
         self.name = name
         self.args = args
+        self._ring = ring
+
+    def set(self, **args) -> None:
+        """Attributes known only once the phase ran (a count of what it
+        handled): added to the event and to the annotation."""
+        self.args.update(args)
+        if self._ann is not None:
+            try:
+                self._ann.set_metadata(**args)
+            except Exception:  # noqa: BLE001
+                pass
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
         self._ts = time.time() * 1e6          # trace_event ts is in µs
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = (time.perf_counter() - self._t0) * 1e6
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if not (trace_enabled() or (self._ring and flight.recording())):
+            return False   # the capture was the only sink
         event = {
             "name": self.name,
             "ph": "X",
@@ -295,20 +361,30 @@ class _Span:
             event["args"] = self.args
         if exc_type is not None:
             event.setdefault("args", {})["error"] = exc_type.__name__
-        _emit(event)
+        _emit(event, self._ring)
         return False
 
 
 def span(name: str, /, **args):
     """Context manager tracing one phase: ``with span("prefill",
     bucket=128): ...``. Emits a Chrome complete event to the trace file
-    (RBT_TRACE=1) and/or the flight ring (RBT_FLIGHT, default on);
-    otherwise returns a shared no-op (no allocation beyond the env
-    reads). ``name`` is positional-only so span attributes may freely use
-    "name" as a key (e.g. reconcile spans labeling the object name)."""
+    (RBT_TRACE=1) and/or the flight ring (RBT_FLIGHT, default on), and a
+    TraceAnnotation into a profiler capture in flight; otherwise returns
+    a shared no-op (no allocation beyond the env reads). ``name`` is
+    positional-only so span attributes may freely use "name" as a key
+    (e.g. reconcile spans labeling the object name)."""
     if not record_enabled():
         return _NULL
     return _Span(name, args)
+
+
+def fine(name: str, /, **args):
+    """``span`` for a phase inside an engine tick or a trainer step: a
+    profiler capture and the trace file record it, the flight ring does
+    not. The shared no-op outside both, whether the ring is on or off."""
+    if not fine_enabled():
+        return _NULL
+    return _Span(name, args, ring=False)
 
 
 def complete(name: str, duration_s: float, /, **args) -> None:
@@ -317,8 +393,11 @@ def complete(name: str, duration_s: float, /, **args) -> None:
     request-scoped phases whose start predates the code that knows their
     name — e.g. a request's queue wait, measured by the engine at
     admission time."""
-    if not record_enabled():
-        return
+    if record_enabled():
+        _complete(name, duration_s, args, ring=True)
+
+
+def _complete(name: str, duration_s: float, args: dict, ring: bool) -> None:
     dur = max(float(duration_s), 0.0) * 1e6
     event = {
         "name": name,
@@ -330,7 +409,56 @@ def complete(name: str, duration_s: float, /, **args) -> None:
     }
     if args:
         event["args"] = args
-    _emit(event)
+    _emit(event, ring)
+
+
+class PhaseSeconds:
+    """Seconds of set-up summed by phase (``startup.weights``,
+    ``warmup.trace``): the object the entry points print on their
+    start-up and warm-up lines and serve as ``warmup_census["phases"]``.
+    No capture runs during set-up, so the durations are kept here; each
+    addition is also a ``fine`` event ending now, so that ``RBT_TRACE=1``
+    lays set-up on the run's own timeline."""
+
+    def __init__(self):
+        self._seconds: dict = {}
+
+    def add(self, name: str, seconds: float, /, **args) -> None:
+        self._seconds[name] = self._seconds.get(name, 0.0) + seconds
+        if trace_enabled():
+            _complete(name, seconds, args, ring=False)
+
+    @contextlib.contextmanager
+    def timed(self, name: str, /, **args):
+        """Add the time the body took."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.add(name, time.perf_counter() - t0, **args)
+
+    def snapshot(self) -> dict:
+        return {k: round(v, 3) for k, v in self._seconds.items()}
+
+
+# The process's start-up phases (``startup.*``), filled by the entry point
+# that runs it (serve/api.main, train/trainer.main) and by nothing else.
+STARTUP = PhaseSeconds()
+
+
+def process_age_s() -> Optional[float]:
+    """Seconds since the kernel started this process: what an entry point
+    records as ``startup.imports`` once its imports are done. None where
+    /proc does not say (the phase is then left out)."""
+    try:
+        with open("/proc/self/stat") as f:
+            # Field 22, counted after the parenthesised command name.
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return max(uptime - start_ticks / os.sysconf("SC_CLK_TCK"), 0.0)
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 def make_instant(name: str, /, **args) -> dict:
@@ -354,6 +482,9 @@ def instant(name: str, /, **args) -> None:
     signal caught, profile started."""
     if not record_enabled():
         return
+    ann = _annotation(name, args)
+    if ann is not None:       # a zero-length event on the profiler's clock
+        ann.__exit__(None, None, None)
     _emit(make_instant(name, **args))
 
 

@@ -150,6 +150,7 @@ def _perm_down(tp):
     return [(i, (i - 1) % tp) for i in range(tp)]
 
 
+@jax.named_scope("ring.ag")
 def _ag_ring(x_l, tp, contract, bidirectional):
     """All-gather-matmul inner loop: contract(shard, global_chunk_index)
     accumulates while shards circulate. Returns the summed result."""
@@ -176,6 +177,7 @@ def _ag_ring(x_l, tp, contract, bidirectional):
     return acc
 
 
+@jax.named_scope("ring.rs")
 def _rs_ring(tp, partial_for, bidirectional):
     """Reduce-scatter-matmul inner loop: partial_for(chunk_idx, half)
     computes this device's contribution to output chunk `chunk_idx`
@@ -299,6 +301,7 @@ def _ag_dense(x, w, mesh, tp, compute_dtype, bidirectional):
 
         return _ag_ring(x_l, tp, contract, bidirectional)
 
+    @jax.named_scope("ring.ag_bwd")
     def bwd_local(x_l, w_l, dy_l):
         # dx: transpose of the all-gather-matmul is a matmul-reduce-scatter
         # — partial dy @ w^T chunks ppermute-accumulate toward their
@@ -430,6 +433,7 @@ def _rs_dense(x, w, mesh, tp, compute_dtype, bidirectional):
 
         return _rs_ring(tp, partial_for, bidirectional)
 
+    @jax.named_scope("ring.rs_bwd")
     def bwd_local(x_l, w_l, do_l):
         # Transpose of the matmul-reduce-scatter is an all-gather-matmul:
         # the output-shard cotangents circulate; each arriving chunk both

@@ -476,12 +476,13 @@ def flash_attention(
         return _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg,
                           scale_v, causal, block_q, block_k, block_skip)
 
-    out, lse = _per_shard(
-        fwd, _shard_plan(q, k), ("q", "kv", "kv", "row", "row", "row", "row"),
-        ("q", "lse"))(
-        jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
-        jax.lax.stop_gradient(v), q_positions, kv_positions,
-        q_segment_ids, kv_segment_ids)
+    with jax.named_scope("flash.fwd"):
+        out, lse = _per_shard(
+            fwd, _shard_plan(q, k),
+            ("q", "kv", "kv", "row", "row", "row", "row"), ("q", "lse"))(
+            jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
+            jax.lax.stop_gradient(v), q_positions, kv_positions,
+            q_segment_ids, kv_segment_ids)
     out = checkpoint_name(out, "attn_context")
     lse = checkpoint_name(lse, "attn_lse")
     return _flash_core(
@@ -613,7 +614,7 @@ def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
         return (bi, hi // n_rep, clamp_k(i, j), 0)
 
     # dq: grid inner dim iterates kv blocks
-    dq = pl.pallas_call(
+    dq_kernel = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale_v, causal=causal,
                           use_segments=use_segments, block_q=block_q,
                           block_k=block_k, block_skip=skip),
@@ -635,7 +636,10 @@ def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
                                        grad_dtype or q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
-    )(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qT, kT, vT, doT, lseT, deltaT)
+    )
+    with jax.named_scope("flash.dq"):
+        dq = dq_kernel(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qT, kT, vT,
+                       doT, lseT, deltaT)
 
     # dk/dv: grid inner dim iterates q blocks
     def hq2(bi, hi, j, i):
@@ -650,7 +654,7 @@ def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
     def hk2_write(bi, hi, j, i):
         return (bi, hi, j, 0)
 
-    dk, dv = pl.pallas_call(
+    dkv_kernel = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale_v, causal=causal,
                           use_segments=use_segments, block_q=block_q,
                           block_k=block_k, block_skip=skip),
@@ -682,7 +686,10 @@ def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
-    )(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qT, kT, vT, doT, lseT, deltaT)
+    )
+    with jax.named_scope("flash.dkv"):
+        dk, dv = dkv_kernel(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qT, kT,
+                            vT, doT, lseT, deltaT)
 
     dq = jnp.swapaxes(dq[:, :, :sq], 1, 2)
     # dk/dv come back at full q-head width; fold the n_rep group back onto

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 # max_top_k logits is negligible for real models).
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jax.Array,              # [batch, vocab] float32
     rng: jax.Array,
@@ -124,6 +125,7 @@ def _filtered_draft_stats(logits, draft, rng, temperature, top_k, top_p,
     return p_draft, resid
 
 
+@jax.named_scope("sample")
 def speculative_verify(
     logits: jax.Array,              # [batch, s, vocab] float32
     drafts: jax.Array,              # [batch, s-1] int32 drafted tokens

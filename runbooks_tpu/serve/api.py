@@ -31,7 +31,7 @@ from runbooks_tpu.obs import incident as obs_incident
 # request_scope lives in obs/trace.py (shared with the gateway, which
 # must not import this module's JAX engine stack); re-exported here for
 # back-compat with existing importers.
-from runbooks_tpu.obs.trace import request_scope  # noqa: F401
+from runbooks_tpu.obs.trace import fine, request_scope  # noqa: F401
 from runbooks_tpu.serve.engine import (
     PRIORITY_RANK,
     EngineDraining,
@@ -300,117 +300,25 @@ class EngineWorker:
         return fut
 
     def _run(self) -> None:
+        # Every stretch of this thread lies under one span, so that a
+        # profiler capture can say what the host did in each gap the
+        # device waited: worker.intake | tick (engine.step) |
+        # worker.finish, or worker.idle when there is nothing to run.
         while not self._stop:
             try:
-                with self._lock:
-                    prefix_jobs, self._prefix_jobs = self._prefix_jobs, []
-                    for req, fut in self._pending:
-                        try:
-                            self.engine.submit(req)
-                        except (EngineOverloaded, ValueError) as exc:
-                            # Race between the synchronous admission check
-                            # and this enqueue: reject this request only,
-                            # don't let it reach the crash catch-all.
-                            # ValueError covers validate() flipping
-                            # between the HTTP-thread check and here —
-                            # e.g. an adapter artifact deleted in the gap
-                            # (validation stats the filesystem).
-                            if not fut.done():
-                                fut.set_exception(exc)
-                            continue
-                        self._inflight.append((req, fut))
-                    self._pending.clear()
-                for job_i, (tokens, fut) in enumerate(prefix_jobs):
-                    try:
-                        # Register WITHOUT the inline warmup sweep (each
-                        # shape is an XLA compile; the whole sweep inline
-                        # would freeze every in-flight stream). Shapes
-                        # queue and warm one per loop iteration,
-                        # interleaved with decode steps.
-                        fresh = not self.engine.has_prefix(tokens)
-                        # Paged engines compile nothing at registration
-                        # (prefix_warmup_shapes() is empty: warmup already
-                        # covered every reachable shape) — the stall
-                        # warning would be a false alarm there.
-                        if fresh and self._warn_cold_prefix \
-                                and self.engine.prefix_warmup_shapes(
-                                    len(tokens)):
-                            self._warn_cold_prefix = False
-                            print(
-                                "serve: runtime /v1/prefix registration "
-                                "compiles the prefix-KV builder on the "
-                                "engine worker thread — in-flight decodes "
-                                "stall until it finishes. Start the server "
-                                "with warm_prefix: true (with warmup) to "
-                                "pre-compile it per bucket.", flush=True)
-                        plen = self.engine.register_prefix(tokens,
-                                                           warmup=False)
-                        if plen and fresh:
-                            key = tuple(int(t) for t in tokens[:plen])
-                            self._queue_warm(key, plen)
-                        fut.set_result(plen)
-                    except Exception as exc:  # noqa: BLE001
-                        if not fut.done():
-                            fut.set_exception(exc)
-                        if isinstance(exc, EngineStepFailed):
-                            # The paged register_prefix drives jitted
-                            # steps that donate the cache: a failure
-                            # there poisons the engine like a crash in
-                            # the main step loop would. Fail the jobs
-                            # not yet reached (the crash handler below
-                            # only sees _prefix_jobs still on the
-                            # instance) and route to it for the full
-                            # doom + reset.
-                            for _t, f in prefix_jobs[job_i + 1:]:
-                                if not f.done():
-                                    f.set_exception(exc)
-                            raise
+                with fine("worker.intake"):
+                    self._intake()
                 if not self.engine.has_work():
                     if self._prefix_warm_queue:
                         self._warm_one()
                         continue
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with fine("worker.idle"):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
                     continue
                 self.engine.step()
-                if self._prefix_warm_queue:
-                    self._warm_one()
-                # Under the lock: drain() (HTTP thread) and the crash
-                # handler both read _inflight concurrently, and the
-                # reshuffle below is a read-then-replace, not an atomic
-                # swap (`rbt check` lock-discipline caught this).
-                with self._lock:
-                    done = [(r, f) for r, f in self._inflight
-                            if r.finished]
-                    if done:
-                        self._inflight = [(r, f) for r, f in self._inflight
-                                          if not r.finished]
-                for req, fut in done:
-                    # Adapter requests never seed the shared-prefix
-                    # cache: their slot KV was computed through the
-                    # tenant's LoRA deltas and must not serve base (or
-                    # other-tenant) prompts. (The paged engine's radix
-                    # adoption namespaces by adapter instead.)
-                    if req.auto_prefix and req._slot >= 0 \
-                            and req.adapter is None:
-                        # Multi-turn chat: lift the prompt's KV out of
-                        # the slot before the next admission can
-                        # recycle it (safe here: admissions happen at
-                        # the next step(), and this thread owns the
-                        # engine). Zero forward passes.
-                        try:
-                            plen = self.engine.register_prefix_from_slot(
-                                req._slot, req.prompt_tokens)
-                            if plen:
-                                key = tuple(
-                                    int(t)
-                                    for t in req.prompt_tokens[:plen])
-                                self._queue_warm(key, plen)
-                        except Exception as exc:  # noqa: BLE001
-                            print(f"serve: auto-prefix registration "
-                                  f"failed: {exc!r}", flush=True)
-                    if not fut.done():
-                        fut.set_result(req)
+                with fine("worker.finish") as finish:
+                    finish.set(finished=self._finish())
             except Exception as exc:  # noqa: BLE001 — engine step blew up
                 # Fail every waiting request AND queued prefix job with
                 # the error (hanging futures would wedge HTTP handlers
@@ -455,6 +363,117 @@ class EngineWorker:
                 # Donated buffers (cache) may have been invalidated by the
                 # failed call — full reset reallocates them.
                 self.engine.reset()
+
+    def _intake(self) -> None:
+        """Hand pending requests to the engine's queue and run queued
+        prefix registrations (top of every loop iteration)."""
+        with self._lock:
+            prefix_jobs, self._prefix_jobs = self._prefix_jobs, []
+            for req, fut in self._pending:
+                try:
+                    self.engine.submit(req)
+                except (EngineOverloaded, ValueError) as exc:
+                    # Race between the synchronous admission check
+                    # and this enqueue: reject this request only,
+                    # don't let it reach the crash catch-all.
+                    # ValueError covers validate() flipping
+                    # between the HTTP-thread check and here —
+                    # e.g. an adapter artifact deleted in the gap
+                    # (validation stats the filesystem).
+                    if not fut.done():
+                        fut.set_exception(exc)
+                    continue
+                self._inflight.append((req, fut))
+            self._pending.clear()
+        for job_i, (tokens, fut) in enumerate(prefix_jobs):
+            try:
+                # Register WITHOUT the inline warmup sweep (each
+                # shape is an XLA compile; the whole sweep inline
+                # would freeze every in-flight stream). Shapes
+                # queue and warm one per loop iteration,
+                # interleaved with decode steps.
+                fresh = not self.engine.has_prefix(tokens)
+                # Paged engines compile nothing at registration
+                # (prefix_warmup_shapes() is empty: warmup already
+                # covered every reachable shape) — the stall
+                # warning would be a false alarm there.
+                if fresh and self._warn_cold_prefix \
+                        and self.engine.prefix_warmup_shapes(
+                            len(tokens)):
+                    self._warn_cold_prefix = False
+                    print(
+                        "serve: runtime /v1/prefix registration "
+                        "compiles the prefix-KV builder on the "
+                        "engine worker thread — in-flight decodes "
+                        "stall until it finishes. Start the server "
+                        "with warm_prefix: true (with warmup) to "
+                        "pre-compile it per bucket.", flush=True)
+                plen = self.engine.register_prefix(tokens,
+                                                   warmup=False)
+                if plen and fresh:
+                    key = tuple(int(t) for t in tokens[:plen])
+                    self._queue_warm(key, plen)
+                fut.set_result(plen)
+            except Exception as exc:  # noqa: BLE001
+                if not fut.done():
+                    fut.set_exception(exc)
+                if isinstance(exc, EngineStepFailed):
+                    # The paged register_prefix drives jitted
+                    # steps that donate the cache: a failure
+                    # there poisons the engine like a crash in
+                    # the main step loop would. Fail the jobs
+                    # not yet reached (the crash handler of _run
+                    # only sees _prefix_jobs still on the
+                    # instance) and route to it for the full
+                    # doom + reset.
+                    for _t, f in prefix_jobs[job_i + 1:]:
+                        if not f.done():
+                            f.set_exception(exc)
+                    raise
+
+    def _finish(self) -> int:
+        """After a step: warm one queued prefix shape, resolve the
+        futures of finished requests (lifting a chat turn's prompt KV
+        first). Returns how many finished."""
+        if self._prefix_warm_queue:
+            self._warm_one()
+        # Under the lock: drain() (HTTP thread) and the crash
+        # handler both read _inflight concurrently, and the
+        # reshuffle below is a read-then-replace, not an atomic
+        # swap (`rbt check` lock-discipline caught this).
+        with self._lock:
+            done = [(r, f) for r, f in self._inflight
+                    if r.finished]
+            if done:
+                self._inflight = [(r, f) for r, f in self._inflight
+                                  if not r.finished]
+        for req, fut in done:
+            # Adapter requests never seed the shared-prefix
+            # cache: their slot KV was computed through the
+            # tenant's LoRA deltas and must not serve base (or
+            # other-tenant) prompts. (The paged engine's radix
+            # adoption namespaces by adapter instead.)
+            if req.auto_prefix and req._slot >= 0 \
+                    and req.adapter is None:
+                # Multi-turn chat: lift the prompt's KV out of
+                # the slot before the next admission can
+                # recycle it (safe here: admissions happen at
+                # the next step(), and this thread owns the
+                # engine). Zero forward passes.
+                try:
+                    plen = self.engine.register_prefix_from_slot(
+                        req._slot, req.prompt_tokens)
+                    if plen:
+                        key = tuple(
+                            int(t)
+                            for t in req.prompt_tokens[:plen])
+                        self._queue_warm(key, plen)
+                except Exception as exc:  # noqa: BLE001
+                    print(f"serve: auto-prefix registration "
+                          f"failed: {exc!r}", flush=True)
+            if not fut.done():
+                fut.set_result(req)
+        return len(done)
 
     def _queue_warm(self, key: tuple, plen: int) -> None:
         """Queue only shapes not already executed or in flight: compiles
@@ -910,8 +929,13 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
         """On-demand TPU/XLA profiler capture: POST /debug/profile
         ?seconds=N (or JSON body {"seconds": N}) traces N seconds of live
         traffic into {artifacts}/profiles/<stamp>-serve (XProf/
-        TensorBoard-loadable). One capture at a time -> 409 while busy."""
+        TensorBoard-loadable). One capture at a time -> 409 while busy.
+        The capture holds the device planes and the program's own spans
+        (obs/trace.py); ?python=1 adds the Python tracer's frames, which
+        slow the threads they instrument."""
         from runbooks_tpu.obs import profile as obs_profile
+
+        python_tracer = request.query.get("python", "0") == "1"
 
         seconds = request.query.get("seconds")
         if seconds is None and request.can_read_body:
@@ -934,7 +958,8 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
             # Blocking timed capture off the event loop: SSE streams and
             # new admissions keep flowing while the profiler records them.
             await asyncio.get_running_loop().run_in_executor(
-                None, obs_profile.PROFILER.capture, log_dir, seconds)
+                None, obs_profile.PROFILER.capture, log_dir, seconds,
+                python_tracer)
         except obs_profile.ProfilerBusy as exc:
             return web.json_response(
                 {"error": {"message": str(exc)}}, status=409)
@@ -942,7 +967,8 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
             return web.json_response(
                 {"error": {"message": f"profile capture failed: {exc}"}},
                 status=500)
-        return web.json_response({"path": log_dir, "seconds": seconds})
+        return web.json_response({"path": log_dir, "seconds": seconds,
+                                  "python_tracer": python_tracer})
 
     async def debug_memory(request: web.Request) -> web.Response:
         """GET /debug/memory: per-device allocator stats (HBM in use /
@@ -971,6 +997,7 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
         dispatch-time distribution, and the compile-sentinel state."""
         from runbooks_tpu.obs import device as obs_device
         from runbooks_tpu.obs import metrics as obs_metrics_mod
+        from runbooks_tpu.obs import trace as obs_trace
 
         # None off-TPU: the fields that need a peak are then absent.
         peaks = obs_device.device_peaks()
@@ -1004,9 +1031,18 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
                     cost["achieved_gbps"] = round(
                         cost["hbm_bytes"] / mean_s / 1e9, 3)
         sentinel = obs_device.SENTINEL
+        warmed = worker.engine.warmup_census
         return web.json_response({
             "programs": census,
-            "warmup_census": worker.engine.warmup_census,
+            # Set-up seconds by phase: the entry point's startup.* joined
+            # with the warm-up's own (docs/observability.md).
+            "warmup_census": warmed and {
+                **warmed,
+                "phases": {**obs_trace.STARTUP.snapshot(),
+                           **warmed.get("phases", {})}},
+            # Decode steps per dispatch: what turns a dispatch time into
+            # a step time (also inside warmup_census once warmed).
+            "decode_chunk": worker.engine.decode_chunk,
             # Speculation economics (docs/speculative-decoding.md):
             # accept rate + decode tok/s per accept-rate bucket, so the
             # "is drafting paying on this traffic" question is one GET.
@@ -1341,15 +1377,20 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
                                tp_out) -> web.Response:
         hdr_priority = (http_request.headers.get("X-Priority")
                         if http_request is not None else None)
-        reqs, err = _parse_requests(app_, body,
-                                    default_priority=hdr_priority)
-        if err is not None:
-            return err
-        # Thread the id through admission -> engine slot -> prefill/
-        # decode spans; multi-prompt bodies get per-prompt suffixes so
-        # each choice's spans stay distinguishable.
-        for i, r in enumerate(reqs):
-            r.request_id = rid if len(reqs) == 1 else f"{rid}/{i}"
+        # Handler entry -> hand-over to the engine worker: parse and
+        # tokenize, on the event loop's thread.
+        with fine("api.submit", request_id=rid) as submit_span:
+            reqs, err = _parse_requests(app_, body,
+                                        default_priority=hdr_priority)
+            if err is not None:
+                return err
+            submit_span.set(prompt_tokens=sum(len(r.prompt_tokens)
+                                              for r in reqs))
+            # Thread the id through admission -> engine slot -> prefill/
+            # decode spans; multi-prompt bodies get per-prompt suffixes so
+            # each choice's spans stay distinguishable.
+            for i, r in enumerate(reqs):
+                r.request_id = rid if len(reqs) == 1 else f"{rid}/{i}"
         if auto_prefix_chat and body.get("_chat"):
             # Multi-turn chat: this turn's prompt KV becomes the next
             # turn's prefix (the rendered history strictly extends).
@@ -1554,11 +1595,22 @@ def _param_any(params: dict, *keys: str, default=None):
 
 
 def main() -> int:
+    import jax
+
+    from runbooks_tpu.obs.trace import STARTUP, process_age_s
+
+    # Set-up phases (obs/trace.py PhaseSeconds): interpreter start to
+    # here is this module's imports; the engine's warm-up adds warmup.*.
+    age = process_age_s()
+    if age is not None:
+        STARTUP.add("startup.imports", age)
     params = contract.load_params()
     # Multi-host slices: form the jax.distributed runtime before any JAX use.
     from runbooks_tpu.parallel.distributed import initialize
 
-    initialize()
+    with STARTUP.timed("startup.backend"):
+        initialize()
+        jax.devices()          # the first touch of the backend
     # Persistent compile cache (placed from outside: utils/jax_cache.py): a
     # restarted serve worker skips the prefill/decode bucket recompiles.
     from runbooks_tpu.utils.jax_cache import enable_compilation_cache
@@ -1574,10 +1626,13 @@ def main() -> int:
     mesh_keys = {f.name for f in _dc.fields(MeshConfig)}
     mesh_args = {k[len("mesh_"):]: int(v) for k, v in params.items()
                  if k.startswith("mesh_") and k[len("mesh_"):] in mesh_keys}
-    if mesh_args:
-        mesh = make_mesh(MeshConfig(**mesh_args))
-    cfg, model_params = load_model(params, mesh)
-    tokenizer = load_tokenizer(params.get("tokenizer"))
+    with STARTUP.timed("startup.weights"):
+        if mesh_args:
+            mesh = make_mesh(MeshConfig(**mesh_args))
+        cfg, model_params = load_model(params, mesh)
+        jax.block_until_ready(model_params)   # made AND placed
+    with STARTUP.timed("startup.tokenizer"):
+        tokenizer = load_tokenizer(params.get("tokenizer"))
     # What this server executes on — one line, before warmup compiles, so
     # any log says what it ran on.
     from runbooks_tpu.models.transformer import (
@@ -1592,6 +1647,7 @@ def main() -> int:
         # Prefill's attention as resolved; decode (q_len 1) is always XLA.
         "attention_impl": ("flash" if use_flash_cached_prefill(
             cfg, FLASH_CACHED_PREFILL_MIN_Q) else "xla"),
+        "phases": STARTUP.snapshot(),
     }), flush=True)
 
     num_pages_raw = _param_any(params, "num_pages", "numPages", "numpages")
@@ -1619,6 +1675,7 @@ def main() -> int:
                          camel.lower())
         if raw is not None:
             queue_shares[cls] = float(raw)
+    t_engine = time.perf_counter()
     app = create_server(
         cfg, model_params, tokenizer,
         max_slots=int(params.get("max_slots", 8)),
@@ -1682,6 +1739,11 @@ def main() -> int:
         grammar=(str(grammar_raw) if grammar_raw is not None else "off"),
         grammar_cache_size=(int(grammar_cache_raw)
                             if grammar_cache_raw is not None else None))
+    # Engine construction (KV cache allocation, jit wrappers): what
+    # create_server took outside the warm-up it ran.
+    STARTUP.add("startup.engine", time.perf_counter() - t_engine
+                - (app["worker"].engine.warmup_census or {}).get(
+                    "warmup_seconds", 0.0))
     port = int(params.get("port", contract.SERVE_PORT))
 
     # Graceful drain on SIGTERM (docs/fault-tolerance.md): run_app's

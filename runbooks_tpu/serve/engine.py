@@ -52,7 +52,13 @@ from runbooks_tpu.obs import device as obs_device
 from runbooks_tpu.obs import flight as obs_flight
 from runbooks_tpu.obs import metrics as obs_metrics
 from runbooks_tpu.obs.trace import complete as trace_complete
-from runbooks_tpu.obs.trace import record_enabled, span
+from runbooks_tpu.obs.trace import (
+    PhaseSeconds,
+    fine,
+    fine_enabled,
+    record_enabled,
+    span,
+)
 from runbooks_tpu.ops.sampling import sample, speculative_verify
 from runbooks_tpu.serve.speculative import NgramDraftIndex, legal_draft_prefix
 from runbooks_tpu.utils.hw import backend_tuning
@@ -293,26 +299,27 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         logits, cache1 = forward(cfg, params, tokens,
                                  positions=positions, cache=cache1,
                                  adapters=adapters)
-        if pool.k.dtype == jnp.int8:
-            from runbooks_tpu.ops.quantization import quantize_kv
+        with jax.named_scope("kv_splice"):
+            if pool.k.dtype == jnp.int8:
+                from runbooks_tpu.ops.quantization import quantize_kv
 
-            rows_k, rows_ks = quantize_kv(cache1.k)
-            rows_v, rows_vs = quantize_kv(cache1.v)
-        else:
-            rows_k, rows_v, rows_ks, rows_vs = (cache1.k, cache1.v,
-                                                None, None)
-        new_k, new_v = pool.k, pool.v
-        new_ks, new_vs = pool.k_scale, pool.v_scale
-        for r in range(rows - 1, -1, -1):
-            new_k = jax.lax.dynamic_update_slice_in_dim(
-                new_k, rows_k[:, r:r + 1], slots[r], axis=1)
-            new_v = jax.lax.dynamic_update_slice_in_dim(
-                new_v, rows_v[:, r:r + 1], slots[r], axis=1)
-            if rows_ks is not None:
-                new_ks = jax.lax.dynamic_update_slice_in_dim(
-                    new_ks, rows_ks[:, r:r + 1], slots[r], axis=1)
-                new_vs = jax.lax.dynamic_update_slice_in_dim(
-                    new_vs, rows_vs[:, r:r + 1], slots[r], axis=1)
+                rows_k, rows_ks = quantize_kv(cache1.k)
+                rows_v, rows_vs = quantize_kv(cache1.v)
+            else:
+                rows_k, rows_v, rows_ks, rows_vs = (cache1.k, cache1.v,
+                                                    None, None)
+            new_k, new_v = pool.k, pool.v
+            new_ks, new_vs = pool.k_scale, pool.v_scale
+            for r in range(rows - 1, -1, -1):
+                new_k = jax.lax.dynamic_update_slice_in_dim(
+                    new_k, rows_k[:, r:r + 1], slots[r], axis=1)
+                new_v = jax.lax.dynamic_update_slice_in_dim(
+                    new_v, rows_v[:, r:r + 1], slots[r], axis=1)
+                if rows_ks is not None:
+                    new_ks = jax.lax.dynamic_update_slice_in_dim(
+                        new_ks, rows_ks[:, r:r + 1], slots[r], axis=1)
+                    new_vs = jax.lax.dynamic_update_slice_in_dim(
+                        new_vs, rows_vs[:, r:r + 1], slots[r], axis=1)
         rng, sub = jax.random.split(rng)
         last_logits = jnp.take_along_axis(
             logits, last_pos[:, None, None], axis=1)[:, 0]
@@ -432,6 +439,70 @@ def make_verify_fn(cfg: ModelConfig, draft_tokens: int, pad_slot: int,
         return accept, resid, full, cache, rng
 
     return verify_fn
+
+
+class WarmupRun:
+    """The clocks of one warm-up sweep, shared by the dense and the paged
+    engine: compile counts from the sentinel, the set-up phases
+    (``warmup.trace`` / ``.compile`` / ``.run`` / ``.cost_capture``,
+    obs/trace.py PhaseSeconds) and the roofline cost capture."""
+
+    def __init__(self):
+        self.sentinel = obs_device.SENTINEL
+        self._compiles = self.sentinel.total
+        self._seconds = self.sentinel.compile_seconds
+        self._hits = self.sentinel.cache_hits
+        self._t0 = time.perf_counter()
+        self.phases = PhaseSeconds()
+        # Roofline cost capture re-traces each shape once (no second
+        # backend compile); RBT_DEVICE_OBS=0 skips it when even that
+        # startup cost matters.
+        self._capture_costs = os.environ.get("RBT_DEVICE_OBS", "1") != "0"
+
+    def program(self, name: str, sig: str, fn, *args, **kwargs):
+        """Warm one program shape and return what the call returned. The
+        call's wall time splits into the backend compile (or cache load:
+        the sentinel's compile clock) and the rest, which is tracing and
+        lowering; its execution is not waited for here (``finish``)."""
+        if self._capture_costs:
+            with self.phases.timed("warmup.cost_capture", program=name):
+                cost = obs_device.program_cost("serve", name, sig, fn,
+                                               *args, **kwargs)
+            if cost is None and not obs_device.PROGRAMS.has_cost(
+                    "serve", name, sig):
+                # The backend gives no analysis (a TPU gives none): one
+                # probe, not one re-trace per program. The roofline
+                # fields of /debug/programs are then absent.
+                self._capture_costs = False
+        compiled = self.sentinel.compile_seconds
+        t0 = time.perf_counter()
+        out = fn(*args, **kwargs)
+        wall = time.perf_counter() - t0
+        compiled = self.sentinel.compile_seconds - compiled
+        self.phases.add("warmup.compile", compiled, program=name)
+        self.phases.add("warmup.trace", max(wall - compiled, 0.0),
+                        program=name)
+        return out
+
+    def finish(self, outputs) -> dict:
+        """Wait for the first executions still running (they overlapped
+        the tracing of the programs after them) and return the census
+        fields both engines report."""
+        with self.phases.timed("warmup.run"):
+            # rbt-check: ignore[device-sync] end of the warm-up sweep, before any traffic: readiness waits for the warmed programs' first executions
+            jax.block_until_ready(outputs)
+        return {
+            "compiles": self.sentinel.total - self._compiles,
+            "compile_seconds": round(
+                self.sentinel.compile_seconds - self._seconds, 3),
+            # Compile requests the persistent cache answered: > 0 on a
+            # warm restart (utils/jax_cache.py), 0 on a cold one.
+            "cache_hits": self.sentinel.cache_hits - self._hits,
+            "warmup_seconds": round(time.perf_counter() - self._t0, 3),
+            # Seconds by set-up phase (docs/observability.md); the entry
+            # point's startup.* join them in GET /debug/programs.
+            "phases": self.phases.snapshot(),
+        }
 
 
 class InferenceEngine:
@@ -1042,23 +1113,8 @@ class InferenceEngine:
         if rows is None:
             rows = (1, self.max_slots) if self.max_slots > 1 else (1,)
         n_prefix = n_prefill = 0
-        # Roofline cost capture re-traces each shape once (no second
-        # backend compile); RBT_DEVICE_OBS=0 skips it when even that
-        # startup cost matters.
-        import os as _os
-
-        capture_costs = _os.environ.get("RBT_DEVICE_OBS", "1") != "0"
-
-        def record_cost(name, sig, fn, *args, **kwargs):
-            if capture_costs:
-                obs_device.program_cost("serve", name, sig, fn, *args,
-                                        **kwargs)
-
-        sentinel = obs_device.SENTINEL
-        compiles_before = sentinel.total
-        seconds_before = sentinel.compile_seconds
-        hits_before = sentinel.cache_hits
-        t_warm = time.perf_counter()
+        run = WarmupRun()
+        sentinel = run.sentinel
         row_set = list(dict.fromkeys(min(r, self.max_slots) for r in rows))
         # Warmup compiles are the intended ones — with another component
         # already steady in this process (a trainer sharing it, a second
@@ -1094,10 +1150,8 @@ class InferenceEngine:
                           **self._grammar_warm_kwargs(
                               (r, self.cfg.vocab_size))}
                     with self._mesh_ctx():
-                        record_cost("prefill", f"b{bucket}r{r}",
-                                    self._prefill, self.params,
-                                    self.cache, *args, **kw)
-                        _, self.cache, _ = self._prefill(
+                        _, self.cache, _ = run.program(
+                            "prefill", f"b{bucket}r{r}", self._prefill,
                             self.params, self.cache, *args, **kw)
                     n_prefill += 1
             zeros = np.zeros(self.max_slots, np.int32)
@@ -1116,11 +1170,10 @@ class InferenceEngine:
                         jnp.zeros(self.max_slots, jnp.int32),
                         jnp.zeros(self.max_slots, bool))
                 with self._mesh_ctx():
-                    record_cost(f"decode_v{view}", f"v{view}",
-                                self._decode_for(view), self.params,
-                                self.cache, *args, **akw)
-                    _, _, self.cache, _ = self._decode_for(view)(
-                        self.params, self.cache, *args, **akw)
+                    _, _, self.cache, _ = run.program(
+                        f"decode_v{view}", f"v{view}",
+                        self._decode_for(view), self.params, self.cache,
+                        *args, **akw)
             n_verify = 0
             if self.speculative != "off":
                 vtok = np.zeros((self.max_slots, self.draft_tokens + 1),
@@ -1138,11 +1191,10 @@ class InferenceEngine:
                             jnp.ones(self.max_slots, jnp.float32),
                             jnp.zeros(self.max_slots, bool))
                     with self._mesh_ctx():
-                        record_cost(f"verify_v{view}", f"v{view}",
-                                    self._verify_for(view), self.params,
-                                    self.cache, *args, **akw)
-                        _, _, _, self.cache, _ = self._verify_for(view)(
-                            self.params, self.cache, *args, **akw)
+                        _, _, _, self.cache, _ = run.program(
+                            f"verify_v{view}", f"v{view}",
+                            self._verify_for(view), self.params,
+                            self.cache, *args, **akw)
                     n_verify += 1
         # Compiled-program census from the tracker (count + names +
         # compile seconds): model-config variants (collective_matmul,
@@ -1168,13 +1220,10 @@ class InferenceEngine:
             "grammar_cache_size": (self._grammar_cache.capacity
                                    if self._grammar_cache is not None
                                    else None),
-            "compiles": sentinel.total - compiles_before,
-            "compile_seconds": round(
-                sentinel.compile_seconds - seconds_before, 3),
-            # Compile requests the persistent cache answered: > 0 on a
-            # warm restart (utils/jax_cache.py), 0 on a cold one.
-            "cache_hits": sentinel.cache_hits - hits_before,
-            "warmup_seconds": round(time.perf_counter() - t_warm, 3),
+            # Decode steps per dispatch: turns serve_decode_dispatch_
+            # seconds into a step time.
+            "decode_chunk": self.decode_chunk,
+            **run.finish(self.cache),
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1187,7 +1236,8 @@ class InferenceEngine:
             f"{self.warmup_census['compiles']} compiles in "
             f"{self.warmup_census['compile_seconds']}s, "
             f"{self.warmup_census['cache_hits']} from the persistent "
-            f"cache ({[(c['name'], c['programs']) for c in census]})",
+            f"cache ({[(c['name'], c['programs']) for c in census]}); "
+            f"phases {self.warmup_census['phases']}",
             flush=True)
         # From here on, a compile is a serve-time stall: the sentinel
         # flags it loudly (xla_unexpected_compiles_total). One refcounted
@@ -1671,49 +1721,40 @@ class InferenceEngine:
         if pkey:
             self.prefix_hits += n
         rows = 1 if n == 1 else self.max_slots
-        tokens = np.zeros((rows, bucket), np.int32)
-        # Real tokens at positions plen..len-1; padding scatters to the
-        # trash slot of each row's scratch cache.
-        positions = np.full((rows, bucket), self._pad_slot, np.int32)
-        slots = np.full(rows, group[0][0], np.int32)
-        for i, (slot, req) in enumerate(group):
-            m = len(req.prompt_tokens) - plen
-            tokens[i, :m] = req.prompt_tokens[plen:]
-            positions[i, :m] = np.arange(plen, plen + m)
-            slots[i] = slot
 
-        # First generated token of each row comes from its last *real*
-        # prompt position (index into the suffix row); sampling happens
-        # inside the jitted prefill (one dispatch, no eager sampling
-        # chain — see prefill_fn).
-        last_pos = np.zeros(rows, np.int32)
-        temps = np.zeros(rows, np.float32)
-        top_ks = np.zeros(rows, np.int32)
-        top_ps = np.ones(rows, np.float32)
-        aslots = np.full(rows, -1, np.int32)
-        for i, (_, req) in enumerate(group):
-            last_pos[i] = len(req.prompt_tokens) - plen - 1
-            temps[i] = req.temperature
-            top_ks[i] = req.top_k
-            top_ps[i] = req.top_p
-            aslots[i] = req._adapter_lane
-        args = (jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(slots), jnp.asarray(last_pos), self.rng,
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps))
-        akw = {**self._adapter_kwargs(aslots),
-               **self._grammar_prefill_kwargs(group, rows)}
-        # Dispatch timing is host-side, outside jit (the np.asarray pull
-        # below is the device sync) — zero effect on compiled programs.
-        t_dispatch = time.perf_counter()
-        # Request ids only materialize when tracing is on (same rule as
-        # the decode span's active count: no per-dispatch list builds on
-        # the hot path for a disabled tracer).
-        attrs = ({"request_ids": [r.request_id for _, r in group]}
-                 if record_enabled() else {})
-        with span("prefill", bucket=bucket, rows=rows, prefix=plen,
-                  **attrs), \
-                self._mesh_ctx():
+        def operands():
+            tokens = np.zeros((rows, bucket), np.int32)
+            # Real tokens at positions plen..len-1; padding scatters to
+            # the trash slot of each row's scratch cache.
+            positions = np.full((rows, bucket), self._pad_slot, np.int32)
+            slots = np.full(rows, group[0][0], np.int32)
+            # First generated token of each row comes from its last *real*
+            # prompt position (index into the suffix row); sampling happens
+            # inside the jitted prefill (one dispatch, no eager sampling
+            # chain — see prefill_fn).
+            last_pos = np.zeros(rows, np.int32)
+            temps = np.zeros(rows, np.float32)
+            top_ks = np.zeros(rows, np.int32)
+            top_ps = np.ones(rows, np.float32)
+            aslots = np.full(rows, -1, np.int32)
+            for i, (slot, req) in enumerate(group):
+                m = len(req.prompt_tokens) - plen
+                tokens[i, :m] = req.prompt_tokens[plen:]
+                positions[i, :m] = np.arange(plen, plen + m)
+                slots[i] = slot
+                last_pos[i] = m - 1
+                temps[i] = req.temperature
+                top_ks[i] = req.top_k
+                top_ps[i] = req.top_p
+                aslots[i] = req._adapter_lane
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(slots), jnp.asarray(last_pos), self.rng,
+                    jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(top_ps))
+            return args, {**self._adapter_kwargs(aslots),
+                          **self._grammar_prefill_kwargs(group, rows)}
+
+        def program(args, akw):
             if pkey:
                 # Admission hit refreshes the LRU position: the prefix
                 # serving live traffic must not be the one evicted.
@@ -1725,21 +1766,49 @@ class InferenceEngine:
             else:
                 first, self.cache, self.rng = self._prefill(
                     self.params, self.cache, *args, **akw)
-            # rbt-check: ignore[device-sync] prefill dispatch boundary — the first token must reach the host to stream
-            first = np.asarray(first)
-        # Labeled by (bucket, rows): the two row shapes are different
-        # compiled programs with ~rows-proportional FLOPs, and the
-        # roofline join (/debug/programs) divides per-program FLOPs by
-        # this distribution's mean — blending row shapes would inflate
-        # the burst program's analytic MFU by ~max_slots.
-        obs_metrics.REGISTRY.observe(
-            "serve_prefill_dispatch_seconds",
-            time.perf_counter() - t_dispatch, bucket=str(bucket),
-            rows=str(rows),
-            help_text="Prefill dispatch+sync wall time per admission "
-                      "group, labeled by prompt bucket and row count.")
-        for i, (slot, req) in enumerate(group):
-            self._activate_slot(slot, req, int(first[i]))
+            return first
+
+        self._prefill_dispatch(bucket, rows, plen, group, operands, program)
+
+    def _prefill_dispatch(self, bucket: int, rows: int, plen: int,
+                          group: List[tuple], operands, program) -> None:
+        """One batched prefill under its spans, shared with the paged
+        engine: `operands()` builds the host arrays and places them
+        (-> args, kwargs), `program(args, kwargs)` makes the jitted call
+        and returns the first tokens still on the device."""
+        # Request ids only materialize when tracing is on (same rule as
+        # the decode span's active count: no per-dispatch list builds on
+        # the hot path for a disabled tracer).
+        attrs = ({"request_ids": [r.request_id for _, r in group]}
+                 if record_enabled() else {})
+        with span("prefill", bucket=bucket, rows=rows, prefix=plen,
+                  **attrs):
+            with fine("prefill.operands"):
+                args, kwargs = operands()
+            # Dispatch timing is host-side, outside jit (the np.asarray
+            # pull below is the device sync) — zero effect on compiled
+            # programs.
+            t_dispatch = time.perf_counter()
+            with self._mesh_ctx():
+                with fine("prefill.dispatch"):
+                    first = program(args, kwargs)
+                with fine("prefill.sync"):
+                    # rbt-check: ignore[device-sync] prefill dispatch boundary — the first token must reach the host to stream
+                    first = np.asarray(first)
+            # Labeled by (bucket, rows): the two row shapes are different
+            # compiled programs with ~rows-proportional FLOPs, and the
+            # roofline join (/debug/programs) divides per-program FLOPs by
+            # this distribution's mean — blending row shapes would inflate
+            # the burst program's analytic MFU by ~max_slots.
+            obs_metrics.REGISTRY.observe(
+                "serve_prefill_dispatch_seconds",
+                time.perf_counter() - t_dispatch, bucket=str(bucket),
+                rows=str(rows),
+                help_text="Prefill dispatch+sync wall time per admission "
+                          "group, labeled by prompt bucket and row count.")
+            with fine("prefill.activate"):
+                for i, (slot, req) in enumerate(group):
+                    self._activate_slot(slot, req, int(first[i]))
 
     def _activate_slot(self, slot: int, req: Request,
                        first_tok: int) -> None:
@@ -2007,19 +2076,25 @@ class InferenceEngine:
         token), otherwise one decode chunk (`decode_chunk` forward steps
         in a single jit call). Returns the number of tokens generated
         across slots."""
-        self._maybe_inject_fault()
-        self._admit(exclude_slots=self._expire_deadlines())
-        if not self.active.any():
-            return 0
-        generated: Optional[int] = None
-        if self._spec_index is not None:
-            drafts = self._collect_drafts()
-            if drafts is not None:
-                generated = self._verify_step(drafts)
-        if generated is None:
-            generated = self._decode_chunk_step()
-        self.steps += 1
-        return generated
+        attrs = ({"active": int(self.active.sum()),
+                  "queued": len(self.queue)} if fine_enabled() else {})
+        with fine("tick", **attrs):
+            self._maybe_inject_fault()
+            with fine("tick.admit") as admit:
+                before = self.prefix_lookups   # one per admitted request
+                self._admit(exclude_slots=self._expire_deadlines())
+                admit.set(admitted=self.prefix_lookups - before)
+            if not self.active.any():
+                return 0
+            generated: Optional[int] = None
+            if self._spec_index is not None:
+                drafts = self._collect_drafts()
+                if drafts is not None:
+                    generated = self._verify_step(drafts)
+            if generated is None:
+                generated = self._decode_chunk_step()
+            self.steps += 1
+            return generated
 
     # -- speculative decoding (docs/speculative-decoding.md) -----------
 
@@ -2135,20 +2210,40 @@ class InferenceEngine:
             bucket[1] += wall
         return generated
 
+    # -- dispatch seams the paged engine overrides (serve/paging.py) ---
+
+    def _view_key(self, max_pos: int) -> tuple:
+        """(key of the decode/verify program, view label in tokens) of
+        the smallest view covering every position a dispatch can reach."""
+        view = self._view_for(max_pos)
+        return view, view
+
+    def _table_operands(self) -> tuple:
+        """Host operands a decode/verify program takes before the token
+        operands: none here, the page table in the paged engine."""
+        return ()
+
+    def _park_position(self) -> int:
+        """Where inactive rows decode: the trash slot (the paged engine's
+        free page-table rows point at the trash page, so 0 there)."""
+        return self._pad_slot
+
     def _verify_dispatch(self, tokens, positions, draft_len, temps,
                          top_ks, top_ps, gkw=None):
-        """Run the dense verify program at the smallest view bucket
-        covering every position this step can write (L + K), returning
-        host verdict arrays. ``gkw`` is the grammar mask kwargs built by
-        the caller against this step's drafts ({} when grammar is off)."""
-        view = self._view_for(int(self.lengths[self.active].max())
-                              + self.draft_tokens + 1)
+        """Run the verify program at the smallest view covering every
+        position this step can write (L + K), returning host verdict
+        arrays. ``gkw`` is the grammar mask kwargs built by the caller
+        against this step's drafts ({} when grammar is off)."""
+        key, label = self._view_key(int(self.lengths[self.active].max())
+                                    + self.draft_tokens + 1)
         t_dispatch = time.perf_counter()
-        with span("verify", view=view, drafted=int(draft_len.sum()),
+        with span("verify", view=label, drafted=int(draft_len.sum()),
                   **self._decode_span_attrs()), self._mesh_ctx():
             accept, resid, full, self.cache, self.rng = \
-                self._verify_for(view)(
-                    self.params, self.cache, jnp.asarray(tokens),
+                self._verify_for(key)(
+                    self.params, self.cache,
+                    *map(jnp.asarray, self._table_operands()),
+                    jnp.asarray(tokens),
                     jnp.asarray(positions), jnp.asarray(draft_len),
                     self.rng, jnp.asarray(temps), jnp.asarray(top_ks),
                     jnp.asarray(top_ps), jnp.asarray(self.active),
@@ -2161,7 +2256,7 @@ class InferenceEngine:
             full = np.asarray(full)
         obs_metrics.REGISTRY.observe(
             "serve_verify_dispatch_seconds",
-            time.perf_counter() - t_dispatch, view=str(view),
+            time.perf_counter() - t_dispatch, view=str(label),
             help_text="Speculative verify dispatch+sync wall time, "
                       "labeled by cache view bucket.")
         return accept, resid, full
@@ -2192,35 +2287,45 @@ class InferenceEngine:
         return out
 
     def _decode_chunk_step(self) -> int:
-        """One plain decode chunk over every active slot (the
-        pre-speculation hot path, unchanged)."""
-        # Inactive rows decode into the trash slot at a harmless position;
-        # mid-chunk, rows that finish are parked there by the device mask.
-        positions = np.where(self.active, self.lengths,
-                             self._pad_slot).astype(np.int32)
-        temps, top_ks, top_ps, eos_ids, remaining = self._sampling_operands()
-        view = self._view_for(int(self.lengths[self.active].max())
-                              + self.decode_chunk)
-        t_dispatch = time.perf_counter()
-        with span("decode", view=view, **self._decode_span_attrs()), \
+        """One plain decode chunk over every active slot, dense or paged
+        (the seams above are all that differs)."""
+        key, label = self._view_key(int(self.lengths[self.active].max())
+                                    + self.decode_chunk)
+        with span("decode", view=label, **self._decode_span_attrs()), \
                 self._mesh_ctx():
-            toks, valid, self.cache, self.rng = self._decode_for(view)(
-                self.params, self.cache, jnp.asarray(self.last_token),
-                jnp.asarray(positions), self.rng,
-                jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-                jnp.asarray(eos_ids), jnp.asarray(remaining),
-                jnp.asarray(self.active), **self._adapter_kwargs(),
-                **self._grammar_decode_kwargs())
-            # rbt-check: ignore[device-sync] decode-chunk dispatch boundary: one sync per chunk, not per token
-            toks = np.asarray(toks)          # [chunk, slots]
-            # rbt-check: ignore[device-sync] same boundary — valid rides the same chunk sync
-            valid = np.asarray(valid)        # [chunk, slots] bool
-        obs_metrics.REGISTRY.observe(
-            "serve_decode_dispatch_seconds",
-            time.perf_counter() - t_dispatch, view=str(view),
-            help_text="Decode-chunk dispatch+sync wall time, labeled by "
-                      "cache view bucket.")
-        return self._replay_chunk(toks, valid)
+            with fine("decode.operands"):
+                # Inactive rows decode at a harmless position; mid-chunk,
+                # rows that finish are parked there by the device mask.
+                positions = np.where(self.active, self.lengths,
+                                     self._park_position()).astype(np.int32)
+                temps, top_ks, top_ps, eos_ids, remaining = \
+                    self._sampling_operands()
+                t_dispatch = time.perf_counter()
+                operands = (
+                    *map(jnp.asarray, self._table_operands()),
+                    jnp.asarray(self.last_token), jnp.asarray(positions),
+                    self.rng, jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(top_ps), jnp.asarray(eos_ids),
+                    jnp.asarray(remaining), jnp.asarray(self.active))
+                kwargs = {**self._adapter_kwargs(),
+                          **self._grammar_decode_kwargs()}
+            with fine("decode.dispatch"):
+                toks, valid, self.cache, self.rng = self._decode_for(key)(
+                    self.params, self.cache, *operands, **kwargs)
+            with fine("decode.sync"):
+                # rbt-check: ignore[device-sync] decode-chunk dispatch boundary: one sync per chunk, not per token
+                toks = np.asarray(toks)          # [chunk, slots]
+                # rbt-check: ignore[device-sync] same boundary — valid rides the same chunk sync
+                valid = np.asarray(valid)        # [chunk, slots] bool
+            obs_metrics.REGISTRY.observe(
+                "serve_decode_dispatch_seconds",
+                time.perf_counter() - t_dispatch, view=str(label),
+                help_text="Decode-chunk dispatch+sync wall time, labeled "
+                          "by cache view bucket.")
+            with fine("decode.replay") as replay:
+                generated = self._replay_chunk(toks, valid)
+                replay.set(tokens=generated)
+        return generated
 
     # ------------------------------------------------------------------
     # Convenience synchronous generation

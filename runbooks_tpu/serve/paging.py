@@ -64,13 +64,14 @@ from runbooks_tpu.models.transformer import KVCache, forward
 from runbooks_tpu.obs import device as obs_device
 from runbooks_tpu.obs import metrics as obs_metrics
 from runbooks_tpu.obs.trace import complete as trace_complete
-from runbooks_tpu.obs.trace import record_enabled, span
+from runbooks_tpu.obs.trace import record_enabled
 from runbooks_tpu.ops.sampling import sample, speculative_verify
 from runbooks_tpu.serve.engine import (
     PRIORITY_RANK,
     EngineStepFailed,
     InferenceEngine,
     Request,
+    WarmupRun,
     view_buckets_for,
 )
 
@@ -1397,20 +1398,8 @@ class PagedInferenceEngine(InferenceEngine):
             rows = (1, self.max_slots) if self.max_slots > 1 else (1,)
         row_set = list(dict.fromkeys(min(r, self.max_slots)
                                      for r in rows))
-        import os as _os
-
-        capture_costs = _os.environ.get("RBT_DEVICE_OBS", "1") != "0"
-
-        def record_cost(name, sig, fn, *args, **kwargs):
-            if capture_costs:
-                obs_device.program_cost("serve", name, sig, fn, *args,
-                                        **kwargs)
-
-        sentinel = obs_device.SENTINEL
-        compiles_before = sentinel.total
-        seconds_before = sentinel.compile_seconds
-        hits_before = sentinel.cache_hits
-        t_warm = time.perf_counter()
+        run = WarmupRun()
+        sentinel = run.sentinel
         shapes = paged_prefill_shapes(self.prefill_buckets,
                                       self.pages_per_slot, self.page_size,
                                       self.max_seq_len)
@@ -1443,12 +1432,10 @@ class PagedInferenceEngine(InferenceEngine):
                            **self._grammar_warm_kwargs(
                                (r, self.cfg.vocab_size))}
                     with self._mesh_ctx():
-                        record_cost("paged_prefill",
-                                    f"b{bucket}r{r}p{ppb}",
-                                    self._paged_prefill, self.params,
-                                    self.cache, *args, **akw)
-                        _, self.cache, _ = self._paged_prefill(
-                            self.params, self.cache, *args, **akw)
+                        _, self.cache, _ = run.program(
+                            "paged_prefill", f"b{bucket}r{r}p{ppb}",
+                            self._paged_prefill, self.params, self.cache,
+                            *args, **akw)
                     n_prefill += 1
             zeros = np.zeros(self.max_slots, np.int32)
             tables = np.full((self.max_slots, self.pages_per_slot), trash,
@@ -1467,10 +1454,8 @@ class PagedInferenceEngine(InferenceEngine):
                         jnp.zeros(self.max_slots, jnp.int32),
                         jnp.zeros(self.max_slots, bool))
                 with self._mesh_ctx():
-                    record_cost(f"decode_p{vp}", f"p{vp}",
-                                self._decode_for(vp), self.params,
-                                self.cache, *args, **akw)
-                    _, _, self.cache, _ = self._decode_for(vp)(
+                    _, _, self.cache, _ = run.program(
+                        f"decode_p{vp}", f"p{vp}", self._decode_for(vp),
                         self.params, self.cache, *args, **akw)
             n_verify = 0
             if self.speculative != "off":
@@ -1489,11 +1474,10 @@ class PagedInferenceEngine(InferenceEngine):
                             jnp.ones(self.max_slots, jnp.float32),
                             jnp.zeros(self.max_slots, bool))
                     with self._mesh_ctx():
-                        record_cost(f"verify_p{vp}", f"p{vp}",
-                                    self._verify_for(vp), self.params,
-                                    self.cache, *args, **akw)
-                        _, _, _, self.cache, _ = self._verify_for(vp)(
-                            self.params, self.cache, *args, **akw)
+                        _, _, _, self.cache, _ = run.program(
+                            f"verify_p{vp}", f"p{vp}",
+                            self._verify_for(vp), self.params, self.cache,
+                            *args, **akw)
                     n_verify += 1
             n_swap = 0
             if self._kv_host_pages_arg > 0:
@@ -1505,16 +1489,15 @@ class PagedInferenceEngine(InferenceEngine):
                 # different jit entry — the lora_pool lesson).
                 pg = np.int32(self.pager.trash_page)
                 with self._mesh_ctx():
-                    record_cost("kv_swap_out", "page",
-                                self._swap_out_prog, self.cache, pg)
-                    out, self.cache = self._swap_out_prog(self.cache, pg)
+                    out, self.cache = run.program(
+                        "kv_swap_out", "page", self._swap_out_prog,
+                        self.cache, pg)
                     payload = tuple(np.asarray(x) for x in out
                                     if x is not None)
                 with self._mesh_ctx():
-                    record_cost("kv_swap_in", "page", self._swap_in_prog,
-                                self.cache, pg, *payload)
-                    self.cache = self._swap_in_prog(self.cache, pg,
-                                                    *payload)
+                    self.cache = run.program(
+                        "kv_swap_in", "page", self._swap_in_prog,
+                        self.cache, pg, *payload)
                 n_swap = 2
         census = obs_device.PROGRAMS.census("serve")
         self.warmup_census = {
@@ -1539,13 +1522,8 @@ class PagedInferenceEngine(InferenceEngine):
             "grammar_cache_size": (self._grammar_cache.capacity
                                    if self._grammar_cache is not None
                                    else None),
-            "compiles": sentinel.total - compiles_before,
-            "compile_seconds": round(
-                sentinel.compile_seconds - seconds_before, 3),
-            # Compile requests the persistent cache answered: > 0 on a
-            # warm restart (utils/jax_cache.py), 0 on a cold one.
-            "cache_hits": sentinel.cache_hits - hits_before,
-            "warmup_seconds": round(time.perf_counter() - t_warm, 3),
+            "decode_chunk": self.decode_chunk,
+            **run.finish(self.cache),
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1559,7 +1537,7 @@ class PagedInferenceEngine(InferenceEngine):
             f"{self.warmup_census['compiles']} compiles in "
             f"{self.warmup_census['compile_seconds']}s, "
             f"{self.warmup_census['cache_hits']} from the persistent "
-            "cache", flush=True)
+            f"cache; phases {self.warmup_census['phases']}", flush=True)
         if not self._marked_steady:
             self._marked_steady = True
             sentinel.mark_steady("serve")
@@ -1788,68 +1766,62 @@ class PagedInferenceEngine(InferenceEngine):
         ps = self.page_size
         self.prefix_lookups += n
         rows = 1 if n == 1 else self.max_slots
-        tokens = np.zeros((rows, bucket), np.int32)
-        positions = np.full((rows, bucket), self._pad_slot, np.int32)
-        trash = self.pager.trash_page
-        dest_pages = np.full((rows, self.pages_per_slot), trash, np.int32)
-        prefix_pages = (np.full((rows, ppb), trash, np.int32)
-                        if ppb else None)
-        prefix_len = np.zeros(rows, np.int32) if ppb else None
-        last_pos = np.zeros(rows, np.int32)
-        temps = np.zeros(rows, np.float32)
-        top_ks = np.zeros(rows, np.int32)
-        top_ps = np.ones(rows, np.float32)
-        aslots = np.full(rows, -1, np.int32)
-        for i, (slot, req) in enumerate(group):
-            aslots[i] = req._adapter_lane
-            nshared = int(self.pager.slot_shared[slot])
-            plen = nshared * ps
-            # Preemption-resume rows prefill the request's own written
-            # history past its adopted pages (engine.py _admit_tokens);
-            # fresh rows see eff == prompt_tokens unchanged.
-            eff = self._admit_tokens(req)
-            m = len(eff) - plen
-            tokens[i, :m] = eff[plen:]
-            positions[i, :m] = np.arange(plen, plen + m)
-            dest_pages[i] = self.pager.page_table[slot]
+
+        def operands():
+            tokens = np.zeros((rows, bucket), np.int32)
+            positions = np.full((rows, bucket), self._pad_slot, np.int32)
+            trash = self.pager.trash_page
+            dest_pages = np.full((rows, self.pages_per_slot), trash,
+                                 np.int32)
+            prefix_pages = (np.full((rows, ppb), trash, np.int32)
+                            if ppb else None)
+            prefix_len = np.zeros(rows, np.int32) if ppb else None
+            last_pos = np.zeros(rows, np.int32)
+            temps = np.zeros(rows, np.float32)
+            top_ks = np.zeros(rows, np.int32)
+            top_ps = np.ones(rows, np.float32)
+            aslots = np.full(rows, -1, np.int32)
+            for i, (slot, req) in enumerate(group):
+                aslots[i] = req._adapter_lane
+                nshared = int(self.pager.slot_shared[slot])
+                plen = nshared * ps
+                # Preemption-resume rows prefill the request's own written
+                # history past its adopted pages (engine.py
+                # _admit_tokens); fresh rows see eff == prompt_tokens
+                # unchanged.
+                eff = self._admit_tokens(req)
+                m = len(eff) - plen
+                tokens[i, :m] = eff[plen:]
+                positions[i, :m] = np.arange(plen, plen + m)
+                dest_pages[i] = self.pager.page_table[slot]
+                if ppb:
+                    prefix_pages[i, :nshared] = \
+                        self.pager.slot_pages[slot][:nshared]
+                    prefix_len[i] = plen
+                last_pos[i] = m - 1
+                temps[i] = req.temperature
+                top_ks[i] = req.top_k
+                top_ps[i] = req.top_p
+                if nshared:
+                    self.prefix_hits += 1
+                    self.prefix_tokens_reused += plen
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(dest_pages), jnp.asarray(last_pos),
+                    self.rng, jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(top_ps))
             if ppb:
-                prefix_pages[i, :nshared] = \
-                    self.pager.slot_pages[slot][:nshared]
-                prefix_len[i] = plen
-            last_pos[i] = m - 1
-            temps[i] = req.temperature
-            top_ks[i] = req.top_k
-            top_ps[i] = req.top_p
-            if nshared:
-                self.prefix_hits += 1
-                self.prefix_tokens_reused += plen
-        args = (jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(dest_pages), jnp.asarray(last_pos), self.rng,
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps))
-        if ppb:
-            args = args + (jnp.asarray(prefix_pages),
-                           jnp.asarray(prefix_len))
-        t_dispatch = time.perf_counter()
-        attrs = ({"request_ids": [r.request_id for _, r in group]}
-                 if record_enabled() else {})
-        with span("prefill", bucket=bucket, rows=rows,
-                  prefix=ppb * ps, **attrs), \
-                self._mesh_ctx():
+                args = args + (jnp.asarray(prefix_pages),
+                               jnp.asarray(prefix_len))
+            return args, {**self._adapter_kwargs(aslots),
+                          **self._grammar_prefill_kwargs(group, rows)}
+
+        def program(args, kwargs):
             first, self.cache, self.rng = self._paged_prefill(
-                self.params, self.cache, *args,
-                **self._adapter_kwargs(aslots),
-                **self._grammar_prefill_kwargs(group, rows))
-            # rbt-check: ignore[device-sync] prefill dispatch boundary — the first token must reach the host to stream
-            first = np.asarray(first)
-        obs_metrics.REGISTRY.observe(
-            "serve_prefill_dispatch_seconds",
-            time.perf_counter() - t_dispatch, bucket=str(bucket),
-            rows=str(rows),
-            help_text="Prefill dispatch+sync wall time per admission "
-                      "group, labeled by prompt bucket and row count.")
-        for i, (slot, req) in enumerate(group):
-            self._activate_slot(slot, req, int(first[i]))
+                self.params, self.cache, *args, **kwargs)
+            return first
+
+        self._prefill_dispatch(bucket, rows, ppb * ps, group, operands,
+                               program)
 
     # -- lifecycle hooks ----------------------------------------------
 
@@ -1868,75 +1840,22 @@ class PagedInferenceEngine(InferenceEngine):
 
     # -- decode --------------------------------------------------------
 
-    def _verify_dispatch(self, tokens, positions, draft_len, temps,
-                         top_ks, top_ps, gkw=None):
-        """Paged speculative verify: same verdict contract as the dense
-        dispatch, against the gathered page view (page-table operand,
-        page-bucketed view sized to cover L + K writes). ``gkw`` is the
-        caller-built grammar mask kwargs ({} when grammar is off)."""
-        vp = self._view_pages_for(int(self.lengths[self.active].max())
-                                  + self.draft_tokens + 1)
-        t_dispatch = time.perf_counter()
-        with span("verify", view=vp * self.page_size,
-                  drafted=int(draft_len.sum()),
-                  **self._decode_span_attrs()), self._mesh_ctx():
-            accept, resid, full, self.cache, self.rng = \
-                self._verify_for(vp)(
-                    self.params, self.cache,
-                    jnp.asarray(self.pager.page_table),
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(draft_len), self.rng,
-                    jnp.asarray(temps), jnp.asarray(top_ks),
-                    jnp.asarray(top_ps), jnp.asarray(self.active),
-                    **self._adapter_kwargs(), **(gkw or {}))
-            # rbt-check: ignore[device-sync] verify dispatch boundary: one sync per verify step, not per token
-            accept = np.asarray(accept)
-            # rbt-check: ignore[device-sync] same boundary — resid rides the same verify sync
-            resid = np.asarray(resid)
-            # rbt-check: ignore[device-sync] same boundary — full rides the same verify sync
-            full = np.asarray(full)
-        obs_metrics.REGISTRY.observe(
-            "serve_verify_dispatch_seconds",
-            time.perf_counter() - t_dispatch,
-            view=str(vp * self.page_size),
-            help_text="Speculative verify dispatch+sync wall time, "
-                      "labeled by cache view bucket.")
-        return accept, resid, full
+    # The decode chunk and the verify step are the dense engine's
+    # (engine.py _decode_chunk_step, _verify_dispatch); only the program
+    # key (a page count), the page-table operand and the parking position
+    # of inactive rows differ.
 
-    def _decode_chunk_step(self) -> int:
-        """One paged decode chunk (page-gated admission already ran in
-        the shared step()). Operand assembly and the chunk replay are
-        the dense engine's shared helpers; only the dispatch differs
-        (page-table operand, page-bucketed view)."""
+    def _view_key(self, max_pos: int) -> tuple:
+        vp = self._view_pages_for(max_pos)
+        return vp, vp * self.page_size
+
+    def _table_operands(self) -> tuple:
+        return (self.pager.page_table,)
+
+    def _park_position(self) -> int:
         # Inactive rows decode at position 0; their writes land in the
         # trash page (free slots' page-table rows all point there).
-        positions = np.where(self.active, self.lengths, 0).astype(np.int32)
-        temps, top_ks, top_ps, eos_ids, remaining = \
-            self._sampling_operands()
-        vp = self._view_pages_for(int(self.lengths[self.active].max())
-                                  + self.decode_chunk)
-        t_dispatch = time.perf_counter()
-        with span("decode", view=vp * self.page_size,
-                  **self._decode_span_attrs()), self._mesh_ctx():
-            toks, valid, self.cache, self.rng = self._decode_for(vp)(
-                self.params, self.cache,
-                jnp.asarray(self.pager.page_table),
-                jnp.asarray(self.last_token), jnp.asarray(positions),
-                self.rng, jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), jnp.asarray(eos_ids),
-                jnp.asarray(remaining), jnp.asarray(self.active),
-                **self._adapter_kwargs(), **self._grammar_decode_kwargs())
-            # rbt-check: ignore[device-sync] decode-chunk dispatch boundary: one sync per chunk, not per token
-            toks = np.asarray(toks)
-            # rbt-check: ignore[device-sync] same boundary — valid rides the same chunk sync
-            valid = np.asarray(valid)
-        obs_metrics.REGISTRY.observe(
-            "serve_decode_dispatch_seconds",
-            time.perf_counter() - t_dispatch,
-            view=str(vp * self.page_size),
-            help_text="Decode-chunk dispatch+sync wall time, labeled by "
-                      "cache view bucket.")
-        return self._replay_chunk(toks, valid)
+        return 0
 
     # -- observability -------------------------------------------------
 

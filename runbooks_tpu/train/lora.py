@@ -173,7 +173,8 @@ def make_lora_train_step(model_cfg, lora_cfg: LoraConfig, optimizer, mesh,
         # Closures capture base_params per trace (construction is free at
         # trace time — no mutable state shared across traces).
         def lora_ce_terms(lora, mb):
-            merged = apply_lora(base_params, lora, lora_cfg)
+            with jax.named_scope("lora"):
+                merged = apply_lora(base_params, lora, lora_cfg)
             loss, total, aux = ce_terms(merged, mb)
             if model_cfg.moe_num_experts and k == 1:
                 # Same objective as full fine-tuning: keep routing balanced
@@ -192,16 +193,19 @@ def make_lora_train_step(model_cfg, lora_cfg: LoraConfig, optimizer, mesh,
 
             (loss, total), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_lora = optax.apply_updates(state.params, updates)
-        grad_norm = optax.global_norm(grads)
-        # Non-finite guard, same contract as make_train_step: a bad batch
-        # skips the update (LoRA params + opt state bitwise unchanged) and
-        # flags the step for the trainer's consecutive-bad-step abort.
-        ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-        new_lora, new_opt = jax.tree.map(
-            lambda new, old: jnp.where(ok, new, old),
-            (new_lora, new_opt), (state.params, state.opt_state))
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            new_lora = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
+            # Non-finite guard, same contract as make_train_step: a bad
+            # batch skips the update (LoRA params + opt state bitwise
+            # unchanged) and flags the step for the trainer's
+            # consecutive-bad-step abort.
+            ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+            new_lora, new_opt = jax.tree.map(
+                lambda new, old: jnp.where(ok, new, old),
+                (new_lora, new_opt), (state.params, state.opt_state))
         metrics = {"loss": loss, "grad_norm": grad_norm,
                    "weight_tokens": total,
                    "nonfinite": (~ok).astype(jnp.int32)}

@@ -260,17 +260,20 @@ def make_ce_terms(cfg: ModelConfig, remat: bool, loss_chunk: int):
                 remat=remat, with_aux=True, return_activations=True)
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["head"])
-            loss, total = chunked_cross_entropy(
-                acts, head, batch["targets"], batch.get("loss_mask"),
-                chunk_size=loss_chunk, compute_dtype=cfg.activation_dtype)
+            with jax.named_scope("loss"):
+                loss, total = chunked_cross_entropy(
+                    acts, head, batch["targets"], batch.get("loss_mask"),
+                    chunk_size=loss_chunk,
+                    compute_dtype=cfg.activation_dtype)
         else:
             logits, _, aux = forward(
                 cfg, params, batch["tokens"],
                 positions=batch.get("positions"),
                 segment_ids=batch.get("segment_ids"),
                 remat=remat, with_aux=True)
-            loss, total = cross_entropy_loss(
-                logits, batch["targets"], batch.get("loss_mask"))
+            with jax.named_scope("loss"):
+                loss, total = cross_entropy_loss(
+                    logits, batch["targets"], batch.get("loss_mask"))
         return loss, total, aux
 
     return ce_terms
@@ -404,19 +407,22 @@ def make_train_step(
 
             (loss, total_weight), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params)
-        updates, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        grad_norm = optax.global_norm(grads)
-        # Non-finite guard (docs/fault-tolerance.md): a poisoned batch or a
-        # numeric blow-up must not write NaN into the params — the update is
-        # skipped wholesale (params AND optimizer state bitwise unchanged,
-        # step counter still advances) and the step is flagged in metrics so
-        # the trainer can count consecutive bad steps and abort.
-        ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-        new_params, new_opt_state = jax.tree.map(
-            lambda new, old: jnp.where(ok, new, old),
-            (new_params, new_opt_state), (state.params, state.opt_state))
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
+            # Non-finite guard (docs/fault-tolerance.md): a poisoned batch
+            # or a numeric blow-up must not write NaN into the params — the
+            # update is skipped wholesale (params AND optimizer state
+            # bitwise unchanged, step counter still advances) and the step
+            # is flagged in metrics so the trainer can count consecutive
+            # bad steps and abort.
+            ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+            new_params, new_opt_state = jax.tree.map(
+                lambda new, old: jnp.where(ok, new, old),
+                (new_params, new_opt_state),
+                (state.params, state.opt_state))
         metrics = {
             "loss": loss,
             "grad_norm": grad_norm,

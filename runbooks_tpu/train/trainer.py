@@ -31,7 +31,7 @@ from runbooks_tpu.obs import trace as obs_trace
 from runbooks_tpu.obs.goodput import GoodputTracker
 from runbooks_tpu.obs.metrics import REGISTRY
 from runbooks_tpu.obs.profile import PROFILER, parse_profile_at_step
-from runbooks_tpu.obs.trace import span
+from runbooks_tpu.obs.trace import fine, span
 from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
 from runbooks_tpu.train import data as data_mod
 from runbooks_tpu.train.checkpoint import CheckpointManager
@@ -242,7 +242,11 @@ def run_training(job: TrainJobConfig,
         raise ValueError(
             f"accumulate_steps={job.accumulate_steps} must divide "
             f"batch_size={job.batch_size}")
-    mesh = make_mesh(job.mesh)
+    # Set-up seconds by phase (obs/trace.py PhaseSeconds): in the summary
+    # and metrics.json as "phases", main()'s startup.imports with them.
+    phases = obs_trace.PhaseSeconds()
+    with phases.timed("startup.backend"):
+        mesh = make_mesh(job.mesh)     # the first touch of the backend
     optimizer = make_optimizer(job.optimizer)
     artifacts = job.artifacts_dir or contract.artifacts_dir()
     os.makedirs(artifacts, exist_ok=True)
@@ -271,12 +275,15 @@ def run_training(job: TrainJobConfig,
         "compile_cache_dir": enable_compilation_cache(),
         "attention_impl": attention_impl,
     }
-    print(json.dumps({"startup": "train", "model": job.model, **identity}),
+    print(json.dumps({"startup": "train", "model": job.model, **identity,
+                      "phases": {**obs_trace.STARTUP.snapshot(),
+                                 **phases.snapshot()}}),
           flush=True)
     ckpt = CheckpointManager(artifacts)
 
     rng = jax.random.key(job.seed)
     lora_mode = job.lora is not None
+    t_weights = time.perf_counter()
     if lora_mode:
         if base_params is None:
             from runbooks_tpu.models.transformer import init_params
@@ -311,6 +318,9 @@ def run_training(job: TrainJobConfig,
         step_fn = make_train_step(model_cfg, optimizer, mesh, shardings,
                                   accumulate_steps=job.accumulate_steps,
                                   loss_chunk=job.loss_chunk)
+
+    jax.block_until_ready(state)       # made AND placed
+    phases.add("startup.weights", time.perf_counter() - t_weights)
 
     # Device-level observability (obs/device.py): compile sentinel +
     # program census. After the first step folds the XLA compile, any
@@ -382,6 +392,7 @@ def run_training(job: TrainJobConfig,
             "batches_consumed": consumed,
             "goodput": goodput.ratio() if goodput.steps else None,
             "goodput_detail": goodput.snapshot(),
+            "phases": {**obs_trace.STARTUP.snapshot(), **phases.snapshot()},
             "device_obs": {
                 # Analytic cross-check of the wall-clock MFU: FLOPs and
                 # HBM bytes from the compiled step's cost_analysis, with
@@ -517,6 +528,7 @@ def run_training(job: TrainJobConfig,
                 batches, depth=job.prefetch_depth,
                 place=data_mod.device_placer(mesh))
         t_start = time.perf_counter()
+        compiled_before = obs_device.SENTINEL.compile_seconds
         with jax.set_mesh(mesh):
             for i in range(start_step, job.steps):
                 if _fault_due(i, "kill"):
@@ -561,15 +573,25 @@ def run_training(job: TrainJobConfig,
                     else:
                         state, metrics = step_fn(state, batch)
                 step_s = time.perf_counter() - t_step
-                _check_nonfinite(pending_nf)
+                with fine("step.sync", step=i):
+                    _check_nonfinite(pending_nf)
                 pending_nf = (i, metrics.get("nonfinite"))
                 if i == start_step:
+                    # The first call traced, lowered and compiled (or
+                    # loaded) the step program: the set-up phases split it
+                    # by the sentinel's compile clock, as the serve
+                    # warm-up does (engine.WarmupRun).
+                    compiled = (obs_device.SENTINEL.compile_seconds
+                                - compiled_before)
+                    phases.add("warmup.compile", compiled)
+                    phases.add("warmup.trace", max(step_s - compiled, 0.0))
                     # The first step folds the XLA compile; pulling the
                     # loss waits for it, then the throughput window resets
                     # so tokens/sec and MFU report steady-state compute
                     # (compile time lands in its own field). The whole
                     # window is restart/startup overhead for goodput.
-                    float(metrics["loss"])
+                    with phases.timed("warmup.run"):
+                        float(metrics["loss"])
                     compile_time_s = time.perf_counter() - t_start
                     goodput.exclude(compile_time_s, "compile")
                     # Compile phase over: from here a compile in the step
@@ -595,6 +617,8 @@ def run_training(job: TrainJobConfig,
                                 "train", "train_step",
                                 f"b{job.batch_size}s{job.seq_len}",
                                 device_cost)
+                        phases.add("warmup.cost_capture",
+                                   time.perf_counter() - t_cost)
                         goodput.exclude(
                             time.perf_counter() - t_cost, "compile")
                     t_start = time.perf_counter()
@@ -616,7 +640,8 @@ def run_training(job: TrainJobConfig,
                     # metrics buffered as device arrays. The sync wait is
                     # device compute finishing — step time, not overhead.
                     t_sync = time.perf_counter()
-                    loss = float(metrics["loss"])
+                    with fine("log", step=i + 1):
+                        loss = float(metrics["loss"])
                     t_synced = time.perf_counter()
                     dt = t_synced - t_start
                     if i != start_step:
@@ -654,70 +679,73 @@ def run_training(job: TrainJobConfig,
                     win["ckpt"] += ckpt_s
                     win["steps"] += 1
                 if is_log:
-                    if tokens_done:
-                        tps = tokens_done / max(dt, 1e-9)
-                    else:  # single measured step: only the compile window
-                        tps = tokens_per_step / max(compile_time_s, 1e-9)
-                    achieved = tps * flops_per_token
-                    entry = {"step": i + 1, "loss": round(loss, 4),
-                             "tokens_per_sec": round(tps, 1),
-                             "tflops_per_sec": round(achieved / 1e12, 2)}
-                    if peak_flops:
-                        entry["mfu"] = round(achieved / peak_flops, 4)
-                    if not history and compile_time_s is not None:
-                        entry["compile_time_s"] = round(compile_time_s, 2)
-                    if win["steps"]:
-                        # Step-time breakdown (window means) + running
-                        # goodput: the is-it-input-bound answer, on every
-                        # log line instead of behind a debugger.
-                        entry["data_wait_s"] = round(
-                            win["data"] / win["steps"], 4)
-                        entry["step_s"] = round(
-                            win["step"] / win["steps"], 4)
-                        if win["ckpt"]:
-                            entry["ckpt_s"] = round(
-                                win["ckpt"] / win["steps"], 4)
-                        entry["goodput"] = round(goodput.ratio(), 4)
+                    # The log point: line printed, metrics.json rewritten
+                    # (every step at log_every: 1).
+                    with fine("log", step=i + 1):
+                        if tokens_done:
+                            tps = tokens_done / max(dt, 1e-9)
+                        else:  # single measured step: only the compile window
+                            tps = tokens_per_step / max(compile_time_s, 1e-9)
+                        achieved = tps * flops_per_token
+                        entry = {"step": i + 1, "loss": round(loss, 4),
+                                 "tokens_per_sec": round(tps, 1),
+                                 "tflops_per_sec": round(achieved / 1e12, 2)}
+                        if peak_flops:
+                            entry["mfu"] = round(achieved / peak_flops, 4)
+                        if not history and compile_time_s is not None:
+                            entry["compile_time_s"] = round(compile_time_s, 2)
+                        if win["steps"]:
+                            # Step-time breakdown (window means) + running
+                            # goodput: the is-it-input-bound answer, on every
+                            # log line instead of behind a debugger.
+                            entry["data_wait_s"] = round(
+                                win["data"] / win["steps"], 4)
+                            entry["step_s"] = round(
+                                win["step"] / win["steps"], 4)
+                            if win["ckpt"]:
+                                entry["ckpt_s"] = round(
+                                    win["ckpt"] / win["steps"], 4)
+                            entry["goodput"] = round(goodput.ratio(), 4)
+                            REGISTRY.set_gauge(
+                                "train_goodput_ratio", entry["goodput"],
+                                help_text="Productive step time / wall clock "
+                                          "(restart overhead excluded).")
+                        # Progress gauges: what the controller's fleet
+                        # scraper folds into Model .status.telemetry
+                        # (step/loss/goodput on `rbt get`).
                         REGISTRY.set_gauge(
-                            "train_goodput_ratio", entry["goodput"],
-                            help_text="Productive step time / wall clock "
-                                      "(restart overhead excluded).")
-                    # Progress gauges: what the controller's fleet
-                    # scraper folds into Model .status.telemetry
-                    # (step/loss/goodput on `rbt get`).
-                    REGISTRY.set_gauge(
-                        "train_step", i + 1,
-                        help_text="Last completed training step.")
-                    REGISTRY.set_gauge(
-                        "train_loss", round(loss, 6),
-                        help_text="Loss at the last logged step.")
-                    # Per-step HBM watermark (device_memory_* gauges;
-                    # absent on CPU where memory_stats() is None) and the
-                    # analytic-MFU cross-check from the step program's
-                    # cost_analysis.
-                    hbm = [m["bytes_in_use"]
-                           for m in obs_device.set_memory_gauges()
-                           if "bytes_in_use" in m]
-                    if hbm:
-                        hbm_per_device = hbm
-                        entry["hbm_used_bytes"] = max(hbm)
-                        hbm_peak_bytes = max(hbm_peak_bytes, max(hbm))
-                    if device_cost and win["steps"] and peak_flops:
-                        entry["analytic_mfu"] = round(
-                            device_cost["flops"]
-                            / (win["step"] / win["steps"]) / peak_flops, 4)
+                            "train_step", i + 1,
+                            help_text="Last completed training step.")
                         REGISTRY.set_gauge(
-                            "train_analytic_mfu", entry["analytic_mfu"],
-                            help_text="cost_analysis FLOPs / measured "
-                                      "step time / peak — the analytic "
-                                      "cross-check of the wall-clock "
-                                      "MFU.")
-                    obs_device.PROGRAMS.set_gauges(component="train")
-                    win = {"data": 0.0, "step": 0.0, "ckpt": 0.0,
-                           "steps": 0}
-                    history.append(entry)
-                    print(json.dumps(entry), flush=True)
-                    _write_metrics()
+                            "train_loss", round(loss, 6),
+                            help_text="Loss at the last logged step.")
+                        # Per-step HBM watermark (device_memory_* gauges;
+                        # absent on CPU where memory_stats() is None) and the
+                        # analytic-MFU cross-check from the step program's
+                        # cost_analysis.
+                        hbm = [m["bytes_in_use"]
+                               for m in obs_device.set_memory_gauges()
+                               if "bytes_in_use" in m]
+                        if hbm:
+                            hbm_per_device = hbm
+                            entry["hbm_used_bytes"] = max(hbm)
+                            hbm_peak_bytes = max(hbm_peak_bytes, max(hbm))
+                        if device_cost and win["steps"] and peak_flops:
+                            entry["analytic_mfu"] = round(
+                                device_cost["flops"]
+                                / (win["step"] / win["steps"]) / peak_flops, 4)
+                            REGISTRY.set_gauge(
+                                "train_analytic_mfu", entry["analytic_mfu"],
+                                help_text="cost_analysis FLOPs / measured "
+                                          "step time / peak — the analytic "
+                                          "cross-check of the wall-clock "
+                                          "MFU.")
+                        obs_device.PROGRAMS.set_gauges(component="train")
+                        win = {"data": 0.0, "step": 0.0, "ckpt": 0.0,
+                               "steps": 0}
+                        history.append(entry)
+                        print(json.dumps(entry), flush=True)
+                        _write_metrics()
             if exit_reason is None:
                 _check_nonfinite(pending_nf)
             else:
@@ -791,6 +819,10 @@ def exit_code_for(summary: Dict[str, Any]) -> int:
 
 
 def main() -> int:
+    # Interpreter start to here: this module's imports (set-up phases).
+    age = obs_trace.process_age_s()
+    if age is not None:
+        obs_trace.STARTUP.add("startup.imports", age)
     params = contract.load_params()
     job = TrainJobConfig.from_params(params)
     # Metrics exposition for the controller's fleet scraper: RBT_METRICS_PORT
