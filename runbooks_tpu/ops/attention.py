@@ -7,6 +7,18 @@ Two execution paths:
   - ``runbooks_tpu.ops.flash_attention``: Pallas blockwise kernel for long
     sequences (imported lazily by ``attention`` to keep CPU tests light).
 
+Grouped heads: K and V are never repeated per query head. The query heads
+are viewed as ``[kv_heads, group]`` and each KV head is contracted against
+its whole group (``bqhgd,bkhd->bhgqk``), so every (row, KV head) is one
+matrix product with ``group * q_len`` rows, also at ``q_len == 1``. The
+repeat-then-contract form it replaced made every (row, query head) of a
+decode step a vector x matrix product, which the TPU compiler lowers to a
+float32 multiply + reduce on the vector unit and not to the MXU, and under
+tensor parallelism it materialized the widened K/V in float32 (AOT for
+v5e:2x2, one layer, falcon-40b decode: 567 MB accessed and 134.5 MB of
+temporaries against 44 MB and none; falcon-7b: 0 against 2 MXU
+convolutions; PERF.md section 6, PR 25).
+
 Masking model: a query token q may attend to key token k iff
   positions[k] <= positions[q]   (causal, by absolute position — this makes
                                   the op correct under sequence-parallel
@@ -21,6 +33,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from runbooks_tpu.parallel.sharding import (
+    _current_mesh,
+    spec_for_array,
+    with_logical_constraint,
+)
 
 NEG_INF = -1e30
 
@@ -63,14 +81,17 @@ def alibi_slopes(num_heads: int) -> jax.Array:
     return jnp.asarray(vals, dtype=jnp.float32)
 
 
-def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
-    """[b, s, kv_heads, d] -> [b, s, kv_heads*n_rep, d] for GQA broadcast."""
-    if n_rep == 1:
-        return x
-    b, s, h, d = x.shape
-    return jnp.broadcast_to(x[:, :, :, None, :], (b, s, h, n_rep, d)).reshape(
-        b, s, h * n_rep, d
-    )
+def _grouped_heads_axes(num_kv_heads: int):
+    """Logical axes of a ``[b, s, kv_heads, group, d]`` view of the query
+    heads. The mesh axis of the heads lies on ``kv_heads`` when it divides
+    them (each device keeps whole KV heads with their groups, as K/V are
+    sharded: no communication), else on ``group`` (multi-query under tensor
+    parallelism: K/V replicated, each device a slice of the group)."""
+    mesh = _current_mesh()
+    on_kv = mesh is None or spec_for_array(
+        (num_kv_heads,), ("act_heads",), mesh)[0] is not None
+    heads = ("act_heads", None) if on_kv else (None, "act_heads")
+    return ("batch", "seq") + heads + (None,)
 
 
 def dot_product_attention(
@@ -82,23 +103,33 @@ def dot_product_attention(
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
 ) -> jax.Array:
-    """Reference attention. fp32 logits/softmax, output in q.dtype."""
-    *_, num_heads, head_dim = q.shape
-    num_kv_heads = k.shape[-2]
+    """Reference attention. fp32 logits/softmax, output in q.dtype.
+
+    Query head ``h * group + r`` attends KV head ``h``; K and V stay at
+    their own width (module docstring)."""
+    b, q_len, num_heads, head_dim = q.shape
+    num_kv_heads = k.shape[2]
+    group = num_heads // num_kv_heads
     scale = scale if scale is not None else head_dim ** -0.5
 
-    k = repeat_kv(k, num_heads // num_kv_heads)
-    v = repeat_kv(v, num_heads // num_kv_heads)
+    def grouped(x):  # [b|1, 1|h, q_len, kv_len] -> [b|1, 1|kvh, 1|g, ...]
+        if x.shape[1] == 1:
+            return x[:, :, None]
+        return x.reshape(x.shape[0], num_kv_heads, group, *x.shape[2:])
 
+    axes = _grouped_heads_axes(num_kv_heads)
+    q = with_logical_constraint(
+        q.reshape(b, q_len, num_kv_heads, group, head_dim), axes)
     logits = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+        "bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32
     )
     logits = logits * scale
     if logit_softcap is not None:
         logits = logit_softcap * jnp.tanh(logits / logit_softcap)
     if bias is not None:
-        logits = logits + bias.astype(jnp.float32)
+        logits = logits + grouped(bias).astype(jnp.float32)
     if mask is not None:
+        mask = grouped(mask)
         logits = jnp.where(mask, logits, NEG_INF)
 
     probs = jax.nn.softmax(logits, axis=-1)
@@ -108,7 +139,8 @@ def dot_product_attention(
         any_valid = jnp.any(mask, axis=-1, keepdims=True)
         probs = jnp.where(any_valid, probs, 0.0)
     out = jnp.einsum(
-        "bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
+        "bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
-    return out.astype(q.dtype)
+    out = with_logical_constraint(out, axes)
+    return out.reshape(b, q_len, num_heads, head_dim).astype(q.dtype)

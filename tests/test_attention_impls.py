@@ -6,10 +6,18 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.sharding import NamedSharding
 
 from runbooks_tpu.models.config import get_config
 from runbooks_tpu.models.transformer import forward, init_params
+from runbooks_tpu.ops.attention import (
+    alibi_slopes,
+    dot_product_attention,
+    make_attention_mask,
+)
 from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
+from runbooks_tpu.parallel.sharding import spec_for_array
 
 
 def cfg_with(impl):
@@ -174,3 +182,148 @@ def test_ring_flash_save_attn_out_skips_fwd_ring_recompute():
             counts[policy] = _count_pallas_calls(jaxpr.jaxpr)
     assert counts["nothing_saveable"] == 8, counts
     assert counts["save_attn_out"] == 6, counts
+
+
+# ---------------------------------------------------------------------------
+# dot_product_attention contracts each KV head against its whole group of
+# query heads; K/V are never repeated per query head (ops/attention.py).
+# ---------------------------------------------------------------------------
+
+KV_LEN = 64
+
+
+def _repeat_kv(x, n_rep):
+    """[b, s, kv_heads, d] -> [b, s, kv_heads*n_rep, d]: head h*n_rep + r
+    is a copy of KV head h."""
+    b, s, h, d = x.shape
+    return jnp.broadcast_to(x[:, :, :, None, :], (b, s, h, n_rep, d)).reshape(
+        b, s, h * n_rep, d)
+
+
+def _repeat_oracle(q, k, v, mask=None, bias=None, logit_softcap=None):
+    """The explicit-repeat form the grouped contraction replaced: widen K/V
+    to one copy per query head, then plain multi-head attention."""
+    n_rep = q.shape[2] // k.shape[2]
+    k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        precision="highest") * q.shape[-1] ** -0.5
+    if logit_softcap is not None:
+        logits = logit_softcap * jnp.tanh(logits / logit_softcap)
+    if bias is not None:
+        logits = logits + bias
+    if mask is not None:
+        logits = jnp.where(mask, logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if mask is not None:
+        probs = jnp.where(jnp.any(mask, axis=-1, keepdims=True), probs, 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision="highest")
+
+
+def _qkv(heads, kv_heads, q_len, b=2, d=16, dtype=jnp.float32):
+    kq, kk, kv = jax.random.split(jax.random.key(heads * 100 + q_len), 3)
+    q = jax.random.normal(kq, (b, q_len, heads, d), dtype)
+    k = jax.random.normal(kk, (b, KV_LEN, kv_heads, d), dtype)
+    v = jax.random.normal(kv, (b, KV_LEN, kv_heads, d), dtype)
+    return q, k, v
+
+
+def _decode_mask(b, q_len):
+    """The cache's mask: the q_len queries are the last positions."""
+    q_pos = jnp.broadcast_to(
+        jnp.arange(KV_LEN - q_len, KV_LEN, dtype=jnp.int32)[None], (b, q_len))
+    kv_pos = jnp.broadcast_to(
+        jnp.arange(KV_LEN, dtype=jnp.int32)[None], (b, KV_LEN))
+    return make_attention_mask(q_pos, kv_pos), q_pos, kv_pos
+
+
+@pytest.mark.parametrize("variant",
+                         ["mask", "mask_alibi", "softcap", "masked_row"])
+@pytest.mark.parametrize("q_len", [1, 5, 64])
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (8, 2), (71, 1)])
+def test_grouped_attention_matches_repeat_oracle(heads, kv_heads, q_len,
+                                                 variant):
+    q, k, v = _qkv(heads, kv_heads, q_len)
+    mask, q_pos, kv_pos = _decode_mask(q.shape[0], q_len)
+    kwargs = {"mask": mask}
+    if variant == "mask_alibi":
+        # Per-head bias [1, H, q, k]: a head landing on the wrong KV group
+        # or the wrong slope shows.
+        dist = (kv_pos[0][None, :] - q_pos[0][:, None]).astype(jnp.float32)
+        kwargs["bias"] = (alibi_slopes(heads)[:, None, None] * dist)[None]
+    elif variant == "softcap":
+        kwargs["logit_softcap"] = 5.0
+    elif variant == "masked_row":
+        # A row that may attend nothing (padding) must come out as zeros;
+        # a per-head mask [b, H, q, k] also takes the reshape of its heads.
+        mask = jnp.broadcast_to(mask, (q.shape[0], heads, q_len, KV_LEN))
+        kwargs["mask"] = mask.at[1, :, 0].set(False).at[0, heads // 2].set(
+            False)
+    got = dot_product_attention(q, k, v, **kwargs)
+    want = _repeat_oracle(q, k, v, **kwargs)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    if variant == "masked_row":
+        assert not np.any(np.asarray(got[1, 0]))
+        assert not np.any(np.asarray(got[0, :, heads // 2]))
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(8, 2), (71, 1)])
+def test_decode_attention_program_stays_at_kv_width(heads, kv_heads):
+    """A property of the traced program, not of a run: no intermediate has
+    K/V's length at the query heads' width, and each KV head is a batch
+    dimension of both products (so its group is the matrix's rows)."""
+    q, k, v = _qkv(heads, kv_heads, 1, dtype=jnp.bfloat16)
+    mask, _, _ = _decode_mask(q.shape[0], 1)
+    jaxpr = jax.make_jaxpr(dot_product_attention)(q, k, v, mask).jaxpr
+    widened = (q.shape[0], KV_LEN, heads, q.shape[-1])
+    dots = []
+    for eqn in _all_eqns(jaxpr):
+        for var in eqn.outvars:
+            assert tuple(var.aval.shape) != widened, eqn
+        if eqn.primitive.name == "dot_general":
+            dots.append(eqn)
+    assert len(dots) == 2
+    for eqn in dots:
+        (_, _), (lhs_batch, rhs_batch) = eqn.params["dimension_numbers"]
+        lhs, rhs = (x.aval.shape for x in eqn.invars)
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert len(lhs_batch) == 2, eqn        # (row, KV head)
+        assert sorted(lhs[i] for i in lhs_batch) == sorted(
+            (q.shape[0], kv_heads)), eqn
+        assert sorted(rhs[i] for i in rhs_batch) == sorted(
+            (q.shape[0], kv_heads)), eqn
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(8, 2), (4, 1)])
+def test_grouped_attention_under_tensor_mesh_moves_no_kv(heads, kv_heads):
+    """tensor=2: with 2 KV heads each device keeps one KV head and its four
+    query heads; with 1 KV head K/V are replicated and each device takes
+    half the group. Neither needs a collective."""
+    mesh = make_mesh(MeshConfig(data=1, fsdp=4, tensor=2))
+    q, k, v = _qkv(heads, kv_heads, 1, b=4)
+    mask, _, _ = _decode_mask(q.shape[0], 1)
+    heads_axes = ("batch", "seq", "act_heads", None)
+
+    def place(x, logical):
+        return jax.device_put(x, NamedSharding(
+            mesh, spec_for_array(x.shape, logical, mesh)))
+
+    args = (place(q, heads_axes), place(k, heads_axes), place(v, heads_axes),
+            place(mask, ("batch", None, None, None)))
+    with jax.set_mesh(mesh):
+        f = jax.jit(dot_product_attention, out_shardings=args[0].sharding)
+        hlo = f.lower(*args).compile().as_text()
+        got = f(*args)
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in hlo, collective
+    assert got.sharding.spec[2] == "tensor"
+    np.testing.assert_allclose(got, _repeat_oracle(q, k, v, mask),
+                               rtol=1e-5, atol=1e-5)
