@@ -62,6 +62,10 @@ class AuditSettings:
     (norm/LSE upcasts) under the byte thresholds so only genuinely large
     silent promotions flag."""
     config: str = "debug"
+    # A model with a layer pattern (linear-attention layers beside full
+    # ones): its prefill and decode programs carry the recurrent cache
+    # leaves (`state`, `conv`) and the token mask (docs/hybrid-models.md).
+    hybrid_config: str = "debug-hybrid"
     max_slots: int = 2
     decode_chunk: int = 2
     # Speculative verify window (serve/engine.py make_verify_fn): the
@@ -583,6 +587,29 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
                       + decode_args[2:]),
              "signatures": len(views)},
         ]
+    # A layer pattern with recurrent state: the same two factories trace
+    # other programs (a scan over periods, the state splice, the token
+    # mask). The engine refuses the prefix, verify, adapter and paged
+    # variants for such a model, so these two are its whole set.
+    cfg_h = get_config(settings.hybrid_config)
+    params_h = jax.eval_shape(functools.partial(init_params, cfg_h), key)
+    pool_h = jax.eval_shape(lambda: KVCache.create(
+        cfg_h, slots, cfg_h.max_seq_len, trash_slot=True))
+    buckets_h = _buckets(cfg_h.max_seq_len)
+    views_h = view_buckets_for(cfg_h.max_seq_len)
+    specs += [
+        {"component": "serve", "name": "hybrid_prefill",
+         "fn": make_prefill_fn(cfg_h, cfg_h.max_seq_len + 1),
+         "args": [params_h, pool_h] + prefill_args(
+             rows_set[-1], buckets_h[-1])[2:],
+         "signatures": len(buckets_h) * len(rows_set)},
+        {"component": "serve", "name": "hybrid_decode",
+         "fn": make_decode_fn(cfg_h, settings.decode_chunk,
+                              cfg_h.max_seq_len, cfg_h.max_seq_len,
+                              views_h[-1]),
+         "args": [params_h, pool_h] + decode_args[2:],
+         "signatures": len(views_h)},
+    ]
     return specs
 
 

@@ -59,6 +59,9 @@ def resolve_collective_matmul_param(params: dict) -> Optional[str]:
 # jax-free validation table mirrors this enum.
 SPECULATIVE_MODES = ("off", "ngram")
 
+# Token-mixer kinds a layer pattern may name (ModelConfig.layer_types).
+LAYER_KINDS = ("full_attention", "linear_attention")
+
 
 def check_speculative(mode: str) -> str:
     """Validate a speculative mode string (single source for the error
@@ -106,15 +109,37 @@ class ModelConfig:
     # Attention
     attn_bias: bool = False
     qk_norm: bool = False
+    # Width of the QK norm: "head" normalizes each head's head_dim on its
+    # own (one [head_dim] scale), "full" the whole projected q / k before
+    # the heads are split (one [q_dim] / [kv_dim] scale; OLMo 2/3).
+    qk_norm_width: str = "head"
     logit_softcap: Optional[float] = None
 
-    # Positional encoding
-    position_type: str = "rope"       # "rope" | "alibi" | "learned"
+    # Positional encoding. "none": no positional signal beside causality
+    # (hybrids whose recurrent layers carry order).
+    position_type: str = "rope"       # "rope" | "alibi" | "learned" | "none"
     rope_theta: float = 10000.0
 
     # Block structure
     parallel_block: bool = False      # falcon/gpt-neox parallel attn+mlp
     shared_layer_norm: bool = True    # for parallel_block: one LN feeds both
+    # "pre": x + f(norm(x)) (every preset above). "post": x + norm(f(x)),
+    # the reordered-norm block (the norm sits on the sub-layer's OUTPUT).
+    norm_position: str = "pre"
+
+    # Layer pattern (docs/hybrid-models.md): ONE period of token-mixer
+    # kinds, repeated num_layers / len(layer_types) times. () = every
+    # layer is "full_attention" (a period of one). "linear_attention" is
+    # the gated delta rule (ops/gated_delta.py): a fixed-size recurrent
+    # state a head instead of keys and values a token.
+    layer_types: tuple = ()
+    linear_num_heads: int = 0         # key heads = value heads
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4       # causal depthwise conv on q, k, v
+    # beta in (0, 2) instead of (0, 1): the state transition may have
+    # negative eigenvalues.
+    linear_allow_neg_eigval: bool = True
 
     # Embeddings / head
     tie_embeddings: bool = False
@@ -256,6 +281,71 @@ class ModelConfig:
     def parameter_dtype(self):
         return jnp.dtype(self.param_dtype)
 
+    def __post_init__(self):
+        kinds = self.layer_pattern
+        bad = [k for k in kinds if k not in LAYER_KINDS]
+        if bad:
+            raise ValueError(
+                f"unknown layer type(s) {bad}; expected {LAYER_KINDS}")
+        if kinds.count("full_attention") != 1:
+            raise ValueError(
+                "a period of the layer pattern holds exactly one "
+                "full_attention layer (params['layers'] is one stack, "
+                f"scanned a period a step); got {kinds}")
+        if self.num_layers % len(kinds):
+            raise ValueError(
+                f"num_layers {self.num_layers} is not a whole number of "
+                f"periods of the layer pattern (length {len(kinds)})")
+        if self.norm_position not in ("pre", "post"):
+            raise ValueError(
+                f"unknown norm_position {self.norm_position!r}; "
+                "expected pre|post")
+        if self.norm_position == "post" and self.parallel_block:
+            raise ValueError(
+                "norm_position: post has no parallel_block form")
+        if self.qk_norm_width not in ("head", "full"):
+            raise ValueError(
+                f"unknown qk_norm_width {self.qk_norm_width!r}; "
+                "expected head|full")
+        if "linear_attention" in kinds and not (
+                self.linear_num_heads and self.linear_key_head_dim
+                and self.linear_value_head_dim):
+            raise ValueError(
+                "linear_attention layers need linear_num_heads, "
+                "linear_key_head_dim and linear_value_head_dim")
+
+    @property
+    def layer_pattern(self) -> tuple:
+        """One period of layer kinds (never empty)."""
+        return tuple(self.layer_types) or ("full_attention",)
+
+    @property
+    def num_periods(self) -> int:
+        return self.num_layers // len(self.layer_pattern)
+
+    def layers_of(self, kind: str) -> int:
+        """How many of the model's layers are of this kind."""
+        return self.num_periods * self.layer_pattern.count(kind)
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Some layer keeps a fixed-size state instead of keys and values:
+        what the serving engine's stale-data invariant does not cover."""
+        return "linear_attention" in self.layer_pattern
+
+    @property
+    def linear_key_dim(self) -> int:
+        return self.linear_num_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.linear_num_heads * self.linear_value_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels under the short convolution: q | k | v."""
+        return 2 * self.linear_key_dim + self.linear_value_dim
+
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
@@ -276,6 +366,9 @@ class ModelConfig:
         attn = h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h
         if self.attn_bias:
             attn += self.q_dim + 2 * self.kv_dim + h
+        if self.qk_norm:
+            attn += (2 * self.head_dim if self.qk_norm_width == "head"
+                     else self.q_dim + self.kv_dim)
         mlp_mats = (2 if self.gated_mlp else 1) * h * self.intermediate_size
         mlp_mats += self.intermediate_size * h
         if self.mlp_bias:
@@ -287,9 +380,18 @@ class ModelConfig:
         norms_per_layer = h if (self.parallel_block and self.shared_layer_norm) else 2 * h
         if self.norm_type == "layernorm":
             norms_per_layer *= 2  # scale + bias
-        per_layer = attn + mlp_mats + norms_per_layer
+        rest = mlp_mats + norms_per_layer
+        kd, vd = self.linear_key_dim, self.linear_value_dim
+        # q, k, v, output gate, out; the a / b heads; conv; A_log, dt_bias;
+        # the output norm (one head's width, shared by the heads).
+        linear = (h * (2 * kd + 2 * vd) + vd * h
+                  + 2 * h * self.linear_num_heads
+                  + self.linear_conv_kernel * self.linear_conv_dim
+                  + 2 * self.linear_num_heads + self.linear_value_head_dim)
         final_norm = h * (2 if self.norm_type == "layernorm" else 1)
-        return embed + head + pos + self.num_layers * per_layer + final_norm
+        return (embed + head + pos + final_norm
+                + self.layers_of("full_attention") * (attn + rest)
+                + self.layers_of("linear_attention") * (linear + rest))
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Forward-pass matmul FLOPs per token (2*N plus attention quadratic).
@@ -305,9 +407,17 @@ class ModelConfig:
         if self.moe_num_experts:
             # top-k active experts per token + the router matmul.
             mlp = mlp * self.moe_top_k + 2 * h * self.moe_num_experts
-        per_layer = attn_proj + attn_scores + mlp
+        kd, vd = self.linear_key_dim, self.linear_value_dim
+        # The delta rule itself: S^T k, the rank-one update, S^T q.
+        linear = (2 * (h * (2 * kd + 2 * vd) + vd * h
+                       + 2 * h * self.linear_num_heads)
+                  + 2 * self.linear_conv_kernel * self.linear_conv_dim
+                  + 6 * self.linear_num_heads * self.linear_key_head_dim
+                  * self.linear_value_head_dim)
         head = 2 * h * self.vocab_size
-        return float(self.num_layers * per_layer + head)
+        return float(
+            self.layers_of("full_attention") * (attn_proj + attn_scores + mlp)
+            + self.layers_of("linear_attention") * (linear + mlp) + head)
 
 
 def _llama(name, v=32000, h=4096, i=11008, l=32, q=32, kv=32, d=128, s=4096,
@@ -364,6 +474,24 @@ def _gpt2(name, v=50257, h=768, i=3072, l=12, q=12, s=1024):
     )
 
 
+def _olmo_hybrid(name, v=100352, h=3840, i=11008, l=32, q=30, d=128,
+                 s=65536, lin_heads=30, lin_dk=96, lin_dv=192):
+    # Three gated-delta linear-attention layers, then one full-attention
+    # layer; reordered-norm blocks; QK norm over the whole projection; no
+    # rotary embedding (the recurrent layers carry order); untied head.
+    return ModelConfig(
+        name=name, vocab_size=v, hidden_size=h, intermediate_size=i,
+        num_layers=l, num_heads=q, num_kv_heads=q, head_dim=d, max_seq_len=s,
+        norm_type="rmsnorm", norm_eps=1e-6, gated_mlp=True, activation="silu",
+        position_type="none", qk_norm=True, qk_norm_width="full",
+        norm_position="post",
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_heads=lin_heads, linear_key_head_dim=lin_dk,
+        linear_value_head_dim=lin_dv, linear_conv_kernel=4,
+        linear_allow_neg_eigval=True,
+    )
+
+
 # Registry mirrors the reference's documented example configs
 # (reference: examples/ tree — llama2-7b, llama2-70b, falcon-7b/40b,
 # facebook-opt-125m) plus debug sizes for tests/benchmarks.
@@ -394,11 +522,18 @@ CONFIGS = {
     # Gemma (MQA 2b / MHA 7b; GeGLU, scaled embeddings, tied head)
     "gemma-2b": _gemma("gemma-2b"),
     "gemma-7b": _gemma("gemma-7b", h=3072, i=24576, l=28, q=16, kv=16),
+    # OLMo hybrid: linear-attention (gated delta rule) layers beside full
+    # ones, 3:1 (docs/hybrid-models.md)
+    "olmo-hybrid-7b": _olmo_hybrid("olmo-hybrid-7b"),
     # GPT-2 (fused-qkv Conv1D checkpoints; learned positions)
     "gpt2": _gpt2("gpt2"),
     "gpt2-xl": _gpt2("gpt2-xl", h=1600, i=6400, l=48, q=25),
     # Debug/bench sizes
     "debug": _llama("debug", v=512, h=128, i=384, l=2, q=4, kv=2, d=32, s=256),
+    # One period of the hybrid pattern at toy widths (rbt check, tests)
+    "debug-hybrid": _olmo_hybrid("debug-hybrid", v=512, h=128, i=384, l=4,
+                                 q=4, d=32, s=128, lin_heads=4, lin_dk=32,
+                                 lin_dv=64),
     "bench-1b": _llama("bench-1b", h=2048, i=5632, l=22, q=16, kv=16, d=128, s=2048),
     "bench-410m": _llama("bench-410m", h=1024, i=2816, l=24, q=16, kv=16, d=64, s=2048),
     # Same params/FLOPs as bench-410m but 8 heads x d128: wider MXU

@@ -8,6 +8,14 @@ delegates compute to external containers, SURVEY.md §2a):
   over them with ``lax.scan`` — one compiled block instead of L unrolled ones
   (faster compiles, natural remat boundary, later the unit of pipeline
   parallelism).
+- The scan runs over PERIODS of the layer pattern (ModelConfig.layer_types;
+  docs/hybrid-models.md). A homogeneous model is a period of one layer and
+  its params["layers"] is what it always was. A hybrid keeps its
+  full-attention layers (one a period) in params["layers"], stacked over
+  those layers only, and its linear-attention layers in
+  params["linear_layers"]: one stack [periods, …] for each position such
+  a layer has in the period, so that every stack is scanned by the
+  period's number alone, as a homogeneous model's is.
 - Every major activation gets a logical sharding constraint
   (runbooks_tpu.parallel.sharding) so pjit can propagate DP/FSDP/SP/TP layouts
   from a rule table.
@@ -20,6 +28,7 @@ delegates compute to external containers, SURVEY.md §2a):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -143,7 +152,10 @@ def _norm_params(cfg: ModelConfig, shape_prefix=()):
 def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     """Random-init parameters (stacked layers). For real checkpoints use
     runbooks_tpu.models.convert (HF weight import)."""
-    h, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
+    h, v = cfg.hidden_size, cfg.vocab_size
+    # params["layers"] holds the full-attention layers: all of them, but
+    # for a hybrid pattern.
+    L = cfg.layers_of("full_attention")
     pd = cfg.parameter_dtype
     keys = iter(jax.random.split(rng, 16))
 
@@ -173,8 +185,11 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
         layers["attn"]["bv"] = jnp.zeros((L, cfg.kv_dim), pd)
         layers["attn"]["bo"] = jnp.zeros((L, h), pd)
     if cfg.qk_norm:
-        layers["attn"]["q_norm"] = jnp.ones((L, cfg.head_dim), pd)
-        layers["attn"]["k_norm"] = jnp.ones((L, cfg.head_dim), pd)
+        full = cfg.qk_norm_width == "full"
+        layers["attn"]["q_norm"] = jnp.ones(
+            (L, cfg.q_dim if full else cfg.head_dim), pd)
+        layers["attn"]["k_norm"] = jnp.ones(
+            (L, cfg.kv_dim if full else cfg.head_dim), pd)
 
     if cfg.moe_num_experts:
         assert cfg.gated_mlp, "MoE experts are gated (mixtral-style)"
@@ -207,7 +222,58 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
         layers["ln2"] = _norm_params(cfg, (L,))
 
     params["layers"] = layers
+    if cfg.has_recurrent_state:
+        params["linear_layers"] = _init_linear_layers(cfg, rng)
     return params
+
+
+def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
+    """The linear-attention layers of a hybrid: a list with one tree for
+    each position such a layer has in the period, its leaves stacked
+    [periods, …]. Every leaf is DRAWN once for all those layers, in layer
+    order [L_lin, …] (one key a leaf, as every other leaf of the model),
+    and then dealt to the positions: linear layer l is period l // n,
+    position l % n. The keys come from a split of their own BESIDE
+    init_params' (fold_in 1), so every leaf a homogeneous preset draws
+    keeps its key, and its seeded weights their values. Matrices N(0, 1/fan_in); conv N(0, 1/kernel);
+    A_log = log U(1, 16) and dt_bias the inverse softplus of
+    exp U(log 1e-3, log 1e-1) (the Gated DeltaNet layer's own recipe, so
+    the decay is neither 0 nor 1 under random weights); norms 1."""
+    assert cfg.gated_mlp and not cfg.moe_num_experts and not cfg.mlp_bias, \
+        "linear-attention layers are written for the gated dense MLP"
+    h, i, pd = cfg.hidden_size, cfg.intermediate_size, cfg.parameter_dtype
+    L = cfg.layers_of("linear_attention")
+    H, kd, vd = cfg.linear_num_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    keys = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
+    mixer = {
+        "wq": _dense_init(next(keys), (L, h, kd), pd, h),
+        "wk": _dense_init(next(keys), (L, h, kd), pd, h),
+        "wv": _dense_init(next(keys), (L, h, vd), pd, h),
+        "wg": _dense_init(next(keys), (L, h, vd), pd, h),
+        "wo": _dense_init(next(keys), (L, vd, h), pd, vd),
+        "wa": _dense_init(next(keys), (L, h, H), pd, h),
+        "wb": _dense_init(next(keys), (L, h, H), pd, h),
+        "conv": _dense_init(next(keys), (L, cfg.linear_conv_kernel,
+                                         cfg.linear_conv_dim), pd,
+                            cfg.linear_conv_kernel),
+        "a_log": jnp.log(jax.random.uniform(
+            next(keys), (L, H), minval=1.0, maxval=16.0)).astype(pd),
+        "o_norm": jnp.ones((L, cfg.linear_value_head_dim), pd),
+    }
+    dt = jnp.exp(jax.random.uniform(
+        next(keys), (L, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    mixer["dt_bias"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
+    in_layer_order = {
+        "mixer": mixer,
+        "mlp": {"wo": _dense_init(next(keys), (L, i, h), pd, i),
+                "wi_gate": _dense_init(next(keys), (L, h, i), pd, h),
+                "wi_up": _dense_init(next(keys), (L, h, i), pd, h)},
+        "ln1": _norm_params(cfg, (L,)),
+        "ln2": _norm_params(cfg, (L,)),
+    }
+    n = cfg.layer_pattern.count("linear_attention")
+    return [jax.tree.map(lambda a: a[pos::n], in_layer_order)
+            for pos in range(n)]
 
 
 def param_logical_axes(cfg: ModelConfig) -> Params:
@@ -239,8 +305,10 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
                      "bv": ("layers", "kv_heads"),
                      "bo": ("layers", "norm")})
     if cfg.qk_norm:
-        attn.update({"q_norm": ("layers", "head_dim"),
-                     "k_norm": ("layers", "head_dim")})
+        full = cfg.qk_norm_width == "full"
+        attn.update({"q_norm": ("layers", "heads" if full else "head_dim"),
+                     "k_norm": ("layers",
+                                "kv_heads" if full else "head_dim")})
 
     if cfg.moe_num_experts:
         from runbooks_tpu.models.moe import moe_logical_axes
@@ -263,6 +331,22 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     if not (cfg.parallel_block and cfg.shared_layer_norm):
         layers["ln2"] = norm1(("layers",))
     axes["layers"] = layers
+    if cfg.has_recurrent_state:
+        col, row = ("layers", "embed", "heads"), ("layers", "heads", "embed")
+        one_position = {
+            "mixer": {"wq": col, "wk": col, "wv": col, "wg": col, "wo": row,
+                      "wa": ("layers", "embed", None),
+                      "wb": ("layers", "embed", None),
+                      "conv": ("layers", None, None),
+                      "a_log": ("layers", None),
+                      "dt_bias": ("layers", None),
+                      "o_norm": ("layers", "head_dim")},
+            "mlp": {"wo": ("layers", "mlp", "embed"),
+                    "wi_gate": ("layers", "embed", "mlp"),
+                    "wi_up": ("layers", "embed", "mlp")},
+            "ln1": norm1(("layers",)), "ln2": norm1(("layers",))}
+        axes["linear_layers"] = [
+            one_position] * cfg.layer_pattern.count("linear_attention")
     return axes
 
 
@@ -273,9 +357,11 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    """Per-model KV cache, layers stacked on the leading axis.
+    """Per-model cache, layers stacked on the leading axis: keys and
+    values for the full-attention layers and, for a hybrid, the recurrent
+    state of the linear-attention layers beside them.
 
-    k, v: [num_layers, batch, cache_len, num_kv_heads, head_dim]
+    k, v: [full layers, batch, cache_len, num_kv_heads, head_dim]
     index: [] int32 — number of tokens already written (same for the whole
     batch). Two write modes in ``forward``:
 
@@ -294,6 +380,17 @@ class KVCache:
     bandwidth-bound decode step streams, which doubles max_slots x
     max_seq_len at fixed memory. forward() detects the int8 dtype and
     quantizes on write / dequantizes on read transparently.
+
+    state, conv (present when the layer pattern has linear-attention
+    layers, as k_scale is for int8): what such a layer keeps of a row's
+    past, whatever its length.
+      state [linear layers, batch, heads, d_k, d_v] float32 (never
+            quantized): the delta rule's S;
+      conv  [linear layers, batch, kernel-1, conv channels]: the inputs of
+            the short convolution at the row's last kernel-1 tokens.
+    Neither has a slot axis, so neither has a trash slot: a token that
+    must not count is named by forward(token_mask=...) and leaves both
+    exactly as they were. A row starts from zeros.
     """
 
     k: jax.Array
@@ -301,14 +398,27 @@ class KVCache:
     index: jax.Array
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    state: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @classmethod
     def create(cls, cfg: ModelConfig, batch: int, max_len: int,
                trash_slot: bool = False,
                quantize_kv: bool = False) -> "KVCache":
         cache_len = max_len + 1 if trash_slot else max_len
-        shape = (cfg.num_layers, batch, cache_len, cfg.num_kv_heads,
-                 cfg.head_dim)
+        shape = (cfg.layers_of("full_attention"), batch, cache_len,
+                 cfg.num_kv_heads, cfg.head_dim)
+        recurrent = {}
+        if cfg.has_recurrent_state:
+            n_lin = cfg.layers_of("linear_attention")
+            recurrent = dict(
+                state=jnp.zeros(
+                    (n_lin, batch, cfg.linear_num_heads,
+                     cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                    jnp.float32),
+                conv=jnp.zeros(
+                    (n_lin, batch, cfg.linear_conv_kernel - 1,
+                     cfg.linear_conv_dim), cfg.activation_dtype))
         if quantize_kv:
             return cls(
                 k=jnp.zeros(shape, jnp.int8),
@@ -316,11 +426,13 @@ class KVCache:
                 index=jnp.zeros((), jnp.int32),
                 k_scale=jnp.zeros(shape[:-1], jnp.float32),
                 v_scale=jnp.zeros(shape[:-1], jnp.float32),
+                **recurrent,
             )
         return cls(
             k=jnp.zeros(shape, cfg.activation_dtype),
             v=jnp.zeros(shape, cfg.activation_dtype),
             index=jnp.zeros((), jnp.int32),
+            **recurrent,
         )
 
     @property
@@ -525,16 +637,23 @@ def _attention_block(
     # profiler capture; docs/observability.md): the compiled program and
     # its compile-cache key do not change.
     with jax.named_scope("attn.qkv"):
-        q = proj(p["wq"], "bq", "wq").reshape(b, s, cfg.num_heads,
-                                              cfg.head_dim)
-        k = proj(p["wk"], "bk", "wk").reshape(b, s, cfg.num_kv_heads,
-                                              cfg.head_dim)
-        v = proj(p["wv"], "bv", "wv").reshape(b, s, cfg.num_kv_heads,
-                                              cfg.head_dim)
+        full_norm = cfg.qk_norm and cfg.qk_norm_width == "full"
+
+        def heads(y, scale, n):
+            # The "full" QK norm runs over the whole projection, before
+            # the heads are split; the per-head one below, after.
+            if full_norm and scale is not None:
+                y = rms_norm(y, scale, cfg.norm_eps)
+            return y.reshape(b, s, n, cfg.head_dim)
+
+        q = heads(proj(p["wq"], "bq", "wq"), p.get("q_norm"), cfg.num_heads)
+        k = heads(proj(p["wk"], "bk", "wk"), p.get("k_norm"),
+                  cfg.num_kv_heads)
+        v = heads(proj(p["wv"], "bv", "wv"), None, cfg.num_kv_heads)
         q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
         k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
         v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
-        if cfg.qk_norm:
+        if cfg.qk_norm and cfg.qk_norm_width == "head":
             q = rms_norm(q, p["q_norm"], cfg.norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.position_type == "rope":
@@ -643,6 +762,72 @@ def _cached_attention(cfg: ModelConfig, q, k, v, positions, mask, bias):
     return out
 
 
+def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
+                            token_mask: Optional[jax.Array], layer_state):
+    """The gated-delta token mixer (ops/gated_delta.py) of one layer.
+    x [b, s, h]; token_mask [b, s] bool or None (all valid; a row's valid
+    tokens are a prefix of it); layer_state None (no cache: start from
+    zeros, keep nothing) or (state [b, H, d_k, d_v] f32, conv tail
+    [b, kernel-1, channels]). Returns (out [b, s, h], new layer_state)."""
+    from runbooks_tpu.ops.gated_delta import (
+        causal_conv,
+        gated_delta_chunked,
+        gated_delta_step,
+        l2_normalize,
+    )
+
+    b, s, _ = x.shape
+    ad = cfg.activation_dtype
+    f32 = jnp.float32
+    H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    kd = cfg.linear_key_dim
+    state, tail = layer_state if layer_state is not None else (None, None)
+
+    def small(w):   # [h, H] heads of the decay and the write strength
+        return jnp.einsum("...k,ko->...o", x, w.astype(ad),
+                          preferred_element_type=f32)
+
+    with jax.named_scope("linattn.proj"):
+        qkv = jnp.concatenate([_matmul(x, p["wq"], ad),
+                               _matmul(x, p["wk"], ad),
+                               _matmul(x, p["wv"], ad)], axis=-1)
+        gate = _matmul(x, p["wg"], ad)
+        g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
+            small(p["wa"]) + p["dt_bias"].astype(f32))
+        beta = jax.nn.sigmoid(small(p["wb"]))
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+    with jax.named_scope("linattn.conv"):
+        n_valid = (None if token_mask is None
+                   else jnp.sum(token_mask, axis=-1, dtype=jnp.int32))
+        qkv, tail = causal_conv(qkv, p["conv"], tail, n_valid)
+    with jax.named_scope("linattn.core"):
+        q = qkv[..., :kd].reshape(b, s, H, dk)
+        k = qkv[..., kd:2 * kd].reshape(b, s, H, dk)
+        v = qkv[..., 2 * kd:].reshape(b, s, H, dv)
+        q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
+        k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
+        v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
+        q = (l2_normalize(q) * dk ** -0.5).astype(ad)
+        k = l2_normalize(k).astype(ad)
+        if layer_state is not None and s == 1:
+            # Decode: one recurrent step a row.
+            o, state = gated_delta_step(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
+                None if token_mask is None else token_mask[:, 0])
+            o = o[:, None].astype(ad)
+        else:
+            o, state = gated_delta_chunked(q, k, v, g, beta, state,
+                                           token_mask)
+    with jax.named_scope("linattn.out"):
+        # One norm weight of d_v, shared by the heads; then the output gate.
+        o = rms_norm(o, p["o_norm"], cfg.norm_eps)
+        o = o * jax.nn.silu(gate.reshape(b, s, H, dv))
+        out = _matmul(o.reshape(b, s, H * dv), p["wo"], ad)
+    return out, (None if layer_state is None else (state, tail))
+
+
 def _mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
                adapter=None) -> jax.Array:
     ad = cfg.activation_dtype
@@ -699,10 +884,27 @@ def _adapter_group(adapter, group: str):
 
 
 def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
-           bias, layer_cache, adapter=None):
+           bias, layer_cache, adapter=None, token_mask=None,
+           kind: str = "full_attention"):
     """One transformer block. x: [b, s, h]. Returns (x, cache, aux).
     ``adapter``: None or (per-layer adapter-pool slice, lane indices) —
-    the grouped LoRA injection (docs/multi-tenant-lora.md)."""
+    the grouped LoRA injection (docs/multi-tenant-lora.md). ``kind`` names
+    the token mixer (ModelConfig.layer_types); a linear-attention layer's
+    ``layer_cache`` is its (state, conv tail) and ``token_mask`` says
+    which tokens may change it."""
+
+    def mixer(h_in):
+        # The linear mixer runs inside the `attn` scope too, under inner
+        # linattn.* scopes: `attn` means "the token mixer" to every reader
+        # of a capture (docs/observability.md).
+        with jax.named_scope("attn"):
+            if kind == "linear_attention":
+                return _linear_attention_block(
+                    cfg, layer["mixer"], h_in, token_mask, layer_cache)
+            return _attention_block(
+                cfg, layer["attn"], h_in, positions, segment_ids, mask, bias,
+                layer_cache, adapter=_adapter_group(adapter, "attn"))
+
     # Scopes: everything a layer does is under `block`; inside it `norm`,
     # `attn` (with its attn.* parts) and `ffn`; what is left directly
     # under `block` is the residual adds.
@@ -710,12 +912,25 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
         act_rules = _act_embed_rules(resolve_collective_matmul(cfg))
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
                                     rules=act_rules)
+        mlp_adapter = _adapter_group(adapter, "mlp")
+        if cfg.norm_position == "post":
+            # Reordered norm: x + norm(f(x)), for both sub-layers.
+            attn_out, new_cache = mixer(x)
+            attn_out = checkpoint_name(attn_out, "attn_out")
+            with jax.named_scope("norm"):
+                attn_out = _norm(cfg, layer["ln1"], attn_out)
+            x = x + attn_out
+            with jax.named_scope("ffn"):
+                ffn_out, aux = _ffn_block(cfg, layer, x, adapter=mlp_adapter)
+            with jax.named_scope("norm"):
+                ffn_out = _norm(cfg, layer["ln2"], ffn_out)
+            x = x + ffn_out
+            x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
+                                        rules=act_rules)
+            return x, new_cache, aux
         with jax.named_scope("norm"):
             h1 = _norm(cfg, layer["ln1"], x)
-        with jax.named_scope("attn"):
-            attn_out, new_cache = _attention_block(
-                cfg, layer["attn"], h1, positions, segment_ids, mask, bias,
-                layer_cache, adapter=_adapter_group(adapter, "attn"))
+        attn_out, new_cache = mixer(h1)
         # Named checkpoint for selective remat: remat_policy=
         # "save_attn_out" saves this [b, s, h] tensor (plus the flash
         # kernel's hoisted "attn_context"/"attn_lse" residuals — see
@@ -723,7 +938,6 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
         # O(s^2) flash fwd kernel, while activations stay
         # O(layers * b * s * h) instead of the dots_saveable blow-up.
         attn_out = checkpoint_name(attn_out, "attn_out")
-        mlp_adapter = _adapter_group(adapter, "mlp")
         if cfg.parallel_block:
             if cfg.shared_layer_norm:
                 h2 = h1
@@ -764,6 +978,7 @@ def forward(
     with_aux: bool = False,
     return_activations: bool = False,
     adapters=None,
+    token_mask: Optional[jax.Array] = None,  # [b, s] bool
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Returns (logits [b, s, vocab] float32, updated cache or None) — or,
     with_aux=True, (logits, cache, aux) where aux is the summed per-layer
@@ -795,9 +1010,19 @@ def forward(
     targeted projection adds its row's ``(x @ A) @ B`` delta — one
     program for any tenant mix. Not supported on the pipeline (stage >
     1) path.
+
+    token_mask (models with linear-attention layers; ignored by the
+    others): which tokens are real. A masked-out token leaves a row's
+    recurrent state and conv tail exactly as they were (a bucket's padding
+    behind a prompt, a parked row of a decode batch). A row's real tokens
+    must be a prefix of it. None = all real. The full-attention layers
+    keep their own rule: padding is parked by *position* (the trash slot).
     """
     b, s = tokens.shape
     ad = cfg.activation_dtype
+    pattern = cfg.layer_pattern
+    if cfg.has_recurrent_state:
+        _check_recurrent_support(cfg, segment_ids, adapters)
 
     if cache is not None and segment_ids is not None:
         raise NotImplementedError(
@@ -876,11 +1101,14 @@ def forward(
                    - positions[:, :, None]).astype(jnp.float32)
             bias = slopes[None, :, None, None] * rel[:, None, :, :]
 
-    block = _block
+    blocks = {kind: (_block if kind == "full_attention"
+                     else functools.partial(_block, kind=kind))
+              for kind in set(pattern)}
     if remat and cfg.remat_policy != "none":
-        block = jax.checkpoint(
-            _block, policy=_remat_policy(cfg.remat_policy),
-            static_argnums=(0,))
+        blocks = {kind: jax.checkpoint(
+            fn, policy=_remat_policy(cfg.remat_policy), static_argnums=(0,))
+            for kind, fn in blocks.items()}
+    block = blocks["full_attention"]
 
     apool = aidx = None
     if adapters is not None:
@@ -889,42 +1117,61 @@ def forward(
         apool, aidx = adapters
         aidx = map_lane_indices(jnp.asarray(aidx), pool_lanes(apool))
 
+    # One scan step is one PERIOD of the layer pattern: its one
+    # full-attention layer (params["layers"], scanned as it lies, so a
+    # homogeneous model's program is what it was before patterns) and its
+    # linear-attention layers, each position's stack scanned the same
+    # way. The cache's recurrent leaves, stacked in layer order
+    # [L_lin, …], are scanned as [periods, per period, …].
+    n_lin = pattern.count("linear_attention")
+
     def scan_body(carry, scanned):
         x, aux_sum = carry
-        if apool is not None:
-            *scanned, pool_layer = scanned
-            adapter = (pool_layer, aidx)
-        else:
-            adapter = None
-        if cache is not None:
-            layer, ck, cv, ck_s, cv_s = scanned
-            layer_cache = (ck, cv, ck_s, cv_s,
-                           None if scatter_mode else cache.index,
-                           cache_view)
-        else:
-            (layer,) = scanned if apool is not None else (scanned,)
-            layer_cache = None
-        x, new_cache, aux = block(cfg, layer, x, positions, segment_ids,
-                                  mask, bias, layer_cache, adapter)
-        return (x, aux_sum + aux), new_cache
+        layers, ck, cv, ck_s, cv_s, pool_layer, lin_layers, st, cn = scanned
+        adapter = None if apool is None else (pool_layer, aidx)
+        new_kv, new_rec = None, []
+        for kind in pattern:
+            if kind == "full_attention":
+                layer_cache = None
+                if cache is not None:
+                    layer_cache = (ck, cv, ck_s, cv_s,
+                                   None if scatter_mode else cache.index,
+                                   cache_view)
+                x, new_kv, aux = block(
+                    cfg, layers, x, positions, segment_ids, mask, bias,
+                    layer_cache, adapter)
+            else:
+                i = len(new_rec)
+                layer_cache = (None if cache is None
+                               else _period_pick((st, cn), n_lin, i))
+                x, new, aux = blocks[kind](
+                    cfg, lin_layers[i], x, positions, segment_ids, mask,
+                    bias, layer_cache, None, token_mask)
+                new_rec.append(new)
+            aux_sum = aux_sum + aux
+        return (x, aux_sum), (new_kv, _period_stack(new_rec, n_lin))
 
+    layers, lin_layers = params["layers"], params.get("linear_layers")
     aux_total = jnp.zeros((), jnp.float32)
     if cache is not None:
         # k_scale/v_scale are None (empty pytrees) for an unquantized
-        # cache; scan threads them through untouched either way. The
+        # cache, as state/conv are for a model without linear-attention
+        # layers; scan threads them through untouched either way. The
         # adapter pool (leading L axis) rides the same scan when given.
-        xs = (params["layers"], cache.k, cache.v,
-              cache.k_scale, cache.v_scale)
-        if apool is not None:
-            xs = xs + (apool,)
+        xs = (layers, cache.k, cache.v, cache.k_scale, cache.v_scale,
+              apool, lin_layers,
+              *_period_split((cache.state, cache.conv), n_lin))
         # `layers`: the scan itself (slices of the stacked weights, what
         # the compiler hoists out of the loop); each layer is a `block`.
         with jax.named_scope("layers"):
-            (x, aux_total), (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
+            (x, aux_total), (new_kv, new_rec) = jax.lax.scan(
                 scan_body, (x, aux_total), xs)
+        new_k, new_v, new_ks, new_vs = new_kv
+        new_state, new_conv = _period_merge(new_rec or (None, None), n_lin)
         new_index = cache.index if scatter_mode else cache.index + s
         new_cache = KVCache(k=new_k, v=new_v, index=new_index,
-                            k_scale=new_ks, v_scale=new_vs)
+                            k_scale=new_ks, v_scale=new_vs,
+                            state=new_state, conv=new_conv)
     else:
         from runbooks_tpu.parallel.sharding import _current_mesh
 
@@ -937,6 +1184,11 @@ def forward(
                     "adapter pools are not supported on the pipeline "
                     "(stage > 1) path; serve adapters with tensor/data "
                     "parallelism (docs/multi-tenant-lora.md)")
+            if len(pattern) > 1:
+                raise NotImplementedError(
+                    "a layer pattern is not supported on the pipeline "
+                    "(stage > 1) path: its stages split one homogeneous "
+                    "stack (parallel/pipeline.py)")
             # Pipeline-parallel path: same block, stacked layers sharded
             # over the stage axis, activations ppermuted between stages
             # (parallel/pipeline.py).
@@ -954,8 +1206,8 @@ def forward(
                     mesh=mesh, n_stages=n_stages,
                     n_microbatches=cfg.pipeline_microbatches or None)
         else:
-            xs = (params["layers"] if apool is None
-                  else (params["layers"], apool))
+            xs = (layers, None, None, None, None, apool, lin_layers,
+                  None, None)
             with jax.named_scope("layers"):
                 (x, aux_total), _ = jax.lax.scan(
                     scan_body, (x, aux_total), xs)
@@ -970,17 +1222,82 @@ def forward(
         if with_aux:
             return x, new_cache, aux_total
         return x, new_cache
+    logits = project_logits(cfg, params, x)
+    if with_aux:
+        return logits, new_cache, aux_total
+    return logits, new_cache
+
+
+def project_logits(cfg: ModelConfig, params: Params,
+                   x: jax.Array) -> jax.Array:
+    """Float32 logits [..., vocab] of post-final-norm activations
+    x [b, s, h] — or [b, h]: the serving prefill projects only each row's
+    last prompt position (forward(return_activations=True), one gather,
+    this), so the [rows, bucket, vocab] tensor is never made."""
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     # bf16 operands + f32 accumulation: the MXU accumulates in f32 either
     # way, but f32 operands run at 1/4 the bf16 MXU rate on v5e/v5p.
     with jax.named_scope("head"):
-        logits = jnp.einsum("bsh,hv->bsv", x.astype(cfg.activation_dtype),
+        logits = jnp.einsum("...h,hv->...v", x.astype(cfg.activation_dtype),
                             head.astype(cfg.activation_dtype),
                             preferred_element_type=jnp.float32)
-        logits = with_logical_constraint(logits, ("batch", "seq", None))
-    if with_aux:
-        return logits, new_cache, aux_total
-    return logits, new_cache
+        seq = ("seq",) * (x.ndim - 2)
+        logits = with_logical_constraint(logits, ("batch", *seq, None))
+    return logits
+
+
+def _check_recurrent_support(cfg: ModelConfig, segment_ids, adapters):
+    """What a model with linear-attention layers cannot do yet, by name
+    (ROADMAP.md M5 / M7)."""
+    if segment_ids is not None:
+        raise NotImplementedError(
+            "packed sequences (segment_ids) with linear-attention layers "
+            "need the recurrent state reset at document boundaries, and "
+            "training on them the chunked scan's backward; neither is "
+            "written (ops/gated_delta.py, ROADMAP.md M7)")
+    if adapters is not None:
+        raise NotImplementedError(
+            "adapter pools target the attention projections of a "
+            "homogeneous stack; a layer pattern has no pooled path "
+            "(docs/hybrid-models.md)")
+    from runbooks_tpu.parallel.sharding import _current_mesh
+
+    mesh = _current_mesh()
+    tensor = int(mesh.shape.get("tensor", 1)) if mesh is not None else 1
+    if cfg.linear_num_heads % tensor:
+        raise NotImplementedError(
+            f"a tensor mesh of {tensor} does not divide the "
+            f"{cfg.linear_num_heads} linear-attention heads: the recurrent "
+            "state shards by head (docs/hybrid-models.md)")
+
+
+def _period_split(tree, n: int):
+    """[layers of a kind, …] leaves -> [periods, n, …] (as they lie when
+    n <= 1: nothing of that kind, or one a period)."""
+    if n <= 1:
+        return tree
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), tree)
+
+
+def _period_pick(tree, n: int, i: int):
+    """Layer i of its kind inside one period's slice."""
+    return tree if n <= 1 else jax.tree.map(lambda a: a[i], tree)
+
+
+def _period_stack(items: list, n: int):
+    """What the layers of a kind return in one period -> one scan output."""
+    if n <= 1:
+        return items[0] if items else None
+    return jax.tree.map(lambda *a: jnp.stack(a), *items)
+
+
+def _period_merge(tree, n: int):
+    """Scan outputs [periods, n, …] -> [layers of the kind, …]."""
+    if n <= 1:
+        return tree
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] * n,) + a.shape[2:]), tree)
 
 
 def loss_and_grads_1f1b(
@@ -1013,6 +1330,10 @@ def loss_and_grads_1f1b(
     n_stages = int(mesh.shape.get("stage", 1)) if mesh is not None else 1
     if n_stages <= 1:
         raise ValueError("loss_and_grads_1f1b needs a mesh with stage > 1")
+    if len(cfg.layer_pattern) > 1:
+        raise NotImplementedError(
+            "a layer pattern is not supported on the pipeline (stage > 1) "
+            "path: its stages split one homogeneous stack")
     b, s = tokens.shape
     ad = cfg.activation_dtype
     M = cfg.pipeline_microbatches or n_stages

@@ -656,6 +656,8 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
                                  grammar=grammar,
                                  grammar_cache_size=grammar_cache_size,
                                  tokenizer=tokenizer)
+    if auto_prefix_chat:
+        engine._refuse_prefix()
     if warmup:
         # Pre-compile all buckets before readiness flips. warm_prefix
         # (params.json: warm_prefix) additionally compiles the prefix-KV
@@ -784,6 +786,12 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
                       help_text="KV pool HBM bytes each device holds "
                                 "(its shard under the serving mesh; "
                                 "equals the aggregate unsharded).")
+        reg.set_gauge("serve_recurrent_state_bytes",
+                      occ.get("recurrent_state_bytes", 0),
+                      help_text="Recurrent state and conv tails of "
+                                "linear-attention layers, all slots "
+                                "(0 without such layers); apart from "
+                                "serve_kv_pool_bytes.")
         reg.set_counter("serve_prefix_lookups_total", eng.prefix_lookups,
                         help_text="Admissions that checked the shared-"
                                   "prefix cache.")

@@ -24,6 +24,10 @@ inference is in-framework and TPU-shaped:
   per request.
 - Per-slot cache writes use the transformer's position-scatter mode with a
   trash slot for padding (see models/transformer.KVCache).
+- A model whose layer pattern has recurrent (linear-attention) layers keeps
+  a fixed-size state a slot beside the K/V rows. It has no trash slot, so
+  the programs mask, freeze and reset it themselves: the invariant is
+  written out in make_prefill_fn (docs/hybrid-models.md).
 - Sampling is jitted with per-slot temperature/top_k/top_p so mixed request
   parameters batch together.
 - Quantized fast path: params may be weight-only int8/int4
@@ -47,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from runbooks_tpu.models.config import ModelConfig
-from runbooks_tpu.models.transformer import KVCache, forward
+from runbooks_tpu.models.transformer import KVCache, forward, project_logits
 from runbooks_tpu.obs import device as obs_device
 from runbooks_tpu.obs import flight as obs_flight
 from runbooks_tpu.obs import metrics as obs_metrics
@@ -262,13 +266,36 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
                    gmask=None):
         # Prefill `rows` requests into fresh zero rows at once, then
         # splice each row into the pool cache (donated => in-place, no
-        # full-cache copy). Stale data from a slot's previous occupant
-        # needs no clearing: this request's queries only ever attend
-        # slots <= their own position, all of which this prefill/decode
-        # has (re)written. Padding rows (beyond the real requests)
+        # full-cache copy). Padding rows (beyond the real requests)
         # carry slots[0] as their destination; the splice loop runs in
         # DESCENDING row order so the real row 0 is written last and
         # overwrites any padding garbage at that slot.
+        #
+        # The invariant, by kind of per-slot state:
+        #
+        # Keys and values (full-attention layers). Stale data from a
+        # slot's previous occupant needs no clearing: this request's
+        # queries only ever attend slots <= their own position, all of
+        # which this prefill/decode has (re)written; pad tokens land in
+        # the trash slot, which no real query ever attends.
+        #
+        # Recurrent state and conv tail (linear-attention layers; pool
+        # leaves `state` / `conv`). They have no slot axis, so no trash
+        # slot, and every token that reaches them changes the answer.
+        # The programs therefore keep four rules themselves:
+        #  (a) reset: every scratch row starts from ZERO state and tail,
+        #      and both are spliced into the pool at the slot with the
+        #      K/V rows — a slot's previous occupant cannot leak;
+        #  (b) mask: tokens of a bucket beyond a row's last real one (and
+        #      whole padding rows) are named by `token_mask` and leave
+        #      state and tail exactly as the last real token left them
+        #      (decay 1, write strength 0, tail taken at the real length);
+        #  (c) freeze: in the decode chunk a row that is not `alive`
+        #      keeps its state bit for bit (make_decode_fn);
+        #  (d) `cache_view` slices only k / v.
+        # Nothing that rewinds a cursor or shares K/V between requests is
+        # sound for such a layer: InferenceEngine refuses speculation,
+        # prefix registration, adapter pools and paging for these models.
         #
         # First-token sampling lives INSIDE the jit: an eager sampling
         # chain here compiled ~20 tiny programs at the first admission
@@ -276,13 +303,10 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # One dispatch also means one host round-trip per admission
         # group. rng advances functionally (split in, successor out).
         rows = tokens.shape[0]
-        row_shape = (cfg.num_layers, rows, cache_len, cfg.num_kv_heads,
-                     cfg.head_dim)
         # Scratch rows stay in the activation dtype even when the pool
         # is int8: prefill attention then runs at full precision, and
         # each row is quantized exactly once at the splice below.
-        k1 = jnp.zeros(row_shape, cfg.activation_dtype)
-        v1 = jnp.zeros(row_shape, cfg.activation_dtype)
+        cache1 = KVCache.create(cfg, rows, cache_len)
         if pk is not None:
             # Shared-prefix reuse: the registered prefix's K/V
             # [L, plen, kv_h, d] lands in slots [0, plen) of every
@@ -290,15 +314,27 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
             # could wrongly attend), and `tokens` holds only the
             # SUFFIX, positions starting at plen.
             plen = pk.shape[1]
-            k1 = k1.at[:, :, :plen].set(
-                pk[:, None].astype(cfg.activation_dtype))
-            v1 = v1.at[:, :, :plen].set(
-                pv[:, None].astype(cfg.activation_dtype))
-        cache1 = KVCache(k=k1, v=v1, index=jnp.zeros((), jnp.int32))
+            cache1 = dataclasses.replace(
+                cache1,
+                k=cache1.k.at[:, :, :plen].set(
+                    pk[:, None].astype(cfg.activation_dtype)),
+                v=cache1.v.at[:, :, :plen].set(
+                    pv[:, None].astype(cfg.activation_dtype)))
         adapters = None if apool is None else (apool, aslots)
-        logits, cache1 = forward(cfg, params, tokens,
-                                 positions=positions, cache=cache1,
-                                 adapters=adapters)
+        # Rule (b): padding is parked at the trash slot, cache_len - 1.
+        token_mask = (positions < cache_len - 1
+                      if cfg.has_recurrent_state else None)
+        # The head runs on each row's LAST prompt position only: the
+        # sampled token's logits are the same numbers, and the
+        # [rows, bucket, vocab] float32 tensor (6.6 GB at 16 384 tokens of
+        # a 100 352 vocabulary) is never made.
+        acts, cache1 = forward(cfg, params, tokens,
+                               positions=positions, cache=cache1,
+                               adapters=adapters, token_mask=token_mask,
+                               return_activations=True)
+        last_acts = jnp.take_along_axis(
+            acts, last_pos[:, None, None], axis=1)[:, 0]
+        last_logits = project_logits(cfg, params, last_acts)
         with jax.named_scope("kv_splice"):
             if pool.k.dtype == jnp.int8:
                 from runbooks_tpu.ops.quantization import quantize_kv
@@ -320,13 +356,22 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
                         new_ks, rows_ks[:, r:r + 1], slots[r], axis=1)
                     new_vs = jax.lax.dynamic_update_slice_in_dim(
                         new_vs, rows_vs[:, r:r + 1], slots[r], axis=1)
+            new_state, new_conv = pool.state, pool.conv
+            if new_state is not None:
+                with jax.named_scope("state_splice"):    # rule (a)
+                    for r in range(rows - 1, -1, -1):
+                        new_state = jax.lax.dynamic_update_slice_in_dim(
+                            new_state, cache1.state[:, r:r + 1], slots[r],
+                            axis=1)
+                        new_conv = jax.lax.dynamic_update_slice_in_dim(
+                            new_conv, cache1.conv[:, r:r + 1], slots[r],
+                            axis=1)
         rng, sub = jax.random.split(rng)
-        last_logits = jnp.take_along_axis(
-            logits, last_pos[:, None, None], axis=1)[:, 0]
         first = sample(last_logits, sub, temps, top_ks, top_ps,
                        gmask=gmask)
         new_pool = KVCache(k=new_k, v=new_v, index=pool.index,
-                           k_scale=new_ks, v_scale=new_vs)
+                           k_scale=new_ks, v_scale=new_vs,
+                           state=new_state, conv=new_conv)
         return first, new_pool, rng
 
     return prefill_fn
@@ -377,9 +422,13 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
         def body(carry, key):
             cache, tok, pos, alive, emitted = carry
             p = jnp.where(alive, pos, pad_slot)
-            logits, cache = forward(cfg, params, tok[:, None],
-                                    positions=p[:, None], cache=cache,
-                                    cache_view=view, adapters=adapters)
+            # Rule (c) of make_prefill_fn's invariant: a parked row's
+            # recurrent state does not move.
+            logits, cache = forward(
+                cfg, params, tok[:, None], positions=p[:, None],
+                cache=cache, cache_view=view, adapters=adapters,
+                token_mask=(alive[:, None] if cfg.has_recurrent_state
+                            else None))
             nxt = sample(logits[:, -1], key, temperature, top_k, top_p,
                          gmask=gmask)
             nxt = jnp.where(alive, nxt, tok)
@@ -513,6 +562,10 @@ class InferenceEngine:
     # paged engine has pages, so the dense constructor rejects
     # preemption="swap" (serve/paging.py flips this).
     _supports_preemption = False
+    # Per-slot recurrent state (a layer pattern with linear-attention
+    # layers) lives beside the dense slot pool's K/V rows; a page table
+    # has one kind of page (serve/paging.py flips this).
+    _supports_recurrent_state = True
 
     def __init__(self, cfg: ModelConfig, params: Params, *,
                  max_slots: int = 8, max_seq_len: Optional[int] = None,
@@ -670,6 +723,25 @@ class InferenceEngine:
             quantize_kv = (cfg.quantize_kv if cfg.quantize_kv is not None
                            else cfg.quantize != "none")
         self.quantize_kv = bool(quantize_kv)
+        # What the recurrent-state invariant (make_prefill_fn) rules out,
+        # refused here with the reason: nothing below would fail loudly.
+        if self.speculative != "off":
+            self._refuse_recurrent(
+                "speculative decoding",
+                "a rejected draft is rolled back by not advancing the K/V "
+                "cursor, and a recurrent state has no cursor to hold back")
+        if int(adapter_pool if adapter_pool is not None
+               else cfg.adapter_pool) > 0:
+            self._refuse_recurrent(
+                "an adapter pool",
+                "pooled LoRA lanes target the attention projections of a "
+                "homogeneous stack")
+        if not self._supports_recurrent_state:
+            self._refuse_recurrent(
+                "kv_paging: paged",
+                "a page table has one kind of page and the radix tree "
+                "shares K/V pages only; the state after a shared prefix "
+                "would have to be snapshotted with them")
         if mesh is not None:
             import contextlib
 
@@ -687,10 +759,12 @@ class InferenceEngine:
                                quantized_logical_axes(
                                    params, param_logical_axes(cfg)), mesh))
 
-            def cache_sharding(shape):
+            def cache_sharding(shape, logical=None):
                 # k/v are 5-d [L, batch, slot, kv_heads, d]; the int8
                 # cache's scale arrays are 4-d [L, batch, slot, kv_heads].
-                logical = (None, "batch", None, "act_heads", None)[:len(shape)]
+                if logical is None:
+                    logical = (None, "batch", None, "act_heads",
+                               None)[:len(shape)]
                 spec = spec_for_array(shape, logical, mesh)
                 return NamedSharding(mesh, spec)
 
@@ -957,19 +1031,35 @@ class InferenceEngine:
         cache = KVCache.create(self.cfg, self.max_slots, self.max_seq_len,
                                trash_slot=True, quantize_kv=self.quantize_kv)
         if self._cache_sharding is not None:
-            def put(a):
-                return (None if a is None
-                        else jax.device_put(a, self._cache_sharding(a.shape)))
+            def put(a, logical=None):
+                return (None if a is None else jax.device_put(
+                    a, self._cache_sharding(a.shape, logical)))
 
             # index is committed too (the scalar's spec resolves to
             # replicated): a dispatch RETURNS it committed, so a fresh
             # uncommitted one would key a second jit entry and the first
             # prefill after every reset() would recompile under traffic.
+            # The recurrent state [L, batch, heads, d_k, d_v] shards by
+            # head; the conv tail (q | k | v channels side by side) does
+            # not split on a head boundary and stays whole.
             cache = KVCache(k=put(cache.k), v=put(cache.v),
                             index=put(cache.index),
                             k_scale=put(cache.k_scale),
-                            v_scale=put(cache.v_scale))
+                            v_scale=put(cache.v_scale),
+                            state=put(cache.state, (None, "batch",
+                                                    "act_heads", None, None)),
+                            conv=put(cache.conv,
+                                     (None, "batch", None, None)))
         return cache
+
+    def _refuse_recurrent(self, feature: str, why: str) -> None:
+        """Refuse, for a model with recurrent (linear-attention) layers, a
+        feature that is only sound for keys and values
+        (docs/hybrid-models.md)."""
+        if self.cfg.has_recurrent_state:
+            raise ValueError(
+                f"{feature} is not supported for a model with recurrent "
+                f"(linear-attention) layers: {why}")
 
     def _adapter_kwargs(self, aslots=None) -> dict:
         """Extra operands for adapter-aware dispatches: the pool pytree
@@ -1110,6 +1200,8 @@ class InferenceEngine:
         so a runtime /v1/prefix registration never compiles on the
         serving thread; start servers that register prefixes under
         traffic with this on (costs len(buckets) extra warmup compiles)."""
+        if prefix_build:
+            self._refuse_prefix()
         if rows is None:
             rows = (1, self.max_slots) if self.max_slots > 1 else (1,)
         n_prefix = n_prefill = 0
@@ -1262,6 +1354,13 @@ class InferenceEngine:
 
     # -- shared-prefix cache -------------------------------------------
 
+    def _refuse_prefix(self) -> None:
+        self._refuse_recurrent(
+            "prefix registration (register_prefix, auto_prefix_chat, "
+            "warm_prefix)",
+            "a shared prefix splices K/V only; the recurrent state after "
+            "the prefix would have to be stored and restored with it")
+
     def _prefix_len_for(self, n: int, quantize: bool = False) -> int:
         """Usable prefix length for an n-token prompt. Explicit
         registrations (rare, usually pre-traffic) round to a multiple of
@@ -1305,6 +1404,7 @@ class InferenceEngine:
         the TTFT killer (measured: the uncompiled prefix path turned a
         79 ms CPU p50 into 4.7 s). Registration is one-time per prefix
         shape; do it before traffic."""
+        self._refuse_prefix()
         plen = self._prefix_len_for(len(tokens))
         if plen < 16:
             return 0
@@ -1341,6 +1441,7 @@ class InferenceEngine:
         — the serving worker does exactly that).
 
         Returns the cached length (0 = too short / already cached)."""
+        self._refuse_prefix()
         plen = self._prefix_len_for(len(tokens), quantize=True)
         if plen < 16:
             return 0
@@ -1554,6 +1655,11 @@ class InferenceEngine:
         arrays = [a for a in (self.cache.k, self.cache.v,
                               self.cache.k_scale, self.cache.v_scale)
                   if a is not None]
+        # Apart from the K/V pool: the recurrent state and conv tails of
+        # linear-attention layers, fixed a slot whatever its tokens (0
+        # for a model without such layers).
+        recurrent = [a for a in (self.cache.state, self.cache.conv)
+                     if a is not None]
         return {"slots_total": self.max_slots,
                 "slots_active": int(self.active.sum()),
                 "kv_tokens": tokens,
@@ -1561,6 +1667,8 @@ class InferenceEngine:
                 "kv_pool_bytes": sum(int(a.nbytes) for a in arrays),
                 "kv_pool_bytes_per_device":
                     sum(obs_device.shard_local_nbytes(a) for a in arrays),
+                "recurrent_state_bytes":
+                    sum(int(a.nbytes) for a in recurrent),
                 "occupancy_ratio": (tokens / capacity) if capacity else 0.0}
 
     def memory_groups(self) -> dict:
@@ -1573,6 +1681,11 @@ class InferenceEngine:
         groups = {"weights": self.params,
                   "kv_cache": self.cache,
                   "prefix_cache": list(self._prefix_cache.copy().values())}
+        if self.cfg.has_recurrent_state:
+            # K/V and the recurrent state are reported apart.
+            groups["kv_cache"] = dataclasses.replace(
+                self.cache, state=None, conv=None)
+            groups["recurrent_state"] = (self.cache.state, self.cache.conv)
         if self.adapters is not None:
             groups["adapter_pool"] = self.adapters.tree
         return groups

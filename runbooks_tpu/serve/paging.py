@@ -1150,6 +1150,7 @@ class PagedInferenceEngine(InferenceEngine):
     # Pages are the unit a preempted slot's state swaps at, so only the
     # paged engine supports preemption="swap" (serve/engine.py gates).
     _supports_preemption = True
+    _supports_recurrent_state = False
 
     def __init__(self, cfg: ModelConfig, params: Params, *,
                  page_size: int = 16, num_pages: Optional[int] = None,

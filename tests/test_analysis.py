@@ -583,6 +583,38 @@ def test_repo_audits_clean_with_zero_compiles():
     assert ("serve", "prefill") in names
     assert ("train", "train_step") in names
     assert ("train", "lora_step") in names
+    assert ("serve", "hybrid_prefill") in names
+    assert ("serve", "hybrid_decode") in names
+
+
+def test_hybrid_programs_are_audited_and_the_rest_of_the_census_stands():
+    """A layer pattern's prefill and decode programs are traced with the
+    recurrent cache leaves beside k / v; every row the census had before
+    them (PR 25's committed baseline, by digest) is what it was."""
+    import hashlib
+    import json
+
+    from runbooks_tpu.analysis.program import (
+        AuditSettings,
+        _engine_specs,
+        load_program_baseline,
+    )
+
+    specs = {s["name"]: s for s in _engine_specs(AuditSettings())}
+    for name in ("hybrid_prefill", "hybrid_decode"):
+        pool = specs[name]["args"][1]
+        assert pool.state.shape[0] == 3 and pool.state.dtype == "float32"
+        assert pool.conv.shape[2] == 3 and pool.k.shape[0] == 1
+        assert isinstance(specs[name]["args"][0]["linear_layers"], list)
+    assert specs["prefill"]["args"][1].state is None
+    base = load_program_baseline(os.path.join(
+        _repo_root(), "config", "program_baseline.json"))
+    old = [p for p in base["programs"]
+           if not p["name"].startswith("hybrid_")]
+    assert len(old) == 31 and len(base["programs"]) == 33
+    assert hashlib.sha256(json.dumps(old, sort_keys=True).encode()
+                          ).hexdigest() == (
+        "e04d823ec805a6e339114d31d41529ec8ada425ff7e3c2d51b4302317a6db011")
 
 
 def test_program_baseline_roundtrip(tmp_path):
