@@ -1,0 +1,211 @@
+"""Read a compiled program's text for cache-sized work inside its loops.
+
+A decode step has to read the weights, read the view of K/V it attends and
+write one token a layer. What it must not do is move the KV pool or a whole
+layer of it from one buffer to another inside a loop (the decode chunk's
+loop over steps, forward's loop over layers): that is bytes moved, not
+work, and it scales with the pool (PERF.md §6, PR 27). Whether it does is a
+property of the compiled program, so it is read there:
+``jax.jit(f).lower(...).compile().as_text()``.
+
+``pool_sized_loop_ops(text, cache)`` returns the instructions inside any
+``while`` body (nested bodies and called computations included) whose
+result has the shape of a whole cache leaf, or of one layer of ``k`` / ``v``
+/ their scales, and that are not an in-place update of a small part:
+
+- allowed: ``dynamic-update-slice`` and ``scatter`` (alone, or as the root
+  of a fusion) whose operand is the loop's own buffer and whose update is
+  smaller than a layer of the leaf written (for ``state`` / ``conv``: one
+  layer, which changes whole every step);
+- allowed: what moves no bytes (``parameter``, ``tuple``,
+  ``get-tuple-element``, ``bitcast``, ``while``, ``call``,
+  ``conditional``, ``optimization-barrier``, the TPU compiler's
+  ``AllocateBuffer``);
+- everything else of such a shape is reported: ``copy``, ``dynamic-slice``
+  of a whole layer, a ``dynamic-update-slice`` that writes a whole layer
+  back, a ``select`` or ``convert`` over the pool.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import re
+from typing import Dict, List, Optional, Tuple
+
+_COMP = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_INSTR = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = (.*)$")
+_SHAPE = re.compile(r"^([a-z]\w*)\[([\d,]*)\]")
+_OPCODE = re.compile(r"^([a-z][\w\-]*)\(")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|true_computation|false_computation)="
+    r"%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+_FREE = frozenset({
+    "parameter", "tuple", "get-tuple-element", "bitcast", "while", "call",
+    "conditional", "optimization-barrier", "constant", "after-all"})
+_IN_PLACE = frozenset({"dynamic-update-slice", "scatter"})
+
+
+@dataclasses.dataclass
+class Instr:
+    name: str
+    dtype: Optional[str]          # None for a tuple result
+    dims: Tuple[int, ...]
+    opcode: str
+    operands: List[str]
+    called: List[str]
+    root: bool
+    line: str
+
+    @property
+    def size(self) -> int:
+        return math.prod(self.dims)
+
+
+def _closing(text: str, depth: int = 0) -> int:
+    """Index of the parenthesis that brings ``depth`` back to zero."""
+    for i, ch in enumerate(text):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return i
+    return len(text)
+
+
+def _split_shape(rest: str) -> Tuple[Optional[str], Tuple[int, ...], str]:
+    """(dtype, dims, what follows the result shape) of an instruction's
+    right-hand side; a tuple shape gives (None, (), …)."""
+    if rest.startswith("("):
+        return None, (), rest[_closing(rest) + 1:].lstrip()
+    m = _SHAPE.match(rest)
+    if not m:
+        return None, (), rest
+    dims = tuple(int(d) for d in m.group(2).split(",") if d)
+    after = rest[m.end():]
+    if after.startswith("{"):          # layout, tiling, memory space
+        after = after[after.index("}") + 1:]
+    return m.group(1), dims, after.lstrip()
+
+
+def parse(text: str) -> Dict[str, List[Instr]]:
+    """Computation name -> its instructions, from ``as_text()``."""
+    comps: Dict[str, List[Instr]] = {}
+    cur: Optional[List[Instr]] = None
+    for line in text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _INSTR.match(line) if cur is not None else None
+        if not m:
+            continue
+        dtype, dims, after = _split_shape(m.group(3))
+        op = _OPCODE.match(after)
+        if not op:
+            continue
+        args = after[op.end():]
+        end = _closing(args, 1)
+        attrs = args[end:]
+        called = _CALLED.findall(attrs)
+        for group in _BRANCHES.findall(attrs):
+            called += [c.strip().lstrip("%") for c in group.split(",")]
+        cur.append(Instr(m.group(2), dtype, dims, op.group(1),
+                         _OPERAND.findall(args[:end]), called,
+                         bool(m.group(1)), line.strip()))
+    return comps
+
+
+def _loop_computations(comps: Dict[str, List[Instr]]) -> List[str]:
+    """Every computation that runs inside some while body, outermost
+    first. Fused computations are not walked: a fusion is judged as one
+    instruction of its caller."""
+    seen: List[str] = []
+    todo = [c for instrs in comps.values() for i in instrs
+            if i.opcode == "while" for c in i.called]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.append(name)
+        for i in comps[name]:
+            if i.opcode in ("while", "call", "conditional"):
+                todo += i.called
+    return seen
+
+
+def _update_size(instr: Instr, comp: List[Instr],
+                 comps: Dict[str, List[Instr]]) -> Optional[int]:
+    """Elements written by an in-place update (alone or the root of a
+    fusion, through bitcasts), or None if the instruction is no such
+    update of a buffer that was handed in."""
+    by_name = {i.name: i for i in comp}
+    if instr.opcode == "fusion":
+        comp = comps.get(instr.called[0], []) if instr.called else []
+        by_name = {i.name: i for i in comp}
+        instr = next((i for i in comp if i.root), None)
+        while instr is not None and instr.opcode == "bitcast":
+            instr = by_name.get(instr.operands[0])
+    if instr is None or instr.opcode not in _IN_PLACE:
+        return None
+    target = by_name.get(instr.operands[0])
+    while target is not None and target.opcode == "bitcast":
+        target = by_name.get(target.operands[0])
+    if target is None or target.opcode not in ("parameter",
+                                               "get-tuple-element"):
+        return None                     # writes into a fresh value
+    update = by_name.get(
+        instr.operands[1 if instr.opcode == "dynamic-update-slice" else 2])
+    return None if update is None else update.size
+
+
+def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
+    """(dtype, dims) -> the largest in-place update allowed there, for a
+    KVCache (or its ShapeDtypeStructs): each leaf whole, and one layer of
+    k / v / the scales. A token-sized write is anything under a layer of
+    K/V; the recurrent leaves change a whole layer at a time."""
+    names = {"bfloat16": "bf16", "float32": "f32", "float16": "f16",
+             "int8": "s8"}
+    shapes: Dict[Tuple[str, Tuple[int, ...]], int] = {}
+    for field in ("k", "v", "k_scale", "v_scale", "state", "conv"):
+        leaf = getattr(cache, field)
+        if leaf is None:
+            continue
+        dtype = names[str(leaf.dtype)]
+        dims = tuple(int(d) for d in leaf.shape)
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None:       # the text has one device's shapes
+            dims = tuple(sharding.shard_shape(dims))
+        layer = math.prod(dims[1:])
+        if field in ("state", "conv"):
+            shapes[(dtype, dims)] = layer
+        else:
+            shapes[(dtype, dims)] = layer - 1
+            shapes[(dtype, dims[1:])] = layer - 1
+            shapes[(dtype, (1,) + dims[1:])] = layer - 1
+    return shapes
+
+
+def pool_sized_loop_ops(text: str, cache) -> List[str]:
+    """The offending instructions, as ``<computation>: <opcode> <shape>
+    <name>`` lines; empty for a program that updates its cache in place."""
+    comps = parse(text)
+    shapes = cache_shapes(cache)
+    found = []
+    for name in _loop_computations(comps):
+        for i in comps[name]:
+            limit = shapes.get((i.dtype, i.dims))
+            if limit is None or i.opcode in _FREE:
+                continue
+            if 'custom_call_target="AllocateBuffer"' in i.line:
+                continue                # reserves, moves nothing
+            written = _update_size(i, comps[name], comps)
+            if written is not None and written <= limit:
+                continue
+            dims = ",".join(map(str, i.dims))
+            found.append(f"{name}: {i.opcode} {i.dtype}[{dims}] {i.name}")
+    return found

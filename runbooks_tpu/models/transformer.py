@@ -391,6 +391,13 @@ class KVCache:
     Neither has a slot axis, so neither has a trash slot: a token that
     must not count is named by forward(token_mask=...) and leaves both
     exactly as they were. A row starts from zeros.
+
+    forward() carries every leaf whole through its layer scan (the carry,
+    not xs/ys): a layer writes this call's tokens at [layer, row, slot],
+    reads [layer, :, :view], and a linear-attention layer reads and writes
+    state/conv at its number in layer order. A jitted caller that donates
+    the cache (the engine's programs do) gets the same buffers back,
+    updated in place; nothing pool-sized is copied inside a loop.
     """
 
     k: jax.Array
@@ -616,7 +623,7 @@ def _attention_block(
     segment_ids: Optional[jax.Array],
     mask: Optional[jax.Array],
     bias: Optional[jax.Array],
-    layer_cache: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
+    layer_cache: Optional[tuple],      # see _write_layer_cache
     adapter=None,
 ):
     b, s, _ = x.shape
@@ -683,10 +690,17 @@ def _attention_block(
 
 
 def _write_layer_cache(k, v, positions, layer_cache, ad):
-    """Write this call's K/V into one layer's cache and return what
-    attention reads: (k, v, new layer cache)."""
+    """Write this call's K/V into the pool at this layer and return what
+    attention reads: (k, v, the pool's leaves after the write).
+
+    ``layer_cache`` is (k, v, k_scale, v_scale, layer, index, view): the
+    WHOLE pool leaves [full layers, batch, cache_len, …] as forward's layer
+    scan carries them, and this layer's number in them. Only the tokens of
+    this call are written and only ``[layer, :, :view]`` is read, both by
+    index into the carried buffer, so the loop updates the pool in place:
+    no layer is sliced out, none is written back."""
     b = k.shape[0]
-    ck, cv, ck_s, cv_s, index, view = layer_cache
+    ck, cv, ck_s, cv_s, layer, index, view = layer_cache
     quantized = ck.dtype == jnp.int8
     if quantized:
         # int8 KV: one f32 scale per (row, slot, kv-head) rides next to
@@ -697,40 +711,46 @@ def _write_layer_cache(k, v, positions, layer_cache, ad):
         k_w, v_w, k_s, v_s = k, v, None, None
     if index is None:
         # Position-scatter mode: row b token j -> slot positions[b, j].
-        cache_len = ck.shape[1]
+        cache_len = ck.shape[2]
         slot = jnp.clip(positions, 0, cache_len - 1)
         b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        ck = ck.at[b_idx, slot].set(k_w)
-        cv = cv.at[b_idx, slot].set(v_w)
+        ck = ck.at[layer, b_idx, slot].set(k_w)
+        cv = cv.at[layer, b_idx, slot].set(v_w)
         if quantized:
-            ck_s = ck_s.at[b_idx, slot].set(k_s)
-            cv_s = cv_s.at[b_idx, slot].set(v_s)
+            ck_s = ck_s.at[layer, b_idx, slot].set(k_s)
+            cv_s = cv_s.at[layer, b_idx, slot].set(v_s)
     else:
-        ck = jax.lax.dynamic_update_slice(ck, k_w, (0, index, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v_w, (0, index, 0, 0))
+        at = (layer, 0, index, 0, 0)
+        ck = jax.lax.dynamic_update_slice(ck, k_w[None], at)
+        cv = jax.lax.dynamic_update_slice(cv, v_w[None], at)
         if quantized:
-            ck_s = jax.lax.dynamic_update_slice(ck_s, k_s, (0, index, 0))
-            cv_s = jax.lax.dynamic_update_slice(cv_s, v_s, (0, index, 0))
+            ck_s = jax.lax.dynamic_update_slice(ck_s, k_s[None], at[:-1])
+            cv_s = jax.lax.dynamic_update_slice(cv_s, v_s[None], at[:-1])
     # Writes go to the FULL cache; attention READS only [0, view).
     # Exact for any view > max query position: slot s is attended only
     # by queries at positions >= s, so slots beyond the view hold
     # nothing a masked-in query could see. Serving uses this to stop
     # decode from streaming the whole max-length cache through HBM
-    # when occupancy is low (the decode step is bandwidth-bound).
-    if view is None:
-        k, v = ck, cv
-        rk_s, rv_s = ck_s, cv_s
-    else:
-        k, v = ck[:, :view], cv[:, :view]
-        rk_s = ck_s[:, :view] if quantized else None
-        rv_s = cv_s[:, :view] if quantized else None
+    # when occupancy is low (the decode step is bandwidth-bound). The
+    # read is of the UPDATED leaves: a read of the old ones would keep
+    # them alive across the write and cost a copy of the pool.
+
+    def read(leaf):
+        if leaf is None:
+            return None
+        size = (1, b, leaf.shape[2] if view is None else view)
+        return jax.lax.dynamic_slice(
+            leaf, (layer,) + (0,) * (leaf.ndim - 1),
+            size + leaf.shape[3:])[0]
+
+    k, v = read(ck), read(cv)
     if quantized:
         # Dequantize at the read: the scale multiply fuses into the
         # attention contraction, so HBM streams int8 + one scale per
         # row — half the bytes of the bf16 cache the decode step is
         # bound on.
-        k = dequantize_kv(k, rk_s, ad)
-        v = dequantize_kv(v, rv_s, ad)
+        k = dequantize_kv(k, read(ck_s), ad)
+        v = dequantize_kv(v, read(cv_s), ad)
     return k, v, (ck, cv, ck_s, cv_s)
 
 
@@ -767,8 +787,12 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     """The gated-delta token mixer (ops/gated_delta.py) of one layer.
     x [b, s, h]; token_mask [b, s] bool or None (all valid; a row's valid
     tokens are a prefix of it); layer_state None (no cache: start from
-    zeros, keep nothing) or (state [b, H, d_k, d_v] f32, conv tail
-    [b, kernel-1, channels]). Returns (out [b, s, h], new layer_state)."""
+    zeros, keep nothing) or (state, conv, layer): the cache's WHOLE
+    recurrent leaves ([linear layers, b, H, d_k, d_v] f32 and [linear
+    layers, b, kernel-1, channels]) as forward's layer scan carries them,
+    and this layer's number among the linear ones. The layer reads its
+    own state and conv tail there and writes the new ones back at the same
+    index. Returns (out [b, s, h], None or the (state, conv) leaves)."""
     from runbooks_tpu.ops.gated_delta import (
         causal_conv,
         gated_delta_chunked,
@@ -782,7 +806,11 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
                  cfg.linear_value_head_dim)
     kd = cfg.linear_key_dim
-    state, tail = layer_state if layer_state is not None else (None, None)
+    state = tail = None
+    if layer_state is not None:
+        all_state, all_tail, layer = layer_state
+        state = jax.lax.dynamic_index_in_dim(all_state, layer, 0, False)
+        tail = jax.lax.dynamic_index_in_dim(all_tail, layer, 0, False)
 
     def small(w):   # [h, H] heads of the decay and the write strength
         return jnp.einsum("...k,ko->...o", x, w.astype(ad),
@@ -825,7 +853,11 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         o = rms_norm(o, p["o_norm"], cfg.norm_eps)
         o = o * jax.nn.silu(gate.reshape(b, s, H, dv))
         out = _matmul(o.reshape(b, s, H * dv), p["wo"], ad)
-    return out, (None if layer_state is None else (state, tail))
+    if layer_state is None:
+        return out, None
+    return out, (
+        jax.lax.dynamic_update_index_in_dim(all_state, state, layer, 0),
+        jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0))
 
 
 def _mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -889,9 +921,11 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
     """One transformer block. x: [b, s, h]. Returns (x, cache, aux).
     ``adapter``: None or (per-layer adapter-pool slice, lane indices) —
     the grouped LoRA injection (docs/multi-tenant-lora.md). ``kind`` names
-    the token mixer (ModelConfig.layer_types); a linear-attention layer's
-    ``layer_cache`` is its (state, conv tail) and ``token_mask`` says
-    which tokens may change it."""
+    the token mixer (ModelConfig.layer_types). ``layer_cache`` holds the
+    cache's whole leaves of the layer's kind and the layer's number in them
+    (_write_layer_cache, _linear_attention_block); the updated leaves come
+    back. ``token_mask`` says which tokens may change a linear-attention
+    layer's state."""
 
     def mixer(h_in):
         # The linear mixer runs inside the `attn` scope too, under inner
@@ -1121,53 +1155,59 @@ def forward(
     # full-attention layer (params["layers"], scanned as it lies, so a
     # homogeneous model's program is what it was before patterns) and its
     # linear-attention layers, each position's stack scanned the same
-    # way. The cache's recurrent leaves, stacked in layer order
-    # [L_lin, …], are scanned as [periods, per period, …].
+    # way. A cache's leaves ride the scan's CARRY, whole: a scanned
+    # output is a new buffer by construction, so leaves scanned as xs/ys
+    # cost a copy of a whole layer in and out every layer, and a copy of
+    # the pool for whoever carries the cache through a loop of its own
+    # (make_decode_fn). Each layer writes its part by index — the period's
+    # number for K/V, period * n_lin + i for the recurrent leaves, which
+    # lie in layer order — and the loop updates the buffers in place.
     n_lin = pattern.count("linear_attention")
 
     def scan_body(carry, scanned):
-        x, aux_sum = carry
-        layers, ck, cv, ck_s, cv_s, pool_layer, lin_layers, st, cn = scanned
+        x, aux_sum, kv, rec = carry
+        layers, pool_layer, lin_layers, period = scanned
         adapter = None if apool is None else (pool_layer, aidx)
-        new_kv, new_rec = None, []
+        i = 0
         for kind in pattern:
             if kind == "full_attention":
                 layer_cache = None
                 if cache is not None:
-                    layer_cache = (ck, cv, ck_s, cv_s,
+                    layer_cache = (*kv, period,
                                    None if scatter_mode else cache.index,
                                    cache_view)
-                x, new_kv, aux = block(
+                x, kv, aux = block(
                     cfg, layers, x, positions, segment_ids, mask, bias,
                     layer_cache, adapter)
             else:
-                i = len(new_rec)
                 layer_cache = (None if cache is None
-                               else _period_pick((st, cn), n_lin, i))
-                x, new, aux = blocks[kind](
+                               else (*rec, period * n_lin + i))
+                x, rec, aux = blocks[kind](
                     cfg, lin_layers[i], x, positions, segment_ids, mask,
                     bias, layer_cache, None, token_mask)
-                new_rec.append(new)
+                i += 1
             aux_sum = aux_sum + aux
-        return (x, aux_sum), (new_kv, _period_stack(new_rec, n_lin))
+        return (x, aux_sum, kv, rec), None
 
     layers, lin_layers = params["layers"], params.get("linear_layers")
     aux_total = jnp.zeros((), jnp.float32)
     if cache is not None:
         # k_scale/v_scale are None (empty pytrees) for an unquantized
         # cache, as state/conv are for a model without linear-attention
-        # layers; scan threads them through untouched either way. The
-        # adapter pool (leading L axis) rides the same scan when given.
-        xs = (layers, cache.k, cache.v, cache.k_scale, cache.v_scale,
-              apool, lin_layers,
-              *_period_split((cache.state, cache.conv), n_lin))
+        # layers; the carry threads them through untouched either way. The
+        # adapter pool (leading L axis) rides the scan as xs when given.
+        xs = (layers, apool, lin_layers,
+              jnp.arange(cache.k.shape[0], dtype=jnp.int32))
+        init = (x, aux_total,
+                (cache.k, cache.v, cache.k_scale, cache.v_scale),
+                (cache.state, cache.conv))
         # `layers`: the scan itself (slices of the stacked weights, what
         # the compiler hoists out of the loop); each layer is a `block`.
         with jax.named_scope("layers"):
-            (x, aux_total), (new_kv, new_rec) = jax.lax.scan(
-                scan_body, (x, aux_total), xs)
+            (x, aux_total, new_kv, new_rec), _ = jax.lax.scan(
+                scan_body, init, xs)
         new_k, new_v, new_ks, new_vs = new_kv
-        new_state, new_conv = _period_merge(new_rec or (None, None), n_lin)
+        new_state, new_conv = new_rec
         new_index = cache.index if scatter_mode else cache.index + s
         new_cache = KVCache(k=new_k, v=new_v, index=new_index,
                             k_scale=new_ks, v_scale=new_vs,
@@ -1206,11 +1246,10 @@ def forward(
                     mesh=mesh, n_stages=n_stages,
                     n_microbatches=cfg.pipeline_microbatches or None)
         else:
-            xs = (layers, None, None, None, None, apool, lin_layers,
-                  None, None)
             with jax.named_scope("layers"):
-                (x, aux_total), _ = jax.lax.scan(
-                    scan_body, (x, aux_total), xs)
+                (x, aux_total, _, _), _ = jax.lax.scan(
+                    scan_body, (x, aux_total, None, None),
+                    (layers, apool, lin_layers, None))
         new_cache = None
 
     with jax.named_scope("head"):
@@ -1269,35 +1308,6 @@ def _check_recurrent_support(cfg: ModelConfig, segment_ids, adapters):
             f"a tensor mesh of {tensor} does not divide the "
             f"{cfg.linear_num_heads} linear-attention heads: the recurrent "
             "state shards by head (docs/hybrid-models.md)")
-
-
-def _period_split(tree, n: int):
-    """[layers of a kind, …] leaves -> [periods, n, …] (as they lie when
-    n <= 1: nothing of that kind, or one a period)."""
-    if n <= 1:
-        return tree
-    return jax.tree.map(
-        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), tree)
-
-
-def _period_pick(tree, n: int, i: int):
-    """Layer i of its kind inside one period's slice."""
-    return tree if n <= 1 else jax.tree.map(lambda a: a[i], tree)
-
-
-def _period_stack(items: list, n: int):
-    """What the layers of a kind return in one period -> one scan output."""
-    if n <= 1:
-        return items[0] if items else None
-    return jax.tree.map(lambda *a: jnp.stack(a), *items)
-
-
-def _period_merge(tree, n: int):
-    """Scan outputs [periods, n, …] -> [layers of the kind, …]."""
-    if n <= 1:
-        return tree
-    return jax.tree.map(
-        lambda a: a.reshape((a.shape[0] * n,) + a.shape[2:]), tree)
 
 
 def loss_and_grads_1f1b(

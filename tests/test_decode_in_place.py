@@ -1,0 +1,207 @@
+"""The compiled decode program updates its cache in place.
+
+forward() carries the cache's leaves through its layer scan and each layer
+writes its own part by index, so neither the loop over decode steps nor
+the loop over layers may hold an operation that moves the pool, or a whole
+layer of K or V, from one buffer to another (runbooks_tpu/analysis/
+loop_copies.py says what counts). That is a property of the COMPILED
+program, and of the TPU's compiler: the CPU's converts a bfloat16 pool to
+float32 around every scatter, which the chip never does. So the programs
+are compiled here for a described v5e:2x2, without a chip (the
+on-chip-measurement guide, section 2): sizes and structure, never a time.
+
+The topology is described inside a fixture, and only in this file: one
+process at a time may load the TPU's library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from runbooks_tpu.analysis.loop_copies import (
+    cache_shapes,
+    parse,
+    pool_sized_loop_ops,
+)
+from runbooks_tpu.models.transformer import KVCache, init_params
+from runbooks_tpu.serve.engine import make_decode_fn
+from tests.hybrid_fixture import tiny_config
+from tests.test_transformer import tiny
+
+SLOTS, MAX_LEN, VIEW, CHUNK = 3, 40, 32, 4
+
+
+def toy(family: str, **over):
+    # bfloat16, as served: the pool's dtype is what the compiler moves.
+    return tiny(family, num_layers=3, dtype="bfloat16", **over)
+
+
+# name -> (config, int8 pool)
+MODELS = {
+    "mqa": (lambda: toy("falcon-7b", num_kv_heads=1), False),
+    "gqa": (lambda: toy("llama2-7b", num_kv_heads=2), False),
+    "hybrid": (tiny_config, False),
+    "gqa-int8-pool": (lambda: toy("llama2-7b", num_kv_heads=2), True),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    for name, value in (("TPU_LOG_DIR", "disabled"),
+                        ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                        ("TPU_WORKER_HOSTNAMES", "localhost")):
+        os.environ.setdefault(name, value)      # quiets the library
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - whatever the library raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compiled_decode(cfg, int8_pool: bool, sharding):
+    """(text of the compiled decode_fn, the pool's shapes), compiled as
+    the engine jits it: the pool donated."""
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    key = jax.random.key(0)
+    params = on_chip(jax.eval_shape(functools.partial(init_params, cfg),
+                                    key))
+    pool = on_chip(jax.eval_shape(lambda: KVCache.create(
+        cfg, SLOTS, MAX_LEN, trash_slot=True, quantize_kv=int8_pool)))
+    decode = jax.jit(make_decode_fn(cfg, CHUNK, MAX_LEN, MAX_LEN, VIEW),
+                     donate_argnums=(1,))
+    i32 = functools.partial(arg, jnp.int32)
+    f32 = functools.partial(arg, jnp.float32)
+    text = decode.lower(
+        params, pool, i32(SLOTS), i32(SLOTS), on_chip(key), f32(SLOTS),
+        i32(SLOTS), f32(SLOTS), i32(SLOTS), i32(SLOTS),
+        arg(jnp.bool_, SLOTS)).compile().as_text()
+    return text, pool
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_decode_loops_hold_no_pool_sized_operation(one_chip, model):
+    make_cfg, int8_pool = MODELS[model]
+    text, pool = compiled_decode(make_cfg(), int8_pool, one_chip)
+    comps = parse(text)
+    # The reader sees the program: two nested loops, and the pool among
+    # the values they carry.
+    loops = [i for c in comps.values() for i in c if i.opcode == "while"]
+    assert len(loops) >= 2
+    shape = "[" + ",".join(map(str, pool.k.shape)) + "]"
+    assert any(shape in i.line for i in loops), shape
+    assert pool_sized_loop_ops(text, pool) == []
+
+
+# What the reader calls pool-sized, on a program small enough to read: the
+# parent's pattern (a layer sliced out, a token scattered into the copy,
+# the layer written back, the pool copied for the next step) and this
+# tree's (the token written into the carried pool).
+_HEAD = """HloModule m
+
+%cond (p: (s32[], bf16[2,3,9,1,4])) -> pred[] {
+  %p = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) parameter(0)
+  ROOT %lt = pred[] constant(true)
+}
+
+%fused_write (a: bf16[2,3,9,1,4], u: bf16[1,3,1,1,4], i: s32[]) -> bf16[2,3,9,1,4] {
+  %a = bf16[2,3,9,1,4]{4,3,2,1,0} parameter(0)
+  %u = bf16[1,3,1,1,4]{4,3,2,1,0} parameter(1)
+  %i = s32[] parameter(2)
+  %z = s32[] constant(0)
+  ROOT %dus = bf16[2,3,9,1,4]{4,3,2,1,0} dynamic-update-slice(%a, %u, %i, %z, %z, %z, %z)
+}
+
+%fused_back (a: bf16[2,3,9,1,4], u: bf16[3,9,1,4], i: s32[]) -> bf16[2,3,9,1,4] {
+  %a = bf16[2,3,9,1,4]{4,3,2,1,0} parameter(0)
+  %u = bf16[3,9,1,4]{3,2,1,0} parameter(1)
+  %b = bf16[1,3,9,1,4]{4,3,2,1,0} bitcast(%u)
+  %i = s32[] parameter(2)
+  %z = s32[] constant(0)
+  ROOT %dus = bf16[2,3,9,1,4]{4,3,2,1,0} dynamic-update-slice(%a, %b, %i, %z, %z, %z, %z)
+}
+"""
+_IN_PLACE = _HEAD + """
+%body (p: (s32[], bf16[2,3,9,1,4])) -> (s32[], bf16[2,3,9,1,4]) {
+  %p = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %pool = bf16[2,3,9,1,4]{4,3,2,1,0} get-tuple-element(%p), index=1
+  %tok = bf16[1,3,1,1,4]{4,3,2,1,0} constant({...})
+  %new = bf16[2,3,9,1,4]{4,3,2,1,0} fusion(%pool, %tok, %i), kind=kLoop, calls=%fused_write
+  ROOT %t = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) tuple(%i, %new)
+}
+
+ENTRY %main (k: bf16[2,3,9,1,4]) -> bf16[2,3,9,1,4] {
+  %k = bf16[2,3,9,1,4]{4,3,2,1,0} parameter(0)
+  %c = bf16[2,3,9,1,4]{4,3,2,1,0} copy(%k)
+  %z = s32[] constant(0)
+  %init = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) tuple(%z, %c)
+  %w = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) while(%init), condition=%cond, body=%body
+  ROOT %out = bf16[2,3,9,1,4]{4,3,2,1,0} get-tuple-element(%w), index=1
+}
+"""
+_COPIES = _HEAD + """
+%body (p: (s32[], bf16[2,3,9,1,4])) -> (s32[], bf16[2,3,9,1,4]) {
+  %p = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %pool = bf16[2,3,9,1,4]{4,3,2,1,0} get-tuple-element(%p), index=1
+  %z = s32[] constant(0)
+  %layer = bf16[1,3,9,1,4]{4,3,2,1,0} dynamic-slice(%pool, %i, %z, %z, %z, %z), dynamic_slice_sizes={1,3,9,1,4}
+  %flat = bf16[3,9,1,4]{3,2,1,0} bitcast(%layer)
+  %back = bf16[2,3,9,1,4]{4,3,2,1,0} fusion(%pool, %flat, %i), kind=kLoop, calls=%fused_back
+  %next = bf16[2,3,9,1,4]{4,3,2,1,0} copy(%back)
+  ROOT %t = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) tuple(%i, %next)
+}
+
+ENTRY %main (k: bf16[2,3,9,1,4]) -> bf16[2,3,9,1,4] {
+  %k = bf16[2,3,9,1,4]{4,3,2,1,0} parameter(0)
+  %z = s32[] constant(0)
+  %init = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) tuple(%z, %k)
+  %w = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) while(%init), condition=%cond, body=%body
+  ROOT %out = bf16[2,3,9,1,4]{4,3,2,1,0} get-tuple-element(%w), index=1
+}
+"""
+
+
+def _pool():
+    leaf = jax.ShapeDtypeStruct((2, 3, 9, 1, 4), jnp.bfloat16)
+    return KVCache(k=leaf, v=leaf, index=None)
+
+
+@pytest.mark.parametrize("text,found", [
+    (_IN_PLACE, []),
+    (_COPIES, ["body: dynamic-slice bf16[1,3,9,1,4] layer",
+               "body: fusion bf16[2,3,9,1,4] back",
+               "body: copy bf16[2,3,9,1,4] next"]),
+], ids=["token-written-in-place", "layer-out-and-back-and-pool-copied"])
+def test_reader_on_a_program_small_enough_to_read(text, found):
+    # main's copy on the way into the loop is outside every body: once a
+    # call, not once a step, and not this reader's business.
+    assert pool_sized_loop_ops(text, _pool()) == found
+
+
+def test_reader_shapes_follow_the_cache():
+    cfg = tiny_config()
+    pool = jax.eval_shape(lambda: KVCache.create(
+        cfg, SLOTS, MAX_LEN, trash_slot=True, quantize_kv=True))
+    shapes = cache_shapes(pool)
+    layer = SLOTS * (MAX_LEN + 1) * cfg.num_kv_heads * cfg.head_dim
+    assert shapes[("s8", pool.k.shape)] == layer - 1
+    assert shapes[("s8", pool.k.shape[1:])] == layer - 1
+    assert shapes[("f32", pool.k_scale.shape)] == layer // cfg.head_dim - 1
+    # The recurrent leaves change a whole layer a step: that write stays.
+    assert shapes[("f32", pool.state.shape)] == pool.state.size // 6
+    assert ("f32", pool.state.shape[1:]) not in shapes

@@ -169,6 +169,55 @@ def test_parked_rows_and_padding_rows_leave_state_alone(model):
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("view", [64, 32], ids=["whole", "short-view"])
+@pytest.mark.parametrize("donate", [False, True],
+                         ids=["kept", "pool-donated"])
+def test_a_row_not_alive_keeps_its_cache_across_a_chunk(model, donate, view):
+    """Rule (c) with the leaves carried through both loops and updated in
+    place: across a chunk of 8 steps a row that is not alive, or that dies
+    on the way, keeps state and conv bit for bit from then on, and its K/V
+    everywhere but the trash slot; a live row's state moves every step."""
+    cfg, params = model
+    slots, max_len, chunk = 4, 64, 8
+    rng = np.random.default_rng(6)
+    pool = KVCache.create(cfg, slots, max_len, trash_slot=True)
+    pool = jax.tree.map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype)
+        if a.ndim else a, pool)
+    before = jax.tree.map(np.asarray, pool)
+    decode = jax.jit(make_decode_fn(cfg, chunk, max_len, max_len, view),
+                     donate_argnums=(1,) if donate else ())
+    # Row 1 is parked from the start; row 2 has two tokens left to emit.
+    alive = jnp.array([True, False, True, True])
+    args = (jnp.array([5, 6, 7, 8]), jnp.array([10, 0, 3, 20]),
+            jax.random.key(0), jnp.zeros(4), jnp.zeros(4, jnp.int32),
+            jnp.ones(4), jnp.full(4, -1))
+    _, valid, after, _ = decode(params, pool, *args,
+                                jnp.array([99, 0, 2, 99]), alive)
+    assert np.asarray(valid).sum(0).tolist() == [8, 0, 2, 8]
+    for leaf in ("state", "conv"):
+        new, old = np.asarray(getattr(after, leaf)), getattr(before, leaf)
+        assert np.array_equal(new[:, 1], old[:, 1]), leaf
+        assert not np.array_equal(new[:, 0], old[:, 0]), leaf
+    for leaf in ("k", "v"):
+        new, old = np.asarray(getattr(after, leaf)), getattr(before, leaf)
+        assert np.array_equal(new[:, 1, :max_len], old[:, 1, :max_len])
+        # Row 0 wrote slots 10..17 and nothing else; row 2 slots 3 and 4.
+        for row, lo, hi in ((0, 10, 18), (2, 3, 5)):
+            changed = np.flatnonzero(
+                (new[:, row, :max_len] != old[:, row, :max_len])
+                .any(axis=(0, 2, 3)))
+            assert changed.tolist() == list(range(lo, hi)), (leaf, row)
+    # Row 2 after it died: the same as a chunk that ends where it died.
+    pool2 = jax.tree.map(jnp.asarray, before)
+    short = jax.jit(make_decode_fn(cfg, 2, max_len, max_len, view))
+    _, _, ended, _ = short(params, pool2, *args,
+                           jnp.array([99, 0, 2, 99]), alive)
+    for leaf in ("state", "conv"):
+        assert np.array_equal(np.asarray(getattr(after, leaf))[:, 2],
+                              np.asarray(getattr(ended, leaf))[:, 2]), leaf
+
+
 REFUSALS = [
     (dict(speculative="ngram"), "speculative decoding is not supported"),
     (dict(adapter_pool=2), "an adapter pool is not supported"),

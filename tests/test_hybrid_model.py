@@ -9,6 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.cache_paths import (
+    MODE_VIEW_IDS,
+    MODES_AND_VIEWS,
+    greedy_chunk,
+    worst_gap,
+)
 from tests.hybrid_fixture import (
     AS_RUN,
     load_reference,
@@ -171,6 +177,30 @@ def test_prefill_in_pieces_then_decode_is_the_whole_forward():
         got.append(logits)
     assert float(jnp.max(jnp.abs(jnp.concatenate(got, 1) - whole))) < 5e-4
     assert int(cache.index) == 100
+
+
+@pytest.mark.parametrize("int8_pool", [False, True],
+                         ids=["float-pool", "int8-pool"])
+@pytest.mark.parametrize("mode,view", MODES_AND_VIEWS, ids=MODE_VIEW_IDS)
+def test_cache_write_modes_and_views_match_full_forward(mode, view,
+                                                        int8_pool):
+    """State and conv tail are read and written at period * 3 + i of the
+    carried leaves, K/V at the period's number, in both write modes; in
+    scatter mode row 1's padding is parked at the trash slot and masked out
+    of its state. The int8 pool quantizes K/V alone (0.035 here)."""
+    cfg = tiny_config(dtype="float32")
+    params = seeded_params(cfg, 3)
+    toks = jnp.asarray(np.random.default_rng(1).integers(1, 512, (2, 12)))
+    gap = worst_gap(cfg, params, toks, mode, view, int8_pool)
+    assert (2e-5 < gap < 0.1) if int8_pool else gap < 5e-5
+
+
+def test_decode_chunk_greedy_tokens_are_the_parents():
+    """As test_transformer.py's: recorded from the parent of PR 27."""
+    cfg = tiny_config(dtype="float32")
+    assert greedy_chunk(cfg, seeded_params(cfg, 3)) == [
+        [66, 483], [444, 239], [232, 7], [373, 125], [165, 304], [46, 126],
+        [46, 116], [172, 248]]
 
 
 def test_cache_view_slices_only_keys_and_values():

@@ -36,6 +36,7 @@ from runbooks_tpu.ops.quantization import (
     unpack_int4,
 )
 from runbooks_tpu.serve.engine import InferenceEngine, Request
+from tests.cache_paths import MODE_VIEW_IDS, MODES_AND_VIEWS, worst_gap
 
 
 def tiny_cfg(**over):
@@ -172,6 +173,23 @@ def test_int8_kv_decode_greedy_agreement():
         f32.generate([a])
         i8.generate([b])
         assert a.output_tokens == b.output_tokens, prompt
+
+
+@pytest.mark.parametrize("mode,view", MODES_AND_VIEWS, ids=MODE_VIEW_IDS)
+@pytest.mark.parametrize("kv_heads", [1, 2], ids=["mqa", "gqa"])
+def test_int8_pool_write_modes_and_views_track_full_forward(kv_heads, mode,
+                                                            view):
+    """Values and scales land at the same [layer, row, slot] in both write
+    modes, and a short view reads both: through the int8 pool the logits
+    stay within int8's error of the forward without a cache (0.045 /
+    0.033 here, logits of order 1)."""
+    cfg = dataclasses.replace(tiny_cfg(), num_kv_heads=kv_heads)
+    params = init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 12), 0,
+                                cfg.vocab_size)
+    exact = worst_gap(cfg, params, tokens, mode, view)
+    int8 = worst_gap(cfg, params, tokens, mode, view, int8_pool=True)
+    assert exact < 2e-5 < int8 < 0.1
 
 
 def test_int8_kv_halves_cache_bytes():

@@ -14,18 +14,24 @@ import pytest
 
 from runbooks_tpu.models.config import get_config
 from runbooks_tpu.models.transformer import KVCache, forward, init_params
+from tests.cache_paths import (
+    MODE_VIEW_IDS,
+    MODES_AND_VIEWS,
+    greedy_chunk,
+    worst_gap,
+)
 
 
-def tiny(family: str):
+def tiny(family: str, **over):
     base = get_config(family)
-    return dataclasses.replace(
-        base, vocab_size=256, hidden_size=64,
+    return dataclasses.replace(base, **{**dict(
+        vocab_size=256, hidden_size=64,
         intermediate_size=128 if not base.gated_mlp else 96,
         num_layers=2, num_heads=4,
         num_kv_heads=2 if base.num_kv_heads < base.num_heads else 4,
         head_dim=16, max_seq_len=64,
         dtype="float32",  # exact-math tests; bf16 noise tested separately
-    )
+    ), **over})
 
 
 FAMILIES = ["llama2-7b", "falcon-7b", "opt-125m"]
@@ -73,6 +79,41 @@ def test_kv_cache_matches_full_forward(family):
     cached_logits = jnp.concatenate(got, axis=1)
     np.testing.assert_allclose(full_logits, cached_logits, rtol=2e-5, atol=2e-5)
     assert int(cache.index) == 10
+
+
+# One KV head for all query heads, and two for four: the two ratios the
+# cache paths are driven at (tests/cache_paths.py; the hybrid's and the
+# int8 pool's cases are in test_hybrid_model.py and test_quantization.py).
+KV_RATIOS = {"mqa": lambda: tiny("falcon-7b", num_kv_heads=1),
+             "gqa": lambda: tiny("llama2-7b")}
+
+
+@pytest.mark.parametrize("mode,view", MODES_AND_VIEWS, ids=MODE_VIEW_IDS)
+@pytest.mark.parametrize("kv", list(KV_RATIOS))
+def test_cache_write_modes_and_views_match_full_forward(kv, mode, view):
+    cfg = KV_RATIOS[kv]()
+    params = init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 12), 0,
+                                cfg.vocab_size)
+    assert worst_gap(cfg, params, tokens, mode, view) < 2e-5
+
+
+# Recorded from the parent of PR 27 (ed1d630: cache leaves scanned as
+# xs/ys), float32, this machine's CPU: threading the leaves through the
+# scan's carry instead moves bytes differently and computes the same.
+PARENT_GREEDY = {
+    "mqa": [[220, 30], [143, 61], [116, 61], [116, 1], [41, 30],
+            [132, 199], [132, 199], [132, 199]],
+    "gqa": [[171, 147], [212, 87], [87, 8], [109, 16], [109, 74],
+            [148, 94], [109, 141], [147, 0]],
+}
+
+
+@pytest.mark.parametrize("kv", list(KV_RATIOS))
+def test_decode_chunk_greedy_tokens_are_the_parents(kv):
+    cfg = KV_RATIOS[kv]()
+    params = init_params(cfg, jax.random.key(0))
+    assert greedy_chunk(cfg, params) == PARENT_GREEDY[kv]
 
 
 def test_packed_segments_are_isolated():
