@@ -375,11 +375,12 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
     # settings.adapter_pool/lora_rank (the max reachable pool shapes);
     # signature cardinality matches the plain programs 1:1 (the pool
     # replaces, never multiplies, the census).
+    from runbooks_tpu.api.serve_params import ServeOptions
     from runbooks_tpu.ops.lora import init_adapter_pool
 
     apool = jax.eval_shape(lambda: init_adapter_pool(
         cfg, settings.adapter_pool, settings.lora_rank,
-        cfg.lora_targets))
+        ServeOptions().lora_targets))
 
     def aslots_sds(rows):
         return _sds((rows,), jnp.int32)

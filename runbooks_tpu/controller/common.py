@@ -12,6 +12,7 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 from runbooks_tpu.api import conditions as cond
+from runbooks_tpu.api.serve_params import OptionError, ServeOptions
 from runbooks_tpu.api.types import Resource
 from runbooks_tpu.k8s import objects as ko
 from runbooks_tpu.utils.contract import params_to_env
@@ -50,14 +51,17 @@ def params_env(params: dict) -> List[dict]:
             for k, v in sorted(params_to_env(params).items())]
 
 
-# Enum-valued spec.params keys with their allowed values. The params dict
-# is otherwise free-form (it flows verbatim into the params.json ConfigMap
-# + PARAM_* env — mount_params), but a typo'd `quantize: int3` would
-# otherwise surface only as a crash-looping serve container behind a
+# spec.params is free-form (it flows verbatim into the params.json
+# ConfigMap + PARAM_* env — mount_params), but a typo'd `quantize: int3`
+# would otherwise surface only as a crash-looping container behind a
 # never-ready Deployment; validating at reconcile time turns it into a
-# visible condition. `quantize` mirrors the reference's Server contract
-# (reference: examples/llama2-70b/server.yaml `quantize: int4`), consumed
-# by serve/api.load_model and models/loader.py.
+# visible condition. What the server reads is declared, with its floors and
+# rules, by ServeOptions (api/serve_params.py) and validated by building
+# the record; what the loader and the trainer read is tabled here.
+# `quantize` mirrors the reference's Server contract (reference:
+# examples/llama2-70b/server.yaml `quantize: int4`), consumed by
+# serve/api.load_model and models/loader.py.
+
 # Gradient accumulation (train/step.py make_train_step): microbatch count
 # per optimizer step. Power-of-two enum — a typo'd value would otherwise
 # surface only as a crash-looping trainer Job at ValueError time; accepted
@@ -77,24 +81,6 @@ _CM_ENUM = ("off", "ring", "auto")
 ENUM_PARAMS = {
     "quantize": ("none", "int8", "int4"),
     "source": ("huggingface", "dir", "random"),
-    # Paged KV serving (serve/paging.py, docs/paged-kv.md): a typo'd
-    # value would otherwise silently serve the dense slot pool.
-    **{k: ("off", "paged") for k in ("kv_paging", "kvPaging",
-                                     "kvpaging")},
-    # QoS slot preemption over the host KV tier (serve/paging.py,
-    # docs/paged-kv.md "Host tier and preemption"): a typo'd value
-    # would otherwise silently serve with overload-429 as the only
-    # degradation mode. One spelling — the name has no word boundary.
-    "preemption": ("off", "swap"),
-    # Speculative decoding (serve/engine.py verify path,
-    # docs/speculative-decoding.md): a typo'd value would otherwise
-    # silently serve without drafting.
-    "speculative": ("off", "ngram"),
-    # Grammar-constrained structured output (serve/grammar.py,
-    # docs/structured-output.md): a typo'd value would otherwise 400
-    # every response_format request at the replica. One spelling — the
-    # name has no word boundary, like preemption.
-    "grammar": ("off", "on"),
     **{k: _ACCUM_ENUM for k in _ACCUM_KEYS},
     **{k: _CM_ENUM for k in _CM_KEYS},
 }
@@ -114,51 +100,10 @@ DEFAULT_PREEMPTION_RESTARTS = 2
 # condition.
 _MAX_BAD_STEPS_KEYS = ("max_bad_steps", "maxBadSteps", "maxbadsteps")
 
-# Speculative-decoding knobs (serve/engine.py, docs/speculative-
-# decoding.md), accepted under the usual three spellings. The defaults
-# mirror ModelConfig.ngram_max/ngram_min (keep in sync, like
-# DEFAULT_TRAIN_BATCH_SIZE): the min<=max cross-check must hold against
-# the default the engine will actually use when the spec sets only one
-# side, or a lone `ngram_min: 5` passes here and crash-loops every
-# replica at engine construction.
-_DRAFT_TOKENS_KEYS = ("draft_tokens", "draftTokens", "drafttokens")
-_NGRAM_MAX_KEYS = ("ngram_max", "ngramMax", "ngrammax")
-_NGRAM_MIN_KEYS = ("ngram_min", "ngramMin", "ngrammin")
-DEFAULT_NGRAM_MAX = 3
-DEFAULT_NGRAM_MIN = 1
-
-# Multi-tenant batched LoRA serving knobs (serve/lora_pool.py,
-# docs/multi-tenant-lora.md): adapter_pool sizes the HBM adapter pool
-# (0 = off — `adapter` then folds at load), lora_rank the static rank
-# bucket every pool lane pads to. Same three-spelling convention as the
-# other serving knobs.
-_ADAPTER_POOL_KEYS = ("adapter_pool", "adapterPool", "adapterpool")
-_LORA_RANK_KEYS = ("lora_rank", "loraRank", "lorarank")
-_ADAPTER_DIR_KEYS = ("adapter_dir", "adapterDir", "adapterdir")
-
-# Grammar compile-cache capacity (serve/grammar.py GrammarCache,
-# docs/structured-output.md): LRU entries of compiled token DFAs. Only
-# meaningful with grammar: on — cross-checked in validate_params. Same
-# three-spelling convention as the other serving knobs.
-_GRAMMAR_CACHE_KEYS = ("grammar_cache_size", "grammarCacheSize",
-                       "grammarcachesize")
-
-# Host-RAM KV swap tier + per-class queue shares (serve/paging.py,
-# docs/paged-kv.md "Host tier and preemption"). kv_host_pages sizes the
-# pinned host pool (0 = no host tier); queue_share_<class> bounds each
-# QoS class to a fraction of max_queue. Same three-spelling convention
-# as the other serving knobs.
-_KV_HOST_PAGES_KEYS = ("kv_host_pages", "kvHostPages", "kvhostpages")
-_QOS_CLASSES = ("interactive", "standard", "batch")
-_QUEUE_SHARE_KEYS = tuple(
-    k for c in _QOS_CLASSES
-    for k in (f"queue_share_{c}", f"queueShare{c.capitalize()}",
-              f"queueshare{c}"))
-
-# Mesh geometry axes (parallel/mesh.py MESH_AXES — keep in sync like
-# DEFAULT_NGRAM_MAX; not imported so the controller stays jax-free). A
-# spec selects sharded serving/training with mesh_<axis> integer params;
-# -1 means "fill with the remaining devices" on at most ONE axis.
+# Mesh geometry axes (parallel/mesh.py MESH_AXES — keep in sync; not
+# imported so the controller stays jax-free). A spec selects sharded
+# serving/training with mesh_<axis> integer params; -1 means "fill with
+# the remaining devices" on at most ONE axis.
 _MESH_AXES = ("data", "stage", "expert", "fsdp", "sequence", "tensor")
 
 INT_PARAMS = {
@@ -168,42 +113,16 @@ INT_PARAMS = {
     "seq_len": 1,
     "steps": 1,
     "mesh_stage": 1,
-    # Serving admission-queue bound (serve/api.py); 0 = reject everything
-    # (load-shed), still valid.
-    "max_queue": 0,
-    # Paged KV pool geometry (serve/paging.py): page_size must divide
-    # max_seq_len — checked at engine construction; here we catch the
-    # crash-loop-shaped typos (non-integers, absurd values).
-    "page_size": 8,
-    "num_pages": 1,
-    **{k: 1 for k in ("numPages", "numpages")},
-    **{k: 8 for k in ("pageSize", "pagesize")},
-    # Speculative decoding window + n-gram sizes (serve/engine.py);
-    # ngram_min <= ngram_max is cross-checked in validate_params.
-    **{k: 1 for k in _DRAFT_TOKENS_KEYS},
-    **{k: 1 for k in _NGRAM_MAX_KEYS},
-    **{k: 1 for k in _NGRAM_MIN_KEYS},
     # Consecutive non-finite steps the trainer tolerates before aborting.
     **{k: 1 for k in _MAX_BAD_STEPS_KEYS},
     **{k: 0 for k in _RESTART_KEYS},
-    # Multi-tenant LoRA serving (docs/multi-tenant-lora.md): pool size 0
-    # is valid (off); the rank bucket must hold at least one column.
-    **{k: 0 for k in _ADAPTER_POOL_KEYS},
-    **{k: 1 for k in _LORA_RANK_KEYS},
-    # Host KV tier size: 0 is valid (no host tier — evictions drop).
-    **{k: 0 for k in _KV_HOST_PAGES_KEYS},
-    # Grammar DFA compile cache: at least one entry (0 would evict every
-    # grammar on the next admission — a footgun, not a mode).
-    **{k: 1 for k in _GRAMMAR_CACHE_KEYS},
 }
 
 # Float-valued params the workloads float()-coerce at startup: key ->
 # minimum allowed value (same crash-loop-vs-condition rationale as
-# INT_PARAMS). All fault-tolerance knobs (docs/fault-tolerance.md).
+# INT_PARAMS; docs/fault-tolerance.md).
 FLOAT_PARAMS = {
     "maintenance_poll_s": 0.0,    # trainer: 0 disables polling
-    "request_timeout_s": 0.0,     # server: default per-request deadline
-    "drain_timeout_s": 0.0,       # server: SIGTERM drain bound
 }
 
 
@@ -352,79 +271,10 @@ def validate_params(params: dict) -> Optional[str]:
                 return f"spec.params.{key}: {val} must be >= {flo}"
         except (TypeError, ValueError):
             return f"spec.params.{key}: {val!r} is not a number"
-    # Speculative-decoding cross-field check (the per-key floors above
-    # already ran, so int() here cannot raise on a validated value).
-    # An omitted side compares against the engine default — the engine
-    # constructs the index (and would crash) even with speculation off.
-    ngram_max = next((params[k] for k in _NGRAM_MAX_KEYS
-                      if params.get(k) is not None), DEFAULT_NGRAM_MAX)
-    ngram_min = next((params[k] for k in _NGRAM_MIN_KEYS
-                      if params.get(k) is not None), DEFAULT_NGRAM_MIN)
-    if int(ngram_min) > int(ngram_max):
-        return (f"spec.params.ngram_min: {ngram_min} must be <= "
-                f"ngram_max {ngram_max}")
-    # Multi-tenant LoRA cross-field checks (docs/multi-tenant-lora.md):
-    # `adapter` must be a non-empty string (it names an artifact path);
-    # a pool-tuning knob without a pool serves nothing (spec typo); and
-    # `adapter` + `adapter_pool` on ONE Server is ambiguous — the fold
-    # path and the pool are mutually exclusive serving modes (tenants
-    # name the pool host via spec.engineRef instead).
-    adapter = params.get("adapter")
-    if adapter is not None and (not isinstance(adapter, str)
-                                or not adapter.strip()):
-        return f"spec.params.adapter: {adapter!r} must be a non-empty path"
-    pool_val = next((params[k] for k in _ADAPTER_POOL_KEYS
-                     if params.get(k) is not None), 0)
-    if int(pool_val or 0) == 0:
-        knob_set = next(
-            (k for k in _LORA_RANK_KEYS + _ADAPTER_DIR_KEYS
-             if params.get(k) is not None), None)
-        if knob_set is not None:
-            return (f"spec.params.{knob_set}: only applies to a pooled "
-                    "engine; set adapter_pool >= 1 "
-                    "(docs/multi-tenant-lora.md)")
-    elif adapter is not None:
-        return ("spec.params.adapter: cannot combine with adapter_pool "
-                "on one Server — the load-time fold serves ONE tenant, "
-                "the pool serves per-request adapters; point tenant "
-                "Servers at this pool via spec.engineRef instead "
-                "(docs/multi-tenant-lora.md)")
-    # Host KV tier / QoS cross-field checks (docs/paged-kv.md "Host
-    # tier and preemption"): the host tier and swap preemption only
-    # exist on the paged engine — without kv_paging: paged the replica
-    # would crash-loop at engine construction instead of surfacing a
-    # condition. Queue shares are fractions of max_queue in (0, 1].
-    for key in _QUEUE_SHARE_KEYS:
-        val = params.get(key)
-        if val is None:
-            continue
-        try:
-            share = float(val)
-        except (TypeError, ValueError):
-            return f"spec.params.{key}: {val!r} is not a number"
-        if not 0.0 < share <= 1.0:
-            return f"spec.params.{key}: {val} must be in (0, 1]"
-    paging = next((params[k] for k in ("kv_paging", "kvPaging",
-                                       "kvpaging")
-                   if params.get(k) is not None), "off")
-    host_pages = next((params[k] for k in _KV_HOST_PAGES_KEYS
-                       if params.get(k) is not None), 0)
-    if int(host_pages or 0) > 0 and str(paging) != "paged":
-        return ("spec.params.kv_host_pages: the host KV tier swaps "
-                "radix PAGES; set kv_paging: paged (docs/paged-kv.md)")
-    if str(params.get("preemption") or "off") == "swap" \
-            and str(paging) != "paged":
-        return ("spec.params.preemption: swap preempts at page "
-                "granularity; set kv_paging: paged (docs/paged-kv.md)")
-    # Grammar cross-field check (docs/structured-output.md): a cache-
-    # sizing knob without the mode serves nothing — same spec-typo shape
-    # as the pool-less LoRA knobs above.
-    if str(params.get("grammar") or "off") == "off":
-        knob_set = next((k for k in _GRAMMAR_CACHE_KEYS
-                         if params.get(k) is not None), None)
-        if knob_set is not None:
-            return (f"spec.params.{knob_set}: only applies with "
-                    "grammar: on (docs/structured-output.md)")
+    try:
+        ServeOptions.from_params(params)
+    except OptionError as err:
+        return str(err)
     # Mesh geometry (parallel/mesh.py): mesh_<axis> params select a
     # sharded engine. An unknown axis name is a typo the workload would
     # silently ignore (serving a single chip while the spec says eight);

@@ -350,14 +350,12 @@ class ServerReconciler:
                 f"shared engine Server {ref!r} not found")
             server.commit_status(ctx.client)
             return Result(requeue_after=2.0)
-        from runbooks_tpu.controller.common import _ADAPTER_POOL_KEYS
+        from runbooks_tpu.api.serve_params import OptionError, ServeOptions
 
-        host_params = ko.deep_get(host, "spec", "params", default={}) or {}
-        pool = next((host_params[k] for k in _ADAPTER_POOL_KEYS
-                     if host_params.get(k) is not None), 0)
         try:
-            pool = int(pool)
-        except (TypeError, ValueError):
+            pool = ServeOptions.from_params(ko.deep_get(
+                host, "spec", "params", default={}) or {}).adapter_pool
+        except OptionError:     # the host carries its own condition
             pool = 0
         if pool < 1:
             server.set_condition(

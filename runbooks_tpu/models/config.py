@@ -52,27 +52,8 @@ def resolve_collective_matmul_param(params: dict) -> Optional[str]:
     return None if val is None else check_collective_matmul(val)
 
 
-# Speculative decoding on the serve decode path (serve/engine.py,
-# docs/speculative-decoding.md): "off" | "ngram" (model-free
-# prompt-lookup drafting + one batched verify forward). Same
-# single-source-of-truth pattern as collective_matmul: the controller's
-# jax-free validation table mirrors this enum.
-SPECULATIVE_MODES = ("off", "ngram")
-
 # Token-mixer kinds a layer pattern may name (ModelConfig.layer_types).
 LAYER_KINDS = ("full_attention", "linear_attention")
-
-
-def check_speculative(mode: str) -> str:
-    """Validate a speculative mode string (single source for the error
-    message — engine, serve entrypoint, and trainer-adjacent readers all
-    funnel through here)."""
-    mode = str(mode)
-    if mode not in SPECULATIVE_MODES:
-        raise ValueError(
-            f"unknown speculative {mode!r}; expected "
-            f"{'|'.join(SPECULATIVE_MODES)}")
-    return mode
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,50 +188,6 @@ class ModelConfig:
     # decode path packed weights. The transformer dispatches on the param
     # type (QuantizedArray), so this field only drives the loaders.
     quantize: str = "none"
-    # Serving KV-cache quantization block: int8 k/v + per-slot-per-head f32
-    # scales. None = follow `quantize` (any quantized weight tier also
-    # quantizes the cache); True/False force.
-    quantize_kv: Optional[bool] = None
-
-    # Speculative decoding on the serve decode path
-    # (docs/speculative-decoding.md): "off" | "ngram". "ngram" turns on
-    # model-free prompt-lookup drafting — a host-side per-slot n-gram
-    # index over each request's prompt + generated tokens proposes up to
-    # `draft_tokens` continuation tokens, and one batched [B, K+1]
-    # verify forward scores them for every slot at once. Decode is
-    # HBM-bandwidth-bound, so each verified-accepted draft token is
-    # nearly free bandwidth-wise (the roofline gauge
-    # xla_program_bandwidth_bound confirms it live).
-    speculative: str = "off"
-    # Draft window K: tokens proposed (and verified) per speculative
-    # step. None = backend default (utils/hw.backend_tuning). Fixed at
-    # engine construction — K is a static program shape, never a
-    # per-request knob.
-    draft_tokens: Optional[int] = None
-    # Prompt-lookup n-gram sizes: the drafter matches the trailing
-    # n-gram of the context for n from ngram_max down to ngram_min and
-    # proposes the tokens that followed its most recent occurrence.
-    ngram_max: int = 3
-    ngram_min: int = 1
-
-    # Multi-tenant batched LoRA serving (serve/lora_pool.py,
-    # docs/multi-tenant-lora.md): adapter_pool > 0 gives the serve engine
-    # an HBM-resident pool of that many LoRA adapters (plus one all-zero
-    # trash lane for base-only rows) and compiles adapter-aware
-    # prefill/decode/verify programs — per-request `adapter` then selects
-    # a lane per slot inside ONE batched dispatch. 0 (default) = off: the
-    # engine compiles the plain program set, and a Server-level
-    # `adapter: <path>` folds the weights at load time instead
-    # (train/lora.py apply_lora — the single-tenant baseline).
-    adapter_pool: int = 0
-    # Static rank bucket every pool lane is padded to. A per-tenant rank
-    # would be a per-tenant compiled program; adapters trained at r <=
-    # lora_rank zero-pad (exact), larger ranks are rejected at load.
-    lora_rank: int = 8
-    # Targets eligible for pooled injection (dotted paths into
-    # params["layers"], same vocabulary as train/lora.py). Attention-only
-    # by default, mirroring the training default.
-    lora_targets: tuple = ("attn.wq", "attn.wk", "attn.wv", "attn.wo")
 
     # Training-time behavior. "nothing_saveable" = full remat (memory-safe
     # default); "dots_saveable" / "dots_with_no_batch_dims_saveable" save

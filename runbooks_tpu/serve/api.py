@@ -16,6 +16,7 @@ model, checkpoint, max_slots, port, tokenizer) or programmatically via
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 import json
 import threading
 import time
@@ -25,6 +26,7 @@ from typing import Any, Optional, Tuple
 
 from aiohttp import web
 
+from runbooks_tpu.api.serve_params import ServeOptions
 from runbooks_tpu.models.config import ModelConfig, get_config
 from runbooks_tpu.obs import flight as obs_flight
 from runbooks_tpu.obs import incident as obs_incident
@@ -92,8 +94,6 @@ def load_model(params: dict, mesh=None) -> Tuple[ModelConfig, Any]:
     quantized here layer-by-layer before serving, so host RAM peaks ~one
     f32 layer above the packed size instead of holding bf16 and packed
     copies of a 70B model at once."""
-    import dataclasses as _dc
-
     import jax
 
     from runbooks_tpu.ops.quantization import (
@@ -108,8 +108,6 @@ def load_model(params: dict, mesh=None) -> Tuple[ModelConfig, Any]:
                      **params.get("model_overrides", {}))
     quantize = resolve_quantize_mode(params, cfg)
     overrides = {"quantize": quantize}
-    if params.get("quantize_kv") is not None:
-        overrides["quantize_kv"] = bool(params["quantize_kv"])
     # Overlapped ring tensor parallelism for the serve engine's
     # prefill/decode programs (docs/tensor-parallel-performance.md);
     # takes effect with a mesh_tensor > 1 serving mesh. One shared
@@ -121,7 +119,7 @@ def load_model(params: dict, mesh=None) -> Tuple[ModelConfig, Any]:
     cm = resolve_collective_matmul_param(params)
     if cm is not None:
         overrides["collective_matmul"] = cm
-    cfg = _dc.replace(cfg, **overrides)
+    cfg = dataclasses.replace(cfg, **overrides)
     ckpt_dir = params.get("checkpoint") or contract.model_dir()
     import os
 
@@ -184,21 +182,9 @@ def load_model(params: dict, mesh=None) -> Tuple[ModelConfig, Any]:
     # path is tested against. Folding happens BEFORE quantization so the
     # quantizer sees the merged weights; a pre-quantized checkpoint has
     # no headroom to fold into and must use the pool instead.
+    # (Beside a pool the key is refused: ServeOptions.from_params.)
     adapter = params.get("adapter")
-    pool_raw = _param_any(params, "adapter_pool", "adapterPool",
-                          "adapterpool", default=0)
-    if adapter and int(pool_raw or 0):
-        # Ambiguous spec (controller validate_params rejects it; this
-        # guards hand-written params.json): folding would hard-wire ONE
-        # tenant into a pool meant for many, and silently ignoring the
-        # fold would serve the base model to clients expecting the
-        # adapter.
-        raise RuntimeError(
-            "params set both `adapter` and `adapter_pool`: the load-time "
-            "fold and the pooled engine are mutually exclusive serving "
-            "modes — drop `adapter` (clients pass it per request) or the "
-            "pool (docs/multi-tenant-lora.md)")
-    if adapter and not int(pool_raw or 0):
+    if adapter:
         if tree_quantize_mode(model_params) != "none":
             raise RuntimeError(
                 "cannot fold adapter into a pre-quantized checkpoint "
@@ -219,9 +205,7 @@ def load_model(params: dict, mesh=None) -> Tuple[ModelConfig, Any]:
         print(f"serve: checkpoint is quantized {stored} but params "
               f"requested quantize={quantize}; serving the stored "
               f"{stored} weights", flush=True)
-        import dataclasses as _dc2
-
-        cfg = _dc2.replace(cfg, quantize=stored)
+        cfg = dataclasses.replace(cfg, quantize=stored)
     if mesh is not None:
         model_params = jax.device_put(
             model_params, mesh_shardings(model_params), donate=True)
@@ -542,131 +526,29 @@ class EngineWorker:
                 fut.set_exception(RuntimeError("engine worker stopped"))
 
 
-def create_server(cfg: ModelConfig, model_params, tokenizer=None,
-                  max_slots: int = 8,
-                  max_seq_len: Optional[int] = None,
-                  mesh=None, warmup: bool = False,
-                  warm_prefix: bool = False,
-                  auto_prefix_chat: bool = False,
-                  prefill_budget: Optional[int] = None,
-                  decode_chunk: Optional[int] = None,
-                  prefix_cache_size: Optional[int] = None,
-                  max_queue: Optional[int] = None,
-                  request_timeout_s: Optional[float] = None,
-                  drain_timeout_s: float = 30.0,
-                  kv_paging: bool = False,
-                  page_size: int = 16,
-                  num_pages: Optional[int] = None,
-                  speculative: Optional[str] = None,
-                  draft_tokens: Optional[int] = None,
-                  ngram_max: Optional[int] = None,
-                  ngram_min: Optional[int] = None,
-                  adapter_pool: Optional[int] = None,
-                  lora_rank: Optional[int] = None,
-                  adapter_dir: Optional[str] = None,
-                  kv_host_pages: int = 0,
-                  preemption: str = "off",
-                  queue_shares: Optional[dict] = None,
-                  grammar: str = "off",
-                  grammar_cache_size: Optional[int] = None,
-                  ) -> web.Application:
-    """max_queue bounds the admission queue (full -> HTTP 429 with
-    Retry-After); request_timeout_s is the default per-request wall-clock
-    deadline (body field "timeout" overrides per request; expiry finishes
-    the request with finish_reason "deadline"; 0/None = no default
-    deadline); drain_timeout_s bounds the SIGTERM graceful drain
-    (docs/fault-tolerance.md).
-
-    kv_paging=True serves from the paged KV engine (serve/paging.py):
-    the cache becomes num_pages pages of page_size tokens with radix-tree
-    prefix sharing across requests, and admission gates on free pages
-    instead of dense slot rows — docs/paged-kv.md covers sizing
-    page_size/num_pages (default num_pages matches the dense worst-case
-    reservation).
-
-    speculative="ngram" turns on prompt-lookup speculative decoding on
-    the decode path (docs/speculative-decoding.md): up to draft_tokens
-    tokens per slot drafted from an n-gram index (ngram_max/ngram_min)
-    over each request's own context and verified in one batched
-    forward. None = follow the model config; greedy outputs are
-    token-for-token identical with speculation on or off.
-
-    adapter_pool >= 1 (None = follow cfg.adapter_pool) turns on
-    multi-tenant batched LoRA serving (serve/lora_pool.py,
-    docs/multi-tenant-lora.md): per-request `adapter` names pin HBM
-    pool lanes at admission and heterogeneous tenants batch in one
-    dispatch. lora_rank is the static rank bucket; adapter_dir roots
-    relative adapter names (absolute paths pass through).
-
-    kv_host_pages >= 1 (paged engines only) adds the host-RAM KV swap
-    tier (docs/paged-kv.md): LRU-evicted radix pages copy to pinned
-    host buffers instead of dropping, and returning sessions swap back
-    in at device_put cost instead of re-prefilling. preemption="swap"
-    lets the engine preempt the lowest-priority active slot under
-    pressure (pages swap to host, the request re-queues with generated
-    tokens intact). queue_shares maps priority class -> fraction of
-    max_queue that class may occupy (admission 429s a class past its
-    share while others still fit).
-
-    grammar="on" turns on grammar-constrained structured output
-    (serve/grammar.py, docs/structured-output.md): request bodies may
-    carry `response_format` (a JSON-schema subset or raw EBNF), which
-    compiles host-side to a token-level DFA over this tokenizer's vocab
-    (LRU cache of grammar_cache_size entries keyed on grammar hash +
-    tokenizer fingerprint) and constrains sampling via a bool mask
-    operand — no per-grammar XLA compile. Constrained requests finish
-    with finish_reason "grammar_complete"."""
-    if not request_timeout_s:
-        # 0 disables, like the other *_s knobs — a validated config of 0
-        # must mean "no deadline", not "400 every deadline-less request".
-        request_timeout_s = None
+def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
+                  mesh=None, **options) -> web.Application:
+    """The serving app over a new engine. ``options`` are fields of
+    ServeOptions (api/serve_params.py, where each is documented);
+    `kv_paging` picks the engine class."""
+    options = ServeOptions(**options)
+    # 0 disables, like the other *_s knobs — a validated config of 0
+    # must mean "no deadline", not "400 every deadline-less request".
+    request_timeout_s = options.request_timeout_s or None
     tokenizer = tokenizer or load_tokenizer(None)
-    if kv_paging:
+    engine_cls = InferenceEngine
+    if options.kv_paging == "paged":
         from runbooks_tpu.serve.paging import PagedInferenceEngine
 
-        engine = PagedInferenceEngine(
-            cfg, model_params, max_slots=max_slots,
-            max_seq_len=max_seq_len, mesh=mesh,
-            prefill_budget=prefill_budget, decode_chunk=decode_chunk,
-            prefix_cache_size=prefix_cache_size, max_queue=max_queue,
-            page_size=page_size, num_pages=num_pages,
-            speculative=speculative, draft_tokens=draft_tokens,
-            ngram_max=ngram_max, ngram_min=ngram_min,
-            adapter_pool=adapter_pool, lora_rank=lora_rank,
-            adapter_dir=adapter_dir,
-            kv_host_pages=kv_host_pages, preemption=preemption,
-            queue_shares=queue_shares, grammar=grammar,
-            grammar_cache_size=grammar_cache_size, tokenizer=tokenizer)
-    else:
-        engine = InferenceEngine(cfg, model_params, max_slots=max_slots,
-                                 max_seq_len=max_seq_len, mesh=mesh,
-                                 prefill_budget=prefill_budget,
-                                 decode_chunk=decode_chunk,
-                                 prefix_cache_size=prefix_cache_size,
-                                 max_queue=max_queue,
-                                 speculative=speculative,
-                                 draft_tokens=draft_tokens,
-                                 ngram_max=ngram_max,
-                                 ngram_min=ngram_min,
-                                 adapter_pool=adapter_pool,
-                                 lora_rank=lora_rank,
-                                 adapter_dir=adapter_dir,
-                                 preemption=preemption,
-                                 queue_shares=queue_shares,
-                                 grammar=grammar,
-                                 grammar_cache_size=grammar_cache_size,
-                                 tokenizer=tokenizer)
-    if auto_prefix_chat:
+        engine_cls = PagedInferenceEngine
+    engine = engine_cls(cfg, model_params, mesh=mesh, tokenizer=tokenizer,
+                        **dataclasses.asdict(options))
+    if options.auto_prefix_chat:
         engine._refuse_prefix()
-    if warmup:
-        # Pre-compile all buckets before readiness flips. warm_prefix
-        # (params.json: warm_prefix) additionally compiles the prefix-KV
-        # builder per bucket so runtime /v1/prefix registrations never
-        # compile on the serving thread (cost: len(buckets) extra startup
-        # compiles).
-        engine.warmup(prefix_build=warm_prefix)
-    worker = EngineWorker(engine,
-                          warn_cold_prefix=not (warmup and warm_prefix))
+    if options.warmup:
+        engine.warmup(prefix_build=options.warm_prefix)
+    worker = EngineWorker(engine, warn_cold_prefix=not (
+        options.warmup and options.warm_prefix))
     # Flight/trace identity: this process's events label as the serving
     # tier in merged timelines and /debug/flight envelopes.
     obs_flight.set_component("serve")
@@ -798,7 +680,7 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
         reg.set_counter("serve_prefix_hits_total", eng.prefix_hits,
                         help_text="Admissions whose prompt matched a "
                                   "registered prefix.")
-        if eng.speculative != "off":
+        if eng.options.speculative != "off":
             # Speculative decoding (serve/engine.py verify path,
             # docs/speculative-decoding.md): draft volume vs verified
             # acceptance — the accept rate is the whole economics of
@@ -812,7 +694,7 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
                             eng.spec_accepted,
                             help_text="Draft tokens verified-accepted "
                                       "by the batched verify forward.")
-        if eng.grammar != "off":
+        if eng.options.grammar != "off":
             # Grammar-constrained structured output (serve/grammar.py,
             # docs/structured-output.md): request volume, compile-cache
             # economics, and spec-draft truncation — absolute mirrors of
@@ -1399,7 +1281,7 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
             # each choice's spans stay distinguishable.
             for i, r in enumerate(reqs):
                 r.request_id = rid if len(reqs) == 1 else f"{rid}/{i}"
-        if auto_prefix_chat and body.get("_chat"):
+        if options.auto_prefix_chat and body.get("_chat"):
             # Multi-turn chat: this turn's prompt KV becomes the next
             # turn's prefix (the rendered history strictly extends).
             for r in reqs:
@@ -1580,9 +1462,10 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
         print("serve: draining (no new admissions; finishing in-flight "
               "requests)", flush=True)
         drained = await asyncio.get_running_loop().run_in_executor(
-            None, worker.drain, drain_timeout_s)
+            None, worker.drain, options.drain_timeout_s)
         if not drained:
-            print(f"serve: drain timed out after {drain_timeout_s}s; "
+            print(f"serve: drain timed out after "
+                  f"{options.drain_timeout_s}s; "
                   "abandoning remaining requests", flush=True)
         # stop() joins the worker thread (up to 5 s) — off the loop too,
         # or the join stalls the final SSE flushes it is waiting behind
@@ -1591,15 +1474,6 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None,
 
     app.on_cleanup.append(on_cleanup)
     return app
-
-
-def _param_any(params: dict, *keys: str, default=None):
-    """First present spelling of a params key (snake_case params.json,
-    the reference's camelCase spec style, the PARAM_* env lowercase)."""
-    for k in keys:
-        if params.get(k) is not None:
-            return params[k]
-    return default
 
 
 def main() -> int:
@@ -1613,6 +1487,8 @@ def main() -> int:
     if age is not None:
         STARTUP.add("startup.imports", age)
     params = contract.load_params()
+    # A bad spec fails here, before the backend and the weights.
+    options = ServeOptions.from_params(params)
     # Multi-host slices: form the jax.distributed runtime before any JAX use.
     from runbooks_tpu.parallel.distributed import initialize
 
@@ -1627,11 +1503,9 @@ def main() -> int:
 
     # mesh_* params select sharded serving (e.g. mesh_tensor: 8 for TP).
     mesh = None
-    import dataclasses as _dc
-
     from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
 
-    mesh_keys = {f.name for f in _dc.fields(MeshConfig)}
+    mesh_keys = {f.name for f in dataclasses.fields(MeshConfig)}
     mesh_args = {k[len("mesh_"):]: int(v) for k, v in params.items()
                  if k.startswith("mesh_") and k[len("mesh_"):] in mesh_keys}
     with STARTUP.timed("startup.weights"):
@@ -1658,95 +1532,9 @@ def main() -> int:
         "phases": STARTUP.snapshot(),
     }), flush=True)
 
-    num_pages_raw = _param_any(params, "num_pages", "numPages", "numpages")
-    pool_raw = _param_any(params, "adapter_pool", "adapterPool",
-                          "adapterpool")
-    rank_raw = _param_any(params, "lora_rank", "loraRank", "lorarank")
-    adapter_dir_raw = _param_any(params, "adapter_dir", "adapterDir",
-                                 "adapterdir")
-    draft_raw = _param_any(params, "draft_tokens", "draftTokens",
-                           "drafttokens")
-    ngram_max_raw = _param_any(params, "ngram_max", "ngramMax", "ngrammax")
-    ngram_min_raw = _param_any(params, "ngram_min", "ngramMin", "ngrammin")
-    host_pages_raw = _param_any(params, "kv_host_pages", "kvHostPages",
-                                "kvhostpages")
-    preemption_raw = params.get("preemption")
-    grammar_raw = params.get("grammar")
-    grammar_cache_raw = _param_any(params, "grammar_cache_size",
-                                   "grammarCacheSize", "grammarcachesize")
-    # Per-class queue shares (queue_share_interactive: 0.5 etc.) fold
-    # into the queue_shares dict the engine validates.
-    queue_shares = {}
-    for cls in ("interactive", "standard", "batch"):
-        camel = f"queueShare{cls.capitalize()}"
-        raw = _param_any(params, f"queue_share_{cls}", camel,
-                         camel.lower())
-        if raw is not None:
-            queue_shares[cls] = float(raw)
     t_engine = time.perf_counter()
-    app = create_server(
-        cfg, model_params, tokenizer,
-        max_slots=int(params.get("max_slots", 8)),
-        max_seq_len=params.get("max_seq_len"),
-        mesh=mesh,
-        warmup=bool(params.get("warmup", True)),
-        warm_prefix=bool(params.get("warm_prefix", False)),
-        auto_prefix_chat=bool(params.get("auto_prefix_chat", False)),
-        prefix_cache_size=(int(params["prefix_cache_size"])
-                           if params.get("prefix_cache_size") is not None
-                           else None),
-        prefill_budget=(int(params["prefill_budget"])
-                        if params.get("prefill_budget") is not None
-                        else None),
-        max_queue=(int(params["max_queue"])
-                   if params.get("max_queue") is not None else None),
-        request_timeout_s=(float(params["request_timeout_s"])
-                           if params.get("request_timeout_s") is not None
-                           else None),
-        drain_timeout_s=float(params.get("drain_timeout_s", 30.0)),
-        # Paged KV serving (docs/paged-kv.md): `kv_paging: paged` is the
-        # validated spelling (controller validate_params, every case the
-        # PARAM_* env round-trip produces); bools are accepted for
-        # hand-written params.json.
-        kv_paging=str(_param_any(params, "kv_paging", "kvPaging",
-                                 "kvpaging", default="off")).lower()
-        in ("paged", "on", "true", "1"),
-        page_size=int(_param_any(params, "page_size", "pageSize",
-                                 "pagesize", default=16)),
-        num_pages=(int(num_pages_raw)
-                   if num_pages_raw is not None else None),
-        # Speculative decoding (docs/speculative-decoding.md):
-        # `speculative: ngram` is the validated spelling (controller
-        # validate_params); the engine re-validates via
-        # check_speculative before warmup compiles anything.
-        speculative=(str(params["speculative"])
-                     if params.get("speculative") is not None else None),
-        draft_tokens=int(draft_raw) if draft_raw is not None else None,
-        ngram_max=int(ngram_max_raw) if ngram_max_raw is not None else None,
-        ngram_min=int(ngram_min_raw) if ngram_min_raw is not None else None,
-        # Multi-tenant batched LoRA serving (docs/multi-tenant-lora.md):
-        # adapter_pool sizes the HBM adapter pool, lora_rank the static
-        # rank bucket, adapter_dir the root for relative adapter names.
-        # (A pool-less `adapter: <path>` already folded at load_model.)
-        adapter_pool=int(pool_raw) if pool_raw is not None else None,
-        lora_rank=int(rank_raw) if rank_raw is not None else None,
-        adapter_dir=str(adapter_dir_raw) if adapter_dir_raw else None,
-        # Host-RAM KV swap tier + QoS preemption (docs/paged-kv.md):
-        # `preemption: swap` is the validated spelling (controller
-        # validate_params); the engine re-validates both before any
-        # cache allocation.
-        kv_host_pages=(int(host_pages_raw)
-                       if host_pages_raw is not None else 0),
-        preemption=(str(preemption_raw)
-                    if preemption_raw is not None else "off"),
-        queue_shares=queue_shares or None,
-        # Grammar-constrained structured output
-        # (docs/structured-output.md): `grammar: on` is the validated
-        # spelling (controller validate_params); the engine re-validates
-        # before warmup compiles anything.
-        grammar=(str(grammar_raw) if grammar_raw is not None else "off"),
-        grammar_cache_size=(int(grammar_cache_raw)
-                            if grammar_cache_raw is not None else None))
+    app = create_server(cfg, model_params, tokenizer, mesh=mesh,
+                        **dataclasses.asdict(options))
     # Engine construction (KV cache allocation, jit wrappers): what
     # create_server took outside the warm-up it ran.
     STARTUP.add("startup.engine", time.perf_counter() - t_engine

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from runbooks_tpu.api.serve_params import QOS_CLASSES, ServeOptions
 from runbooks_tpu.models.config import ModelConfig
 from runbooks_tpu.models.transformer import KVCache, forward, project_logits
 from runbooks_tpu.obs import device as obs_device
@@ -110,7 +111,7 @@ def _observe_request_done(req: "Request", now: float) -> None:
 # the class as X-Priority and spills batch traffic first
 # (serve/gateway.py); the strings are the public API surface
 # (docs/api.md `priority`).
-PRIORITY_RANK = {"interactive": 0, "standard": 1, "batch": 2}
+PRIORITY_RANK = {cls: rank for rank, cls in enumerate(QOS_CLASSES)}
 
 
 class EngineOverloaded(RuntimeError):
@@ -558,156 +559,46 @@ class InferenceEngine:
     """Batched generation over a fixed slot pool. Thread-unsafe by design;
     drive it from one loop (the API server wraps it in a single worker)."""
 
-    # Preemption swaps a victim's pages into the radix tree — only the
-    # paged engine has pages, so the dense constructor rejects
-    # preemption="swap" (serve/paging.py flips this).
-    _supports_preemption = False
+    # The ServeOptions.kv_paging this class serves (serve/paging.py:
+    # "paged"); create_server picks the class by it.
+    kv_paging = "off"
     # Per-slot recurrent state (a layer pattern with linear-attention
     # layers) lives beside the dense slot pool's K/V rows; a page table
     # has one kind of page (serve/paging.py flips this).
     _supports_recurrent_state = True
 
-    def __init__(self, cfg: ModelConfig, params: Params, *,
-                 max_slots: int = 8, max_seq_len: Optional[int] = None,
-                 seed: int = 0, mesh=None,
-                 prefill_budget: Optional[int] = None,
-                 decode_chunk: Optional[int] = None,
-                 prefix_cache_size: Optional[int] = None,
-                 quantize_kv: Optional[bool] = None,
-                 max_queue: Optional[int] = None,
-                 speculative: Optional[str] = None,
-                 draft_tokens: Optional[int] = None,
-                 ngram_max: Optional[int] = None,
-                 ngram_min: Optional[int] = None,
-                 adapter_pool: Optional[int] = None,
-                 lora_rank: Optional[int] = None,
-                 adapter_dir: Optional[str] = None,
-                 preemption: str = "off",
-                 queue_shares: Optional[dict] = None,
-                 grammar: str = "off",
-                 grammar_cache_size: Optional[int] = None,
-                 tokenizer=None):
-        """mesh: optional jax.sharding.Mesh for sharded serving — params
+    def __init__(self, cfg: ModelConfig, params: Params, *, seed: int = 0,
+                 mesh=None, tokenizer=None, **options):
+        """``options`` are fields of ServeOptions (api/serve_params.py,
+        where each is documented); the record is kept as ``self.options``.
+
+        mesh: optional jax.sharding.Mesh for sharded serving — params
         shard by the model's logical axes (tensor parallelism over heads/
         mlp, fsdp over embed) and the KV cache shards batch over data/fsdp
         and kv-heads over tensor. All jitted steps then run SPMD under the
         mesh; XLA inserts the per-layer collectives.
 
-        prefill_budget: max prompt tokens (bucket-padded) admitted per
-        step. Prefills run serially before the step's decode, so an
-        unbounded admission burst stalls every in-flight request's next
-        token; the budget spreads a burst over steps, bounding inter-token
-        latency while decode throughput continues. Default: max_seq_len
-        (≈ one full-length prefill worth per step). A single over-budget
-        request still admits alone — the budget shapes bursts, it never
-        starves.
-
-        decode_chunk: decode steps run on-device per host round-trip.
-        Each step() call scans `chunk` forwards in one jit call, tracking
-        EOS / max_tokens / out-of-room per slot on device, and replays the
-        emitted tokens on the host afterwards. Larger chunks amortize the
-        host↔device sync (the dominant per-token cost at small batch on
-        TPU) at the price of admission latency ≤ chunk-1 extra steps and
-        streaming granularity of ≤ chunk tokens. Default: 8 on TPU, 1
-        elsewhere (CPU dispatch is cheap and tests want step-at-a-time).
-
-        quantize_kv: store the slot-pool KV cache as int8 with per-slot-
-        per-head f32 scales (models/transformer.KVCache). The decode step
-        is HBM-bandwidth-bound, so halving the cache bytes it streams buys
-        tok/s directly and doubles max_slots x max_seq_len at fixed memory.
-        Prefill still computes attention in the activation dtype (the
-        scratch rows are unquantized); rows are quantized once at the
-        splice into the pool, and decode reads dequantize in-register.
-        Pairs with weight-only quantized params (ops/quantization.py) for
-        the reference's 4-bit serving tier. None = follow the config: any
-        quantized-weight tier (cfg.quantize != "none") also quantizes the
-        cache unless cfg.quantize_kv forces otherwise.
-
-        max_queue: bound on the admission queue (waiting requests, not
-        in-flight slots). submit() past the bound raises the typed
-        EngineOverloaded instead of growing the list without limit — at
-        overload, every queued request's deadline/latency degrades
-        together, so shedding with a 429 beats accepting work the engine
-        cannot serve in time. Default: max(16, 4 * max_slots).
-
-        speculative / draft_tokens / ngram_max / ngram_min: speculative
-        decoding (docs/speculative-decoding.md). None = follow the
-        config (cfg.speculative etc.; draft_tokens then defaults via
-        utils/hw.backend_tuning). "ngram" drives the decode loop through
-        draft-then-verify: a host-side prompt-lookup index proposes up
-        to draft_tokens continuation tokens per slot and one [B, K+1]
-        verify forward scores every slot's drafts at once; steps with no
-        draft anywhere fall back to the plain decode chunk.
-
-        adapter_pool / lora_rank / adapter_dir: multi-tenant batched
-        LoRA serving (serve/lora_pool.py, docs/multi-tenant-lora.md).
-        adapter_pool > 0 (None = follow cfg.adapter_pool) keeps that
-        many LoRA adapters resident in HBM as a stacked pool and
-        compiles adapter-aware prefill/decode/verify programs; each
-        request's `adapter` name pins a pool lane at admission (paged in
-        from artifact storage on demand, LRU-evicted among unpinned
-        lanes) and base-only rows ride the all-zero trash lane, so
-        mixed-tenant traffic batches in ONE dispatch. lora_rank is the
-        static rank bucket every lane pads to; adapter_dir roots
-        relative adapter names.
-
-        preemption: "off" (default) or "swap" (paged engine only).
-        With "swap", a queue head blocked on pages/slots preempts the
-        lowest-class active slot at a step boundary: the victim's
-        written pages are adopted into the radix tree (where they may
-        later swap to the host tier), the request re-queues with its
-        generated tokens intact, and it resumes via a radix match on
-        its own history (docs/paged-kv.md).
-
-        queue_shares: optional {class: share} dict bounding each QoS
-        class to ceil(share * max_queue) queued entries (share in
-        (0, 1], default 1.0 per class) — a batch flood then sheds with
-        429 before it can fill the whole queue against interactive
-        traffic.
-
-        grammar / grammar_cache_size / tokenizer: grammar-constrained
-        structured output (serve/grammar.py,
-        docs/structured-output.md). grammar: "on" compiles each
-        request's `response_format` (JSON-schema subset or EBNF) into a
-        token-level DFA — LRU-cached, grammar_cache_size entries
-        (default 64), keyed on (grammar hash, tokenizer fingerprint) —
-        and every dispatch then carries a [rows, vocab] bool
-        allowed-token mask operand (all-True rows for unconstrained
-        slots, so mixed traffic stays ONE program and warmup's masked
-        signatures are the steady-state ones). The tokenizer is needed
-        to map DFA bytes onto token ids; passing it with grammar: "off"
-        just exposes `tokenizer_fingerprint` (/debug/programs)."""
+        tokenizer: maps grammar bytes onto token ids (needed with grammar:
+        on); with grammar off it only feeds `tokenizer_fingerprint`
+        (/debug/programs)."""
+        self.options = options = ServeOptions(
+            **{**options, "kv_paging": self.kv_paging})
         self.cfg = cfg
         self.mesh = mesh
-        self.prefill_budget = prefill_budget
+        max_slots = self.max_slots = options.max_slots
+        # What the engine derives where the record says "default": the
+        # two backend-tuned shapes here, the rest below beside its input.
         tuning = backend_tuning()
-        if decode_chunk is None:
-            decode_chunk = tuning["decode_chunk"]
-        if decode_chunk < 1:
-            raise ValueError(f"decode_chunk must be >= 1, got {decode_chunk}")
-        self.decode_chunk = decode_chunk
-        from runbooks_tpu.models.config import check_speculative
-
-        self.speculative = check_speculative(
-            speculative if speculative is not None else cfg.speculative)
-        self.draft_tokens = int(
-            draft_tokens if draft_tokens is not None
-            else cfg.draft_tokens if cfg.draft_tokens is not None
-            else tuning["draft_tokens"])
-        if self.draft_tokens < 1:
-            raise ValueError(
-                f"draft_tokens must be >= 1, got {self.draft_tokens}")
-        self.ngram_max = int(ngram_max if ngram_max is not None
-                             else cfg.ngram_max)
-        self.ngram_min = int(ngram_min if ngram_min is not None
-                             else cfg.ngram_min)
-        # The index constructor validates 1 <= ngram_min <= ngram_max;
-        # probe even when speculation is off so a bad config fails at
-        # construction, not when someone flips speculative on.
-        self._spec_index: Optional[NgramDraftIndex] = NgramDraftIndex(
-            max_slots, self.ngram_max, self.ngram_min)
-        if self.speculative == "off":
-            self._spec_index = None
+        self.decode_chunk = (options.decode_chunk
+                             if options.decode_chunk is not None
+                             else tuning["decode_chunk"])
+        self.draft_tokens = (options.draft_tokens
+                             if options.draft_tokens is not None
+                             else tuning["draft_tokens"])
+        self._spec_index: Optional[NgramDraftIndex] = None
+        if options.speculative != "off":
+            self._spec_index = NgramDraftIndex(
+                max_slots, options.ngram_max, options.ngram_min)
         # Speculation accounting (cumulative; /metrics + spec_stats()).
         self.spec_drafted = 0        # draft tokens proposed
         self.spec_accepted = 0       # draft tokens verified-accepted
@@ -719,19 +610,20 @@ class InferenceEngine:
             raise ValueError(
                 "pipeline (stage) parallelism is a training-path feature; "
                 "serve with tensor/data parallelism instead (mesh_tensor)")
-        if quantize_kv is None:
-            quantize_kv = (cfg.quantize_kv if cfg.quantize_kv is not None
-                           else cfg.quantize != "none")
-        self.quantize_kv = bool(quantize_kv)
+        # The KV pool is int8 when quantize_kv says so, else exactly when
+        # the weights are quantized: decode is HBM-bandwidth-bound, so the
+        # two halves of the bytes it streams shrink together.
+        self.quantize_kv = (options.quantize_kv
+                            if options.quantize_kv is not None
+                            else cfg.quantize != "none")
         # What the recurrent-state invariant (make_prefill_fn) rules out,
         # refused here with the reason: nothing below would fail loudly.
-        if self.speculative != "off":
+        if options.speculative != "off":
             self._refuse_recurrent(
                 "speculative decoding",
                 "a rejected draft is rolled back by not advancing the K/V "
                 "cursor, and a recurrent state has no cursor to hold back")
-        if int(adapter_pool if adapter_pool is not None
-               else cfg.adapter_pool) > 0:
+        if options.adapter_pool > 0:
             self._refuse_recurrent(
                 "an adapter pool",
                 "pooled LoRA lanes target the attention projections of a "
@@ -776,21 +668,19 @@ class InferenceEngine:
 
             self._mesh_ctx = contextlib.nullcontext
         self.params = params
-        self.max_slots = max_slots
-        self.max_seq_len = max_seq_len or cfg.max_seq_len
+        self.max_seq_len = options.max_seq_len or cfg.max_seq_len
         self._pad_slot = self.max_seq_len  # trash slot index
         # Multi-tenant LoRA adapter pool (serve/lora_pool.py,
         # docs/multi-tenant-lora.md): None when off — the engine then
         # compiles the plain (adapter-free) program set and requests
         # carrying an `adapter` 400 at validation.
-        pool_size = int(adapter_pool if adapter_pool is not None
-                        else cfg.adapter_pool)
         self.adapters = None
-        if pool_size > 0:
+        if options.adapter_pool > 0:
             from runbooks_tpu.serve.lora_pool import AdapterPool
 
-            self.adapters = AdapterPool(cfg, pool_size=pool_size,
-                                        rank=lora_rank, root=adapter_dir)
+            self.adapters = AdapterPool(
+                cfg, options.adapter_pool, options.lora_rank,
+                options.lora_targets, root=options.adapter_dir)
             if mesh is not None:
                 from runbooks_tpu.ops.lora import \
                     adapter_pool_logical_axes
@@ -806,52 +696,28 @@ class InferenceEngine:
         # operand every adapter-aware dispatch gathers A/B by.
         self.adapter_slots = np.full(max_slots, -1, np.int32)
         self._init_cache()
-        if self.prefill_budget is None:
-            self.prefill_budget = self.max_seq_len
-        self.max_queue = (max_queue if max_queue is not None
+        self.prefill_budget = (options.prefill_budget
+                               if options.prefill_budget is not None
+                               else self.max_seq_len)
+        self.max_queue = (options.max_queue
+                          if options.max_queue is not None
                           else max(16, 4 * max_slots))
-        if preemption not in ("off", "swap"):
-            raise ValueError(
-                f"preemption must be 'off' or 'swap', got {preemption!r}")
-        if preemption == "swap" and not self._supports_preemption:
-            raise ValueError(
-                "preemption: swap needs the paged engine (pages are the "
-                "unit a preempted slot swaps at); set kv_paging: paged "
-                "(docs/paged-kv.md)")
-        self.preemption = preemption
-        # Per-class queued-entry bounds from queue_shares; missing
-        # classes default to the full queue.
-        shares = dict(queue_shares or {})
-        for cls, share in shares.items():
-            if cls not in PRIORITY_RANK:
-                raise ValueError(
-                    f"queue_shares: unknown class {cls!r} (expected one "
-                    f"of {sorted(PRIORITY_RANK)})")
-            if not 0.0 < float(share) <= 1.0:
-                raise ValueError(
-                    f"queue_shares[{cls!r}] must be in (0, 1], got "
-                    f"{share}")
-        self.queue_shares = {
-            cls: float(shares.get(cls, 1.0)) for cls in PRIORITY_RANK}
+        # Per-class queued-entry bounds.
         self._class_bounds = {
-            cls: max(1, int(np.ceil(self.max_queue * s)))
-            for cls, s in self.queue_shares.items()}
+            cls: max(1, int(np.ceil(self.max_queue * share)))
+            for cls, share in options.queue_shares.items()}
         # Grammar-constrained decoding (serve/grammar.py): with
         # grammar="on" every dispatch carries a gmask operand, so the
         # masked program variants REPLACE the plain ones in the census
         # (same discipline as the adapter pool's apool/aslots operands —
         # variants never multiply the compiled set).
-        if grammar not in ("off", "on"):
-            raise ValueError(
-                f"grammar must be 'off' or 'on', got {grammar!r}")
-        self.grammar = grammar
         self.tokenizer = tokenizer
         self._token_vocab = None
         self._grammar_cache = None
         self.grammar_requests = 0          # compiled-constraint requests
         self.grammar_completed = 0         # grammar_complete finishes
         self.grammar_draft_truncations = 0  # drafts cut at illegal token
-        if grammar == "on":
+        if options.grammar == "on":
             from runbooks_tpu.serve.grammar import GrammarCache, TokenVocab
 
             if tokenizer is None:
@@ -861,8 +727,7 @@ class InferenceEngine:
             self._token_vocab = TokenVocab.from_tokenizer(tokenizer)
             self._grammar_cache = GrammarCache(
                 self._token_vocab, cfg.vocab_size,
-                capacity=(int(grammar_cache_size)
-                          if grammar_cache_size is not None else 64))
+                capacity=options.grammar_cache_size)
         self.deadline_expired = 0   # observability/tests
         self.preemptions = 0          # slots preempted (observability)
         self.preempted_resumed = 0    # preempted requests re-admitted
@@ -886,8 +751,8 @@ class InferenceEngine:
         # live conversation holds an entry between its turns, so a
         # 4-entry cache behind 8 slots would evict before reuse. Each
         # entry costs <= [L, plen, kv_h, d] x2 in HBM.
-        self.prefix_cache_size = (prefix_cache_size
-                                  if prefix_cache_size is not None
+        self.prefix_cache_size = (options.prefix_cache_size
+                                  if options.prefix_cache_size is not None
                                   else max(4, 2 * max_slots))
         # Ordered dict doubles as the LRU: last key = most recently used
         # (registration AND admission hits refresh), first key evicts.
@@ -1170,7 +1035,7 @@ class InferenceEngine:
     def grammar_stats(self) -> dict:
         """Grammar-mode snapshot (/debug/programs): compile-cache
         hit/miss/size, compile seconds, and engine-side counters."""
-        out = {"mode": self.grammar}
+        out = {"mode": self.options.grammar}
         if self._grammar_cache is None:
             return out
         out.update(self._grammar_cache.stats())
@@ -1267,7 +1132,7 @@ class InferenceEngine:
                         self._decode_for(view), self.params, self.cache,
                         *args, **akw)
             n_verify = 0
-            if self.speculative != "off":
+            if self.options.speculative != "off":
                 vtok = np.zeros((self.max_slots, self.draft_tokens + 1),
                                 np.int32)
                 akw = {**self._adapter_kwargs(),
@@ -1302,13 +1167,13 @@ class InferenceEngine:
             "decode_views": list(self.view_buckets),
             "prefix_builders": n_prefix,
             "verify_programs": n_verify,
-            "speculative": self.speculative,
+            "speculative": self.options.speculative,
             "draft_tokens": self.draft_tokens,
             "adapter_pool": (self.adapters.pool_size
                              if self.adapters is not None else 0),
             "lora_rank": (self.adapters.rank
                           if self.adapters is not None else None),
-            "grammar": self.grammar,
+            "grammar": self.options.grammar,
             "grammar_cache_size": (self._grammar_cache.capacity
                                    if self._grammar_cache is not None
                                    else None),
@@ -2379,13 +2244,13 @@ class InferenceEngine:
         volume, accept rate, and decode tok/s per accept-rate bucket —
         the host-side join that says whether drafting pays on THIS
         traffic (docs/speculative-decoding.md)."""
-        out = {"mode": self.speculative}
-        if self.speculative == "off":
+        out = {"mode": self.options.speculative}
+        if self.options.speculative == "off":
             return out
         out.update({
             "draft_tokens": self.draft_tokens,
-            "ngram_max": self.ngram_max,
-            "ngram_min": self.ngram_min,
+            "ngram_max": self.options.ngram_max,
+            "ngram_min": self.options.ngram_min,
             "drafted_total": self.spec_drafted,
             "accepted_total": self.spec_accepted,
             "accept_rate": (round(self.spec_accepted / self.spec_drafted,

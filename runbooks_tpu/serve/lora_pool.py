@@ -33,7 +33,7 @@ from __future__ import annotations
 import json
 import os
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -239,14 +239,13 @@ class AdapterPool:
     additionally lock-guarded because submit() (HTTP handler threads)
     counts into it while the worker thread swaps lanes."""
 
-    def __init__(self, cfg: ModelConfig, pool_size: Optional[int] = None,
-                 rank: Optional[int] = None, root: Optional[str] = None,
+    def __init__(self, cfg: ModelConfig, pool_size: int, rank: int,
+                 targets: Tuple[str, ...], root: Optional[str] = None,
                  loader=None):
         self.cfg = cfg
-        self.pool_size = int(pool_size if pool_size is not None
-                             else cfg.adapter_pool)
-        self.rank = int(rank if rank is not None else cfg.lora_rank)
-        self.targets = tuple(cfg.lora_targets)
+        self.pool_size = pool_size
+        self.rank = rank
+        self.targets = tuple(targets)
         if cfg.moe_num_experts and any(t.startswith("mlp.")
                                        for t in self.targets):
             raise ValueError(

@@ -1142,20 +1142,13 @@ class PagedInferenceEngine(InferenceEngine):
     admission: slots hold page tables into a shared pool, admission
     gates on page availability (pages, not slots, are the scarce
     resource), and every finished request's prompt pages feed the radix
-    tree for many-user prefix reuse. ``num_pages`` defaults to the dense
-    engine's worst-case capacity (max_slots * max_seq_len / page_size) —
-    size it DOWN from HBM headroom to overcommit on sharing
-    (docs/paged-kv.md)."""
+    tree for many-user prefix reuse (docs/paged-kv.md)."""
 
-    # Pages are the unit a preempted slot's state swaps at, so only the
-    # paged engine supports preemption="swap" (serve/engine.py gates).
-    _supports_preemption = True
+    kv_paging = "paged"
     _supports_recurrent_state = False
 
-    def __init__(self, cfg: ModelConfig, params: Params, *,
-                 page_size: int = 16, num_pages: Optional[int] = None,
-                 kv_host_pages: int = 0, **kwargs):
-        mesh = kwargs.get("mesh")
+    def __init__(self, cfg: ModelConfig, params: Params, *, mesh=None,
+                 **kwargs):
         if mesh is not None:
             # Precise mesh-geometry validation: each error names the one
             # constraint that failed (docs/troubleshooting.md). Anything
@@ -1176,28 +1169,20 @@ class PagedInferenceEngine(InferenceEngine):
                     f"kv-head count (docs/paged-kv.md)")
             # stage > 1 is rejected by the dense engine's constructor
             # (pipeline parallelism is a training-path feature).
-        self.page_size = int(page_size)
-        self._num_pages_arg = num_pages
-        if int(kv_host_pages) < 0:
-            raise ValueError(
-                f"kv_host_pages must be >= 0, got {kv_host_pages}")
-        self._kv_host_pages_arg = int(kv_host_pages)
-        super().__init__(cfg, params, **kwargs)
+        super().__init__(cfg, params, mesh=mesh, **kwargs)
 
     # -- storage -------------------------------------------------------
 
     def _init_cache(self) -> None:
-        ps = self.page_size
-        if ps < 1:
-            raise ValueError(f"page_size must be >= 1, got {ps}")
+        ps = self.page_size = self.options.page_size
         if self.max_seq_len % ps:
             raise ValueError(
                 f"page_size {ps} must divide max_seq_len "
                 f"{self.max_seq_len} (static page tables assume whole "
                 "pages per slot)")
         self.pages_per_slot = self.max_seq_len // ps
-        self.num_pages = (int(self._num_pages_arg)
-                          if self._num_pages_arg is not None
+        self.num_pages = (self.options.num_pages
+                          if self.options.num_pages is not None
                           else self.max_slots * self.pages_per_slot)
         if self.num_pages < self.pages_per_slot:
             raise ValueError(
@@ -1218,9 +1203,10 @@ class PagedInferenceEngine(InferenceEngine):
         being replaced, so carrying them over would resurrect pages of
         a discarded tree. No-op when kv_host_pages is 0 — eviction then
         drops pages exactly as before the host tier existed."""
-        if self._kv_host_pages_arg <= 0:
+        if self.options.kv_host_pages <= 0:
             return
-        self.host_pool = HostPagePool(self.cfg, self._kv_host_pages_arg,
+        self.host_pool = HostPagePool(self.cfg,
+                                      self.options.kv_host_pages,
                                       self.page_size,
                                       quantize_kv=self.quantize_kv)
         self.pager.radix.host = self.host_pool
@@ -1315,7 +1301,7 @@ class PagedInferenceEngine(InferenceEngine):
             return self._verify_fns[view_pages]
 
         self._verify_for = verify_for
-        if self._kv_host_pages_arg > 0:
+        if self.options.kv_host_pages > 0:
             self._swap_out_prog = jax.jit(make_kv_swap_out_fn(),
                                           donate_argnums=(0,))
             obs_device.PROGRAMS.register("serve", "kv_swap_out",
@@ -1459,7 +1445,7 @@ class PagedInferenceEngine(InferenceEngine):
                         f"decode_p{vp}", f"p{vp}", self._decode_for(vp),
                         self.params, self.cache, *args, **akw)
             n_verify = 0
-            if self.speculative != "off":
+            if self.options.speculative != "off":
                 vtok = np.zeros((self.max_slots, self.draft_tokens + 1),
                                 np.int32)
                 akw = {**self._adapter_kwargs(),
@@ -1481,7 +1467,7 @@ class PagedInferenceEngine(InferenceEngine):
                             *args, **akw)
                     n_verify += 1
             n_swap = 0
-            if self._kv_host_pages_arg > 0:
+            if self.options.kv_host_pages > 0:
                 # Swap splices warm against the trash page: the gather
                 # reads garbage and the splice writes a page nothing
                 # references — harmless, and EXACTLY the runtime operand
@@ -1512,14 +1498,14 @@ class PagedInferenceEngine(InferenceEngine):
             "num_pages": self.num_pages,
             "verify_programs": n_verify,
             "swap_programs": n_swap,
-            "kv_host_pages": self._kv_host_pages_arg,
-            "speculative": self.speculative,
+            "kv_host_pages": self.options.kv_host_pages,
+            "speculative": self.options.speculative,
             "draft_tokens": self.draft_tokens,
             "adapter_pool": (self.adapters.pool_size
                              if self.adapters is not None else 0),
             "lora_rank": (self.adapters.rank
                           if self.adapters is not None else None),
-            "grammar": self.grammar,
+            "grammar": self.options.grammar,
             "grammar_cache_size": (self._grammar_cache.capacity
                                    if self._grammar_cache is not None
                                    else None),
@@ -1625,7 +1611,7 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _admit(self, exclude_slots=()) -> None:
         blocked = self._admit_pass(exclude_slots)
-        if (self.preemption == "swap" and blocked
+        if (self.options.preemption == "swap" and blocked
                 and self._maybe_preempt(exclude_slots)):
             # The victim's slot and pages freed at this step boundary:
             # a second pass admits the better-class head NOW instead of

@@ -246,7 +246,7 @@ def phase_serve() -> None:
 
     cfg = get_config("debug", dtype="float32")
     app = create_server(cfg, init_params(cfg, jax.random.key(0)),
-                        max_slots=2)
+                        max_slots=2, warmup=False)
     serve_port = free_port()
 
     def run_serve():

@@ -171,7 +171,7 @@ def test_chat_streams_against_live_server(monkeypatch, capsys):
 
     cfg = get_config("debug", dtype="float32")
     app = create_server(cfg, init_params(cfg, jax.random.key(0)),
-                        max_slots=2)
+                        max_slots=2, warmup=False)
     started = threading.Event()
     bound = {}
 

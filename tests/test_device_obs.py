@@ -320,7 +320,7 @@ def test_http_debug_memory_endpoint():
 
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    app = create_server(cfg, params, max_slots=2)
+    app = create_server(cfg, params, max_slots=2, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:

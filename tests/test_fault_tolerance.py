@@ -476,7 +476,7 @@ def test_http_429_retry_after_and_503_draining():
     cfg = _tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
     # max_queue=0: every admission is an overload — deterministic 429.
-    app = create_server(cfg, params, max_slots=1, max_queue=0)
+    app = create_server(cfg, params, max_slots=1, max_queue=0, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:
@@ -514,7 +514,7 @@ def test_http_request_timeout_deadline():
 
     cfg = _tiny_cfg()
     app = create_server(cfg, init_params(cfg, jax.random.key(0)),
-                        max_slots=1)
+                        max_slots=1, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:

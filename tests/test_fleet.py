@@ -540,7 +540,7 @@ def test_request_id_propagation_end_to_end(tmp_path, monkeypatch, capsys):
     obs_trace.configure(path)
     cfg = tiny_cfg()
     app = create_server(cfg, init_params(cfg, jax.random.key(0)),
-                        max_slots=2)
+                        max_slots=2, warmup=False)
     tp_in = "00-" + "ab" * 16 + "-" + "cd" * 8 + "-01"
 
     async def drive():

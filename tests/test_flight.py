@@ -340,7 +340,7 @@ def test_http_incident_endpoints_and_debounce(tmp_path, monkeypatch):
 
     monkeypatch.setenv("RBT_CONTENT_DIR", str(tmp_path))
     cfg = tiny_cfg()
-    app = create_server(cfg, tiny_params(cfg), max_slots=2)
+    app = create_server(cfg, tiny_params(cfg), max_slots=2, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:

@@ -415,7 +415,6 @@ def test_zero_unexpected_compiles_mixed_grammar_lora_loop(
     from runbooks_tpu.train.lora import LoraConfig, init_lora
 
     cfg, params = model
-    c = dataclasses.replace(cfg, adapter_pool=2, lora_rank=8)
     lora = init_lora(params, LoraConfig(rank=4, alpha=8.0),
                      jax.random.key(11))
     lora = jax.tree.map(
@@ -425,11 +424,12 @@ def test_zero_unexpected_compiles_mixed_grammar_lora_loop(
     save_adapter(path, lora, rank=4, alpha=8.0)
 
     if engine_cls == "paged":
-        eng = PagedInferenceEngine(c, params, max_slots=2, page_size=16,
+        eng = PagedInferenceEngine(cfg, params, max_slots=2, page_size=16,
+                                   adapter_pool=2, lora_rank=8,
                                    grammar="on", tokenizer=TOK)
     else:
-        eng = InferenceEngine(c, params, max_slots=2, grammar="on",
-                              tokenizer=TOK)
+        eng = InferenceEngine(cfg, params, max_slots=2, adapter_pool=2,
+                              lora_rank=8, grammar="on", tokenizer=TOK)
     sentinel = obs_device.SENTINEL
     if not sentinel.install():
         pytest.skip("jax.monitoring unavailable; sentinel cannot verify")
@@ -509,7 +509,7 @@ def test_http_response_format_end_to_end(model):
 
     cfg, params = model
     app = create_server(cfg, params, tokenizer=ByteTokenizer(),
-                        max_slots=2, grammar="on")
+                        max_slots=2, grammar="on", warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:
@@ -569,7 +569,7 @@ def test_http_response_format_rejected_when_grammar_off(model):
 
     cfg, params = model
     app = create_server(cfg, params, tokenizer=ByteTokenizer(),
-                        max_slots=1)
+                        max_slots=1, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:

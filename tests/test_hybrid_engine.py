@@ -267,10 +267,10 @@ def test_server_refuses_auto_prefix_chat_and_reports_state_bytes(model):
 
     with pytest.raises(ValueError, match="auto_prefix_chat"):
         create_server(cfg, params, tokenizer=Tok(), max_slots=2,
-                      max_seq_len=64, auto_prefix_chat=True)
+                      max_seq_len=64, auto_prefix_chat=True, warmup=False)
     with pytest.raises(ValueError, match="kv_paging: paged"):
         create_server(cfg, params, tokenizer=Tok(), max_slots=2,
-                      max_seq_len=64, kv_paging=True)
+                      max_seq_len=64, kv_paging=True, warmup=False)
     eng = InferenceEngine(cfg, params, max_slots=2, max_seq_len=64)
     occ = eng.kv_occupancy()
     # 6 linear layers x 2 slots x (4 x 32 x 64 f32 + 3 x 512 f32).

@@ -169,7 +169,7 @@ def test_preemption_resumes_without_token_loss(model):
 def test_queue_class_ordering_and_shares(model):
     cfg, params = model
     engine = InferenceEngine(cfg, params, max_slots=1, max_queue=10,
-                             queue_shares={"batch": 0.2})
+                             queue_share_batch=0.2)
     # batch's share bounds it to ceil(0.2 * 10) = 2 queued entries —
     # the third sheds while other classes keep their queue room
     mk = lambda pri, t: Request(prompt_tokens=[t, t + 1], max_tokens=2,
@@ -200,12 +200,11 @@ def test_queue_class_ordering_and_shares(model):
 def test_qos_validation_is_typed():
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    with pytest.raises(ValueError, match="unknown class"):
-        InferenceEngine(cfg, params, max_slots=1,
-                        queue_shares={"urgent": 0.5})
+    with pytest.raises(TypeError, match="queue_share_urgent"):
+        InferenceEngine(cfg, params, max_slots=1, queue_share_urgent=0.5)
     with pytest.raises(ValueError, match="in \\(0, 1\\]"):
         InferenceEngine(cfg, params, max_slots=1,
-                        queue_shares={"batch": 0.0})
+                        queue_share_batch=0.0)
     # the dense engine has no pages to swap: preemption=swap is a typed
     # construction error pointing at kv_paging, not a silent no-op
     with pytest.raises(ValueError, match="kv_paging: paged"):
@@ -390,7 +389,7 @@ def test_http_qos_and_host_tier_surface(model):
     app = create_server(cfg, params, max_slots=2, kv_paging=True,
                         page_size=16, num_pages=5, kv_host_pages=2,
                         preemption="swap",
-                        queue_shares={"batch": 0.5})
+                        queue_share_batch=0.5, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:
@@ -441,7 +440,7 @@ def test_http_shed_carries_load_derived_retry_after(model):
     from runbooks_tpu.serve.api import create_server
 
     cfg, params = model
-    app = create_server(cfg, params, max_slots=1, max_queue=0)
+    app = create_server(cfg, params, max_slots=1, max_queue=0, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:

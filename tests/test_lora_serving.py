@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from runbooks_tpu.api.serve_params import ServeOptions
 from runbooks_tpu.models.config import get_config
 from runbooks_tpu.models.transformer import forward, init_params
 from runbooks_tpu.ops.quantization import quantize_params
@@ -50,10 +51,14 @@ def tiny_cfg(dtype="float32", **over):
         get_config("llama2-7b"), vocab_size=128, hidden_size=64,
         intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
         head_dim=16, max_seq_len=64, dtype=dtype, param_dtype="float32",
-        adapter_pool=4, lora_rank=8, **over)
+        **over)
 
 
 N_ADAPTERS = 4
+# The pool every engine here serves from unless a test sizes its own
+# (lora_rank and lora_targets at ServeOptions' defaults).
+POOL = 4
+TARGETS, RANK = ServeOptions().lora_targets, ServeOptions().lora_rank
 PROMPTS = [[5, 9, 17], [3, 4, 5, 6, 7], [40, 2], [8, 8, 8, 9]]
 
 
@@ -95,7 +100,8 @@ def test_heterogeneous_batch_parity_dense(world):
     """Four distinct adapters concurrently on ONE dense engine ==
     token-for-token four dedicated merged-weights engines."""
     cfg, params, paths, merged, _ = world
-    pooled = InferenceEngine(cfg, params, max_slots=N_ADAPTERS)
+    pooled = InferenceEngine(cfg, params, adapter_pool=POOL,
+                             max_slots=N_ADAPTERS)
     reqs = _reqs(paths)
     for r in reqs:
         pooled.submit(r)
@@ -106,7 +112,8 @@ def test_heterogeneous_batch_parity_dense(world):
     while pooled.has_work():
         pooled.step()
     for prompt, m, r in zip(PROMPTS, merged, reqs):
-        dedicated = InferenceEngine(cfg, m, max_slots=N_ADAPTERS)
+        dedicated = InferenceEngine(cfg, m, adapter_pool=POOL,
+                                    max_slots=N_ADAPTERS)
         oracle = Request(prompt_tokens=list(prompt), max_tokens=8,
                          temperature=0.0)
         dedicated.generate([oracle])
@@ -118,12 +125,14 @@ def test_heterogeneous_batch_parity_dense(world):
 
 def test_heterogeneous_batch_parity_paged(world):
     cfg, params, paths, merged, _ = world
-    pooled = PagedInferenceEngine(cfg, params, max_slots=N_ADAPTERS,
+    pooled = PagedInferenceEngine(cfg, params, adapter_pool=POOL,
+                                  max_slots=N_ADAPTERS,
                                   page_size=8)
     reqs = _reqs(paths)
     pooled.generate(reqs)
     for prompt, m, r in zip(PROMPTS, merged, reqs):
-        dedicated = InferenceEngine(cfg, m, max_slots=N_ADAPTERS)
+        dedicated = InferenceEngine(cfg, m, adapter_pool=POOL,
+                                    max_slots=N_ADAPTERS)
         oracle = Request(prompt_tokens=list(prompt), max_tokens=8,
                          temperature=0.0)
         dedicated.generate([oracle])
@@ -134,7 +143,7 @@ def test_mixed_base_and_adapter_traffic_one_dispatch(world):
     """Base-only rows (trash lane) and tenant rows share one batch; the
     base rows are BITWISE the no-pool engine's output."""
     cfg, params, paths, merged, _ = world
-    pooled = InferenceEngine(cfg, params, max_slots=3)
+    pooled = InferenceEngine(cfg, params, adapter_pool=POOL, max_slots=3)
     reqs = [
         Request(prompt_tokens=[5, 9, 17], max_tokens=8, temperature=0.0,
                 adapter=paths[0]),
@@ -144,14 +153,13 @@ def test_mixed_base_and_adapter_traffic_one_dispatch(world):
                 adapter=paths[1]),
     ]
     pooled.generate(reqs)
-    plain = InferenceEngine(dataclasses.replace(cfg, adapter_pool=0),
-                            params, max_slots=3)
+    plain = InferenceEngine(cfg, params, adapter_pool=0, max_slots=3)
     base_oracle = Request(prompt_tokens=[3, 4, 5, 6], max_tokens=8,
                           temperature=0.0)
     plain.generate([base_oracle])
     assert reqs[1].output_tokens == base_oracle.output_tokens
     for i, m in ((0, merged[0]), (2, merged[1])):
-        dedicated = InferenceEngine(cfg, m, max_slots=3)
+        dedicated = InferenceEngine(cfg, m, adapter_pool=POOL, max_slots=3)
         oracle = Request(prompt_tokens=list(reqs[i].prompt_tokens),
                          max_tokens=8, temperature=0.0)
         dedicated.generate([oracle])
@@ -181,11 +189,11 @@ def test_batching_neutrality_bf16_and_int8(world, quantize, engine_cls):
                   if quantize != "none" else params)
 
     def make(pool):
-        c = dataclasses.replace(cfg, adapter_pool=pool)
         if engine_cls == "paged":
-            return PagedInferenceEngine(c, eng_params,
+            return PagedInferenceEngine(cfg, eng_params, adapter_pool=pool,
                                         max_slots=N_ADAPTERS, page_size=8)
-        return InferenceEngine(c, eng_params, max_slots=N_ADAPTERS)
+        return InferenceEngine(cfg, eng_params, adapter_pool=pool,
+                               max_slots=N_ADAPTERS)
 
     multi = make(N_ADAPTERS)
     reqs = _reqs(paths)
@@ -212,11 +220,10 @@ def test_pool_eviction_and_page_back_in(world):
     """pool=2 serving 3 tenants round-robin: LRU eviction under
     pressure, page-back-in on return, correctness after reload."""
     cfg, params, paths, merged, _ = world
-    eng = InferenceEngine(dataclasses.replace(cfg, adapter_pool=2),
-                          params, max_slots=2)
+    eng = InferenceEngine(cfg, params, adapter_pool=2, max_slots=2)
     expected = []
     for prompt, m in zip(PROMPTS[:3], merged[:3]):
-        dedicated = InferenceEngine(cfg, m, max_slots=2)
+        dedicated = InferenceEngine(cfg, m, adapter_pool=POOL, max_slots=2)
         oracle = Request(prompt_tokens=list(prompt), max_tokens=6,
                          temperature=0.0)
         dedicated.generate([oracle])
@@ -239,7 +246,7 @@ def test_pool_refcount_pins_active_lane(world):
     """An adapter pinned by an in-flight request is never the eviction
     victim; releasing it at finish frees the lane."""
     cfg, params, paths, _, _ = world
-    pool = AdapterPool(dataclasses.replace(cfg, adapter_pool=2))
+    pool = AdapterPool(cfg, 2, RANK, TARGETS)
     lane_a = pool.acquire(paths[0])
     lane_b = pool.acquire(paths[1])
     assert {lane_a, lane_b} == {0, 1}
@@ -259,8 +266,8 @@ def test_admission_429_on_pool_exhaustion(world):
     queue backs up, submit() sheds with the typed 429 — and the queued
     tenant is served once a lane frees."""
     cfg, params, paths, merged, _ = world
-    eng = InferenceEngine(dataclasses.replace(cfg, adapter_pool=1),
-                          params, max_slots=2, max_queue=2)
+    eng = InferenceEngine(cfg, params, adapter_pool=1,
+                          max_slots=2, max_queue=2)
     long_req = Request(prompt_tokens=[5, 9, 17], max_tokens=30,
                        temperature=0.0, adapter=paths[0])
     eng.submit(long_req)
@@ -279,7 +286,7 @@ def test_admission_429_on_pool_exhaustion(world):
     while eng.has_work():
         eng.step()
     assert long_req.finished and waiting.finished
-    dedicated = InferenceEngine(cfg, merged[1], max_slots=2)
+    dedicated = InferenceEngine(cfg, merged[1], adapter_pool=POOL, max_slots=2)
     oracle = Request(prompt_tokens=[40, 2], max_tokens=4, temperature=0.0)
     dedicated.generate([oracle])
     assert waiting.output_tokens == oracle.output_tokens
@@ -298,11 +305,11 @@ def test_zero_unexpected_compiles_steady_adapter_swapping(world,
     from runbooks_tpu.obs import device as obs_device
 
     cfg, params, paths, _, _ = world
-    c = dataclasses.replace(cfg, adapter_pool=2)
     if engine_cls == "paged":
-        eng = PagedInferenceEngine(c, params, max_slots=2, page_size=8)
+        eng = PagedInferenceEngine(cfg, params, adapter_pool=2,
+                                   max_slots=2, page_size=8)
     else:
-        eng = InferenceEngine(c, params, max_slots=2)
+        eng = InferenceEngine(cfg, params, adapter_pool=2, max_slots=2)
     sentinel = obs_device.SENTINEL
     if not sentinel.install():
         pytest.skip("jax.monitoring unavailable; sentinel cannot verify")
@@ -330,15 +337,14 @@ def test_zero_unexpected_compiles_steady_adapter_swapping(world,
 
 def test_adapter_request_without_pool_rejected(world):
     cfg, params, paths, _, _ = world
-    eng = InferenceEngine(dataclasses.replace(cfg, adapter_pool=0),
-                          params, max_slots=2)
+    eng = InferenceEngine(cfg, params, adapter_pool=0, max_slots=2)
     with pytest.raises(ValueError, match="no adapter pool"):
         eng.submit(Request(prompt_tokens=[1, 2], adapter=paths[0]))
 
 
 def test_unknown_adapter_path_rejected_at_submit(world):
     cfg, params, _, _, _ = world
-    eng = InferenceEngine(cfg, params, max_slots=2)
+    eng = InferenceEngine(cfg, params, adapter_pool=POOL, max_slots=2)
     with pytest.raises(ValueError, match="no such directory"):
         eng.submit(Request(prompt_tokens=[1, 2],
                            adapter="/does/not/exist"))
@@ -353,7 +359,7 @@ def test_rank_above_bucket_rejected(world, tmp_path):
     path = str(tmp_path / "bigrank")
     save_adapter(path, lora, rank=16, alpha=16.0)
     with pytest.raises(AdapterLoadError, match="rank 16 exceeds"):
-        load_adapter_tree(path, cfg, cfg.lora_targets, cfg.lora_rank)
+        load_adapter_tree(path, cfg, TARGETS, RANK)
 
 
 def test_malformed_artifact_raises_typed_error(world, tmp_path):
@@ -373,11 +379,11 @@ def test_malformed_artifact_raises_typed_error(world, tmp_path):
     finally:
         mgr.close()
     with pytest.raises(AdapterLoadError, match="not an .a, b. LoRA"):
-        load_adapter_tree(path, cfg, cfg.lora_targets, cfg.lora_rank)
+        load_adapter_tree(path, cfg, TARGETS, RANK)
     # And end to end: the engine finishes the request with an error
     # instead of crashing the loop (load fails only at admission — the
     # artifact dir itself looks valid to the cheap submit-time probe).
-    eng = InferenceEngine(cfg, params, max_slots=2)
+    eng = InferenceEngine(cfg, params, adapter_pool=POOL, max_slots=2)
     r = Request(prompt_tokens=[1, 2, 3], max_tokens=4, temperature=0.0,
                 adapter=path)
     eng.generate([r])
@@ -398,12 +404,12 @@ def test_small_rank_pads_exactly(world, tmp_path):
                                                x.shape, x.dtype), lora)
     path = str(tmp_path / "r2")
     save_adapter(path, lora, rank=2, alpha=4.0)
-    eng = InferenceEngine(cfg, params, max_slots=2)
+    eng = InferenceEngine(cfg, params, adapter_pool=POOL, max_slots=2)
     r = Request(prompt_tokens=[5, 9, 17], max_tokens=6, temperature=0.0,
                 adapter=path)
     eng.generate([r])
     dedicated = InferenceEngine(cfg, apply_lora(params, lora, lcfg),
-                                max_slots=2)
+                                adapter_pool=POOL, max_slots=2)
     oracle = Request(prompt_tokens=[5, 9, 17], max_tokens=6,
                      temperature=0.0)
     dedicated.generate([oracle])
@@ -440,7 +446,8 @@ def test_paged_radix_respects_adapter_namespaces(world):
     """Same prompt prefix, different adapters: pages never cross tenants
     (the K/V differ per adapter); same adapter reuses pages."""
     cfg, params, paths, merged, _ = world
-    eng = PagedInferenceEngine(cfg, params, max_slots=2, page_size=8)
+    eng = PagedInferenceEngine(cfg, params, adapter_pool=POOL,
+                               max_slots=2, page_size=8)
     long_prompt = list(range(1, 25))
     r1 = Request(prompt_tokens=long_prompt + [30], max_tokens=3,
                  temperature=0.0, adapter=paths[0])
@@ -455,7 +462,7 @@ def test_paged_radix_respects_adapter_namespaces(world):
                  temperature=0.0, adapter=paths[1])
     eng.generate([r3])
     assert eng.pager.pages_reused_total == before  # tenant isolation
-    dedicated = InferenceEngine(cfg, merged[1], max_slots=2)
+    dedicated = InferenceEngine(cfg, merged[1], adapter_pool=POOL, max_slots=2)
     oracle = Request(prompt_tokens=long_prompt + [31], max_tokens=3,
                      temperature=0.0)
     dedicated.generate([oracle])
@@ -469,11 +476,11 @@ def test_sharded_adapter_engine_matches_unsharded(world):
     from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
 
     cfg, params, paths, _, _ = world
-    plain = InferenceEngine(cfg, params, max_slots=2)
+    plain = InferenceEngine(cfg, params, adapter_pool=POOL, max_slots=2)
     r0 = Request(prompt_tokens=[5, 9, 17], max_tokens=6, temperature=0.0,
                  adapter=paths[0])
     plain.generate([r0])
-    sharded = InferenceEngine(cfg, params, max_slots=2,
+    sharded = InferenceEngine(cfg, params, adapter_pool=POOL, max_slots=2,
                               mesh=make_mesh(MeshConfig(tensor=2)))
     r1 = Request(prompt_tokens=[5, 9, 17], max_tokens=6, temperature=0.0,
                  adapter=paths[0])
@@ -493,7 +500,7 @@ def test_http_adapter_field_and_metrics(world):
     from runbooks_tpu.serve.api import create_server
 
     cfg, params, paths, _, _ = world
-    app = create_server(cfg, params, max_slots=2, adapter_pool=2)
+    app = create_server(cfg, params, max_slots=2, adapter_pool=2, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:
@@ -527,8 +534,8 @@ def test_http_adapter_field_and_metrics(world):
     from runbooks_tpu.obs import metrics as obs_metrics
 
     obs_metrics.REGISTRY.reset()
-    plain = create_server(dataclasses.replace(cfg, adapter_pool=0),
-                          params, max_slots=2)
+    plain = create_server(cfg, params, adapter_pool=0, max_slots=2,
+                          warmup=False)
 
     async def drive_plain():
         async with TestClient(TestServer(plain)) as client:

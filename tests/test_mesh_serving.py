@@ -133,7 +133,6 @@ def test_mesh_parity_lora_pool(model, mesh, tmp_path):
     from runbooks_tpu.train.lora import LoraConfig, init_lora
 
     cfg, params = model
-    cfg = dataclasses.replace(cfg, adapter_pool=4, lora_rank=8)
     paths = []
     for i in range(4):
         lora = init_lora(params, LoraConfig(rank=4, alpha=8.0),
@@ -151,10 +150,11 @@ def test_mesh_parity_lora_pool(model, mesh, tmp_path):
                 for p, a in zip(PROMPTS, paths)]
 
     want = outputs(
-        PagedInferenceEngine(cfg, params, max_slots=4, page_size=16),
+        PagedInferenceEngine(cfg, params, max_slots=4, page_size=16,
+                             adapter_pool=4, lora_rank=8),
         reqs())
     eng = PagedInferenceEngine(cfg, params, max_slots=4, page_size=16,
-                               mesh=mesh)
+                               adapter_pool=4, lora_rank=8, mesh=mesh)
     got = outputs(eng, reqs())
     assert got == want
 

@@ -454,7 +454,7 @@ def test_http_metrics_renders_from_registry_with_content_type():
 
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    app = create_server(cfg, params, max_slots=2)
+    app = create_server(cfg, params, max_slots=2, warmup=False)
 
     async def drive():
         import asyncio  # noqa: F401
@@ -496,7 +496,7 @@ def test_http_debug_profile_endpoint(tmp_path, monkeypatch):
     monkeypatch.setenv("RBT_CONTENT_DIR", str(tmp_path))
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    app = create_server(cfg, params, max_slots=2)
+    app = create_server(cfg, params, max_slots=2, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:

@@ -446,7 +446,7 @@ def test_http_paged_server_metrics_and_memory(model):
 
     cfg, params = model
     app = create_server(cfg, params, max_slots=2, kv_paging=True,
-                        page_size=16)
+                        page_size=16, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:
@@ -488,7 +488,7 @@ def test_dense_metrics_do_not_export_page_series(model):
     # in this module — a fresh registry proves the DENSE path never sets
     # them (reset() is the test-only full wipe)
     obs_metrics.REGISTRY.reset()
-    app = create_server(cfg, params, max_slots=2)
+    app = create_server(cfg, params, max_slots=2, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:

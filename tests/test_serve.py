@@ -189,7 +189,7 @@ def test_http_api_end_to_end():
 
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    app = create_server(cfg, params, max_slots=2)
+    app = create_server(cfg, params, max_slots=2, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:
@@ -254,7 +254,7 @@ def test_http_streaming_sse():
 
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    app = create_server(cfg, params, max_slots=2)
+    app = create_server(cfg, params, max_slots=2, warmup=False)
 
     def parse_sse(raw: str):
         events = []
@@ -512,7 +512,7 @@ def test_http_prefix_registration_endpoint():
 
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    app = create_server(cfg, params, max_slots=2)
+    app = create_server(cfg, params, max_slots=2, warmup=False)
 
     async def drive():
         async with TestClient(TestServer(app)) as client:
@@ -611,11 +611,12 @@ def test_http_chat_auto_prefix_multi_turn():
             eng = app["worker"].engine
             return answers, eng.prefix_tokens_reused
 
-    app_off = create_server(cfg, params, max_slots=2)
+    app_off = create_server(cfg, params, max_slots=2, warmup=False)
     want, reused_off = asyncio.run(converse(app_off))
     assert reused_off == 0
 
-    app_on = create_server(cfg, params, max_slots=2, auto_prefix_chat=True)
+    app_on = create_server(cfg, params, max_slots=2, auto_prefix_chat=True,
+                           warmup=False)
     got, reused_on = asyncio.run(converse(app_on))
     assert reused_on > 0, "second turn did not reuse the first turn's KV"
     assert got == want

@@ -413,9 +413,12 @@ def test_eos_inside_accepted_draft(model):
     off = InferenceEngine(cfg, params, max_slots=2, max_seq_len=64)
     probe = greedy_reqs([REP_PROMPT], max_tokens=12)
     run_all(off, probe)
-    # pick an EOS that lands mid-output, so with K=4 drafting it can sit
-    # INSIDE an accepted draft run
-    eos = probe[0].output_tokens[3]
+    # pick an EOS that FIRST appears mid-output, so with K=4 drafting it
+    # sits INSIDE an accepted draft run (a token that also opens the
+    # output would end the request at the prefill's token, before any
+    # draft is verified: this model repeats its first token nine times)
+    out = probe[0].output_tokens
+    eos = next(t for i, t in enumerate(out) if i >= 2 and t not in out[:i])
     reqs_off = greedy_reqs([REP_PROMPT], max_tokens=12, eos_id=eos)
     run_all(off, reqs_off)
     on = _OracleEngine(cfg, params, max_slots=2, max_seq_len=64,
@@ -515,11 +518,10 @@ def test_engine_speculative_validation(model):
     with pytest.raises(ValueError, match="ngram"):
         InferenceEngine(cfg, params, max_slots=2, max_seq_len=64,
                         speculative="ngram", ngram_max=1, ngram_min=2)
-    # config-driven resolution: the engine follows cfg.speculative
-    cfg_on = dataclasses.replace(cfg, speculative="ngram",
-                                 draft_tokens=2)
-    eng = InferenceEngine(cfg_on, params, max_slots=2, max_seq_len=64)
-    assert eng.speculative == "ngram" and eng.draft_tokens == 2
+    # the engine holds the record it was given, and serves from it
+    eng = InferenceEngine(cfg, params, max_slots=2, max_seq_len=64,
+                          speculative="ngram", draft_tokens=2)
+    assert eng.options.speculative == "ngram" and eng.draft_tokens == 2
     assert eng._spec_index is not None
 
 
