@@ -655,9 +655,9 @@ def agree_child(sz: dict, chips: int, tiny: bool) -> int:
 
     # (2) Same widths in float32 at depth 2, where flash and XLA must agree
     # to float32 round-off of the softmax sums (1e-3 leaves room for the
-    # TPU's multi-pass f32 matmul): the training layout (block skip on) and
-    # the serve layout (queries at an offset into a longer, unaligned KV
-    # view; block skip off).
+    # TPU's multi-pass f32 matmul): the training layout and the serve
+    # layout (queries at an offset into a longer, unaligned KV view); the
+    # forward works out from the positions which kv blocks to visit.
     c32 = variant(cfg, num_layers=2, dtype="float32", param_dtype="float32")
     p32 = sharded_params(c32)
     ref = logits(variant(c32, attention_impl="xla"), p32, tokens)

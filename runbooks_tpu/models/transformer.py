@@ -373,6 +373,9 @@ class KVCache:
       Allocate with ``trash_slot=True`` (cache_len = max_len+1) and point
       padding at slot max_len so pad tokens land in a slot no real query
       ever attends (slot s is visible only to queries with position >= s).
+      The cache's last slot IS the trash slot in this mode: a token whose
+      position is clipped to it is nobody's query, and the flash prefill
+      computes nothing for it (its attention output is 0).
 
     quantize_kv=True stores k/v as int8 with one f32 scale per
     (layer, row, slot, kv-head) in k_scale/v_scale
@@ -674,7 +677,12 @@ def _attention_block(
             k, v, new_layer_cache = _write_layer_cache(k, v, positions,
                                                        layer_cache, ad)
         with jax.named_scope("attn.core"):
-            out = _cached_attention(cfg, q, k, v, positions, mask, bias)
+            # layer_cache = (k, v, ..., layer, index, view): no index means
+            # position-scatter mode, whose last slot is the trash slot.
+            trash_pos = (layer_cache[0].shape[2] - 1
+                         if layer_cache[5] is None else None)
+            out = _cached_attention(cfg, q, k, v, positions, mask, bias,
+                                    trash_pos)
     else:
         with jax.named_scope("attn.core"):
             out = _dispatch_attention(cfg, q, k, v, positions, segment_ids,
@@ -754,25 +762,35 @@ def _write_layer_cache(k, v, positions, layer_cache, ad):
     return k, v, (ck, cv, ck_s, cv_s)
 
 
-def _cached_attention(cfg: ModelConfig, q, k, v, positions, mask, bias):
-    """Attention of q against one layer's cache view."""
+def _cached_attention(cfg: ModelConfig, q, k, v, positions, mask, bias,
+                      trash_pos=None):
+    """Attention of q against one layer's cache view. trash_pos: in
+    position-scatter mode the cache's last slot, where callers park a
+    bucket's padding (KVCache); None in append-at-index mode."""
     b = q.shape[0]
     if mask is None:
         # Flash cached-prefill (forward() skipped the O(s*kv) mask
         # build): cache slot i holds absolute position i by
         # construction, so the kernel's causal-by-absolute-position
         # masking reproduces the XLA path's mask exactly — unwritten
-        # or future slots are never attended. block_skip stays off:
-        # query rows start at position cache.index, not 0, so grid
-        # index alignment does not hold.
+        # or future slots are never attended, and the kernel works out
+        # from these positions which kv blocks to visit at all (query
+        # rows start at cache.index or at a prefix length, not at 0).
         from runbooks_tpu.ops.flash_attention import flash_attention
 
         kv_pos = jnp.broadcast_to(
             jnp.arange(k.shape[1], dtype=jnp.int32)[None, :],
             (b, k.shape[1]))
+        if trash_pos is not None:
+            # A token parked at the trash slot is nobody's query: nothing
+            # reads its output. At its parked position it would see every
+            # key, and its block would visit every kv block for it; at -1
+            # it sees none (the row comes out exactly 0), so a bucket's
+            # padded tail costs nothing.
+            positions = jnp.where(positions >= trash_pos, -1, positions)
         out = flash_attention(
             q, k, v, positions, kv_pos, None, None, True, None,
-            cfg.flash_block_q, cfg.flash_block_k, block_skip=False)
+            cfg.flash_block_q, cfg.flash_block_k)
     else:
         # Decode (s=1) keeps the XLA path: a one-row query block has no
         # O(s^2) term and the step is bandwidth-bound anyway.

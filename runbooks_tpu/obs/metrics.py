@@ -86,6 +86,10 @@ CATALOG: Dict[str, str] = {
     "serve_inter_token_seconds": "histogram",
     "serve_request_duration_seconds": "histogram",
     "serve_prefill_dispatch_seconds": "histogram",
+    # flash forward, prefill: blocks computed / blocks of the grid
+    # (ops/flash_attention.block_ranges; flash prefill only)
+    "serve_flash_blocks_visited_total": "counter",
+    "serve_flash_blocks_grid_total": "counter",
     "serve_decode_dispatch_seconds": "histogram",
     # Speculative decoding (serve/engine.py verify path,
     # docs/speculative-decoding.md): exported only when speculative is

@@ -4,22 +4,33 @@ Design (see /opt/skills/guides/pallas_guide.md):
 - Grid (batch, heads, q_blocks, kv_blocks); TPU executes the grid sequentially
   with the last dimension innermost, so the kernel accumulates the softmax
   running state (m, l, acc) across kv-block iterations in VMEM scratch and
-  finalizes on the last kv block.
+  finalizes on the last kv block it visits.
 - fp32 accumulation throughout; inputs may be bf16.
 - Masking is by absolute position (causal) + optional segment ids (packed
   sequences), matching runbooks_tpu.ops.attention semantics so the XLA path
   is a drop-in numerical oracle.
+- The forward visits only the kv blocks a query block can see. Which those
+  are is computed from the positions and segment ids of the call itself
+  (``block_ranges``: one ``[lo, hi]`` a batch row and query block), not from
+  grid indices and not from a caller's flag, and rides into the kernel as
+  scalar-prefetch operands: the k / v index maps clamp the kv index into the
+  range (a repeated block index issues no DMA) and the body runs only inside
+  it. The element-wise mask is unchanged, so any range that covers the
+  needed blocks is exact: a cached prefill whose queries start at a prefix
+  length, a bucket's padded tail, ``sk != sq``, a rotated ring shard and a
+  packed training batch all take the one path, and a block whose every
+  score the mask would set to NEG_INF is never loaded or computed.
 - Backward: standard flash backward from saved logsumexp — one kernel for dq
   (grid over q blocks) and one for dk/dv (grid over kv blocks), both
-  recomputing p blockwise.
+  recomputing p blockwise. They still skip by GRID index (``block_skip``:
+  exact only where q storage index i and kv storage index i hold the same
+  position, the training layout), see ``flash_attention``.
 - GQA-native: k/v stay at kv_heads width; the BlockSpec index map routes
   q head hi to kv head hi // n_rep, so no repeated k/v is ever materialized.
 
 On non-TPU backends the kernels run in interpreter mode (tests). The
 default ``attention_impl="auto"`` picks this kernel on TPU and the XLA
-reference path elsewhere; causal block skipping (above-diagonal blocks
-never DMA'd or computed) is on by default and exact for globally monotone
-position layouts.
+reference path elsewhere.
 """
 
 from __future__ import annotations
@@ -29,6 +40,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -72,30 +84,106 @@ def _interpret() -> bool:
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _last_valid_kv(qi, block_q: int, block_k: int, num_kv):
-    """Last kv-block index that can contain an unmasked key for q block qi,
-    under causal masking with globally monotone positions (standard training
-    layout, including contiguous packing: a later global index is either a
-    future position or a later segment — masked either way)."""
-    return jnp.minimum(num_kv - 1, ((qi + 1) * block_q - 1) // block_k)
+def block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
+                 causal: bool):
+    """Which kv blocks each query block has to visit: ``(lo, hi)``, int32
+    ``[b, q_blocks]``, the hull of the needed kv blocks of every batch row
+    and query block; an empty range is ``lo = 0, hi = -1``.
+
+    kv block j is needed by query block i iff some valid key of j can be
+    seen by some valid query of i. A key is valid unless it is padding
+    (position PAD_POS, or segment 0 where segments are given); a query is
+    valid unless its segment is 0. Segments: the two blocks' spans of
+    segment ids overlap. Causal: the least valid key of j is not above the
+    greatest valid query of i — by position alone without segments, and
+    with them by (segment, position) in dictionary order, which a key and
+    a query of one document keep (in a packed row a later block starts a
+    document at position 0, below every query position, and is still not
+    needed). Every unmasked (query, key) pair satisfies these, whatever
+    the layout (offset, non-monotone or repeated positions, sk != sq,
+    lengths that are no multiple of a block), so the hull never drops a
+    block that holds one, and the kernel's element-wise mask makes any
+    superset exact.
+
+    q_pos [b, sq], kv_pos [b, sk], q_seg / kv_seg the same shapes or None.
+    NumPy arrays give NumPy results (the serving engine counts blocks on
+    the host with this same function), anything else goes through jax.numpy.
+    Blocks are clamped to the lengths as the kernel clamps them."""
+    xp = np if isinstance(q_pos, np.ndarray) else jnp
+    b, sq = q_pos.shape
+    sk = kv_pos.shape[1]
+    block_q, block_k = min(block_q, sq), min(block_k, sk)
+    lowest, highest = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+
+    def blocks(x, block, fill):          # [b, s] -> [b, s_p // block, block]
+        pad = -x.shape[1] % block
+        x = xp.pad(x.astype(xp.int32), ((0, 0), (0, pad)),
+                   constant_values=fill)
+        return x.reshape(b, -1, block)
+
+    def span(x, ok):                     # least and greatest valid entry
+        return (xp.where(ok, x, highest).min(axis=-1),
+                xp.where(ok, x, lowest).max(axis=-1))
+
+    def pairs(of_keys, of_queries):      # [b, nk], [b, nq] -> [b, nq, nk]
+        return of_keys[:, None, :], of_queries[:, :, None]
+
+    qp, kp = blocks(q_pos, block_q, 0), blocks(kv_pos, block_k, PAD_POS)
+    q_ok = blocks(xp.ones_like(q_pos), block_q, 0) != 0
+    k_ok = kp < PAD_POS
+    if q_seg is not None:
+        qs, ks = blocks(q_seg, block_q, 0), blocks(kv_seg, block_k, 0)
+        q_ok, k_ok = q_ok & (qs != 0), k_ok & (ks != 0)
+        (q_first, q_last), (k_first, k_last) = span(qs, q_ok), span(ks, k_ok)
+    need = q_ok.any(axis=-1)[:, :, None] & k_ok.any(axis=-1)[:, None, :]
+    if q_seg is not None:
+        k_lo, q_hi = pairs(k_first, q_last)
+        k_hi, q_lo = pairs(k_last, q_first)
+        need &= (k_lo <= q_hi) & (q_lo <= k_hi)
+        # What is left of the causal test: the least position of a block's
+        # first segment against the greatest of the other's last one.
+        k_ok = k_ok & (ks == k_first[..., None])
+        q_ok = q_ok & (qs == q_last[..., None])
+    if causal:
+        k_min, q_max = pairs(span(kp, k_ok)[0], span(qp, q_ok)[1])
+        before = k_min <= q_max
+        need &= before if q_seg is None else (k_lo < q_hi) | before
+    j = xp.arange(need.shape[-1], dtype=xp.int32)
+    hi = xp.where(need, j, -1).max(axis=-1)
+    lo = xp.where(need, j, highest).min(axis=-1)
+    return xp.where(hi < 0, 0, lo).astype(xp.int32), hi.astype(xp.int32)
 
 
-def _fwd_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,  # prefetch-ish
+def block_counts(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
+                 causal: bool):
+    """(visited, grid): how many (query block, kv block) pairs a head of
+    the forward computes for these host arrays, and how many its grid has."""
+    lo, hi = block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q, block_k,
+                          causal)
+    sk = kv_pos.shape[1]
+    return (int(np.maximum(hi - lo + 1, 0).sum()),
+            lo.size * -(-sk // min(block_k, sk)))
+
+
+def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
+                q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
                 q_ref, k_ref, v_ref,
                 o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, use_segments: bool,
-                block_q: int, block_k: int, block_skip: bool):
+                *, scale: float, causal: bool, use_segments: bool):
     kv_idx = pl.program_id(3)
-    num_kv = pl.num_programs(3)
-    if block_skip and causal:
-        last_kv = _last_valid_kv(pl.program_id(2), block_q, block_k, num_kv)
-    else:
-        last_kv = num_kv - 1
+    lo = lo_ref[pl.program_id(0), pl.program_id(2)]
+    hi = hi_ref[pl.program_id(0), pl.program_id(2)]
 
-    @pl.when(kv_idx <= last_kv)
+    @pl.when(jnp.logical_and(hi < lo, kv_idx == 0))
+    def _nothing_to_see():
+        # No query of this block sees any key (a bucket's padded tail).
+        o_ref[0, 0] = jnp.zeros(o_ref.shape[2:], o_ref.dtype)
+        lse_ref[0, 0] = jnp.full(lse_ref.shape[2:], NEG_INF, lse_ref.dtype)
+
+    @pl.when(jnp.logical_and(lo <= kv_idx, kv_idx <= hi))
     def _body():
-        @pl.when(kv_idx == 0)
+        @pl.when(kv_idx == lo)
         def _init():
             m_scr[:] = jnp.full_like(m_scr, NEG_INF)
             l_scr[:] = jnp.zeros_like(l_scr)
@@ -138,7 +226,7 @@ def _fwd_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,  # prefetch-ish
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-        @pl.when(kv_idx == last_kv)
+        @pl.when(kv_idx == hi)
         def _finalize():
             l = l_scr[:]
             l_safe = jnp.where(l == 0.0, 1.0, l)          # fully-masked rows
@@ -176,8 +264,7 @@ def flash_fwd_qside(q, q_pos, q_seg, block_q):
 
 
 def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
-               block_q, block_k, block_skip=True, out_dtype=None,
-               qside=None):
+               block_q, block_k, out_dtype=None, qside=None):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     kv_h = k.shape[2]
@@ -197,67 +284,63 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
     kv_pos_p = _pad_to(kv_pos.astype(jnp.int32), sk_p, 1, value=PAD_POS)
     kv_seg_p = (_pad_to(kv_seg.astype(jnp.int32), sk_p, 1, value=0)
                 if use_segments else jnp.zeros_like(kv_pos_p))
+    lo, hi = block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q, block_k,
+                          causal)
 
-    grid = (b, h, sq_p // block_q, sk_p // block_k)
-    # Grid-index skip is only exact when q index i and kv index i carry the
-    # same global position; unequal lengths guarantee misalignment.
-    skip = bool(block_skip and causal and sq == sk)
-    num_kv = sk_p // block_k
+    def kv_block(bi, qi, ki, lo_ref, hi_ref):
+        # Outside [lo, hi] the index repeats the nearest block inside it —
+        # the same index as a neighbouring iteration, so Pallas issues no
+        # DMA, and pl.when skips the compute. (An empty range points at
+        # block 0.)
+        first = lo_ref[bi, qi]
+        return jnp.clip(ki, first, jnp.maximum(first, hi_ref[bi, qi]))
 
-    def clamp_k(qi, ki):
-        # Causal block skip: iterations past the diagonal re-point at the
-        # last valid block — same index as the previous iteration, so Pallas
-        # issues no DMA, and pl.when skips the compute.
-        if skip:
-            return jnp.minimum(ki, _last_valid_kv(qi, block_q, block_k,
-                                                  num_kv))
-        return ki
-
-    def q_map(bi, hi, qi, ki):
+    def q_map(bi, hi, qi, ki, *_):
         return (bi, hi, qi, 0)
 
-    def kv_map(bi, hi, qi, ki):
+    def kv_map(bi, hi, qi, ki, *ranges):
         # GQA: q head hi reads kv head hi // n_rep — no repeated HBM copy.
-        return (bi, hi // n_rep, clamp_k(qi, ki), 0)
+        return (bi, hi // n_rep, kv_block(bi, qi, ki, *ranges), 0)
 
-    def qrow_map(bi, hi, qi, ki):
+    def qrow_map(bi, hi, qi, ki, *_):
         return (bi, qi, 0)
 
-    def krow_map(bi, hi, qi, ki):
-        return (bi, 0, clamp_k(qi, ki))
+    def krow_map(bi, hi, qi, ki, *ranges):
+        return (bi, 0, kv_block(bi, qi, ki, *ranges))
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, use_segments=use_segments,
-        block_q=block_q, block_k=block_k, block_skip=skip)
+        _fwd_kernel, scale=scale, causal=causal, use_segments=use_segments)
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, LANES), qrow_map),          # q_pos
-            pl.BlockSpec((1, SUBLANES, block_k), krow_map),       # kv_pos
-            pl.BlockSpec((1, block_q, LANES), qrow_map),          # q_seg
-            pl.BlockSpec((1, SUBLANES, block_k), krow_map),       # kv_seg
-            pl.BlockSpec((1, 1, block_q, d), q_map),              # q
-            pl.BlockSpec((1, 1, block_k, d), kv_map),             # k
-            pl.BlockSpec((1, 1, block_k, d), kv_map),             # v
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), q_map),
-            pl.BlockSpec((1, 1, block_q, LANES),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                                # lo, hi
+            grid=(b, h, sq_p // block_q, sk_p // block_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, LANES), qrow_map),      # q_pos
+                pl.BlockSpec((1, SUBLANES, block_k), krow_map),   # kv_pos
+                pl.BlockSpec((1, block_q, LANES), qrow_map),      # q_seg
+                pl.BlockSpec((1, SUBLANES, block_k), krow_map),   # kv_seg
+                pl.BlockSpec((1, 1, block_q, d), q_map),          # q
+                pl.BlockSpec((1, 1, block_k, d), kv_map),         # k
+                pl.BlockSpec((1, 1, block_k, d), kv_map),         # v
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, d), q_map),
+                pl.BlockSpec((1, 1, block_q, LANES), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq_p, d), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_p, LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
         interpret=_interpret(),
-    )(q_pos_l, _bcast_sublanes(kv_pos_p),
+    )(lo, hi, q_pos_l, _bcast_sublanes(kv_pos_p),
       q_seg_l, _bcast_sublanes(kv_seg_p), qT, kT, vT)
 
     out = jnp.swapaxes(out[:, :, :sq], 1, 2)          # [b, sq, h, d]
@@ -267,6 +350,14 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
 # ---------------------------------------------------------------------------
 # Backward kernels
 # ---------------------------------------------------------------------------
+
+def _last_valid_kv(qi, block_q: int, block_k: int, num_kv):
+    """Last kv-block index that can contain an unmasked key for q block qi,
+    under causal masking with globally monotone positions (standard training
+    layout, including contiguous packing: a later global index is either a
+    future position or a later segment — masked either way)."""
+    return jnp.minimum(num_kv - 1, ((qi + 1) * block_q - 1) // block_k)
+
 
 def _bwd_dq_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
                    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -451,13 +542,17 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK_K,
     block_skip: bool = True,
 ) -> jax.Array:
-    """block_skip skips above-diagonal blocks by GRID index; it is exact
-    iff q storage index i holds the same global position as kv storage
-    index i (q_positions[:, i] == kv_positions[:, i] — standard training
-    layout, including contiguous packing). Offset layouts (e.g. a chunked
-    prefill where q rows start at position P > 0) violate this; the skip
-    auto-disables when sq != sk, and callers with aligned lengths but
-    misaligned positions must pass block_skip=False.
+    """The forward needs no hint: it visits the kv blocks its positions
+    and segment ids say a query can see (block_ranges), whatever the layout.
+
+    block_skip is the BACKWARD kernels' alone: they skip above-diagonal
+    blocks by GRID index, which is exact iff q storage index i holds the
+    same global position as kv storage index i (q_positions[:, i] ==
+    kv_positions[:, i] — standard training layout, including contiguous
+    packing). Offset layouts (e.g. q rows that start at position P > 0)
+    violate this; the skip auto-disables when sq != sk, and a caller that
+    differentiates through aligned lengths but misaligned positions must
+    pass block_skip=False.
 
     Structure: the fwd kernel runs OUTSIDE the custom_vjp, and its outputs
     (out, lse) — exactly the backward kernels' residuals — enter the vjp as
@@ -474,7 +569,7 @@ def flash_attention(
     # q/k/v args carry the real tangents.
     def fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg):
         return _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg,
-                          scale_v, causal, block_q, block_k, block_skip)
+                          scale_v, causal, block_q, block_k)
 
     with jax.named_scope("flash.fwd"):
         out, lse = _per_shard(
@@ -587,7 +682,9 @@ def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
     kv_pos_s = _bcast_sublanes(kv_pos_p)
     kv_seg_s = _bcast_sublanes(kv_seg_p)
 
-    skip = bool(block_skip and causal and sq == sk)  # see _flash_fwd note
+    # Grid-index skip is only exact when q index i and kv index i carry the
+    # same global position; unequal lengths guarantee misalignment.
+    skip = bool(block_skip and causal and sq == sk)
     num_kv = sk_p // block_k
     num_q = sq_p // block_q
 

@@ -24,8 +24,12 @@ Two inner implementations per ring step:
 Correctness under sharding falls out of the absolute-position masking
 convention shared with ops.attention / ops.flash_attention: each shard owns
 its positions/segment ids, so causality and packing need no global index
-arithmetic. The flash path must pass block_skip=False on rotated shards
-(storage index no longer equals position — the skip's alignment premise).
+arithmetic. The flash forward works out from each held shard's positions
+which of its blocks a query can see (ops.flash_attention.block_ranges), so a
+rotated shard that lies wholly in the future costs nothing; only the
+backward kernels still skip by grid index and must be told
+block_skip=False on rotated shards (storage index no longer equals
+position — that skip's alignment premise).
 
 Call *inside* ``jax.shard_map`` with q/k/v already sequence-sharded — or use
 ``runbooks_tpu.models.transformer`` with ``attention_impl="ring"`` which does
@@ -181,9 +185,8 @@ def _ring_flash_fwd_pass(q, k, v, q_positions, kv_positions, q_seg, kv_seg,
     # through bf16 between steps.
     qside = flash_fwd_qside(q, q_positions, q_seg, block_q)
 
-    # Local shard first: storage aligns with positions, block skip valid.
     acc, lse_run = _flash_fwd(q, k, v, q_positions, kv_positions, q_seg,
-                              kv_seg, scale, causal, block_q, block_k, True,
+                              kv_seg, scale, causal, block_q, block_k,
                               out_dtype=f32, qside=qside)
 
     def step(carry, _):
@@ -192,10 +195,8 @@ def _ring_flash_fwd_pass(q, k, v, q_positions, kv_positions, q_seg, kv_seg,
         vc = jax.lax.ppermute(vc, axis_name, perm)
         kp = jax.lax.ppermute(kp, axis_name, perm)
         ks = jax.lax.ppermute(ks, axis_name, perm)
-        # Rotated shards: storage index no longer equals position, so the
-        # causal block skip's alignment premise is void — skip off.
         o_blk, lse_blk = _flash_fwd(q, kc, vc, q_positions, kp, q_seg, ks,
-                                    scale, causal, block_q, block_k, False,
+                                    scale, causal, block_q, block_k,
                                     out_dtype=f32, qside=qside)
         acc, lse_run = _merge(acc, lse_run, o_blk, lse_blk)
         return (acc, lse_run, kc, vc, kp, ks), None

@@ -52,7 +52,12 @@ import numpy as np
 
 from runbooks_tpu.api.serve_params import QOS_CLASSES, ServeOptions
 from runbooks_tpu.models.config import ModelConfig
-from runbooks_tpu.models.transformer import KVCache, forward, project_logits
+from runbooks_tpu.models.transformer import (
+    KVCache,
+    forward,
+    project_logits,
+    use_flash_cached_prefill,
+)
 from runbooks_tpu.obs import device as obs_device
 from runbooks_tpu.obs import flight as obs_flight
 from runbooks_tpu.obs import metrics as obs_metrics
@@ -278,7 +283,12 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # slot's previous occupant needs no clearing: this request's
         # queries only ever attend slots <= their own position, all of
         # which this prefill/decode has (re)written; pad tokens land in
-        # the trash slot, which no real query ever attends.
+        # the trash slot, which no real query ever attends. A token parked
+        # there is nobody's query either: nothing reads its attention
+        # output (the head runs on last_pos, the splice copies K/V), so
+        # the flash forward is handed a position for it that sees no key,
+        # and a bucket's padded tail costs no kv block
+        # (models/transformer._cached_attention).
         #
         # Recurrent state and conv tail (linear-attention layers; pool
         # leaves `state` / `conv`). They have no slot axis, so no trash
@@ -1729,8 +1739,9 @@ class InferenceEngine:
                     jnp.asarray(slots), jnp.asarray(last_pos), self.rng,
                     jnp.asarray(temps), jnp.asarray(top_ks),
                     jnp.asarray(top_ps))
-            return args, {**self._adapter_kwargs(aslots),
-                          **self._grammar_prefill_kwargs(group, rows)}
+            kwargs = {**self._adapter_kwargs(aslots),
+                      **self._grammar_prefill_kwargs(group, rows)}
+            return args, kwargs, positions
 
         def program(args, akw):
             if pkey:
@@ -1752,8 +1763,9 @@ class InferenceEngine:
                           group: List[tuple], operands, program) -> None:
         """One batched prefill under its spans, shared with the paged
         engine: `operands()` builds the host arrays and places them
-        (-> args, kwargs), `program(args, kwargs)` makes the jitted call
-        and returns the first tokens still on the device."""
+        (-> args, kwargs, the host `positions` [rows, bucket]),
+        `program(args, kwargs)` makes the jitted call and returns the
+        first tokens still on the device."""
         # Request ids only materialize when tracing is on (same rule as
         # the decode span's active count: no per-dispatch list builds on
         # the hot path for a disabled tracer).
@@ -1762,7 +1774,7 @@ class InferenceEngine:
         with span("prefill", bucket=bucket, rows=rows, prefix=plen,
                   **attrs):
             with fine("prefill.operands"):
-                args, kwargs = operands()
+                args, kwargs, positions = operands()
             # Dispatch timing is host-side, outside jit (the np.asarray
             # pull below is the device sync) — zero effect on compiled
             # programs.
@@ -1770,6 +1782,8 @@ class InferenceEngine:
             with self._mesh_ctx():
                 with fine("prefill.dispatch"):
                     first = program(args, kwargs)
+                    # The call has returned and the device is at work.
+                    self._count_flash_blocks(bucket, positions)
                 with fine("prefill.sync"):
                     # rbt-check: ignore[device-sync] prefill dispatch boundary — the first token must reach the host to stream
                     first = np.asarray(first)
@@ -1787,6 +1801,34 @@ class InferenceEngine:
             with fine("prefill.activate"):
                 for i, (slot, req) in enumerate(group):
                     self._activate_slot(slot, req, int(first[i]))
+
+    def _count_flash_blocks(self, bucket: int,
+                            positions: np.ndarray) -> None:
+        """Which share of the flash forward's grid a prefill dispatch
+        computes: one kernel call a row of the dispatch, which every
+        full-attention layer repeats. Counted on the host from the
+        positions the dispatch was given, by the function the kernel takes
+        its ranges from, as models/transformer._cached_attention hands them
+        over (parked tokens at -1 against one scratch row of keys)."""
+        if not use_flash_cached_prefill(self.cfg, bucket):
+            return
+        from runbooks_tpu.ops.flash_attention import block_counts
+
+        cache_len = self.max_seq_len + 1
+        visited, grid = block_counts(
+            np.where(positions >= cache_len - 1, -1, positions),
+            np.broadcast_to(np.arange(cache_len, dtype=np.int32),
+                            (positions.shape[0], cache_len)),
+            None, None, self.cfg.flash_block_q, self.cfg.flash_block_k, True)
+        obs_metrics.REGISTRY.inc(
+            "serve_flash_blocks_visited_total", visited, bucket=str(bucket),
+            help_text="(query block, kv block) pairs the flash forward "
+                      "computed a head and layer in prefill, by bucket.")
+        obs_metrics.REGISTRY.inc(
+            "serve_flash_blocks_grid_total", grid, bucket=str(bucket),
+            help_text="(query block, kv block) pairs of the flash "
+                      "forward's grid a head and layer in prefill, by "
+                      "bucket.")
 
     def _activate_slot(self, slot: int, req: Request,
                        first_tok: int) -> None:

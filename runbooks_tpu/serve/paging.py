@@ -1799,8 +1799,9 @@ class PagedInferenceEngine(InferenceEngine):
             if ppb:
                 args = args + (jnp.asarray(prefix_pages),
                                jnp.asarray(prefix_len))
-            return args, {**self._adapter_kwargs(aslots),
-                          **self._grammar_prefill_kwargs(group, rows)}
+            kwargs = {**self._adapter_kwargs(aslots),
+                      **self._grammar_prefill_kwargs(group, rows)}
+            return args, kwargs, positions
 
         def program(args, kwargs):
             first, self.cache, self.rng = self._paged_prefill(

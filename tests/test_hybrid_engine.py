@@ -62,6 +62,43 @@ def requests(spec, seed=1):
 GAP_LIMIT = 2e-4
 
 
+def test_flash_prefill_counts_the_blocks_it_visits(reference):
+    """A prompt shorter than its bucket through the flash forward: /metrics
+    shows fewer blocks visited than the grid has, with the numbers
+    block_ranges gives for the positions the kernel was handed, and what
+    the engine then serves (prefill, then decode) is still the uncached
+    forward's."""
+    from runbooks_tpu.obs.metrics import REGISTRY
+    from runbooks_tpu.ops.flash_attention import block_counts
+
+    cfg = tiny_config(dtype="float32", param_dtype="bfloat16",
+                      attention_impl="flash", flash_block_q=16,
+                      flash_block_k=32)
+    eng = InferenceEngine(cfg, seeded_params(cfg, SEED), max_slots=2,
+                          max_seq_len=128)
+    (req,) = requests([(40, 6)])
+    bucket = eng._bucket_for(40)
+    assert bucket == 64
+    names = ("serve_flash_blocks_visited_total",
+             "serve_flash_blocks_grid_total")
+    before = [REGISTRY.counter_value(n, bucket="64") for n in names]
+    eng.generate([req])
+    visited, grid = (REGISTRY.counter_value(n, bucket="64") - b
+                     for n, b in zip(names, before))
+    # Query blocks of 16 rows: positions 0-15 and 16-31 see kv block 0,
+    # 32-39 (and 8 parked rows) blocks 0-1, the all-parked fourth none;
+    # the grid is 4 x ceil(129 / 32).
+    q_pos = np.full((1, bucket), -1, np.int32)
+    q_pos[0, :40] = np.arange(40)
+    kv_pos = np.arange(129, dtype=np.int32)[None]
+    assert (visited, grid) == block_counts(q_pos, kv_pos, None, None, 16, 32,
+                                           True) == (4, 20)
+    text = REGISTRY.render()
+    assert all(f'{n}{{bucket="64"}}' in text for n in names)
+    assert len(req.output_tokens) == 6
+    assert served_gap(reference, req) < GAP_LIMIT
+
+
 @pytest.mark.parametrize("chunk", [1, 4])
 def test_one_bucket_one_group_then_chunked_decode(model, reference, chunk):
     """Prompts of unequal length in one bucket (64) and one admission
