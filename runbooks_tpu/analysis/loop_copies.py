@@ -166,14 +166,15 @@ def _update_size(instr: Instr, comp: List[Instr],
 def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
     """(dtype, dims) -> the largest in-place update allowed there, for a
     KVCache (or its ShapeDtypeStructs): each leaf whole, and one layer of
-    k / v / the scales. A token-sized write is anything under a layer of
+    k / v / the scales / the latent. A token-sized write is anything under a layer of
     K/V; the recurrent leaves change a whole layer at a time."""
     names = {"bfloat16": "bf16", "float32": "f32", "float16": "f16",
              "int8": "s8"}
     shapes: Dict[Tuple[str, Tuple[int, ...]], int] = {}
-    for field in ("k", "v", "k_scale", "v_scale", "state", "conv"):
+    for field in ("k", "v", "k_scale", "v_scale", "state", "conv",
+                  "latent"):
         leaf = getattr(cache, field)
-        if leaf is None:
+        if leaf is None or 0 in leaf.shape:   # a latent cache's empty k, v
             continue
         dtype = names[str(leaf.dtype)]
         dims = tuple(int(d) for d in leaf.shape)

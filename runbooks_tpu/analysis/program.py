@@ -66,6 +66,7 @@ class AuditSettings:
     # ones): its prefill and decode programs carry the recurrent cache
     # leaves (`state`, `conv`) and the token mask (docs/hybrid-models.md).
     hybrid_config: str = "debug-hybrid"
+    sparse_latent_config: str = "debug-sparse-latent"
     max_slots: int = 2
     decode_chunk: int = 2
     # Speculative verify window (serve/engine.py make_verify_fn): the
@@ -610,6 +611,30 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
                               views_h[-1]),
          "args": [params_h, pool_h] + decode_args[2:],
          "signatures": len(views_h)},
+    ]
+    # Latent attention, a leading dense layer and sparse FFNs held as a
+    # share: again the same two factories, other programs (the latent
+    # leaf's splice, the absorbed decode, the grouped product, the counts
+    # among the results). The engine refuses the prefix, verify, adapter
+    # and paged variants here too.
+    cfg_s = get_config(settings.sparse_latent_config, moe_experts_held=8)
+    params_s = jax.eval_shape(functools.partial(init_params, cfg_s), key)
+    pool_s = jax.eval_shape(lambda: KVCache.create(
+        cfg_s, slots, cfg_s.max_seq_len, trash_slot=True))
+    buckets_s = _buckets(cfg_s.max_seq_len)
+    views_s = view_buckets_for(cfg_s.max_seq_len)
+    specs += [
+        {"component": "serve", "name": "sparse_latent_prefill",
+         "fn": make_prefill_fn(cfg_s, cfg_s.max_seq_len + 1),
+         "args": [params_s, pool_s] + prefill_args(
+             rows_set[-1], buckets_s[-1])[2:],
+         "signatures": len(buckets_s) * len(rows_set)},
+        {"component": "serve", "name": "sparse_latent_decode",
+         "fn": make_decode_fn(cfg_s, settings.decode_chunk,
+                              cfg_s.max_seq_len, cfg_s.max_seq_len,
+                              views_s[-1]),
+         "args": [params_s, pool_s] + decode_args[2:],
+         "signatures": len(views_s)},
     ]
     return specs
 

@@ -53,7 +53,11 @@ def resolve_collective_matmul_param(params: dict) -> Optional[str]:
 
 
 # Token-mixer kinds a layer pattern may name (ModelConfig.layer_types).
-LAYER_KINDS = ("full_attention", "linear_attention")
+LAYER_KINDS = ("full_attention", "linear_attention", "latent_attention")
+# The kinds whose layers are the period's ONE stack params["layers"] (keys
+# and values, or a latent, a token): exactly one of them a period.
+ATTENTION_KINDS = ("full_attention", "latent_attention")
+MOE_ROUTERS = ("softmax", "sigmoid")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,13 +83,34 @@ class ModelConfig:
     activation: str = "silu"          # "silu" | "gelu" | "relu"
     mlp_bias: bool = False
 
-    # Mixture of Experts (models/moe.py). 0 experts = dense MLP. With
-    # experts, the FFN becomes top-k-routed gated experts whose leading dim
-    # shards over the "expert" mesh axis (expert parallelism).
-    moe_num_experts: int = 0
+    # Mixture of Experts (models/moe.py, docs/sparse-latent-models.md).
+    # 0 experts = dense MLP. With experts, the FFN becomes top-k-routed
+    # gated experts, dropless, whose leading dim shards over the "expert"
+    # mesh axis (expert parallelism).
+    moe_num_experts: int = 0          # experts the router scores over
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01        # load-balance loss weight
+    # "softmax": softmax over all experts, the chosen k renormalised
+    # (Mixtral). "sigmoid": a sigmoid a score, a selection bias added ONLY
+    # to choose, gate weights the chosen scores over their sum, times
+    # moe_routed_scale.
+    moe_router: str = "softmax"
+    moe_router_bias: bool = False     # the selection bias (sigmoid router)
+    moe_routed_scale: float = 1.0
+    # Width of an expert (0 = intermediate_size) and how many shared
+    # experts run on every token beside the routed ones (one dense gated
+    # MLP of moe_shared_experts x that width, added unscaled).
+    moe_intermediate_size: int = 0
+    moe_shared_experts: int = 0
+    # A chip's share: this process holds the weights of experts
+    # [moe_experts_first, moe_experts_first + moe_experts_held) and
+    # computes their part of the sum; what the other experts would add is
+    # left out (0 held = all of them).
+    moe_experts_held: int = 0
+    moe_experts_first: int = 0
+    # Layers BEFORE the period scan with a dense FFN of intermediate_size
+    # (params["leading_layers"]); the periods take num_layers minus these.
+    leading_dense_layers: int = 0
 
     # Attention
     attn_bias: bool = False
@@ -100,6 +125,20 @@ class ModelConfig:
     # (hybrids whose recurrent layers carry order).
     position_type: str = "rope"       # "rope" | "alibi" | "learned" | "none"
     rope_theta: float = 10000.0
+    # YaRN (ops/rotary.yarn_inv_freq): () = plain rotary, else
+    # (factor, original_max_position, beta_fast, beta_slow, mscale,
+    # mscale_all_dim): blended inverse frequencies, and the softmax scale
+    # times yarn_attn_factor ** 2.
+    rope_yarn: tuple = ()
+
+    # Latent attention (layer kind "latent_attention", MLA): what a token
+    # and layer caches is [c (kv_lora_rank), k_r (qk_rope_head_dim)], with
+    # no head axis; a head's query is qk_nope_head_dim + qk_rope_head_dim
+    # wide, its value v_head_dim.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # Block structure
     parallel_block: bool = False      # falcon/gpt-neox parallel attn+mlp
@@ -219,20 +258,50 @@ class ModelConfig:
         return jnp.dtype(self.param_dtype)
 
     def __post_init__(self):
+        # A params.json gives a list; the config is hashed (a static
+        # argument of jitted and checkpointed functions).
+        object.__setattr__(self, "rope_yarn", tuple(self.rope_yarn))
         kinds = self.layer_pattern
         bad = [k for k in kinds if k not in LAYER_KINDS]
         if bad:
             raise ValueError(
                 f"unknown layer type(s) {bad}; expected {LAYER_KINDS}")
-        if kinds.count("full_attention") != 1:
+        if sum(kinds.count(k) for k in ATTENTION_KINDS) != 1:
             raise ValueError(
                 "a period of the layer pattern holds exactly one "
-                "full_attention layer (params['layers'] is one stack, "
-                f"scanned a period a step); got {kinds}")
-        if self.num_layers % len(kinds):
+                "full_attention or latent_attention layer (params['layers'] "
+                f"is one stack, scanned a period a step); got {kinds}")
+        if (self.num_layers - self.leading_dense_layers) % len(kinds) \
+                or self.leading_dense_layers >= self.num_layers:
             raise ValueError(
-                f"num_layers {self.num_layers} is not a whole number of "
-                f"periods of the layer pattern (length {len(kinds)})")
+                f"num_layers {self.num_layers} less the "
+                f"{self.leading_dense_layers} leading layers is not a whole "
+                f"number of periods of the layer pattern (length "
+                f"{len(kinds)})")
+        if self.leading_dense_layers and len(kinds) > 1:
+            raise ValueError(
+                "leading_dense_layers are layers of the period's one "
+                "attention kind; a longer pattern has no leading form")
+        if "latent_attention" in kinds and not (
+                self.kv_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim):
+            raise ValueError(
+                "latent_attention layers need kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+        if self.moe_router not in MOE_ROUTERS:
+            raise ValueError(
+                f"unknown moe_router {self.moe_router!r}; expected "
+                f"{'|'.join(MOE_ROUTERS)}")
+        if self.moe_num_experts:
+            first, held = self.moe_experts_first, self.moe_experts_here
+            if not 0 <= first <= first + held <= self.moe_num_experts:
+                raise ValueError(
+                    f"experts [{first}, {first + held}) are not among the "
+                    f"{self.moe_num_experts} the router scores over")
+        if self.rope_yarn and len(self.rope_yarn) != 6:
+            raise ValueError(
+                "rope_yarn is (factor, original_max_position, beta_fast, "
+                "beta_slow, mscale, mscale_all_dim)")
         if self.norm_position not in ("pre", "post"):
             raise ValueError(
                 f"unknown norm_position {self.norm_position!r}; "
@@ -258,11 +327,58 @@ class ModelConfig:
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // len(self.layer_pattern)
+        return ((self.num_layers - self.leading_dense_layers)
+                // len(self.layer_pattern))
 
     def layers_of(self, kind: str) -> int:
-        """How many of the model's layers are of this kind."""
-        return self.num_periods * self.layer_pattern.count(kind)
+        """How many of the model's layers are of this kind (the leading
+        layers are of the period's attention kind)."""
+        n = self.num_periods * self.layer_pattern.count(kind)
+        if kind == self.attention_kind:
+            n += self.leading_dense_layers
+        return n
+
+    @property
+    def attention_kind(self) -> str:
+        """The kind of the period's one attention layer."""
+        return next(k for k in self.layer_pattern if k in ATTENTION_KINDS)
+
+    @property
+    def latent_cache(self) -> bool:
+        """The attention layers cache one latent a token, with no head
+        axis, instead of keys and values a KV head."""
+        return self.attention_kind == "latent_attention"
+
+    @property
+    def latent_width(self) -> int:
+        """What a latent-attention layer caches a token."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def q_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def moe_experts_here(self) -> int:
+        """Experts whose weights this process holds."""
+        return self.moe_experts_held or self.moe_num_experts
+
+    @property
+    def moe_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def yarn_attn_factor(self) -> float:
+        """m of the YaRN softmax scale, 0.1 mscale_all_dim ln(factor) + 1:
+        scores are scaled by q_head_dim^-1/2 m^2."""
+        if not self.rope_yarn:
+            return 1.0
+        import math
+
+        factor, mscale_all = self.rope_yarn[0], self.rope_yarn[5]
+        if factor <= 1 or not mscale_all:
+            return 1.0
+        return 0.1 * mscale_all * math.log(factor) + 1.0
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -291,29 +407,53 @@ class ModelConfig:
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
-    @property
-    def num_params(self) -> int:
-        """Parameter count (embedding included once if tied)."""
-        h, v = self.hidden_size, self.vocab_size
-        embed = v * h
-        head = 0 if self.tie_embeddings else v * h
-        pos = v * 0
-        if self.position_type == "learned":
-            pos = self.max_seq_len * h
+    def _ffn_params(self, width: int) -> int:
+        h = self.hidden_size
+        n = (2 if self.gated_mlp else 1) * h * width + width * h
+        if self.mlp_bias:
+            n += (2 if self.gated_mlp else 1) * width + h
+        return n
+
+    def _sparse_ffn_params(self) -> int:
+        """The experts HELD, the router (and its selection bias) and the
+        shared expert of one sparse layer."""
+        E = self.moe_num_experts
+        return (self.moe_experts_here * self._ffn_params(self.moe_width)
+                + self.hidden_size * E
+                + (E if self.moe_router_bias else 0)
+                + (self._ffn_params(self.moe_width * self.moe_shared_experts)
+                   if self.moe_shared_experts else 0))
+
+    def _attn_params(self) -> int:
+        h = self.hidden_size
+        if self.latent_cache:
+            H, r = self.num_heads, self.kv_lora_rank
+            return (h * H * self.q_head_dim + h * self.latent_width
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * h
+                    + (self.q_head_dim if self.qk_norm else 0) + r)
         attn = h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h
         if self.attn_bias:
             attn += self.q_dim + 2 * self.kv_dim + h
         if self.qk_norm:
             attn += (2 * self.head_dim if self.qk_norm_width == "head"
                      else self.q_dim + self.kv_dim)
-        mlp_mats = (2 if self.gated_mlp else 1) * h * self.intermediate_size
-        mlp_mats += self.intermediate_size * h
-        if self.mlp_bias:
-            mlp_mats += (2 if self.gated_mlp else 1) * self.intermediate_size + h
-        if self.moe_num_experts:
-            # E expert copies of the (gated) FFN + the router matrix.
-            mlp_mats = self.moe_num_experts * mlp_mats \
-                + h * self.moe_num_experts
+        return attn
+
+    @property
+    def num_params(self) -> int:
+        """Parameter count of what this process holds (embedding included
+        once if tied; of a sparse layer the experts held)."""
+        h, v = self.hidden_size, self.vocab_size
+        embed = v * h
+        head = 0 if self.tie_embeddings else v * h
+        pos = v * 0
+        if self.position_type == "learned":
+            pos = self.max_seq_len * h
+        attn = self._attn_params()
+        dense = self._ffn_params(self.intermediate_size)
+        mlp_mats = self._sparse_ffn_params() if self.moe_num_experts \
+            else dense
         norms_per_layer = h if (self.parallel_block and self.shared_layer_norm) else 2 * h
         if self.norm_type == "layernorm":
             norms_per_layer *= 2  # scale + bias
@@ -326,8 +466,11 @@ class ModelConfig:
                   + self.linear_conv_kernel * self.linear_conv_dim
                   + 2 * self.linear_num_heads + self.linear_value_head_dim)
         final_norm = h * (2 if self.norm_type == "layernorm" else 1)
+        lead = self.leading_dense_layers
         return (embed + head + pos + final_norm
-                + self.layers_of("full_attention") * (attn + rest)
+                + lead * (attn + dense + norms_per_layer)
+                + (self.layers_of(self.attention_kind) - lead)
+                * (attn + rest)
                 + self.layers_of("linear_attention") * (linear + rest))
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
@@ -337,13 +480,27 @@ class ModelConfig:
         """
         s = seq_len or self.max_seq_len
         h = self.hidden_size
-        attn_proj = 2 * (h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h)
-        attn_scores = 2 * 2 * s * self.q_dim  # QK^T and PV, per token
-        mlp = 2 * ((2 if self.gated_mlp else 1) * h * self.intermediate_size
-                   + self.intermediate_size * h)
+        if self.latent_cache:
+            # The expanded form: projections, then scores at the query
+            # width and the weighted sum at the value width.
+            attn_proj = 2 * (self._attn_params() - self.kv_lora_rank
+                             - (self.q_head_dim if self.qk_norm else 0))
+            attn_scores = 2 * s * self.num_heads * (self.q_head_dim
+                                                    + self.v_head_dim)
+        else:
+            attn_proj = 2 * (h * self.q_dim + 2 * h * self.kv_dim
+                             + self.q_dim * h)
+            attn_scores = 2 * 2 * s * self.q_dim  # QK^T and PV, per token
+        gates = 2 if self.gated_mlp else 1
+        dense = 2 * (gates + 1) * h * self.intermediate_size
+        mlp = dense
         if self.moe_num_experts:
-            # top-k active experts per token + the router matmul.
-            mlp = mlp * self.moe_top_k + 2 * h * self.moe_num_experts
+            # top-k active experts per token (the share of them held
+            # here), the shared expert, and the router matmul.
+            share = self.moe_experts_here / self.moe_num_experts
+            mlp = (2 * (gates + 1) * h * self.moe_width
+                   * (self.moe_top_k * share + self.moe_shared_experts)
+                   + 2 * h * self.moe_num_experts)
         kd, vd = self.linear_key_dim, self.linear_value_dim
         # The delta rule itself: S^T k, the rank-one update, S^T q.
         linear = (2 * (h * (2 * kd + 2 * vd) + vd * h
@@ -352,8 +509,11 @@ class ModelConfig:
                   + 6 * self.linear_num_heads * self.linear_key_head_dim
                   * self.linear_value_head_dim)
         head = 2 * h * self.vocab_size
+        lead = self.leading_dense_layers
         return float(
-            self.layers_of("full_attention") * (attn_proj + attn_scores + mlp)
+            lead * (attn_proj + attn_scores + dense)
+            + (self.layers_of(self.attention_kind) - lead)
+            * (attn_proj + attn_scores + mlp)
             + self.layers_of("linear_attention") * (linear + mlp) + head)
 
 
@@ -429,6 +589,28 @@ def _olmo_hybrid(name, v=100352, h=3840, i=11008, l=32, q=30, d=128,
     )
 
 
+def _sarvam_mla(name, v=262144, h=4096, i=16384, l=32, q=64, s=131072,
+                nope=128, rope=64, vd=128, rank=512, experts=128, top_k=8,
+                moe_i=2048, yarn=(40.0, 4096, 32.0, 1.0, 1.0, 1.0)):
+    # Latent attention (MLA, direct query projection), one leading dense
+    # layer, then sparse layers: sigmoid router with a selection bias,
+    # top-k of the routed experts beside one shared expert
+    # (docs/sparse-latent-models.md). head_dim is the cached, absorbed
+    # width kv_lora_rank + qk_rope_head_dim, as the published config has it.
+    return ModelConfig(
+        name=name, vocab_size=v, hidden_size=h, intermediate_size=i,
+        num_layers=l, num_heads=q, num_kv_heads=q, head_dim=rank + rope,
+        max_seq_len=s, norm_type="rmsnorm", norm_eps=1e-6, gated_mlp=True,
+        activation="silu", position_type="rope", rope_theta=10000.0,
+        rope_yarn=yarn, qk_norm=True,
+        layer_types=("latent_attention",), kv_lora_rank=rank,
+        qk_nope_head_dim=nope, qk_rope_head_dim=rope, v_head_dim=vd,
+        leading_dense_layers=1, moe_num_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_i, moe_shared_experts=1,
+        moe_router="sigmoid", moe_router_bias=True, moe_routed_scale=2.5,
+    )
+
+
 # Registry mirrors the reference's documented example configs
 # (reference: examples/ tree — llama2-7b, llama2-70b, falcon-7b/40b,
 # facebook-opt-125m) plus debug sizes for tests/benchmarks.
@@ -462,6 +644,9 @@ CONFIGS = {
     # OLMo hybrid: linear-attention (gated delta rule) layers beside full
     # ones, 3:1 (docs/hybrid-models.md)
     "olmo-hybrid-7b": _olmo_hybrid("olmo-hybrid-7b"),
+    # Latent attention + sparse experts + a leading dense layer
+    # (docs/sparse-latent-models.md)
+    "sarvam-105b": _sarvam_mla("sarvam-105b"),
     # GPT-2 (fused-qkv Conv1D checkpoints; learned positions)
     "gpt2": _gpt2("gpt2"),
     "gpt2-xl": _gpt2("gpt2-xl", h=1600, i=6400, l=48, q=25),
@@ -471,6 +656,12 @@ CONFIGS = {
     "debug-hybrid": _olmo_hybrid("debug-hybrid", v=512, h=128, i=384, l=4,
                                  q=4, d=32, s=128, lin_heads=4, lin_dk=32,
                                  lin_dv=64),
+    # The same mechanisms at toy widths: 1 dense + 2 sparse layers, 16
+    # experts of which a process may hold a share (rbt check, tests)
+    "debug-sparse-latent": _sarvam_mla(
+        "debug-sparse-latent", v=512, h=128, i=384, l=3, q=4, s=256,
+        nope=32, rope=16, vd=32, rank=64, experts=16, top_k=4, moe_i=64,
+        yarn=(4.0, 64, 32.0, 1.0, 1.0, 1.0)),
     "bench-1b": _llama("bench-1b", h=2048, i=5632, l=22, q=16, kv=16, d=128, s=2048),
     "bench-410m": _llama("bench-410m", h=1024, i=2816, l=24, q=16, kv=16, d=64, s=2048),
     # Same params/FLOPs as bench-410m but 8 heads x d128: wider MXU

@@ -153,9 +153,10 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     """Random-init parameters (stacked layers). For real checkpoints use
     runbooks_tpu.models.convert (HF weight import)."""
     h, v = cfg.hidden_size, cfg.vocab_size
-    # params["layers"] holds the full-attention layers: all of them, but
-    # for a hybrid pattern.
-    L = cfg.layers_of("full_attention")
+    # params["layers"] holds the attention layers the period scan runs: all
+    # of the model's layers, but for a hybrid pattern (its linear layers)
+    # and for leading layers (run before the scan, a stack of their own).
+    L = cfg.num_periods
     pd = cfg.parameter_dtype
     keys = iter(jax.random.split(rng, 16))
 
@@ -170,47 +171,39 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     if not cfg.tie_embeddings:
         params["head"] = _dense_init(next(keys), (h, v), pd, h)
 
-    layers: Params = {
-        "attn": {
-            "wq": _dense_init(next(keys), (L, h, cfg.q_dim), pd, h),
-            "wk": _dense_init(next(keys), (L, h, cfg.kv_dim), pd, h),
-            "wv": _dense_init(next(keys), (L, h, cfg.kv_dim), pd, h),
-            "wo": _dense_init(next(keys), (L, cfg.q_dim, h), pd, cfg.q_dim),
-        },
-        "ln1": _norm_params(cfg, (L,)),
-    }
-    if cfg.attn_bias:
-        layers["attn"]["bq"] = jnp.zeros((L, cfg.q_dim), pd)
-        layers["attn"]["bk"] = jnp.zeros((L, cfg.kv_dim), pd)
-        layers["attn"]["bv"] = jnp.zeros((L, cfg.kv_dim), pd)
-        layers["attn"]["bo"] = jnp.zeros((L, h), pd)
-    if cfg.qk_norm:
-        full = cfg.qk_norm_width == "full"
-        layers["attn"]["q_norm"] = jnp.ones(
-            (L, cfg.q_dim if full else cfg.head_dim), pd)
-        layers["attn"]["k_norm"] = jnp.ones(
-            (L, cfg.kv_dim if full else cfg.head_dim), pd)
+    layers: Params = {"attn": _init_attention(cfg, keys, L),
+                      "ln1": _norm_params(cfg, (L,))}
 
     if cfg.moe_num_experts:
         assert cfg.gated_mlp, "MoE experts are gated (mixtral-style)"
-        E, m = cfg.moe_num_experts, cfg.intermediate_size
+        # The router scores over ALL experts; the expert weights are those
+        # of the experts held here (models/moe.py).
+        E, held, m = cfg.moe_num_experts, cfg.moe_experts_here, cfg.moe_width
         layers["moe"] = {
             "router": (jax.random.normal(next(keys), (L, h, E))
                        * h ** -0.5).astype(pd),
-            "wi_gate": _dense_init(next(keys), (L, E, h, m), pd, h),
-            "wi_up": _dense_init(next(keys), (L, E, h, m), pd, h),
-            "wo": _dense_init(next(keys), (L, E, m, h), pd, m),
+            "wi_gate": _dense_init(next(keys), (L, held, h, m), pd, h),
+            "wi_up": _dense_init(next(keys), (L, held, h, m), pd, h),
+            "wo": _dense_init(next(keys), (L, held, m, h), pd, m),
         }
+        if cfg.moe_shared_experts:
+            layers["moe"]["shared"] = _init_gated_mlp(
+                cfg, keys, L, m * cfg.moe_shared_experts)
+        if cfg.moe_router_bias:
+            # Not zero: a bias left out of the choice, or let into the
+            # gate weights, must change the logits.
+            layers["moe"]["router_bias"] = (
+                jax.random.normal(next(keys), (L, E)) * 0.05).astype(pd)
     else:
-        mlp: Params = {
-            "wo": _dense_init(next(keys), (L, cfg.intermediate_size, h), pd,
-                              cfg.intermediate_size),
-        }
         if cfg.gated_mlp:
-            mlp["wi_gate"] = _dense_init(next(keys), (L, h, cfg.intermediate_size), pd, h)
-            mlp["wi_up"] = _dense_init(next(keys), (L, h, cfg.intermediate_size), pd, h)
+            mlp: Params = _init_gated_mlp(cfg, keys, L,
+                                          cfg.intermediate_size)
         else:
-            mlp["wi"] = _dense_init(next(keys), (L, h, cfg.intermediate_size), pd, h)
+            mlp = {"wo": _dense_init(next(keys),
+                                     (L, cfg.intermediate_size, h), pd,
+                                     cfg.intermediate_size),
+                   "wi": _dense_init(next(keys),
+                                     (L, h, cfg.intermediate_size), pd, h)}
         if cfg.mlp_bias:
             for k in ("wi_gate", "wi_up", "wi"):
                 if k in mlp:
@@ -224,7 +217,79 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     params["layers"] = layers
     if cfg.has_recurrent_state:
         params["linear_layers"] = _init_linear_layers(cfg, rng)
+    if cfg.leading_dense_layers:
+        params["leading_layers"] = _init_leading_layers(cfg, rng)
     return params
+
+
+def _init_gated_mlp(cfg: ModelConfig, keys, L: int, width: int) -> Params:
+    h, pd = cfg.hidden_size, cfg.parameter_dtype
+    return {"wo": _dense_init(next(keys), (L, width, h), pd, width),
+            "wi_gate": _dense_init(next(keys), (L, h, width), pd, h),
+            "wi_up": _dense_init(next(keys), (L, h, width), pd, h)}
+
+
+def _init_attention(cfg: ModelConfig, keys, L: int) -> Params:
+    """The attention parameters of L layers of the period's attention
+    kind, stacked; one key a matrix, in a fixed order."""
+    if cfg.latent_cache:
+        return _init_latent_attention(cfg, keys, L)
+    h, pd = cfg.hidden_size, cfg.parameter_dtype
+    attn = {
+        "wq": _dense_init(next(keys), (L, h, cfg.q_dim), pd, h),
+        "wk": _dense_init(next(keys), (L, h, cfg.kv_dim), pd, h),
+        "wv": _dense_init(next(keys), (L, h, cfg.kv_dim), pd, h),
+        "wo": _dense_init(next(keys), (L, cfg.q_dim, h), pd, cfg.q_dim),
+    }
+    if cfg.attn_bias:
+        attn["bq"] = jnp.zeros((L, cfg.q_dim), pd)
+        attn["bk"] = jnp.zeros((L, cfg.kv_dim), pd)
+        attn["bv"] = jnp.zeros((L, cfg.kv_dim), pd)
+        attn["bo"] = jnp.zeros((L, h), pd)
+    if cfg.qk_norm:
+        full = cfg.qk_norm_width == "full"
+        attn["q_norm"] = jnp.ones(
+            (L, cfg.q_dim if full else cfg.head_dim), pd)
+        attn["k_norm"] = jnp.ones(
+            (L, cfg.kv_dim if full else cfg.head_dim), pd)
+    return attn
+
+
+def _init_latent_attention(cfg: ModelConfig, keys, L: int) -> Params:
+    """Latent attention (MLA): the query projected directly to heads of
+    qk_nope + qk_rope, the input projected DOWN to [c, k_r] (what is
+    cached), and c projected UP to every head's k_nope and v."""
+    h, pd, H = cfg.hidden_size, cfg.parameter_dtype, cfg.num_heads
+    r = cfg.kv_lora_rank
+    attn = {
+        "wq": _dense_init(next(keys), (L, h, H * cfg.q_head_dim), pd, h),
+        "w_kva": _dense_init(next(keys), (L, h, cfg.latent_width), pd, h),
+        "w_kvb": _dense_init(
+            next(keys), (L, r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            pd, r),
+        "wo": _dense_init(next(keys), (L, H * cfg.v_head_dim, h), pd,
+                          H * cfg.v_head_dim),
+        "kv_norm": jnp.ones((L, r), pd),
+    }
+    if cfg.qk_norm:
+        attn["q_norm"] = jnp.ones((L, cfg.q_head_dim), pd)
+    return attn
+
+
+def _init_leading_layers(cfg: ModelConfig, rng: jax.Array) -> Params:
+    """The layers before the period scan: the period's attention kind with
+    a DENSE gated FFN of intermediate_size, stacked [leading, …]. Their
+    keys come from a split of their own (fold_in 2), beside init_params'
+    and the linear layers': no other leaf's key moves."""
+    assert cfg.gated_mlp and not cfg.mlp_bias and \
+        not (cfg.parallel_block and cfg.shared_layer_norm), \
+        "leading layers are written for the gated dense MLP, two norms"
+    n = cfg.leading_dense_layers
+    keys = iter(jax.random.split(jax.random.fold_in(rng, 2), 16))
+    attn = _init_attention(cfg, keys, n)
+    return {"attn": attn,
+            "mlp": _init_gated_mlp(cfg, keys, n, cfg.intermediate_size),
+            "ln1": _norm_params(cfg, (n,)), "ln2": _norm_params(cfg, (n,))}
 
 
 def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
@@ -299,12 +364,22 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         "wv": ("layers", "embed", "kv_heads"),
         "wo": ("layers", "heads", "embed"),
     }
+    if cfg.latent_cache:
+        # The down-projection and the latent are whole on every device (no
+        # head axis to split); the heads' projections split by head.
+        attn = {"wq": ("layers", "embed", "heads"),
+                "w_kva": ("layers", "embed", None),
+                "w_kvb": ("layers", None, "heads"),
+                "wo": ("layers", "heads", "embed"),
+                "kv_norm": ("layers", None)}
+        if cfg.qk_norm:
+            attn["q_norm"] = ("layers", "head_dim")
     if cfg.attn_bias:
         attn.update({"bq": ("layers", "heads"),
                      "bk": ("layers", "kv_heads"),
                      "bv": ("layers", "kv_heads"),
                      "bo": ("layers", "norm")})
-    if cfg.qk_norm:
+    if cfg.qk_norm and not cfg.latent_cache:
         full = cfg.qk_norm_width == "full"
         attn.update({"q_norm": ("layers", "heads" if full else "head_dim"),
                      "k_norm": ("layers",
@@ -312,7 +387,7 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
 
     if cfg.moe_num_experts:
         from runbooks_tpu.models.moe import moe_logical_axes
-        ffn_key, ffn_axes = "moe", moe_logical_axes()
+        ffn_key, ffn_axes = "moe", moe_logical_axes(cfg)
     else:
         mlp = {"wo": ("layers", "mlp", "embed")}
         if cfg.gated_mlp:
@@ -347,6 +422,13 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             "ln1": norm1(("layers",)), "ln2": norm1(("layers",))}
         axes["linear_layers"] = [
             one_position] * cfg.layer_pattern.count("linear_attention")
+    if cfg.leading_dense_layers:
+        axes["leading_layers"] = {
+            "attn": attn,
+            "mlp": {"wo": ("layers", "mlp", "embed"),
+                    "wi_gate": ("layers", "embed", "mlp"),
+                    "wi_up": ("layers", "embed", "mlp")},
+            "ln1": norm1(("layers",)), "ln2": norm1(("layers",))}
     return axes
 
 
@@ -395,6 +477,13 @@ class KVCache:
     must not count is named by forward(token_mask=...) and leaves both
     exactly as they were. A row starts from zeros.
 
+    latent (present instead of k / v content when the attention layers are
+    latent_attention; k and v then hold no layer):
+      latent [latent layers, batch, cache_len, kv_lora_rank +
+             qk_rope_head_dim], activation dtype: a token's compressed
+             [c, k_r], with NO head axis. Slots, the trash slot and both
+             write modes are those of k / v.
+
     forward() carries every leaf whole through its layer scan (the carry,
     not xs/ys): a layer writes this call's tokens at [layer, row, slot],
     reads [layer, :, :view], and a linear-attention layer reads and writes
@@ -410,6 +499,7 @@ class KVCache:
     v_scale: Optional[jax.Array] = None
     state: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    latent: Optional[jax.Array] = None
 
     @classmethod
     def create(cls, cfg: ModelConfig, batch: int, max_len: int,
@@ -419,6 +509,15 @@ class KVCache:
         shape = (cfg.layers_of("full_attention"), batch, cache_len,
                  cfg.num_kv_heads, cfg.head_dim)
         recurrent = {}
+        if cfg.latent_cache:
+            if quantize_kv:
+                raise NotImplementedError(
+                    "quantize_kv stores one scale a KV head; a latent "
+                    "cache has no head axis and no int8 form yet "
+                    "(docs/sparse-latent-models.md)")
+            recurrent["latent"] = jnp.zeros(
+                (cfg.layers_of("latent_attention"), batch, cache_len,
+                 cfg.latent_width), cfg.activation_dtype)
         if cfg.has_recurrent_state:
             n_lin = cfg.layers_of("linear_attention")
             recurrent = dict(
@@ -534,9 +633,10 @@ def use_flash_cached_prefill(cfg: ModelConfig, q_len: int) -> bool:
 
 
 def _dispatch_attention(cfg: ModelConfig, q, k, v, positions, segment_ids,
-                        mask, bias):
+                        mask, bias, scale=None):
     """Pick the attention implementation for the no-cache (training) path.
-    k/v stay at kv_heads width on every path (GQA-native kernels)."""
+    k/v stay at kv_heads width on every path (GQA-native kernels). scale:
+    None = head_dim ** -0.5."""
     impl = resolve_attention_impl(cfg)  # forces xla for alibi/softcap
 
     if impl == "flash":
@@ -544,9 +644,13 @@ def _dispatch_attention(cfg: ModelConfig, q, k, v, positions, segment_ids,
 
         return flash_attention(
             q, k, v, positions, positions, segment_ids, segment_ids,
-            True, None, cfg.flash_block_q, cfg.flash_block_k)
+            True, scale, cfg.flash_block_q, cfg.flash_block_k)
 
     if impl == "ring":
+        if scale is not None:
+            raise NotImplementedError(
+                "ring attention takes no softmax scale of the caller's: "
+                "latent attention has no sequence-parallel path")
         from runbooks_tpu.parallel.ring_attention import (
             ring_attention,
             ring_flash_attention_sharded,
@@ -594,7 +698,7 @@ def _dispatch_attention(cfg: ModelConfig, q, k, v, positions, segment_ids,
             check_vma=False,
         )(q, k, v, positions, seg)
 
-    return dot_product_attention(q, k, v, mask=mask, bias=bias,
+    return dot_product_attention(q, k, v, mask=mask, bias=bias, scale=scale,
                                  logit_softcap=cfg.logit_softcap)
 
 
@@ -763,10 +867,11 @@ def _write_layer_cache(k, v, positions, layer_cache, ad):
 
 
 def _cached_attention(cfg: ModelConfig, q, k, v, positions, mask, bias,
-                      trash_pos=None):
+                      trash_pos=None, scale=None):
     """Attention of q against one layer's cache view. trash_pos: in
     position-scatter mode the cache's last slot, where callers park a
-    bucket's padding (KVCache); None in append-at-index mode."""
+    bucket's padding (KVCache); None in append-at-index mode. scale: None
+    = head_dim ** -0.5."""
     b = q.shape[0]
     if mask is None:
         # Flash cached-prefill (forward() skipped the O(s*kv) mask
@@ -789,15 +894,123 @@ def _cached_attention(cfg: ModelConfig, q, k, v, positions, mask, bias,
             # padded tail costs nothing.
             positions = jnp.where(positions >= trash_pos, -1, positions)
         out = flash_attention(
-            q, k, v, positions, kv_pos, None, None, True, None,
+            q, k, v, positions, kv_pos, None, None, True, scale,
             cfg.flash_block_q, cfg.flash_block_k)
     else:
         # Decode (s=1) keeps the XLA path: a one-row query block has no
         # O(s^2) term and the step is bandwidth-bound anyway.
         out = dot_product_attention(
-            q, k, v, mask=mask, bias=bias,
+            q, k, v, mask=mask, bias=bias, scale=scale,
             logit_softcap=cfg.logit_softcap)
     return out
+
+
+def _write_layer_latent(lat, positions, layer_cache):
+    """_write_layer_cache for the latent leaf: write this call's [c, k_r]
+    (lat [b, s, width]) at [layer, row, slot], token-sized and in place in
+    the carried leaf [latent layers, batch, cache_len, width]; return
+    (this layer's view [b, view, width] of the UPDATED leaf, the leaf).
+    ``layer_cache`` is (leaf, layer, index, view)."""
+    b = lat.shape[0]
+    leaf, layer, index, view = layer_cache
+    if index is None:
+        slot = jnp.clip(positions, 0, leaf.shape[2] - 1)
+        b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+        leaf = leaf.at[layer, b_idx, slot].set(lat)
+    else:
+        leaf = jax.lax.dynamic_update_slice(leaf, lat[None],
+                                            (layer, 0, index, 0))
+    seen = jax.lax.dynamic_slice(
+        leaf, (layer, 0, 0, 0),
+        (1, b, leaf.shape[2] if view is None else view, leaf.shape[3]))[0]
+    return seen, leaf
+
+
+def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
+                            positions, segment_ids, mask, layer_cache):
+    """Latent attention (MLA; docs/sparse-latent-models.md). What a token
+    caches is lat = [RMSNorm(c), rotated k_r]: kv_lora_rank +
+    qk_rope_head_dim numbers with no head axis. Two forms of the same
+    attention, chosen by what the call is:
+
+    expanded  (no cache, or a cached prefill on the flash path, mask None)
+              every head's k_nope and v are made from c by w_kvb, the one
+              k_r is shared by the heads, and attention runs at
+              (q_head_dim, q_head_dim, v_head_dim);
+    absorbed  (a cache read through a mask: decode, the chunk loop) the
+              query is taken into the latent space, q_lat = q_nope W_uk,
+              scores are q_lat . c + q_rope . k_r against the leaf as it
+              lies, the weighted sum of c comes back through W_uv: the
+              cache is never expanded to per-head keys or values.
+
+    ``layer_cache``: None or (latent leaf, layer, index, view), see
+    _write_layer_latent. Returns (out [b, s, h], None or the updated
+    leaves: a tuple of the one leaf, as _attention_block gives four)."""
+    b, s, _ = x.shape
+    ad = cfg.activation_dtype
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    scale = cfg.q_head_dim ** -0.5 * cfg.yarn_attn_factor ** 2
+
+    def rope(t):
+        return apply_rope(t, positions, cfg.rope_theta, cfg.rope_yarn)
+
+    with jax.named_scope("mla.q"):
+        q = _matmul(x, p["wq"], ad).reshape(b, s, H, dn + dr)
+        q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
+        if "q_norm" in p:
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
+    with jax.named_scope("mla.kv_down"):
+        down = _matmul(x, p["w_kva"], ad)
+        lat = jnp.concatenate(
+            [rms_norm(down[..., :r], p["kv_norm"], cfg.norm_eps),
+             rope(down[..., None, r:])[:, :, 0]], axis=-1)
+    w_kvb = p["w_kvb"].astype(ad).reshape(r, H, dn + dv)
+
+    leaf = None
+    if layer_cache is not None:
+        with jax.named_scope("attn.kv_write"):
+            lat, leaf = _write_layer_latent(lat, positions, layer_cache)
+    if layer_cache is None or mask is None:
+        with jax.named_scope("mla.kv_up"):
+            up = jnp.einsum("bkr,rhd->bkhd", lat[..., :r], w_kvb,
+                            preferred_element_type=jnp.float32).astype(ad)
+            k = jnp.concatenate(
+                [up[..., :dn], jnp.broadcast_to(
+                    lat[:, :, None, r:], (*up.shape[:3], dr))], axis=-1)
+            v = up[..., dn:]
+            k = with_logical_constraint(
+                k, ("batch", "seq", "act_heads", None))
+            v = with_logical_constraint(
+                v, ("batch", "seq", "act_heads", None))
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        with jax.named_scope("mla.core"):
+            if layer_cache is None:
+                out = _dispatch_attention(cfg, q, k, v, positions,
+                                          segment_ids, mask, None, scale)
+            else:
+                trash_pos = (layer_cache[0].shape[2] - 1
+                             if layer_cache[2] is None else None)
+                out = _cached_attention(cfg, q, k, v, positions, None, None,
+                                        trash_pos, scale)
+    else:
+        with jax.named_scope("mla.absorb"):
+            q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn],
+                               preferred_element_type=jnp.float32).astype(ad)
+        with jax.named_scope("mla.core"):
+            # One "KV head" (the latent) under all H query heads: each row
+            # is one matrix product with H * s rows (ops/attention.py).
+            o_lat = dot_product_attention(
+                jnp.concatenate([q_lat, q_rope], axis=-1),
+                lat[:, :, None, :], lat[:, :, None, :r], mask=mask,
+                scale=scale)
+        with jax.named_scope("mla.absorb"):
+            out = jnp.einsum("bshr,rhd->bshd", o_lat, w_kvb[..., dn:],
+                             preferred_element_type=jnp.float32).astype(ad)
+    with jax.named_scope("mla.out"):
+        out = _matmul(out.reshape(b, s, H * dv), p["wo"], ad)
+    return out, None if leaf is None else (leaf,)
 
 
 def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -913,14 +1126,17 @@ def _mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
 
 
 def _ffn_block(cfg: ModelConfig, layer: Params, x: jax.Array,
-               adapter=None):
-    """Dense MLP or MoE, returning (out, aux-loss scalar)."""
-    if cfg.moe_num_experts:
+               adapter=None, token_mask=None):
+    """Dense MLP or MoE, by what the layer holds (a sparse model's leading
+    layers are dense), returning (out, aux-loss scalar, None or the sparse
+    layer's assignment counts, models/moe.py)."""
+    if "moe" in layer:
         from runbooks_tpu.models.moe import moe_block
 
-        return moe_block(cfg, layer["moe"], x)
+        return moe_block(cfg, layer["moe"], x, token_mask=token_mask,
+                         layer=layer.get("moe_layer"))
     return (_mlp_block(cfg, layer["mlp"], x, adapter=adapter),
-            jnp.zeros((), jnp.float32))
+            jnp.zeros((), jnp.float32), None)
 
 
 def _adapter_group(adapter, group: str):
@@ -936,14 +1152,15 @@ def _adapter_group(adapter, group: str):
 def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
            bias, layer_cache, adapter=None, token_mask=None,
            kind: str = "full_attention"):
-    """One transformer block. x: [b, s, h]. Returns (x, cache, aux).
+    """One transformer block. x: [b, s, h]. Returns (x, cache, aux,
+    counts): counts is None, or a sparse FFN's assignment counts.
     ``adapter``: None or (per-layer adapter-pool slice, lane indices) —
     the grouped LoRA injection (docs/multi-tenant-lora.md). ``kind`` names
     the token mixer (ModelConfig.layer_types). ``layer_cache`` holds the
     cache's whole leaves of the layer's kind and the layer's number in them
     (_write_layer_cache, _linear_attention_block); the updated leaves come
     back. ``token_mask`` says which tokens may change a linear-attention
-    layer's state."""
+    layer's state, and which a sparse FFN routes at all."""
 
     def mixer(h_in):
         # The linear mixer runs inside the `attn` scope too, under inner
@@ -953,6 +1170,10 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
             if kind == "linear_attention":
                 return _linear_attention_block(
                     cfg, layer["mixer"], h_in, token_mask, layer_cache)
+            if kind == "latent_attention":
+                return _latent_attention_block(
+                    cfg, layer["attn"], h_in, positions, segment_ids, mask,
+                    layer_cache)
             return _attention_block(
                 cfg, layer["attn"], h_in, positions, segment_ids, mask, bias,
                 layer_cache, adapter=_adapter_group(adapter, "attn"))
@@ -973,13 +1194,15 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
                 attn_out = _norm(cfg, layer["ln1"], attn_out)
             x = x + attn_out
             with jax.named_scope("ffn"):
-                ffn_out, aux = _ffn_block(cfg, layer, x, adapter=mlp_adapter)
+                ffn_out, aux, counts = _ffn_block(
+                    cfg, layer, x, adapter=mlp_adapter,
+                    token_mask=token_mask)
             with jax.named_scope("norm"):
                 ffn_out = _norm(cfg, layer["ln2"], ffn_out)
             x = x + ffn_out
             x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
                                         rules=act_rules)
-            return x, new_cache, aux
+            return x, new_cache, aux, counts
         with jax.named_scope("norm"):
             h1 = _norm(cfg, layer["ln1"], x)
         attn_out, new_cache = mixer(h1)
@@ -997,20 +1220,22 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
                 with jax.named_scope("norm"):
                     h2 = _norm(cfg, layer["ln2"], x)
             with jax.named_scope("ffn"):
-                mlp_out, aux = _ffn_block(cfg, layer, h2,
-                                          adapter=mlp_adapter)
+                mlp_out, aux, counts = _ffn_block(
+                    cfg, layer, h2, adapter=mlp_adapter,
+                    token_mask=token_mask)
             x = x + attn_out + mlp_out
         else:
             x = x + attn_out
             with jax.named_scope("norm"):
                 h2 = _norm(cfg, layer["ln2"], x)
             with jax.named_scope("ffn"):
-                ffn_out, aux = _ffn_block(cfg, layer, h2,
-                                          adapter=mlp_adapter)
+                ffn_out, aux, counts = _ffn_block(
+                    cfg, layer, h2, adapter=mlp_adapter,
+                    token_mask=token_mask)
             x = x + ffn_out
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
                                     rules=act_rules)
-    return x, new_cache, aux
+    return x, new_cache, aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -1031,10 +1256,16 @@ def forward(
     return_activations: bool = False,
     adapters=None,
     token_mask: Optional[jax.Array] = None,  # [b, s] bool
+    with_moe_counts: bool = False,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Returns (logits [b, s, vocab] float32, updated cache or None) — or,
     with_aux=True, (logits, cache, aux) where aux is the summed per-layer
     auxiliary loss (MoE load balance; 0.0 for dense models).
+
+    with_moe_counts=True (sparse models) appends int32 counts [sparse
+    layers, experts held + 1] to what is returned: the (token, expert)
+    assignments each held expert of each layer got in this call, and last
+    those routed to experts held elsewhere (models/moe.py).
 
     return_activations=True skips the head matmul and returns the
     post-final-norm activations [b, s, hidden] in place of logits — the
@@ -1063,11 +1294,13 @@ def forward(
     program for any tenant mix. Not supported on the pipeline (stage >
     1) path.
 
-    token_mask (models with linear-attention layers; ignored by the
-    others): which tokens are real. A masked-out token leaves a row's
-    recurrent state and conv tail exactly as they were (a bucket's padding
-    behind a prompt, a parked row of a decode batch). A row's real tokens
-    must be a prefix of it. None = all real. The full-attention layers
+    token_mask (models with linear-attention layers or sparse FFNs;
+    ignored by the others): which tokens are real. A masked-out token
+    leaves a row's recurrent state and conv tail exactly as they were (a
+    bucket's padding behind a prompt, a parked row of a decode batch), and
+    a sparse FFN routes it to no expert (its output there is the shared
+    expert's alone; nobody reads it). For a recurrent layer a row's real
+    tokens must be a prefix of it. None = all real. The full-attention layers
     keep their own rule: padding is parked by *position* (the trash slot).
     """
     b, s = tokens.shape
@@ -1075,6 +1308,8 @@ def forward(
     pattern = cfg.layer_pattern
     if cfg.has_recurrent_state:
         _check_recurrent_support(cfg, segment_ids, adapters)
+    if cfg.latent_cache:
+        _check_latent_support(cfg, adapters)
 
     if cache is not None and segment_ids is not None:
         raise NotImplementedError(
@@ -1160,7 +1395,7 @@ def forward(
         blocks = {kind: jax.checkpoint(
             fn, policy=_remat_policy(cfg.remat_policy), static_argnums=(0,))
             for kind, fn in blocks.items()}
-    block = blocks["full_attention"]
+    block = blocks[cfg.attention_kind]
 
     apool = aidx = None
     if adapters is not None:
@@ -1181,55 +1416,100 @@ def forward(
     # number for K/V, period * n_lin + i for the recurrent leaves, which
     # lie in layer order — and the loop updates the buffers in place.
     n_lin = pattern.count("linear_attention")
+    n_lead = cfg.leading_dense_layers
+
+    def attention_layer(layer_params, x, kv, layer, adapter=None):
+        """One layer of the period's attention kind at index `layer` of
+        the cache's leaves (kv: the K/V leaves, or the latent leaf)."""
+        layer_cache = None
+        if cache is not None:
+            layer_cache = (*kv, layer,
+                           None if scatter_mode else cache.index,
+                           cache_view)
+        x, new_kv, aux, counts = block(
+            cfg, layer_params, x, positions, segment_ids, mask, bias,
+            layer_cache, adapter, token_mask)
+        return x, new_kv, aux, counts
+
+    layers, lin_layers = params["layers"], params.get("linear_layers")
+    expert_stacks = None
+    if cache is not None and "moe" in layers and not _expert_mesh():
+        # Serving: a sparse layer gets its expert matrices as the WHOLE
+        # stacks beside its number in them, not as the scan's slice of
+        # them (models/moe.grouped_matmul says why).
+        names = ("wi_gate", "wi_up", "wo")
+        expert_stacks = {k: layers["moe"][k] for k in names}
+        layers = {**layers, "moe": {k: v for k, v in layers["moe"].items()
+                                    if k not in names}}
 
     def scan_body(carry, scanned):
         x, aux_sum, kv, rec = carry
         layers, pool_layer, lin_layers, period = scanned
+        if expert_stacks is not None:
+            layers = {**layers, "moe": {**layers["moe"], **expert_stacks},
+                      "moe_layer": period - n_lead}
         adapter = None if apool is None else (pool_layer, aidx)
         i = 0
+        counts = None
         for kind in pattern:
-            if kind == "full_attention":
-                layer_cache = None
-                if cache is not None:
-                    layer_cache = (*kv, period,
-                                   None if scatter_mode else cache.index,
-                                   cache_view)
-                x, kv, aux = block(
-                    cfg, layers, x, positions, segment_ids, mask, bias,
-                    layer_cache, adapter)
+            if kind != "linear_attention":
+                # The scan's layers lie behind the leading ones in the
+                # cache's leaves.
+                x, kv, aux, counts = attention_layer(
+                    layers, x, kv, period, adapter)
             else:
                 layer_cache = (None if cache is None
                                else (*rec, period * n_lin + i))
-                x, rec, aux = blocks[kind](
+                x, rec, aux, _ = blocks[kind](
                     cfg, lin_layers[i], x, positions, segment_ids, mask,
                     bias, layer_cache, None, token_mask)
                 i += 1
             aux_sum = aux_sum + aux
-        return (x, aux_sum, kv, rec), None
+        return (x, aux_sum, kv, rec), counts
 
-    layers, lin_layers = params["layers"], params.get("linear_layers")
     aux_total = jnp.zeros((), jnp.float32)
+    kv = rec = None
     if cache is not None:
         # k_scale/v_scale are None (empty pytrees) for an unquantized
         # cache, as state/conv are for a model without linear-attention
-        # layers; the carry threads them through untouched either way. The
-        # adapter pool (leading L axis) rides the scan as xs when given.
+        # layers; the carry threads them through untouched either way. A
+        # latent cache carries its one leaf in their place.
+        kv = ((cache.latent,) if cfg.latent_cache
+              else (cache.k, cache.v, cache.k_scale, cache.v_scale))
+        rec = (cache.state, cache.conv)
+    if n_lead:
+        # Leading layers (dense FFN, parameter shapes of their own) run
+        # unrolled before the scan, under the same block, at cache
+        # indices 0 .. n_lead - 1.
+        with jax.named_scope("leading_layers"):
+            for i in range(n_lead):
+                x, kv, aux, _ = attention_layer(
+                    jax.tree.map(lambda a: a[i], params["leading_layers"]),
+                    x, kv, i)
+                aux_total = aux_total + aux
+    moe_counts = None
+    if cache is not None:
+        # The adapter pool (leading L axis) rides the scan as xs when given.
         xs = (layers, apool, lin_layers,
-              jnp.arange(cache.k.shape[0], dtype=jnp.int32))
-        init = (x, aux_total,
-                (cache.k, cache.v, cache.k_scale, cache.v_scale),
-                (cache.state, cache.conv))
+              n_lead + jnp.arange(cfg.num_periods, dtype=jnp.int32)
+              if n_lead else jnp.arange(cfg.num_periods, dtype=jnp.int32))
+        init = (x, aux_total, kv, rec)
         # `layers`: the scan itself (slices of the stacked weights, what
         # the compiler hoists out of the loop); each layer is a `block`.
         with jax.named_scope("layers"):
-            (x, aux_total, new_kv, new_rec), _ = jax.lax.scan(
+            (x, aux_total, new_kv, new_rec), moe_counts = jax.lax.scan(
                 scan_body, init, xs)
-        new_k, new_v, new_ks, new_vs = new_kv
         new_state, new_conv = new_rec
         new_index = cache.index if scatter_mode else cache.index + s
-        new_cache = KVCache(k=new_k, v=new_v, index=new_index,
-                            k_scale=new_ks, v_scale=new_vs,
-                            state=new_state, conv=new_conv)
+        if cfg.latent_cache:
+            new_cache = dataclasses.replace(
+                cache, index=new_index, latent=new_kv[0],
+                state=new_state, conv=new_conv)
+        else:
+            new_k, new_v, new_ks, new_vs = new_kv
+            new_cache = KVCache(k=new_k, v=new_v, index=new_index,
+                                k_scale=new_ks, v_scale=new_vs,
+                                state=new_state, conv=new_conv)
     else:
         from runbooks_tpu.parallel.sharding import _current_mesh
 
@@ -1254,7 +1534,7 @@ def forward(
 
             def pipe_block(layer, xx, mb_consts):
                 pos, seg, mk, bs = mb_consts
-                y, _, aux = block(cfg, layer, xx, pos, seg, mk, bs, None)
+                y, _, aux, _ = block(cfg, layer, xx, pos, seg, mk, bs, None)
                 return y, aux
 
             with jax.named_scope("layers"):
@@ -1265,24 +1545,27 @@ def forward(
                     n_microbatches=cfg.pipeline_microbatches or None)
         else:
             with jax.named_scope("layers"):
-                (x, aux_total, _, _), _ = jax.lax.scan(
+                (x, aux_total, _, _), moe_counts = jax.lax.scan(
                     scan_body, (x, aux_total, None, None),
                     (layers, apool, lin_layers, None))
         new_cache = None
 
     with jax.named_scope("head"):
         x = _norm(cfg, params["final_norm"], x)
+    extra = (aux_total,) if with_aux else ()
+    if with_moe_counts:
+        if moe_counts is None:
+            raise ValueError(
+                "with_moe_counts: this model has no sparse layer in its "
+                "period scan (or runs the pipeline path)")
+        extra += (moe_counts,)
     if return_activations:
         act_rules = _act_embed_rules(resolve_collective_matmul(cfg))
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
                                     rules=act_rules)
-        if with_aux:
-            return x, new_cache, aux_total
-        return x, new_cache
+        return (x, new_cache, *extra)
     logits = project_logits(cfg, params, x)
-    if with_aux:
-        return logits, new_cache, aux_total
-    return logits, new_cache
+    return (logits, new_cache, *extra)
 
 
 def project_logits(cfg: ModelConfig, params: Params,
@@ -1301,6 +1584,40 @@ def project_logits(cfg: ModelConfig, params: Params,
         seq = ("seq",) * (x.ndim - 2)
         logits = with_logical_constraint(logits, ("batch", *seq, None))
     return logits
+
+
+def _expert_mesh() -> bool:
+    """The active mesh shards experts (models/moe.py runs a shard of them
+    a device then)."""
+    from runbooks_tpu.parallel.sharding import _current_mesh
+
+    mesh = _current_mesh()
+    return mesh is not None and int(mesh.shape.get("expert", 1)) > 1
+
+
+def _check_latent_support(cfg: ModelConfig, adapters):
+    """What a model with latent-attention layers cannot do yet, by name
+    (docs/sparse-latent-models.md)."""
+    if adapters is not None:
+        raise NotImplementedError(
+            "adapter pools target the wq / wk / wv / wo of per-head "
+            "attention; latent attention has no pooled path")
+    from runbooks_tpu.parallel.sharding import _current_mesh
+
+    mesh = _current_mesh()
+    if mesh is None:
+        return
+    for axis, why in (
+            ("tensor", "the latent cache has no head axis to shard, and the "
+                       "absorbed decode's head split is not written"),
+            ("sequence", "ring attention takes no softmax scale or value "
+                         "width of the caller's"),
+            ("stage", "the pipeline's stages split one homogeneous stack, "
+                      "and leading layers are not part of it")):
+        if int(mesh.shape.get(axis, 1)) > 1:
+            raise NotImplementedError(
+                f"a {axis} mesh axis > 1 is not supported with "
+                f"latent-attention layers: {why}")
 
 
 def _check_recurrent_support(cfg: ModelConfig, segment_ids, adapters):
@@ -1408,7 +1725,7 @@ def loss_and_grads_1f1b(
 
     def blk_fn(layer, xx, mb_consts):
         pos, seg, mk, bs = mb_consts
-        y, _, aux = _block(cfg, layer, xx, pos, seg, mk, bs, None)
+        y, _, aux, _ = _block(cfg, layer, xx, pos, seg, mk, bs, None)
         return y, aux
 
     def head_loss_fn(nl, y, lc):

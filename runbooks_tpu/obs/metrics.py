@@ -129,6 +129,15 @@ CATALOG: Dict[str, str] = {
     # each chip holds under a serving mesh; equal unsharded)
     "serve_kv_pool_bytes": "gauge",
     "serve_kv_pool_bytes_per_device": "gauge",
+    # latent (MLA) cache leaf and sparse layers
+    # (docs/sparse-latent-models.md): the moe families exist for a sparse
+    # model only
+    "serve_latent_cache_bytes": "gauge",
+    "serve_moe_assignments_total": "counter",
+    "serve_moe_expert_tokens_total": "counter",
+    "serve_moe_expert_hits_total": "counter",
+    "serve_moe_expert_calls_total": "counter",
+    "serve_moe_layer_peak_assignments_total": "counter",
     "serve_prefix_lookups_total": "counter",
     "serve_prefix_hits_total": "counter",
     # Paged KV pool (serve/paging.py, docs/paged-kv.md): exported only

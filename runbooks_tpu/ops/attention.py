@@ -97,7 +97,7 @@ def _grouped_heads_axes(num_kv_heads: int):
 def dot_product_attention(
     q: jax.Array,                   # [b, q_len, num_heads, head_dim]
     k: jax.Array,                   # [b, kv_len, num_kv_heads, head_dim]
-    v: jax.Array,                   # [b, kv_len, num_kv_heads, head_dim]
+    v: jax.Array,                   # [b, kv_len, num_kv_heads, v_dim]
     mask: Optional[jax.Array] = None,       # [b, 1|h, q_len, kv_len] bool
     bias: Optional[jax.Array] = None,       # [b|1, h, q_len, kv_len] additive
     scale: Optional[float] = None,
@@ -143,4 +143,6 @@ def dot_product_attention(
         preferred_element_type=jnp.float32,
     )
     out = with_logical_constraint(out, axes)
-    return out.reshape(b, q_len, num_heads, head_dim).astype(q.dtype)
+    # At the VALUE width: keys and values may differ in it (latent
+    # attention: 192-wide keys, 128-wide values).
+    return out.reshape(b, q_len, num_heads, v.shape[-1]).astype(q.dtype)

@@ -266,6 +266,10 @@ def flash_fwd_qside(q, q_pos, q_seg, block_q):
 def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
                block_q, block_k, out_dtype=None, qside=None):
     b, sq, h, d = q.shape
+    # Values may be narrower or wider than keys (latent attention expanded:
+    # 192-wide q and k, 128-wide v): the accumulator and the output take
+    # the value width.
+    dv = v.shape[-1]
     sk = k.shape[1]
     kv_h = k.shape[2]
     n_rep = h // kv_h
@@ -323,27 +327,27 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
                 pl.BlockSpec((1, SUBLANES, block_k), krow_map),   # kv_seg
                 pl.BlockSpec((1, 1, block_q, d), q_map),          # q
                 pl.BlockSpec((1, 1, block_k, d), kv_map),         # k
-                pl.BlockSpec((1, 1, block_k, d), kv_map),         # v
+                pl.BlockSpec((1, 1, block_k, dv), kv_map),        # v
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, block_q, d), q_map),
+                pl.BlockSpec((1, 1, block_q, dv), q_map),
                 pl.BlockSpec((1, 1, block_q, LANES), q_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq_p, d), out_dtype or q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq_p, dv), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_p, LANES), jnp.float32),
         ],
         interpret=_interpret(),
     )(lo, hi, q_pos_l, _bcast_sublanes(kv_pos_p),
       q_seg_l, _bcast_sublanes(kv_seg_p), qT, kT, vT)
 
-    out = jnp.swapaxes(out[:, :, :sq], 1, 2)          # [b, sq, h, d]
+    out = jnp.swapaxes(out[:, :, :sq], 1, 2)          # [b, sq, h, dv]
     return out, lse[:, :, :sq, 0]
 
 
@@ -591,8 +595,17 @@ def _flash_core(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse,
     return out
 
 
+class UnequalWidthsBackward(NotImplementedError):
+    """The backward kernels are written for keys and values of one width."""
+
+
 def _vjp_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse,
              causal, scale, block_q, block_k, block_skip):
+    if v.shape[-1] != q.shape[-1]:
+        raise UnequalWidthsBackward(
+            f"flash attention's backward takes q, k and v of one width; got "
+            f"{q.shape[-1]} and {v.shape[-1]} (the forward alone takes a "
+            "value width of its own)")
     return out, (q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse)
 
 

@@ -9,23 +9,53 @@ is position-shift-friendly for KV-cache decoding and sequence-parallel shards
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
-def rope_sin_cos(positions: jax.Array, head_dim: int, theta: float = 10000.0):
+def yarn_inv_freq(head_dim: int, theta: float, yarn: tuple) -> np.ndarray:
+    """YaRN's blended inverse frequencies [head_dim//2] (float32, a
+    constant of the program): pairs that turn more than beta_fast times
+    inside the original context keep theta's frequency, pairs that turn
+    fewer than beta_slow times take it divided by factor, and a linear
+    ramp over the pair index joins the two. yarn = (factor, original max
+    position, beta_fast, beta_slow, ...)."""
+    factor, original, beta_fast, beta_slow = yarn[:4]
+    half = head_dim // 2
+    plain = 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
+
+    def pair_index(turns):   # the pair that turns `turns` times
+        return (head_dim * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_index(beta_fast)), 0)
+    high = min(math.ceil(pair_index(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def rope_sin_cos(positions: jax.Array, head_dim: int, theta: float = 10000.0,
+                 yarn: tuple = ()):
     """positions [...,] int32 -> (sin, cos) each [..., head_dim//2] float32."""
     half = head_dim // 2
-    freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn:
+        freq = jnp.asarray(yarn_inv_freq(head_dim, theta, yarn))
+    else:
+        freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                / half))
     angles = positions.astype(jnp.float32)[..., None] * freq  # [..., half]
     return jnp.sin(angles), jnp.cos(angles)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+               yarn: tuple = ()) -> jax.Array:
     """Apply RoPE. x: [batch, seq, heads, head_dim]; positions: [batch, seq]."""
     dtype = x.dtype
     half = x.shape[-1] // 2
-    sin, cos = rope_sin_cos(positions, x.shape[-1], theta)  # [b, s, half]
+    sin, cos = rope_sin_cos(positions, x.shape[-1], theta, yarn)  # [b, s, half]
     sin = sin[:, :, None, :]  # broadcast over heads
     cos = cos[:, :, None, :]
     x = x.astype(jnp.float32)

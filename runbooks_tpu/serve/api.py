@@ -674,6 +674,46 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                                 "linear-attention layers, all slots "
                                 "(0 without such layers); apart from "
                                 "serve_kv_pool_bytes.")
+        reg.set_gauge("serve_latent_cache_bytes",
+                      occ.get("latent_cache_bytes", 0),
+                      help_text="The latent (MLA) cache leaf, all slots "
+                                "(0 for per-head K/V): the part of "
+                                "serve_kv_pool_bytes with no head axis.")
+        moe = eng.moe_stats()
+        if moe is not None:
+            # Sparse layers (models/moe.py, docs/sparse-latent-models.md):
+            # the engine sums what its programs return; mirrored here.
+            here = sum(moe["expert_tokens"])
+            for held, n in (("here", here), ("elsewhere", moe["elsewhere"])):
+                reg.set_counter(
+                    "serve_moe_assignments_total", n, held=held,
+                    help_text="(token, expert) assignments of real tokens, "
+                              "by whether this process holds the expert.")
+            for i, n in enumerate(moe["expert_tokens"]):
+                reg.set_counter(
+                    "serve_moe_expert_tokens_total", n,
+                    expert=str(moe["first_expert"] + i),
+                    help_text="Assignments by held expert, summed over "
+                              "the sparse layers.")
+            reg.set_counter(
+                "serve_moe_layer_peak_assignments_total", moe["peak"],
+                help_text="Sum over dispatches and sparse layers of the "
+                          "assignments of the most loaded held expert; "
+                          "over serve_moe_assignments_total{held=here} / "
+                          "experts held it is the load imbalance a "
+                          "dispatch sees (max over mean).")
+            for prog in moe["hits"]:
+                reg.set_counter(
+                    "serve_moe_expert_hits_total", moe["hits"][prog],
+                    program=prog,
+                    help_text="(layer, expert) pairs that got at least "
+                              "one token in a forward, by program: the "
+                              "expert weights a forward had to read.")
+                reg.set_counter(
+                    "serve_moe_expert_calls_total", moe["calls"][prog],
+                    program=prog,
+                    help_text="(layer, expert) pairs offered, a forward, "
+                              "by program.")
         reg.set_counter("serve_prefix_lookups_total", eng.prefix_lookups,
                         help_text="Admissions that checked the shared-"
                                   "prefix cache.")

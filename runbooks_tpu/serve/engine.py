@@ -28,6 +28,11 @@ inference is in-framework and TPU-shaped:
   a fixed-size state a slot beside the K/V rows. It has no trash slot, so
   the programs mask, freeze and reset it themselves: the invariant is
   written out in make_prefill_fn (docs/hybrid-models.md).
+- A model whose attention layers are latent (MLA) caches one leaf
+  `latent` [layers, slots, cache_len, width] with no head axis in place of
+  K/V: same slots, trash slot, splice and views; a sparse model's programs
+  also return the experts' assignment counts, which ride the pull a
+  dispatch makes anyway (docs/sparse-latent-models.md).
 - Sampling is jitted with per-slot temperature/top_k/top_p so mixed request
   parameters batch together.
 - Quantized fast path: params may be weight-only int8/int4
@@ -266,6 +271,8 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
     sampled token; all-True rows are the identity, so unconstrained
     requests ride the same program (serve/grammar.py)."""
 
+    sparse = bool(cfg.moe_num_experts)
+
     def prefill_fn(params, pool, tokens, positions, slots,
                    last_pos, rng, temps, top_ks, top_ps,
                    pk=None, pv=None, apool=None, aslots=None,
@@ -332,17 +339,18 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
                 v=cache1.v.at[:, :, :plen].set(
                     pv[:, None].astype(cfg.activation_dtype)))
         adapters = None if apool is None else (apool, aslots)
-        # Rule (b): padding is parked at the trash slot, cache_len - 1.
+        # Rule (b): padding is parked at the trash slot, cache_len - 1. A
+        # sparse FFN routes such a token to no expert.
         token_mask = (positions < cache_len - 1
-                      if cfg.has_recurrent_state else None)
+                      if cfg.has_recurrent_state or sparse else None)
         # The head runs on each row's LAST prompt position only: the
         # sampled token's logits are the same numbers, and the
         # [rows, bucket, vocab] float32 tensor (6.6 GB at 16 384 tokens of
         # a 100 352 vocabulary) is never made.
-        acts, cache1 = forward(cfg, params, tokens,
-                               positions=positions, cache=cache1,
-                               adapters=adapters, token_mask=token_mask,
-                               return_activations=True)
+        acts, cache1, *moe = forward(
+            cfg, params, tokens, positions=positions, cache=cache1,
+            adapters=adapters, token_mask=token_mask,
+            return_activations=True, with_moe_counts=sparse)
         last_acts = jnp.take_along_axis(
             acts, last_pos[:, None, None], axis=1)[:, 0]
         last_logits = project_logits(cfg, params, last_acts)
@@ -367,6 +375,12 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
                         new_ks, rows_ks[:, r:r + 1], slots[r], axis=1)
                     new_vs = jax.lax.dynamic_update_slice_in_dim(
                         new_vs, rows_vs[:, r:r + 1], slots[r], axis=1)
+            new_latent = pool.latent
+            if new_latent is not None:
+                for r in range(rows - 1, -1, -1):
+                    new_latent = jax.lax.dynamic_update_slice_in_dim(
+                        new_latent, cache1.latent[:, r:r + 1], slots[r],
+                        axis=1)
             new_state, new_conv = pool.state, pool.conv
             if new_state is not None:
                 with jax.named_scope("state_splice"):    # rule (a)
@@ -382,10 +396,20 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
                        gmask=gmask)
         new_pool = KVCache(k=new_k, v=new_v, index=pool.index,
                            k_scale=new_ks, v_scale=new_vs,
-                           state=new_state, conv=new_conv)
-        return first, new_pool, rng
+                           state=new_state, conv=new_conv,
+                           latent=new_latent)
+        # A sparse model's programs return one thing more: (counts, hits).
+        return (first, new_pool, rng, *map(dispatch_stats, moe))
 
     return prefill_fn
+
+
+def dispatch_stats(counts):
+    """(counts, hits) of one forward of a sparse model: counts [sparse
+    layers, experts held + 1] as forward(with_moe_counts=True) gives them,
+    hits the (layer, expert) pairs that got at least one token — whose
+    weights this forward had to read."""
+    return counts, jnp.sum(counts[:, :-1] > 0, dtype=jnp.int32)
 
 
 def make_prefix_build_fn(cfg: ModelConfig, cache_len: int):
@@ -418,6 +442,8 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
     chunk=1 step-at-a-time would. rng advances functionally (successor
     key returned) — no eager split on the host per chunk."""
 
+    sparse = bool(cfg.moe_num_experts)
+
     def decode_fn(params, cache, tokens, positions, rng,
                   temperature, top_k, top_p, eos_ids, remaining, active,
                   apool=None, aslots=None, gmask=None):
@@ -435,15 +461,18 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
             p = jnp.where(alive, pos, pad_slot)
             # Rule (c) of make_prefill_fn's invariant: a parked row's
             # recurrent state does not move.
-            logits, cache = forward(
+            logits, cache, *moe = forward(
                 cfg, params, tok[:, None], positions=p[:, None],
                 cache=cache, cache_view=view, adapters=adapters,
-                token_mask=(alive[:, None] if cfg.has_recurrent_state
-                            else None))
+                token_mask=(alive[:, None]
+                            if cfg.has_recurrent_state or sparse else None),
+                with_moe_counts=sparse)
             nxt = sample(logits[:, -1], key, temperature, top_k, top_p,
                          gmask=gmask)
             nxt = jnp.where(alive, nxt, tok)
-            out = (nxt, alive)
+            # Every step reads the weights of the experts it hits: the
+            # steps' counts and hits add up.
+            out = (nxt, alive, *map(dispatch_stats, moe))
             emitted = emitted + alive
             pos = pos + alive
             hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
@@ -453,8 +482,9 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
 
         init = (cache, tokens, positions, active,
                 jnp.zeros_like(remaining))
-        (cache, *_), (toks, valid) = jax.lax.scan(body, init, keys)
-        return toks, valid, cache, rng
+        (cache, *_), (toks, valid, *moe) = jax.lax.scan(body, init, keys)
+        return (toks, valid, cache, rng,
+                *jax.tree.map(lambda a: a.sum(axis=0), moe))
 
     return decode_fn
 
@@ -576,6 +606,9 @@ class InferenceEngine:
     # layers) lives beside the dense slot pool's K/V rows; a page table
     # has one kind of page (serve/paging.py flips this).
     _supports_recurrent_state = True
+    # A latent cache (MLA) has no head axis: pages are laid out and
+    # sharded by KV head (serve/paging.py flips this too).
+    _supports_latent_cache = True
 
     def __init__(self, cfg: ModelConfig, params: Params, *, seed: int = 0,
                  mesh=None, tokenizer=None, **options):
@@ -644,6 +677,34 @@ class InferenceEngine:
                 "a page table has one kind of page and the radix tree "
                 "shares K/V pages only; the state after a shared prefix "
                 "would have to be snapshotted with them")
+        # What is only sound, or only written, for keys and values a head
+        # (docs/sparse-latent-models.md): each refused by its mechanism.
+        if options.speculative != "off":
+            self._refuse_latent(
+                "speculative decoding",
+                "the verify forward's [slots, K+1] queries take neither of "
+                "the two attention paths a test holds (absorbed at one "
+                "query a row, expanded over a prefill bucket)")
+        if options.adapter_pool > 0:
+            self._refuse_latent(
+                "an adapter pool",
+                "pooled LoRA lanes target the wq / wk / wv / wo of "
+                "per-head attention")
+        if not self._supports_latent_cache:
+            self._refuse_latent(
+                "kv_paging: paged",
+                "pages are [page, kv_heads, head_dim] and shard over KV "
+                "heads; a latent page has no head axis")
+        if self.quantize_kv:
+            self._refuse_latent(
+                "quantize_kv",
+                "the int8 pool keeps one scale a KV head, and a latent has "
+                "no head axis")
+        if mesh is not None and int(mesh.shape.get("tensor", 1)) > 1:
+            self._refuse_latent(
+                "a tensor mesh axis > 1",
+                "the latent cache has no head axis to shard, and the "
+                "absorbed decode's head split is not written")
         if mesh is not None:
             import contextlib
 
@@ -738,6 +799,18 @@ class InferenceEngine:
             self._grammar_cache = GrammarCache(
                 self._token_vocab, cfg.vocab_size,
                 capacity=options.grammar_cache_size)
+        # Sparse layers (models/moe.py): assignments by held expert (and
+        # last, those routed elsewhere) summed over layers, and by program
+        # the (layer, expert) pairs hit and offered. Fed by the counts the
+        # programs return; /metrics reads moe_stats().
+        self._moe_counts = np.zeros(
+            (cfg.moe_experts_here + 1) if cfg.moe_num_experts else 0,
+            np.int64)
+        self._moe_hits = {"prefill": [0, 0], "decode": [0, 0]}
+        # Sum over dispatches and layers of the most loaded held expert's
+        # assignments: against the mean (here / held) it is the imbalance
+        # a dispatch sees.
+        self._moe_peak = 0
         self.deadline_expired = 0   # observability/tests
         self.preemptions = 0          # slots preempted (observability)
         self.preempted_resumed = 0    # preempted requests re-admitted
@@ -924,8 +997,18 @@ class InferenceEngine:
                             state=put(cache.state, (None, "batch",
                                                     "act_heads", None, None)),
                             conv=put(cache.conv,
-                                     (None, "batch", None, None)))
+                                     (None, "batch", None, None)),
+                            latent=put(cache.latent,
+                                       (None, "batch", None, None)))
         return cache
+
+    def _refuse_latent(self, feature: str, why: str) -> None:
+        """Refuse, for a model with latent-attention layers, a feature
+        that is written for keys and values a head."""
+        if self.cfg.latent_cache:
+            raise ValueError(
+                f"{feature} is not supported for a model with latent "
+                f"(MLA) attention layers: {why}")
 
     def _refuse_recurrent(self, feature: str, why: str) -> None:
         """Refuse, for a model with recurrent (linear-attention) layers, a
@@ -1117,7 +1200,7 @@ class InferenceEngine:
                           **self._grammar_warm_kwargs(
                               (r, self.cfg.vocab_size))}
                     with self._mesh_ctx():
-                        _, self.cache, _ = run.program(
+                        _, self.cache, *_ = run.program(
                             "prefill", f"b{bucket}r{r}", self._prefill,
                             self.params, self.cache, *args, **kw)
                     n_prefill += 1
@@ -1137,7 +1220,7 @@ class InferenceEngine:
                         jnp.zeros(self.max_slots, jnp.int32),
                         jnp.zeros(self.max_slots, bool))
                 with self._mesh_ctx():
-                    _, _, self.cache, _ = run.program(
+                    _, _, self.cache, *_ = run.program(
                         f"decode_v{view}", f"v{view}",
                         self._decode_for(view), self.params, self.cache,
                         *args, **akw)
@@ -1235,6 +1318,11 @@ class InferenceEngine:
             "warm_prefix)",
             "a shared prefix splices K/V only; the recurrent state after "
             "the prefix would have to be stored and restored with it")
+        self._refuse_latent(
+            "prefix registration (register_prefix, auto_prefix_chat, "
+            "warm_prefix)",
+            "a shared prefix is stored and spliced as K/V a head; the "
+            "latent rows have no such path")
 
     def _prefix_len_for(self, n: int, quantize: bool = False) -> int:
         """Usable prefix length for an n-token prompt. Explicit
@@ -1528,7 +1616,8 @@ class InferenceEngine:
         # under a serving mesh each chip holds only its kv-head shard
         # (shard_local_nbytes reads the sharding metadata, no sync).
         arrays = [a for a in (self.cache.k, self.cache.v,
-                              self.cache.k_scale, self.cache.v_scale)
+                              self.cache.k_scale, self.cache.v_scale,
+                              self.cache.latent)
                   if a is not None]
         # Apart from the K/V pool: the recurrent state and conv tails of
         # linear-attention layers, fixed a slot whatever its tokens (0
@@ -1544,6 +1633,9 @@ class InferenceEngine:
                     sum(obs_device.shard_local_nbytes(a) for a in arrays),
                 "recurrent_state_bytes":
                     sum(int(a.nbytes) for a in recurrent),
+                # The part of kv_pool_bytes that is a latent (MLA) leaf.
+                "latent_cache_bytes": (0 if self.cache.latent is None
+                                       else int(self.cache.latent.nbytes)),
                 "occupancy_ratio": (tokens / capacity) if capacity else 0.0}
 
     def memory_groups(self) -> dict:
@@ -1561,6 +1653,10 @@ class InferenceEngine:
             groups["kv_cache"] = dataclasses.replace(
                 self.cache, state=None, conv=None)
             groups["recurrent_state"] = (self.cache.state, self.cache.conv)
+        if self.cfg.latent_cache:
+            groups["kv_cache"] = dataclasses.replace(
+                groups["kv_cache"], latent=None)
+            groups["latent_cache"] = self.cache.latent
         if self.adapters is not None:
             groups["adapter_pool"] = self.adapters.tree
         return groups
@@ -1749,13 +1845,13 @@ class InferenceEngine:
                 # serving live traffic must not be the one evicted.
                 pk, pv = self._prefix_cache[pkey]
                 self._prefix_cache_hit(pkey)
-                first, self.cache, self.rng = self._prefill_prefix(
+                first, self.cache, self.rng, *moe = self._prefill_prefix(
                     self.params, self.cache, pk, pv, *args, **akw)
                 self.prefix_tokens_reused += plen * n
             else:
-                first, self.cache, self.rng = self._prefill(
+                first, self.cache, self.rng, *moe = self._prefill(
                     self.params, self.cache, *args, **akw)
-            return first
+            return (first, *moe)
 
         self._prefill_dispatch(bucket, rows, plen, group, operands, program)
 
@@ -1764,8 +1860,9 @@ class InferenceEngine:
         """One batched prefill under its spans, shared with the paged
         engine: `operands()` builds the host arrays and places them
         (-> args, kwargs, the host `positions` [rows, bucket]),
-        `program(args, kwargs)` makes the jitted call and returns the
-        first tokens still on the device."""
+        `program(args, kwargs)` makes the jitted call and returns a tuple:
+        the first tokens still on the device and, for a sparse model, the
+        dispatch's (counts, hits)."""
         # Request ids only materialize when tracing is on (same rule as
         # the decode span's active count: no per-dispatch list builds on
         # the hot path for a disabled tracer).
@@ -1781,12 +1878,13 @@ class InferenceEngine:
             t_dispatch = time.perf_counter()
             with self._mesh_ctx():
                 with fine("prefill.dispatch"):
-                    first = program(args, kwargs)
+                    first, *moe = program(args, kwargs)
                     # The call has returned and the device is at work.
                     self._count_flash_blocks(bucket, positions)
                 with fine("prefill.sync"):
                     # rbt-check: ignore[device-sync] prefill dispatch boundary — the first token must reach the host to stream
                     first = np.asarray(first)
+                    self._count_moe("prefill", moe)
             # Labeled by (bucket, rows): the two row shapes are different
             # compiled programs with ~rows-proportional FLOPs, and the
             # roofline join (/debug/programs) divides per-program FLOPs by
@@ -1829,6 +1927,33 @@ class InferenceEngine:
             help_text="(query block, kv block) pairs of the flash "
                       "forward's grid a head and layer in prefill, by "
                       "bucket.")
+
+    def _count_moe(self, program: str, moe: list, steps: int = 1) -> None:
+        """Add one dispatch's (counts, hits) of a sparse model to the
+        engine's sums ([] for a dense one). Called right after the pull the
+        dispatch makes anyway: the arrays came back with it."""
+        for counts, hits in moe:
+            # rbt-check: ignore[device-sync] same dispatch boundary — the counts ride the pull above
+            counts = np.asarray(counts)
+            self._moe_counts += counts.sum(axis=0)
+            self._moe_peak += int(counts[:, :-1].max(axis=1).sum())
+            # rbt-check: ignore[device-sync] same boundary
+            self._moe_hits[program][0] += int(hits)
+            self._moe_hits[program][1] += steps * counts[:, :-1].size
+
+    def moe_stats(self) -> Optional[dict]:
+        """Sparse-layer counters for /metrics (None for a dense model):
+        assignments by held expert summed over layers, those routed to
+        experts held elsewhere, and by program the (layer, expert) pairs
+        that got a token against the pairs offered, a forward."""
+        if not self.cfg.moe_num_experts:
+            return None
+        return {"first_expert": self.cfg.moe_experts_first,
+                "expert_tokens": self._moe_counts[:-1].tolist(),
+                "elsewhere": int(self._moe_counts[-1]),
+                "peak": self._moe_peak,
+                "hits": {k: v[0] for k, v in self._moe_hits.items()},
+                "calls": {k: v[1] for k, v in self._moe_hits.items()}}
 
     def _activate_slot(self, slot: int, req: Request,
                        first_tok: int) -> None:
@@ -2330,13 +2455,15 @@ class InferenceEngine:
                 kwargs = {**self._adapter_kwargs(),
                           **self._grammar_decode_kwargs()}
             with fine("decode.dispatch"):
-                toks, valid, self.cache, self.rng = self._decode_for(key)(
-                    self.params, self.cache, *operands, **kwargs)
+                toks, valid, self.cache, self.rng, *moe = \
+                    self._decode_for(key)(
+                        self.params, self.cache, *operands, **kwargs)
             with fine("decode.sync"):
                 # rbt-check: ignore[device-sync] decode-chunk dispatch boundary: one sync per chunk, not per token
                 toks = np.asarray(toks)          # [chunk, slots]
                 # rbt-check: ignore[device-sync] same boundary — valid rides the same chunk sync
                 valid = np.asarray(valid)        # [chunk, slots] bool
+                self._count_moe("decode", moe, steps=self.decode_chunk)
             obs_metrics.REGISTRY.observe(
                 "serve_decode_dispatch_seconds",
                 time.perf_counter() - t_dispatch, view=str(label),

@@ -1146,6 +1146,7 @@ class PagedInferenceEngine(InferenceEngine):
 
     kv_paging = "paged"
     _supports_recurrent_state = False
+    _supports_latent_cache = False
 
     def __init__(self, cfg: ModelConfig, params: Params, *, mesh=None,
                  **kwargs):
@@ -1806,7 +1807,7 @@ class PagedInferenceEngine(InferenceEngine):
         def program(args, kwargs):
             first, self.cache, self.rng = self._paged_prefill(
                 self.params, self.cache, *args, **kwargs)
-            return first
+            return (first,)
 
         self._prefill_dispatch(bucket, rows, ppb * ps, group, operands,
                                program)

@@ -610,11 +610,28 @@ def test_hybrid_programs_are_audited_and_the_rest_of_the_census_stands():
     base = load_program_baseline(os.path.join(
         _repo_root(), "config", "program_baseline.json"))
     old = [p for p in base["programs"]
-           if not p["name"].startswith("hybrid_")]
-    assert len(old) == 31 and len(base["programs"]) == 33
+           if not p["name"].startswith(("hybrid_", "sparse_latent_"))]
+    assert len(old) == 31 and len(base["programs"]) == 35
     assert hashlib.sha256(json.dumps(old, sort_keys=True).encode()
                           ).hexdigest() == (
         "e04d823ec805a6e339114d31d41529ec8ada425ff7e3c2d51b4302317a6db011")
+
+
+def test_sparse_latent_programs_are_audited():
+    """PR 30's two programs are traced with the latent leaf in place of
+    k / v, a leading-layer stack and a share of the experts; the counts
+    are among their results."""
+    from runbooks_tpu.analysis.program import AuditSettings, _engine_specs
+
+    specs = {s["name"]: s for s in _engine_specs(AuditSettings())}
+    for name in ("sparse_latent_prefill", "sparse_latent_decode"):
+        params, pool = specs[name]["args"][:2]
+        assert pool.k.shape[0] == 0 and pool.latent.shape[0] == 3
+        assert pool.latent.shape[-1] == 80 and pool.state is None
+        assert params["layers"]["moe"]["wi_gate"].shape[:2] == (2, 8)
+        assert params["layers"]["moe"]["router"].shape == (2, 128, 16)
+        assert params["leading_layers"]["mlp"]["wi_gate"].shape[0] == 1
+    assert specs["prefill"]["args"][1].latent is None
 
 
 def test_program_baseline_roundtrip(tmp_path):

@@ -107,7 +107,7 @@ def test_mixtral_parity():
         name="mixtral-test", vocab_size=128, hidden_size=64,
         intermediate_size=96, num_layers=2, num_heads=4, num_kv_heads=2,
         head_dim=16, max_seq_len=64, dtype="float32",
-        moe_num_experts=4, moe_top_k=2, moe_capacity_factor=8.0)
+        moe_num_experts=4, moe_top_k=2)
     tokens = np.random.default_rng(2).integers(0, 128, (2, 12))
     compare(cfg, hf, tokens)
 

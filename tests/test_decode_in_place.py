@@ -26,6 +26,7 @@ from runbooks_tpu.analysis.loop_copies import (
     parse,
     pool_sized_loop_ops,
 )
+from runbooks_tpu.models.config import get_config
 from runbooks_tpu.models.transformer import KVCache, init_params
 from runbooks_tpu.serve.engine import make_decode_fn
 from tests.hybrid_fixture import tiny_config
@@ -45,6 +46,11 @@ MODELS = {
     "gqa": (lambda: toy("llama2-7b", num_kv_heads=2), False),
     "hybrid": (tiny_config, False),
     "gqa-int8-pool": (lambda: toy("llama2-7b", num_kv_heads=2), True),
+    # Latent attention's one head-less leaf, a leading dense layer before
+    # the scan, sparse FFNs of which a share is held.
+    "latent-sparse": (lambda: get_config(
+        "debug-sparse-latent", dtype="bfloat16", param_dtype="bfloat16",
+        moe_experts_held=8), False),
 }
 
 
@@ -101,7 +107,8 @@ def test_decode_loops_hold_no_pool_sized_operation(one_chip, model):
     # the values they carry.
     loops = [i for c in comps.values() for i in c if i.opcode == "while"]
     assert len(loops) >= 2
-    shape = "[" + ",".join(map(str, pool.k.shape)) + "]"
+    leaf = pool.k if pool.latent is None else pool.latent
+    shape = "[" + ",".join(map(str, leaf.shape)) + "]"
     assert any(shape in i.line for i in loops), shape
     assert pool_sized_loop_ops(text, pool) == []
 

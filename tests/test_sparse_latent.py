@@ -1,0 +1,630 @@
+"""Latent attention (MLA), the dropless expert layer held as a share, and
+leading dense layers, against the plain reference
+(benchmark/reference/sarvam_mla.py; docs/sparse-latent-models.md).
+
+Seeded random weights at toy widths on the CPU; LOGITS are compared, never
+sampled tokens. Activations run in float32 under "highest" matmul
+precision, weights are the bfloat16 the recipe stores, so what separates
+program and reference is the order of float32 sums: every tolerance below
+is 2e-4 absolute on logits of order 1 for that reason, unless it says
+otherwise.
+"""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from runbooks_tpu.models.config import get_config
+from runbooks_tpu.models.moe import moe_block, route
+from runbooks_tpu.models.transformer import KVCache, forward, init_params
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOL = 2e-4
+
+
+def load_reference():
+    path = os.path.join(ROOT, "benchmark", "reference", "sarvam_mla.py")
+    spec = importlib.util.spec_from_file_location("ref_sarvam_mla", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = load_reference()
+
+
+@pytest.fixture(autouse=True)
+def exact_matmuls():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def toy(**over):
+    kw = dict(dtype="float32", param_dtype="bfloat16")
+    kw.update(over)
+    return get_config("debug-sparse-latent", **kw)
+
+
+def as_run_of(cfg) -> dict:
+    """The reference's description of a ModelConfig of this family."""
+    factor, original, fast, slow, mscale, mscale_all = cfg.rope_yarn
+    return {
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "vocab_size": cfg.vocab_size, "rms_norm_eps": cfg.norm_eps,
+        "num_attention_heads": cfg.num_heads,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim,
+        "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "kv_lora_rank": cfg.kv_lora_rank,
+        "use_qk_norm": cfg.qk_norm,
+        "first_k_dense_replace": cfg.leading_dense_layers,
+        "num_hidden_layers": cfg.num_layers,
+        "num_experts_routed": cfg.moe_num_experts,
+        "num_experts": cfg.moe_experts_here,
+        "first_expert_held": cfg.moe_experts_first,
+        "num_experts_per_tok": cfg.moe_top_k,
+        "moe_intermediate_size": cfg.moe_width,
+        "num_shared_experts": cfg.moe_shared_experts,
+        "moe_router_enable_expert_bias": cfg.moe_router_bias,
+        "router_bias_std": 0.05,
+        "routed_scaling_factor": cfg.moe_routed_scale,
+        "rope_theta": cfg.rope_theta,
+        "rope_scaling": {
+            "type": "deepseek_yarn", "factor": factor,
+            "original_max_position_embeddings": original,
+            "beta_fast": fast, "beta_slow": slow, "mscale": mscale,
+            "mscale_all_dim": mscale_all}}
+
+
+def seeded(cfg, seed):
+    """init_params as the server makes them: under jit (an eager draw
+    rounds a few elements in 65 536 to the other bfloat16 neighbour)."""
+    return jax.jit(lambda key: init_params(cfg, key))(jax.random.key(seed))
+
+
+def tokens_for(cfg, n, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, cfg.vocab_size, n).astype(np.int32)
+
+
+def reference_logits(cfg, seed, toks):
+    w = ref.init_weights(as_run_of(cfg), seed)
+    return np.asarray(ref.logits_at(as_run_of(cfg), w, toks,
+                                    np.arange(len(toks))))
+
+
+# --------------------------------------------------------------------------
+# The seeded recipe, the preset, the config's checks
+# --------------------------------------------------------------------------
+
+def test_preset_holds_the_published_sizes():
+    cfg = get_config("sarvam-105b")
+    assert (cfg.num_layers, cfg.hidden_size, cfg.num_heads) == (32, 4096, 64)
+    assert (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+            cfg.kv_lora_rank, cfg.head_dim) == (128, 64, 128, 512, 576)
+    assert cfg.q_head_dim == 192 and cfg.latent_width == 576
+    assert (cfg.intermediate_size, cfg.moe_width, cfg.moe_num_experts,
+            cfg.moe_top_k, cfg.moe_shared_experts) == (16384, 2048, 128, 8, 1)
+    assert cfg.leading_dense_layers == 1 and cfg.vocab_size == 262144
+    assert cfg.moe_routed_scale == 2.5 and cfg.moe_router == "sigmoid"
+    assert cfg.rope_yarn == (40.0, 4096, 32.0, 1.0, 1.0, 1.0)
+    assert abs(cfg.yarn_attn_factor - 1.3689) < 1e-4
+    assert 105e9 < cfg.num_params < 107e9
+    cut = get_config("sarvam-105b", num_layers=6, moe_experts_held=32,
+                     vocab_size=65536)
+    # ISSUE 30's arithmetic: 296.0 M + 5 x 925.6 M + 536.9 M.
+    assert abs(cut.num_params - 5.461e9) < 2e6
+
+
+@pytest.mark.parametrize("field,value,text", [
+    ("moe_router", "tanh", "moe_router"),
+    ("moe_experts_held", 17, "not among"),
+    ("leading_dense_layers", 3, "leading"),
+    ("kv_lora_rank", 0, "latent_attention layers need"),
+    ("rope_yarn", (40.0, 4096), "rope_yarn is"),
+])
+def test_config_refuses(field, value, text):
+    with pytest.raises(ValueError, match=text):
+        toy(**{field: value})
+
+
+def test_seeded_weights_are_the_references_bit_for_bit():
+    cfg = toy(moe_experts_held=4, moe_experts_first=8)
+    p = seeded(cfg, 11)
+    w = ref.init_weights(as_run_of(cfg), 11)
+    pairs = {"embed": p["embed"], "head": p["head"],
+             "router": p["layers"]["moe"]["router"],
+             "router_bias": p["layers"]["moe"]["router_bias"],
+             "exp_gate": p["layers"]["moe"]["wi_gate"],
+             "exp_up": p["layers"]["moe"]["wi_up"],
+             "exp_down": p["layers"]["moe"]["wo"],
+             "shared_gate": p["layers"]["moe"]["shared"]["wi_gate"],
+             "shared_up": p["layers"]["moe"]["shared"]["wi_up"],
+             "shared_down": p["layers"]["moe"]["shared"]["wo"],
+             "lead_mlp_gate": p["leading_layers"]["mlp"]["wi_gate"],
+             "lead_mlp_up": p["leading_layers"]["mlp"]["wi_up"],
+             "lead_mlp_down": p["leading_layers"]["mlp"]["wo"]}
+    for pre, tree in (("", p["layers"]), ("lead_", p["leading_layers"])):
+        for name in ("wq", "w_kva", "w_kvb", "wo"):
+            pairs[pre + name] = tree["attn"][name]
+    assert set(pairs) == {n for n in w if "norm" not in n and "ln" not in n}
+    for name, leaf in pairs.items():
+        assert leaf.dtype == jnp.bfloat16 and w[name].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(leaf.astype(jnp.float32)),
+            np.asarray(w[name].astype(jnp.float32)), err_msg=name)
+    # The selection bias is not zero: leaving it out must be seen.
+    assert float(jnp.abs(p["layers"]["moe"]["router_bias"]
+                         .astype(jnp.float32)).mean()) > 0.01
+
+
+def test_other_presets_keep_their_seeded_weights():
+    """The new leaves take keys of their own: a dense preset's and the
+    softmax MoE's leaves are drawn as before."""
+    for name, over in (("debug", {}),
+                       ("debug", dict(moe_num_experts=4, moe_top_k=2))):
+        cfg = get_config(name, **over)
+        p = seeded(cfg, 3)
+        keys = jax.random.split(jax.random.key(3), 16)
+        # (Eager against jitted: equal to a float32 rounding.)
+        np.testing.assert_allclose(
+            np.asarray(p["layers"]["attn"]["wq"]),
+            np.asarray(jax.random.normal(
+                keys[2], p["layers"]["attn"]["wq"].shape)
+                * cfg.hidden_size ** -0.5), rtol=1e-6)
+
+
+# --------------------------------------------------------------------------
+# The forward pass against the reference
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("share", [(0, 0), (4, 8)], ids=["whole", "share"])
+def test_forward_matches_reference(share):
+    """Leading dense layer + sparse layers + latent attention, no cache
+    (expanded attention on both sides; grouped product against the
+    reference's expert-at-a-time loop)."""
+    held, first = share
+    cfg = toy(moe_experts_held=held, moe_experts_first=first)
+    toks = tokens_for(cfg, 40)
+    p = seeded(cfg, 5)
+    got, _ = forward(cfg, p, jnp.asarray(toks)[None])
+    want = reference_logits(cfg, 5, toks)
+    assert np.abs(want).max() > 0.5
+    np.testing.assert_allclose(np.asarray(got[0]), want, atol=TOL)
+
+
+def test_prefill_then_absorbed_decode_matches_reference():
+    """Two rows of different lengths prefilled in one padded call
+    (position-scatter mode, padding at the trash slot), then decoded a
+    token at a time through a view shorter than the pool: every logit the
+    absorbed path gives equals the reference's full expanded forward."""
+    cfg = toy(moe_experts_held=8)
+    p = seeded(cfg, 7)
+    seqs = [tokens_for(cfg, 30, 1), tokens_for(cfg, 19, 2)]
+    n_pre = [22, 11]
+    max_len, bucket, view = 48, 32, 40
+    cache = KVCache.create(cfg, 2, max_len, trash_slot=True)
+    assert cache.k.shape[0] == 0 and cache.latent.shape == (
+        3, 2, max_len + 1, cfg.latent_width)
+    toks = np.zeros((2, bucket), np.int32)
+    pos = np.full((2, bucket), max_len, np.int32)
+    for r, (s, n) in enumerate(zip(seqs, n_pre)):
+        toks[r, :n], pos[r, :n] = s[:n], np.arange(n)
+    logits, cache = forward(cfg, p, jnp.asarray(toks),
+                            positions=jnp.asarray(pos), cache=cache,
+                            token_mask=jnp.asarray(pos < max_len))
+    want = [reference_logits(cfg, 7, s) for s in seqs]
+    for r, n in enumerate(n_pre):
+        np.testing.assert_allclose(np.asarray(logits[r, :n]), want[r][:n],
+                                   atol=TOL)
+    step = jax.jit(lambda c, t, q: forward(
+        cfg, p, t, positions=q, cache=c, cache_view=view))
+    for i in range(8):
+        at = np.array([n + i for n in n_pre], np.int32)
+        t = np.array([[s[a]] for s, a in zip(seqs, at)], np.int32)
+        logits, cache = step(cache, jnp.asarray(t), jnp.asarray(at[:, None]))
+        for r in range(2):
+            np.testing.assert_allclose(np.asarray(logits[r, 0]),
+                                       want[r][at[r]], atol=TOL)
+
+
+def test_flash_prefill_expands_from_the_cache():
+    """The expanded cached path (the flash forward at key width 48, value
+    width 32, block ranges from the scattered positions) equals the
+    absorbed one a test above holds to the reference."""
+    base = toy(moe_experts_held=8, flash_block_q=16, flash_block_k=16)
+    p = init_params(base, jax.random.key(7))
+    s = tokens_for(base, 24, 3)
+    toks, pos = np.zeros((1, 32), np.int32), np.full((1, 32), 40, np.int32)
+    toks[0, :24], pos[0, :24] = s, np.arange(24)
+    out = {}
+    for impl in ("xla", "flash"):
+        cfg = dataclasses.replace(base, attention_impl=impl)
+        out[impl], _ = forward(
+            cfg, p, jnp.asarray(toks), positions=jnp.asarray(pos),
+            cache=KVCache.create(cfg, 1, 40, trash_slot=True),
+            token_mask=jnp.asarray(pos < 40))
+    np.testing.assert_allclose(np.asarray(out["flash"][0, :24]),
+                               np.asarray(out["xla"][0, :24]), atol=TOL)
+
+
+def test_chunked_decode_program_serves_the_references_best_token():
+    """make_prefill_fn + make_decode_fn (the chunk loop, a finished row
+    parked): each greedy token is the reference's best at its position, or
+    within TOL of it."""
+    from runbooks_tpu.serve.engine import make_decode_fn, make_prefill_fn
+
+    cfg = toy(moe_experts_held=8)
+    p = seeded(cfg, 9)
+    max_len, slots, chunk = 64, 2, 4
+    prompts = [tokens_for(cfg, 21, 4), tokens_for(cfg, 9, 5)]
+    pool = KVCache.create(cfg, slots, max_len, trash_slot=True)
+    toks = np.zeros((slots, 32), np.int32)
+    pos = np.full((slots, 32), max_len, np.int32)
+    for r, s in enumerate(prompts):
+        toks[r, :len(s)], pos[r, :len(s)] = s, np.arange(len(s))
+    zeros, ones = jnp.zeros(slots), jnp.ones(slots)
+    first, pool, rng, (counts, hits) = jax.jit(
+        make_prefill_fn(cfg, max_len + 1))(
+        p, pool, jnp.asarray(toks), jnp.asarray(pos),
+        jnp.arange(slots, dtype=jnp.int32),
+        jnp.asarray([len(s) - 1 for s in prompts], jnp.int32),
+        jax.random.key(0), zeros, zeros.astype(jnp.int32), ones)
+    # Real tokens only: 30 prompt tokens x top-4 x 2 sparse layers.
+    assert int(counts.sum()) == 30 * 4 * 2
+    assert counts.shape == (2, 9) and 0 < int(hits) <= 16
+    served = [[int(t)] for t in first]
+    decode = jax.jit(make_decode_fn(cfg, chunk, max_len, max_len, max_len))
+    remaining = jnp.asarray([9, 3], jnp.int32)   # row 1 stops mid-chunk
+    out, valid, pool, rng, (counts, hits) = decode(
+        p, pool, first, jnp.asarray([len(s) for s in prompts], jnp.int32),
+        rng, zeros, zeros.astype(jnp.int32), ones,
+        jnp.full(slots, -1, jnp.int32), remaining, jnp.ones(slots, bool))
+    out, valid = np.asarray(out), np.asarray(valid)
+    assert valid[:, 0].all() and valid[:, 1].tolist() == [True] * 3 + [False]
+    # A parked row is routed to no expert: 4 + 3 live tokens.
+    assert int(counts.sum()) == 7 * 4 * 2
+    for r in range(slots):
+        served[r] += [int(out[k, r]) for k in range(chunk) if valid[k, r]]
+        seq = np.concatenate([prompts[r], served[r]]).astype(np.int32)
+        logits = reference_logits(cfg, 9, seq)
+        rows = np.arange(len(prompts[r]) - 1, len(seq) - 1)
+        gap = logits[rows].max(-1) - logits[rows, served[r]]
+        assert gap.max() <= TOL, (r, gap)
+
+
+# --------------------------------------------------------------------------
+# The expert layer
+# --------------------------------------------------------------------------
+
+def layer_and_input(cfg, seed=0, tokens=24):
+    p = jax.tree.map(lambda a: a[0],
+                     seeded(cfg, seed)["layers"]["moe"])
+    x = jax.random.normal(jax.random.key(seed + 100),
+                          (2, tokens // 2, cfg.hidden_size), jnp.float32)
+    return p, x
+
+
+def reference_layer(cfg, p, x, first=0, held=None):
+    held = cfg.moe_num_experts if held is None else held
+    dm = ref.dims(dict(as_run_of(cfg), num_experts=held,
+                       first_expert_held=first))
+    lw = {"router": p["router"].astype(jnp.float32),
+          "router_bias": p["router_bias"].astype(jnp.float32),
+          "exp_gate": p["wi_gate"][first:first + held],
+          "exp_up": p["wi_up"][first:first + held],
+          "exp_down": p["wo"][first:first + held],
+          "shared_gate": p["shared"]["wi_gate"],
+          "shared_up": p["shared"]["wi_up"],
+          "shared_down": p["shared"]["wo"]}
+    y, _, _ = ref.sparse_ffn(dm, x.reshape(-1, x.shape[-1]), lw, ref.matmul)
+    return np.asarray(y).reshape(x.shape)
+
+
+def share_of(p, first, held):
+    return {**p, **{k: p[k][first:first + held]
+                    for k in ("wi_gate", "wi_up", "wo")}}
+
+
+def test_the_four_shares_add_up_to_the_whole_layer():
+    """held = 0, 4, 8, 12 of a 16-expert layer, the shared expert counted
+    once: their sum is the uncut reference's whole layer, and one share
+    is the reference given the same share."""
+    cfg = toy()
+    p, x = layer_and_input(cfg)
+    whole = reference_layer(cfg, p, x)
+    total, all_counts = 0.0, []
+    for i, first in enumerate((0, 4, 8, 12)):
+        y, _, counts = moe_block(cfg, share_of(p, first, 4), x, held=first,
+                                 shared=(i == 0))
+        total = total + np.asarray(y)
+        all_counts.append(np.asarray(counts))
+        if i == 1:
+            one = reference_layer(cfg, p, x, first, 4)
+            shared_only = reference_layer(cfg, p, x, 0, 0)
+            np.testing.assert_allclose(np.asarray(y), one - shared_only,
+                                       atol=TOL)
+    np.testing.assert_allclose(total, whole, atol=TOL)
+    # Every assignment is held by exactly one share.
+    n = x.shape[0] * x.shape[1] * cfg.moe_top_k
+    assert sum(int(c[:-1].sum()) for c in all_counts) == n
+    assert all(int(c.sum()) == n for c in all_counts)
+    # The whole layer in one call gives the same sum.
+    y, _, counts = moe_block(cfg, p, x)
+    np.testing.assert_allclose(np.asarray(y), whole, atol=TOL)
+    assert int(counts[-1]) == 0
+
+
+def test_dropless_a_token_does_not_depend_on_its_batch_mates():
+    """Routing that sends most tokens to one expert (what a capacity
+    would drop): a token's output alone equals its output in the batch."""
+    cfg = toy()
+    p, x = layer_and_input(cfg, tokens=64)
+    router = np.asarray(p["router"].astype(jnp.float32)) * 0.01
+    router[:, 3] = 0.0
+    p = {**p, "router": jnp.asarray(router),
+         "router_bias": p["router_bias"].at[3].set(1.0)}
+    y, _, counts = moe_block(cfg, p, x)
+    assert int(counts[3]) == 64          # every token chose expert 3
+    for b, s in ((0, 0), (1, 17)):
+        alone, _, _ = moe_block(cfg, p, x[b:b + 1, s:s + 1])
+        np.testing.assert_allclose(np.asarray(alone[0, 0]),
+                                   np.asarray(y[b, s]), atol=TOL)
+    np.testing.assert_allclose(np.asarray(y), reference_layer(cfg, p, x),
+                               atol=TOL)
+
+
+def test_bias_changes_the_choice_and_not_the_weights():
+    cfg = toy()
+    p, x = layer_and_input(cfg)
+    xt = x.reshape(-1, cfg.hidden_size)
+    scores, idx, gate = route(cfg, p, xt)
+    pushed = {**p, "router_bias": p["router_bias"].at[5].set(10.0)}
+    scores2, idx2, gate2 = route(cfg, pushed, xt)
+    np.testing.assert_array_equal(np.asarray(scores), np.asarray(scores2))
+    assert (np.asarray(idx2) == 5).any(axis=-1).all()
+    assert not (np.asarray(idx) == 5).any(axis=-1).all()
+    # The weights are the UNBIASED scores of the chosen, over their sum,
+    # times routed_scaling_factor, once.
+    chosen = np.take_along_axis(np.asarray(scores2), np.asarray(idx2), -1)
+    np.testing.assert_allclose(
+        np.asarray(gate2), 2.5 * chosen / chosen.sum(-1, keepdims=True),
+        rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(gate2).sum(-1), 2.5, rtol=1e-6)
+
+
+def test_routed_scaling_factor_is_applied_once():
+    cfg = toy(moe_shared_experts=0)
+    p, x = layer_and_input(cfg)
+    y, _, _ = moe_block(cfg, p, x)
+    y1, _, _ = moe_block(dataclasses.replace(cfg, moe_routed_scale=1.0), p, x)
+    np.testing.assert_allclose(np.asarray(y), 2.5 * np.asarray(y1),
+                               atol=TOL)
+
+
+def test_softmax_router_renormalises_the_chosen():
+    cfg = get_config("debug", moe_num_experts=4, moe_top_k=2,
+                     dtype="float32")
+    p = jax.tree.map(lambda a: a[0],
+                     init_params(cfg, jax.random.key(0))["layers"]["moe"])
+    xt = jax.random.normal(jax.random.key(1), (10, cfg.hidden_size))
+    scores, idx, gate = route(cfg, p, xt)
+    np.testing.assert_allclose(np.asarray(scores).sum(-1), 1.0, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(gate).sum(-1), 1.0, rtol=1e-5)
+    assert (np.asarray(idx)[:, 0]
+            == np.asarray(scores).argmax(-1)).all()
+
+
+def test_masked_tokens_are_routed_nowhere_and_long_inputs_are_chunked(
+        monkeypatch):
+    from runbooks_tpu.models import moe
+
+    cfg = toy(moe_experts_held=8)
+    p, x = layer_and_input(cfg, tokens=40)
+    p = share_of(p, 0, 8)
+    mask = jnp.arange(20)[None, :] < jnp.asarray([[13], [20]])
+    y, _, counts = moe_block(cfg, p, x, token_mask=mask)
+    assert int(counts.sum()) == 33 * cfg.moe_top_k
+    full, _, full_counts = moe_block(cfg, p, x)
+    real = np.asarray(mask)
+    np.testing.assert_allclose(np.asarray(y)[real], np.asarray(full)[real],
+                               atol=TOL)
+    monkeypatch.setattr(moe, "TOKEN_CHUNK", 16)     # 40 tokens: 3 chunks
+    chunked, _, chunk_counts = moe_block(cfg, p, x)
+    np.testing.assert_allclose(np.asarray(chunked), np.asarray(full),
+                               atol=TOL)
+    np.testing.assert_array_equal(np.asarray(chunk_counts),
+                                  np.asarray(full_counts))
+
+
+def test_grouped_matmul_on_the_whole_stack_is_the_layers_own():
+    """The serving path hands the product the whole [layers, experts, k, n]
+    stack and the layer's number; the layer's groups among zero-sized ones
+    give what the layer's own slice gives, rows past the groups apart."""
+    from runbooks_tpu.models.moe import _gmm_tiling, grouped_matmul
+
+    ks = jax.random.split(jax.random.key(0), 2)
+    lhs = jax.random.normal(ks[0], (24, 16), jnp.float32)
+    stack = jax.random.normal(ks[1], (3, 4, 16, 8), jnp.float32)
+    sizes = jnp.asarray([5, 0, 9, 4], jnp.int32)        # 6 rows in no group
+    for layer in range(3):
+        whole = grouped_matmul(lhs, stack, sizes, layer=jnp.int32(layer))
+        own = grouped_matmul(lhs, stack[layer], sizes)
+        np.testing.assert_allclose(np.asarray(whole[:18]),
+                                   np.asarray(own[:18]), atol=1e-5)
+        want = np.concatenate([
+            np.asarray(lhs[a:b] @ stack[layer, e])
+            for e, (a, b) in enumerate(((0, 5), (5, 5), (5, 14), (14, 18)))])
+        np.testing.assert_allclose(np.asarray(own[:18]), want, atol=1e-5)
+    # The Pallas kernel's row tile must divide the rows; widths in lanes.
+    assert _gmm_tiling(64, 4096, 2048) == (64, 1024, 1024)
+    assert _gmm_tiling(16384, 2048, 4096) == (512, 1024, 1024)
+    assert _gmm_tiling(16384, 4096, 2048, 4) == (256, 512, 1024)
+    assert _gmm_tiling(24, 4096, 2048) is None
+    assert _gmm_tiling(64, 4096, 100) is None
+
+
+def test_decode_never_expands_the_cache_to_heads():
+    """The absorbed path's lowered program holds no tensor with a (view,
+    heads) pair of axes at the key or value width: nothing per-head is
+    made of the cache."""
+    import re
+
+    cfg = toy(moe_experts_held=8)
+    p = jax.eval_shape(lambda: seeded(cfg, 0))
+    cache = jax.eval_shape(lambda: KVCache.create(cfg, 2, 71,
+                                                  trash_slot=True))
+    i32 = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    text = jax.jit(lambda p, c, t, q: forward(
+        cfg, p, t, positions=q, cache=c, cache_view=56)).lower(
+        p, cache, i32, i32).as_text()
+    H, widths = cfg.num_heads, (cfg.qk_nope_head_dim, cfg.v_head_dim,
+                                cfg.q_head_dim,
+                                cfg.qk_nope_head_dim + cfg.v_head_dim)
+    shapes = set(re.findall(r"tensor<([0-9x]+)x[a-z]", text))
+    for shape in shapes:
+        dims_ = [int(d) for d in shape.split("x")]
+        per_head = (56 in dims_ or 72 in dims_) and H in dims_ \
+            and dims_[-1] in widths and len(dims_) >= 4
+        assert not per_head, shape
+    # ... while the latent view itself is there.
+    assert f"2x56x{cfg.latent_width}" in " ".join(shapes)
+
+
+# --------------------------------------------------------------------------
+# The flash forward with a value width of its own
+# --------------------------------------------------------------------------
+
+def test_flash_forward_value_width_against_xla():
+    """(192, 192, 128), block ranges from positions that start past 0 and
+    leave a padded tail; against ops/attention."""
+    from runbooks_tpu.ops.attention import (
+        dot_product_attention,
+        make_attention_mask,
+    )
+    from runbooks_tpu.ops.flash_attention import (
+        UnequalWidthsBackward,
+        flash_attention,
+    )
+
+    b, sq, sk, h = 2, 64, 96, 2
+    ks = jax.random.split(jax.random.key(0), 3)
+    q = jax.random.normal(ks[0], (b, sq, h, 192), jnp.float32)
+    k = jax.random.normal(ks[1], (b, sk, h, 192), jnp.float32)
+    v = jax.random.normal(ks[2], (b, sk, h, 128), jnp.float32)
+    q_pos = jnp.asarray(np.stack([np.arange(20, 20 + sq),
+                                  np.where(np.arange(sq) < 40,
+                                           np.arange(sq), -1)]), jnp.int32)
+    kv_pos = jnp.broadcast_to(jnp.arange(sk, dtype=jnp.int32), (b, sk))
+    scale = 192 ** -0.5 * 1.3689 ** 2
+    got = flash_attention(q, k, v, q_pos, kv_pos, None, None, True, scale,
+                          16, 32)
+    assert got.shape == (b, sq, h, 128)
+    want = dot_product_attention(
+        q, k, v, mask=make_attention_mask(q_pos, kv_pos), scale=scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert float(jnp.abs(got[1, 40:]).max()) == 0.0     # sees no key
+    with pytest.raises(UnequalWidthsBackward, match="one width"):
+        jax.grad(lambda q_: flash_attention(
+            q_, k, v, q_pos, kv_pos, None, None, True, scale, 16,
+            32).sum())(q)
+
+
+def test_flash_forward_equal_widths_still_differentiates():
+    from runbooks_tpu.ops.flash_attention import flash_attention
+
+    ks = jax.random.split(jax.random.key(1), 3)
+    q, k, v = (jax.random.normal(kk, (1, 32, 2, 64), jnp.float32)
+               for kk in ks)
+    pos = jnp.arange(32, dtype=jnp.int32)[None]
+    g = jax.grad(lambda q_: flash_attention(
+        q_, k, v, pos, pos, None, None, True, None, 16, 16).sum())(q)
+    assert np.isfinite(np.asarray(g)).all() and float(jnp.abs(g).max()) > 0
+
+
+# --------------------------------------------------------------------------
+# The engine
+# --------------------------------------------------------------------------
+
+def greedy_reference(cfg, seed, prompt, n):
+    toks = list(prompt)
+    for _ in range(n):
+        logits = reference_logits(cfg, seed, np.asarray(toks, np.int32))
+        toks.append(int(logits[-1].argmax()))
+    return toks[len(prompt):]
+
+
+def test_engine_slots_at_different_lengths_and_a_reused_slot():
+    from runbooks_tpu.serve.engine import InferenceEngine, Request
+
+    cfg = toy(moe_experts_held=8)
+    p = seeded(cfg, 13)
+    eng = InferenceEngine(cfg, p, max_slots=2, max_seq_len=64,
+                          decode_chunk=4)
+    prompts = [tokens_for(cfg, 7, 6).tolist(), tokens_for(cfg, 25, 7).tolist(),
+               tokens_for(cfg, 12, 8).tolist()]   # the third reuses a slot
+    reqs = [Request(prompt_tokens=list(q), max_tokens=m, temperature=0.0)
+            for q, m in zip(prompts, (3, 9, 5))]
+    eng.generate(reqs)
+    for q, r in zip(prompts, reqs):
+        seq = np.asarray(q + r.output_tokens, np.int32)
+        logits = reference_logits(cfg, 13, seq)
+        rows = np.arange(len(q) - 1, len(seq) - 1)
+        gap = logits[rows].max(-1) - logits[rows, r.output_tokens]
+        assert len(r.output_tokens) == r.max_tokens and gap.max() <= TOL
+    stats = eng.moe_stats()
+    here, elsewhere = sum(stats["expert_tokens"]), stats["elsewhere"]
+    # Real tokens only: prompts + every decoded token but each request's
+    # last (sampled, never fed back), x top-4 x 2 sparse layers.
+    fed = sum(len(q) for q in prompts) + sum(m - 1 for m in (3, 9, 5))
+    assert here + elsewhere == fed * 4 * 2
+    assert 0.3 < here / (here + elsewhere) < 0.7      # 8 of 16 experts
+    assert stats["hits"]["decode"] <= stats["calls"]["decode"]
+    occ = eng.kv_occupancy()
+    assert occ["latent_cache_bytes"] == occ["kv_pool_bytes"] > 0
+    groups = eng.memory_groups()
+    assert groups["latent_cache"].shape == (3, 2, 65, cfg.latent_width)
+    assert groups["kv_cache"].latent is None
+
+
+@pytest.mark.parametrize("options,text", [
+    (dict(speculative="ngram"), "speculative decoding"),
+    (dict(adapter_pool=2), "adapter pool"),
+    (dict(quantize_kv=True), "quantize_kv"),
+])
+def test_engine_refuses_by_mechanism(options, text):
+    from runbooks_tpu.serve.engine import InferenceEngine
+
+    cfg = toy(moe_experts_held=8)
+    p = seeded(cfg, 0)
+    with pytest.raises(ValueError, match=f"{text}.*latent"):
+        InferenceEngine(cfg, p, max_slots=2, max_seq_len=64, **options)
+
+
+def test_engine_refuses_paging_prefixes_and_a_tensor_mesh():
+    from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
+    from runbooks_tpu.serve.engine import InferenceEngine
+    from runbooks_tpu.serve.paging import PagedInferenceEngine
+
+    cfg = toy(moe_experts_held=8)
+    p = seeded(cfg, 0)
+    with pytest.raises(ValueError, match="kv_paging: paged.*latent"):
+        PagedInferenceEngine(cfg, p, max_slots=2, max_seq_len=64)
+    eng = InferenceEngine(cfg, p, max_slots=2, max_seq_len=64)
+    with pytest.raises(ValueError, match="prefix registration.*latent"):
+        eng.register_prefix(list(range(40)))
+    with pytest.raises(ValueError, match="prefix registration.*latent"):
+        eng.warmup(rows=(1,), prefix_build=True)
+    mesh = make_mesh(MeshConfig(data=4, tensor=2, fsdp=1))
+    with pytest.raises(ValueError, match="tensor mesh axis.*latent"):
+        InferenceEngine(cfg, p, max_slots=2, max_seq_len=64, mesh=mesh)
+    with jax.set_mesh(mesh):
+        with pytest.raises(NotImplementedError, match="tensor mesh axis"):
+            forward(cfg, p, jnp.zeros((2, 8), jnp.int32))
+    with pytest.raises(NotImplementedError, match="no head axis"):
+        KVCache.create(cfg, 2, 32, quantize_kv=True)
