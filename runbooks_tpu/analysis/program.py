@@ -234,6 +234,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
         make_prefill_fn,
         make_prefix_build_fn,
         make_verify_fn,
+        pack_decode_fn,
         view_buckets_for,
     )
     import jax
@@ -276,14 +277,15 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
     rep_plen, rep_bucket, rep_rows = splice[-1] if splice \
         else (16, buckets[0], 1)
 
-    decode = make_decode_fn(cfg, settings.decode_chunk, max_seq_len,
-                            max_seq_len, views[-1])
-    decode_args = [params, pool,
-                   _sds((slots,), jnp.int32), _sds((slots,), jnp.int32),
-                   key, _sds((slots,), jnp.float32),
-                   _sds((slots,), jnp.int32), _sds((slots,), jnp.float32),
-                   _sds((slots,), jnp.int32), _sds((slots,), jnp.int32),
-                   _sds((slots,), jnp.bool_)]
+    # Decode programs are audited as the engine jits them: over the two
+    # packed per-slot blocks (pack_decode_fn), the adapter lanes a row.
+    def packed_decode(*factory_args):
+        return pack_decode_fn(make_decode_fn(*factory_args))
+
+    decode = packed_decode(cfg, settings.decode_chunk, max_seq_len,
+                           max_seq_len, views[-1])
+    decode_args = [params, pool, _sds((7, slots), jnp.int32),
+                   _sds((2, slots), jnp.float32), key]
 
     prefix_build = make_prefix_build_fn(cfg, cache_len)
 
@@ -340,15 +342,12 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
         key, _sds((slots,), jnp.float32), _sds((slots,), jnp.int32),
         _sds((slots,), jnp.float32),
         _sds((slots, rep_ppb), jnp.int32), _sds((slots,), jnp.int32)]
-    paged_decode = make_paged_decode_fn(
+    paged_decode = pack_decode_fn(make_paged_decode_fn(
         cfg, settings.decode_chunk, max_seq_len, page_size,
-        vp_buckets[-1], pool_pages)
+        vp_buckets[-1], pool_pages))
     paged_decode_args = [
         params, paged_pool, _sds((slots, mpps), jnp.int32),
-        _sds((slots,), jnp.int32), _sds((slots,), jnp.int32), key,
-        _sds((slots,), jnp.float32), _sds((slots,), jnp.int32),
-        _sds((slots,), jnp.float32), _sds((slots,), jnp.int32),
-        _sds((slots,), jnp.int32), _sds((slots,), jnp.bool_)]
+        *decode_args[2:]]
     paged_verify = make_paged_verify_fn(cfg, K, page_size,
                                         vp_buckets[-1], pool_pages)
     paged_verify_args = [
@@ -390,9 +389,8 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
         return prefill(params_, pool_, *rest, apool=apool_,
                        aslots=aslots_)
 
-    def adapter_decode(params_, pool_, apool_, aslots_, *rest):
-        return decode(params_, pool_, *rest, apool=apool_,
-                      aslots=aslots_)
+    def adapter_decode(params_, pool_, apool_, *rest):
+        return decode(params_, pool_, *rest, apool=apool_)
 
     def adapter_verify(params_, pool_, apool_, aslots_, *rest):
         return verify(params_, pool_, *rest, apool=apool_,
@@ -402,9 +400,8 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
         return paged_prefill(params_, pool_, *rest, apool=apool_,
                              aslots=aslots_)
 
-    def paged_adapter_decode(params_, pool_, apool_, aslots_, *rest):
-        return paged_decode(params_, pool_, *rest, apool=apool_,
-                            aslots=aslots_)
+    def paged_adapter_decode(params_, pool_, apool_, *rest):
+        return paged_decode(params_, pool_, *rest, apool=apool_)
 
     def paged_adapter_verify(params_, pool_, apool_, aslots_, *rest):
         return paged_verify(params_, pool_, *rest, apool=apool_,
@@ -474,8 +471,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
          "signatures": len(buckets) * len(rows_set)},
         {"component": "serve", "name": "adapter_decode",
          "fn": adapter_decode,
-         "args": ([params, pool, apool, aslots_sds(slots)]
-                  + decode_args[2:]),
+         "args": [params, pool, apool] + decode_args[2:],
          "signatures": len(views)},
         {"component": "serve", "name": "adapter_verify",
          "fn": adapter_verify,
@@ -489,8 +485,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
          "signatures": len(pshapes) * len(rows_set)},
         {"component": "serve", "name": "paged_adapter_decode",
          "fn": paged_adapter_decode,
-         "args": ([params, paged_pool, apool, aslots_sds(slots)]
-                  + paged_decode_args[2:]),
+         "args": [params, paged_pool, apool] + paged_decode_args[2:],
          "signatures": len(vp_buckets)},
         {"component": "serve", "name": "paged_adapter_verify",
          "fn": paged_adapter_verify,
@@ -547,20 +542,19 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
         cfg_tp = _dc.replace(cfg, collective_matmul="auto")
 
         prefill_tp = make_prefill_fn(cfg_tp, cache_len)
-        decode_tp = make_decode_fn(cfg_tp, settings.decode_chunk,
-                                   max_seq_len, max_seq_len, views[-1])
+        decode_tp = packed_decode(cfg_tp, settings.decode_chunk,
+                                  max_seq_len, max_seq_len, views[-1])
         verify_tp = make_verify_fn(cfg_tp, K, max_seq_len, views[-1])
         paged_prefill_tp = make_paged_prefill_fn(cfg_tp, cache_len,
                                                  page_size, pool_pages)
-        paged_decode_tp = make_paged_decode_fn(
+        paged_decode_tp = pack_decode_fn(make_paged_decode_fn(
             cfg_tp, settings.decode_chunk, max_seq_len, page_size,
-            vp_buckets[-1], pool_pages)
+            vp_buckets[-1], pool_pages))
         paged_verify_tp = make_paged_verify_fn(cfg_tp, K, page_size,
                                                vp_buckets[-1], pool_pages)
 
-        def adapter_decode_tp(params_, pool_, apool_, aslots_, *rest):
-            return decode_tp(params_, pool_, *rest, apool=apool_,
-                             aslots=aslots_)
+        def adapter_decode_tp(params_, pool_, apool_, *rest):
+            return decode_tp(params_, pool_, *rest, apool=apool_)
 
         specs += [
             {"component": "serve", "name": "prefill_sharded",
@@ -585,8 +579,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
              "args": paged_verify_args, "signatures": len(vp_buckets)},
             {"component": "serve", "name": "adapter_decode_sharded",
              "fn": adapter_decode_tp, "mesh": mesh,
-             "args": ([params, pool, apool, aslots_sds(slots)]
-                      + decode_args[2:]),
+             "args": [params, pool, apool] + decode_args[2:],
              "signatures": len(views)},
         ]
     # A layer pattern with recurrent state: the same two factories trace
@@ -606,9 +599,9 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
              rows_set[-1], buckets_h[-1])[2:],
          "signatures": len(buckets_h) * len(rows_set)},
         {"component": "serve", "name": "hybrid_decode",
-         "fn": make_decode_fn(cfg_h, settings.decode_chunk,
-                              cfg_h.max_seq_len, cfg_h.max_seq_len,
-                              views_h[-1]),
+         "fn": packed_decode(cfg_h, settings.decode_chunk,
+                             cfg_h.max_seq_len, cfg_h.max_seq_len,
+                             views_h[-1]),
          "args": [params_h, pool_h] + decode_args[2:],
          "signatures": len(views_h)},
     ]
@@ -630,9 +623,9 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
              rows_set[-1], buckets_s[-1])[2:],
          "signatures": len(buckets_s) * len(rows_set)},
         {"component": "serve", "name": "sparse_latent_decode",
-         "fn": make_decode_fn(cfg_s, settings.decode_chunk,
-                              cfg_s.max_seq_len, cfg_s.max_seq_len,
-                              views_s[-1]),
+         "fn": packed_decode(cfg_s, settings.decode_chunk,
+                             cfg_s.max_seq_len, cfg_s.max_seq_len,
+                             views_s[-1]),
          "args": [params_s, pool_s] + decode_args[2:],
          "signatures": len(views_s)},
     ]

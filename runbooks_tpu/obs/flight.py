@@ -223,7 +223,7 @@ _PROMOTIONS = _PromotionBudget()
 def tail_sample(request_id: str, duration_s: float, finish_reason: str,
                 error: bool = False) -> bool:
     """Terminal hook per request (the engine calls it from
-    ``_observe_request_done``; the serve worker's crash handler calls it
+    ``_finish_request``; the serve worker's crash handler calls it
     with ``error=True``): promote the request's ring timeline to the
     trace file when the request was *interesting* — errored, finished by
     deadline, or slower than ``RBT_TRACE_TAIL_MS``. With ``RBT_TRACE=1``

@@ -91,6 +91,10 @@ CATALOG: Dict[str, str] = {
     "serve_flash_blocks_visited_total": "counter",
     "serve_flash_blocks_grid_total": "counter",
     "serve_decode_dispatch_seconds": "histogram",
+    # decode chunks by when their tokens were handed over, and by where
+    # their per-slot operands came from (serve/engine._decode_chunk_step)
+    "serve_decode_chunks_total": "counter",
+    "serve_decode_operand_places_total": "counter",
     # Speculative decoding (serve/engine.py verify path,
     # docs/speculative-decoding.md): exported only when speculative is
     # on ("off" engines register none of these)

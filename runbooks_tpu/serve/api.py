@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import json
 import threading
 import time
@@ -267,10 +268,19 @@ class EngineWorker:
             futs = []
             for req in reqs:
                 fut: Future = Future()
+                # Resolved the moment the engine finishes the request —
+                # which may be while its next dispatch runs — not when
+                # that step returns (_finish keeps the rest).
+                req.on_finish = functools.partial(self._resolve, fut)
                 self._pending.append((req, fut))
                 futs.append(fut)
         self._wake.set()
         return futs
+
+    @staticmethod
+    def _resolve(fut: Future, req: Request) -> None:
+        if not fut.done():
+            fut.set_result(req)
 
     def register_prefix(self, tokens: list) -> Future:
         """Register a shared prompt prefix on the worker thread (the
@@ -308,6 +318,15 @@ class EngineWorker:
                 # the error (hanging futures would wedge HTTP handlers
                 # forever), drop pending warm shapes, and reset the slot
                 # state so subsequent requests get a clean engine.
+                # First the tokens the engine took from its last decoded
+                # chunk and had not handed over yet (host work only): a
+                # request that chunk finished has its result, whatever
+                # the step after it did.
+                try:
+                    self.engine.deliver_parked()
+                except Exception as hook_exc:  # noqa: BLE001 — a failing on_token hook must not stop the reset below
+                    print(f"serve: handing over the last chunk's tokens "
+                          f"failed: {hook_exc!r}", flush=True)
                 with self._lock:
                     doomed = self._inflight + self._pending
                     doomed_prefix = self._prefix_jobs
@@ -608,6 +627,20 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                         help_text="Completion tokens returned to clients.")
         reg.set_counter("serve_decode_steps_total", eng.steps,
                         help_text="Engine decode chunks executed.")
+        for delivery, n in eng.decode_deliveries.items():
+            reg.set_counter(
+                "serve_decode_chunks_total", n, delivery=delivery,
+                help_text="Decoded chunks by when their tokens were "
+                          "handed over: deferred = while the next "
+                          "dispatch ran on the device, inline = before "
+                          "it (grammar, speculation, nothing to follow).")
+        for kind, n in eng.operand_places.items():
+            reg.set_counter(
+                "serve_decode_operand_places_total", n, kind=kind,
+                help_text="Decode chunks by their per-slot operands: "
+                          "carry = the device arrays the chunk before "
+                          "returned, rebuilt = placed from the host "
+                          "after a slot changed hands.")
         reg.set_gauge("serve_active_slots", int(eng.active.sum()),
                       help_text="Slots currently decoding.")
         reg.set_gauge("serve_queue_depth", len(eng.queue),

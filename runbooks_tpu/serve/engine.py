@@ -15,9 +15,14 @@ inference is in-framework and TPU-shaped:
 - Decode runs ``decode_chunk`` steps per host round-trip (a lax.scan with
   on-device EOS/limit tracking), because on TPU a per-step host sync
   dominates small-batch inter-token latency. chunk=1 reproduces classic
-  step-at-a-time behavior exactly; the host replays the device's per-step
-  validity mask so slot bookkeeping matches the single-step semantics
-  token for token.
+  step-at-a-time behavior exactly; the host takes each slot's tokens by
+  the device's count of its live steps, so slot bookkeeping matches the
+  single-step semantics token for token.
+- Between the pull of a chunk and the next dispatch the host does only
+  what that dispatch depends on (the slot half, _take_chunk): the tokens
+  are handed over (on_token, histograms, finished) while the next program
+  runs, and the scan's carry and the per-slot sampling operands stay on
+  the device until a slot changes hands (_decode_chunk_step).
 - Prefill is batched: requests admitted in the same tick are grouped by
   length bucket and prefilled as one [rows, bucket] forward (rows padded to
   a power of two), so a burst costs one dispatch per bucket instead of one
@@ -100,18 +105,24 @@ _INTER_TOKEN_BUCKETS = (
     0.1, 0.25, 0.5, 1.0, 2.5)
 
 
-def _observe_request_done(req: "Request", now: float) -> None:
-    """Terminal latency accounting for one request (normal finish or
-    deadline expiry): end-to-end duration, labeled by finish reason —
-    plus the tail-sampling decision (obs/flight.py): a slow or
-    deadline-expired request's flight-ring timeline is promoted to
-    trace.jsonl even with RBT_TRACE=0."""
+def _finish_request(req: "Request", reason: str, now: float) -> None:
+    """The one place a request becomes finished (normal finish, error or
+    deadline expiry): the reason, then `finished` — last, the worker and
+    the stream handlers act on it — then the terminal latency accounting:
+    end-to-end duration, labeled by finish reason, plus the tail-sampling
+    decision (obs/flight.py): a slow or deadline-expired request's
+    flight-ring timeline is promoted to trace.jsonl even with
+    RBT_TRACE=0. Then the request's own hook."""
+    req.finish_reason = reason
+    req.finished = True
     obs_metrics.REGISTRY.observe(
         "serve_request_duration_seconds", now - req._submitted,
         reason=req.finish_reason or "stop",
         help_text="End-to-end request latency (submit to finish).")
     obs_flight.tail_sample(req.request_id, now - req._submitted,
                            req.finish_reason or "stop")
+    if req.on_finish is not None:
+        req.on_finish(req)
 
 
 # QoS classes, best first. Admission orders the queue by class (FIFO
@@ -192,6 +203,11 @@ class Request:
     # generated token lands in output_tokens. Keep it cheap and non-blocking
     # — it runs inside the decode loop (SSE uses call_soon_threadsafe).
     on_token: Optional[Callable[[int], None]] = None
+    # Called once (same thread) when the request finishes, after its last
+    # on_token: the serving worker resolves the request's future here, so
+    # that a finish handed over while the next dispatch runs on the device
+    # (engine.deliver_parked) does not wait for that dispatch to end.
+    on_finish: Optional[Callable[["Request"], None]] = None
     _slot: int = -1
     _adapter_lane: int = -1   # pool lane pinned at admission (-1 = base)
     # Compiled DFA cursor (serve/grammar.GrammarCursor) when
@@ -433,14 +449,37 @@ def make_prefix_build_fn(cfg: ModelConfig, cache_len: int):
     return prefix_build_fn
 
 
+# The two per-slot operand blocks of a decode program, by row: one int32
+# [7, slots] and one float32 [2, slots]. The first four int rows are the
+# scan's carry, which the program returns advanced; the rest change only
+# when a slot changes hands. The engine's host mirror of the blocks IS its
+# per-slot state (InferenceEngine.__init__).
+ROW_TOKEN, ROW_POS, ROW_LEFT, ROW_ALIVE, ROW_TOP_K, ROW_EOS, ROW_ASLOT = \
+    range(7)
+ROW_TEMP, ROW_TOP_P = range(2)
+
+
+def advance_rows(nxt, pos, alive, left, eos_ids, max_len: int):
+    """The device's copy of the finish rules (EOS, max_tokens budget,
+    cache out of room — the host's is InferenceEngine._take_tokens), after
+    one decode step sampled `nxt`: (pos, alive, left) of the next step."""
+    pos = pos + alive
+    left = left - alive
+    hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
+    return pos, alive & ~hit_eos & (left > 0) & (pos < max_len), left
+
+
 def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
                    pad_slot: int, view: int):
     """`chunk` decode steps in one jit call (lax.scan). Per-slot
     liveness is tracked ON DEVICE with exactly the host's finish rules
-    (EOS, max_tokens budget, cache out-of-room), so the host can replay
+    (EOS, max_tokens budget, cache out-of-room), so the host can take
     (tokens, valid) afterwards and land in the same slot state as
-    chunk=1 step-at-a-time would. rng advances functionally (successor
-    key returned) — no eager split on the host per chunk."""
+    chunk=1 step-at-a-time would, and the scan's final carry — (token,
+    position, alive, budget left) a slot — is returned beside them: it is
+    the next chunk's operands when no slot changed hands in between
+    (pack_decode_fn). rng advances functionally (successor key returned)
+    — no eager split on the host per chunk."""
 
     sparse = bool(cfg.moe_num_experts)
 
@@ -450,14 +489,14 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
         # gmask [B, vocab] is each slot's allowed-token row AT CHUNK
         # START; it stays fixed across the scan, so it is exact only for
         # the chunk's first step. The host takes exactly one token per
-        # chunk for constrained slots (_replay_chunk) — chunk=1 (the CPU
+        # chunk for constrained slots (_take_chunk) — chunk=1 (the CPU
         # default) degenerates to fully exact per-step masking.
         rng, step_rng = jax.random.split(rng)
         keys = jax.random.split(step_rng, chunk)
         adapters = None if apool is None else (apool, aslots)
 
         def body(carry, key):
-            cache, tok, pos, alive, emitted = carry
+            cache, tok, pos, alive, left = carry
             p = jnp.where(alive, pos, pad_slot)
             # Rule (c) of make_prefill_fn's invariant: a parked row's
             # recurrent state does not move.
@@ -473,20 +512,48 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
             # Every step reads the weights of the experts it hits: the
             # steps' counts and hits add up.
             out = (nxt, alive, *map(dispatch_stats, moe))
-            emitted = emitted + alive
-            pos = pos + alive
-            hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
-            alive = (alive & ~hit_eos & (emitted < remaining)
-                     & (pos < max_len))
-            return (cache, nxt, pos, alive, emitted), out
+            pos, alive, left = advance_rows(nxt, pos, alive, left, eos_ids,
+                                            max_len)
+            return (cache, nxt, pos, alive, left), out
 
-        init = (cache, tokens, positions, active,
-                jnp.zeros_like(remaining))
-        (cache, *_), (toks, valid, *moe) = jax.lax.scan(body, init, keys)
-        return (toks, valid, cache, rng,
+        init = (cache, tokens, positions, active, remaining)
+        (cache, *carry), (toks, valid, *moe) = jax.lax.scan(body, init, keys)
+        return (toks, valid, tuple(carry), cache, rng,
                 *jax.tree.map(lambda a: a.sum(axis=0), moe))
 
     return decode_fn
+
+
+def pack_decode_fn(decode_fn):
+    """The decode program as the engine runs it: `decode_fn`
+    (make_decode_fn, or the paged one, whose page table stays the leading
+    operand) over the two per-slot blocks. Returns (pulled, ints,
+    cache, rng, *moe): `pulled` int32 [chunk + 2, slots] is everything the
+    host reads of a chunk in ONE transfer — the tokens a step, then the
+    count of tokens a slot emitted (a row is alive for a prefix of the
+    chunk, so they are its first ones), then who is alive after it —
+    and `ints` is the block with the carry rows advanced, which stays on
+    the device and goes straight into the next chunk."""
+
+    def packed(params, cache, *operands, apool=None, gmask=None):
+        *table, ints, floats, rng = operands
+        toks, valid, (tok, pos, alive, left), cache, rng, *moe = decode_fn(
+            params, cache, *table, ints[ROW_TOKEN], ints[ROW_POS], rng,
+            floats[ROW_TEMP], ints[ROW_TOP_K], floats[ROW_TOP_P],
+            ints[ROW_EOS], ints[ROW_LEFT], ints[ROW_ALIVE] != 0,
+            apool=apool, aslots=None if apool is None else ints[ROW_ASLOT],
+            gmask=gmask)
+        pulled = jnp.concatenate([
+            toks, valid.sum(axis=0, dtype=jnp.int32)[None],
+            alive.astype(jnp.int32)[None]])
+        ints = jnp.stack([tok, pos, left, alive.astype(jnp.int32),
+                          *ints[ROW_TOP_K:]])
+        return (pulled, ints, cache, rng, *moe)
+
+    # The compiled module is named after the function (jit_decode_fn): a
+    # profiler capture's readers find the program by it.
+    packed.__name__ = decode_fn.__name__
+    return packed
 
 
 def make_verify_fn(cfg: ModelConfig, draft_tokens: int, pad_slot: int,
@@ -763,9 +830,34 @@ class InferenceEngine:
                         jax.eval_shape(lambda: self.adapters.tree),
                         adapter_pool_logical_axes(self.adapters.tree),
                         mesh))
+        # The host mirror of a decode program's two per-slot blocks (rows:
+        # ROW_*). Its rows ARE the engine's per-slot state — the arrays
+        # below are views of them — so placing the blocks copies nothing
+        # together first; the sampling rows are written when a slot
+        # changes hands (_activate_slot), never rebuilt a chunk.
+        self._slot_ints = np.zeros((7, max_slots), np.int32)
+        self._slot_floats = np.zeros((2, max_slots), np.float32)
+        self._slot_floats[ROW_TOP_P] = 1.0
+        self._slot_ints[ROW_EOS] = -1
         # Per-slot adapter lane indices (-1 = base-only/trash lane): the
         # operand every adapter-aware dispatch gathers A/B by.
-        self.adapter_slots = np.full(max_slots, -1, np.int32)
+        self.adapter_slots = self._slot_ints[ROW_ASLOT]
+        self.adapter_slots[:] = -1
+        # The blocks on the device, (ints, floats), as the last decode
+        # chunk returned them: the next chunk's operands as they are. None
+        # once the host changed a slot behind the device's back (admission,
+        # a finish only the host saw, deadline, preemption, verify, reset);
+        # the next chunk then places the host mirror again.
+        self._dev_blocks: Optional[tuple] = None
+        # Decoded chunks by when their tokens were handed over, and by
+        # where their operands came from (/metrics:
+        # serve_decode_chunks_total, serve_decode_operand_places_total).
+        self.decode_deliveries = {"deferred": 0, "inline": 0}
+        self.operand_places = {"carry": 0, "rebuilt": 0}
+        # The delivery half of the last decoded chunk, waiting for the next
+        # dispatch to hide behind: (request, tokens, finish reason) a row,
+        # by request OBJECT — its slot may be another request's by then.
+        self._parked: List[tuple] = []
         self._init_cache()
         self.prefill_budget = (options.prefill_budget
                                if options.prefill_budget is not None
@@ -814,9 +906,9 @@ class InferenceEngine:
         self.deadline_expired = 0   # observability/tests
         self.preemptions = 0          # slots preempted (observability)
         self.preempted_resumed = 0    # preempted requests re-admitted
-        self.lengths = np.zeros(max_slots, np.int32)       # tokens in cache
+        self.lengths = self._slot_ints[ROW_POS]            # tokens in cache
         self.active = np.zeros(max_slots, bool)
-        self.last_token = np.zeros(max_slots, np.int32)
+        self.last_token = self._slot_ints[ROW_TOKEN]
         self.slot_req: List[Optional[Request]] = [None] * max_slots
         self.queue: List[Request] = []
         self.rng = self._commit_key(jax.random.key(seed))
@@ -903,10 +995,7 @@ class InferenceEngine:
         No-op off-mesh (single-device placement is already unique)."""
         if self.mesh is None:
             return key
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        return jax.device_put(
-            key, NamedSharding(self.mesh, PartitionSpec()))
+        return jax.device_put(key, self._replicated())
 
     def _init_programs(self) -> None:
         """Build and register the engine's jitted program set. Overridable
@@ -945,10 +1034,9 @@ class InferenceEngine:
 
         def decode_for(view: int):
             if view not in self._decode_fns:
-                self._decode_fns[view] = jax.jit(
+                self._decode_fns[view] = self._jit_decode(
                     make_decode_fn(cfg, chunk, max_len, self._pad_slot,
-                                   view),
-                    donate_argnums=(1,))
+                                   view))
                 obs_device.PROGRAMS.register("serve", f"decode_v{view}",
                                              self._decode_fns[view])
             return self._decode_fns[view]
@@ -972,6 +1060,32 @@ class InferenceEngine:
             return self._verify_fns[view]
 
         self._verify_for = verify_for
+
+    def _jit_decode(self, decode_fn):
+        """Jit a decode program over the packed blocks (pack_decode_fn).
+        Under a mesh the returned int block is pinned replicated, as
+        _place_blocks places it: the carry then goes back in with the
+        sharding the program was compiled for, not re-laid every chunk."""
+        out_shardings = None
+        if self.mesh is not None:
+            out_shardings = (None, self._replicated(), None, None) + (
+                (None,) if self.cfg.moe_num_experts else ())
+        return jax.jit(pack_decode_fn(decode_fn), donate_argnums=(1,),
+                       out_shardings=out_shardings)
+
+    def _replicated(self):
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return NamedSharding(self.mesh, PartitionSpec())
+
+    def _place_blocks(self, ints, floats) -> tuple:
+        """The two per-slot blocks on the device: one transfer a dtype.
+        Of copies: the host mirror is written in place, and on the CPU
+        backend a placed array may share its host buffer's memory."""
+        blocks = (ints.copy(), floats.copy())
+        if self.mesh is None:
+            return tuple(map(jnp.asarray, blocks))
+        return tuple(jax.device_put(blocks, self._replicated()))
 
     def _new_pool_cache(self) -> KVCache:
         """Fresh slot-pool cache (int8 + scales when quantize_kv), sharded
@@ -1018,6 +1132,11 @@ class InferenceEngine:
             raise ValueError(
                 f"{feature} is not supported for a model with recurrent "
                 f"(linear-attention) layers: {why}")
+
+    def _decode_kwargs(self) -> dict:
+        """The adapter pool as a decode program takes it: the lane indices
+        are a row of its int block."""
+        return {} if self.adapters is None else {"apool": self.adapters.tree}
 
     def _adapter_kwargs(self, aslots=None) -> dict:
         """Extra operands for adapter-aware dispatches: the pool pytree
@@ -1205,20 +1324,15 @@ class InferenceEngine:
                             self.params, self.cache, *args, **kw)
                     n_prefill += 1
             zeros = np.zeros(self.max_slots, np.int32)
-            akw = {**self._adapter_kwargs(),
+            akw = {**self._decode_kwargs(),
                    **self._grammar_warm_kwargs(
                        (self.max_slots, self.cfg.vocab_size))}
             for view in self.view_buckets:
-                args = (jnp.asarray(zeros),
-                        jnp.asarray(np.full(self.max_slots, self._pad_slot,
-                                            np.int32)),
-                        self._commit_key(jax.random.key(0)),
-                        jnp.zeros(self.max_slots, jnp.float32),
-                        jnp.zeros(self.max_slots, jnp.int32),
-                        jnp.ones(self.max_slots, jnp.float32),
-                        jnp.full(self.max_slots, -1, jnp.int32),
-                        jnp.zeros(self.max_slots, jnp.int32),
-                        jnp.zeros(self.max_slots, bool))
+                # No row alive.
+                args = (*self._place_blocks(
+                    np.zeros_like(self._slot_ints),
+                    np.zeros_like(self._slot_floats)),
+                        self._commit_key(jax.random.key(0)))
                 with self._mesh_ctx():
                     _, _, self.cache, *_ = run.program(
                         f"decode_v{view}", f"v{view}",
@@ -1581,7 +1695,17 @@ class InferenceEngine:
     def reset(self) -> None:
         """Recover from a failed jitted step: donated cache buffers may be
         invalid, so reallocate, and clear all slot state."""
+        self._reset_slots()
         self.cache = self._new_pool_cache()
+
+    def _reset_slots(self) -> None:
+        """Shared reset head: hand over what the last chunk decoded (its
+        tokens were taken; host work only), then forget every request. No
+        adapter lane stays pinned; residency survives (the pool tree is
+        never donated to an engine step, so its buffers are valid even
+        after a crash) — the next admission hits instead of reloading."""
+        self.deliver_parked()
+        self._dev_blocks = None
         self.lengths[:] = 0
         self.active[:] = False
         self.last_token[:] = 0
@@ -1589,13 +1713,6 @@ class InferenceEngine:
         self.queue.clear()
         if self._spec_index is not None:
             self._spec_index.reset()
-        self._reset_adapters()
-
-    def _reset_adapters(self) -> None:
-        """Shared reset tail: every in-flight request is gone, so no
-        adapter lane stays pinned. Residency survives (the pool tree is
-        never donated to an engine step, so its buffers are valid even
-        after a crash) — the next admission hits instead of reloading."""
         self.adapter_slots[:] = -1
         if self.adapters is not None:
             self.adapters.reset_refs()
@@ -1714,11 +1831,9 @@ class InferenceEngine:
         try:
             lane = self.adapters.acquire(req.adapter)
         except AdapterLoadError as exc:
-            req.finished = True
-            req.finish_reason = "error"
             print(f"serve: adapter {req.adapter!r} failed to load at "
                   f"admission: {exc}", flush=True)
-            _observe_request_done(req, time.monotonic())
+            _finish_request(req, "error", time.monotonic())
             return True
         if lane is None:
             return False
@@ -1881,6 +1996,7 @@ class InferenceEngine:
                     first, *moe = program(args, kwargs)
                     # The call has returned and the device is at work.
                     self._count_flash_blocks(bucket, positions)
+                self.deliver_parked(hidden=True)
                 with fine("prefill.sync"):
                     # rbt-check: ignore[device-sync] prefill dispatch boundary — the first token must reach the host to stream
                     first = np.asarray(first)
@@ -1967,6 +2083,12 @@ class InferenceEngine:
         self.lengths[slot] = len(eff)
         self.slot_req[slot] = req
         self.adapter_slots[slot] = req._adapter_lane
+        self._slot_ints[ROW_TOP_K, slot] = req.top_k
+        self._slot_ints[ROW_EOS, slot] = (-1 if req.eos_id is None
+                                          else req.eos_id)
+        self._slot_floats[ROW_TEMP, slot] = req.temperature
+        self._slot_floats[ROW_TOP_P, slot] = req.top_p
+        self._dev_blocks = None
         req._slot = slot
         if resumed:
             # Resume after preemption: the cache again holds the full
@@ -1993,70 +2115,103 @@ class InferenceEngine:
         self._record_token(slot, first_tok)
 
     def _record_token(self, slot: int, tok: int) -> None:
+        """One token, taken and handed over at once (a prefill's first
+        token, a verify step's): both halves below, inline."""
         req = self.slot_req[slot]
         assert req is not None
-        req.output_tokens.append(tok)
+        self._deliver(req, [tok], self._take_tokens(slot, req, [tok]))
+
+    def _take_tokens(self, slot: int, req: Request, toks: List[int]) -> str:
+        """The engine's half of recording a slot's newest tokens (the
+        caller moved `lengths` and `last_token`): everything the next
+        dispatch depends on, nothing the outside sees. Holds the host's
+        copy of the finish rules, applied to the last token — a decode
+        chunk stops a row at the first token that ends it
+        (advance_rows). Returns the finish reason, "" while the request
+        goes on; a finished slot is free when this returns."""
+        req.output_tokens.extend(toks)
         if self._spec_index is not None:
-            self._spec_index.extend(slot, tok)
-        # Latency histograms, host-observed: TTFT on the first token,
-        # inter-token gaps after. Chunked decode replays a chunk's tokens
-        # in one host loop, so within-chunk gaps are microseconds and the
-        # chunk's first token carries the chunk wall time — exactly the
-        # burst cadence an SSE client observes (docs/observability.md).
-        now = time.monotonic()
-        reg = obs_metrics.REGISTRY
-        if len(req.output_tokens) == 1:
-            reg.observe("serve_ttft_seconds", now - req._submitted,
-                        help_text="Time to first generated token "
-                                  "(submit to first sampled token).")
-        else:
-            reg.observe("serve_inter_token_seconds",
-                        now - req._last_token_t,
-                        buckets=_INTER_TOKEN_BUCKETS,
-                        help_text="Host-observed gap between consecutive "
-                                  "generated tokens of one request.")
-        req._last_token_t = now
-        if req.on_token is not None:
-            req.on_token(tok)
-        hit_eos = req.eos_id is not None and tok == req.eos_id
-        # Grammar cursor advance — the single mutation point (draft
-        # gating and verify masks preview with the non-mutating walk).
-        # EOS is not a grammar token: the mask allows it exactly at
-        # accepting states, and it finishes via the normal "stop" path.
-        # A terminal state (accepting, no legal continuation) finishes
-        # the slot HERE — its empty mask row is never dispatched.
-        grammar_done = False
-        if req._grammar is not None and not hit_eos:
-            if not req._grammar.advance(tok):
-                # Masked sampling makes this unreachable; an assert
-                # would take the whole engine down for one request.
-                req.finished = True
-                req.finish_reason = "error"
-                self.active[slot] = False
-                self.slot_req[slot] = None
-                _observe_request_done(req, now)
-                self._on_slot_finished(slot, req)
-                return
-            grammar_done = req._grammar.at_terminal
-        out_len = len(req.output_tokens)
-        # lengths[slot] counts tokens written to the cache; the next decode
-        # writes at position lengths[slot], which must stay < max_seq_len
-        # (slot max_seq_len is the trash slot).
-        out_of_room = self.lengths[slot] >= self.max_seq_len
-        if hit_eos or grammar_done or out_len >= req.max_tokens \
-                or out_of_room:
-            req.finished = True
-            if hit_eos:
-                req.finish_reason = "stop"
-            elif grammar_done:
-                req.finish_reason = "grammar_complete"
-                self.grammar_completed += 1
-            else:
-                req.finish_reason = "length"
+            for tok in toks:
+                self._spec_index.extend(slot, tok)
+        tok = toks[-1]
+        reason = ""
+        if req.eos_id is not None and tok == req.eos_id:
+            # EOS is not a grammar token: the mask allows it exactly at
+            # accepting states, and it finishes via the normal path.
+            reason = "stop"
+        elif req._grammar is not None and not req._grammar.advance(tok):
+            # Grammar cursor advance — the single mutation point (draft
+            # gating and verify masks preview with the non-mutating
+            # walk). Masked sampling makes a refusal unreachable; an
+            # assert would take the whole engine down for one request.
+            reason = "error"
+        elif req._grammar is not None and req._grammar.at_terminal:
+            # A terminal state (accepting, no legal continuation)
+            # finishes the slot HERE — its empty mask row is never
+            # dispatched.
+            reason = "grammar_complete"
+            self.grammar_completed += 1
+        elif (len(req.output_tokens) >= req.max_tokens
+              # lengths[slot] counts tokens written to the cache; the next
+              # decode writes at position lengths[slot], which must stay
+              # < max_seq_len (slot max_seq_len is the trash slot).
+              or self.lengths[slot] >= self.max_seq_len):
+            reason = "length"
+        if reason:
             self.active[slot] = False
             self.slot_req[slot] = None
-            _observe_request_done(req, now)
             self._on_slot_finished(slot, req)
+        return reason
+
+    def _deliver(self, req: Request, toks: List[int], reason: str) -> None:
+        """The outside's half: per token, in order, the latency
+        histograms and the streaming hook; then, with a finish reason, the
+        finish itself. `finished` is set last — the worker resolves a
+        request's future on it, and a stream must not close before its
+        last tokens were handed over. Keyed by the request object alone:
+        its slot may be another request's by now.
+
+        Latency is host-observed: TTFT on the first token, inter-token
+        gaps after. A chunk's tokens are handed over in one host loop, on
+        one reading of the clock: within-chunk gaps are zero and the
+        chunk's first token carries the chunk wall time — exactly the
+        burst cadence an SSE client observes (docs/observability.md)."""
+        reg = obs_metrics.REGISTRY
+        now = time.monotonic()
+        for tok in toks:
+            if req._last_token_t:
+                reg.observe("serve_inter_token_seconds",
+                            now - req._last_token_t,
+                            buckets=_INTER_TOKEN_BUCKETS,
+                            help_text="Host-observed gap between "
+                                      "consecutive generated tokens of one "
+                                      "request.")
+            else:
+                reg.observe("serve_ttft_seconds", now - req._submitted,
+                            help_text="Time to first generated token "
+                                      "(submit to first sampled token).")
+            req._last_token_t = now
+            if req.on_token is not None:
+                req.on_token(tok)
+        if reason:
+            _finish_request(req, reason, now)
+
+    def deliver_parked(self, hidden: bool = False) -> None:
+        """Hand over the tokens of the last decoded chunk, if they still
+        wait (`_parked`). `hidden`: the caller has just dispatched a
+        program, so this runs while the device works — the place a chunk's
+        delivery is deferred to. Everyone else calls it because nothing
+        will be dispatched to hide it behind, or because they are about to
+        touch a request whose tokens may be parked (deadline, preemption,
+        reset, the worker's crash path)."""
+        if not self._parked:
+            return
+        parked, self._parked = self._parked, []
+        self.decode_deliveries["deferred" if hidden else "inline"] += 1
+        with fine("decode.replay") as replay:
+            for row in parked:
+                self._deliver(*row)
+            replay.set(tokens=sum(len(toks) for _, toks, _ in parked))
 
     def _on_slot_finished(self, slot: int, req: Request) -> None:
         """Called once per slot whose request just finished (normal stop,
@@ -2119,15 +2274,13 @@ class InferenceEngine:
         keep = []
         for r in self.queue:
             if expired(r):
-                r.finished = True
-                r.finish_reason = "deadline"
                 # A queued request may already hold an adapter lane pin
                 # (acquired while waiting for a slot/pages): release it
                 # or the lane stays unEvictable forever.
                 if self.adapters is not None and r._adapter_lane >= 0:
                     self.adapters.release(r._adapter_lane)
                     r._adapter_lane = -1
-                _observe_request_done(r, now)
+                _finish_request(r, "deadline", now)
                 n += 1
             else:
                 keep.append(r)
@@ -2137,38 +2290,17 @@ class InferenceEngine:
         for slot in range(self.max_slots):
             req = self.slot_req[slot]
             if self.active[slot] and req is not None and expired(req):
-                req.finished = True
-                req.finish_reason = "deadline"
-                _observe_request_done(req, now)
+                # Its last tokens first, then the finish.
+                self.deliver_parked()
+                self._dev_blocks = None
                 self.active[slot] = False
                 self.slot_req[slot] = None
                 self._on_slot_finished(slot, req)
+                _finish_request(req, "deadline", now)
                 freed.append(slot)
                 n += 1
         self.deadline_expired += n
         return freed
-
-    def _sampling_operands(self):
-        """Per-slot sampling + device-side finish-tracking operands for
-        one decode chunk (inactive rows get inert values; eos/remaining
-        mirror _record_token: EOS id (-1 = none), tokens left in the
-        request budget). Shared with the paged engine's step
-        (serve/paging.py)."""
-        temps = np.array([self.slot_req[i].temperature if self.active[i]
-                          else 0.0 for i in range(self.max_slots)], np.float32)
-        top_ks = np.array([self.slot_req[i].top_k if self.active[i] else 0
-                           for i in range(self.max_slots)], np.int32)
-        top_ps = np.array([self.slot_req[i].top_p if self.active[i] else 1.0
-                           for i in range(self.max_slots)], np.float32)
-        eos_ids = np.array([
-            self.slot_req[i].eos_id
-            if self.active[i] and self.slot_req[i].eos_id is not None else -1
-            for i in range(self.max_slots)], np.int32)
-        remaining = np.array([
-            self.slot_req[i].max_tokens - len(self.slot_req[i].output_tokens)
-            if self.active[i] else 0
-            for i in range(self.max_slots)], np.int32)
-        return temps, top_ks, top_ps, eos_ids, remaining
 
     def _decode_span_attrs(self) -> dict:
         """Decode-span attrs, computed only when tracing is on: span()
@@ -2181,11 +2313,16 @@ class InferenceEngine:
                                 for i in range(self.max_slots)
                                 if self.active[i]]}
 
-    def _replay_chunk(self, toks, valid) -> int:
-        """Replay one decode chunk on the host: `valid[k]` is exactly the
-        set of slots that were alive at device step k, so this loop lands
-        in the same bookkeeping state as chunk=1 stepping would. Returns
-        tokens generated.
+    def _take_chunk(self, pulled: np.ndarray) -> bool:
+        """The slot half of one decoded chunk, from what the host pulled
+        of it (pack_decode_fn: tokens a step, tokens a slot, alive after):
+        every active slot takes its tokens, the rows that ended are freed,
+        and what the outside will see of it is parked (`_parked`), a row a
+        request. After it the engine's slot state is what chunk=1 stepping
+        would have left, and _admit may reuse a freed slot. Returns whether
+        the device's carry agrees with that state — the host took every
+        token the device emitted and ended exactly the rows it ended — so
+        that it may be the next chunk's operands.
 
         Grammar-constrained slots take only the chunk's FIRST token: the
         gmask is exact for step 0 only (it cannot advance inside the
@@ -2194,25 +2331,20 @@ class InferenceEngine:
         is rewritten by the next dispatch, the same stale-data invariant
         speculative rollback rides. chunk=1 (the CPU default) makes this
         a no-op; spec decode restores multi-token steps for constrained
-        slots. The device can't see a grammar_complete finish either, so
-        slots the host just finished skip the rest of their chunk."""
-        generated = 0
-        taken: set = set()
-        for k in range(toks.shape[0]):
-            for slot in np.nonzero(valid[k])[0]:
-                if not self.active[slot]:
-                    continue  # finished host-side (grammar_complete)
-                req = self.slot_req[slot]
-                if req is not None and req._grammar is not None:
-                    if slot in taken:
-                        continue
-                    taken.add(slot)
-                generated += 1
-                self.lengths[slot] += 1
-                tok = int(toks[k, slot])
-                self.last_token[slot] = tok
-                self._record_token(slot, tok)
-        return generated
+        slots. The device can't see a grammar_complete finish either."""
+        toks, counts, alive = pulled[:-2], pulled[-2], pulled[-1] != 0
+        agreed = True
+        for slot in np.nonzero(self.active)[0]:
+            req = self.slot_req[slot]
+            n = int(counts[slot])
+            if req._grammar is not None and n > 1:
+                n, agreed = 1, False
+            new = toks[:n, slot].tolist()
+            self.lengths[slot] += n
+            self.last_token[slot] = new[-1]
+            self._parked.append(
+                (req, new, self._take_tokens(slot, req, new)))
+        return agreed and np.array_equal(self.active, alive)
 
     def step(self) -> int:
         """Admit queued requests, then advance every active slot: one
@@ -2230,6 +2362,8 @@ class InferenceEngine:
                 self._admit(exclude_slots=self._expire_deadlines())
                 admit.set(admitted=self.prefix_lookups - before)
             if not self.active.any():
+                # Nothing is dispatched to hide a delivery behind.
+                self.deliver_parked()
                 return 0
             generated: Optional[int] = None
             if self._spec_index is not None:
@@ -2308,12 +2442,13 @@ class InferenceEngine:
                 tokens[slot, 1:1 + len(d)] = d
                 draft_len[slot] = len(d)
         positions = np.where(self.active, self.lengths, 0).astype(np.int32)
-        temps, top_ks, top_ps, _eos, _rem = self._sampling_operands()
         step_drafted = int(draft_len.sum())
         t_dispatch = time.perf_counter()
         accept, resid, full = self._verify_dispatch(
-            tokens, positions, draft_len, temps, top_ks, top_ps,
+            tokens, positions, draft_len, self._slot_floats[ROW_TEMP],
+            self._slot_ints[ROW_TOP_K], self._slot_floats[ROW_TOP_P],
             self._grammar_verify_kwargs(drafts))
+        self._dev_blocks = None     # the host moves every cursor below
         wall = time.perf_counter() - t_dispatch
         generated = 0
         step_accepted = 0
@@ -2367,11 +2502,6 @@ class InferenceEngine:
         """Host operands a decode/verify program takes before the token
         operands: none here, the page table in the paged engine."""
         return ()
-
-    def _park_position(self) -> int:
-        """Where inactive rows decode: the trash slot (the paged engine's
-        free page-table rows point at the trash page, so 0 there)."""
-        return self._pad_slot
 
     def _verify_dispatch(self, tokens, positions, draft_len, temps,
                          top_ks, top_ps, gkw=None):
@@ -2433,45 +2563,68 @@ class InferenceEngine:
 
     def _decode_chunk_step(self) -> int:
         """One plain decode chunk over every active slot, dense or paged
-        (the seams above are all that differs)."""
+        (the seams above are all that differs). Between the pull of a
+        chunk and the next dispatch the host does only what that dispatch
+        depends on (_take_chunk); handing the tokens over (deliver_parked)
+        waits until the next program runs — here, or in _prefill_dispatch
+        when the next tick admits — unless the host alone can see how this
+        chunk's requests go on: a grammar cursor (one token a chunk,
+        finishes the device cannot see) or the speculative draft index
+        (current before _collect_drafts)."""
         key, label = self._view_key(int(self.lengths[self.active].max())
                                     + self.decode_chunk)
+        inline = self._spec_index is not None or (
+            self._grammar_cache is not None and any(
+                self.slot_req[slot]._grammar is not None
+                for slot in np.nonzero(self.active)[0]))
         with span("decode", view=label, **self._decode_span_attrs()), \
                 self._mesh_ctx():
             with fine("decode.operands"):
-                # Inactive rows decode at a harmless position; mid-chunk,
-                # rows that finish are parked there by the device mask.
-                positions = np.where(self.active, self.lengths,
-                                     self._park_position()).astype(np.int32)
-                temps, top_ks, top_ps, eos_ids, remaining = \
-                    self._sampling_operands()
+                blocks = self._dev_blocks
+                self.operand_places["carry" if blocks else "rebuilt"] += 1
+                if blocks is None:
+                    # A slot changed hands: the budget and who is alive
+                    # join the rows the host keeps current anyway. Inactive
+                    # rows' other values are inert (the device parks them).
+                    left = self._slot_ints[ROW_LEFT]
+                    left[:] = 0
+                    for slot in np.nonzero(self.active)[0]:
+                        req = self.slot_req[slot]
+                        left[slot] = req.max_tokens - len(req.output_tokens)
+                    self._slot_ints[ROW_ALIVE] = self.active
                 t_dispatch = time.perf_counter()
-                operands = (
-                    *map(jnp.asarray, self._table_operands()),
-                    jnp.asarray(self.last_token), jnp.asarray(positions),
-                    self.rng, jnp.asarray(temps), jnp.asarray(top_ks),
-                    jnp.asarray(top_ps), jnp.asarray(eos_ids),
-                    jnp.asarray(remaining), jnp.asarray(self.active))
-                kwargs = {**self._adapter_kwargs(),
+                if blocks is None:
+                    blocks = self._place_blocks(self._slot_ints,
+                                                self._slot_floats)
+                operands = (*map(jnp.asarray, self._table_operands()),
+                            *blocks, self.rng)
+                kwargs = {**self._decode_kwargs(),
                           **self._grammar_decode_kwargs()}
             with fine("decode.dispatch"):
-                toks, valid, self.cache, self.rng, *moe = \
+                pulled, ints, self.cache, self.rng, *moe = \
                     self._decode_for(key)(
                         self.params, self.cache, *operands, **kwargs)
+            # The chunk before this one, while the device works.
+            self.deliver_parked(hidden=True)
             with fine("decode.sync"):
-                # rbt-check: ignore[device-sync] decode-chunk dispatch boundary: one sync per chunk, not per token
-                toks = np.asarray(toks)          # [chunk, slots]
-                # rbt-check: ignore[device-sync] same boundary — valid rides the same chunk sync
-                valid = np.asarray(valid)        # [chunk, slots] bool
+                # rbt-check: ignore[device-sync] decode-chunk dispatch boundary: one sync per chunk, not per token (tokens, counts and liveness in one array)
+                pulled = np.asarray(pulled)      # [chunk + 2, slots]
                 self._count_moe("decode", moe, steps=self.decode_chunk)
             obs_metrics.REGISTRY.observe(
                 "serve_decode_dispatch_seconds",
                 time.perf_counter() - t_dispatch, view=str(label),
                 help_text="Decode-chunk dispatch+sync wall time, labeled "
                           "by cache view bucket.")
-            with fine("decode.replay") as replay:
-                generated = self._replay_chunk(toks, valid)
-                replay.set(tokens=generated)
+            agreed = self._take_chunk(pulled)
+            self._dev_blocks = (ints, blocks[1]) if agreed else None
+            generated = sum(len(toks) for _, toks, _ in self._parked)
+            if inline or not self.has_work() or any(
+                    reason and req.auto_prefix
+                    for req, _, reason in self._parked):
+                # No dispatch follows to hide behind, or the worker lifts
+                # a finished auto_prefix request's prompt K/V out of its
+                # slot before the next admission can recycle it.
+                self.deliver_parked()
         return generated
 
     # ------------------------------------------------------------------
@@ -2485,4 +2638,5 @@ class InferenceEngine:
         deadline = time.monotonic() + timeout_s
         while self.has_work() and time.monotonic() < deadline:
             self.step()
+        self.deliver_parked()       # a timeout may leave a chunk parked
         return requests

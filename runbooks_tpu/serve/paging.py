@@ -72,6 +72,7 @@ from runbooks_tpu.serve.engine import (
     InferenceEngine,
     Request,
     WarmupRun,
+    advance_rows,
     view_buckets_for,
 )
 
@@ -741,7 +742,8 @@ def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
     the view and scatters each newly written token's K/V back to its
     physical page, so the pool is exact when the chunk returns. Liveness
     (EOS / budget / out-of-room) tracks on device exactly as the dense
-    decode does — the host replays (tokens, valid) identically."""
+    decode does (advance_rows) — the host takes (tokens, valid)
+    identically, and the final carry is returned as the dense one is."""
     n_flat = (num_pages + 1) * page_size
     trash_flat = num_pages * page_size
     V = view_pages * page_size
@@ -752,7 +754,7 @@ def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
                         active, apool=None, aslots=None, gmask=None):
         # gmask [B, vocab]: chunk-start allowed-token rows, same
         # first-step-exact contract as the dense decode (the host takes
-        # one token per chunk for constrained slots — _replay_chunk).
+        # one token per chunk for constrained slots — _take_chunk).
         B = tokens.shape[0]
         quantized = pool.k.dtype == jnp.int8
         flat_k = pool.k.reshape(L, n_flat, kvh, d)
@@ -779,7 +781,7 @@ def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
         adapters = None if apool is None else (apool, aslots)
 
         def body(carry, key):
-            fk, fv, fks, fvs, cache, tok, pos, alive, emitted = carry
+            fk, fv, fks, fvs, cache, tok, pos, alive, left = carry
             p = jnp.where(alive, pos, V)   # park at the view trash slot
             logits, cache = forward(cfg, params, tok[:, None],
                                     positions=p[:, None], cache=cache,
@@ -809,16 +811,13 @@ def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
                 fks = fks.at[:, fi].set(wks)
                 fvs = fvs.at[:, fi].set(wvs)
             out = (nxt, alive)
-            emitted = emitted + alive
-            pos = pos + alive
-            hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
-            alive = (alive & ~hit_eos & (emitted < remaining)
-                     & (pos < max_len))
-            return (fk, fv, fks, fvs, cache, nxt, pos, alive, emitted), out
+            pos, alive, left = advance_rows(nxt, pos, alive, left, eos_ids,
+                                            max_len)
+            return (fk, fv, fks, fvs, cache, nxt, pos, alive, left), out
 
         init = (flat_k, flat_v, flat_ks, flat_vs, view_cache, tokens,
-                positions, active, jnp.zeros_like(remaining))
-        (fk, fv, fks, fvs, *_), (toks, valid) = jax.lax.scan(
+                positions, active, remaining)
+        (fk, fv, fks, fvs, _, *carry), (toks, valid) = jax.lax.scan(
             body, init, keys)
         new_pool = PagePool(
             k=fk.reshape(pool.k.shape), v=fv.reshape(pool.v.shape),
@@ -826,7 +825,7 @@ def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
                      if quantized else None),
             v_scale=(fvs.reshape(pool.v_scale.shape)
                      if quantized else None))
-        return toks, valid, new_pool, rng
+        return toks, valid, tuple(carry), new_pool, rng
 
     return paged_decode_fn
 
@@ -1244,20 +1243,13 @@ class PagedInferenceEngine(InferenceEngine):
         """Crash recovery: donated pool buffers may be invalid, so the
         pool reallocates and ALL paging state resets — the radix tree's
         pages lived in the doomed pool, so its content goes too."""
+        self._reset_slots()
         self.pager = PagedKVManager(self.num_pages, self.page_size,
                                     self.max_slots, self.pages_per_slot)
         self._wire_host_tier()
         self.cache = self._shard_pool(
             PagePool.create(self.cfg, self.num_pages, self.page_size,
                             quantize_kv=self.quantize_kv))
-        self.lengths[:] = 0
-        self.active[:] = False
-        self.last_token[:] = 0
-        self.slot_req = [None] * self.max_slots
-        self.queue.clear()
-        if self._spec_index is not None:
-            self._spec_index.reset()
-        self._reset_adapters()
 
     # -- programs ------------------------------------------------------
 
@@ -1276,11 +1268,10 @@ class PagedInferenceEngine(InferenceEngine):
 
         def decode_for(view_pages: int):
             if view_pages not in self._decode_fns:
-                self._decode_fns[view_pages] = jax.jit(
+                self._decode_fns[view_pages] = self._jit_decode(
                     make_paged_decode_fn(cfg, self.decode_chunk,
                                          self.max_seq_len, self.page_size,
-                                         view_pages, self.num_pages),
-                    donate_argnums=(1,))
+                                         view_pages, self.num_pages))
                 obs_device.PROGRAMS.register(
                     "serve", f"decode_p{view_pages}",
                     self._decode_fns[view_pages])
@@ -1428,19 +1419,16 @@ class PagedInferenceEngine(InferenceEngine):
             zeros = np.zeros(self.max_slots, np.int32)
             tables = np.full((self.max_slots, self.pages_per_slot), trash,
                              np.int32)
-            akw = {**self._adapter_kwargs(),
+            akw = {**self._decode_kwargs(),
                    **self._grammar_warm_kwargs(
                        (self.max_slots, self.cfg.vocab_size))}
             for vp in self.view_page_buckets:
-                args = (jnp.asarray(tables), jnp.asarray(zeros),
-                        jnp.asarray(zeros),
-                        self._commit_key(jax.random.key(0)),
-                        jnp.zeros(self.max_slots, jnp.float32),
-                        jnp.zeros(self.max_slots, jnp.int32),
-                        jnp.ones(self.max_slots, jnp.float32),
-                        jnp.full(self.max_slots, -1, jnp.int32),
-                        jnp.zeros(self.max_slots, jnp.int32),
-                        jnp.zeros(self.max_slots, bool))
+                # No row alive.
+                args = (jnp.asarray(tables),
+                        *self._place_blocks(
+                            np.zeros_like(self._slot_ints),
+                            np.zeros_like(self._slot_floats)),
+                        self._commit_key(jax.random.key(0)))
                 with self._mesh_ctx():
                     _, _, self.cache, _ = run.program(
                         f"decode_p{vp}", f"p{vp}", self._decode_for(vp),
@@ -1724,6 +1712,9 @@ class PagedInferenceEngine(InferenceEngine):
         traffic that preempted it."""
         req = self.slot_req[slot]
         assert req is not None
+        # Its last tokens first: they may still wait for the next dispatch.
+        self.deliver_parked()
+        self._dev_blocks = None
         m = len(req.output_tokens)
         written = len(req.prompt_tokens) + max(0, m - 1)
         toks = (req.prompt_tokens + req.output_tokens)[:written]
@@ -1831,8 +1822,8 @@ class PagedInferenceEngine(InferenceEngine):
 
     # The decode chunk and the verify step are the dense engine's
     # (engine.py _decode_chunk_step, _verify_dispatch); only the program
-    # key (a page count), the page-table operand and the parking position
-    # of inactive rows differ.
+    # key (a page count) and the page-table operand differ (inactive rows
+    # park at the view's trash slot, whose writes land in the trash page).
 
     def _view_key(self, max_pos: int) -> tuple:
         vp = self._view_pages_for(max_pos)
@@ -1840,11 +1831,6 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _table_operands(self) -> tuple:
         return (self.pager.page_table,)
-
-    def _park_position(self) -> int:
-        # Inactive rows decode at position 0; their writes land in the
-        # trash page (free slots' page-table rows all point there).
-        return 0
 
     # -- observability -------------------------------------------------
 

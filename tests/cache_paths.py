@@ -100,7 +100,7 @@ def greedy_chunk(cfg, params, steps: int = 8):
     first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     decode = jax.jit(make_decode_fn(cfg, steps, MAX_LEN, MAX_LEN, view),
                      donate_argnums=(1,))
-    toks, valid, pool, _ = decode(
+    toks, valid, _, pool, _ = decode(
         params, pool, first, jnp.full(slots, 5, jnp.int32),
         jax.random.key(0), jnp.zeros(slots), jnp.zeros(slots, jnp.int32),
         jnp.ones(slots), jnp.full(slots, -1), jnp.full(slots, 100),

@@ -170,7 +170,7 @@ def test_parked_rows_and_padding_rows_leave_state_alone(model):
     # Decode, rows 1 and 3 parked: their state does not move by a bit.
     decode = jax.jit(make_decode_fn(cfg, 4, max_len, max_len, max_len))
     alive = jnp.array([True, False, True, False])
-    _, valid, after, _ = decode(
+    _, valid, _, after, _ = decode(
         params, pool, jnp.array([5, 6, 7, 8]), jnp.array([10, 0, 3, 0]),
         key, jnp.zeros(4), jnp.zeros(4, jnp.int32), jnp.ones(4),
         jnp.full(4, -1), jnp.array([9, 0, 2, 0]), alive)
@@ -229,8 +229,8 @@ def test_a_row_not_alive_keeps_its_cache_across_a_chunk(model, donate, view):
     args = (jnp.array([5, 6, 7, 8]), jnp.array([10, 0, 3, 20]),
             jax.random.key(0), jnp.zeros(4), jnp.zeros(4, jnp.int32),
             jnp.ones(4), jnp.full(4, -1))
-    _, valid, after, _ = decode(params, pool, *args,
-                                jnp.array([99, 0, 2, 99]), alive)
+    _, valid, _, after, _ = decode(params, pool, *args,
+                                   jnp.array([99, 0, 2, 99]), alive)
     assert np.asarray(valid).sum(0).tolist() == [8, 0, 2, 8]
     for leaf in ("state", "conv"):
         new, old = np.asarray(getattr(after, leaf)), getattr(before, leaf)
@@ -248,8 +248,8 @@ def test_a_row_not_alive_keeps_its_cache_across_a_chunk(model, donate, view):
     # Row 2 after it died: the same as a chunk that ends where it died.
     pool2 = jax.tree.map(jnp.asarray, before)
     short = jax.jit(make_decode_fn(cfg, 2, max_len, max_len, view))
-    _, _, ended, _ = short(params, pool2, *args,
-                           jnp.array([99, 0, 2, 99]), alive)
+    _, _, _, ended, _ = short(params, pool2, *args,
+                              jnp.array([99, 0, 2, 99]), alive)
     for leaf in ("state", "conv"):
         assert np.array_equal(np.asarray(getattr(after, leaf))[:, 2],
                               np.asarray(getattr(ended, leaf))[:, 2]), leaf

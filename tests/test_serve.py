@@ -308,33 +308,38 @@ def test_http_streaming_sse():
 
 
 @pytest.mark.slow
-def test_engine_chunked_decode_matches_single_step():
+@pytest.mark.parametrize("chunk", (3, 4, 8))
+def test_engine_chunked_decode_matches_single_step(chunk):
     """decode_chunk>1 (the TPU default: K scan steps per host round-trip)
     must emit token-for-token what chunk=1 stepping emits — including
-    requests that hit EOS or max_tokens MID-chunk (device liveness mask)."""
+    requests that hit EOS or max_tokens MID-chunk (device liveness mask) —
+    and hand every token over in order (tests/test_decode_deferred.py has
+    the order of a tick)."""
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
     prompts = [[5, 9, 17], [3, 4, 5, 6, 7, 8, 9, 10], [42]]
     expect = {tuple(p): greedy_rollout(cfg, params, p, 11) for p in prompts}
     eos = expect[(5, 9, 17)][4]  # force a mid-chunk stop for request 0
 
-    for chunk in (3, 4, 8):
-        engine = InferenceEngine(cfg, params, max_slots=4,
-                                 decode_chunk=chunk)
-        reqs = [Request(prompt_tokens=list(p), max_tokens=n,
-                        temperature=0.0, eos_id=e)
-                for p, n, e in [(prompts[0], 11, eos),
-                                (prompts[1], 7, None),
-                                (prompts[2], 11, None)]]
-        engine.generate(reqs)
-        full = expect[tuple(prompts[0])]
-        stop_at = full.index(eos) + 1 if eos in full else 11
-        assert reqs[0].output_tokens == full[:stop_at]
-        if eos in full:
-            assert reqs[0].finish_reason == "stop"
-        assert reqs[1].output_tokens == expect[tuple(prompts[1])][:7]
-        assert reqs[1].finish_reason == "length"
-        assert reqs[2].output_tokens == expect[tuple(prompts[2])]
+    engine = InferenceEngine(cfg, params, max_slots=4, decode_chunk=chunk)
+    reqs = [Request(prompt_tokens=list(p), max_tokens=n,
+                    temperature=0.0, eos_id=e)
+            for p, n, e in [(prompts[0], 11, eos),
+                            (prompts[1], 7, None),
+                            (prompts[2], 11, None)]]
+    streamed = [[] for _ in reqs]
+    for r, out in zip(reqs, streamed):
+        r.on_token = out.append
+    engine.generate(reqs)
+    full = expect[tuple(prompts[0])]
+    stop_at = full.index(eos) + 1 if eos in full else 11
+    assert reqs[0].output_tokens == full[:stop_at]
+    if eos in full:
+        assert reqs[0].finish_reason == "stop"
+    assert reqs[1].output_tokens == expect[tuple(prompts[1])][:7]
+    assert reqs[1].finish_reason == "length"
+    assert reqs[2].output_tokens == expect[tuple(prompts[2])]
+    assert streamed == [r.output_tokens for r in reqs]
 
 
 def test_engine_chunked_decode_capacity_bound():

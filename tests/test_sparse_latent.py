@@ -281,7 +281,7 @@ def test_chunked_decode_program_serves_the_references_best_token():
     served = [[int(t)] for t in first]
     decode = jax.jit(make_decode_fn(cfg, chunk, max_len, max_len, max_len))
     remaining = jnp.asarray([9, 3], jnp.int32)   # row 1 stops mid-chunk
-    out, valid, pool, rng, (counts, hits) = decode(
+    out, valid, _, pool, rng, (counts, hits) = decode(
         p, pool, first, jnp.asarray([len(s) for s in prompts], jnp.int32),
         rng, zeros, zeros.astype(jnp.int32), ones,
         jnp.full(slots, -1, jnp.int32), remaining, jnp.ones(slots, bool))
