@@ -167,12 +167,14 @@ def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
     """(dtype, dims) -> the largest in-place update allowed there, for a
     KVCache (or its ShapeDtypeStructs): each leaf whole, and one layer of
     k / v / the scales / the latent. A token-sized write is anything under a layer of
-    K/V; the recurrent leaves change a whole layer at a time."""
+    K/V; the recurrent leaves change a whole layer at a time. Of a window
+    layer's ring leaves only the whole leaf counts: one layer of a ring is
+    what a decode step reads (a window and a margin long, by design)."""
     names = {"bfloat16": "bf16", "float32": "f32", "float16": "f16",
              "int8": "s8"}
     shapes: Dict[Tuple[str, Tuple[int, ...]], int] = {}
     for field in ("k", "v", "k_scale", "v_scale", "state", "conv",
-                  "latent"):
+                  "latent", "ring_k", "ring_v"):
         leaf = getattr(cache, field)
         if leaf is None or 0 in leaf.shape:   # a latent cache's empty k, v
             continue
@@ -184,6 +186,8 @@ def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
         layer = math.prod(dims[1:])
         if field in ("state", "conv"):
             shapes[(dtype, dims)] = layer
+        elif field in ("ring_k", "ring_v"):
+            shapes[(dtype, dims)] = layer - 1
         else:
             shapes[(dtype, dims)] = layer - 1
             shapes[(dtype, dims[1:])] = layer - 1

@@ -67,6 +67,7 @@ class AuditSettings:
     # leaves (`state`, `conv`) and the token mask (docs/hybrid-models.md).
     hybrid_config: str = "debug-hybrid"
     sparse_latent_config: str = "debug-sparse-latent"
+    window_full_config: str = "debug-window-full"
     max_slots: int = 2
     decode_chunk: int = 2
     # Speculative verify window (serve/engine.py make_verify_fn): the
@@ -628,6 +629,31 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
                              views_s[-1]),
          "args": [params_s, pool_s] + decode_args[2:],
          "signatures": len(views_s)},
+    ]
+    # Window layers with a sink and a ring cache beside full layers of
+    # another KV head count: the same two factories once more (the ring
+    # leaves' write with dropped tokens and their splice, the ring read
+    # under the age mask, a stack a position of the period). The engine
+    # refuses the prefix, verify, adapter and paged variants here too
+    # (docs/window-full-models.md).
+    cfg_w = get_config(settings.window_full_config, moe_experts_held=8)
+    params_w = jax.eval_shape(functools.partial(init_params, cfg_w), key)
+    pool_w = jax.eval_shape(lambda: KVCache.create(
+        cfg_w, slots, cfg_w.max_seq_len, trash_slot=True))
+    buckets_w = _buckets(cfg_w.max_seq_len)
+    views_w = view_buckets_for(cfg_w.max_seq_len)
+    specs += [
+        {"component": "serve", "name": "window_full_prefill",
+         "fn": make_prefill_fn(cfg_w, cfg_w.max_seq_len + 1),
+         "args": [params_w, pool_w] + prefill_args(
+             rows_set[-1], buckets_w[-1])[2:],
+         "signatures": len(buckets_w) * len(rows_set)},
+        {"component": "serve", "name": "window_full_decode",
+         "fn": packed_decode(cfg_w, settings.decode_chunk,
+                             cfg_w.max_seq_len, cfg_w.max_seq_len,
+                             views_w[-1]),
+         "args": [params_w, pool_w] + decode_args[2:],
+         "signatures": len(views_w)},
     ]
     return specs
 

@@ -15,7 +15,7 @@ logits/softmax.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
 
@@ -53,10 +53,27 @@ def resolve_collective_matmul_param(params: dict) -> Optional[str]:
 
 
 # Token-mixer kinds a layer pattern may name (ModelConfig.layer_types).
-LAYER_KINDS = ("full_attention", "linear_attention", "latent_attention")
+LAYER_KINDS = ("full_attention", "linear_attention", "latent_attention",
+               "sliding_attention")
 # The kinds whose layers are the period's ONE stack params["layers"] (keys
-# and values, or a latent, a token): exactly one of them a period.
+# and values, or a latent, a token): exactly one of them a period. Every
+# other layer of a period (linear_attention, sliding_attention) has a stack
+# a position of its kind (params["linear_layers"], params["window_layers"]).
 ATTENTION_KINDS = ("full_attention", "latent_attention")
+# Slots a window layer's ring cache has beyond its window: one dispatch may
+# write RING_MARGIN + 1 tokens of a row before its first query reads
+# (transformer.KVCache). A decode step writes one.
+RING_MARGIN = 8
+
+
+class AttnShape(NamedTuple):
+    """What per-head attention layers of one kind differ in."""
+    kv_heads: int
+    rope_theta: float
+    sink: bool          # a learned logit a query head beside the keys'
+    window: int         # 0 = every earlier key
+
+
 MOE_ROUTERS = ("softmax", "sigmoid")
 
 
@@ -96,6 +113,11 @@ class ModelConfig:
     # moe_routed_scale.
     moe_router: str = "softmax"
     moe_router_bias: bool = False     # the selection bias (sigmoid router)
+    # Standard deviation of the selection bias in SEEDED weights (a
+    # checkpoint brings its own). In score units: at top-8 of 256 a bias of
+    # 0.05 is 0.43 logits, a factor 2.4 in an expert's popularity a
+    # standard deviation, which no trained, load-balancing bias has.
+    moe_router_bias_std: float = 0.05
     moe_routed_scale: float = 1.0
     # Width of an expert (0 = intermediate_size) and how many shared
     # experts run on every token beside the routed ones (one dense gated
@@ -138,7 +160,25 @@ class ModelConfig:
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
+    # A head's value width. Per-head attention (full_attention,
+    # sliding_attention): 0 = head_dim; keys and queries stay head_dim wide.
     v_head_dim: int = 0
+
+    # Per-head attention beyond one shape for every layer
+    # (docs/window-full-models.md). rotary_dim: the first rotary_dim of a
+    # head's head_dim dimensions rotate, the rest pass (0 = all of them).
+    # attn_value_scale multiplies the projected values.
+    rotary_dim: int = 0
+    attn_value_scale: float = 1.0
+    # Layer kind "sliding_attention": a query at position t sees the keys
+    # j with 0 <= t - j < sliding_window. Such layers may differ from the
+    # full ones in KV heads and rotary base (0 = as the full layers), and
+    # may have a sink: one learned logit a query head that takes weight in
+    # the softmax and gives no value.
+    sliding_window: int = 0
+    sliding_num_kv_heads: int = 0
+    sliding_rope_theta: float = 0.0
+    sliding_sink: bool = False
 
     # Block structure
     parallel_block: bool = False      # falcon/gpt-neox parallel attn+mlp
@@ -270,7 +310,9 @@ class ModelConfig:
             raise ValueError(
                 "a period of the layer pattern holds exactly one "
                 "full_attention or latent_attention layer (params['layers'] "
-                f"is one stack, scanned a period a step); got {kinds}")
+                "is that layer's stack, scanned a period a step) beside any "
+                "number of linear_attention and sliding_attention layers; "
+                f"got {kinds}")
         if (self.num_layers - self.leading_dense_layers) % len(kinds) \
                 or self.leading_dense_layers >= self.num_layers:
             raise ValueError(
@@ -278,10 +320,21 @@ class ModelConfig:
                 f"{self.leading_dense_layers} leading layers is not a whole "
                 f"number of periods of the layer pattern (length "
                 f"{len(kinds)})")
-        if self.leading_dense_layers and len(kinds) > 1:
+        if "sliding_attention" in kinds:
+            if self.sliding_window < 1:
+                raise ValueError(
+                    "sliding_attention layers need sliding_window >= 1")
+            if "latent_attention" in kinds:
+                raise ValueError(
+                    "sliding_attention layers are per-head attention; "
+                    "beside latent_attention they have no form")
+            if self.num_heads % self.attn_shape("sliding_attention").kv_heads:
+                raise ValueError(
+                    "sliding_num_kv_heads does not divide num_heads")
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.head_dim:
             raise ValueError(
-                "leading_dense_layers are layers of the period's one "
-                "attention kind; a longer pattern has no leading form")
+                f"rotary_dim {self.rotary_dim} is not an even number of a "
+                f"head's {self.head_dim} dimensions")
         if "latent_attention" in kinds and not (
                 self.kv_lora_rank and self.qk_nope_head_dim
                 and self.qk_rope_head_dim and self.v_head_dim):
@@ -332,7 +385,7 @@ class ModelConfig:
 
     def layers_of(self, kind: str) -> int:
         """How many of the model's layers are of this kind (the leading
-        layers are of the period's attention kind)."""
+        layers are of the kind of params["layers"]: attention_kind)."""
         n = self.num_periods * self.layer_pattern.count(kind)
         if kind == self.attention_kind:
             n += self.leading_dense_layers
@@ -340,8 +393,40 @@ class ModelConfig:
 
     @property
     def attention_kind(self) -> str:
-        """The kind of the period's one attention layer."""
+        """The kind of the period's one layer in params["layers"]."""
         return next(k for k in self.layer_pattern if k in ATTENTION_KINDS)
+
+    @property
+    def has_window(self) -> bool:
+        """Some layer attends a sliding window and caches a ring."""
+        return "sliding_attention" in self.layer_pattern
+
+    @property
+    def ring_len(self) -> int:
+        """Slots a row of a window layer's ring cache has."""
+        return self.sliding_window + RING_MARGIN
+
+    def attn_shape(self, kind: str) -> AttnShape:
+        """KV heads, rotary base, sink and window of the per-head attention
+        layers of `kind`."""
+        if kind == "sliding_attention":
+            return AttnShape(self.sliding_num_kv_heads or self.num_kv_heads,
+                             self.sliding_rope_theta or self.rope_theta,
+                             self.sliding_sink, self.sliding_window)
+        return AttnShape(self.num_kv_heads, self.rope_theta, False, 0)
+
+    @property
+    def value_head_dim(self) -> int:
+        """A head's value width in per-head attention (a latent model's
+        v_head_dim is its latent attention's; it has no per-head layer)."""
+        if self.latent_cache:
+            return self.head_dim
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def o_dim(self) -> int:
+        """What per-head attention hands its output projection."""
+        return self.num_heads * self.value_head_dim
 
     @property
     def latent_cache(self) -> bool:
@@ -424,21 +509,40 @@ class ModelConfig:
                 + (self._ffn_params(self.moe_width * self.moe_shared_experts)
                    if self.moe_shared_experts else 0))
 
-    def _attn_params(self) -> int:
-        h = self.hidden_size
-        if self.latent_cache:
-            H, r = self.num_heads, self.kv_lora_rank
+    def _attn_params(self, kind: Optional[str] = None) -> int:
+        """Attention parameters of one layer of `kind` (None: the kind of
+        params["layers"])."""
+        kind = kind or self.attention_kind
+        if kind == "latent_attention":
+            h, H, r = self.hidden_size, self.num_heads, self.kv_lora_rank
             return (h * H * self.q_head_dim + h * self.latent_width
                     + r * H * (self.qk_nope_head_dim + self.v_head_dim)
                     + H * self.v_head_dim * h
                     + (self.q_head_dim if self.qk_norm else 0) + r)
-        attn = h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h
+        return self._attn_matrices(kind) + self._attn_extras(kind)
+
+    def _attn_matrices(self, kind: str) -> int:
+        """wq, wk, wv, wo of a per-head attention layer: queries and keys
+        head_dim wide, values and the output projection's input
+        value_head_dim."""
+        kv = self.attn_shape(kind).kv_heads
+        return self.hidden_size * (
+            self.q_dim + kv * (self.head_dim + self.value_head_dim)
+            + self.o_dim)
+
+    def _attn_extras(self, kind: str) -> int:
+        """Per-head attention parameters that are no matrix (biases, norm
+        scales, sinks): in num_params, not in flops_per_token."""
+        shape = self.attn_shape(kind)
+        k_dim = shape.kv_heads * self.head_dim
+        n = self.num_heads if shape.sink else 0
         if self.attn_bias:
-            attn += self.q_dim + 2 * self.kv_dim + h
+            n += (self.q_dim + k_dim
+                  + shape.kv_heads * self.value_head_dim + self.hidden_size)
         if self.qk_norm:
-            attn += (2 * self.head_dim if self.qk_norm_width == "head"
-                     else self.q_dim + self.kv_dim)
-        return attn
+            n += (2 * self.head_dim if self.qk_norm_width == "head"
+                  else self.q_dim + k_dim)
+        return n
 
     @property
     def num_params(self) -> int:
@@ -471,7 +575,9 @@ class ModelConfig:
                 + lead * (attn + dense + norms_per_layer)
                 + (self.layers_of(self.attention_kind) - lead)
                 * (attn + rest)
-                + self.layers_of("linear_attention") * (linear + rest))
+                + self.layers_of("linear_attention") * (linear + rest)
+                + self.layers_of("sliding_attention")
+                * (self._attn_params("sliding_attention") + rest))
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Forward-pass matmul FLOPs per token (2*N plus attention quadratic).
@@ -488,9 +594,18 @@ class ModelConfig:
             attn_scores = 2 * s * self.num_heads * (self.q_head_dim
                                                     + self.v_head_dim)
         else:
-            attn_proj = 2 * (h * self.q_dim + 2 * h * self.kv_dim
-                             + self.q_dim * h)
-            attn_scores = 2 * 2 * s * self.q_dim  # QK^T and PV, per token
+            # QK^T at the key width and PV at the value width, per token.
+            attn_proj = 2 * self._attn_matrices(self.attention_kind)
+            attn_scores = 2 * s * self.num_heads * (self.head_dim
+                                                    + self.value_head_dim)
+        # A window layer's token sees at most its window, whatever the
+        # context.
+        sliding = 0.0
+        if self.has_window:
+            sliding = (
+                2 * self._attn_matrices("sliding_attention")
+                + 2 * min(s, self.sliding_window) * self.num_heads
+                * (self.head_dim + self.value_head_dim))
         gates = 2 if self.gated_mlp else 1
         dense = 2 * (gates + 1) * h * self.intermediate_size
         mlp = dense
@@ -514,7 +629,8 @@ class ModelConfig:
             lead * (attn_proj + attn_scores + dense)
             + (self.layers_of(self.attention_kind) - lead)
             * (attn_proj + attn_scores + mlp)
-            + self.layers_of("linear_attention") * (linear + mlp) + head)
+            + self.layers_of("linear_attention") * (linear + mlp)
+            + self.layers_of("sliding_attention") * (sliding + mlp) + head)
 
 
 def _llama(name, v=32000, h=4096, i=11008, l=32, q=32, kv=32, d=128, s=4096,
@@ -611,6 +727,35 @@ def _sarvam_mla(name, v=262144, h=4096, i=16384, l=32, q=64, s=131072,
     )
 
 
+def _mimo_v2(name, v=152576, h=4096, i=16384, periods=7, q=64, kv=4,
+             swa_kv=8, d=192, vd=128, rot=64, s=262144, window=128,
+             windows_a_period=5, experts=256, top_k=8, moe_i=2048):
+    # Window layers (a sink, KV heads and rotary base of their own, a ring
+    # cache) beside full layers, windows_a_period : 1; keys wider than
+    # values; a rotary over part of a head; one leading full-attention
+    # layer with a dense FFN, then sparse layers: sigmoid router with a
+    # selection bias, no shared expert (docs/window-full-models.md). The
+    # published 48 layers are F W W W W F then 7 x (W W W W W F): the first
+    # period is one window layer short, which a repeated pattern cannot
+    # say, so the preset is the leading layer and the seven whole periods
+    # (43 layers).
+    return ModelConfig(
+        name=name, vocab_size=v, hidden_size=h, intermediate_size=i,
+        num_layers=1 + periods * (windows_a_period + 1), num_heads=q,
+        num_kv_heads=kv, head_dim=d, v_head_dim=vd, rotary_dim=rot,
+        attn_value_scale=0.707, max_seq_len=s, norm_type="rmsnorm",
+        norm_eps=1e-5, gated_mlp=True, activation="silu",
+        position_type="rope", rope_theta=5000000.0,
+        layer_types=("sliding_attention",) * windows_a_period
+        + ("full_attention",),
+        sliding_window=window, sliding_num_kv_heads=swa_kv,
+        sliding_rope_theta=10000.0, sliding_sink=True,
+        leading_dense_layers=1, moe_num_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_i, moe_router="sigmoid",
+        moe_router_bias=True, moe_router_bias_std=0.01, moe_routed_scale=1.0,
+    )
+
+
 # Registry mirrors the reference's documented example configs
 # (reference: examples/ tree — llama2-7b, llama2-70b, falcon-7b/40b,
 # facebook-opt-125m) plus debug sizes for tests/benchmarks.
@@ -647,6 +792,10 @@ CONFIGS = {
     # Latent attention + sparse experts + a leading dense layer
     # (docs/sparse-latent-models.md)
     "sarvam-105b": _sarvam_mla("sarvam-105b"),
+    # Window layers with a sink beside full layers of another KV head
+    # count, 192-wide keys on 128-wide values, 256 routed experts
+    # (docs/window-full-models.md)
+    "mimo-v2-flash": _mimo_v2("mimo-v2-flash"),
     # GPT-2 (fused-qkv Conv1D checkpoints; learned positions)
     "gpt2": _gpt2("gpt2"),
     "gpt2-xl": _gpt2("gpt2-xl", h=1600, i=6400, l=48, q=25),
@@ -662,6 +811,14 @@ CONFIGS = {
         "debug-sparse-latent", v=512, h=128, i=384, l=3, q=4, s=256,
         nope=32, rope=16, vd=32, rank=64, experts=16, top_k=4, moe_i=64,
         yarn=(4.0, 64, 32.0, 1.0, 1.0, 1.0)),
+    # The same mechanisms at the published RATIOS and toy widths: 1 dense
+    # full layer + 1 period of (3 window, 1 full), window 8, KV heads 4 / 2
+    # under 8 query heads, keys 24 wide (8 rotate) on values 16 wide, 16
+    # experts (rbt check, tests)
+    "debug-window-full": _mimo_v2(
+        "debug-window-full", v=512, h=128, i=384, periods=1, q=8, kv=2,
+        swa_kv=4, d=24, vd=16, rot=8, s=256, window=8, windows_a_period=3,
+        experts=16, top_k=4, moe_i=64),
     "bench-1b": _llama("bench-1b", h=2048, i=5632, l=22, q=16, kv=16, d=128, s=2048),
     "bench-410m": _llama("bench-410m", h=1024, i=2816, l=24, q=16, kv=16, d=64, s=2048),
     # Same params/FLOPs as bench-410m but 8 heads x d128: wider MXU

@@ -322,6 +322,12 @@ def convert(cfg: ModelConfig, state_dict: StateDict,
     checkpoint peaks at ~one f32 layer above the packed size."""
     import jax
 
+    if cfg.has_window:
+        raise NotImplementedError(
+            "no converter lays a checkpoint out as params['window_layers'] "
+            "(a stack a position of the period, KV heads and a sink of "
+            "their own): models with sliding_attention layers run from "
+            "seeded weights only (docs/window-full-models.md)")
     params = CONVERTERS[family_of(cfg)](cfg, state_dict)
     params = jax.tree.map(lambda x: np.asarray(x, dtype=dtype), params)
     if quantize != "none":

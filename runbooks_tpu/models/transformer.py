@@ -173,43 +173,8 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
 
     layers: Params = {"attn": _init_attention(cfg, keys, L),
                       "ln1": _norm_params(cfg, (L,))}
-
-    if cfg.moe_num_experts:
-        assert cfg.gated_mlp, "MoE experts are gated (mixtral-style)"
-        # The router scores over ALL experts; the expert weights are those
-        # of the experts held here (models/moe.py).
-        E, held, m = cfg.moe_num_experts, cfg.moe_experts_here, cfg.moe_width
-        layers["moe"] = {
-            "router": (jax.random.normal(next(keys), (L, h, E))
-                       * h ** -0.5).astype(pd),
-            "wi_gate": _dense_init(next(keys), (L, held, h, m), pd, h),
-            "wi_up": _dense_init(next(keys), (L, held, h, m), pd, h),
-            "wo": _dense_init(next(keys), (L, held, m, h), pd, m),
-        }
-        if cfg.moe_shared_experts:
-            layers["moe"]["shared"] = _init_gated_mlp(
-                cfg, keys, L, m * cfg.moe_shared_experts)
-        if cfg.moe_router_bias:
-            # Not zero: a bias left out of the choice, or let into the
-            # gate weights, must change the logits.
-            layers["moe"]["router_bias"] = (
-                jax.random.normal(next(keys), (L, E)) * 0.05).astype(pd)
-    else:
-        if cfg.gated_mlp:
-            mlp: Params = _init_gated_mlp(cfg, keys, L,
-                                          cfg.intermediate_size)
-        else:
-            mlp = {"wo": _dense_init(next(keys),
-                                     (L, cfg.intermediate_size, h), pd,
-                                     cfg.intermediate_size),
-                   "wi": _dense_init(next(keys),
-                                     (L, h, cfg.intermediate_size), pd, h)}
-        if cfg.mlp_bias:
-            for k in ("wi_gate", "wi_up", "wi"):
-                if k in mlp:
-                    mlp["b" + k[1:]] = jnp.zeros((L, cfg.intermediate_size), pd)
-            mlp["bo"] = jnp.zeros((L, h), pd)
-        layers["mlp"] = mlp
+    ffn_key, ffn = _init_ffn(cfg, keys, L)
+    layers[ffn_key] = ffn
 
     if not (cfg.parallel_block and cfg.shared_layer_norm):
         layers["ln2"] = _norm_params(cfg, (L,))
@@ -217,9 +182,54 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     params["layers"] = layers
     if cfg.has_recurrent_state:
         params["linear_layers"] = _init_linear_layers(cfg, rng)
+    if cfg.has_window:
+        params["window_layers"] = _init_window_layers(cfg, rng)
     if cfg.leading_dense_layers:
         params["leading_layers"] = _init_leading_layers(cfg, rng)
     return params
+
+
+def _init_ffn(cfg: ModelConfig, keys, L: int):
+    """("moe" | "mlp", the FFN parameters of L layers, stacked): the
+    model's sparse layer if it has experts, else its dense MLP; one key a
+    matrix, in a fixed order."""
+    h, pd = cfg.hidden_size, cfg.parameter_dtype
+    if cfg.moe_num_experts:
+        assert cfg.gated_mlp, "MoE experts are gated (mixtral-style)"
+        # The router scores over ALL experts; the expert weights are those
+        # of the experts held here (models/moe.py).
+        E, held, m = cfg.moe_num_experts, cfg.moe_experts_here, cfg.moe_width
+        moe = {
+            "router": (jax.random.normal(next(keys), (L, h, E))
+                       * h ** -0.5).astype(pd),
+            "wi_gate": _dense_init(next(keys), (L, held, h, m), pd, h),
+            "wi_up": _dense_init(next(keys), (L, held, h, m), pd, h),
+            "wo": _dense_init(next(keys), (L, held, m, h), pd, m),
+        }
+        if cfg.moe_shared_experts:
+            moe["shared"] = _init_gated_mlp(
+                cfg, keys, L, m * cfg.moe_shared_experts)
+        if cfg.moe_router_bias:
+            # Not zero: a bias left out of the choice, or let into the
+            # gate weights, must change the logits.
+            moe["router_bias"] = (
+                jax.random.normal(next(keys), (L, E))
+                * cfg.moe_router_bias_std).astype(pd)
+        return "moe", moe
+    if cfg.gated_mlp:
+        mlp: Params = _init_gated_mlp(cfg, keys, L, cfg.intermediate_size)
+    else:
+        mlp = {"wo": _dense_init(next(keys),
+                                 (L, cfg.intermediate_size, h), pd,
+                                 cfg.intermediate_size),
+               "wi": _dense_init(next(keys),
+                                 (L, h, cfg.intermediate_size), pd, h)}
+    if cfg.mlp_bias:
+        for k in ("wi_gate", "wi_up", "wi"):
+            if k in mlp:
+                mlp["b" + k[1:]] = jnp.zeros((L, cfg.intermediate_size), pd)
+        mlp["bo"] = jnp.zeros((L, h), pd)
+    return "mlp", mlp
 
 
 def _init_gated_mlp(cfg: ModelConfig, keys, L: int, width: int) -> Params:
@@ -229,29 +239,38 @@ def _init_gated_mlp(cfg: ModelConfig, keys, L: int, width: int) -> Params:
             "wi_up": _dense_init(next(keys), (L, h, width), pd, h)}
 
 
-def _init_attention(cfg: ModelConfig, keys, L: int) -> Params:
-    """The attention parameters of L layers of the period's attention
-    kind, stacked; one key a matrix, in a fixed order."""
-    if cfg.latent_cache:
+def _init_attention(cfg: ModelConfig, keys, L: int,
+                    kind: Optional[str] = None) -> Params:
+    """The attention parameters of L layers of `kind` (None: the kind of
+    params["layers"]), stacked; one key a matrix, in a fixed order."""
+    kind = kind or cfg.attention_kind
+    if kind == "latent_attention":
         return _init_latent_attention(cfg, keys, L)
     h, pd = cfg.hidden_size, cfg.parameter_dtype
+    shape = cfg.attn_shape(kind)
+    k_dim = shape.kv_heads * cfg.head_dim
+    v_dim = shape.kv_heads * cfg.value_head_dim
     attn = {
         "wq": _dense_init(next(keys), (L, h, cfg.q_dim), pd, h),
-        "wk": _dense_init(next(keys), (L, h, cfg.kv_dim), pd, h),
-        "wv": _dense_init(next(keys), (L, h, cfg.kv_dim), pd, h),
-        "wo": _dense_init(next(keys), (L, cfg.q_dim, h), pd, cfg.q_dim),
+        "wk": _dense_init(next(keys), (L, h, k_dim), pd, h),
+        "wv": _dense_init(next(keys), (L, h, v_dim), pd, h),
+        "wo": _dense_init(next(keys), (L, cfg.o_dim, h), pd, cfg.o_dim),
     }
+    if shape.sink:
+        # Not zero: a sink left out of the softmax must change the logits.
+        attn["sink"] = jax.random.normal(
+            next(keys), (L, cfg.num_heads)).astype(pd)
     if cfg.attn_bias:
         attn["bq"] = jnp.zeros((L, cfg.q_dim), pd)
-        attn["bk"] = jnp.zeros((L, cfg.kv_dim), pd)
-        attn["bv"] = jnp.zeros((L, cfg.kv_dim), pd)
+        attn["bk"] = jnp.zeros((L, k_dim), pd)
+        attn["bv"] = jnp.zeros((L, v_dim), pd)
         attn["bo"] = jnp.zeros((L, h), pd)
     if cfg.qk_norm:
         full = cfg.qk_norm_width == "full"
         attn["q_norm"] = jnp.ones(
             (L, cfg.q_dim if full else cfg.head_dim), pd)
         attn["k_norm"] = jnp.ones(
-            (L, cfg.kv_dim if full else cfg.head_dim), pd)
+            (L, k_dim if full else cfg.head_dim), pd)
     return attn
 
 
@@ -290,6 +309,28 @@ def _init_leading_layers(cfg: ModelConfig, rng: jax.Array) -> Params:
     return {"attn": attn,
             "mlp": _init_gated_mlp(cfg, keys, n, cfg.intermediate_size),
             "ln1": _norm_params(cfg, (n,)), "ln2": _norm_params(cfg, (n,))}
+
+
+def _init_window_layers(cfg: ModelConfig, rng: jax.Array) -> list:
+    """The sliding-attention layers: a list with one tree for each position
+    such a layer has in the period, its leaves stacked [periods, …], as
+    params["linear_layers"] is. Every leaf is drawn once for all those
+    layers in layer order [L_win, …] (one key a leaf, from a split of their
+    own, fold_in 3) and dealt to the positions: window layer l is period
+    l // n, position l % n. Each has the model's FFN (sparse if it has
+    experts) and two norms."""
+    assert not (cfg.parallel_block and cfg.shared_layer_norm), \
+        "window layers are written for two norms a block"
+    L = cfg.layers_of("sliding_attention")
+    keys = iter(jax.random.split(jax.random.fold_in(rng, 3), 16))
+    in_layer_order: Params = {
+        "attn": _init_attention(cfg, keys, L, "sliding_attention"),
+        "ln1": _norm_params(cfg, (L,)), "ln2": _norm_params(cfg, (L,))}
+    ffn_key, ffn = _init_ffn(cfg, keys, L)
+    in_layer_order[ffn_key] = ffn
+    n = cfg.layer_pattern.count("sliding_attention")
+    return [jax.tree.map(lambda a: a[pos::n], in_layer_order)
+            for pos in range(n)]
 
 
 def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
@@ -422,6 +463,13 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             "ln1": norm1(("layers",)), "ln2": norm1(("layers",))}
         axes["linear_layers"] = [
             one_position] * cfg.layer_pattern.count("linear_attention")
+    if cfg.has_window:
+        one_position = {"attn": dict(attn), ffn_key: ffn_axes,
+                        "ln1": norm1(("layers",)), "ln2": norm1(("layers",))}
+        if cfg.sliding_sink:
+            one_position["attn"]["sink"] = ("layers", "heads")
+        axes["window_layers"] = [
+            one_position] * cfg.layer_pattern.count("sliding_attention")
     if cfg.leading_dense_layers:
         axes["leading_layers"] = {
             "attn": attn,
@@ -443,7 +491,9 @@ class KVCache:
     values for the full-attention layers and, for a hybrid, the recurrent
     state of the linear-attention layers beside them.
 
-    k, v: [full layers, batch, cache_len, num_kv_heads, head_dim]
+    k: [full layers, batch, cache_len, num_kv_heads, head_dim]
+    v: [full layers, batch, cache_len, num_kv_heads, value_head_dim] (the
+    value width is the key width unless the model says otherwise)
     index: [] int32 — number of tokens already written (same for the whole
     batch). Two write modes in ``forward``:
 
@@ -484,6 +534,33 @@ class KVCache:
              [c, k_r], with NO head axis. Slots, the trash slot and both
              write modes are those of k / v.
 
+    ring_k, ring_v (present when the layer pattern has sliding_attention
+    layers): those layers' keys and values, a RING a row and not cache_len
+    slots:
+      ring_k [window layers, batch, ring, sliding kv heads, head_dim]
+      ring_v [window layers, batch, ring, sliding kv heads, value_head_dim]
+    with ring = sliding_window + RING_MARGIN (models/config.py). The token
+    at position p lies at slot p mod ring. Nothing is stored about a slot:
+    a query at position t takes slot j to hold position t - a, a = (t - j)
+    mod ring its age, and sees it iff a < sliding_window and a <= t. That
+    is true of every slot it sees as long as (1) every token of the row
+    from max(0, t - window + 1) to t has been written by the row's
+    present occupant, and (2) nothing younger than t has overwritten one
+    of them: a dispatch that writes n tokens of a row before its first
+    query reads overwrites, for that query, the slots of ages ring - 1
+    down to ring - (n - 1), so n <= RING_MARGIN + 1 (a decode step writes
+    one; forward() sends a longer call another way, below). A slot the
+    rule hides may hold anything: a previous occupant's tokens (age > t),
+    a rejected or parked write (none is made: a token that is nobody's —
+    a bucket's padding, a parked decode row — is DROPPED from the write,
+    the ring has no trash slot). So a freed row's ring needs no clearing.
+    A call of more than RING_MARGIN + 1 tokens a row is taken as a row's
+    FIRST tokens (a prompt prefilled whole): a window layer then attends
+    the call's own keys and values, not the ring, and writes only the
+    row's last `ring` real tokens. Nothing that puts earlier tokens under
+    such a call (a spliced prefix, a second prefill chunk) is sound, and
+    the serving engine refuses it (docs/window-full-models.md).
+
     forward() carries every leaf whole through its layer scan (the carry,
     not xs/ys): a layer writes this call's tokens at [layer, row, slot],
     reads [layer, :, :view], and a linear-attention layer reads and writes
@@ -500,6 +577,8 @@ class KVCache:
     state: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None
+    ring_k: Optional[jax.Array] = None
+    ring_v: Optional[jax.Array] = None
 
     @classmethod
     def create(cls, cfg: ModelConfig, batch: int, max_len: int,
@@ -508,7 +587,20 @@ class KVCache:
         cache_len = max_len + 1 if trash_slot else max_len
         shape = (cfg.layers_of("full_attention"), batch, cache_len,
                  cfg.num_kv_heads, cfg.head_dim)
+        v_shape = shape[:-1] + (cfg.value_head_dim,)
         recurrent = {}
+        if cfg.has_window:
+            if quantize_kv:
+                raise NotImplementedError(
+                    "quantize_kv has no form for a window layer's ring "
+                    "cache yet (docs/window-full-models.md)")
+            ring = (cfg.layers_of("sliding_attention"), batch, cfg.ring_len,
+                    cfg.attn_shape("sliding_attention").kv_heads)
+            recurrent.update(
+                ring_k=jnp.zeros(ring + (cfg.head_dim,),
+                                 cfg.activation_dtype),
+                ring_v=jnp.zeros(ring + (cfg.value_head_dim,),
+                                 cfg.activation_dtype))
         if cfg.latent_cache:
             if quantize_kv:
                 raise NotImplementedError(
@@ -520,7 +612,7 @@ class KVCache:
                  cfg.latent_width), cfg.activation_dtype)
         if cfg.has_recurrent_state:
             n_lin = cfg.layers_of("linear_attention")
-            recurrent = dict(
+            recurrent.update(
                 state=jnp.zeros(
                     (n_lin, batch, cfg.linear_num_heads,
                      cfg.linear_key_head_dim, cfg.linear_value_head_dim),
@@ -531,7 +623,7 @@ class KVCache:
         if quantize_kv:
             return cls(
                 k=jnp.zeros(shape, jnp.int8),
-                v=jnp.zeros(shape, jnp.int8),
+                v=jnp.zeros(v_shape, jnp.int8),
                 index=jnp.zeros((), jnp.int32),
                 k_scale=jnp.zeros(shape[:-1], jnp.float32),
                 v_scale=jnp.zeros(shape[:-1], jnp.float32),
@@ -539,7 +631,7 @@ class KVCache:
             )
         return cls(
             k=jnp.zeros(shape, cfg.activation_dtype),
-            v=jnp.zeros(shape, cfg.activation_dtype),
+            v=jnp.zeros(v_shape, cfg.activation_dtype),
             index=jnp.zeros((), jnp.int32),
             **recurrent,
         )
@@ -730,11 +822,20 @@ def _attention_block(
     segment_ids: Optional[jax.Array],
     mask: Optional[jax.Array],
     bias: Optional[jax.Array],
-    layer_cache: Optional[tuple],      # see _write_layer_cache
+    layer_cache: Optional[tuple],  # _write_layer_cache, _window_attention
     adapter=None,
+    kind: str = "full_attention",
 ):
+    """Per-head attention of one layer of `kind` (full_attention or
+    sliding_attention: ModelConfig.attn_shape gives what they differ in).
+    Keys are head_dim wide and values value_head_dim; the first rotary_dim
+    dimensions of a query or key head rotate."""
     b, s, _ = x.shape
     ad = cfg.activation_dtype
+    shape = cfg.attn_shape(kind)
+    # Scope names: a window layer's parts are swa.*, inside `attn` like
+    # every token mixer's (docs/observability.md).
+    sc = "swa" if shape.window else "attn"
     ring_on = resolve_collective_matmul(cfg)
     ring_col = "ag" if ring_on else None
     ring_row = "rs" if ring_on else None
@@ -747,23 +848,30 @@ def _attention_block(
             y = y + p[bname].astype(ad)
         return y
 
+    def rope(t):
+        return apply_rope(t, positions, shape.rope_theta,
+                          rotary_dim=cfg.rotary_dim)
+
     # The named scopes are metadata only (op names in the HLO and in a
     # profiler capture; docs/observability.md): the compiled program and
     # its compile-cache key do not change.
-    with jax.named_scope("attn.qkv"):
+    with jax.named_scope(sc + ".qkv"):
         full_norm = cfg.qk_norm and cfg.qk_norm_width == "full"
 
-        def heads(y, scale, n):
+        def heads(y, scale, n, d=cfg.head_dim):
             # The "full" QK norm runs over the whole projection, before
             # the heads are split; the per-head one below, after.
             if full_norm and scale is not None:
                 y = rms_norm(y, scale, cfg.norm_eps)
-            return y.reshape(b, s, n, cfg.head_dim)
+            return y.reshape(b, s, n, d)
 
         q = heads(proj(p["wq"], "bq", "wq"), p.get("q_norm"), cfg.num_heads)
         k = heads(proj(p["wk"], "bk", "wk"), p.get("k_norm"),
-                  cfg.num_kv_heads)
-        v = heads(proj(p["wv"], "bv", "wv"), None, cfg.num_kv_heads)
+                  shape.kv_heads)
+        v = heads(proj(p["wv"], "bv", "wv"), None, shape.kv_heads,
+                  cfg.value_head_dim)
+        if cfg.attn_value_scale != 1.0:
+            v = v * jnp.asarray(cfg.attn_value_scale, ad)
         q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
         k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
         v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
@@ -771,12 +879,15 @@ def _attention_block(
             q = rms_norm(q, p["q_norm"], cfg.norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.position_type == "rope":
-        with jax.named_scope("attn.rope"):
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("swa.qkv" if shape.window else "attn.rope"):
+            q, k = rope(q), rope(k)
 
     new_layer_cache = None
-    if layer_cache is not None:
+    if shape.window:
+        out, new_layer_cache = _window_attention(
+            cfg, q, k, v, p.get("sink"), positions, segment_ids,
+            mask is None, layer_cache)
+    elif layer_cache is not None:
         with jax.named_scope("attn.kv_write"):
             k, v, new_layer_cache = _write_layer_cache(k, v, positions,
                                                        layer_cache, ad)
@@ -791,14 +902,101 @@ def _attention_block(
         with jax.named_scope("attn.core"):
             out = _dispatch_attention(cfg, q, k, v, positions, segment_ids,
                                       mask, bias)
-    with jax.named_scope("attn.out"):
-        out = out.reshape(b, s, cfg.q_dim)
+    with jax.named_scope(sc + ".out"):
+        out = out.reshape(b, s, cfg.o_dim)
         attn_ctx = out
         out = _matmul(out, p["wo"], ad, ring=ring_row, ring_bidir=bidir)
         out = _adapter_delta(adapter, "wo", attn_ctx, out, ad)
         if "bo" in p:
             out = out + p["bo"].astype(ad)
     return out, new_layer_cache
+
+
+def _window_attention(cfg: ModelConfig, q, k, v, sink, positions,
+                      segment_ids, flash: bool, ring_cache):
+    """The core of a sliding-attention layer: a query at position t sees
+    the keys j of its row (and document) with 0 <= t - j < sliding_window,
+    and the sink. Three forms of it, chosen by what the call is:
+
+    no cache            the call's own keys under a causal window mask
+                        (flash forward, or XLA);
+    cache, a call of    write the tokens into the ring, then read the ring
+    <= RING_MARGIN + 1  whole at KV-head width under the age mask (decode:
+    tokens a row        one token a row);
+    cache, a longer     a row's first tokens (KVCache): the call's own keys
+    call                as without a cache, and the row's last `ring` real
+                        tokens written to the ring, the rest to nowhere.
+
+    ``ring_cache``: None or (ring_k, ring_v, layer, parked): the WHOLE
+    ring leaves as forward's scan carries them, this layer's number in
+    them, and [b, s] bool naming the tokens that are nobody's (None: no
+    such token). ``flash``: the caller built no mask because the flash
+    forward needs none. Returns (out [b, s, heads, value_head_dim], None or
+    the (ring_k, ring_v) leaves)."""
+    from runbooks_tpu.models.config import RING_MARGIN
+
+    b, s = positions.shape
+    W, ring = cfg.sliding_window, cfg.ring_len
+    parked = None if ring_cache is None else ring_cache[3]
+    leaves = None
+    through_ring = ring_cache is not None and s <= RING_MARGIN + 1
+    if ring_cache is not None:
+        with jax.named_scope("swa.ring_write"):
+            ring_k, ring_v, layer, _ = ring_cache
+            keep = jnp.ones((b, s), bool) if parked is None else ~parked
+            if not through_ring:
+                # Of a long call only the row's last `ring` real tokens:
+                # earlier ones would share their slots.
+                last = jnp.max(jnp.where(keep, positions, -1), axis=-1,
+                               keepdims=True)
+                keep &= positions > last - ring
+            # A token that is not kept is written nowhere: slot `ring` is
+            # out of bounds, and the scatter drops it.
+            slot = jnp.where(keep, positions % ring, ring)
+            b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+            ring_k = ring_k.at[layer, b_idx, slot].set(k, mode="drop")
+            ring_v = ring_v.at[layer, b_idx, slot].set(v, mode="drop")
+            leaves = (ring_k, ring_v)
+    with jax.named_scope("swa.core"):
+        if through_ring:
+            def row(leaf):
+                return jax.lax.dynamic_slice(
+                    leaf, (layer, 0, 0, 0, 0), (1,) + leaf.shape[1:])[0]
+
+            age = (positions[:, :, None]
+                   - jnp.arange(ring, dtype=jnp.int32)) % ring  # [b, s, ring]
+            seen = (age < W) & (age <= positions[:, :, None])
+            if parked is not None:
+                seen &= ~parked[:, :, None]
+            out = dot_product_attention(q, row(ring_k), row(ring_v),
+                                        mask=seen[:, None], sink=sink)
+        else:
+            q_pos = kv_pos = positions
+            if parked is not None:
+                from runbooks_tpu.ops.flash_attention import PAD_POS
+
+                # Nobody's tokens see no key and are no key.
+                q_pos = jnp.where(parked, -1, positions)
+                kv_pos = jnp.where(parked, PAD_POS, positions)
+            if flash:
+                from runbooks_tpu.ops.flash_attention import flash_attention
+
+                # At the full layers' block sizes: with this kernel a key
+                # block of the window's order computes a fifth of the
+                # scores and is SLOWER (a grid step costs over a
+                # microsecond whatever it computes; PERF.md section 6,
+                # PR 32: 18.0 against 13.3 ms a call at [8, 2048]).
+                out = flash_attention(
+                    q, k, v, q_pos, kv_pos, segment_ids, segment_ids, True,
+                    None, cfg.flash_block_q, cfg.flash_block_k, window=W,
+                    sink=sink)
+            else:
+                seen = make_attention_mask(q_pos, kv_pos, segment_ids,
+                                           segment_ids, causal=True)
+                seen &= (q_pos[:, None, :, None]
+                         - kv_pos[:, None, None, :]) < W
+                out = dot_product_attention(q, k, v, mask=seen, sink=sink)
+    return out, leaves
 
 
 def _write_layer_cache(k, v, positions, layer_cache, ad):
@@ -1158,9 +1356,9 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
     the grouped LoRA injection (docs/multi-tenant-lora.md). ``kind`` names
     the token mixer (ModelConfig.layer_types). ``layer_cache`` holds the
     cache's whole leaves of the layer's kind and the layer's number in them
-    (_write_layer_cache, _linear_attention_block); the updated leaves come
-    back. ``token_mask`` says which tokens may change a linear-attention
-    layer's state, and which a sparse FFN routes at all."""
+    (_write_layer_cache, _linear_attention_block, _window_attention); the
+    updated leaves come back. ``token_mask`` says which tokens may change a
+    linear-attention layer's state, and which a sparse FFN routes at all."""
 
     def mixer(h_in):
         # The linear mixer runs inside the `attn` scope too, under inner
@@ -1176,7 +1374,8 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
                     layer_cache)
             return _attention_block(
                 cfg, layer["attn"], h_in, positions, segment_ids, mask, bias,
-                layer_cache, adapter=_adapter_group(adapter, "attn"))
+                layer_cache, adapter=_adapter_group(adapter, "attn"),
+                kind=kind)
 
     # Scopes: everything a layer does is under `block`; inside it `norm`,
     # `attn` (with its attn.* parts) and `ffn`; what is left directly
@@ -1310,6 +1509,8 @@ def forward(
         _check_recurrent_support(cfg, segment_ids, adapters)
     if cfg.latent_cache:
         _check_latent_support(cfg, adapters)
+    if cfg.has_window:
+        _check_window_support(cfg, adapters)
 
     if cache is not None and segment_ids is not None:
         raise NotImplementedError(
@@ -1416,10 +1617,11 @@ def forward(
     # number for K/V, period * n_lin + i for the recurrent leaves, which
     # lie in layer order — and the loop updates the buffers in place.
     n_lin = pattern.count("linear_attention")
+    n_win = pattern.count("sliding_attention")
     n_lead = cfg.leading_dense_layers
 
     def attention_layer(layer_params, x, kv, layer, adapter=None):
-        """One layer of the period's attention kind at index `layer` of
+        """One layer of the kind of params["layers"] at index `layer` of
         the cache's leaves (kv: the K/V leaves, or the latent leaf)."""
         layer_cache = None
         if cache is not None:
@@ -1432,43 +1634,78 @@ def forward(
         return x, new_kv, aux, counts
 
     layers, lin_layers = params["layers"], params.get("linear_layers")
-    expert_stacks = None
-    if cache is not None and "moe" in layers and not _expert_mesh():
-        # Serving: a sparse layer gets its expert matrices as the WHOLE
-        # stacks beside its number in them, not as the scan's slice of
-        # them (models/moe.grouped_matmul says why).
-        names = ("wi_gate", "wi_up", "wo")
-        expert_stacks = {k: layers["moe"][k] for k in names}
-        layers = {**layers, "moe": {k: v for k, v in layers["moe"].items()
-                                    if k not in names}}
+    win_layers = params.get("window_layers")
+    # Tokens a window layer's ring must not take: in position-scatter mode
+    # padding is parked at the K/V leaves' last slot, by position.
+    parked = (positions >= cache.k.shape[2] - 1
+              if scatter_mode and n_win else None)
+    names = ("wi_gate", "wi_up", "wo")
+    whole_stacks = (cache is not None and "moe" in layers
+                    and not _expert_mesh())
+
+    def without_stacks(tree):
+        """Serving: a sparse layer gets its expert matrices as the WHOLE
+        stacks beside its number in them, not as the scan's slice of them
+        (models/moe.grouped_matmul says why): (the tree without them,
+        them)."""
+        if not whole_stacks:
+            return tree, None
+        moe = tree["moe"]
+        return ({**tree, "moe": {k: v for k, v in moe.items()
+                                 if k not in names}},
+                {k: moe[k] for k in names})
+
+    def with_stacks(tree, stacks, period):
+        if stacks is None:
+            return tree
+        return {**tree, "moe": {**tree["moe"], **stacks},
+                "moe_layer": period - n_lead}
+
+    layers, expert_stacks = without_stacks(layers)
+    if win_layers is not None:
+        win_layers, win_stacks = zip(*map(without_stacks, win_layers))
 
     def scan_body(carry, scanned):
-        x, aux_sum, kv, rec = carry
-        layers, pool_layer, lin_layers, period = scanned
-        if expert_stacks is not None:
-            layers = {**layers, "moe": {**layers["moe"], **expert_stacks},
-                      "moe_layer": period - n_lead}
+        x, aux_sum, kv, rec, ring = carry
+        layers, pool_layer, lin_layers, win_layers, period = scanned
         adapter = None if apool is None else (pool_layer, aidx)
-        i = 0
-        counts = None
+        # The period's number among the scanned ones: where its layers lie
+        # in the leaves that hold no leading layer (recurrent, ring).
+        # (No cache: no number, and nothing reads one.)
+        rel = period - n_lead if n_lead and cache is not None else period
+        i = w = 0
+        counts = []
         for kind in pattern:
-            if kind != "linear_attention":
-                # The scan's layers lie behind the leading ones in the
-                # cache's leaves.
-                x, kv, aux, counts = attention_layer(
-                    layers, x, kv, period, adapter)
-            else:
+            if kind == "linear_attention":
                 layer_cache = (None if cache is None
-                               else (*rec, period * n_lin + i))
+                               else (*rec, rel * n_lin + i))
                 x, rec, aux, _ = blocks[kind](
                     cfg, lin_layers[i], x, positions, segment_ids, mask,
                     bias, layer_cache, None, token_mask)
                 i += 1
+            elif kind == "sliding_attention":
+                layer_cache = (None if cache is None
+                               else (*ring, rel * n_win + w, parked))
+                x, ring, aux, c = blocks[kind](
+                    cfg, with_stacks(win_layers[w], win_stacks[w], period),
+                    x, positions, segment_ids, mask, bias, layer_cache,
+                    None, token_mask)
+                counts.append(c)
+                w += 1
+            else:
+                x, kv, aux, c = attention_layer(
+                    with_stacks(layers, expert_stacks, period), x, kv,
+                    period, adapter)
+                counts.append(c)
             aux_sum = aux_sum + aux
-        return (x, aux_sum, kv, rec), counts
+        # A sparse model's assignment counts, a row a layer in the
+        # period's order (one row: as the one layer gave them).
+        counts = (None if counts[0] is None
+                  else counts[0] if len(counts) == 1 else jnp.stack(counts))
+        return (x, aux_sum, kv, rec, ring), counts
 
     aux_total = jnp.zeros((), jnp.float32)
-    kv = rec = None
+    kv = rec = ring = None
     if cache is not None:
         # k_scale/v_scale are None (empty pytrees) for an unquantized
         # cache, as state/conv are for a model without linear-attention
@@ -1477,6 +1714,7 @@ def forward(
         kv = ((cache.latent,) if cfg.latent_cache
               else (cache.k, cache.v, cache.k_scale, cache.v_scale))
         rec = (cache.state, cache.conv)
+        ring = (cache.ring_k, cache.ring_v)
     if n_lead:
         # Leading layers (dense FFN, parameter shapes of their own) run
         # unrolled before the scan, under the same block, at cache
@@ -1490,15 +1728,17 @@ def forward(
     moe_counts = None
     if cache is not None:
         # The adapter pool (leading L axis) rides the scan as xs when given.
-        xs = (layers, apool, lin_layers,
+        # The period's number as the K/V leaves count it: the scan's
+        # layers lie behind the leading ones there.
+        xs = (layers, apool, lin_layers, win_layers,
               n_lead + jnp.arange(cfg.num_periods, dtype=jnp.int32)
               if n_lead else jnp.arange(cfg.num_periods, dtype=jnp.int32))
-        init = (x, aux_total, kv, rec)
+        init = (x, aux_total, kv, rec, ring)
         # `layers`: the scan itself (slices of the stacked weights, what
         # the compiler hoists out of the loop); each layer is a `block`.
         with jax.named_scope("layers"):
-            (x, aux_total, new_kv, new_rec), moe_counts = jax.lax.scan(
-                scan_body, init, xs)
+            (x, aux_total, new_kv, new_rec, new_ring), moe_counts = \
+                jax.lax.scan(scan_body, init, xs)
         new_state, new_conv = new_rec
         new_index = cache.index if scatter_mode else cache.index + s
         if cfg.latent_cache:
@@ -1509,7 +1749,8 @@ def forward(
             new_k, new_v, new_ks, new_vs = new_kv
             new_cache = KVCache(k=new_k, v=new_v, index=new_index,
                                 k_scale=new_ks, v_scale=new_vs,
-                                state=new_state, conv=new_conv)
+                                state=new_state, conv=new_conv,
+                                ring_k=new_ring[0], ring_v=new_ring[1])
     else:
         from runbooks_tpu.parallel.sharding import _current_mesh
 
@@ -1545,9 +1786,9 @@ def forward(
                     n_microbatches=cfg.pipeline_microbatches or None)
         else:
             with jax.named_scope("layers"):
-                (x, aux_total, _, _), moe_counts = jax.lax.scan(
-                    scan_body, (x, aux_total, None, None),
-                    (layers, apool, lin_layers, None))
+                (x, aux_total, *_), moe_counts = jax.lax.scan(
+                    scan_body, (x, aux_total, None, None, None),
+                    (layers, apool, lin_layers, win_layers, None))
         new_cache = None
 
     with jax.named_scope("head"):
@@ -1558,7 +1799,8 @@ def forward(
             raise ValueError(
                 "with_moe_counts: this model has no sparse layer in its "
                 "period scan (or runs the pipeline path)")
-        extra += (moe_counts,)
+        # [periods, layers a period, held + 1] -> a row a sparse layer.
+        extra += (moe_counts.reshape(-1, moe_counts.shape[-1]),)
     if return_activations:
         act_rules = _act_embed_rules(resolve_collective_matmul(cfg))
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
@@ -1618,6 +1860,31 @@ def _check_latent_support(cfg: ModelConfig, adapters):
             raise NotImplementedError(
                 f"a {axis} mesh axis > 1 is not supported with "
                 f"latent-attention layers: {why}")
+
+
+def _check_window_support(cfg: ModelConfig, adapters):
+    """What a model with sliding-attention layers cannot do yet, by name
+    (docs/window-full-models.md)."""
+    if adapters is not None:
+        raise NotImplementedError(
+            "adapter pools target the attention projections of a "
+            "homogeneous stack; window layers have stacks of their own and "
+            "no pooled path")
+    from runbooks_tpu.parallel.sharding import _current_mesh
+
+    mesh = _current_mesh()
+    if mesh is None:
+        return
+    for axis, why in (
+            ("tensor", "the flash forward with a window or a sink is not "
+                       "launched per shard, and the ring leaves' layout by "
+                       "KV head is not held by a test"),
+            ("sequence", "ring attention knows no window and no sink"),
+            ("stage", "the pipeline's stages split one homogeneous stack")):
+        if int(mesh.shape.get(axis, 1)) > 1:
+            raise NotImplementedError(
+                f"a {axis} mesh axis > 1 is not supported with "
+                f"sliding-attention layers: {why}")
 
 
 def _check_recurrent_support(cfg: ModelConfig, segment_ids, adapters):

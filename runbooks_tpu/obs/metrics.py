@@ -90,6 +90,12 @@ CATALOG: Dict[str, str] = {
     # (ops/flash_attention.block_ranges; flash prefill only)
     "serve_flash_blocks_visited_total": "counter",
     "serve_flash_blocks_grid_total": "counter",
+    # The same for window layers, at their block sizes, beside the scores
+    # a window needs (docs/window-full-models.md)
+    "serve_window_blocks_visited_total": "counter",
+    "serve_window_blocks_grid_total": "counter",
+    "serve_window_scores_visited_total": "counter",
+    "serve_window_scores_needed_total": "counter",
     "serve_decode_dispatch_seconds": "histogram",
     # decode chunks by when their tokens were handed over, and by where
     # their per-slot operands came from (serve/engine._decode_chunk_step)
@@ -137,6 +143,7 @@ CATALOG: Dict[str, str] = {
     # (docs/sparse-latent-models.md): the moe families exist for a sparse
     # model only
     "serve_latent_cache_bytes": "gauge",
+    "serve_kv_ring_bytes": "gauge",
     "serve_moe_assignments_total": "counter",
     "serve_moe_expert_tokens_total": "counter",
     "serve_moe_expert_hits_total": "counter",

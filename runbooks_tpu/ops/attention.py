@@ -102,11 +102,14 @@ def dot_product_attention(
     bias: Optional[jax.Array] = None,       # [b|1, h, q_len, kv_len] additive
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
+    sink: Optional[jax.Array] = None,       # [num_heads] float
 ) -> jax.Array:
     """Reference attention. fp32 logits/softmax, output in q.dtype.
 
     Query head ``h * group + r`` attends KV head ``h``; K and V stay at
-    their own width (module docstring)."""
+    their own width (module docstring). ``sink``: one more logit a query
+    head beside the keys', which takes weight in the softmax and gives no
+    value (its column is dropped after the softmax)."""
     b, q_len, num_heads, head_dim = q.shape
     num_kv_heads = k.shape[2]
     group = num_heads // num_kv_heads
@@ -132,7 +135,17 @@ def dot_product_attention(
         mask = grouped(mask)
         logits = jnp.where(mask, logits, NEG_INF)
 
-    probs = jax.nn.softmax(logits, axis=-1)
+    if sink is not None:
+        # The sink as one more key column: the softmax's denominator
+        # holds it, the weighted sum of values does not. A fully-masked
+        # row gives it all the weight, and comes out 0.
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, num_kv_heads, group, 1, 1),
+            logits.shape[:-1] + (1,))
+        probs = jax.nn.softmax(
+            jnp.concatenate([logits, col], axis=-1), axis=-1)[..., :-1]
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
     # Fully-masked query rows (e.g. padding) softmax to uniform; zero them so
     # padding contributes nothing downstream.
     if mask is not None:

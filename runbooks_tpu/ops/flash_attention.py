@@ -85,7 +85,7 @@ def _interpret() -> bool:
 # ---------------------------------------------------------------------------
 
 def block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
-                 causal: bool):
+                 causal: bool, window: int = 0):
     """Which kv blocks each query block has to visit: ``(lo, hi)``, int32
     ``[b, q_blocks]``, the hull of the needed kv blocks of every batch row
     and query block; an empty range is ``lo = 0, hi = -1``.
@@ -99,7 +99,12 @@ def block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
     with them by (segment, position) in dictionary order, which a key and
     a query of one document keep (in a packed row a later block starts a
     document at position 0, below every query position, and is still not
-    needed). Every unmasked (query, key) pair satisfies these, whatever
+    needed). Window (``window`` > 0: a query at position t sees the keys j
+    with t - j < window, beside causality): the greatest valid key of j is
+    less than a window below the least valid query of i, by position over
+    all their valid entries whatever their segments (queries at negative
+    positions apart, which see nothing), so ``lo`` rises with the queries
+    as ``hi`` does. Every unmasked (query, key) pair satisfies these, whatever
     the layout (offset, non-monotone or repeated positions, sk != sq,
     lengths that are no multiple of a block), so the hull never drops a
     block that holds one, and the kernel's element-wise mask makes any
@@ -136,6 +141,13 @@ def block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
         q_ok, k_ok = q_ok & (qs != 0), k_ok & (ks != 0)
         (q_first, q_last), (k_first, k_last) = span(qs, q_ok), span(ks, k_ok)
     need = q_ok.any(axis=-1)[:, :, None] & k_ok.any(axis=-1)[:, None, :]
+    if window:
+        # A query parked at a negative position sees no key (the callers'
+        # convention for a token that is nobody's): it does not hold lo
+        # down.
+        k_max, q_min = pairs(span(kp, k_ok)[1],
+                             span(qp, q_ok & (qp >= 0))[0])
+        need &= k_max > q_min - window
     if q_seg is not None:
         k_lo, q_hi = pairs(k_first, q_last)
         k_hi, q_lo = pairs(k_last, q_first)
@@ -154,28 +166,56 @@ def block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
     return xp.where(hi < 0, 0, lo).astype(xp.int32), hi.astype(xp.int32)
 
 
+def grid_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
+                causal: bool, window: int = 0):
+    """``block_ranges`` as the forward's grid walks them: (lo, hi, steps),
+    ``steps`` the length of the grid's kv axis. Without a window that is
+    every kv block. A window bounds how many kv blocks a query block can
+    see — those that can hold the block_q + window - 1 consecutive
+    positions its queries see, whatever their alignment — so the grid
+    walks that many FROM lo and not the whole kv extent: a grid step costs
+    its overhead even where nothing is computed. Exact where a row's valid
+    queries and keys each hold consecutive positions at consecutive
+    indices (a prompt, a cache view, documents packed one behind the
+    other): the hull is then no longer than this. The clamp of hi keeps
+    any other layout finite, not exact."""
+    xp = np if isinstance(q_pos, np.ndarray) else jnp
+    sq, sk = q_pos.shape[1], kv_pos.shape[1]
+    block_q, block_k = min(block_q, sq), min(block_k, sk)
+    lo, hi = block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q, block_k,
+                          causal, window)
+    steps = -(-sk // block_k)
+    if window:
+        steps = min(steps, (block_q + window - 2) // block_k + 2)
+        hi = xp.minimum(hi, lo + steps - 1)
+    return lo, hi, steps
+
+
 def block_counts(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
-                 causal: bool):
+                 causal: bool, window: int = 0):
     """(visited, grid): how many (query block, kv block) pairs a head of
     the forward computes for these host arrays, and how many its grid has."""
-    lo, hi = block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q, block_k,
-                          causal)
-    sk = kv_pos.shape[1]
-    return (int(np.maximum(hi - lo + 1, 0).sum()),
-            lo.size * -(-sk // min(block_k, sk)))
+    lo, hi, steps = grid_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q,
+                                block_k, causal, window)
+    return int(np.maximum(hi - lo + 1, 0).sum()), lo.size * steps
 
 
 def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
                 q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
-                q_ref, k_ref, v_ref,
-                o_ref, lse_ref,
-                m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, use_segments: bool):
-    kv_idx = pl.program_id(3)
+                q_ref, k_ref, v_ref, *rest,
+                scale: float, causal: bool, use_segments: bool,
+                window: int = 0, has_sink: bool = False):
+    # rest: [sink_ref,] o_ref, lse_ref, m_scr, l_scr, acc_scr.
+    sink_ref = rest[0] if has_sink else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[-5:]
+    step = pl.program_id(3)
     lo = lo_ref[pl.program_id(0), pl.program_id(2)]
     hi = hi_ref[pl.program_id(0), pl.program_id(2)]
+    # With a window the grid walks blocks FROM lo (grid_ranges says how
+    # many), else every kv block.
+    kv_idx = step + lo if window else step
 
-    @pl.when(jnp.logical_and(hi < lo, kv_idx == 0))
+    @pl.when(jnp.logical_and(hi < lo, step == 0))
     def _nothing_to_see():
         # No query of this block sees any key (a bucket's padded tail).
         o_ref[0, 0] = jnp.zeros(o_ref.shape[2:], o_ref.dtype)
@@ -203,6 +243,8 @@ def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
         if causal:
             qp = q_pos_ref[0][:, :1]                          # [bq, 1]
             mask = jnp.logical_and(mask, kp <= qp)
+        if window:
+            mask = jnp.logical_and(mask, q_pos_ref[0][:, :1] - kp < window)
         if use_segments:
             qs = q_seg_ref[0][:, :1]
             ks = kv_seg_ref[0][:1, :]
@@ -229,9 +271,20 @@ def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
         @pl.when(kv_idx == hi)
         def _finalize():
             l = l_scr[:]
-            l_safe = jnp.where(l == 0.0, 1.0, l)          # fully-masked rows
-            o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
             m = m_scr[:]
+            acc = acc_scr[:]
+            if has_sink:
+                # The sink joins the softmax here, as one more logit of
+                # this head that gives no value: the running state moves
+                # to max(m, sink) and the sum takes its term.
+                sink = sink_ref[0][:1, :1]                    # [1, 1]
+                m_all = jnp.maximum(m, sink)
+                alpha = jnp.where(m <= NEG_INF, 0.0, jnp.exp(m - m_all))
+                l = alpha * l + jnp.exp(sink - m_all)
+                acc = acc * alpha
+                m = m_all
+            l_safe = jnp.where(l == 0.0, 1.0, l)          # fully-masked rows
+            o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
             lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(l_safe))  # [bq,1]
             lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
@@ -264,7 +317,8 @@ def flash_fwd_qside(q, q_pos, q_seg, block_q):
 
 
 def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
-               block_q, block_k, out_dtype=None, qside=None):
+               block_q, block_k, out_dtype=None, qside=None, window=0,
+               sink=None):
     b, sq, h, d = q.shape
     # Values may be narrower or wider than keys (latent attention expanded:
     # 192-wide q and k, 128-wide v): the accumulator and the output take
@@ -288,8 +342,8 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
     kv_pos_p = _pad_to(kv_pos.astype(jnp.int32), sk_p, 1, value=PAD_POS)
     kv_seg_p = (_pad_to(kv_seg.astype(jnp.int32), sk_p, 1, value=0)
                 if use_segments else jnp.zeros_like(kv_pos_p))
-    lo, hi = block_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q, block_k,
-                          causal)
+    lo, hi, kv_steps = grid_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q,
+                                   block_k, causal, window)
 
     def kv_block(bi, qi, ki, lo_ref, hi_ref):
         # Outside [lo, hi] the index repeats the nearest block inside it —
@@ -297,6 +351,8 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
         # DMA, and pl.when skips the compute. (An empty range points at
         # block 0.)
         first = lo_ref[bi, qi]
+        if window:
+            ki = ki + first
         return jnp.clip(ki, first, jnp.maximum(first, hi_ref[bi, qi]))
 
     def q_map(bi, hi, qi, ki, *_):
@@ -313,13 +369,21 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
         return (bi, 0, kv_block(bi, qi, ki, *ranges))
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, use_segments=use_segments)
+        _fwd_kernel, scale=scale, causal=causal, use_segments=use_segments,
+        window=window, has_sink=sink is not None)
+    sink_specs, sink_args = [], ()
+    if sink is not None:
+        # One tile a head, the layout note at the top of the file.
+        sink_specs = [pl.BlockSpec((1, SUBLANES, LANES),
+                                   lambda bi, hi, qi, ki, *_: (hi, 0, 0))]
+        sink_args = (jax.lax.broadcast_in_dim(
+            sink.astype(jnp.float32), (h, SUBLANES, LANES), (0,)),)
 
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                                # lo, hi
-            grid=(b, h, sq_p // block_q, sk_p // block_k),
+            grid=(b, h, sq_p // block_q, kv_steps),
             in_specs=[
                 pl.BlockSpec((1, block_q, LANES), qrow_map),      # q_pos
                 pl.BlockSpec((1, SUBLANES, block_k), krow_map),   # kv_pos
@@ -328,6 +392,7 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
                 pl.BlockSpec((1, 1, block_q, d), q_map),          # q
                 pl.BlockSpec((1, 1, block_k, d), kv_map),         # k
                 pl.BlockSpec((1, 1, block_k, dv), kv_map),        # v
+                *sink_specs,
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, dv), q_map),
@@ -345,7 +410,7 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
         ],
         interpret=_interpret(),
     )(lo, hi, q_pos_l, _bcast_sublanes(kv_pos_p),
-      q_seg_l, _bcast_sublanes(kv_seg_p), qT, kT, vT)
+      q_seg_l, _bcast_sublanes(kv_seg_p), qT, kT, vT, *sink_args)
 
     out = jnp.swapaxes(out[:, :, :sq], 1, 2)          # [b, sq, h, dv]
     return out, lse[:, :, :sq, 0]
@@ -545,8 +610,17 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     block_skip: bool = True,
+    window: int = 0,
+    sink: Optional[jax.Array] = None,  # [h] float
 ) -> jax.Array:
-    """The forward needs no hint: it visits the kv blocks its positions
+    """window > 0: a query at position t sees only keys j with t - j <
+    window (beside causality), the ranges follow it and the grid shrinks
+    to the blocks a window can span (_flash_fwd). sink: one more logit a
+    query head in the softmax, which takes weight and gives no value. Both
+    are the FORWARD's alone: differentiating such a call raises
+    WindowSinkBackward, and under a multi-device mesh it is refused.
+
+    The forward needs no hint: it visits the kv blocks its positions
     and segment ids say a query can see (block_ranges), whatever the layout.
 
     block_skip is the BACKWARD kernels' alone: they skip above-diagonal
@@ -571,6 +645,19 @@ def flash_attention(
     # producing kernel as a constant (the pallas call has no JVP rule);
     # the differentiable path runs through _flash_core's custom vjp, whose
     # q/k/v args carry the real tangents.
+    if window or sink is not None:
+        if _shard_plan(q, k) is not None:
+            raise NotImplementedError(
+                "flash attention with a window or a sink runs on one "
+                "device: its per-shard launch is not written")
+        with jax.named_scope("flash.fwd"):
+            out, _ = _flash_fwd(
+                jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
+                jax.lax.stop_gradient(v), q_positions, kv_positions,
+                q_segment_ids, kv_segment_ids, scale_v, causal, block_q,
+                block_k, window=window, sink=sink)
+        return _forward_only(out, q, k, v)
+
     def fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg):
         return _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg,
                           scale_v, causal, block_q, block_k)
@@ -593,6 +680,26 @@ def flash_attention(
 def _flash_core(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse,
                 causal, scale, block_q, block_k, block_skip):
     return out
+
+
+class WindowSinkBackward(NotImplementedError):
+    """The backward kernels know neither a window nor a sink."""
+
+
+@jax.custom_vjp
+def _forward_only(out, q, k, v):
+    return out
+
+
+def _forward_only_fwd(out, q, k, v):
+    raise WindowSinkBackward(
+        "flash attention's backward kernels mask by causality and segments "
+        "alone and normalise over the keys alone: a call with a window or "
+        "a sink has a forward only (train such layers on the XLA path, "
+        "attention_impl: xla)")
+
+
+_forward_only.defvjp(_forward_only_fwd, lambda res, g: res)
 
 
 class UnequalWidthsBackward(NotImplementedError):

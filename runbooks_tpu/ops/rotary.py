@@ -51,8 +51,16 @@ def rope_sin_cos(positions: jax.Array, head_dim: int, theta: float = 10000.0,
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-               yarn: tuple = ()) -> jax.Array:
-    """Apply RoPE. x: [batch, seq, heads, head_dim]; positions: [batch, seq]."""
+               yarn: tuple = (), rotary_dim: int = 0) -> jax.Array:
+    """Apply RoPE. x: [batch, seq, heads, head_dim]; positions: [batch, seq].
+    rotary_dim (0 = head_dim): only the first rotary_dim dimensions of a
+    head rotate, as a head of that width would (pairs (x_i,
+    x_{i + rotary_dim/2}), frequencies theta^(-2i/rotary_dim)); the rest
+    pass unchanged."""
+    if rotary_dim and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary_dim], positions, theta, yarn),
+             x[..., rotary_dim:]], axis=-1)
     dtype = x.dtype
     half = x.shape[-1] // 2
     sin, cos = rope_sin_cos(positions, x.shape[-1], theta, yarn)  # [b, s, half]

@@ -712,6 +712,11 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                       help_text="The latent (MLA) cache leaf, all slots "
                                 "(0 for per-head K/V): the part of "
                                 "serve_kv_pool_bytes with no head axis.")
+        reg.set_gauge("serve_kv_ring_bytes", occ.get("kv_ring_bytes", 0),
+                      help_text="Window layers' ring caches, all slots (0 "
+                                "without such layers): the part of "
+                                "serve_kv_pool_bytes that does not grow "
+                                "with max_seq_len.")
         moe = eng.moe_stats()
         if moe is not None:
             # Sparse layers (models/moe.py, docs/sparse-latent-models.md):

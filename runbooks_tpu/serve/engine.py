@@ -331,6 +331,16 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # sound for such a layer: InferenceEngine refuses speculation,
         # prefix registration, adapter pools and paging for these models.
         #
+        # Ring leaves (sliding-attention layers; pool leaves `ring_k` /
+        # `ring_v`, KVCache). A window layer attends this call's own keys
+        # (the prompt is prefilled whole), writes the row's last `ring`
+        # real tokens at position mod ring and drops the rest — padding
+        # too: a ring has no trash slot. The scratch row's ring is spliced
+        # into the pool at the slot like the K/V row. A slot of the ring
+        # is valid by its age against the row's length alone, so a
+        # previous occupant's tokens are hidden without clearing. A
+        # spliced prefix would lie UNDER the call's own keys: refused.
+        #
         # First-token sampling lives INSIDE the jit: an eager sampling
         # chain here compiled ~20 tiny programs at the first admission
         # that warmup never hit.
@@ -397,6 +407,14 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
                     new_latent = jax.lax.dynamic_update_slice_in_dim(
                         new_latent, cache1.latent[:, r:r + 1], slots[r],
                         axis=1)
+            new_ring = [pool.ring_k, pool.ring_v]
+            if pool.ring_k is not None:
+                for i, rows_ring in enumerate((cache1.ring_k,
+                                               cache1.ring_v)):
+                    for r in range(rows - 1, -1, -1):
+                        new_ring[i] = jax.lax.dynamic_update_slice_in_dim(
+                            new_ring[i], rows_ring[:, r:r + 1], slots[r],
+                            axis=1)
             new_state, new_conv = pool.state, pool.conv
             if new_state is not None:
                 with jax.named_scope("state_splice"):    # rule (a)
@@ -413,7 +431,8 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         new_pool = KVCache(k=new_k, v=new_v, index=pool.index,
                            k_scale=new_ks, v_scale=new_vs,
                            state=new_state, conv=new_conv,
-                           latent=new_latent)
+                           latent=new_latent, ring_k=new_ring[0],
+                           ring_v=new_ring[1])
         # A sparse model's programs return one thing more: (counts, hits).
         return (first, new_pool, rng, *map(dispatch_stats, moe))
 
@@ -676,6 +695,9 @@ class InferenceEngine:
     # A latent cache (MLA) has no head axis: pages are laid out and
     # sharded by KV head (serve/paging.py flips this too).
     _supports_latent_cache = True
+    # A window layer's ring is a row's own and a ring long: a page table
+    # maps positions to pages of one kind (serve/paging.py flips this).
+    _supports_window_ring = True
 
     def __init__(self, cfg: ModelConfig, params: Params, *, seed: int = 0,
                  mesh=None, tokenizer=None, **options):
@@ -772,6 +794,37 @@ class InferenceEngine:
                 "a tensor mesh axis > 1",
                 "the latent cache has no head axis to shard, and the "
                 "absorbed decode's head split is not written")
+        # What is not made sound for a ring leaf or two attention kinds
+        # (docs/window-full-models.md).
+        if options.speculative != "off":
+            self._refuse_window(
+                "speculative decoding",
+                "the verify forward writes K + 1 tokens a row before its "
+                "first query reads; the ring's margin would hold them, but "
+                "no test holds the [slots, K+1] path or its rollback "
+                "through a ring")
+        if options.adapter_pool > 0:
+            self._refuse_window(
+                "an adapter pool",
+                "pooled LoRA lanes target the attention projections of "
+                "one homogeneous stack; window layers have stacks of their "
+                "own")
+        if not self._supports_window_ring:
+            self._refuse_window(
+                "kv_paging: paged",
+                "a page table maps a row's positions to pages of one kind "
+                "and one KV head count; a ring is a row's own, a window "
+                "long, and has another head count")
+        if self.quantize_kv:
+            self._refuse_window(
+                "quantize_kv",
+                "the ring leaves have no int8 form (no scales beside them)")
+        if mesh is not None and int(mesh.shape.get("tensor", 1)) > 1:
+            self._refuse_window(
+                "a tensor mesh axis > 1",
+                "the flash forward with a window or a sink is not launched "
+                "per shard, and the ring leaves' layout by KV head is not "
+                "held by a test")
         if mesh is not None:
             import contextlib
 
@@ -1113,8 +1166,18 @@ class InferenceEngine:
                             conv=put(cache.conv,
                                      (None, "batch", None, None)),
                             latent=put(cache.latent,
-                                       (None, "batch", None, None)))
+                                       (None, "batch", None, None)),
+                            ring_k=put(cache.ring_k), ring_v=put(cache.ring_v))
         return cache
+
+    def _refuse_window(self, feature: str, why: str) -> None:
+        """Refuse, for a model with sliding-attention layers, a feature
+        that is not sound for a ring cache or for two kinds of attention
+        layer (docs/window-full-models.md)."""
+        if self.cfg.has_window:
+            raise ValueError(
+                f"{feature} is not supported for a model with sliding "
+                f"(window) attention layers: {why}")
 
     def _refuse_latent(self, feature: str, why: str) -> None:
         """Refuse, for a model with latent-attention layers, a feature
@@ -1437,6 +1500,12 @@ class InferenceEngine:
             "warm_prefix)",
             "a shared prefix is stored and spliced as K/V a head; the "
             "latent rows have no such path")
+        self._refuse_window(
+            "prefix registration (register_prefix, auto_prefix_chat, "
+            "warm_prefix)",
+            "a window layer prefills a prompt whole against its own keys; "
+            "a spliced prefix would lie under them, and its ring is not "
+            "stored")
 
     def _prefix_len_for(self, n: int, quantize: bool = False) -> int:
         """Usable prefix length for an n-token prompt. Explicit
@@ -1732,10 +1801,12 @@ class InferenceEngine:
         # Aggregate vs per-device bytes: nbytes is the LOGICAL pool size;
         # under a serving mesh each chip holds only its kv-head shard
         # (shard_local_nbytes reads the sharding metadata, no sync).
+        rings = [a for a in (self.cache.ring_k, self.cache.ring_v)
+                 if a is not None]
         arrays = [a for a in (self.cache.k, self.cache.v,
                               self.cache.k_scale, self.cache.v_scale,
                               self.cache.latent)
-                  if a is not None]
+                  if a is not None] + rings
         # Apart from the K/V pool: the recurrent state and conv tails of
         # linear-attention layers, fixed a slot whatever its tokens (0
         # for a model without such layers).
@@ -1753,6 +1824,9 @@ class InferenceEngine:
                 # The part of kv_pool_bytes that is a latent (MLA) leaf.
                 "latent_cache_bytes": (0 if self.cache.latent is None
                                        else int(self.cache.latent.nbytes)),
+                # ... and the part that is window layers' rings, whose size
+                # does not grow with max_seq_len.
+                "kv_ring_bytes": sum(int(a.nbytes) for a in rings),
                 "occupancy_ratio": (tokens / capacity) if capacity else 0.0}
 
     def memory_groups(self) -> dict:
@@ -2019,21 +2093,26 @@ class InferenceEngine:
     def _count_flash_blocks(self, bucket: int,
                             positions: np.ndarray) -> None:
         """Which share of the flash forward's grid a prefill dispatch
-        computes: one kernel call a row of the dispatch, which every
-        full-attention layer repeats. Counted on the host from the
-        positions the dispatch was given, by the function the kernel takes
-        its ranges from, as models/transformer._cached_attention hands them
-        over (parked tokens at -1 against one scratch row of keys)."""
+        computes, by kind of attention layer: one kernel call a row of the
+        dispatch, which every layer of the kind repeats. Counted on the
+        host from the positions the dispatch was given, by the function
+        the kernel takes its ranges from. Full layers: as
+        models/transformer._cached_attention hands them over (parked
+        tokens at -1 against one scratch row of keys). Window layers: as
+        _window_attention does (the call's own keys, parked ones padding,
+        the ranges of a window), beside the scores a window needs."""
         if not use_flash_cached_prefill(self.cfg, bucket):
             return
-        from runbooks_tpu.ops.flash_attention import block_counts
+        from runbooks_tpu.ops.flash_attention import PAD_POS, block_counts
 
+        cfg = self.cfg
         cache_len = self.max_seq_len + 1
+        parked = positions >= cache_len - 1
         visited, grid = block_counts(
-            np.where(positions >= cache_len - 1, -1, positions),
+            np.where(parked, -1, positions),
             np.broadcast_to(np.arange(cache_len, dtype=np.int32),
                             (positions.shape[0], cache_len)),
-            None, None, self.cfg.flash_block_q, self.cfg.flash_block_k, True)
+            None, None, cfg.flash_block_q, cfg.flash_block_k, True)
         obs_metrics.REGISTRY.inc(
             "serve_flash_blocks_visited_total", visited, bucket=str(bucket),
             help_text="(query block, kv block) pairs the flash forward "
@@ -2043,6 +2122,32 @@ class InferenceEngine:
             help_text="(query block, kv block) pairs of the flash "
                       "forward's grid a head and layer in prefill, by "
                       "bucket.")
+        if not cfg.has_window:
+            return
+        block_q, block_k = cfg.flash_block_q, cfg.flash_block_k
+        visited, grid = block_counts(
+            np.where(parked, -1, positions),
+            np.where(parked, PAD_POS, positions), None, None,
+            block_q, block_k, True, cfg.sliding_window)
+        # A real token at position t sees min(t + 1, window) keys.
+        needed = int(np.minimum(positions[~parked] + 1,
+                                cfg.sliding_window).sum())
+        for name, value, what in (
+                ("blocks_visited", visited,
+                 "(query block, kv block) pairs the flash forward computed"),
+                ("blocks_grid", grid,
+                 "(query block, kv block) pairs of the flash forward's "
+                 "grid"),
+                ("scores_visited",
+                 visited * min(block_q, bucket) * min(block_k, bucket),
+                 "scores in the blocks the flash forward computed"),
+                ("scores_needed", needed,
+                 "scores a window needs (a token at position t sees "
+                 "min(t + 1, window) keys)")):
+            obs_metrics.REGISTRY.inc(
+                f"serve_window_{name}_total", value, bucket=str(bucket),
+                help_text=what + " a head and window layer in prefill, by "
+                                 "bucket.")
 
     def _count_moe(self, program: str, moe: list, steps: int = 1) -> None:
         """Add one dispatch's (counts, hits) of a sparse model to the

@@ -1146,6 +1146,7 @@ class PagedInferenceEngine(InferenceEngine):
     kv_paging = "paged"
     _supports_recurrent_state = False
     _supports_latent_cache = False
+    _supports_window_ring = False
 
     def __init__(self, cfg: ModelConfig, params: Params, *, mesh=None,
                  **kwargs):

@@ -610,8 +610,9 @@ def test_hybrid_programs_are_audited_and_the_rest_of_the_census_stands():
     base = load_program_baseline(os.path.join(
         _repo_root(), "config", "program_baseline.json"))
     old = [p for p in base["programs"]
-           if not p["name"].startswith(("hybrid_", "sparse_latent_"))]
-    assert len(old) == 31 and len(base["programs"]) == 35
+           if not p["name"].startswith(("hybrid_", "sparse_latent_",
+                                        "window_full_"))]
+    assert len(old) == 31 and len(base["programs"]) == 37
     assert hashlib.sha256(json.dumps(old, sort_keys=True).encode()
                           ).hexdigest() == (
         "e04d823ec805a6e339114d31d41529ec8ada425ff7e3c2d51b4302317a6db011")
@@ -632,6 +633,26 @@ def test_sparse_latent_programs_are_audited():
         assert params["layers"]["moe"]["router"].shape == (2, 128, 16)
         assert params["leading_layers"]["mlp"]["wi_gate"].shape[0] == 1
     assert specs["prefill"]["args"][1].latent is None
+
+
+def test_window_full_programs_are_audited():
+    """PR 32's two programs are traced with the ring leaves beside K/V of
+    another head count and width, a stack a position of the period and a
+    share of the experts."""
+    from runbooks_tpu.analysis.program import AuditSettings, _engine_specs
+
+    specs = {s["name"]: s for s in _engine_specs(AuditSettings())}
+    for name in ("window_full_prefill", "window_full_decode"):
+        params, pool = specs[name]["args"][:2]
+        assert pool.ring_k.shape == (3, 2, 16, 4, 24)
+        assert pool.ring_v.shape == (3, 2, 16, 4, 16)
+        assert pool.k.shape == (2, 2, 257, 2, 24) and pool.v.shape[-1] == 16
+        assert len(params["window_layers"]) == 3
+        assert params["window_layers"][0]["attn"]["sink"].shape == (1, 8)
+        assert params["window_layers"][0]["moe"]["wi_gate"].shape[:2] == (
+            1, 8)
+        assert "sink" not in params["layers"]["attn"]
+    assert specs["prefill"]["args"][1].ring_k is None
 
 
 def test_program_baseline_roundtrip(tmp_path):

@@ -51,6 +51,12 @@ MODELS = {
     "latent-sparse": (lambda: get_config(
         "debug-sparse-latent", dtype="bfloat16", param_dtype="bfloat16",
         moe_experts_held=8), False),
+    # Window layers' ring leaves beside full layers' K/V of another head
+    # count and width, stacks a position of the period, sparse FFNs; two
+    # periods behind the leading layer, so that the layer scan is a loop.
+    "window-full": (lambda: get_config(
+        "debug-window-full", dtype="bfloat16", param_dtype="bfloat16",
+        moe_experts_held=8, num_layers=9), False),
 }
 
 
@@ -110,6 +116,9 @@ def test_decode_loops_hold_no_pool_sized_operation(one_chip, model):
     leaf = pool.k if pool.latent is None else pool.latent
     shape = "[" + ",".join(map(str, leaf.shape)) + "]"
     assert any(shape in i.line for i in loops), shape
+    if pool.ring_k is not None:
+        ring = "[" + ",".join(map(str, pool.ring_k.shape)) + "]"
+        assert any(ring in i.line for i in loops), ring
     assert pool_sized_loop_ops(text, pool) == []
 
 
