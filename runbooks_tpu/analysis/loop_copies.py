@@ -24,6 +24,16 @@ result has the shape of a whole cache leaf, or of one layer of ``k`` / ``v``
 - everything else of such a shape is reported: ``copy``, ``dynamic-slice``
   of a whole layer, a ``dynamic-update-slice`` that writes a whole layer
   back, a ``select`` or ``convert`` over the pool.
+
+``entry_param_copies(text)`` reads the other place bytes are moved without
+work: the entry computation, once a call. A program compiled for a
+parameter in one layout whose loops want it in another copies the whole
+parameter there before anything else runs (PERF.md §6, PR 33: 3.7 GiB of
+``falcon-7b``'s weights every decode chunk). Each such copy names the
+parameter and the layout the program wants it in, which is what
+``serve/weight_layout.py`` places the weights by;
+``param_sized_entry_copies(text, shapes)`` is the same list as lines, for
+the leaves of a parameter tree.
 """
 
 from __future__ import annotations
@@ -33,7 +43,7 @@ import math
 import re
 from typing import Dict, List, Optional, Tuple
 
-_COMP = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_COMP = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
 _INSTR = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = (.*)$")
 _SHAPE = re.compile(r"^([a-z]\w*)\[([\d,]*)\]")
 _OPCODE = re.compile(r"^([a-z][\w\-]*)\(")
@@ -43,6 +53,9 @@ _CALLED = re.compile(
     r"%?([\w.\-]+)")
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 
+# numpy's dtype names as the compiled text spells them.
+_DTYPE_NAMES = {"bfloat16": "bf16", "float32": "f32", "float16": "f16",
+                "int8": "s8", "int32": "s32", "uint8": "u8"}
 _FREE = frozenset({
     "parameter", "tuple", "get-tuple-element", "bitcast", "while", "call",
     "conditional", "optimization-barrier", "constant", "after-all"})
@@ -96,7 +109,7 @@ def parse(text: str) -> Dict[str, List[Instr]]:
     for line in text.splitlines():
         m = _COMP.match(line)
         if m:
-            cur = comps.setdefault(m.group(1), [])
+            cur = comps.setdefault(m.group(2), [])
             continue
         if line.startswith("}"):
             cur = None
@@ -170,15 +183,13 @@ def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
     K/V; the recurrent leaves change a whole layer at a time. Of a window
     layer's ring leaves only the whole leaf counts: one layer of a ring is
     what a decode step reads (a window and a margin long, by design)."""
-    names = {"bfloat16": "bf16", "float32": "f32", "float16": "f16",
-             "int8": "s8"}
     shapes: Dict[Tuple[str, Tuple[int, ...]], int] = {}
     for field in ("k", "v", "k_scale", "v_scale", "state", "conv",
                   "latent", "ring_k", "ring_v"):
         leaf = getattr(cache, field)
         if leaf is None or 0 in leaf.shape:   # a latent cache's empty k, v
             continue
-        dtype = names[str(leaf.dtype)]
+        dtype = _DTYPE_NAMES[str(leaf.dtype)]
         dims = tuple(int(d) for d in leaf.shape)
         sharding = getattr(leaf, "sharding", None)
         if sharding is not None:       # the text has one device's shapes
@@ -214,3 +225,76 @@ def pool_sized_loop_ops(text: str, cache) -> List[str]:
             dims = ",".join(map(str, i.dims))
             found.append(f"{name}: {i.opcode} {i.dtype}[{dims}] {i.name}")
     return found
+
+
+_ENTRY = re.compile(r"^ENTRY %?([\w.\-]+) ", re.M)
+_RESULT_LAYOUT = re.compile(r"= [a-z]\w*\[[\d,]*\](\{[^}]*\})")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_PARAM_NO = re.compile(r"parameter\((\d+)\)")
+# What hands a value on as it is: a prefetch into another memory space is
+# the pair copy-start / copy-done, and the re-layout, if any, follows it.
+_PASSES_ON = frozenset({"bitcast", "copy-done", "copy-start"})
+
+
+@dataclasses.dataclass
+class EntryCopy:
+    """One whole-parameter copy in an entry computation."""
+    param: str                    # the parameter's op name: "params['embed']"
+    number: int                   # parameter(N)
+    dtype: str
+    dims: Tuple[int, ...]
+    layout: str                   # the copy's result: "{1,0:T(8,128)(2,1)}"
+    opcode: str
+    name: str
+
+
+def entry_param_copies(text: str) -> List[EntryCopy]:
+    """The ``copy`` and ``transpose`` instructions of the entry computation
+    that take a whole entry parameter (as it is, or through a prefetch)
+    into another layout of the device's main memory. A copy whose result
+    lives in another memory space (``S(n)`` in its layout) is a prefetch
+    the scheduler placed: the parameter's one read, not a second."""
+    m = _ENTRY.search(text)
+    entry = parse(text).get(m.group(1), []) if m else []
+    by_name = {i.name: i for i in entry}
+    found = []
+    for i in entry:
+        if i.opcode not in ("copy", "transpose") or not i.operands:
+            continue
+        layout = _RESULT_LAYOUT.search(i.line)
+        if layout is None or "S(" in layout.group(1):
+            continue
+        src = by_name.get(i.operands[0])
+        while src is not None and src.opcode in _PASSES_ON and src.operands:
+            src = by_name.get(src.operands[0])
+        if src is None or src.opcode != "parameter" or src.size != i.size:
+            continue
+        op_name = _OP_NAME.search(src.line)
+        found.append(EntryCopy(
+            param=op_name.group(1).replace("\\'", "'") if op_name else "",
+            number=int(_PARAM_NO.search(src.line).group(1)),
+            dtype=i.dtype, dims=i.dims, layout=layout.group(1),
+            opcode=i.opcode, name=i.name))
+    return found
+
+
+def param_sized_entry_copies(text: str, shapes) -> List[str]:
+    """The whole-parameter copies of the entry computation whose source has
+    the shape of a leaf of ``shapes`` (a parameter tree, or its
+    ShapeDtypeStructs; under a sharding, one device's part), as ``<opcode>
+    <shape> <name> <- <parameter>`` lines; empty for a program that reads
+    its parameters where they lie."""
+    import jax
+
+    leaves = set()
+    for leaf in jax.tree.leaves(shapes):
+        dims = tuple(int(d) for d in leaf.shape)
+        sharding = getattr(leaf, "sharding", None)
+        if hasattr(sharding, "shard_shape"):
+            dims = tuple(sharding.shard_shape(dims))
+        leaves.add((_DTYPE_NAMES.get(str(leaf.dtype), str(leaf.dtype)),
+                    tuple(sorted(dims))))
+    return [f"{c.opcode} {c.dtype}[{','.join(map(str, c.dims))}] {c.name} "
+            f"<- {c.param}"
+            for c in entry_param_copies(text)
+            if (c.dtype, tuple(sorted(c.dims))) in leaves]

@@ -1441,6 +1441,22 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
 # Forward
 # ---------------------------------------------------------------------------
 
+def _read_in_place(w: jax.Array, stack_layout) -> jax.Array:
+    """One layer's slice `w` of a ``[layers, ...]`` stack that lies in
+    `stack_layout`, held to that layout without its leading axis. As it is
+    (no constraint) where the layout is unknown, where the layers are not
+    the stack's major-most axis, for a type under a byte, and for a slice
+    under two dimensions (a norm's scale: its tiles are not a matrix's)."""
+    order = getattr(stack_layout, "major_to_minor", None)
+    if (not order or order[0] != 0 or w.ndim < 2 or len(order) != w.ndim + 1
+            or getattr(stack_layout, "_sub_byte_element_size_in_bits", 0)):
+        return w
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        w, Layout(tuple(d - 1 for d in order[1:]), stack_layout.tiling))
+
+
 def forward(
     cfg: ModelConfig,
     params: Params,
@@ -1456,6 +1472,7 @@ def forward(
     adapters=None,
     token_mask: Optional[jax.Array] = None,  # [b, s] bool
     with_moe_counts: bool = False,
+    weight_layouts=None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Returns (logits [b, s, vocab] float32, updated cache or None) — or,
     with_aux=True, (logits, cache, aux) where aux is the summed per-layer
@@ -1465,6 +1482,14 @@ def forward(
     layers, experts held + 1] to what is returned: the (token, expert)
     assignments each held expert of each layer got in this call, and last
     those routed to experts held elsewhere (models/moe.py).
+
+    weight_layouts (a serving decode program's; ``jax.tree.leaves(params)``
+    order, a ``jax.experimental.layout.Layout`` or None a leaf): the layout
+    each weight lies in on the device. Each layer's slice of a scanned
+    stack is then read in that layout (``_read_in_place``): a program that
+    runs this forward inside a loop of its own otherwise picks the layers'
+    layouts for itself and copies whole stacks to them once a call
+    (serve/weight_layout.py).
 
     return_activations=True skips the head matmul and returns the
     post-final-norm activations [b, s, hidden] in place of logits — the
@@ -1664,10 +1689,21 @@ def forward(
     layers, expert_stacks = without_stacks(layers)
     if win_layers is not None:
         win_layers, win_stacks = zip(*map(without_stacks, win_layers))
+    # The scanned stacks' layouts, in the order the scan body flattens
+    # its slices of them (the leaves are the parameters' own objects).
+    lain = None
+    if weight_layouts is not None:
+        by_leaf = dict(zip(map(id, jax.tree.leaves(params)), weight_layouts))
+        lain = [by_leaf.get(id(w)) for w in jax.tree.leaves(
+            (layers, lin_layers, win_layers))]
 
     def scan_body(carry, scanned):
         x, aux_sum, kv, rec, ring = carry
         layers, pool_layer, lin_layers, win_layers, period = scanned
+        if lain is not None:
+            sliced, tree = jax.tree.flatten((layers, lin_layers, win_layers))
+            layers, lin_layers, win_layers = tree.unflatten(
+                list(map(_read_in_place, sliced, lain)))
         adapter = None if apool is None else (pool_layer, aidx)
         # The period's number among the scanned ones: where its layers lie
         # in the leaves that hold no leading layer (recurrent, ring).

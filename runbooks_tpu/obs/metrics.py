@@ -139,6 +139,10 @@ CATALOG: Dict[str, str] = {
     # each chip holds under a serving mesh; equal unsharded)
     "serve_kv_pool_bytes": "gauge",
     "serve_kv_pool_bytes_per_device": "gauge",
+    # weights the engine put into the layout its decode program reads
+    # them in, once at load (serve/weight_layout.py)
+    "serve_weight_leaves_replaced": "gauge",
+    "serve_weight_bytes_replaced": "gauge",
     # latent (MLA) cache leaf and sparse layers
     # (docs/sparse-latent-models.md): the moe families exist for a sparse
     # model only

@@ -562,6 +562,10 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
         engine_cls = PagedInferenceEngine
     engine = engine_cls(cfg, model_params, mesh=mesh, tokenizer=tokenizer,
                         **dataclasses.asdict(options))
+    # The engine's tree is the weights' from here on: it re-placed the
+    # leaves its decode program wants elsewhere (serve/weight_layout.py),
+    # and a second holder would keep their sources beside the warm-up.
+    del model_params
     if options.auto_prefix_chat:
         engine._refuse_prefix()
     if options.warmup:
@@ -712,6 +716,17 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                       help_text="The latent (MLA) cache leaf, all slots "
                                 "(0 for per-head K/V): the part of "
                                 "serve_kv_pool_bytes with no head axis.")
+        # Weights put into the layout decode reads them in, once at load
+        # (serve/weight_layout.py); 0 where the client's layouts serve.
+        reg.set_gauge("serve_weight_leaves_replaced",
+                      eng.weight_layout["leaves_replaced"],
+                      help_text="Weight leaves the engine put into another "
+                                "layout at load because its decode program "
+                                "asked for it.")
+        reg.set_gauge("serve_weight_bytes_replaced",
+                      eng.weight_layout["bytes_replaced"],
+                      help_text="Bytes of the weight leaves re-placed at "
+                                "load (serve_weight_leaves_replaced).")
         reg.set_gauge("serve_kv_ring_bytes", occ.get("kv_ring_bytes", 0),
                       help_text="Window layers' ring caches, all slots (0 "
                                 "without such layers): the part of "
@@ -1611,7 +1626,10 @@ def main() -> int:
     }), flush=True)
 
     t_engine = time.perf_counter()
-    app = create_server(cfg, model_params, tokenizer, mesh=mesh,
+    # Handed over, not shared: this frame lives as long as the server.
+    weights = [model_params]
+    del model_params
+    app = create_server(cfg, weights.pop(), tokenizer, mesh=mesh,
                         **dataclasses.asdict(options))
     # Engine construction (KV cache allocation, jit wrappers): what
     # create_server took outside the warm-up it ran.

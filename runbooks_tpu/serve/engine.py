@@ -81,6 +81,12 @@ from runbooks_tpu.obs.trace import (
 )
 from runbooks_tpu.ops.sampling import sample, speculative_verify
 from runbooks_tpu.serve.speculative import NgramDraftIndex, legal_draft_prefix
+from runbooks_tpu.serve.weight_layout import (
+    Placement,
+    asked_formats,
+    stack_layouts,
+)
+from runbooks_tpu.serve.weight_layout import place as place_weights
 from runbooks_tpu.utils.hw import backend_tuning
 
 Params = Any
@@ -489,7 +495,7 @@ def advance_rows(nxt, pos, alive, left, eos_ids, max_len: int):
 
 
 def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
-                   pad_slot: int, view: int):
+                   pad_slot: int, view: int, weight_layouts=None):
     """`chunk` decode steps in one jit call (lax.scan). Per-slot
     liveness is tracked ON DEVICE with exactly the host's finish rules
     (EOS, max_tokens budget, cache out-of-room), so the host can take
@@ -498,7 +504,9 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
     position, alive, budget left) a slot — is returned beside them: it is
     the next chunk's operands when no slot changed hands in between
     (pack_decode_fn). rng advances functionally (successor key returned)
-    — no eager split on the host per chunk."""
+    — no eager split on the host per chunk. `weight_layouts` is where the
+    weights lie, for forward: the layer loop inside the step loop reads
+    them there (serve/weight_layout.py)."""
 
     sparse = bool(cfg.moe_num_experts)
 
@@ -524,7 +532,7 @@ def make_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
                 cache=cache, cache_view=view, adapters=adapters,
                 token_mask=(alive[:, None]
                             if cfg.has_recurrent_state or sparse else None),
-                with_moe_counts=sparse)
+                with_moe_counts=sparse, weight_layouts=weight_layouts)
             nxt = sample(logits[:, -1], key, temperature, top_k, top_p,
                          gmask=gmask)
             nxt = jnp.where(alive, nxt, tok)
@@ -715,6 +723,8 @@ class InferenceEngine:
         (/debug/programs)."""
         self.options = options = ServeOptions(
             **{**options, "kv_paging": self.kv_paging})
+        if mesh is not None:
+            self._check_mesh(cfg, mesh)
         self.cfg = cfg
         self.mesh = mesh
         max_slots = self.max_slots = options.max_slots
@@ -859,6 +869,14 @@ class InferenceEngine:
 
             self._mesh_ctx = contextlib.nullcontext
         self.params = params
+        # The engine's tree is the one _place_weights rebuilds; this frame
+        # must not keep the sources of the leaves it re-places alive.
+        del params
+        # Off a mesh: the one device the weights are on (_home).
+        first = jax.tree.leaves(self.params)[0]
+        self._device_sharding = jax.sharding.SingleDeviceSharding(
+            next(iter(first.devices())) if hasattr(first, "devices")
+            else jax.devices()[0])
         self.max_seq_len = options.max_seq_len or cfg.max_seq_len
         self._pad_slot = self.max_seq_len  # trash slot index
         # Multi-tenant LoRA adapter pool (serve/lora_pool.py,
@@ -1030,7 +1048,17 @@ class InferenceEngine:
             if self._swap_fault < 1:
                 raise ValueError(
                     f"RBT_FAULT_INJECT={fault!r}: K must be >= 1")
+        # Where each weight lies (jax.tree.leaves order), once placed.
+        self._stack_layouts: Optional[list] = None
         self._init_programs()
+        self._place_weights()
+
+    def _check_mesh(self, cfg: ModelConfig, mesh) -> None:
+        """What a subclass requires of a serving mesh, raised before
+        anything is placed (the paged engine's pool geometry). A hook and
+        not a constructor of the subclass's own: a second frame holding
+        ``params`` would keep alive the sources of the leaves
+        _place_weights re-places."""
 
     def _init_cache(self) -> None:
         """Allocate the engine's KV storage. Overridable: the paged
@@ -1039,16 +1067,13 @@ class InferenceEngine:
         self.cache = self._new_pool_cache()
 
     def _commit_key(self, key):
-        """Pin an rng key's placement under the serving mesh. A fresh key
-        traces as an UNSPECIFIED-sharding jit operand while the key a
-        dispatch RETURNS is committed (replicated NamedSharding) — two
-        cache entries for the same program, so every warmup-compiled
-        program would recompile once under steady traffic. Committing
-        the key up front makes warmup and runtime signatures identical.
-        No-op off-mesh (single-device placement is already unique)."""
-        if self.mesh is None:
-            return key
-        return jax.device_put(key, self._replicated())
+        """Pin an rng key's placement (_home). A fresh key traces as an
+        UNSPECIFIED-sharding jit operand while the key a dispatch RETURNS
+        is committed — two cache entries for the same program, so every
+        warmup-compiled program would recompile once under steady
+        traffic. Committing the key up front makes warmup and runtime
+        signatures identical."""
+        return jax.device_put(key, self._home())
 
     def _init_programs(self) -> None:
         """Build and register the engine's jitted program set. Overridable
@@ -1085,11 +1110,16 @@ class InferenceEngine:
         self.view_buckets = view_buckets_for(self.max_seq_len)
         self._decode_fns: dict = {}
 
+        def make_decode(view: int, weight_layouts=None):
+            return make_decode_fn(cfg, chunk, max_len, self._pad_slot, view,
+                                  weight_layouts)
+
+        self._make_decode = make_decode
+
         def decode_for(view: int):
             if view not in self._decode_fns:
                 self._decode_fns[view] = self._jit_decode(
-                    make_decode_fn(cfg, chunk, max_len, self._pad_slot,
-                                   view))
+                    make_decode(view, self._stack_layouts))
                 obs_device.PROGRAMS.register("serve", f"decode_v{view}",
                                              self._decode_fns[view])
             return self._decode_fns[view]
@@ -1119,55 +1149,126 @@ class InferenceEngine:
         Under a mesh the returned int block is pinned replicated, as
         _place_blocks places it: the carry then goes back in with the
         sharding the program was compiled for, not re-laid every chunk."""
+        return jax.jit(pack_decode_fn(decode_fn), **self._decode_jit_kwargs())
+
+    def _decode_jit_kwargs(self) -> dict:
         out_shardings = None
         if self.mesh is not None:
             out_shardings = (None, self._replicated(), None, None) + (
                 (None,) if self.cfg.moe_num_experts else ())
-        return jax.jit(pack_decode_fn(decode_fn), donate_argnums=(1,),
-                       out_shardings=out_shardings)
+        return {"donate_argnums": (1,), "out_shardings": out_shardings}
+
+    def _place_weights(self) -> None:
+        """Ask the decode program of the largest view which layout it
+        wants each weight in, place the weights so, once, before anything
+        is warmed, and keep where they lie for the decode programs built
+        after this (serve/weight_layout.py): every program then compiles
+        against that placement and none re-lays a weight a call. Where the
+        program agrees with the layouts the weights have no leaf is
+        touched. Whatever the backend cannot say or do leaves the weights
+        as they are, counted in ``weight_layout``."""
+        key, _ = self._view_key(self.max_seq_len + self.decode_chunk)
+        packed = pack_decode_fn(self._make_decode(key))
+        operands = (*self._table_operands(),
+                    *self._place_blocks(self._slot_ints, self._slot_floats),
+                    self.rng)
+        kwargs = {**self._decode_kwargs(),
+                  **self._grammar_warm_kwargs(
+                      (self.max_slots, self.cfg.vocab_size))}
+
+        def program(params, cache, operands, kwargs):
+            # in_shardings cannot go with keyword arguments.
+            return packed(params, cache, *operands, **kwargs)
+
+        t0 = time.perf_counter()
+        placement = Placement()
+        with fine("startup.weight_layout") as sp, \
+                obs_device.SENTINEL.expected(), self._mesh_ctx():
+            # The flat list is the weights' only holder while they move:
+            # a source is let go before the next leaf is put.
+            leaves, tree = jax.tree.flatten(self.params)
+            try:
+                wanted = asked_formats(program, self.params, self.cache,
+                                       operands, kwargs,
+                                       **self._decode_jit_kwargs())
+                self.params = None
+                placement = place_weights(leaves, wanted)
+            except Exception as exc:   # noqa: BLE001 - a backend without layouts
+                placement.why = f"{type(exc).__name__}: {exc}"
+            self.params = tree.unflatten(leaves)
+            # Off a mesh only: the constraint forward() puts on a layer's
+            # slice is a custom call, which the SPMD partitioner answers
+            # by gathering the slice whole on every device (falcon-40b on
+            # four chips: decode's temporaries 258 -> 1938 MiB, AOT).
+            if self.mesh is None:
+                self._stack_layouts = stack_layouts(leaves)
+            sp.set(leaves_replaced=placement.leaves_replaced,
+                   bytes_replaced=placement.bytes_replaced)
+        # /metrics: serve_weight_leaves_replaced, serve_weight_bytes_
+        # replaced; the warm-up census and its line carry the record.
+        self.weight_layout = {
+            **dataclasses.asdict(placement),
+            "seconds": round(time.perf_counter() - t0, 3)}
+        if placement.why:
+            print(f"serve: weight layout: {placement.leaves_kept} leaves "
+                  f"kept where they were: {placement.why}", flush=True)
 
     def _replicated(self):
         from jax.sharding import NamedSharding, PartitionSpec
 
         return NamedSharding(self.mesh, PartitionSpec())
 
+    def _home(self):
+        """Where the engine commits the state it carries from one program
+        to the next (rng, the per-slot blocks, off a mesh the pool too):
+        replicated over the serving mesh, or the one device the weights
+        are on. Off a mesh too, because a program hands back committed
+        arrays as soon as one of its operands is committed, and a weight
+        _place_weights re-placed is: state that started uncommitted would
+        change its jit signature after the first dispatch, and every
+        warmed program would compile once more under traffic."""
+        if self.mesh is not None:
+            return self._replicated()
+        return self._device_sharding
+
     def _place_blocks(self, ints, floats) -> tuple:
         """The two per-slot blocks on the device: one transfer a dtype.
         Of copies: the host mirror is written in place, and on the CPU
         backend a placed array may share its host buffer's memory."""
-        blocks = (ints.copy(), floats.copy())
-        if self.mesh is None:
-            return tuple(map(jnp.asarray, blocks))
-        return tuple(jax.device_put(blocks, self._replicated()))
+        return tuple(jax.device_put((ints.copy(), floats.copy()),
+                                    self._home()))
 
     def _new_pool_cache(self) -> KVCache:
         """Fresh slot-pool cache (int8 + scales when quantize_kv), sharded
         under the serving mesh when one is configured."""
         cache = KVCache.create(self.cfg, self.max_slots, self.max_seq_len,
                                trash_slot=True, quantize_kv=self.quantize_kv)
-        if self._cache_sharding is not None:
-            def put(a, logical=None):
-                return (None if a is None else jax.device_put(
-                    a, self._cache_sharding(a.shape, logical)))
+        if self._cache_sharding is None:
+            # Committed off a mesh too (_home).
+            return jax.device_put(cache, self._home())
 
-            # index is committed too (the scalar's spec resolves to
-            # replicated): a dispatch RETURNS it committed, so a fresh
-            # uncommitted one would key a second jit entry and the first
-            # prefill after every reset() would recompile under traffic.
-            # The recurrent state [L, batch, heads, d_k, d_v] shards by
-            # head; the conv tail (q | k | v channels side by side) does
-            # not split on a head boundary and stays whole.
-            cache = KVCache(k=put(cache.k), v=put(cache.v),
-                            index=put(cache.index),
-                            k_scale=put(cache.k_scale),
-                            v_scale=put(cache.v_scale),
-                            state=put(cache.state, (None, "batch",
-                                                    "act_heads", None, None)),
-                            conv=put(cache.conv,
-                                     (None, "batch", None, None)),
-                            latent=put(cache.latent,
-                                       (None, "batch", None, None)),
-                            ring_k=put(cache.ring_k), ring_v=put(cache.ring_v))
+        def put(a, logical=None):
+            return (None if a is None else jax.device_put(
+                a, self._cache_sharding(a.shape, logical)))
+
+        # index is committed too (the scalar's spec resolves to
+        # replicated): a dispatch RETURNS it committed, so a fresh
+        # uncommitted one would key a second jit entry and the first
+        # prefill after every reset() would recompile under traffic.
+        # The recurrent state [L, batch, heads, d_k, d_v] shards by
+        # head; the conv tail (q | k | v channels side by side) does
+        # not split on a head boundary and stays whole.
+        cache = KVCache(k=put(cache.k), v=put(cache.v),
+                        index=put(cache.index),
+                        k_scale=put(cache.k_scale),
+                        v_scale=put(cache.v_scale),
+                        state=put(cache.state, (None, "batch",
+                                                "act_heads", None, None)),
+                        conv=put(cache.conv,
+                                 (None, "batch", None, None)),
+                        latent=put(cache.latent,
+                                   (None, "batch", None, None)),
+                        ring_k=put(cache.ring_k), ring_v=put(cache.ring_v))
         return cache
 
     def _refuse_window(self, feature: str, why: str) -> None:
@@ -1451,6 +1552,7 @@ class InferenceEngine:
             # seconds into a step time.
             "decode_chunk": self.decode_chunk,
             **run.finish(self.cache),
+            "weight_layout": self.weight_layout,
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1464,6 +1566,7 @@ class InferenceEngine:
             f"{self.warmup_census['compile_seconds']}s, "
             f"{self.warmup_census['cache_hits']} from the persistent "
             f"cache ({[(c['name'], c['programs']) for c in census]}); "
+            f"weight layout {self.weight_layout}; "
             f"phases {self.warmup_census['phases']}",
             flush=True)
         # From here on, a compile is a serve-time stall: the sentinel

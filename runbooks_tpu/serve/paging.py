@@ -735,7 +735,8 @@ def make_paged_prefill_fn(cfg: ModelConfig, cache_len: int,
 
 
 def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
-                         page_size: int, view_pages: int, num_pages: int):
+                         page_size: int, view_pages: int, num_pages: int,
+                         weight_layouts=None):
     """``chunk`` decode steps over paged KV in one jit call. The slots'
     pages are gathered ONCE into a contiguous [slots, view_pages*page_size
     + 1] view (last slot = view trash for parked rows); the scan attends
@@ -743,7 +744,8 @@ def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
     physical page, so the pool is exact when the chunk returns. Liveness
     (EOS / budget / out-of-room) tracks on device exactly as the dense
     decode does (advance_rows) — the host takes (tokens, valid)
-    identically, and the final carry is returned as the dense one is."""
+    identically, and the final carry is returned as the dense one is.
+    `weight_layouts`: as make_decode_fn's."""
     n_flat = (num_pages + 1) * page_size
     trash_flat = num_pages * page_size
     V = view_pages * page_size
@@ -785,7 +787,8 @@ def make_paged_decode_fn(cfg: ModelConfig, chunk: int, max_len: int,
             p = jnp.where(alive, pos, V)   # park at the view trash slot
             logits, cache = forward(cfg, params, tok[:, None],
                                     positions=p[:, None], cache=cache,
-                                    adapters=adapters)
+                                    adapters=adapters,
+                                    weight_layouts=weight_layouts)
             nxt = sample(logits[:, -1], key, temperature, top_k, top_p,
                          gmask=gmask)
             nxt = jnp.where(alive, nxt, tok)
@@ -1148,29 +1151,26 @@ class PagedInferenceEngine(InferenceEngine):
     _supports_latent_cache = False
     _supports_window_ring = False
 
-    def __init__(self, cfg: ModelConfig, params: Params, *, mesh=None,
-                 **kwargs):
-        if mesh is not None:
-            # Precise mesh-geometry validation: each error names the one
-            # constraint that failed (docs/troubleshooting.md). Anything
-            # that passes here serves correctly — the pool shards its
-            # kv-heads axis over `tensor` and replicates over the data/
-            # fsdp axes (page identity is global: the page tables, the
-            # allocator, and the radix tree stay replicated host state).
-            if not isinstance(mesh, jax.sharding.Mesh):
-                raise ValueError(
-                    f"mesh must be a jax.sharding.Mesh, got "
-                    f"{type(mesh).__name__}")
-            tensor = int(mesh.shape.get("tensor", 1))
-            if tensor > 1 and cfg.num_kv_heads % tensor:
-                raise ValueError(
-                    f"kv-heads not divisible by mesh_tensor: the paged "
-                    f"pool shards num_kv_heads={cfg.num_kv_heads} over "
-                    f"tensor={tensor}; pick mesh_tensor dividing the "
-                    f"kv-head count (docs/paged-kv.md)")
-            # stage > 1 is rejected by the dense engine's constructor
-            # (pipeline parallelism is a training-path feature).
-        super().__init__(cfg, params, mesh=mesh, **kwargs)
+    def _check_mesh(self, cfg: ModelConfig, mesh) -> None:
+        # Precise mesh-geometry validation: each error names the one
+        # constraint that failed (docs/troubleshooting.md). Anything
+        # that passes here serves correctly — the pool shards its
+        # kv-heads axis over `tensor` and replicates over the data/
+        # fsdp axes (page identity is global: the page tables, the
+        # allocator, and the radix tree stay replicated host state).
+        if not isinstance(mesh, jax.sharding.Mesh):
+            raise ValueError(
+                f"mesh must be a jax.sharding.Mesh, got "
+                f"{type(mesh).__name__}")
+        tensor = int(mesh.shape.get("tensor", 1))
+        if tensor > 1 and cfg.num_kv_heads % tensor:
+            raise ValueError(
+                f"kv-heads not divisible by mesh_tensor: the paged "
+                f"pool shards num_kv_heads={cfg.num_kv_heads} over "
+                f"tensor={tensor}; pick mesh_tensor dividing the "
+                f"kv-head count (docs/paged-kv.md)")
+        # stage > 1 is rejected by the dense engine's constructor
+        # (pipeline parallelism is a training-path feature).
 
     # -- storage -------------------------------------------------------
 
@@ -1224,7 +1224,7 @@ class PagedInferenceEngine(InferenceEngine):
         replicated flat-token axis and GSPMD propagates the head
         sharding straight through them."""
         if self.mesh is None:
-            return pool
+            return jax.device_put(pool, self._home())
         from jax.sharding import NamedSharding
 
         from runbooks_tpu.parallel.sharding import spec_for_array
@@ -1267,12 +1267,18 @@ class PagedInferenceEngine(InferenceEngine):
                                                        self.page_size)
         self._decode_fns: dict = {}
 
+        def make_decode(view_pages: int, weight_layouts=None):
+            return make_paged_decode_fn(cfg, self.decode_chunk,
+                                        self.max_seq_len, self.page_size,
+                                        view_pages, self.num_pages,
+                                        weight_layouts)
+
+        self._make_decode = make_decode
+
         def decode_for(view_pages: int):
             if view_pages not in self._decode_fns:
                 self._decode_fns[view_pages] = self._jit_decode(
-                    make_paged_decode_fn(cfg, self.decode_chunk,
-                                         self.max_seq_len, self.page_size,
-                                         view_pages, self.num_pages))
+                    make_decode(view_pages, self._stack_layouts))
                 obs_device.PROGRAMS.register(
                     "serve", f"decode_p{view_pages}",
                     self._decode_fns[view_pages])
@@ -1501,6 +1507,7 @@ class PagedInferenceEngine(InferenceEngine):
                                    else None),
             "decode_chunk": self.decode_chunk,
             **run.finish(self.cache),
+            "weight_layout": self.weight_layout,
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1514,7 +1521,8 @@ class PagedInferenceEngine(InferenceEngine):
             f"{self.warmup_census['compiles']} compiles in "
             f"{self.warmup_census['compile_seconds']}s, "
             f"{self.warmup_census['cache_hits']} from the persistent "
-            f"cache; phases {self.warmup_census['phases']}", flush=True)
+            f"cache; weight layout {self.weight_layout}; "
+            f"phases {self.warmup_census['phases']}", flush=True)
         if not self._marked_steady:
             self._marked_steady = True
             sentinel.mark_steady("serve")

@@ -23,12 +23,18 @@ import pytest
 
 from runbooks_tpu.analysis.loop_copies import (
     cache_shapes,
+    param_sized_entry_copies,
     parse,
     pool_sized_loop_ops,
 )
 from runbooks_tpu.models.config import get_config
 from runbooks_tpu.models.transformer import KVCache, init_params
-from runbooks_tpu.serve.engine import make_decode_fn
+from runbooks_tpu.serve.engine import (
+    make_decode_fn,
+    make_prefill_fn,
+    pack_decode_fn,
+)
+from runbooks_tpu.serve.weight_layout import asked_formats
 from tests.hybrid_fixture import tiny_config
 from tests.test_transformer import tiny
 
@@ -161,7 +167,7 @@ _IN_PLACE = _HEAD + """
 }
 
 ENTRY %main (k: bf16[2,3,9,1,4]) -> bf16[2,3,9,1,4] {
-  %k = bf16[2,3,9,1,4]{4,3,2,1,0} parameter(0)
+  %k = bf16[2,3,9,1,4]{4,1,3,2,0} parameter(0), metadata={op_name="cache.k"}
   %c = bf16[2,3,9,1,4]{4,3,2,1,0} copy(%k)
   %z = s32[] constant(0)
   %init = (s32[], bf16[2,3,9,1,4]{4,3,2,1,0}) tuple(%z, %c)
@@ -197,16 +203,156 @@ def _pool():
     return KVCache(k=leaf, v=leaf, index=None)
 
 
-@pytest.mark.parametrize("text,found", [
-    (_IN_PLACE, []),
-    (_COPIES, ["body: dynamic-slice bf16[1,3,9,1,4] layer",
-               "body: fusion bf16[2,3,9,1,4] back",
-               "body: copy bf16[2,3,9,1,4] next"]),
-], ids=["token-written-in-place", "layer-out-and-back-and-pool-copied"])
-def test_reader_on_a_program_small_enough_to_read(text, found):
+@pytest.mark.parametrize("reader,text,found", [
+    (pool_sized_loop_ops, _IN_PLACE, []),
+    (pool_sized_loop_ops, _COPIES,
+     ["body: dynamic-slice bf16[1,3,9,1,4] layer",
+      "body: fusion bf16[2,3,9,1,4] back",
+      "body: copy bf16[2,3,9,1,4] next"]),
+    (param_sized_entry_copies, _IN_PLACE,
+     ["copy bf16[2,3,9,1,4] c <- cache.k"]),
+    (param_sized_entry_copies, _COPIES, []),
+], ids=["token-written-in-place", "layer-out-and-back-and-pool-copied",
+        "parameter-copied-on-entry", "parameter-read-where-it-lies"])
+def test_reader_on_a_program_small_enough_to_read(reader, text, found):
     # main's copy on the way into the loop is outside every body: once a
-    # call, not once a step, and not this reader's business.
-    assert pool_sized_loop_ops(text, _pool()) == found
+    # call, not once a step. It is not the loop reader's business, and it
+    # is all the entry reader's: a whole parameter into another layout.
+    assert reader(text, _pool()) == found
+
+
+# ---------------------------------------------------------------------------
+# The weights are read where they lie (serve/weight_layout.py, PR 33)
+# ---------------------------------------------------------------------------
+
+def published(name: str):
+    # The published widths, cut in depth only: falcon-7b is hidden 4544
+    # (35.5 x 128), 71 query heads on 1 KV head, FFN 18176, vocabulary
+    # 65024; llama2-7b's 4096 is the control.
+    return get_config(name, num_layers=2, dtype="bfloat16",
+                      param_dtype="bfloat16")
+
+
+class Served:
+    """One model's serving programs as shapes on the described chip: the
+    weights as the client lays them out, and as the engine places them
+    after asking its decode program (engine._place_weights, step for
+    step, on shapes)."""
+
+    slots, max_len, chunk = 16, 1024, 8
+
+    def __init__(self, cfg, sharding):
+        self.cfg, self.sharding = cfg, sharding
+        key = jax.random.key(0)
+        self.default = self.on_chip(jax.eval_shape(
+            functools.partial(init_params, cfg), key))
+        self.pool = self.on_chip(jax.eval_shape(lambda: KVCache.create(
+            cfg, self.slots, self.max_len, trash_slot=True)))
+        self.key = self.on_chip(key)
+        self.operands = (self.arg(jnp.int32, 7, self.slots),
+                         self.arg(jnp.float32, 2, self.slots), self.key)
+        packed = pack_decode_fn(self.decode_fn())
+        self.wanted = asked_formats(
+            lambda params, cache, operands, kwargs: packed(
+                params, cache, *operands, **kwargs),
+            self.default, self.pool, self.operands, {}, donate_argnums=(1,))
+        leaves, tree = jax.tree.flatten(self.default)
+        self.moved = [w for leaf, w in zip(leaves, self.wanted)
+                      if w.layout != self.layout_of(leaf)]
+        self.placed = tree.unflatten([
+            jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=w)
+            for leaf, w in zip(leaves, self.wanted)])
+        self.layouts = [w.layout for w in self.wanted]
+
+    def on_chip(self, tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=self.sharding), tree)
+
+    def arg(self, dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self.sharding)
+
+    def layout_of(self, leaf):
+        """The layout the client gives a parameter of this shape: what a
+        program compiled for it as it comes reports."""
+        return jax.jit(lambda a: a).lower(leaf).compile(
+            ).input_formats[0][0].layout
+
+    def decode_fn(self, layouts=None):
+        return make_decode_fn(self.cfg, self.chunk, self.max_len,
+                              self.max_len, self.max_len, layouts)
+
+    def decode_text(self, params, layouts=None) -> str:
+        return jax.jit(pack_decode_fn(self.decode_fn(layouts)),
+                       donate_argnums=(1,)).lower(
+            params, self.pool, *self.operands).compile().as_text()
+
+    def prefill_text(self, params, rows: int, bucket: int) -> str:
+        i32 = functools.partial(self.arg, jnp.int32)
+        f32 = functools.partial(self.arg, jnp.float32)
+        return jax.jit(make_prefill_fn(self.cfg, self.max_len + 1),
+                       donate_argnums=(1,)).lower(
+            params, self.pool, i32(rows, bucket), i32(rows, bucket),
+            i32(rows), i32(rows), self.key, f32(rows), i32(rows),
+            f32(rows)).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def falcon(one_chip):
+    return Served(published("falcon-7b"), one_chip)
+
+
+def test_default_layouts_copy_three_weights_a_decode_call(falcon):
+    # What this guards, on the parent's program: the embedding table,
+    # the query projection and the FFN's second matrix are re-laid in
+    # `main` once a chunk of 8 steps (3.7 GiB at 16 layers).
+    found = param_sized_entry_copies(falcon.decode_text(falcon.default),
+                                     falcon.default)
+    assert sorted(line.split(" <- ")[1] for line in found) == [
+        "params['embed']", "params['layers']['attn']['wq']",
+        "params['layers']['mlp']['wo']"], found
+
+
+def test_placed_weights_are_read_where_they_lie_by_decode(falcon):
+    # The engine's placement and the layouts it hands its decode programs.
+    assert len(falcon.moved) == 2          # embed, wq: mlp.wo stays
+    text = falcon.decode_text(falcon.placed, falcon.layouts)
+    assert param_sized_entry_copies(text, falcon.placed) == []
+    # Placing alone is not enough for a program with two nested loops:
+    # compiled for the same layouts as given ones, it copies mlp.wo.
+    found = param_sized_entry_copies(falcon.decode_text(falcon.placed),
+                                     falcon.placed)
+    assert [line.split(" <- ")[1] for line in found] == [
+        "params['layers']['mlp']['wo']"], found
+
+
+def test_placed_weights_are_read_where_they_lie_by_a_prefill(
+        falcon, monkeypatch):
+    # The branch the chip takes (the flash forward), as rehearse.py does.
+    import runbooks_tpu.models.transformer as tr
+    import runbooks_tpu.ops.flash_attention as fa
+    import runbooks_tpu.utils.hw as hw
+
+    for mod in (hw, tr, fa):
+        if hasattr(mod, "on_tpu"):
+            monkeypatch.setattr(mod, "on_tpu", lambda: True)
+    text = falcon.prefill_text(falcon.placed, 1, 256)
+    assert param_sized_entry_copies(text, falcon.placed) == []
+
+
+def test_placement_follows_the_compiler_not_a_width(one_chip):
+    # hidden 4096 = 32 x 128 is no exemption (ISSUE 33 expected this
+    # control to move nothing): at the client's layouts llama2-7b's decode
+    # re-lays wq and wv once a chunk too, the compiler asks for the three
+    # projections with the contraction dimension minor, and against that
+    # placement even the plain program copies nothing.
+    llama = Served(published("llama2-7b"), one_chip)
+    found = param_sized_entry_copies(llama.decode_text(llama.default),
+                                     llama.default)
+    assert sorted(line.split(" <- ")[1] for line in found) == [
+        "params['layers']['attn']['wq']", "params['layers']['attn']['wv']"]
+    assert len(llama.moved) == 3
+    assert param_sized_entry_copies(llama.decode_text(llama.placed),
+                                    llama.placed) == []
 
 
 def test_reader_shapes_follow_the_cache():
