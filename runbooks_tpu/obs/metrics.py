@@ -81,7 +81,13 @@ CATALOG: Dict[str, str] = {
     "serve_queue_depth": "gauge",
     "serve_queue_limit": "gauge",
     "serve_draining": "gauge",
+    # a request's phases, handler entry to first SSE write (serve/api.py,
+    # serve/engine.py); the first four add up to serve_ttft_seconds
+    "serve_request_parse_seconds": "histogram",
+    "serve_pending_wait_seconds": "histogram",
     "serve_queue_wait_seconds": "histogram",
+    "serve_first_token_seconds": "histogram",
+    "serve_first_write_seconds": "histogram",
     "serve_ttft_seconds": "histogram",
     "serve_inter_token_seconds": "histogram",
     "serve_request_duration_seconds": "histogram",

@@ -31,9 +31,11 @@ from runbooks_tpu.api.serve_params import ServeOptions
 from runbooks_tpu.models.config import ModelConfig, get_config
 from runbooks_tpu.obs import flight as obs_flight
 from runbooks_tpu.obs import incident as obs_incident
+from runbooks_tpu.obs import metrics as obs_metrics
 # request_scope lives in obs/trace.py (shared with the gateway, which
 # must not import this module's JAX engine stack); re-exported here for
 # back-compat with existing importers.
+from runbooks_tpu.obs.trace import complete as trace_complete
 from runbooks_tpu.obs.trace import fine, request_scope  # noqa: F401
 from runbooks_tpu.serve.engine import (
     PRIORITY_RANK,
@@ -42,6 +44,7 @@ from runbooks_tpu.serve.engine import (
     EngineStepFailed,
     InferenceEngine,
     Request,
+    request_start,
 )
 from runbooks_tpu.train.data import load_tokenizer
 from runbooks_tpu.utils import contract
@@ -56,6 +59,17 @@ _KNOWN_BODY_FIELDS = frozenset({
     "timeout", "adapter", "priority", "stream", "response_format",
     "model", "user", "_chat",
 })
+
+
+def _observe_parse(reqs: list) -> None:
+    """Once the worker took a body's requests: handler entry to the
+    hand-over (JSON, tokenizer, stream set-up), a request."""
+    for r in reqs:
+        obs_metrics.REGISTRY.observe(
+            "serve_request_parse_seconds", r._handed - r._received,
+            help_text="HTTP handler entry to the hand-over to the engine "
+                      "worker (EngineWorker.submit_many): JSON, "
+                      "tokenizer, stream set-up.")
 
 
 def _encode(tok, text: str) -> list:
@@ -266,7 +280,9 @@ class EngineWorker:
                     f"admission queue full ({backlog} waiting, bound "
                     f"{self.engine.max_queue}); retry later")
             futs = []
+            handed = time.monotonic()
             for req in reqs:
+                req._handed = handed
                 fut: Future = Future()
                 # Resolved the moment the engine finishes the request —
                 # which may be while its next dispatch runs — not when
@@ -341,9 +357,9 @@ class EngineWorker:
                     # Error tail sampling: each doomed request's flight
                     # timeline is worth keeping — these are exactly the
                     # traces a postmortem needs.
+                    start = request_start(req)
                     obs_flight.tail_sample(
-                        req.request_id,
-                        now - req._submitted if req._submitted else 0.0,
+                        req.request_id, now - start if start else 0.0,
                         req.finish_reason or "error", error=True)
                 for _tokens, fut in doomed_prefix:
                     if not fut.done():
@@ -386,6 +402,17 @@ class EngineWorker:
                     if not fut.done():
                         fut.set_exception(exc)
                     continue
+                # Behind the tick the worker was in when the request was
+                # handed over: engine.submit stamped `_submitted` just now.
+                pending_s = req._submitted - req._handed
+                obs_metrics.REGISTRY.observe(
+                    "serve_pending_wait_seconds", pending_s,
+                    help_text="Hand-over by the HTTP handler "
+                              "(EngineWorker.submit_many) to engine.submit "
+                              "on the worker's thread: the rest of the "
+                              "tick the worker was in.")
+                trace_complete("pending_wait", pending_s,
+                               request_id=req.request_id)
                 self._inflight.append((req, fut))
             self._pending.clear()
         for job_i, (tokens, fut) in enumerate(prefix_jobs):
@@ -618,8 +645,6 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
         instances in one process each scrape their own truth), plus the
         latency histograms (TTFT, inter-token, queue-wait, end-to-end,
         prefill/decode dispatch) the engine records as it serves."""
-        from runbooks_tpu.obs import metrics as obs_metrics
-
         reg = obs_metrics.REGISTRY
         eng = worker.engine
         reg.set_counter("serve_requests_total", app["requests_total"],
@@ -1240,6 +1265,7 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
             app_["requests_failed_total"] += len(reqs)
             return web.json_response(
                 {"error": {"message": str(exc)}}, status=400)
+        _observe_parse(reqs)
         for i, f in enumerate(futs):
             f.add_done_callback(
                 lambda fut, i=i: events.put_nowait(("done", i, fut)))
@@ -1295,6 +1321,27 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
             start[i] = len(ids)
             return text or None
 
+        async def write_delta(i, flush=False):
+            """One token event of request i, its synchronous part under a
+            span of this thread (decode since the last delta, encode, the
+            write call); the first delta written stamps `_first_write`."""
+            with fine("api.write", request_id=reqs[i].request_id):
+                delta = next_delta(i, flush=flush)
+                if delta is None:
+                    return
+                await resp.write(chunk(i, text=delta))
+            req = reqs[i]
+            if not req._first_write:
+                req._first_write = time.monotonic()
+                obs_metrics.REGISTRY.observe(
+                    "serve_first_write_seconds",
+                    req._first_write - req._first_token,
+                    help_text="First token's hand-over by the engine to "
+                              "the first SSE write of a delta of the "
+                              "request: the way to the event loop, "
+                              "detokenise, encode, write (streamed "
+                              "requests only).")
+
         remaining = len(reqs)
         try:
             while remaining:
@@ -1313,16 +1360,12 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                                 "message": str(exc), "index": i,
                             }}).encode() + b"\n\n")
                         continue
-                    delta = next_delta(i, flush=True)
-                    if delta is not None:
-                        await resp.write(chunk(i, text=delta))
+                    await write_delta(i, flush=True)
                     app_["tokens_total"] += len(reqs[i].output_tokens)
                     await resp.write(chunk(
                         i, finish=reqs[i].finish_reason or "stop"))
                     continue
-                delta = next_delta(ev)
-                if delta is not None:
-                    await resp.write(chunk(ev, text=delta))
+                await write_delta(ev)
             await resp.write(b"data: [DONE]\n\n")
             await resp.write_eof()
         except (asyncio.TimeoutError, ConnectionResetError):
@@ -1345,7 +1388,8 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
         rid, tp_out = request_scope(
             http_request.headers if http_request is not None else {})
         t0 = time.monotonic()
-        resp = await _complete_scoped(app_, body, http_request, rid, tp_out)
+        resp = await _complete_scoped(app_, body, http_request, rid, tp_out,
+                                      t0)
         if not resp.prepared:  # SSE responses already carry the headers
             resp.headers["X-Request-Id"] = rid
             if tp_out:
@@ -1356,8 +1400,8 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
               f"dur_ms={(time.monotonic() - t0) * 1000:.1f}", flush=True)
         return resp
 
-    async def _complete_scoped(app_, body, http_request, rid,
-                               tp_out) -> web.Response:
+    async def _complete_scoped(app_, body, http_request, rid, tp_out,
+                               received: float) -> web.Response:
         hdr_priority = (http_request.headers.get("X-Priority")
                         if http_request is not None else None)
         # Handler entry -> hand-over to the engine worker: parse and
@@ -1374,6 +1418,7 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
             # each choice's spans stay distinguishable.
             for i, r in enumerate(reqs):
                 r.request_id = rid if len(reqs) == 1 else f"{rid}/{i}"
+                r._received = received
         if options.auto_prefix_chat and body.get("_chat"):
             # Multi-turn chat: this turn's prompt KV becomes the next
             # turn's prefix (the rendered history strictly extends).
@@ -1396,6 +1441,7 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
             app_["requests_failed_total"] += len(reqs)
             return web.json_response(
                 {"error": {"message": str(exc)}}, status=400)
+        _observe_parse(reqs)
         try:
             done_reqs = await asyncio.wait_for(
                 asyncio.gather(*futs), timeout=600)

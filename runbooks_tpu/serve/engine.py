@@ -111,6 +111,28 @@ _INTER_TOKEN_BUCKETS = (
     0.1, 0.25, 0.5, 1.0, 2.5)
 
 
+def request_start(req: "Request") -> float:
+    """The instant a request's latency counts from: the HTTP handler's
+    entry where one stamped it (`_received`), else engine.submit (direct
+    users of the engine). `deadline_s` counts from `_submitted` always."""
+    return req._received or req._submitted
+
+
+def _pull(name: str, *arrays) -> list:
+    """A dispatch's small results on the host, one np.asarray each: the
+    sync of a dispatch boundary. While fine spans are recorded the wait
+    has two children under the caller's span: `<name>.ready` (the device
+    still works, or the runtime has not woken this thread) and
+    `<name>.pull` (the copy to the host and its conversion)."""
+    if fine_enabled():
+        with fine(name + ".ready"):
+            # rbt-check: ignore[device-sync] the same dispatch boundary, split in two while a capture or the trace file records it
+            jax.block_until_ready(arrays)
+    with fine(name + ".pull"):     # the shared no-op with recording off
+        # rbt-check: ignore[device-sync] dispatch boundary: one sync a dispatch, not a token
+        return [np.asarray(a) for a in arrays]
+
+
 def _finish_request(req: "Request", reason: str, now: float) -> None:
     """The one place a request becomes finished (normal finish, error or
     deadline expiry): the reason, then `finished` — last, the worker and
@@ -121,11 +143,14 @@ def _finish_request(req: "Request", reason: str, now: float) -> None:
     RBT_TRACE=0. Then the request's own hook."""
     req.finish_reason = reason
     req.finished = True
+    duration = now - request_start(req)
     obs_metrics.REGISTRY.observe(
-        "serve_request_duration_seconds", now - req._submitted,
+        "serve_request_duration_seconds", duration,
         reason=req.finish_reason or "stop",
-        help_text="End-to-end request latency (submit to finish).")
-    obs_flight.tail_sample(req.request_id, now - req._submitted,
+        help_text="End-to-end request latency (HTTP handler entry, or "
+                  "engine.submit where no handler stamped one, to "
+                  "finish).")
+    obs_flight.tail_sample(req.request_id, duration,
                            req.finish_reason or "stop")
     if req.on_finish is not None:
         req.on_finish(req)
@@ -226,8 +251,15 @@ class Request:
     # re-admission resumes via a radix match on its own history — no
     # token loss, no resample of already-recorded tokens.
     _preempted: bool = False
-    _submitted: float = 0.0   # monotonic submit time (deadline anchor)
-    _admitted: float = 0.0    # monotonic admission time (queue-wait end)
+    # A request's instants (time.monotonic), each stamped where a phase
+    # ends; 0.0 = not reached. The first two and the last are the HTTP
+    # layer's (serve/api.py, event loop), the others the engine's (worker).
+    _received: float = 0.0    # handler entry (TTFT and duration anchor)
+    _handed: float = 0.0      # joined EngineWorker._pending (parse end)
+    _submitted: float = 0.0   # engine.submit (deadline anchor)
+    _admitted: float = 0.0    # slot assignment (queue-wait end)
+    _first_token: float = 0.0  # first token handed over (TTFT end)
+    _first_write: float = 0.0  # first SSE write of a delta (streams only)
     _last_token_t: float = 0.0  # previous token's host-observed time
 
 
@@ -2054,8 +2086,10 @@ class InferenceEngine:
             obs_metrics.REGISTRY.observe(
                 "serve_queue_wait_seconds",
                 req._admitted - req._submitted,
-                help_text="Admission-queue wait (submit to slot "
-                          "assignment).")
+                help_text="Admission-queue wait (engine.submit, on the "
+                          "worker's thread, to slot assignment; the wait "
+                          "for the worker before it is "
+                          "serve_pending_wait_seconds).")
             if record_enabled():
                 # The queue phase ends here; backdated complete event so
                 # the request's trace shows queue -> prefill -> decode.
@@ -2161,9 +2195,13 @@ class InferenceEngine:
         attrs = ({"request_ids": [r.request_id for _, r in group]}
                  if record_enabled() else {})
         with span("prefill", bucket=bucket, rows=rows, prefix=plen,
-                  **attrs):
+                  **attrs) as prefill:
             with fine("prefill.operands"):
                 args, kwargs, positions = operands()
+            if record_enabled():
+                # What this dispatch carries: real prompt tokens, after
+                # the prefix, without padding or parked rows.
+                prefill.set(tokens=int((positions < self._pad_slot).sum()))
             # Dispatch timing is host-side, outside jit (the np.asarray
             # pull below is the device sync) — zero effect on compiled
             # programs.
@@ -2175,8 +2213,8 @@ class InferenceEngine:
                     self._count_flash_blocks(bucket, positions)
                 self.deliver_parked(hidden=True)
                 with fine("prefill.sync"):
-                    # rbt-check: ignore[device-sync] prefill dispatch boundary — the first token must reach the host to stream
-                    first = np.asarray(first)
+                    # The first token must reach the host to stream.
+                    (first,) = _pull("prefill.sync", first)
                     self._count_moe("prefill", moe)
             # Labeled by (bucket, rows): the two row shapes are different
             # compiled programs with ~rows-proportional FLOPs, and the
@@ -2395,9 +2433,19 @@ class InferenceEngine:
                                       "consecutive generated tokens of one "
                                       "request.")
             else:
-                reg.observe("serve_ttft_seconds", now - req._submitted,
+                req._first_token = now
+                reg.observe("serve_ttft_seconds", now - request_start(req),
                             help_text="Time to first generated token "
-                                      "(submit to first sampled token).")
+                                      "(HTTP handler entry, or "
+                                      "engine.submit where no handler "
+                                      "stamped one, to the first token's "
+                                      "hand-over).")
+                reg.observe("serve_first_token_seconds",
+                            now - req._admitted,
+                            help_text="Slot assignment to the first "
+                                      "token's hand-over: other groups' "
+                                      "prefills of the tick, operands, "
+                                      "dispatch, sync, activation.")
             req._last_token_t = now
             if req.on_token is not None:
                 req.on_token(tok)
@@ -2731,12 +2779,8 @@ class InferenceEngine:
                     self.rng, jnp.asarray(temps), jnp.asarray(top_ks),
                     jnp.asarray(top_ps), jnp.asarray(self.active),
                     **self._adapter_kwargs(), **(gkw or {}))
-            # rbt-check: ignore[device-sync] verify dispatch boundary: one sync per verify step, not per token
-            accept = np.asarray(accept)
-            # rbt-check: ignore[device-sync] same boundary — resid rides the same verify sync
-            resid = np.asarray(resid)
-            # rbt-check: ignore[device-sync] same boundary — full rides the same verify sync
-            full = np.asarray(full)
+            # One sync a verify step, not a token.
+            accept, resid, full = _pull("verify.sync", accept, resid, full)
         obs_metrics.REGISTRY.observe(
             "serve_verify_dispatch_seconds",
             time.perf_counter() - t_dispatch, view=str(label),
@@ -2815,8 +2859,9 @@ class InferenceEngine:
             # The chunk before this one, while the device works.
             self.deliver_parked(hidden=True)
             with fine("decode.sync"):
-                # rbt-check: ignore[device-sync] decode-chunk dispatch boundary: one sync per chunk, not per token (tokens, counts and liveness in one array)
-                pulled = np.asarray(pulled)      # [chunk + 2, slots]
+                # Tokens, counts and liveness in one [chunk + 2, slots]
+                # array: one sync a chunk.
+                (pulled,) = _pull("decode.sync", pulled)
                 self._count_moe("decode", moe, steps=self.decode_chunk)
             obs_metrics.REGISTRY.observe(
                 "serve_decode_dispatch_seconds",

@@ -1666,8 +1666,10 @@ class PagedInferenceEngine(InferenceEngine):
             obs_metrics.REGISTRY.observe(
                 "serve_queue_wait_seconds",
                 req._admitted - req._submitted,
-                help_text="Admission-queue wait (submit to slot "
-                          "assignment).")
+                help_text="Admission-queue wait (engine.submit, on the "
+                          "worker's thread, to slot assignment; the wait "
+                          "for the worker before it is "
+                          "serve_pending_wait_seconds).")
             if record_enabled():
                 trace_complete("queue_wait",
                                req._admitted - req._submitted,

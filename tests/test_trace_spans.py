@@ -195,6 +195,238 @@ def test_ring_events_of_a_request_are_what_they_were(kind):
 
 
 # ---------------------------------------------------------------------------
+# A request's instants, from the HTTP handler to its first SSE write
+# (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+PHASES = ("serve_request_parse_seconds", "serve_pending_wait_seconds",
+          "serve_queue_wait_seconds", "serve_first_token_seconds")
+
+
+def hist(name):
+    """(count, sum) of a label-less family of the process's registry."""
+    from runbooks_tpu.obs import metrics as obs_metrics
+
+    return obs_metrics.REGISTRY.histogram_stats(name) or (0, 0.0)
+
+
+def grew(before, names):
+    return {n: tuple(a - b for a, b in zip(hist(n), before[n]))
+            for n in names}
+
+
+def test_streamed_completion_stamps_six_instants_in_order(monkeypatch,
+                                                          tmp_path):
+    """Through the app: received <= handed <= submitted <= admitted <=
+    first token <= first write, every phase observed once, and the first
+    four add up to serve_ttft_seconds (which starts at handler entry)."""
+    import asyncio
+    import json
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from runbooks_tpu.serve import api as serve_api
+    from runbooks_tpu.train.data import ByteTokenizer
+
+    cfg = dataclasses.replace(tiny_cfg(), vocab_size=512)
+    app = serve_api.create_server(
+        cfg, init_params(cfg, jax.random.key(0)), ByteTokenizer(),
+        max_slots=2, decode_chunk=2, warmup=False)
+    seen = []
+    submit_many = app["worker"].submit_many
+    monkeypatch.setattr(app["worker"], "submit_many",
+                        lambda reqs: seen.extend(reqs) or submit_many(reqs))
+    monkeypatch.setenv("RBT_TRACE", "1")
+    obs_trace.configure(str(tmp_path / "trace.jsonl"))
+    names = PHASES + ("serve_first_write_seconds", "serve_ttft_seconds")
+    before = {n: hist(n) for n in names}
+
+    async def drive():
+        async with TestClient(TestServer(app)) as client:
+            r = await client.post("/v1/completions", json={
+                "prompt": "hello", "max_tokens": 6, "temperature": 0.0,
+                "stream": True}, headers={"X-Request-Id": "r-stamps"})
+            assert r.status == 200
+            lines = [ln async for ln in r.content]
+            return [json.loads(ln[6:]) for ln in lines
+                    if ln.startswith(b"data: {")]
+
+    try:
+        chunks = asyncio.run(drive())
+    finally:
+        app["worker"].stop()
+        obs_trace.close()
+        obs_trace.configure(None)
+    assert chunks[-1]["choices"][0]["finish_reason"] == "length"
+    (req,) = seen
+    instants = [req._received, req._handed, req._submitted, req._admitted,
+                req._first_token, req._first_write]
+    assert all(t > 0 for t in instants)
+    assert instants == sorted(instants)
+    delta = grew(before, names)
+    assert {n: c for n, (c, _) in delta.items()} == dict.fromkeys(names, 1)
+    assert abs(sum(delta[n][1] for n in PHASES)
+               - delta["serve_ttft_seconds"][1]) < 1e-3
+    assert abs(delta["serve_ttft_seconds"][1]
+               - (req._first_token - req._received)) < 1e-6
+    # The trace file holds the request's way with no hole: the handler's
+    # span, both backdated waits, the prefill, the write of a delta.
+    events = [json.loads(ln.rstrip(",\n")) for ln in
+              (tmp_path / "trace.jsonl").read_text().splitlines()[1:]]
+    mine = [e["name"] for e in events
+            if "r-stamps" in str(e.get("args", {}).get("request_id", ""))
+            or "r-stamps" in str(e.get("args", {}).get("request_ids", ""))]
+    for name in ("api.submit", "pending_wait", "queue_wait", "prefill",
+                 "api.write"):
+        assert name in mine, f"no {name} event of the request: {mine}"
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_a_request_handed_over_during_a_tick_waits_as_pending(kind):
+    """The wait the parent's accounting left out: a request handed to the
+    worker while engine.step runs reaches engine.submit only at the next
+    intake. That is serve_pending_wait_seconds, not the queue's wait."""
+    import threading
+    import time
+
+    from runbooks_tpu.serve.api import EngineWorker
+    from runbooks_tpu.serve.engine import Request
+
+    cfg = tiny_cfg()
+    engine = make_engine(kind, cfg, init_params(cfg, jax.random.key(0)))
+    step, held, hold = engine.step, threading.Event(), [True]
+
+    def held_step():
+        out = step()
+        if hold[0]:
+            hold[0] = False
+            held.set()
+            time.sleep(0.05)        # the tick goes on for 50 ms
+        return out
+
+    engine.step = held_step
+    worker = EngineWorker(engine)
+    names = ("serve_pending_wait_seconds", "serve_queue_wait_seconds")
+    try:
+        first = worker.submit(Request(prompt_tokens=[1, 2, 3],
+                                      max_tokens=24, request_id="r-first"))
+        assert held.wait(timeout=60)
+        before = {n: hist(n) for n in names}
+        late = Request(prompt_tokens=[4, 5, 6], max_tokens=2,
+                       request_id="r-late")
+        late_fut = worker.submit(late)
+        assert late_fut.result(timeout=60) is late
+        first.result(timeout=60)
+    finally:
+        worker.stop()
+    delta = grew(before, names)
+    assert delta["serve_pending_wait_seconds"][0] == 1
+    assert delta["serve_pending_wait_seconds"][1] >= 0.040
+    assert late._submitted - late._handed >= 0.040
+    assert delta["serve_queue_wait_seconds"] == (
+        1, pytest.approx(late._admitted - late._submitted))
+    assert delta["serve_queue_wait_seconds"][1] < 0.040
+    # No handler stamped it: TTFT counts from engine.submit.
+    assert late._received == 0.0 and late._first_write == 0.0
+    assert [e["name"] for e in
+            obs_flight.RING.snapshot(request_id="r-late")][:2] \
+        == ["pending_wait", "queue_wait"]
+
+
+# ---------------------------------------------------------------------------
+# What the host waits for inside *.sync; what a prefill carried (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_sync_children_and_prefill_tokens_under_a_capture(kind, tmp_path):
+    cfg = tiny_cfg()
+    engine = make_engine(kind, cfg, init_params(cfg, jax.random.key(0)))
+    engine.generate(two_requests()[:1])      # compile outside the capture
+    profiler = obs_profile.Profiler()
+    profiler.start(str(tmp_path / "cap"))
+    try:
+        engine.generate(two_requests())
+    finally:
+        profiler.stop()
+    (events,) = capture_events(str(tmp_path / "cap")).values()
+    by_name = {}
+    for ev in events:
+        by_name.setdefault(ev[0], []).append(ev)
+    for parent in ("decode.sync", "prefill.sync"):
+        assert len(by_name[parent + ".ready"]) == len(by_name[parent]) \
+            == len(by_name[parent + ".pull"])
+        for _, p_lo, p_hi, _ in by_name[parent]:
+            (ready,) = [e for e in by_name[parent + ".ready"]
+                        if p_lo <= e[1] and e[2] <= p_hi]
+            (pull,) = [e for e in by_name[parent + ".pull"]
+                       if p_lo <= e[1] and e[2] <= p_hi]
+            assert ready[2] <= pull[1], "ready ends before the pull begins"
+    # Both prompts in one dispatch, or one each: either way the spans
+    # carry the prompts' real tokens (no prefix here), padding left out.
+    assert sum(int(st["tokens"]) for _, _, _, st in by_name["prefill"]) \
+        == sum(len(r.prompt_tokens) for r in two_requests())
+
+
+def test_prefill_span_tokens_leave_out_a_registered_prefix(monkeypatch):
+    from runbooks_tpu.serve.engine import InferenceEngine, Request
+
+    cfg = tiny_cfg()
+    engine = InferenceEngine(cfg, init_params(cfg, jax.random.key(0)),
+                             max_slots=2, seed=0, decode_chunk=2)
+    prefix = list(range(1, 17))
+    assert engine.register_prefix(prefix, warmup=False) == 16
+    obs_flight.RING.clear()
+    engine.generate([Request(prompt_tokens=prefix + [20, 21, 22],
+                             max_tokens=2, request_id="r-p")])
+    (prefill,) = [e for e in obs_flight.RING.snapshot(request_id="r-p")
+                  if e["name"] == "prefill"]
+    assert prefill["args"]["prefix"] == 16
+    assert prefill["args"]["tokens"] == 3
+
+
+def test_one_pull_and_no_wait_call_with_recording_off(monkeypatch):
+    """Outside a capture and without RBT_TRACE a decode chunk syncs as it
+    did: one np.asarray of a device array, no block_until_ready."""
+    import numpy as np
+
+    from runbooks_tpu.serve import engine as engine_mod
+
+    cfg = tiny_cfg()
+    engine = make_engine("dense", cfg, init_params(cfg, jax.random.key(0)))
+    from runbooks_tpu.serve.engine import Request
+
+    engine.generate(two_requests()[:1])
+    engine.submit(Request(prompt_tokens=[1, 2, 3], max_tokens=20))
+    engine.step()                  # admits: the prefill's pull, a chunk
+    calls = {"pulls": 0, "waits": 0}
+
+    class CountingNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(a, *args, **kwargs):
+            calls["pulls"] += isinstance(a, jax.Array)
+            return np.asarray(a, *args, **kwargs)
+
+    block_until_ready = jax.block_until_ready
+
+    def wait(x):
+        calls["waits"] += 1
+        return block_until_ready(x)
+
+    monkeypatch.setattr(engine_mod, "np", CountingNumpy())
+    monkeypatch.setattr(engine_mod.jax, "block_until_ready", wait)
+    assert not obs_trace.fine_enabled()
+    assert engine._decode_chunk_step() > 0
+    assert calls == {"pulls": 1, "waits": 0}
+    monkeypatch.setenv("RBT_TRACE", "1")     # recorded: the split appears
+    assert engine._decode_chunk_step() >= 0
+    assert calls == {"pulls": 2, "waits": 1}
+    engine.deliver_parked()
+
+
+# ---------------------------------------------------------------------------
 # Named scopes in the lowered programs
 # ---------------------------------------------------------------------------
 
