@@ -724,6 +724,38 @@ def use_flash_cached_prefill(cfg: ModelConfig, q_len: int) -> bool:
     return on_tpu()
 
 
+def flash_heads_per_step(cfg: ModelConfig, q_len: int, kv_len: int,
+                         tensor_parallel: int = 1) -> dict:
+    """{layer kind: G}: how many query heads one grid step of the flash
+    kernels holds (ops/flash_attention.head_block) when a forward of
+    ``q_len`` queries on ``kv_len`` keys takes the flash path, by kind of
+    attention layer, on the shard one device of a tensor mesh launches.
+    Static per compiled program, so the engine and the trainer publish it
+    at start-up from the shapes they are about to compile. Window layers
+    attend the call's own keys; latent attention is expanded to as many
+    key heads as query heads (a group of one)."""
+    from runbooks_tpu.ops.flash_attention import head_block, heads_per_shard
+
+    def g(kv_heads, keys, d, dv, sink=False, window=0):
+        h, kv_h = heads_per_shard(cfg.num_heads, kv_heads, tensor_parallel)
+        return head_block(h // kv_h, min(cfg.flash_block_q, q_len),
+                          min(cfg.flash_block_k, keys), d, dv, sink, window)
+
+    if cfg.latent_cache:
+        heads = {"latent_attention": g(cfg.num_heads, kv_len,
+                                       cfg.q_head_dim, cfg.v_head_dim)}
+    else:
+        heads = {"full_attention": g(
+            cfg.attn_shape("full_attention").kv_heads, kv_len, cfg.head_dim,
+            cfg.value_head_dim)}
+    if cfg.has_window:
+        shape = cfg.attn_shape("sliding_attention")
+        heads["sliding_attention"] = g(
+            shape.kv_heads, q_len, cfg.head_dim, cfg.value_head_dim,
+            shape.sink, shape.window)
+    return heads
+
+
 def _dispatch_attention(cfg: ModelConfig, q, k, v, positions, segment_ids,
                         mask, bias, scale=None):
     """Pick the attention implementation for the no-cache (training) path.

@@ -149,6 +149,9 @@ CATALOG: Dict[str, str] = {
     # them in, once at load (serve/weight_layout.py)
     "serve_weight_leaves_replaced": "gauge",
     "serve_weight_bytes_replaced": "gauge",
+    # query heads a grid step of the flash forward holds, by prefill
+    # program and kind of attention layer (ops/flash_attention.head_block)
+    "serve_flash_heads_per_step": "gauge",
     # latent (MLA) cache leaf and sparse layers
     # (docs/sparse-latent-models.md): the moe families exist for a sparse
     # model only

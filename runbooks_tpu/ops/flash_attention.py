@@ -1,10 +1,34 @@
 """Pallas TPU flash attention (blockwise, O(seq) memory) with custom VJP.
 
 Design (see /opt/skills/guides/pallas_guide.md):
-- Grid (batch, heads, q_blocks, kv_blocks); TPU executes the grid sequentially
-  with the last dimension innermost, so the kernel accumulates the softmax
-  running state (m, l, acc) across kv-block iterations in VMEM scratch and
-  finalizes on the last kv block it visits.
+- A grid step works on ONE KV head and a block of ``G`` of the query heads
+  of its group (``n_rep = h // kv_h`` heads share a KV head). Forward and
+  dq: grid (batch, kv_heads, q_blocks, head_blocks, kv_blocks); dkv: grid
+  (batch, kv_heads, kv_blocks, q_blocks, head_blocks). TPU executes the grid
+  sequentially with the last dimension innermost, so the forward keeps the
+  softmax running state (m, l, acc) of its G heads across kv-block
+  iterations in VMEM scratch with a leading G and finalizes on the last kv
+  block it visits.
+- What does not depend on the head is made once a step, not once a head:
+  the mask of the (query block, kv block) pair (``_mask_bias``: padding,
+  causality, window, segments, as a float32 bias of 0 / NEG_INF in
+  scratch), the K and the V block (one DMA, one cast to float32 into
+  scratch), the kv-side and the query-side rows (one DMA). The heads are a
+  loop INSIDE the step (``jax.lax.fori_loop`` over the block's leading
+  axis), so the live scores stay one head's ``[block_q, block_k]`` float32.
+  Per head the mathematics and the precision are those of a step that held
+  one head: same dots on the same operand types, same order of the running
+  softmax, so the forward and dq are bit-equal for every G.
+- ``G`` is a function of shapes alone (``head_block``), under a stated VMEM
+  budget; a group of one gets G = 1. Where G does not divide the group
+  (``falcon-7b``: 71, a prime) the group's LAST block is PARTIAL: the arrays
+  are viewed as ``[b * kv_h, n_rep, s, d]`` and blocked ``(1, G, block, d)``,
+  Pallas pads the overhanging block (what it reads there is unspecified,
+  what is written there is dropped), and the loops over heads stop at the
+  last real head (``_heads_here``), so the padding is never read. That was
+  taken over padding the group with zero heads because it costs nothing: no
+  padded copy of q / do in HBM, no cut of the outputs, no products for
+  heads that are not there.
 - fp32 accumulation throughout; inputs may be bf16.
 - Masking is by absolute position (causal) + optional segment ids (packed
   sequences), matching runbooks_tpu.ops.attention semantics so the XLA path
@@ -21,12 +45,42 @@ Design (see /opt/skills/guides/pallas_guide.md):
   packed training batch all take the one path, and a block whose every
   score the mask would set to NEG_INF is never loaded or computed.
 - Backward: standard flash backward from saved logsumexp — one kernel for dq
-  (grid over q blocks) and one for dk/dv (grid over kv blocks), both
-  recomputing p blockwise. They still skip by GRID index (``block_skip``:
-  exact only where q storage index i and kv storage index i hold the same
-  position, the training layout), see ``flash_attention``.
-- GQA-native: k/v stay at kv_heads width; the BlockSpec index map routes
-  q head hi to kv head hi // n_rep, so no repeated k/v is ever materialized.
+  (kv blocks innermost) and one for dk/dv (query blocks, then head blocks
+  innermost), both recomputing p blockwise. They still skip by GRID index
+  (``block_skip``: exact only where q storage index i and kv storage index
+  i hold the same position, the training layout), see ``flash_attention``.
+- GQA-native: k/v stay at kv_heads width and no repeated k/v is ever
+  materialized. The dkv kernel sums over the heads of the group in its
+  float32 scratch — for each query block the heads in order, whatever G, so
+  dk / dv are bit-equal for every G too — and writes dk, dv at KV-head
+  width: no ``[b, h, sk, d]`` copies, no sum outside the kernel.
+
+VMEM arithmetic behind ``head_block`` (every element counted at 4 bytes, a
+block's last two dims rounded up to the (8, 128) tile; ``tile(r, c)``
+below). At the model's blocks 512 x 1024 and d = dv = 64, for dq, which
+binds there:
+  a step, whatever G   rows 2 x 2 x (tile(bq, 128) + tile(8, bk))  1.1 MiB
+                       K, V blocks, two buffers each               2.0
+                       their float32 casts                         1.0
+                       the bias tile(bq, bk)                       2.0
+                       live scores of ONE head, 4 x tile(bq, bk)   8.0
+  a head               q, do, dq: two buffers each                 1.5
+                       lse, delta lane-broadcast, two buffers      1.0
+                       dq scratch                                  0.25
+so G = (VMEM_BUDGET_BYTES - 14.1 MiB) // 2.75 MiB = 18 of 71 heads (dkv:
+17.1 MiB a step with dk, dv and 2 MiB a head; the forward: 10.1 and 2.25).
+Each kernel asks Mosaic for what is counted for it (``_ask_vmem``: at 18
+heads 50.6 MiB the forward, 63.6 dq, 53.1 dkv; bf16 blocks take half of
+what is counted for them, which is the compiler's room for what this sum
+cannot see), and a step of one head asks for nothing and holds neither
+the casts nor the bias in scratch. Asking is not free — a 2 ms forward
+that asked for 96 MiB took 0.2-0.8 ms longer on the chip than under the
+default, whatever it used — so nothing asks for more than it counts.
+What more heads a step buy flattens early — on the chip the LoRA forward
+alone reads 7.59 ms at one head a step, 6.70 at 4, 6.28 at 8, 6.06 at 16,
+6.05 at 20 — because the loop over heads is bound by the MXU's slots (73 %
+full in the forward's schedule, 94 % in dq's and dkv's, and the same with
+bf16 operands), not by what a step shares (PERF.md section 6, PR 37).
 
 On non-TPU backends the kernels run in interpreter mode (tests). The
 default ``attention_impl="auto"`` picks this kernel on TPU and the XLA
@@ -64,6 +118,16 @@ DEFAULT_BLOCK_K = 128
 LANES = 128
 SUBLANES = 8
 
+# What head_block lets the buffers it can count take of a v5e core's 128 MiB
+# of VMEM (every element at 4 bytes, so bf16 calls stay far below). A step
+# of several heads asks Mosaic for what is counted for its kernel and no
+# more; a step of one head asks for nothing (Mosaic's default of 16 MiB, as
+# before there were blocks). The limit is not free: on the chip a forward
+# of 2 ms that asked for 96 MiB took 0.2-0.8 ms longer than the same
+# kernel under the default, whatever it then used (PERF.md section 6,
+# PR 37).
+VMEM_BUDGET_BYTES = 64 * 2 ** 20
+
 
 def _bcast_lanes(x):  # [b, s] -> [b, s, LANES]
     return jax.lax.broadcast_in_dim(x, (*x.shape, LANES), (0, 1))
@@ -78,6 +142,84 @@ def _interpret() -> bool:
     # Mosaic on a TPU, the Pallas interpreter everywhere else; a function
     # of the one probe (utils/hw.on_tpu), so nothing on a TPU interprets.
     return not on_tpu()
+
+
+# ---------------------------------------------------------------------------
+# How many query heads a grid step holds
+# ---------------------------------------------------------------------------
+
+def _tile(rows: int, cols: int) -> int:
+    """Bytes of a [rows, cols] block in VMEM at 4 bytes an element: the
+    last two dims are rounded up to the (8, 128) tile (a bf16 block takes
+    half, which the budget does not count on)."""
+    return 4 * (-(-rows // SUBLANES) * SUBLANES) * (-(-cols // LANES) * LANES)
+
+
+def vmem_by_kernel(g: int, block_q: int, block_k: int, d: int, dv: int,
+                   sink: bool = False) -> dict:
+    """{kernel: bytes}: what a grid step of ``g`` heads of the forward, dq
+    and dkv holds in VMEM, as far as shapes say it. The module docstring
+    has the sum in numbers."""
+    bq_d, bq_dv, bq_row = _tile(block_q, d), _tile(block_q, dv), \
+        _tile(block_q, LANES)
+    scores, kv = _tile(block_q, block_k), _tile(block_k, d) + _tile(block_k, dv)
+    # Whatever g: the row data, K and V (two buffers and the float32 cast),
+    # the bias.
+    a_step = 2 * 2 * (bq_row + _tile(SUBLANES, block_k)) + 3 * kv + scores
+    return {
+        # One head's s and p live; a head's q, o, lse in two buffers, its
+        # m, l, acc, its sink's tile.
+        "fwd": a_step + 2 * scores + g * (
+            2 * (bq_d + bq_dv + bq_row) + 2 * _tile(block_q, 1) + bq_dv
+            + (2 * _tile(SUBLANES, LANES) if sink else 0)),
+        # s, p, dp, ds live; a head's q, do, lse, delta, dq in two buffers
+        # and dq's scratch.
+        "dq": a_step + 4 * scores + g * (
+            2 * (2 * bq_d + bq_dv + 2 * bq_row) + bq_d),
+        # dk, dv (scratch and two out buffers) beside the scores; a head's
+        # q, do, lse, delta in two buffers.
+        "dkv": a_step + 4 * scores + 3 * kv + g * 2 * (
+            bq_d + bq_dv + 2 * bq_row)}
+
+
+def vmem_bytes(g: int, block_q: int, block_k: int, d: int, dv: int,
+               sink: bool = False, window: int = 0) -> int:
+    """The largest of vmem_by_kernel over the kernels the call has: a call
+    with a sink, a window or values of another width than keys has a
+    forward only (flash_attention refuses its backward). ``window`` takes
+    no VMEM (it shortens the grid)."""
+    by_kernel = vmem_by_kernel(g, block_q, block_k, d, dv, sink)
+    if sink or window or d != dv:
+        return by_kernel["fwd"]
+    return max(by_kernel.values())
+
+
+def _ask_vmem(kernel: str, g: int, *shape) -> pltpu.CompilerParams:
+    """What a kernel asks Mosaic for: what is counted for a step of ``g``
+    heads of it (vmem_by_kernel; head_block keeps that under the budget),
+    or nothing for a step of one head."""
+    if g == 1:
+        return pltpu.CompilerParams()
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=vmem_by_kernel(g, *shape)[kernel])
+
+
+def head_block(n_rep: int, block_q: int, block_k: int, d: int, dv: int,
+               sink: bool = False, window: int = 0) -> int:
+    """G: how many of a KV head's ``n_rep`` query heads one grid step of
+    the kernels holds. A pure function of shapes: the most heads that
+    ``vmem_bytes`` puts under VMEM_BUDGET_BYTES, then evened out over the
+    blocks the group needs (71 heads at most 18 a step are 4 blocks, three
+    of 18 and a last one of 17; 32 heads at most 18 are 2 blocks of 16).
+    One G for the forward, dq and dkv of a call, sized by the kernel that
+    needs most, so a program has one number to report. A group of one gets
+    1, which is the kernel of one head a step. Never less than 1: blocks
+    too large for the budget at G = 1 are the caller's to shrink, as
+    before."""
+    most = max((g for g in range(1, n_rep + 1)
+                if vmem_bytes(g, block_q, block_k, d, dv, sink, window)
+                <= VMEM_BUDGET_BYTES), default=1)
+    return -(-n_rep // -(-n_rep // most))
 
 
 # ---------------------------------------------------------------------------
@@ -200,15 +342,95 @@ def block_counts(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
     return int(np.maximum(hi - lo + 1, 0).sum()), lo.size * steps
 
 
+def _mask_bias(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref, shape, *,
+               causal: bool, use_segments: bool, window: int = 0):
+    """The mask of one (query block, kv block) pair as what a score takes
+    on: 0 where the query sees the key, NEG_INF where it does not (padding
+    key, causality, window, another segment). It depends on no head, so a
+    grid step makes it once for every head it holds. score + 0 is the
+    score and score + NEG_INF is NEG_INF exactly in float32, so adding it
+    is ``where(mask, score, NEG_INF)`` to the bit, and exp of a masked
+    score minus any row maximum or logsumexp is exactly 0 with no second
+    select."""
+    kp = kv_pos_ref[0][:1, :]                                 # [1, bk]
+    mask = jnp.broadcast_to(kp < PAD_POS, shape)   # padding keys, always
+    if causal or window:
+        qp = q_pos_ref[0][:, :1]                              # [bq, 1]
+    if causal:
+        mask = jnp.logical_and(mask, kp <= qp)
+    if window:
+        mask = jnp.logical_and(mask, qp - kp < window)
+    if use_segments:
+        ks = kv_seg_ref[0][:1, :]
+        mask = jnp.logical_and(mask, q_seg_ref[0][:, :1] == ks)
+        mask = jnp.logical_and(mask, ks != 0)
+    return jnp.where(mask, 0.0, NEG_INF).astype(jnp.float32)
+
+
+def _heads_here(block_axis: int, n_rep: int, g: int):
+    """How many heads of the block a grid step holds are real: all ``g``,
+    but in a group's last block where ``g`` does not divide the group.
+    The rest of that block lies past the array's end: Pallas copies
+    nothing in for it and nothing out, and the loops over heads stop
+    before it, so what its VMEM holds is never read."""
+    if n_rep % g == 0:
+        return g
+    return jnp.minimum(g, n_rep - pl.program_id(block_axis) * g)
+
+
+def _for_heads(heads, g: int, head) -> None:
+    """``head(i)`` for the step's real heads in turn; a step of one head
+    (static) is that head's straight-line code, as before there were
+    blocks."""
+    if g == 1:
+        head(0)
+    else:
+        jax.lax.fori_loop(0, heads, lambda i, carry: head(i), None)
+
+
+def _shared(rows, q_ref, k_ref, v_ref, scratch, **mask):
+    """What a step makes once for all its heads, as a function a head
+    calls to read it: K and V in float32 and the bias of the mask
+    (_mask_bias of the four row refs ``rows`` under the flags ``mask``). A
+    step of several heads makes them into ``scratch`` (k, v, bias) and
+    each head loads them; a step of one head has nothing to share, gets no
+    scratch, and keeps them as values: the step of before there were
+    blocks, under Mosaic's default VMEM limit."""
+    if scratch:
+        k_scr, v_scr, bias_scr = scratch
+        k_scr[:] = k_ref[0, 0].astype(jnp.float32)            # [bk, d]
+        v_scr[:] = v_ref[0, 0].astype(jnp.float32)            # [bk, dv]
+        bias_scr[:] = _mask_bias(*rows, bias_scr.shape, **mask)  # [bq, bk]
+        return lambda: (k_scr[:], v_scr[:], bias_scr[:])
+    k, v = k_ref[0, 0].astype(jnp.float32), v_ref[0, 0].astype(jnp.float32)
+    bias = _mask_bias(*rows, (q_ref.shape[2], k_ref.shape[2]), **mask)
+    return lambda: (k, v, bias)
+
+
+def _shared_scratch(g: int, block_q: int, block_k: int, d: int, dv: int):
+    """_shared's scratch: none for a step of one head."""
+    if g == 1:
+        return []
+    return [pltpu.VMEM((block_k, d), jnp.float32),                # k
+            pltpu.VMEM((block_k, dv), jnp.float32),               # v
+            pltpu.VMEM((block_q, block_k), jnp.float32)]          # bias
+
+
 def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
                 q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
                 q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, use_segments: bool,
-                window: int = 0, has_sink: bool = False):
-    # rest: [sink_ref,] o_ref, lse_ref, m_scr, l_scr, acc_scr.
-    sink_ref = rest[0] if has_sink else None
-    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[-5:]
-    step = pl.program_id(3)
+                n_rep: int, window: int = 0, has_sink: bool = False):
+    # rest: [sink_ref,] o_ref, lse_ref, then scratch: the running state a
+    # head (m, l, acc) and, for a step of several heads, what it makes
+    # once for all of them (_shared).
+    # Grid (b, kv_h, q blocks, head blocks, kv steps).
+    rest = list(rest)
+    sink_ref = rest.pop(0) if has_sink else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr, *scratch = rest
+    g = q_ref.shape[1]
+    heads = _heads_here(3, n_rep, g)
+    step = pl.program_id(4)
     lo = lo_ref[pl.program_id(0), pl.program_id(2)]
     hi = hi_ref[pl.program_id(0), pl.program_id(2)]
     # With a window the grid walks blocks FROM lo (grid_ranges says how
@@ -218,8 +440,8 @@ def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
     @pl.when(jnp.logical_and(hi < lo, step == 0))
     def _nothing_to_see():
         # No query of this block sees any key (a bucket's padded tail).
-        o_ref[0, 0] = jnp.zeros(o_ref.shape[2:], o_ref.dtype)
-        lse_ref[0, 0] = jnp.full(lse_ref.shape[2:], NEG_INF, lse_ref.dtype)
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+        lse_ref[0] = jnp.full(lse_ref.shape[1:], NEG_INF, lse_ref.dtype)
 
     @pl.when(jnp.logical_and(lo <= kv_idx, kv_idx <= hi))
     def _body():
@@ -229,64 +451,57 @@ def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
             l_scr[:] = jnp.zeros_like(l_scr)
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-        q = q_ref[0, 0].astype(jnp.float32)           # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)           # [bk, d]
-        v = v_ref[0, 0].astype(jnp.float32)           # [bk, d]
+        shared = _shared(
+            (q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref), q_ref, k_ref,
+            v_ref, scratch, causal=causal, use_segments=use_segments,
+            window=window)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [bq, bk]
+        def head(i):
+            k, v, bias = shared()
+            q = q_ref[0, i].astype(jnp.float32)               # [bq, d]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias
 
-        kp = kv_pos_ref[0][:1, :]                             # [1, bk]
-        mask = kp < PAD_POS  # padding keys masked regardless of causality
-        mask = jnp.broadcast_to(mask, s.shape)
-        if causal:
-            qp = q_pos_ref[0][:, :1]                          # [bq, 1]
-            mask = jnp.logical_and(mask, kp <= qp)
-        if window:
-            mask = jnp.logical_and(mask, q_pos_ref[0][:, :1] - kp < window)
-        if use_segments:
-            qs = q_seg_ref[0][:, :1]
-            ks = kv_seg_ref[0][:1, :]
-            mask = jnp.logical_and(mask, qs == ks)
-            mask = jnp.logical_and(mask, ks != 0)
-        s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_scr[i]                                 # [bq, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # Rows with no valid key yet keep m == NEG_INF; guard the exp
+            # shift.
+            m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
+            p = jnp.exp(s - m_safe)                # exactly 0 where masked
 
-        m_prev = m_scr[:]                                     # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # Rows with no valid key yet keep m == NEG_INF; guard the exp shift.
-        m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(mask, p, 0.0)
+            alpha = jnp.where(m_prev <= NEG_INF, 0.0,
+                              jnp.exp(m_prev - m_safe))
+            l_scr[i] = alpha * l_scr[i] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[i] = acc_scr[i] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[i] = m_new
 
-        alpha = jnp.where(m_prev <= NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        _for_heads(heads, g, head)
 
         @pl.when(kv_idx == hi)
         def _finalize():
-            l = l_scr[:]
-            m = m_scr[:]
-            acc = acc_scr[:]
-            if has_sink:
-                # The sink joins the softmax here, as one more logit of
-                # this head that gives no value: the running state moves
-                # to max(m, sink) and the sum takes its term.
-                sink = sink_ref[0][:1, :1]                    # [1, 1]
-                m_all = jnp.maximum(m, sink)
-                alpha = jnp.where(m <= NEG_INF, 0.0, jnp.exp(m - m_all))
-                l = alpha * l + jnp.exp(sink - m_all)
-                acc = acc * alpha
-                m = m_all
-            l_safe = jnp.where(l == 0.0, 1.0, l)          # fully-masked rows
-            o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
-            lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(l_safe))  # [bq,1]
-            lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+            def head(i):
+                l = l_scr[i]
+                m = m_scr[i]
+                acc = acc_scr[i]
+                if has_sink:
+                    # The sink joins the softmax here, as one more logit
+                    # of this head that gives no value: the running state
+                    # moves to max(m, sink) and the sum takes its term.
+                    sink = sink_ref[0, i][:1, :1]             # [1, 1]
+                    m_all = jnp.maximum(m, sink)
+                    alpha = jnp.where(m <= NEG_INF, 0.0, jnp.exp(m - m_all))
+                    l = alpha * l + jnp.exp(sink - m_all)
+                    acc = acc * alpha
+                    m = m_all
+                l_safe = jnp.where(l == 0.0, 1.0, l)      # fully-masked rows
+                o_ref[0, i] = (acc / l_safe).astype(o_ref.dtype)
+                lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(l_safe))
+                lse_ref[0, i] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+
+            _for_heads(heads, g, head)
 
 
 def _pad_to(x, size, axis, value=0):
@@ -355,62 +570,75 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
             ki = ki + first
         return jnp.clip(ki, first, jnp.maximum(first, hi_ref[bi, qi]))
 
-    def q_map(bi, hi, qi, ki, *_):
-        return (bi, hi, qi, 0)
+    # The query heads as [b * kv_h, n_rep, ...]: a KV head's group is one
+    # row, blocked G heads a step; G need not divide it (module docstring).
+    g = head_block(n_rep, block_q, block_k, d, dv, sink is not None, window)
 
-    def kv_map(bi, hi, qi, ki, *ranges):
-        # GQA: q head hi reads kv head hi // n_rep — no repeated HBM copy.
-        return (bi, hi // n_rep, kv_block(bi, qi, ki, *ranges), 0)
+    def q_map(bi, kh, qi, hb, ki, *_):
+        return (bi * kv_h + kh, hb, qi, 0)
 
-    def qrow_map(bi, hi, qi, ki, *_):
+    def kv_map(bi, kh, qi, hb, ki, *ranges):
+        return (bi, kh, kv_block(bi, qi, ki, *ranges), 0)
+
+    def qrow_map(bi, kh, qi, hb, ki, *_):
         return (bi, qi, 0)
 
-    def krow_map(bi, hi, qi, ki, *ranges):
+    def krow_map(bi, kh, qi, hb, ki, *ranges):
         return (bi, 0, kv_block(bi, qi, ki, *ranges))
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, use_segments=use_segments,
-        window=window, has_sink=sink is not None)
+        n_rep=n_rep, window=window, has_sink=sink is not None)
     sink_specs, sink_args = [], ()
     if sink is not None:
         # One tile a head, the layout note at the top of the file.
-        sink_specs = [pl.BlockSpec((1, SUBLANES, LANES),
-                                   lambda bi, hi, qi, ki, *_: (hi, 0, 0))]
+        sink_specs = [pl.BlockSpec((1, g, SUBLANES, LANES),
+                                   lambda bi, kh, qi, hb, ki, *_:
+                                   (kh, hb, 0, 0))]
         sink_args = (jax.lax.broadcast_in_dim(
-            sink.astype(jnp.float32), (h, SUBLANES, LANES), (0,)),)
+            sink.astype(jnp.float32).reshape(kv_h, n_rep),
+            (kv_h, n_rep, SUBLANES, LANES), (0, 1)),)
 
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                                # lo, hi
-            grid=(b, h, sq_p // block_q, kv_steps),
+            grid=(b, kv_h, sq_p // block_q, pl.cdiv(n_rep, g), kv_steps),
             in_specs=[
                 pl.BlockSpec((1, block_q, LANES), qrow_map),      # q_pos
                 pl.BlockSpec((1, SUBLANES, block_k), krow_map),   # kv_pos
                 pl.BlockSpec((1, block_q, LANES), qrow_map),      # q_seg
                 pl.BlockSpec((1, SUBLANES, block_k), krow_map),   # kv_seg
-                pl.BlockSpec((1, 1, block_q, d), q_map),          # q
+                pl.BlockSpec((1, g, block_q, d), q_map),          # q
                 pl.BlockSpec((1, 1, block_k, d), kv_map),         # k
                 pl.BlockSpec((1, 1, block_k, dv), kv_map),        # v
                 *sink_specs,
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, block_q, dv), q_map),
-                pl.BlockSpec((1, 1, block_q, LANES), q_map),
+                pl.BlockSpec((1, g, block_q, dv), q_map),
+                pl.BlockSpec((1, g, block_q, LANES), q_map),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((g, block_q, 1), jnp.float32),         # m
+                pltpu.VMEM((g, block_q, 1), jnp.float32),         # l
+                pltpu.VMEM((g, block_q, dv), jnp.float32),        # acc
+                *_shared_scratch(g, block_q, block_k, d, dv),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq_p, dv), out_dtype or q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq_p, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b * kv_h, n_rep, sq_p, dv),
+                                 out_dtype or q.dtype),
+            jax.ShapeDtypeStruct((b * kv_h, n_rep, sq_p, LANES),
+                                 jnp.float32),
         ],
+        compiler_params=_ask_vmem("fwd", g, block_q, block_k, d, dv,
+                                  sink is not None),
         interpret=_interpret(),
     )(lo, hi, q_pos_l, _bcast_sublanes(kv_pos_p),
-      q_seg_l, _bcast_sublanes(kv_seg_p), qT, kT, vT, *sink_args)
+      q_seg_l, _bcast_sublanes(kv_seg_p),
+      qT.reshape(b * kv_h, n_rep, sq_p, d), kT, vT, *sink_args)
+    out = out.reshape(b, h, sq_p, dv)
+    lse = lse.reshape(b, h, sq_p, LANES)
 
     out = jnp.swapaxes(out[:, :, :sq], 1, 2)          # [b, sq, h, dv]
     return out, lse[:, :, :sq, 0]
@@ -430,11 +658,14 @@ def _last_valid_kv(qi, block_q: int, block_k: int, num_kv):
 
 def _bwd_dq_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
                    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_scr,
-                   *, scale, causal, use_segments,
+                   dq_ref, dq_scr, *scratch,
+                   scale, causal, use_segments, n_rep,
                    block_q, block_k, block_skip):
-    kv_idx = pl.program_id(3)
-    num_kv = pl.num_programs(3)
+    # Grid (b, kv_h, q blocks, head blocks, kv blocks).
+    g = q_ref.shape[1]
+    heads = _heads_here(3, n_rep, g)
+    kv_idx = pl.program_id(4)
+    num_kv = pl.num_programs(4)
     if block_skip and causal:
         last_kv = _last_valid_kv(pl.program_id(2), block_q, block_k, num_kv)
     else:
@@ -446,35 +677,35 @@ def _bwd_dq_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
         def _init():
             dq_scr[:] = jnp.zeros_like(dq_scr)
 
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, :1]                            # [bq, 1]
-        delta = delta_ref[0, 0][:, :1]                        # [bq, 1]
+        shared = _shared(
+            (q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref), q_ref, k_ref,
+            v_ref, scratch, causal=causal, use_segments=use_segments)
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = jnp.broadcast_to(kv_pos_ref[0][:1, :] < PAD_POS, s.shape)
-        if causal:
-            mask = jnp.logical_and(
-                mask, kv_pos_ref[0][:1, :] <= q_pos_ref[0][:, :1])
-        if use_segments:
-            mask = jnp.logical_and(
-                mask, q_seg_ref[0][:, :1] == kv_seg_ref[0][:1, :])
-            mask = jnp.logical_and(mask, kv_seg_ref[0][:1, :] != 0)
-        lse_safe = jnp.where(lse <= NEG_INF, 0.0, lse)
-        p = jnp.where(mask, jnp.exp(s - lse_safe), 0.0)
+        def head(i):
+            k, v, bias = shared()
+            q = q_ref[0, i].astype(jnp.float32)
+            do = do_ref[0, i].astype(jnp.float32)
+            lse = lse_ref[0, i][:, :1]                        # [bq, 1]
+            delta = delta_ref[0, i][:, :1]                    # [bq, 1]
 
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_scr[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias
+            lse_safe = jnp.where(lse <= NEG_INF, 0.0, lse)
+            p = jnp.exp(s - lse_safe)              # exactly 0 where masked
+
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * scale
+            dq_scr[i] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        _for_heads(heads, g, head)
 
         @pl.when(kv_idx == last_kv)
         def _finalize():
-            dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+            dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _first_valid_q(ki, block_q: int, block_k: int, num_q):
@@ -487,11 +718,17 @@ def _first_valid_q(ki, block_q: int, block_k: int, num_q):
 
 def _bwd_dkv_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
                     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, use_segments,
+                    dk_ref, dv_ref, dk_scr, dv_scr, *scratch,
+                    scale, causal, use_segments, n_rep,
                     block_q, block_k, block_skip):
-    q_idx = pl.program_id(3)
-    num_q = pl.num_programs(3)
+    # Grid (b, kv_h, kv blocks, q blocks, head blocks): dk_scr / dv_scr take
+    # every head of the group and every query block before they are
+    # written, once, at KV-head width — for each query block the heads in
+    # order, so the float32 sum does not depend on G.
+    g = q_ref.shape[1]
+    heads = _heads_here(4, n_rep, g)
+    q_idx, head_idx = pl.program_id(3), pl.program_id(4)
+    num_q, num_head_blocks = pl.num_programs(3), pl.num_programs(4)
     if block_skip and causal:
         first_q = _first_valid_q(pl.program_id(2), block_q, block_k, num_q)
     else:
@@ -499,40 +736,42 @@ def _bwd_dkv_kernel(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
 
     @pl.when(q_idx >= first_q)
     def _body():
-        @pl.when(q_idx == first_q)
+        @pl.when(jnp.logical_and(q_idx == first_q, head_idx == 0))
         def _init():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
 
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
+        shared = _shared(
+            (q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref), q_ref, k_ref,
+            v_ref, scratch, causal=causal, use_segments=use_segments)
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = jnp.broadcast_to(kv_pos_ref[0][:1, :] < PAD_POS, s.shape)
-        if causal:
-            mask = jnp.logical_and(
-                mask, kv_pos_ref[0][:1, :] <= q_pos_ref[0][:, :1])
-        if use_segments:
-            mask = jnp.logical_and(
-                mask, q_seg_ref[0][:, :1] == kv_seg_ref[0][:1, :])
-            mask = jnp.logical_and(mask, kv_seg_ref[0][:1, :] != 0)
-        lse_safe = jnp.where(lse <= NEG_INF, 0.0, lse)
-        p = jnp.where(mask, jnp.exp(s - lse_safe), 0.0)        # [bq, bk]
+        def head(i):
+            k, v, bias = shared()
+            q = q_ref[0, i].astype(jnp.float32)
+            do = do_ref[0, i].astype(jnp.float32)
+            lse = lse_ref[0, i][:, :1]
+            delta = delta_ref[0, i][:, :1]
 
-        dv_scr[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                          # [bq, bk]
-        dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias
+            lse_safe = jnp.where(lse <= NEG_INF, 0.0, lse)
+            p = jnp.exp(s - lse_safe)    # [bq, bk], exactly 0 where masked
 
-        @pl.when(q_idx == num_q - 1)
+            dv_scr[:] += jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * scale                      # [bq, bk]
+            dk_scr[:] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        _for_heads(heads, g, head)
+
+        @pl.when(jnp.logical_and(q_idx == num_q - 1,
+                                 head_idx == num_head_blocks - 1))
         def _finalize():
             dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -564,14 +803,22 @@ def _shard_plan(q, k) -> Optional[_ShardPlan]:
     if mesh is None or mesh.size == 1:
         return None
     batch = spec_for_array(q.shape[:1], ("batch",), mesh)[0]
-    tp = int(mesh.shape.get("tensor", 1))
-    h, kv_h = q.shape[2], k.shape[2]
-    heads = kv_heads = None
+    h, kv_h = heads_per_shard(q.shape[2], k.shape[2],
+                              int(mesh.shape.get("tensor", 1)))
+    return _ShardPlan(mesh, batch, "tensor" if h < q.shape[2] else None,
+                      "tensor" if kv_h < k.shape[2] else None)
+
+
+def heads_per_shard(h: int, kv_h: int, tp: int) -> tuple:
+    """(query heads, kv heads) a device's launch of the kernels holds
+    under a tensor mesh of ``tp``: both divided where both divide; a
+    single kv head whole on every shard beside its share of the group
+    (multi-query: head_block is handed that share); else nothing shards."""
     if tp > 1 and h % tp == 0 and kv_h % tp == 0:
-        heads = kv_heads = "tensor"
-    elif tp > 1 and h % tp == 0 and kv_h == 1:
-        heads = "tensor"
-    return _ShardPlan(mesh, batch, heads, kv_heads)
+        return h // tp, kv_h // tp
+    if tp > 1 and h % tp == 0 and kv_h == 1:
+        return h // tp, 1
+    return h, kv_h
 
 
 def _per_shard(fn, plan: Optional[_ShardPlan], in_kinds, out_kinds):
@@ -808,113 +1055,122 @@ def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
     num_kv = sk_p // block_k
     num_q = sq_p // block_q
 
+    g_heads = head_block(n_rep, block_q, block_k, d, d)
+    head_blocks = pl.cdiv(n_rep, g_heads)
+    # The query heads as [b * kv_h, n_rep, ...], as in the forward.
+    qG, doG, lseG, deltaG = (x.reshape(b * kv_h, n_rep, *x.shape[2:])
+                             for x in (qT, doT, lseT, deltaT))
+    kernel_args = dict(scale=scale_v, causal=causal,
+                       use_segments=use_segments, n_rep=n_rep,
+                       block_q=block_q, block_k=block_k, block_skip=skip)
+    shared_scratch = _shared_scratch(g_heads, block_q, block_k, d, d)
+
     def clamp_k(i, j):  # dq pass: kv block j valid only up to the diagonal
         if skip:
             return jnp.minimum(j, _last_valid_kv(i, block_q, block_k, num_kv))
         return j
 
-    def clamp_q(j, i):  # dkv pass: q block i valid only from the diagonal on
-        if skip:
-            return jnp.maximum(i, _first_valid_q(j, block_q, block_k, num_q))
-        return i
-
-    def qrow(bi, hi, i, j):
+    def qrow(bi, kh, i, hb, j):
         return (bi, i, 0)
 
-    def krow(bi, hi, i, j):
+    def krow(bi, kh, i, hb, j):
         return (bi, 0, clamp_k(i, j))
 
-    def hq(bi, hi, i, j):
-        return (bi, hi, i, 0)
+    def hq(bi, kh, i, hb, j):
+        return (bi * kv_h + kh, hb, i, 0)
 
-    def hk(bi, hi, i, j):
-        return (bi, hi // n_rep, clamp_k(i, j), 0)
+    def hk(bi, kh, i, hb, j):
+        return (bi, kh, clamp_k(i, j), 0)
 
     # dq: grid inner dim iterates kv blocks
     dq_kernel = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale_v, causal=causal,
-                          use_segments=use_segments, block_q=block_q,
-                          block_k=block_k, block_skip=skip),
-        grid=(b, h, sq_p // block_q, sk_p // block_k),
+        functools.partial(_bwd_dq_kernel, **kernel_args),
+        grid=(b, kv_h, num_q, head_blocks, num_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, LANES), qrow),
             pl.BlockSpec((1, SUBLANES, block_k), krow),
             pl.BlockSpec((1, block_q, LANES), qrow),
             pl.BlockSpec((1, SUBLANES, block_k), krow),
-            pl.BlockSpec((1, 1, block_q, d), hq),
+            pl.BlockSpec((1, g_heads, block_q, d), hq),
             pl.BlockSpec((1, 1, block_k, d), hk),
             pl.BlockSpec((1, 1, block_k, d), hk),
-            pl.BlockSpec((1, 1, block_q, d), hq),
-            pl.BlockSpec((1, 1, block_q, LANES), hq),
-            pl.BlockSpec((1, 1, block_q, LANES), hq),
+            pl.BlockSpec((1, g_heads, block_q, d), hq),
+            pl.BlockSpec((1, g_heads, block_q, LANES), hq),
+            pl.BlockSpec((1, g_heads, block_q, LANES), hq),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d), hq),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d),
+        out_specs=pl.BlockSpec((1, g_heads, block_q, d), hq),
+        out_shape=jax.ShapeDtypeStruct((b * kv_h, n_rep, sq_p, d),
                                        grad_dtype or q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((g_heads, block_q, d), jnp.float32),
+                        *shared_scratch],
+        compiler_params=_ask_vmem("dq", g_heads, block_q, block_k, d, d),
         interpret=_interpret(),
     )
     with jax.named_scope("flash.dq"):
-        dq = dq_kernel(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qT, kT, vT,
-                       doT, lseT, deltaT)
+        dq = dq_kernel(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qG, kT, vT,
+                       doG, lseG, deltaG)
 
-    # dk/dv: grid inner dim iterates q blocks
-    def hq2(bi, hi, j, i):
-        return (bi, hi, clamp_q(j, i), 0)
+    # dk/dv: grid inner dims iterate q blocks, then the group's head blocks
+    def q_step(j, i, hb):
+        # Before the diagonal every step points at the first block that
+        # runs, (first_q, head block 0): a repeated index issues no DMA.
+        if skip:
+            first = _first_valid_q(j, block_q, block_k, num_q)
+            return jnp.maximum(i, first), jnp.where(i < first, 0, hb)
+        return i, hb
 
-    def qrow2(bi, hi, j, i):
-        return (bi, clamp_q(j, i), 0)
+    def hq2(bi, kh, j, i, hb):
+        i, hb = q_step(j, i, hb)
+        return (bi * kv_h + kh, hb, i, 0)
 
-    def hk2_read(bi, hi, j, i):
-        return (bi, hi // n_rep, j, 0)
+    def qrow2(bi, kh, j, i, hb):
+        return (bi, q_step(j, i, hb)[0], 0)
 
-    def hk2_write(bi, hi, j, i):
-        return (bi, hi, j, 0)
+    def krow2(bi, kh, j, i, hb):
+        return (bi, 0, j)
+
+    def hk2(bi, kh, j, i, hb):
+        return (bi, kh, j, 0)
 
     dkv_kernel = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale_v, causal=causal,
-                          use_segments=use_segments, block_q=block_q,
-                          block_k=block_k, block_skip=skip),
-        grid=(b, h, sk_p // block_k, sq_p // block_q),
+        functools.partial(_bwd_dkv_kernel, **kernel_args),
+        grid=(b, kv_h, num_kv, num_q, head_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, LANES), qrow2),
-            pl.BlockSpec((1, SUBLANES, block_k),
-                         lambda bi, hi, j, i: (bi, 0, j)),
+            pl.BlockSpec((1, SUBLANES, block_k), krow2),
             pl.BlockSpec((1, block_q, LANES), qrow2),
-            pl.BlockSpec((1, SUBLANES, block_k),
-                         lambda bi, hi, j, i: (bi, 0, j)),
-            pl.BlockSpec((1, 1, block_q, d), hq2),
-            pl.BlockSpec((1, 1, block_k, d), hk2_read),
-            pl.BlockSpec((1, 1, block_k, d), hk2_read),
-            pl.BlockSpec((1, 1, block_q, d), hq2),
-            pl.BlockSpec((1, 1, block_q, LANES), hq2),
-            pl.BlockSpec((1, 1, block_q, LANES), hq2),
+            pl.BlockSpec((1, SUBLANES, block_k), krow2),
+            pl.BlockSpec((1, g_heads, block_q, d), hq2),
+            pl.BlockSpec((1, 1, block_k, d), hk2),
+            pl.BlockSpec((1, 1, block_k, d), hk2),
+            pl.BlockSpec((1, g_heads, block_q, d), hq2),
+            pl.BlockSpec((1, g_heads, block_q, LANES), hq2),
+            pl.BlockSpec((1, g_heads, block_q, LANES), hq2),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), hk2_write),
-            pl.BlockSpec((1, 1, block_k, d), hk2_write),
+            pl.BlockSpec((1, 1, block_k, d), hk2),
+            pl.BlockSpec((1, 1, block_k, d), hk2),
         ],
+        # At KV-head width: the kernel has summed over the group's heads.
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sk_p, d), grad_dtype or k.dtype),
-            jax.ShapeDtypeStruct((b, h, sk_p, d), grad_dtype or v.dtype),
+            jax.ShapeDtypeStruct((b, kv_h, sk_p, d), grad_dtype or k.dtype),
+            jax.ShapeDtypeStruct((b, kv_h, sk_p, d), grad_dtype or v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),                # dk
+            pltpu.VMEM((block_k, d), jnp.float32),                # dv
+            *shared_scratch,
         ],
+        compiler_params=_ask_vmem("dkv", g_heads, block_q, block_k, d, d),
         interpret=_interpret(),
     )
     with jax.named_scope("flash.dkv"):
-        dk, dv = dkv_kernel(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qT, kT,
-                            vT, doT, lseT, deltaT)
+        dk, dv = dkv_kernel(q_pos_l, kv_pos_s, q_seg_l, kv_seg_s, qG, kT,
+                            vT, doG, lseG, deltaG)
 
-    dq = jnp.swapaxes(dq[:, :, :sq], 1, 2)
-    # dk/dv come back at full q-head width; fold the n_rep group back onto
-    # each kv head (sum over the query heads sharing it).
-    dk = dk.reshape(b, kv_h, n_rep, sk_p, d).sum(axis=2)[:, :, :sk]
-    dv = dv.reshape(b, kv_h, n_rep, sk_p, d).sum(axis=2)[:, :, :sk]
-    dk = jnp.swapaxes(dk, 1, 2).astype(grad_dtype or k.dtype)
-    dv = jnp.swapaxes(dv, 1, 2).astype(grad_dtype or v.dtype)
+    dq = jnp.swapaxes(dq.reshape(b, h, sq_p, d)[:, :, :sq], 1, 2)
+    dk = jnp.swapaxes(dk[:, :, :sk], 1, 2)
+    dv = jnp.swapaxes(dv[:, :, :sk], 1, 2)
     return dq, dk, dv
 
 

@@ -752,6 +752,17 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                       eng.weight_layout["bytes_replaced"],
                       help_text="Bytes of the weight leaves re-placed at "
                                 "load (serve_weight_leaves_replaced).")
+        for program, kinds in eng.flash_head_block.items():
+            for kind, heads in kinds.items():
+                reg.set_gauge(
+                    "serve_flash_heads_per_step", heads, program=program,
+                    kind=kind,
+                    help_text="Query heads of one KV head's group that a "
+                              "grid step of the flash forward holds in "
+                              "this prefill program, by kind of attention "
+                              "layer (ops/flash_attention.head_block: a "
+                              "function of the program's shapes; 1 = a "
+                              "head a step).")
         reg.set_gauge("serve_kv_ring_bytes", occ.get("kv_ring_bytes", 0),
                       help_text="Window layers' ring caches, all slots (0 "
                                 "without such layers): the part of "

@@ -52,6 +52,7 @@ inference is in-framework and TPU-shaped:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import time
 from typing import Any, Callable, List, Optional
@@ -66,6 +67,7 @@ from runbooks_tpu.models.transformer import (
     KVCache,
     forward,
     project_logits,
+    flash_heads_per_step,
     use_flash_cached_prefill,
 )
 from runbooks_tpu.obs import device as obs_device
@@ -1585,6 +1587,7 @@ class InferenceEngine:
             "decode_chunk": self.decode_chunk,
             **run.finish(self.cache),
             "weight_layout": self.weight_layout,
+            "flash_head_block": self.flash_head_block,
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1599,6 +1602,7 @@ class InferenceEngine:
             f"{self.warmup_census['cache_hits']} from the persistent "
             f"cache ({[(c['name'], c['programs']) for c in census]}); "
             f"weight layout {self.weight_layout}; "
+            f"flash heads a step {self.flash_head_block}; "
             f"phases {self.warmup_census['phases']}",
             flush=True)
         # From here on, a compile is a serve-time stall: the sentinel
@@ -2230,6 +2234,19 @@ class InferenceEngine:
             with fine("prefill.activate"):
                 for i, (slot, req) in enumerate(group):
                     self._activate_slot(slot, req, int(first[i]))
+
+    @functools.cached_property
+    def flash_head_block(self) -> dict:
+        """{prefill program: {kind of attention layer: G}}: query heads a
+        grid step of the flash forward holds (ops/flash_attention.
+        head_block), for every row count of the bucket's program. Static
+        per compiled program, a function of its shapes, so it is worked
+        out and not measured. {} where no prefill takes the flash path."""
+        tp = int(self.mesh.shape.get("tensor", 1)) if self.mesh else 1
+        return {f"prefill_b{bucket}": flash_heads_per_step(
+                    self.cfg, bucket, self.max_seq_len + 1, tp)
+                for bucket in self.prefill_buckets
+                if use_flash_cached_prefill(self.cfg, bucket)}
 
     def _count_flash_blocks(self, bucket: int,
                             positions: np.ndarray) -> None:

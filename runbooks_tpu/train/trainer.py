@@ -262,7 +262,10 @@ def run_training(job: TrainJobConfig,
     obs_trace.configure(os.path.join(artifacts, "trace.jsonl"))
     # Persistent compile cache (placed from outside: utils/jax_cache.py):
     # a restarted Job (slice restart / resume) skips the XLA recompile.
-    from runbooks_tpu.models.transformer import resolve_attention_impl
+    from runbooks_tpu.models.transformer import (
+        flash_heads_per_step,
+        resolve_attention_impl,
+    )
     from runbooks_tpu.utils.hw import chip_peaks, device_identity
     from runbooks_tpu.utils.jax_cache import enable_compilation_cache
 
@@ -275,6 +278,13 @@ def run_training(job: TrainJobConfig,
         "compile_cache_dir": enable_compilation_cache(),
         "attention_impl": attention_impl,
     }
+    if attention_impl in ("flash", "ring"):
+        # Query heads a grid step of the flash kernels holds, by kind of
+        # attention layer: a function of the step's shapes alone.
+        shard_len = job.seq_len // int(mesh.shape.get("sequence", 1))
+        identity["flash_head_block"] = flash_heads_per_step(
+            model_cfg, shard_len, shard_len,
+            int(mesh.shape.get("tensor", 1)))
     print(json.dumps({"startup": "train", "model": job.model, **identity,
                       "phases": {**obs_trace.STARTUP.snapshot(),
                                  **phases.snapshot()}}),

@@ -367,3 +367,74 @@ def test_reader_shapes_follow_the_cache():
     # The recurrent leaves change a whole layer a step: that write stays.
     assert shapes[("f32", pool.state.shape)] == pool.state.size // 6
     assert ("f32", pool.state.shape[1:]) not in shapes
+
+
+# --------------------------------------------------------------------------
+# The flash kernels at published widths, by the TPU's compiler
+# --------------------------------------------------------------------------
+
+FLASH_CASES = {
+    # name: (b, sq, sk, heads, kv heads, d, dv, dtype, segments, window,
+    #        sink, backward, the kernels' results in the compiled text)
+    "falcon-7b lora step": (
+        4, 2048, 2048, 71, 1, 64, 64, "bfloat16", True, 0, False, True,
+        ["(bf16[4,71,2048,64], f32[4,71,2048,128])",
+         # dk, dv at KV-head width: one head, not 71 to be added up.
+         "(bf16[4,1,2048,64], bf16[4,1,2048,64])", "bf16[4,71,2048,64]"]),
+    # Every block at the 4 bytes an element head_block counts.
+    "falcon-7b in float32": (
+        1, 2048, 2048, 71, 1, 64, 64, "float32", True, 0, False, True,
+        ["(f32[1,71,2048,64], f32[1,71,2048,128])",
+         "(f32[1,1,2048,64], f32[1,1,2048,64])", "f32[1,71,2048,64]"]),
+    "falcon-40b shard prefill": (
+        1, 2048, 2049, 32, 2, 64, 64, "bfloat16", False, 0, False, False,
+        ["(bf16[2,16,2048,64], f32[2,16,2048,128])"]),
+    "mimo-v2-flash window layer": (
+        1, 2048, 2048, 64, 8, 192, 128, "bfloat16", False, 128, True, False,
+        ["(bf16[8,8,2048,128], f32[8,8,2048,128])"]),
+    "sarvam-105b expanded": (
+        1, 2048, 2049, 64, 64, 192, 128, "bfloat16", False, 0, False, False,
+        ["(bf16[64,1,2048,128], f32[64,1,2048,128])"]),
+}
+
+
+@pytest.mark.parametrize("name", FLASH_CASES)
+def test_flash_kernels_compile_at_published_widths(name, one_chip,
+                                                   monkeypatch):
+    """Mosaic takes the three kernels at the model's blocks (512 x 1024)
+    with the heads a step head_block gives them — the VMEM they ask for
+    included, which the interpreter never checks — and their results keep
+    the shapes the benchmark tells them by: (out, lse); dk and dv of ONE
+    shape, at KV-head width; dq 4-D."""
+    import re
+
+    import runbooks_tpu.ops.flash_attention as fa
+
+    (b, sq, sk, h, kv_h, d, dv, dtype, segments, window, sink, backward,
+     want) = FLASH_CASES[name]
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    dtype = jnp.dtype(dtype)
+
+    def like(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def attend(q, k, v, q_pos, kv_pos, seg, sinks):
+        return fa.flash_attention(
+            q, k, v, q_pos, kv_pos, seg if segments else None,
+            seg if segments else None, True, None, 512, 1024,
+            window=window, sink=sinks if sink else None)
+
+    def step(q, k, v, *rows):
+        if not backward:
+            return attend(q, k, v, *rows)
+        return jax.grad(lambda *qkv: attend(*qkv, *rows).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(step).lower(
+        like((b, sq, h, d), dtype), like((b, sk, kv_h, d), dtype),
+        like((b, sk, kv_h, dv), dtype), like((b, sq), jnp.int32),
+        like((b, sk), jnp.int32), like((b, sq), jnp.int32),
+        like((h,), jnp.float32)).compile().as_text()
+    calls = [re.sub(r"\{[^{}]*\}", "", m) for m in re.findall(
+        r"= (\([^=]*?\)|\S+) custom-call\([^\n]*tpu_custom_call", text)]
+    assert sorted(calls) == sorted(want)

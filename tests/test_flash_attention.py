@@ -457,3 +457,212 @@ def test_save_attn_out_skips_fwd_kernel_recompute():
         counts[policy] = _count_pallas_calls(jaxpr.jaxpr)
     assert counts["nothing_saveable"] == 4, counts
     assert counts["save_attn_out"] == 3, counts
+
+
+# --------------------------------------------------------------------------
+# A grid step holds a block of a KV head's group of query heads
+# --------------------------------------------------------------------------
+
+def _group_case(h, kv_h, layout, s=48, d=16):
+    """(q, k, v, q_pos, kv_pos, q_seg, kv_seg, extra keywords) of one
+    layout at `h` query heads on `kv_h` KV heads; two rows."""
+    dv = d
+    sq = sk = s
+    over = {}
+    rng = np.random.default_rng(h * 131 + kv_h)
+    q_seg = kv_seg = None
+    q_pos = kv_pos = np.broadcast_to(np.arange(s, dtype=np.int32), (2, s))
+    if layout == "packed segments":
+        # Documents behind one another, a padded tail in row 1.
+        lengths = [20, 9, s - 29], [17, 25, s - 42]
+        q_seg = kv_seg = np.stack([np.repeat([1, 2, 3], lengths[0]),
+                                   np.repeat([1, 2, 0], lengths[1])
+                                   ]).astype(np.int32)
+        q_pos = kv_pos = np.stack([np.concatenate(
+            [np.arange(n) for n in row]) for row in lengths]).astype(np.int32)
+    elif layout == "cache view":
+        # 24 queries at positions 17 .. 40 against a cache of 56 slots of
+        # which 41 are written (sk != sq; the static skip is off).
+        sq, sk = 24, 56
+        q_pos = np.broadcast_to(np.arange(17, 41, dtype=np.int32), (2, sq))
+        kv_pos = np.broadcast_to(np.where(np.arange(sk) < 41, np.arange(sk),
+                                          PAD_POS).astype(np.int32), (2, sk))
+    elif layout == "window and sink":
+        over = dict(window=7, sink=jnp.asarray(rng.normal(size=h),
+                                               jnp.float32))
+    elif layout == "192 / 128 widths":
+        d, dv = 24, 16
+    ks = jax.random.split(jax.random.key(h + kv_h), 3)
+    q = jax.random.normal(ks[0], (2, sq, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (2, sk, kv_h, d), jnp.float32)
+    v = jax.random.normal(ks[2], (2, sk, kv_h, dv), jnp.float32)
+    give = (lambda a: None if a is None else jnp.asarray(a))
+    return (q, k, v, give(q_pos), give(kv_pos), give(q_seg), give(kv_seg),
+            over)
+
+
+GROUP_LAYOUTS = ["causal prompt", "packed segments", "cache view",
+                 "window and sink", "192 / 128 widths"]
+
+
+@pytest.mark.parametrize("layout", GROUP_LAYOUTS)
+@pytest.mark.parametrize("h,kv_h", [(71, 1), (16, 2), (8, 8), (5, 1)])
+def test_a_step_of_a_block_of_heads_is_exact(monkeypatch, h, kv_h, layout):
+    """Every ratio of heads (a prime group, two groups of 8, groups of
+    one, a small odd group) in every layout a caller has: the output and,
+    where the call has a backward, dq, dk, dv equal the XLA path at this
+    file's tolerances, and equal the G = 1 kernel BIT FOR BIT — at the G
+    head_block gives (the whole group at these sizes) and at 3 heads a
+    step, whose last block is partial for groups of 71 (2 heads), 8 (2)
+    and 5 (2). Bit for bit and not within an ulp, because a head's
+    arithmetic does not depend on which heads share its step, and dkv adds
+    the heads of a group in one order whatever G (for each query block the
+    heads in turn)."""
+    import runbooks_tpu.ops.flash_attention as fa
+
+    q, k, v, q_pos, kv_pos, q_seg, kv_seg, over = _group_case(h, kv_h, layout)
+    has_bwd = not over and v.shape[-1] == q.shape[-1]
+    weights = jax.random.normal(jax.random.key(3),
+                                (*q.shape[:3], v.shape[-1]), jnp.float32)
+
+    def run(attend):
+        def loss(q, k, v):
+            out = attend(q, k, v)
+            return jnp.sum(out * weights), out
+        if not has_bwd:
+            return (jax.jit(attend)(q, k, v),)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (out, *grads)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, q_pos, kv_pos, q_seg, kv_seg, True,
+                               None, 16, 32, **over)
+
+    def xla(q, k, v):
+        mask = make_attention_mask(q_pos, kv_pos, q_seg, kv_seg)
+        if "window" in over:
+            mask &= (q_pos[:, None, :, None] - kv_pos[:, None, None, :]
+                     < over["window"])
+        return dot_product_attention(q, k, v, mask=mask,
+                                     sink=over.get("sink"))
+
+    n_rep = h // kv_h
+    assert fa.head_block(n_rep, 16, 32, q.shape[-1], v.shape[-1]) == n_rep
+    whole = run(flash)
+    steps = {}
+    for g in (1, 3):
+        monkeypatch.setattr(fa, "head_block",
+                            lambda n_rep, *a, g=g: min(n_rep, g))
+        steps[g] = run(flash)
+    want = run(xla)
+    assert dk_width_is(whole, k) and dk_width_is(steps[3], k)
+    for name, one, three, all_, ref in zip(
+            ("out", "dq", "dk", "dv"), steps[1], steps[3], whole, want):
+        np.testing.assert_array_equal(np.asarray(three), np.asarray(one),
+                                      err_msg=f"{name}: 3 a step")
+        np.testing.assert_array_equal(np.asarray(all_), np.asarray(one),
+                                      err_msg=f"{name}: the whole group")
+        tol = 2e-5 if name == "out" else 1e-4
+        np.testing.assert_allclose(np.asarray(one), np.asarray(ref),
+                                   rtol=tol, atol=tol, err_msg=name)
+
+
+def dk_width_is(results, k):
+    return len(results) == 1 or (results[2].shape == k.shape
+                                 and results[3].shape == k.shape)
+
+
+def test_dk_and_dv_leave_the_kernel_at_kv_head_width():
+    """The dkv kernel sums over the heads of a group itself: its two
+    results are [b, kv_h, sk, d] — one shape, as the benchmark tells the
+    kernel by — dq keeps [b * kv_h, n_rep, sq, d], and nothing after the
+    kernels adds anything up."""
+    from runbooks_tpu.ops.flash_attention import flash_attention_bwd
+
+    b, s, h, kv_h, d = 2, 64, 10, 2, 16
+    q = jnp.zeros((b, s, h, d))
+    k = v = jnp.zeros((b, s, kv_h, d))
+    pos = jnp.zeros((b, s), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention_bwd(
+        q, k, v, pos, pos, None, None, q, jnp.zeros((b, h, s)), q,
+        causal=True, scale=1.0, block_q=32, block_k=32,
+        block_skip=True))(q, k, v)
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert [[o.aval.shape for o in e.outvars] for e in calls] == [
+        [(b * kv_h, h // kv_h, s, d)], [(b, kv_h, s, d)] * 2]
+    after = jaxpr.eqns[jaxpr.eqns.index(calls[1]) + 1:]
+    assert not [e for e in after if e.primitive.name.startswith("reduce")]
+
+
+def test_head_block_is_a_function_of_shapes_under_the_budget():
+    """G = 1 for a group of one; never over the VMEM budget where one head
+    fits at all; never more than the group; the blocks of a group are
+    evened out; not larger at larger blocks or widths; a forward-only call
+    (a sink, a window, 192 / 128) is sized by the forward alone."""
+    from runbooks_tpu.ops.flash_attention import (
+        VMEM_BUDGET_BYTES,
+        _ask_vmem,
+        head_block,
+        vmem_by_kernel,
+        vmem_bytes,
+    )
+
+    # A step of one head asks Mosaic for nothing; a step of several for
+    # what is counted for its kernel, which head_block keeps in budget.
+    assert _ask_vmem("fwd", 1, 512, 1024, 64, 64).vmem_limit_bytes is None
+    asked = [_ask_vmem(kernel, 18, 512, 1024, 64, 64).vmem_limit_bytes
+             for kernel in ("fwd", "dq", "dkv")]
+    assert asked == list(vmem_by_kernel(18, 512, 1024, 64, 64).values())
+    assert max(asked) == vmem_bytes(18, 512, 1024, 64, 64) \
+        <= VMEM_BUDGET_BYTES
+    sizes = [(bq, bk, d) for bq in (128, 256, 512, 1024)
+             for bk in (128, 512, 1024, 2048) for d in (64, 128, 192)]
+    for bq, bk, d in sizes:
+        assert head_block(1, bq, bk, d, d) == 1
+        for n_rep in (2, 5, 8, 16, 32, 71, 128):
+            g = head_block(n_rep, bq, bk, d, d)
+            assert 1 <= g <= n_rep
+            if vmem_bytes(1, bq, bk, d, d) <= VMEM_BUDGET_BYTES:
+                assert vmem_bytes(g, bq, bk, d, d) <= VMEM_BUDGET_BYTES
+            # Evened out: one head fewer a step would need another block.
+            assert g == 1 or -(-n_rep // (g - 1)) > -(-n_rep // g)
+            for bq2, bk2, d2 in ((2 * bq, bk, d), (bq, 2 * bk, d),
+                                 (bq, bk, d + 64)):
+                assert head_block(n_rep, bq2, bk2, d2, d2) <= g
+    # The model's blocks: falcon-7b's 71 on 1, a falcon-40b shard's 16,
+    # mimo-v2-flash's window (8, a sink) and full (16) layers, a group of 1.
+    assert head_block(71, 512, 1024, 64, 64) == 18      # 18, 18, 18, 17
+    assert head_block(16, 512, 1024, 64, 64) == 16
+    assert head_block(8, 512, 1024, 192, 128, True, 128) == 8
+    assert head_block(16, 512, 1024, 192, 128) == 16
+    assert head_block(1, 512, 1024, 192, 128) == 1
+    assert head_block(16, 512, 1024, 192, 192) == 8     # it has a backward
+
+
+@pytest.mark.parametrize("model,tp,q_len,kv_len,want", [
+    # 71 on 1: three blocks of 18 and a partial one of 17.
+    ("falcon-7b", 1, 2048, 2049, {"full_attention": 18}),
+    # The chat buckets: smaller query blocks leave room for more heads.
+    ("falcon-7b", 1, 256, 1025, {"full_attention": 36}),
+    # 128 on 8, four ways: a shard holds 2 KV heads with their 16 each.
+    ("falcon-40b", 4, 2048, 2049, {"full_attention": 16}),
+    # Multi-query under a tensor mesh gives a shard a slice of the group —
+    # where the heads divide (71 does not: nothing shards).
+    ("falcon-7b", 71, 2048, 2049, {"full_attention": 1}),
+    ("falcon-7b", 4, 2048, 2049, {"full_attention": 18}),
+    # Groups of one: the step of one head.
+    ("sarvam-105b", 1, 2048, 2049, {"latent_attention": 1}),
+    ("olmo-hybrid-7b", 1, 2048, 2049, {"full_attention": 1}),
+    # 64 on 4 (full) and on 8 with a sink under a window (forward only).
+    ("mimo-v2-flash", 1, 2048, 2049,
+     {"full_attention": 16, "sliding_attention": 8}),
+])
+def test_heads_a_step_of_the_published_models(model, tp, q_len, kv_len, want):
+    """What the engine's census, /metrics and the trainer's start-up line
+    report for the benchmark's configurations (the published widths; depth
+    does not enter)."""
+    from runbooks_tpu.models.config import get_config
+    from runbooks_tpu.models.transformer import flash_heads_per_step
+
+    assert flash_heads_per_step(get_config(model), q_len, kv_len, tp) == want

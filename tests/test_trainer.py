@@ -133,3 +133,28 @@ def test_params_env_roundtrip(monkeypatch):
     assert params["model"] == "debug"
     env = contract.params_to_env({"steps": 7, "model": "debug"})
     assert env == {"PARAM_STEPS": "7", "PARAM_MODEL": "debug"}
+
+
+def test_start_up_line_says_the_heads_a_flash_step_holds(tmp_path, capsys):
+    """On the flash path the start-up line and the summary carry what
+    ops/flash_attention.head_block answers for the step's shapes, per
+    shard: `debug`'s query heads on its KV heads, two ways over `tensor`.
+    On the XLA path (the CPU's `auto`) there is no such field."""
+    from runbooks_tpu.models.config import get_config
+    from runbooks_tpu.models.transformer import flash_heads_per_step
+
+    cfg = get_config("debug")
+    flash = job(tmp_path, steps=1)
+    flash.model_overrides["attention_impl"] = "flash"
+    summary = run_training(flash)
+    want = flash_heads_per_step(cfg, 32, 32, 2)
+    assert want == {"full_attention":
+                    cfg.num_heads // max(cfg.num_kv_heads, 2)}
+    assert summary["flash_head_block"] == want
+    start = next(json.loads(line) for line in
+                 capsys.readouterr().out.splitlines()
+                 if line.startswith('{"startup": "train"'))
+    assert start["attention_impl"] == "flash"
+    assert start["flash_head_block"] == want
+    assert "flash_head_block" not in run_training(
+        job(tmp_path / "xla", steps=1))

@@ -457,8 +457,9 @@ def test_flash_without_window_or_sink_is_the_plain_forward_bit_for_bit(how):
     if how == "plain":
         text = str(jax.make_jaxpr(lambda *a: flash_attention(
             *a, pos, pos, None, None, True, None, 32, 16))(q, k, v))
-        assert "f32[8,8,128]" not in text        # the sink's tile
-        assert "grid=(2, 8, 3, 6)" in text or "(2, 8, 3, 6)" in text
+        assert "f32[4,2,8,128]" not in text      # the sinks' tiles
+        # (b, kv heads, query blocks, head blocks of a group of 2, kv blocks)
+        assert "(2, 4, 3, 1, 6)" in text
         return
     over = (dict(window=4096) if how == "window hides nothing"
             else dict(sink=jnp.full((8,), -1e30)))
@@ -702,3 +703,74 @@ def test_forward_and_tools_refuse_by_name(what):
 
         with pytest.raises(NotImplementedError, match="sliding_attention"):
             convert(cfg, {})
+
+
+def test_a_program_reports_the_heads_a_step_its_kernels_got(monkeypatch):
+    """What the engine and the trainer publish (flash_heads_per_step, from
+    a configuration and two lengths) is what head_block answers while the
+    program is traced: a cached prefill of 32 tokens on 41 cache slots,
+    full layers 8 heads on 2 (the cache view's keys), window layers 8 on 4
+    with a sink (the call's own keys). The budget is shrunk until a full
+    layer's group of 4 no longer fits a step."""
+    import runbooks_tpu.ops.flash_attention as fa
+    from runbooks_tpu.models.transformer import flash_heads_per_step
+
+    cfg = toy(moe_experts_held=8, flash_block_q=16, flash_block_k=16,
+              attention_impl="flash")
+    monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 300 * 1024)
+    want = flash_heads_per_step(cfg, 32, 41)
+    assert want == {"full_attention": 2, "sliding_attention": 2}
+    asked, answer = [], fa.head_block
+    monkeypatch.setattr(fa, "head_block", lambda *a: asked.append(
+        (a, answer(*a))) or asked[-1][1])
+    p = jax.eval_shape(lambda: init_params(cfg, jax.random.key(7)))
+    cache = jax.eval_shape(lambda: KVCache.create(cfg, 1, 40,
+                                                  trash_slot=True))
+    toks = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    jax.eval_shape(
+        lambda p, cache, toks, pos: forward(
+            cfg, p, toks, positions=pos, cache=cache, token_mask=pos < 40),
+        p, cache, toks, toks)
+    # (group, block_q, block_k, d, dv, sink, window) -> G, once a layer.
+    assert {(a[0], a[5], a[6]): g for a, g in asked} == {
+        (4, False, 0): want["full_attention"],
+        (2, True, cfg.sliding_window): want["sliding_attention"]}
+
+
+def test_metrics_and_the_census_carry_the_heads_a_step():
+    """`serve_flash_heads_per_step{program, kind}` on /metrics and
+    `flash_head_block` of the engine (what warmup_census and
+    /debug/programs repeat): one entry a prefill bucket that takes the
+    flash path, the whole groups at these sizes (4 full, 2 window). (The
+    XLA path's empty census entry: tests/test_engine_weight_layout.py.)"""
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from runbooks_tpu.serve.api import create_server
+    from runbooks_tpu.train.data import ByteTokenizer
+
+    cfg = toy(moe_experts_held=8, attention_impl="flash")
+    params = init_params(cfg, jax.random.key(1))
+    app = create_server(cfg, params, ByteTokenizer(), max_slots=2,
+                        max_seq_len=64, warmup=False)
+    engine = app["worker"].engine
+    want = {f"prefill_b{b}": {"full_attention": 4, "sliding_attention": 2}
+            for b in engine.prefill_buckets if b >= 16}
+    assert want and engine.flash_head_block == want
+
+    async def scrape():
+        async with TestClient(TestServer(app)) as client:
+            return await (await client.get("/metrics")).text()
+
+    try:
+        text = asyncio.run(scrape())
+    finally:
+        app["worker"].stop()
+    lines = {ln.rsplit(" ", 1)[0]: float(ln.rsplit(" ", 1)[1])
+             for ln in text.splitlines()
+             if ln.startswith("serve_flash_heads_per_step{")}
+    assert lines == {
+        f'serve_flash_heads_per_step{{kind="{kind}",program="{program}"}}':
+        float(g) for program, kinds in want.items()
+        for kind, g in kinds.items()}
