@@ -72,6 +72,12 @@ class AttnShape(NamedTuple):
     rope_theta: float
     sink: bool          # a learned logit a query head beside the keys'
     window: int         # 0 = every earlier key
+    heads: int          # query heads: wq, wo and the gate are this wide
+    rotary_dim: int     # the first rotary_dim dimensions of a head rotate
+    rope_yarn: tuple    # () = plain frequencies (ModelConfig.rope_yarn)
+    rope_factor: float  # on sin and cos: the rotated part of a score
+    #                     carries its square, the part that passes 1
+    gate: bool          # sigmoid(u W_g), a number a head, on the core's output
 
 
 MOE_ROUTERS = ("softmax", "sigmoid")
@@ -109,8 +115,8 @@ class ModelConfig:
     moe_aux_coef: float = 0.01        # load-balance loss weight
     # "softmax": softmax over all experts, the chosen k renormalised
     # (Mixtral). "sigmoid": a sigmoid a score, a selection bias added ONLY
-    # to choose, gate weights the chosen scores over their sum, times
-    # moe_routed_scale.
+    # to choose, gate weights the chosen scores over their sum. Either
+    # way the renormalised weights are multiplied by moe_routed_scale.
     moe_router: str = "softmax"
     moe_router_bias: bool = False     # the selection bias (sigmoid router)
     # Standard deviation of the selection bias in SEEDED weights (a
@@ -149,8 +155,12 @@ class ModelConfig:
     rope_theta: float = 10000.0
     # YaRN (ops/rotary.yarn_inv_freq): () = plain rotary, else
     # (factor, original_max_position, beta_fast, beta_slow, mscale,
-    # mscale_all_dim): blended inverse frequencies, and the softmax scale
-    # times yarn_attn_factor ** 2.
+    # mscale_all_dim): blended inverse frequencies over the rotated width,
+    # sin and cos times yarn_rotary_factor = m(mscale) / m(mscale_all_dim),
+    # and (latent attention) the softmax scale times yarn_attn_factor ** 2 =
+    # m(mscale_all_dim) ** 2, with m(a) = 0.1 a ln(factor) + 1. The layers
+    # of params["layers"] take it; window layers rotate plainly (their keys
+    # are never more than a window away).
     rope_yarn: tuple = ()
 
     # Latent attention (layer kind "latent_attention", MLA): what a token
@@ -172,13 +182,19 @@ class ModelConfig:
     attn_value_scale: float = 1.0
     # Layer kind "sliding_attention": a query at position t sees the keys
     # j with 0 <= t - j < sliding_window. Such layers may differ from the
-    # full ones in KV heads and rotary base (0 = as the full layers), and
-    # may have a sink: one learned logit a query head that takes weight in
-    # the softmax and gives no value.
+    # full ones in query heads, KV heads, rotary base and rotated width (0
+    # = as the full layers), and may have a sink: one learned logit a query
+    # head that takes weight in the softmax and gives no value.
     sliding_window: int = 0
     sliding_num_kv_heads: int = 0
     sliding_rope_theta: float = 0.0
     sliding_sink: bool = False
+    sliding_num_heads: int = 0
+    sliding_rotary_dim: int = 0
+    # Per-head output gate, both kinds: g = sigmoid(u W_g) with W_g
+    # [hidden, query heads of the kind] on the layer's normed input u, one
+    # number a head and token, times the core's output before wo.
+    attn_gate: bool = False
 
     # Block structure
     parallel_block: bool = False      # falcon/gpt-neox parallel attn+mlp
@@ -328,13 +344,21 @@ class ModelConfig:
                 raise ValueError(
                     "sliding_attention layers are per-head attention; "
                     "beside latent_attention they have no form")
-            if self.num_heads % self.attn_shape("sliding_attention").kv_heads:
+            window = self.attn_shape("sliding_attention")
+            if window.heads % window.kv_heads:
                 raise ValueError(
-                    "sliding_num_kv_heads does not divide num_heads")
-        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.head_dim:
+                    "sliding_num_kv_heads does not divide the window "
+                    f"layers' {window.heads} query heads")
+        elif self.sliding_num_heads or self.sliding_rotary_dim:
             raise ValueError(
-                f"rotary_dim {self.rotary_dim} is not an even number of a "
-                f"head's {self.head_dim} dimensions")
+                "sliding_num_heads and sliding_rotary_dim describe "
+                "sliding_attention layers; the layer pattern has none")
+        for name in ("rotary_dim", "sliding_rotary_dim"):
+            r = getattr(self, name)
+            if r % 2 or not 0 <= r <= self.head_dim:
+                raise ValueError(
+                    f"{name} {r} is not an even number of a head's "
+                    f"{self.head_dim} dimensions")
         if "latent_attention" in kinds and not (
                 self.kv_lora_rank and self.qk_nope_head_dim
                 and self.qk_rope_head_dim and self.v_head_dim):
@@ -355,6 +379,12 @@ class ModelConfig:
             raise ValueError(
                 "rope_yarn is (factor, original_max_position, beta_fast, "
                 "beta_slow, mscale, mscale_all_dim)")
+        if not self.latent_cache and self.yarn_attn_factor != 1.0:
+            raise ValueError(
+                "rope_yarn with mscale_all_dim on per-head attention: the "
+                "softmax scale's m ** 2 is latent attention's; per-head "
+                "layers carry YaRN's factor on sin and cos (mscale, with "
+                "mscale_all_dim 0)")
         if self.norm_position not in ("pre", "post"):
             raise ValueError(
                 f"unknown norm_position {self.norm_position!r}; "
@@ -407,13 +437,21 @@ class ModelConfig:
         return self.sliding_window + RING_MARGIN
 
     def attn_shape(self, kind: str) -> AttnShape:
-        """KV heads, rotary base, sink and window of the per-head attention
-        layers of `kind`."""
+        """What the per-head attention layers of `kind` are shaped by: KV
+        and query heads, rotary (base, rotated width, YaRN and its factor
+        on sin and cos), sink, window and output gate."""
         if kind == "sliding_attention":
-            return AttnShape(self.sliding_num_kv_heads or self.num_kv_heads,
-                             self.sliding_rope_theta or self.rope_theta,
-                             self.sliding_sink, self.sliding_window)
-        return AttnShape(self.num_kv_heads, self.rope_theta, False, 0)
+            return AttnShape(
+                self.sliding_num_kv_heads or self.num_kv_heads,
+                self.sliding_rope_theta or self.rope_theta,
+                self.sliding_sink, self.sliding_window,
+                self.sliding_num_heads or self.num_heads,
+                self.sliding_rotary_dim or self.rotary_dim or self.head_dim,
+                (), 1.0, self.attn_gate)
+        return AttnShape(self.num_kv_heads, self.rope_theta, False, 0,
+                         self.num_heads, self.rotary_dim or self.head_dim,
+                         self.rope_yarn, self.yarn_rotary_factor,
+                         self.attn_gate)
 
     @property
     def value_head_dim(self) -> int:
@@ -423,10 +461,13 @@ class ModelConfig:
             return self.head_dim
         return self.v_head_dim or self.head_dim
 
-    @property
-    def o_dim(self) -> int:
-        """What per-head attention hands its output projection."""
-        return self.num_heads * self.value_head_dim
+    def q_dim_of(self, kind: str) -> int:
+        """Width of wq's output in a per-head layer of `kind`."""
+        return self.attn_shape(kind).heads * self.head_dim
+
+    def o_dim_of(self, kind: str) -> int:
+        """Width of wo's input in a per-head layer of `kind`."""
+        return self.attn_shape(kind).heads * self.value_head_dim
 
     @property
     def latent_cache(self) -> bool:
@@ -452,18 +493,28 @@ class ModelConfig:
     def moe_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
-    @property
-    def yarn_attn_factor(self) -> float:
-        """m of the YaRN softmax scale, 0.1 mscale_all_dim ln(factor) + 1:
-        scores are scaled by q_head_dim^-1/2 m^2."""
-        if not self.rope_yarn:
+    def _yarn_m(self, a: float) -> float:
+        """m(a) = 0.1 a ln(factor) + 1 of rope_yarn (1 without YaRN)."""
+        if not self.rope_yarn or self.rope_yarn[0] <= 1 or not a:
             return 1.0
         import math
 
-        factor, mscale_all = self.rope_yarn[0], self.rope_yarn[5]
-        if factor <= 1 or not mscale_all:
+        return 0.1 * a * math.log(self.rope_yarn[0]) + 1.0
+
+    @property
+    def yarn_attn_factor(self) -> float:
+        """m of the YaRN softmax scale, m(mscale_all_dim): latent
+        attention's scores are scaled by q_head_dim^-1/2 m^2."""
+        return self._yarn_m(self.rope_yarn[5]) if self.rope_yarn else 1.0
+
+    @property
+    def yarn_rotary_factor(self) -> float:
+        """What YaRN multiplies sin and cos by, m(mscale) /
+        m(mscale_all_dim): 1 where the two are equal."""
+        if not self.rope_yarn:
             return 1.0
-        return 0.1 * mscale_all * math.log(factor) + 1.0
+        return (self._yarn_m(self.rope_yarn[4])
+                / self._yarn_m(self.rope_yarn[5]))
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -522,26 +573,27 @@ class ModelConfig:
         return self._attn_matrices(kind) + self._attn_extras(kind)
 
     def _attn_matrices(self, kind: str) -> int:
-        """wq, wk, wv, wo of a per-head attention layer: queries and keys
-        head_dim wide, values and the output projection's input
-        value_head_dim."""
-        kv = self.attn_shape(kind).kv_heads
+        """wq, wk, wv, wo and the gate of a per-head attention layer:
+        queries and keys head_dim wide, values and the output projection's
+        input value_head_dim, the gate a column a query head."""
+        shape = self.attn_shape(kind)
         return self.hidden_size * (
-            self.q_dim + kv * (self.head_dim + self.value_head_dim)
-            + self.o_dim)
+            self.q_dim_of(kind)
+            + shape.kv_heads * (self.head_dim + self.value_head_dim)
+            + self.o_dim_of(kind) + (shape.heads if shape.gate else 0))
 
     def _attn_extras(self, kind: str) -> int:
         """Per-head attention parameters that are no matrix (biases, norm
         scales, sinks): in num_params, not in flops_per_token."""
         shape = self.attn_shape(kind)
-        k_dim = shape.kv_heads * self.head_dim
-        n = self.num_heads if shape.sink else 0
+        q_dim, k_dim = self.q_dim_of(kind), shape.kv_heads * self.head_dim
+        n = shape.heads if shape.sink else 0
         if self.attn_bias:
-            n += (self.q_dim + k_dim
+            n += (q_dim + k_dim
                   + shape.kv_heads * self.value_head_dim + self.hidden_size)
         if self.qk_norm:
             n += (2 * self.head_dim if self.qk_norm_width == "head"
-                  else self.q_dim + k_dim)
+                  else q_dim + k_dim)
         return n
 
     @property
@@ -604,7 +656,8 @@ class ModelConfig:
         if self.has_window:
             sliding = (
                 2 * self._attn_matrices("sliding_attention")
-                + 2 * min(s, self.sliding_window) * self.num_heads
+                + 2 * min(s, self.sliding_window)
+                * self.attn_shape("sliding_attention").heads
                 * (self.head_dim + self.value_head_dim))
         gates = 2 if self.gated_mlp else 1
         dense = 2 * (gates + 1) * h * self.intermediate_size
@@ -756,6 +809,37 @@ def _mimo_v2(name, v=152576, h=4096, i=16384, periods=7, q=64, kv=4,
     )
 
 
+def _laguna(name, v=100352, h=2048, i=8192, periods=9, q=48, swa_q=64,
+            kv=8, d=128, rot=64, s=262144, window=512, windows_a_period=3,
+            experts=256, top_k=8, moe_i=512,
+            yarn=(64.0, 4096, 64.0, 1.0, 1.0, 0.0)):
+    # Window and full layers that differ in everything but the KV heads:
+    # query heads (q / swa_q), the rotary (full: YaRN over the first `rot`
+    # dimensions of a head, its factor m(1) = 0.1 ln(factor) + 1 on sin and
+    # cos; window: plain, the whole head), and a per-head sigmoid gate on
+    # both. One leading full layer with a dense FFN, then sparse layers:
+    # softmax router, the chosen weights renormalised and times 2.5, one
+    # shared expert (docs/window-full-models.md). The published 40 layers
+    # are (F W W W) x 10: behind the leading layer lie nine whole periods
+    # (W W W F) and three window layers more, a period's remainder that a
+    # repeated pattern cannot say, so the preset is 1 + 9 x 4 = 37 layers.
+    return ModelConfig(
+        name=name, vocab_size=v, hidden_size=h, intermediate_size=i,
+        num_layers=1 + periods * (windows_a_period + 1), num_heads=q,
+        num_kv_heads=kv, head_dim=d, rotary_dim=rot, max_seq_len=s,
+        norm_type="rmsnorm", norm_eps=1e-6, gated_mlp=True,
+        activation="silu", position_type="rope", rope_theta=500000.0,
+        rope_yarn=yarn, attn_gate=True,
+        layer_types=("sliding_attention",) * windows_a_period
+        + ("full_attention",),
+        sliding_window=window, sliding_rope_theta=10000.0,
+        sliding_num_heads=swa_q, sliding_rotary_dim=d,
+        leading_dense_layers=1, moe_num_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_i, moe_shared_experts=1,
+        moe_router="softmax", moe_routed_scale=2.5,
+    )
+
+
 # Registry mirrors the reference's documented example configs
 # (reference: examples/ tree — llama2-7b, llama2-70b, falcon-7b/40b,
 # facebook-opt-125m) plus debug sizes for tests/benchmarks.
@@ -796,6 +880,10 @@ CONFIGS = {
     # count, 192-wide keys on 128-wide values, 256 routed experts
     # (docs/window-full-models.md)
     "mimo-v2-flash": _mimo_v2("mimo-v2-flash"),
+    # Window and full layers of other query head counts, rotaries and a
+    # per-head output gate, 256 narrow experts beside a shared one
+    # (docs/window-full-models.md)
+    "laguna-xs.2": _laguna("laguna-xs.2"),
     # GPT-2 (fused-qkv Conv1D checkpoints; learned positions)
     "gpt2": _gpt2("gpt2"),
     "gpt2-xl": _gpt2("gpt2-xl", h=1600, i=6400, l=48, q=25),
@@ -819,6 +907,15 @@ CONFIGS = {
         "debug-window-full", v=512, h=128, i=384, periods=1, q=8, kv=2,
         swa_kv=4, d=24, vd=16, rot=8, s=256, window=8, windows_a_period=3,
         experts=16, top_k=4, moe_i=64),
+    # The same mechanisms at the published RATIOS and toy widths: 1 dense
+    # full layer + 1 period of (3 window, 1 full), 6 gated query heads with
+    # half a YaRN rotary on full layers, 8 with a plain one on window
+    # layers, 2 KV heads (groups of 3 and 4), window 8, 16 experts beside a
+    # shared one (rbt check, tests)
+    "debug-laguna": _laguna(
+        "debug-laguna", v=512, h=128, i=384, periods=1, q=6, swa_q=8, kv=2,
+        d=16, rot=8, s=256, window=8, experts=16, top_k=4, moe_i=64,
+        yarn=(8.0, 32, 8.0, 1.0, 1.0, 0.0)),
     "bench-1b": _llama("bench-1b", h=2048, i=5632, l=22, q=16, kv=16, d=128, s=2048),
     "bench-410m": _llama("bench-410m", h=1024, i=2816, l=24, q=16, kv=16, d=64, s=2048),
     # Same params/FLOPs as bench-410m but 8 heads x d128: wider MXU

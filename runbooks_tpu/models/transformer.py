@@ -248,27 +248,32 @@ def _init_attention(cfg: ModelConfig, keys, L: int,
         return _init_latent_attention(cfg, keys, L)
     h, pd = cfg.hidden_size, cfg.parameter_dtype
     shape = cfg.attn_shape(kind)
+    q_dim, o_dim = cfg.q_dim_of(kind), cfg.o_dim_of(kind)
     k_dim = shape.kv_heads * cfg.head_dim
     v_dim = shape.kv_heads * cfg.value_head_dim
     attn = {
-        "wq": _dense_init(next(keys), (L, h, cfg.q_dim), pd, h),
+        "wq": _dense_init(next(keys), (L, h, q_dim), pd, h),
         "wk": _dense_init(next(keys), (L, h, k_dim), pd, h),
         "wv": _dense_init(next(keys), (L, h, v_dim), pd, h),
-        "wo": _dense_init(next(keys), (L, cfg.o_dim, h), pd, cfg.o_dim),
+        "wo": _dense_init(next(keys), (L, o_dim, h), pd, o_dim),
     }
     if shape.sink:
         # Not zero: a sink left out of the softmax must change the logits.
         attn["sink"] = jax.random.normal(
-            next(keys), (L, cfg.num_heads)).astype(pd)
+            next(keys), (L, shape.heads)).astype(pd)
+    if shape.gate:
+        # N(0, 1/fan_in) like a matrix: a gate left out (sigmoid = 1
+        # instead of about a half) must change the logits.
+        attn["wg"] = _dense_init(next(keys), (L, h, shape.heads), pd, h)
     if cfg.attn_bias:
-        attn["bq"] = jnp.zeros((L, cfg.q_dim), pd)
+        attn["bq"] = jnp.zeros((L, q_dim), pd)
         attn["bk"] = jnp.zeros((L, k_dim), pd)
         attn["bv"] = jnp.zeros((L, v_dim), pd)
         attn["bo"] = jnp.zeros((L, h), pd)
     if cfg.qk_norm:
         full = cfg.qk_norm_width == "full"
         attn["q_norm"] = jnp.ones(
-            (L, cfg.q_dim if full else cfg.head_dim), pd)
+            (L, q_dim if full else cfg.head_dim), pd)
         attn["k_norm"] = jnp.ones(
             (L, k_dim if full else cfg.head_dim), pd)
     return attn
@@ -311,6 +316,19 @@ def _init_leading_layers(cfg: ModelConfig, rng: jax.Array) -> Params:
             "ln1": _norm_params(cfg, (n,)), "ln2": _norm_params(cfg, (n,))}
 
 
+def _deal_to_positions(in_layer_order: Params, n: int) -> list:
+    """Leaves drawn in layer order [L, …] -> one tree for each of the n
+    positions such a layer has in the period, leaves [L / n, …]: layer l is
+    period l // n, position l % n. Through a reshape, not a strided slice:
+    of a[pos::n] the TPU compiler keeps every whole stack alive beside its
+    dealt copies (6.2 GiB of temporaries at 27 window layers of 32 experts,
+    more than a chip has left beside the weights; PERF.md section 6,
+    PR 38)."""
+    return [jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:])[:, pos],
+        in_layer_order) for pos in range(n)]
+
+
 def _init_window_layers(cfg: ModelConfig, rng: jax.Array) -> list:
     """The sliding-attention layers: a list with one tree for each position
     such a layer has in the period, its leaves stacked [periods, …], as
@@ -328,9 +346,8 @@ def _init_window_layers(cfg: ModelConfig, rng: jax.Array) -> list:
         "ln1": _norm_params(cfg, (L,)), "ln2": _norm_params(cfg, (L,))}
     ffn_key, ffn = _init_ffn(cfg, keys, L)
     in_layer_order[ffn_key] = ffn
-    n = cfg.layer_pattern.count("sliding_attention")
-    return [jax.tree.map(lambda a: a[pos::n], in_layer_order)
-            for pos in range(n)]
+    return _deal_to_positions(
+        in_layer_order, cfg.layer_pattern.count("sliding_attention"))
 
 
 def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
@@ -377,9 +394,8 @@ def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
         "ln1": _norm_params(cfg, (L,)),
         "ln2": _norm_params(cfg, (L,)),
     }
-    n = cfg.layer_pattern.count("linear_attention")
-    return [jax.tree.map(lambda a: a[pos::n], in_layer_order)
-            for pos in range(n)]
+    return _deal_to_positions(
+        in_layer_order, cfg.layer_pattern.count("linear_attention"))
 
 
 def param_logical_axes(cfg: ModelConfig) -> Params:
@@ -425,6 +441,8 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         attn.update({"q_norm": ("layers", "heads" if full else "head_dim"),
                      "k_norm": ("layers",
                                 "kv_heads" if full else "head_dim")})
+    if cfg.attn_gate and not cfg.latent_cache:
+        attn["wg"] = ("layers", "embed", "heads")
 
     if cfg.moe_num_experts:
         from runbooks_tpu.models.moe import moe_logical_axes
@@ -736,23 +754,24 @@ def flash_heads_per_step(cfg: ModelConfig, q_len: int, kv_len: int,
     key heads as query heads (a group of one)."""
     from runbooks_tpu.ops.flash_attention import head_block, heads_per_shard
 
-    def g(kv_heads, keys, d, dv, sink=False, window=0):
-        h, kv_h = heads_per_shard(cfg.num_heads, kv_heads, tensor_parallel)
+    def g(q_heads, kv_heads, keys, d, dv, sink=False, window=0):
+        h, kv_h = heads_per_shard(q_heads, kv_heads, tensor_parallel)
         return head_block(h // kv_h, min(cfg.flash_block_q, q_len),
                           min(cfg.flash_block_k, keys), d, dv, sink, window)
 
     if cfg.latent_cache:
-        heads = {"latent_attention": g(cfg.num_heads, kv_len,
+        heads = {"latent_attention": g(cfg.num_heads, cfg.num_heads, kv_len,
                                        cfg.q_head_dim, cfg.v_head_dim)}
     else:
+        shape = cfg.attn_shape("full_attention")
         heads = {"full_attention": g(
-            cfg.attn_shape("full_attention").kv_heads, kv_len, cfg.head_dim,
+            shape.heads, shape.kv_heads, kv_len, cfg.head_dim,
             cfg.value_head_dim)}
     if cfg.has_window:
         shape = cfg.attn_shape("sliding_attention")
         heads["sliding_attention"] = g(
-            shape.kv_heads, q_len, cfg.head_dim, cfg.value_head_dim,
-            shape.sink, shape.window)
+            shape.heads, shape.kv_heads, q_len, cfg.head_dim,
+            cfg.value_head_dim, shape.sink, shape.window)
     return heads
 
 
@@ -860,8 +879,9 @@ def _attention_block(
 ):
     """Per-head attention of one layer of `kind` (full_attention or
     sliding_attention: ModelConfig.attn_shape gives what they differ in).
-    Keys are head_dim wide and values value_head_dim; the first rotary_dim
-    dimensions of a query or key head rotate."""
+    Keys are head_dim wide and values value_head_dim; the kind's shape says
+    how many query heads there are, how many of a head's dimensions rotate
+    and how, and whether a per-head gate multiplies the core's output."""
     b, s, _ = x.shape
     ad = cfg.activation_dtype
     shape = cfg.attn_shape(kind)
@@ -881,8 +901,8 @@ def _attention_block(
         return y
 
     def rope(t):
-        return apply_rope(t, positions, shape.rope_theta,
-                          rotary_dim=cfg.rotary_dim)
+        return apply_rope(t, positions, shape.rope_theta, shape.rope_yarn,
+                          shape.rotary_dim, shape.rope_factor)
 
     # The named scopes are metadata only (op names in the HLO and in a
     # profiler capture; docs/observability.md): the compiled program and
@@ -897,7 +917,7 @@ def _attention_block(
                 y = rms_norm(y, scale, cfg.norm_eps)
             return y.reshape(b, s, n, d)
 
-        q = heads(proj(p["wq"], "bq", "wq"), p.get("q_norm"), cfg.num_heads)
+        q = heads(proj(p["wq"], "bq", "wq"), p.get("q_norm"), shape.heads)
         k = heads(proj(p["wk"], "bk", "wk"), p.get("k_norm"),
                   shape.kv_heads)
         v = heads(proj(p["wv"], "bv", "wv"), None, shape.kv_heads,
@@ -934,8 +954,14 @@ def _attention_block(
         with jax.named_scope("attn.core"):
             out = _dispatch_attention(cfg, q, k, v, positions, segment_ids,
                                       mask, bias)
+    if shape.gate:
+        with jax.named_scope(sc + ".gate"):
+            # One number a head and token, from the layer's normed input.
+            g = jax.nn.sigmoid(
+                _matmul(x, p["wg"], ad).astype(jnp.float32)).astype(ad)
+            out = out * g[..., None]
     with jax.named_scope(sc + ".out"):
-        out = out.reshape(b, s, cfg.o_dim)
+        out = out.reshape(b, s, shape.heads * cfg.value_head_dim)
         attn_ctx = out
         out = _matmul(out, p["wo"], ad, ring=ring_row, ring_bidir=bidir)
         out = _adapter_delta(adapter, "wo", attn_ctx, out, ad)
@@ -1183,7 +1209,8 @@ def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     scale = cfg.q_head_dim ** -0.5 * cfg.yarn_attn_factor ** 2
 
     def rope(t):
-        return apply_rope(t, positions, cfg.rope_theta, cfg.rope_yarn)
+        return apply_rope(t, positions, cfg.rope_theta, cfg.rope_yarn,
+                          factor=cfg.yarn_rotary_factor)
 
     with jax.named_scope("mla.q"):
         q = _matmul(x, p["wq"], ad).reshape(b, s, H, dn + dr)

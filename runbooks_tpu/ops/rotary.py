@@ -38,8 +38,9 @@ def yarn_inv_freq(head_dim: int, theta: float, yarn: tuple) -> np.ndarray:
 
 
 def rope_sin_cos(positions: jax.Array, head_dim: int, theta: float = 10000.0,
-                 yarn: tuple = ()):
-    """positions [...,] int32 -> (sin, cos) each [..., head_dim//2] float32."""
+                 yarn: tuple = (), factor: float = 1.0):
+    """positions [...,] int32 -> (sin, cos) each [..., head_dim//2] float32,
+    both times ``factor`` (YaRN's factor on the rotated dimensions)."""
     half = head_dim // 2
     if yarn:
         freq = jnp.asarray(yarn_inv_freq(head_dim, theta, yarn))
@@ -47,23 +48,32 @@ def rope_sin_cos(positions: jax.Array, head_dim: int, theta: float = 10000.0,
         freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
                                 / half))
     angles = positions.astype(jnp.float32)[..., None] * freq  # [..., half]
-    return jnp.sin(angles), jnp.cos(angles)
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if factor != 1.0:
+        sin, cos = sin * factor, cos * factor
+    return sin, cos
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-               yarn: tuple = (), rotary_dim: int = 0) -> jax.Array:
+               yarn: tuple = (), rotary_dim: int = 0,
+               factor: float = 1.0) -> jax.Array:
     """Apply RoPE. x: [batch, seq, heads, head_dim]; positions: [batch, seq].
     rotary_dim (0 = head_dim): only the first rotary_dim dimensions of a
     head rotate, as a head of that width would (pairs (x_i,
-    x_{i + rotary_dim/2}), frequencies theta^(-2i/rotary_dim)); the rest
-    pass unchanged."""
+    x_{i + rotary_dim/2}), frequencies theta^(-2i/rotary_dim), YaRN's
+    blend over that width); the rest pass unchanged. ``factor`` multiplies
+    sin and cos, so the rotated dimensions alone: between a query and a key
+    rotated alike, their part of a score carries its square and the part
+    that passes carries 1."""
     if rotary_dim and rotary_dim < x.shape[-1]:
         return jnp.concatenate(
-            [apply_rope(x[..., :rotary_dim], positions, theta, yarn),
+            [apply_rope(x[..., :rotary_dim], positions, theta, yarn,
+                        factor=factor),
              x[..., rotary_dim:]], axis=-1)
     dtype = x.dtype
     half = x.shape[-1] // 2
-    sin, cos = rope_sin_cos(positions, x.shape[-1], theta, yarn)  # [b, s, half]
+    sin, cos = rope_sin_cos(positions, x.shape[-1], theta, yarn,
+                            factor)                       # [b, s, half]
     sin = sin[:, :, None, :]  # broadcast over heads
     cos = cos[:, :, None, :]
     x = x.astype(jnp.float32)

@@ -63,6 +63,12 @@ MODELS = {
     "window-full": (lambda: get_config(
         "debug-window-full", dtype="bfloat16", param_dtype="bfloat16",
         moe_experts_held=8, num_layers=9), False),
+    # The same leaves under layers whose query heads, rotary and per-head
+    # gate differ by kind (stacks of other shapes a position), a shared
+    # expert beside the held ones.
+    "window-full-by-kind": (lambda: get_config(
+        "debug-laguna", dtype="bfloat16", param_dtype="bfloat16",
+        moe_experts_held=8, num_layers=9), False),
 }
 
 
