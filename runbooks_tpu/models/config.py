@@ -227,12 +227,15 @@ class ModelConfig:
     # Measured (v5e-1, bench-410m-d128 bs8x2048 train): flash 44.2% MFU vs
     # xla 23.1% — the XLA path materializes [b,h,s,s] f32 scores in HBM.
     attention_impl: str = "auto"
-    # Flash kernel tile sizes (clamped to seq len). Bigger tiles amortize
-    # the sequential grid and raise arithmetic intensity; v5e sweep:
-    # 512x1024 best (44.2%), 1024x1024/512x512 within 4%; 1024x2048
-    # exceeds the 16 MiB scoped-VMEM limit.
-    flash_block_q: int = 512
-    flash_block_k: int = 1024
+    # Flash kernel block sizes. None = from the call's own shapes
+    # (ops/flash_attention.block_shape: which kernel, the lengths, the
+    # window, the group a grid step holds; its table is a sweep on the chip
+    # at the calls the benchmark's cells compile, PERF.md section 6, PR 39). An
+    # integer is honoured as given (clamped to the lengths): tests pin
+    # 16 / 32 / 64 to get several blocks out of toy lengths. No preset
+    # sets them.
+    flash_block_q: Optional[int] = None
+    flash_block_k: Optional[int] = None
 
     # Ring attention's per-step inner kernel. None = auto: the Pallas
     # flash kernel per rotated K/V block on TPU (out/lse merge forward, a

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -742,37 +742,82 @@ def use_flash_cached_prefill(cfg: ModelConfig, q_len: int) -> bool:
     return on_tpu()
 
 
-def flash_heads_per_step(cfg: ModelConfig, q_len: int, kv_len: int,
-                         tensor_parallel: int = 1) -> dict:
-    """{layer kind: G}: how many query heads one grid step of the flash
-    kernels holds (ops/flash_attention.head_block) when a forward of
-    ``q_len`` queries on ``kv_len`` keys takes the flash path, by kind of
-    attention layer, on the shard one device of a tensor mesh launches.
-    Static per compiled program, so the engine and the trainer publish it
-    at start-up from the shapes they are about to compile. Window layers
-    attend the call's own keys; latent attention is expanded to as many
-    key heads as query heads (a group of one)."""
-    from runbooks_tpu.ops.flash_attention import head_block, heads_per_shard
+class FlashCall(NamedTuple):
+    """The static shapes of one flash call: what ops/flash_attention.
+    block_shape and head_block are asked about while it is traced."""
+    sq: int
+    sk: int
+    d: int
+    dv: int
+    n_rep: int                 # query heads a KV head
+    window: int = 0
+    sink: bool = False
 
-    def g(q_heads, kv_heads, keys, d, dv, sink=False, window=0):
+
+def flash_call_shapes(cfg: ModelConfig, q_len: int, kv_len: int,
+                      tensor_parallel: int = 1) -> dict:
+    """{layer kind: FlashCall}: the flash call a forward of ``q_len``
+    queries on ``kv_len`` keys makes in each kind of attention layer, on
+    the shard one device of a tensor mesh launches. Window layers attend
+    the call's own keys; latent attention is expanded to as many key heads
+    as query heads (a group of one)."""
+    from runbooks_tpu.ops.flash_attention import heads_per_shard
+
+    def call(q_heads, kv_heads, keys, d, dv, window=0, sink=False):
         h, kv_h = heads_per_shard(q_heads, kv_heads, tensor_parallel)
-        return head_block(h // kv_h, min(cfg.flash_block_q, q_len),
-                          min(cfg.flash_block_k, keys), d, dv, sink, window)
+        return FlashCall(q_len, keys, d, dv, h // kv_h, window, sink)
 
     if cfg.latent_cache:
-        heads = {"latent_attention": g(cfg.num_heads, cfg.num_heads, kv_len,
-                                       cfg.q_head_dim, cfg.v_head_dim)}
+        calls = {"latent_attention": call(cfg.num_heads, cfg.num_heads,
+                                          kv_len, cfg.q_head_dim,
+                                          cfg.v_head_dim)}
     else:
         shape = cfg.attn_shape("full_attention")
-        heads = {"full_attention": g(
+        calls = {"full_attention": call(
             shape.heads, shape.kv_heads, kv_len, cfg.head_dim,
             cfg.value_head_dim)}
     if cfg.has_window:
         shape = cfg.attn_shape("sliding_attention")
-        heads["sliding_attention"] = g(
+        calls["sliding_attention"] = call(
             shape.heads, shape.kv_heads, q_len, cfg.head_dim,
-            cfg.value_head_dim, shape.sink, shape.window)
-    return heads
+            cfg.value_head_dim, shape.window, shape.sink)
+    return calls
+
+
+def _flash_block_shape(cfg: ModelConfig, kernel: str, c: FlashCall) -> tuple:
+    """block_shape of a call: cfg.flash_block_q / _k where a test set them,
+    else what the call's shapes say."""
+    from runbooks_tpu.ops.flash_attention import block_shape
+
+    return block_shape(kernel, c.sq, c.sk, c.n_rep, c.window,
+                       cfg.flash_block_q, cfg.flash_block_k)
+
+
+def flash_blocks(cfg: ModelConfig, q_len: int, kv_len: int,
+                 tensor_parallel: int = 1, backward: bool = False) -> dict:
+    """{layer kind: {"fwd": [block_q, block_k][, "bwd": ...]}}: the block
+    shape each flash kernel of such a forward (and its backward) compiles
+    with. Static per compiled program, so the engine and the trainer
+    publish it at start-up, and the engine counts visited blocks at it."""
+    from runbooks_tpu.ops.flash_attention import KERNELS
+
+    return {kind: {kernel: list(_flash_block_shape(cfg, kernel, call))
+                   for kernel in KERNELS if backward or kernel == "fwd"}
+            for kind, call in flash_call_shapes(
+                cfg, q_len, kv_len, tensor_parallel).items()}
+
+
+def flash_heads_per_step(cfg: ModelConfig, q_len: int, kv_len: int,
+                         tensor_parallel: int = 1) -> dict:
+    """{layer kind: G}: how many query heads one grid step of the flash
+    forward holds (ops/flash_attention.head_block at the call's block
+    shape). Static per compiled program, as flash_blocks."""
+    from runbooks_tpu.ops.flash_attention import head_block
+
+    return {kind: head_block(c.n_rep, *_flash_block_shape(cfg, "fwd", c),
+                             c.d, c.dv, c.sink, c.window)
+            for kind, c in flash_call_shapes(
+                cfg, q_len, kv_len, tensor_parallel).items()}
 
 
 def _dispatch_attention(cfg: ModelConfig, q, k, v, positions, segment_ids,
@@ -1039,11 +1084,13 @@ def _window_attention(cfg: ModelConfig, q, k, v, sink, positions,
             if flash:
                 from runbooks_tpu.ops.flash_attention import flash_attention
 
-                # At the full layers' block sizes: with this kernel a key
-                # block of the window's order computes a fifth of the
-                # scores and is SLOWER (a grid step costs over a
-                # microsecond whatever it computes; PERF.md section 6,
-                # PR 32: 18.0 against 13.3 ms a call at [8, 2048]).
+                # The block shape follows the window (ops/flash_attention.
+                # block_shape): a query block of 256 on key blocks of 512
+                # under windows up to 256, today's 512 x 1024 under longer
+                # ones — on the chip 1.97 against 2.13 ms a call at window
+                # 128 with a group of 8 a step, and nothing to gain at
+                # window 512 (PERF.md section 6, PR 39; with ONE head a
+                # step a key block of 128 was slower, PR 32).
                 out = flash_attention(
                     q, k, v, q_pos, kv_pos, segment_ids, segment_ids, True,
                     None, cfg.flash_block_q, cfg.flash_block_k, window=W,

@@ -152,6 +152,9 @@ CATALOG: Dict[str, str] = {
     # query heads a grid step of the flash forward holds, by prefill
     # program and kind of attention layer (ops/flash_attention.head_block)
     "serve_flash_heads_per_step": "gauge",
+    # ... and the block shape it compiled with, side q / k
+    # (ops/flash_attention.block_shape)
+    "serve_flash_block_shape": "gauge",
     # latent (MLA) cache leaf and sparse layers
     # (docs/sparse-latent-models.md): the moe families exist for a sparse
     # model only

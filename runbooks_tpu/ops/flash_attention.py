@@ -55,9 +55,51 @@ Design (see /opt/skills/guides/pallas_guide.md):
   dk / dv are bit-equal for every G too — and writes dk, dv at KV-head
   width: no ``[b, h, sk, d]`` copies, no sum outside the kernel.
 
+The block shape is a function of the call too (``block_shape``: which
+kernel, the lengths, the group, the window), read from a sweep on the chip
+(TPU v5 lite, PERF.md section 6, PR 39) of block_q x block_k at the calls
+the benchmark's cells compile; ms a call with the transposes and the row
+data around the kernel, median of 12, G by ``head_block``:
+  forward                       128x128 256x256 256x512 512x512 256x1024 512x1024 1024x1024 512x2048
+  LoRA [4,2048] 71 on 1, packed   13.97    9.72    7.11    7.44     6.26     6.12      6.23     7.31
+  [8,2048] x 2049, 71 on 1 at 64  26.96   16.52   11.81   11.17    10.49     9.76     11.77    12.11
+  [1,2048] x 2049, 71 on 1 at 64   4.20    3.01    2.29    2.24     2.04     2.04      2.14     2.27
+  a 40b shard, 16 on 2 at 64       2.37    1.76    1.37    1.41     1.26     1.31      1.37     1.41
+  latent expanded, 64 x 1, 192/128 7.21    4.17    3.46    3.25     3.27     2.96      3.10     3.40
+  30 x 1 at 128                    2.79    1.81    1.48    1.45     1.40     1.34      1.50     1.61
+  64 on 4 at 192 / 128             5.12    3.20    2.56    2.56     2.39     2.35      2.61     2.79
+  48 on 8 at 128                   3.35    2.45    1.95    1.86     1.77     1.72      1.85     1.91
+  64 on 8 at 128, window 512       2.96    2.26    1.93    1.95     1.87     1.87      2.16     2.24
+  64 on 8, 192/128, window 128     2.46    2.21    2.08    2.22     2.12     2.19      2.60     2.77
+  (sweep to sweep a reading moves 2-4 %.) Interleaved, 25 rounds, at
+  512x1024 / 256x1024 / 512x512 / 256x512: the last row 2.130 / 2.092 /
+  2.082 / 1.973, its widths at window 256 2.223 / 2.193 / 2.192 / 2.048;
+  64 on 8 at 128: window 512 1.865 / 1.940 / 1.939 / 1.953, window 256
+  1.778 / 1.786 / 1.856 / 1.759, window 128 1.785 / 1.746 / 1.842 / 1.755.
+  backward, LoRA rows             256x256 256x512 512x256 512x512 512x1024 1024x512 1024x1024
+  dq                                 9.39    7.83    8.64    7.65     7.65     7.79      8.92
+  dkv                               12.99    9.39   10.77    7.74     8.13     8.20      8.27
+  both in one call, interleaved: 13.107 at 512x1024, 12.830 at 512x512.
+Finer blocks LOSE, with a group a step as without one: a 512 x 512 forward
+step visits 19 % fewer scores of the packed rows than 512 x 1024 and takes
+22 % longer. What a step shares (the step's fixed cost, the mask, K / V)
+is no longer what a small block pays for; the loop over heads is: by the
+compiler's own schedule a head and block costs 2644 bundles at 512 x 1024,
+1925 at 512 x 512, 1610 at 512 x 256, 1519 at 256 x 1024 and 861 at
+256 x 256 — about 1200 bundles a head that do not shrink with the key
+block (the running softmax's chain from the scores' pop over max, exp and
+sum to the value product and the accumulator's rescale, once a head and
+key step, which nothing overlaps: the heads of a step run one after the
+other). dq (3122 -> 1644 bundles) and dkv (4031 -> 2313) have no such
+chain, so the backward takes the smaller key block for the blocks its
+static skip then leaves out. Larger blocks lose what they visit beyond the
+mask. So: the forward stays at 512 x 1024 whatever the group, but under a
+window of up to 256 with a group a step (256 x 512: a key block that holds
+a query block and its window); the backward runs at 512 x 512.
+
 VMEM arithmetic behind ``head_block`` (every element counted at 4 bytes, a
 block's last two dims rounded up to the (8, 128) tile; ``tile(r, c)``
-below). At the model's blocks 512 x 1024 and d = dv = 64, for dq, which
+below). At the forward's blocks 512 x 1024 and d = dv = 64, for dq, which
 binds there:
   a step, whatever G   rows 2 x 2 x (tile(bq, 128) + tile(8, bk))  1.1 MiB
                        K, V blocks, two buffers each               2.0
@@ -104,8 +146,6 @@ from runbooks_tpu.utils.hw import on_tpu
 
 NEG_INF = -1e30
 PAD_POS = 2 ** 30  # kv-position sentinel for padding; always masked
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 
 # Mosaic requires the last two dims of every block to be (multiples of the
 # (8, 128) tile) or equal to the array dims. Row metadata (positions/segment
@@ -211,8 +251,9 @@ def head_block(n_rep: int, block_q: int, block_k: int, d: int, dv: int,
     ``vmem_bytes`` puts under VMEM_BUDGET_BYTES, then evened out over the
     blocks the group needs (71 heads at most 18 a step are 4 blocks, three
     of 18 and a last one of 17; 32 heads at most 18 are 2 blocks of 16).
-    One G for the forward, dq and dkv of a call, sized by the kernel that
-    needs most, so a program has one number to report. A group of one gets
+    One G for the kernels of a call at one block shape (the forward at
+    its own, dq and dkv at theirs), sized by the kernel that needs most,
+    so a program has one number to report. A group of one gets
     1, which is the kernel of one head a step. Never less than 1: blocks
     too large for the budget at G = 1 are the caller's to shrink, as
     before."""
@@ -220,6 +261,48 @@ def head_block(n_rep: int, block_q: int, block_k: int, d: int, dv: int,
                 if vmem_bytes(g, block_q, block_k, d, dv, sink, window)
                 <= VMEM_BUDGET_BYTES), default=1)
     return -(-n_rep // -(-n_rep // most))
+
+
+# ---------------------------------------------------------------------------
+# The block shape of a call
+# ---------------------------------------------------------------------------
+
+KERNELS = ("fwd", "bwd")       # the backward's two kernels share a shape
+
+
+def block_shape(kernel: str, sq: int, sk: int, n_rep: int, window: int = 0,
+                block_q: Optional[int] = None,
+                block_k: Optional[int] = None) -> tuple:
+    """(block_q, block_k) of a flash call, Python ints from what is static
+    when the call is traced: which kernel ("fwd", or "bwd" for dq and
+    dkv, which share the padded query side), the lengths, the group
+    ``n_rep`` a grid step draws its heads from, the window. A given
+    ``block_q`` / ``block_k`` is honoured (tests pin small ones); the
+    answer is clamped to the lengths, which keeps it a multiple of the
+    (8, 128) tile or the whole length. The table is the module
+    docstring's chip sweep:
+    - forward: 512 x 1024, the flat optimum of every call swept;
+    - forward under a window, where a step holds a group and a key block
+      of 512 holds a query block of 256 with its window (windows up to
+      256): 256 x 512;
+    - backward: 512 x 512 (the static skip by grid index visits 62.5 % of
+      a 2048 x 2048 rectangle where 512 x 1024 visits 75 %)."""
+    if kernel == "bwd":
+        rule = (512, 512)
+    elif window and n_rep > 1 and 256 + window <= 512:
+        rule = (256, 512)
+    else:
+        rule = (512, 1024)
+    return (min(rule[0] if block_q is None else block_q, sq),
+            min(rule[1] if block_k is None else block_k, sk))
+
+
+def blocks_of_call(kernel: str, q, k, block_q: Optional[int],
+                   block_k: Optional[int], window: int = 0) -> tuple:
+    """block_shape of the arrays of a call: q [b, sq, h, d], k
+    [b, sk, kv_h, d]."""
+    return block_shape(kernel, q.shape[1], k.shape[1],
+                       q.shape[2] // k.shape[2], window, block_q, block_k)
 
 
 # ---------------------------------------------------------------------------
@@ -532,8 +615,8 @@ def flash_fwd_qside(q, q_pos, q_seg, block_q):
 
 
 def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
-               block_q, block_k, out_dtype=None, qside=None, window=0,
-               sink=None):
+               block_q=None, block_k=None, out_dtype=None, qside=None,
+               window=0, sink=None):
     b, sq, h, d = q.shape
     # Values may be narrower or wider than keys (latent attention expanded:
     # 192-wide q and k, 128-wide v): the accumulator and the output take
@@ -542,8 +625,7 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
     sk = k.shape[1]
     kv_h = k.shape[2]
     n_rep = h // kv_h
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q, block_k = blocks_of_call("fwd", q, k, block_q, block_k, window)
     sq_p = pl.cdiv(sq, block_q) * block_q
     sk_p = pl.cdiv(sk, block_k) * block_k
 
@@ -854,13 +936,17 @@ def flash_attention(
     kv_segment_ids: Optional[jax.Array],  # [b, sk] or None
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     block_skip: bool = True,
     window: int = 0,
     sink: Optional[jax.Array] = None,  # [h] float
 ) -> jax.Array:
-    """window > 0: a query at position t sees only keys j with t - j <
+    """block_q / block_k: None = block_shape's answer for the call (the
+    forward's and the backward's may differ: the residual lse is a row,
+    not a block); an integer is honoured, clamped to the lengths.
+
+    window > 0: a query at position t sees only keys j with t - j <
     window (beside causality), the ranges follow it and the grid shrinks
     to the blocks a window can span (_flash_fwd). sink: one more logit a
     query head in the softmax, which takes weight and gives no value. Both
@@ -1017,8 +1103,8 @@ def flash_bwd_qside(q, g, out, lse, q_pos, q_seg, block_q):
 
 
 def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
-                        *, causal, scale, block_q, block_k, block_skip,
-                        grad_dtype=None, qside=None):
+                        *, causal, scale, block_q=None, block_k=None,
+                        block_skip, grad_dtype=None, qside=None):
     """Backward kernels (dq, dkv) given the GLOBAL (out, lse) for these
     queries. Besides serving flash_attention's vjp, this is the per-block
     building block of ring attention's backward pass: with global lse the
@@ -1032,8 +1118,7 @@ def flash_attention_bwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, out, lse, g,
     sk = k.shape[1]
     kv_h = k.shape[2]
     n_rep = h // kv_h
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q, block_k = blocks_of_call("bwd", q, k, block_q, block_k)
     sq_p = pl.cdiv(sq, block_q) * block_q
     sk_p = pl.cdiv(sk, block_k) * block_k
 

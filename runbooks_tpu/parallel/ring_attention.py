@@ -174,11 +174,18 @@ def _merge(acc, lse_run, o_blk, lse_blk):
 
 def _ring_flash_fwd_pass(q, k, v, q_positions, kv_positions, q_seg, kv_seg,
                          axis_name, causal, scale, block_q, block_k):
-    from runbooks_tpu.ops.flash_attention import _flash_fwd, flash_fwd_qside
+    from runbooks_tpu.ops.flash_attention import (
+        _flash_fwd,
+        blocks_of_call,
+        flash_fwd_qside,
+    )
 
     n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     f32 = jnp.float32
+    # None = from the shard's shapes, once for every step of the ring: the
+    # hoisted query side is padded to the query block.
+    block_q, block_k = blocks_of_call("fwd", q, k, block_q, block_k)
     # q-side kernel prep is ring-step-invariant: hoist it out of the scan
     # (XLA does not reliably pull it from the while-loop body). Per-block
     # outputs come back f32 so the running accumulator never round-trips
@@ -219,6 +226,7 @@ def _ring_flash_bwd_pass(q, k, v, q_positions, kv_positions, q_seg, kv_seg,
     in f32 (grad_dtype) — no per-step bf16 round-trip — and the q-side
     prep (delta reduction, lane broadcasts) is hoisted out of the scan."""
     from runbooks_tpu.ops.flash_attention import (
+        blocks_of_call,
         flash_attention_bwd,
         flash_bwd_qside,
     )
@@ -226,6 +234,7 @@ def _ring_flash_bwd_pass(q, k, v, q_positions, kv_positions, q_seg, kv_seg,
     n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     f32 = jnp.float32
+    block_q, block_k = blocks_of_call("bwd", q, k, block_q, block_k)
     qside = flash_bwd_qside(q, g, out, lse, q_positions, q_seg, block_q)
 
     dq_acc, dk_acc, dv_acc = flash_attention_bwd(
@@ -262,7 +271,7 @@ def _ring_flash_bwd_pass(q, k, v, q_positions, kv_positions, q_seg, kv_seg,
 def ring_flash_attention_sharded(
     q, k, v, positions, segment_ids, mesh, qspec, kspec, rspec, lse_spec,
     causal: bool = True, scale: Optional[float] = None,
-    block_q: int = 512, block_k: int = 512,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
 ):
     """The SPxflash composition at the UNSHARDED trace level.
 

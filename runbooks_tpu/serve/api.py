@@ -763,6 +763,18 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                               "layer (ops/flash_attention.head_block: a "
                               "function of the program's shapes; 1 = a "
                               "head a step).")
+        for program, kinds in eng.flash_blocks.items():
+            for kind, kernels in kinds.items():
+                for side, block in zip("qk", kernels["fwd"]):
+                    reg.set_gauge(
+                        "serve_flash_block_shape", block, program=program,
+                        kind=kind, side=side,
+                        help_text="Rows of a query block (side q) and of "
+                                  "a key block (side k) of the flash "
+                                  "forward in this prefill program, by "
+                                  "kind of attention layer (ops/"
+                                  "flash_attention.block_shape: a "
+                                  "function of the program's shapes).")
         reg.set_gauge("serve_kv_ring_bytes", occ.get("kv_ring_bytes", 0),
                       help_text="Window layers' ring caches, all slots (0 "
                                 "without such layers): the part of "

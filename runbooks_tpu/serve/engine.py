@@ -67,6 +67,7 @@ from runbooks_tpu.models.transformer import (
     KVCache,
     forward,
     project_logits,
+    flash_blocks,
     flash_heads_per_step,
     use_flash_cached_prefill,
 )
@@ -1588,6 +1589,7 @@ class InferenceEngine:
             **run.finish(self.cache),
             "weight_layout": self.weight_layout,
             "flash_head_block": self.flash_head_block,
+            "flash_blocks": self.flash_blocks,
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1602,7 +1604,8 @@ class InferenceEngine:
             f"{self.warmup_census['cache_hits']} from the persistent "
             f"cache ({[(c['name'], c['programs']) for c in census]}); "
             f"weight layout {self.weight_layout}; "
-            f"flash heads a step {self.flash_head_block}; "
+            f"flash heads a step {self.flash_head_block}, blocks "
+            f"{self.flash_blocks}; "
             f"phases {self.warmup_census['phases']}",
             flush=True)
         # From here on, a compile is a serve-time stall: the sentinel
@@ -2235,18 +2238,31 @@ class InferenceEngine:
                 for i, (slot, req) in enumerate(group):
                     self._activate_slot(slot, req, int(first[i]))
 
+    def _by_flash_program(self, of_shapes) -> dict:
+        """{prefill program: of_shapes(cfg, queries, keys, tensor shards)}
+        for every bucket whose prefill takes the flash path: what is static
+        per compiled program, a function of its shapes, so it is worked out
+        and not measured. {} where no prefill takes the flash path."""
+        tp = int(self.mesh.shape.get("tensor", 1)) if self.mesh else 1
+        return {f"prefill_b{bucket}": of_shapes(
+                    self.cfg, bucket, self.max_seq_len + 1, tp)
+                for bucket in self.prefill_buckets
+                if use_flash_cached_prefill(self.cfg, bucket)}
+
     @functools.cached_property
     def flash_head_block(self) -> dict:
         """{prefill program: {kind of attention layer: G}}: query heads a
         grid step of the flash forward holds (ops/flash_attention.
-        head_block), for every row count of the bucket's program. Static
-        per compiled program, a function of its shapes, so it is worked
-        out and not measured. {} where no prefill takes the flash path."""
-        tp = int(self.mesh.shape.get("tensor", 1)) if self.mesh else 1
-        return {f"prefill_b{bucket}": flash_heads_per_step(
-                    self.cfg, bucket, self.max_seq_len + 1, tp)
-                for bucket in self.prefill_buckets
-                if use_flash_cached_prefill(self.cfg, bucket)}
+        head_block), for every row count of the bucket's program."""
+        return self._by_flash_program(flash_heads_per_step)
+
+    @functools.cached_property
+    def flash_blocks(self) -> dict:
+        """{prefill program: {kind of attention layer: {"fwd": [block_q,
+        block_k]}}}: the block shape the flash forward of the program was
+        compiled with (ops/flash_attention.block_shape, or the
+        configuration's override). _count_flash_blocks counts at it."""
+        return self._by_flash_program(flash_blocks)
 
     def _count_flash_blocks(self, bucket: int,
                             positions: np.ndarray) -> None:
@@ -2254,23 +2270,26 @@ class InferenceEngine:
         computes, by kind of attention layer: one kernel call a row of the
         dispatch, which every layer of the kind repeats. Counted on the
         host from the positions the dispatch was given, by the function
-        the kernel takes its ranges from. Full layers: as
+        the kernel takes its ranges from and at the block shape the
+        program compiled with (flash_blocks). Full layers: as
         models/transformer._cached_attention hands them over (parked
         tokens at -1 against one scratch row of keys). Window layers: as
         _window_attention does (the call's own keys, parked ones padding,
         the ranges of a window), beside the scores a window needs."""
-        if not use_flash_cached_prefill(self.cfg, bucket):
+        blocks = self.flash_blocks.get(f"prefill_b{bucket}")
+        if blocks is None:
             return
         from runbooks_tpu.ops.flash_attention import PAD_POS, block_counts
 
         cfg = self.cfg
         cache_len = self.max_seq_len + 1
         parked = positions >= cache_len - 1
+        full = next(kind for kind in blocks if kind != "sliding_attention")
         visited, grid = block_counts(
             np.where(parked, -1, positions),
             np.broadcast_to(np.arange(cache_len, dtype=np.int32),
                             (positions.shape[0], cache_len)),
-            None, None, cfg.flash_block_q, cfg.flash_block_k, True)
+            None, None, *blocks[full]["fwd"], True)
         obs_metrics.REGISTRY.inc(
             "serve_flash_blocks_visited_total", visited, bucket=str(bucket),
             help_text="(query block, kv block) pairs the flash forward "
@@ -2282,7 +2301,7 @@ class InferenceEngine:
                       "bucket.")
         if not cfg.has_window:
             return
-        block_q, block_k = cfg.flash_block_q, cfg.flash_block_k
+        block_q, block_k = blocks["sliding_attention"]["fwd"]
         visited, grid = block_counts(
             np.where(parked, -1, positions),
             np.where(parked, PAD_POS, positions), None, None,
@@ -2296,8 +2315,7 @@ class InferenceEngine:
                 ("blocks_grid", grid,
                  "(query block, kv block) pairs of the flash forward's "
                  "grid"),
-                ("scores_visited",
-                 visited * min(block_q, bucket) * min(block_k, bucket),
+                ("scores_visited", visited * block_q * block_k,
                  "scores in the blocks the flash forward computed"),
                 ("scores_needed", needed,
                  "scores a window needs (a token at position t sees "

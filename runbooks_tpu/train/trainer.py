@@ -263,6 +263,7 @@ def run_training(job: TrainJobConfig,
     # Persistent compile cache (placed from outside: utils/jax_cache.py):
     # a restarted Job (slice restart / resume) skips the XLA recompile.
     from runbooks_tpu.models.transformer import (
+        flash_blocks,
         flash_heads_per_step,
         resolve_attention_impl,
     )
@@ -279,12 +280,15 @@ def run_training(job: TrainJobConfig,
         "attention_impl": attention_impl,
     }
     if attention_impl in ("flash", "ring"):
-        # Query heads a grid step of the flash kernels holds, by kind of
-        # attention layer: a function of the step's shapes alone.
+        # Query heads a grid step of the flash kernels holds and the
+        # block shape of the forward and the backward, by kind of
+        # attention layer: functions of the step's shapes alone.
         shard_len = job.seq_len // int(mesh.shape.get("sequence", 1))
+        tp = int(mesh.shape.get("tensor", 1))
         identity["flash_head_block"] = flash_heads_per_step(
-            model_cfg, shard_len, shard_len,
-            int(mesh.shape.get("tensor", 1)))
+            model_cfg, shard_len, shard_len, tp)
+        identity["flash_blocks"] = flash_blocks(
+            model_cfg, shard_len, shard_len, tp, backward=True)
     print(json.dumps({"startup": "train", "model": job.model, **identity,
                       "phases": {**obs_trace.STARTUP.snapshot(),
                                  **phases.snapshot()}}),

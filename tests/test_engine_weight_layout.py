@@ -253,7 +253,9 @@ def test_gauges_census_line_and_span(model, monkeypatch, tmp_path, capsys):
     assert programs["warmup_census"]["weight_layout"] == engine.weight_layout
     # No prefill of this engine takes the flash path (XLA off the TPU).
     assert programs["warmup_census"]["flash_head_block"] == {}
+    assert programs["warmup_census"]["flash_blocks"] == {}
     assert "serve_flash_heads_per_step" not in text
+    assert "serve_flash_block_shape" not in text
     assert f"'bytes_replaced': {moved}" in capsys.readouterr().out
     events = [json.loads(ln.rstrip(",")) for ln in
               (tmp_path / "trace.jsonl").read_text().splitlines()

@@ -666,3 +666,190 @@ def test_heads_a_step_of_the_published_models(model, tp, q_len, kv_len, want):
     from runbooks_tpu.models.transformer import flash_heads_per_step
 
     assert flash_heads_per_step(get_config(model), q_len, kv_len, tp) == want
+
+
+# --------------------------------------------------------------------------
+# The block shape of a call comes from its shapes (block_shape)
+# --------------------------------------------------------------------------
+
+def test_block_shape_is_a_pure_function_of_static_shapes():
+    """Python ints, multiples of the (8, 128) tile or the whole length,
+    never over the lengths, the same for the same shapes; a given size is
+    honoured and clamped; a group of one keeps 512 x 1024 under a window
+    too (nothing shares its step's fixed cost)."""
+    from runbooks_tpu.ops.flash_attention import (
+        KERNELS,
+        LANES,
+        SUBLANES,
+        block_shape,
+    )
+
+    for kernel in KERNELS:
+        for sq in (1, 24, 128, 300, 512, 2048, 8192):
+            for sk in (sq, sq + 1, 2049):
+                for n_rep in (1, 8, 71):
+                    for window in (0, 128, 256, 512, 4096):
+                        got = block_shape(kernel, sq, sk, n_rep, window)
+                        bq, bk = got
+                        assert type(bq) is int and type(bk) is int
+                        assert 0 < bq <= sq and 0 < bk <= sk
+                        assert bq == sq or bq % SUBLANES == 0
+                        assert bk == sk or bk % LANES == 0
+                        assert got == block_shape(kernel, sq, sk, n_rep,
+                                                  window)
+    assert block_shape("fwd", 40, 48, 4, 0, 16, 32) == (16, 32)
+    assert block_shape("bwd", 40, 48, 4, 0, 64, None) == (40, 48)
+    assert block_shape("fwd", 2048, 2048, 1, 128) == (512, 1024)
+    assert block_shape("fwd", 2048, 2048, 8, 128) == (256, 512)
+    assert block_shape("fwd", 2048, 2048, 8, 384) == (512, 1024)
+
+
+@pytest.mark.parametrize("model,tp,q_len,kv_len,backward,want", [
+    # The LoRA step, [4, 2048] packed rows: the backward's own shape.
+    ("falcon-7b", 1, 2048, 2048, True,
+     {"full_attention": {"fwd": [512, 1024], "bwd": [512, 512]}}),
+    # doc_flood's prefill of one row against the cache view.
+    ("falcon-7b", 1, 2048, 2049, False,
+     {"full_attention": {"fwd": [512, 1024]}}),
+    # A chat bucket: clamped to the queries.
+    ("falcon-7b", 1, 128, 1025, False,
+     {"full_attention": {"fwd": [128, 1024]}}),
+    ("falcon-40b", 4, 2048, 2049, False,
+     {"full_attention": {"fwd": [512, 1024]}}),
+    # Groups of one.
+    ("sarvam-105b", 1, 2048, 2049, False,
+     {"latent_attention": {"fwd": [512, 1024]}}),
+    ("olmo-hybrid-7b", 1, 2048, 2049, False,
+     {"full_attention": {"fwd": [512, 1024]}}),
+    # Window 128 with a group of 8: a key block that holds a query block
+    # and its window. Window 512: nothing to gain below 512 x 1024.
+    ("mimo-v2-flash", 1, 2048, 2049, False,
+     {"full_attention": {"fwd": [512, 1024]},
+      "sliding_attention": {"fwd": [256, 512]}}),
+    ("laguna-xs.2", 1, 2048, 2049, False,
+     {"full_attention": {"fwd": [512, 1024]},
+      "sliding_attention": {"fwd": [512, 1024]}}),
+])
+def test_block_shapes_of_the_published_models(model, tp, q_len, kv_len,
+                                              backward, want):
+    """The table of the chip sweep (the module docstring), as the engine's
+    census, /metrics and the trainer's start-up line report it for the
+    benchmark's configurations. No preset sets a block size."""
+    from runbooks_tpu.models.config import get_config
+    from runbooks_tpu.models.transformer import flash_blocks
+
+    cfg = get_config(model)
+    assert cfg.flash_block_q is None and cfg.flash_block_k is None
+    assert flash_blocks(cfg, q_len, kv_len, tp, backward) == want
+
+
+def _rule_case(layout):
+    """A call long enough for block_shape's own answers to give several
+    blocks each way: (q, k, v, q_pos, kv_pos, q_seg, kv_seg, keywords)."""
+    rng = np.random.default_rng(len(layout))
+    h, kv_h, d, dv, sq, sk, over = 4, 2, 16, 16, 1100, 1100, {}
+    q_seg = kv_seg = None
+    if layout == "cached prefill at an offset, parked tails":
+        # 640 queries of which 600 are real, at positions 700 .. 1299 of a
+        # cache view of 1301 slots; parked tokens at -1 (the engine's).
+        sq, sk = 640, 1301
+        q_pos = np.where(np.arange(sq) < 600, np.arange(sq) + 700, -1)
+        kv_pos = np.arange(sk)
+    elif layout.startswith("window"):
+        # A group of 4 a step; window 128 takes the rule's 256 x 512, 512
+        # its 512 x 1024. Parked tail as _window_attention hands it over.
+        d, dv = 24, 16
+        over = dict(window=int(layout.split()[1]))
+        if "sink" in layout:
+            over["sink"] = jnp.asarray(rng.normal(size=h), jnp.float32)
+        q_pos = np.where(np.arange(sq) < 1000, np.arange(sq), -1)
+        kv_pos = np.where(np.arange(sk) < 1000, np.arange(sk), PAD_POS)
+    elif layout.startswith("packed"):
+        # Documents behind one another; a boundary ON the forward's query
+        # block edge (512) and the backward's key block edge, or off both.
+        cuts = [512, 1024] if "on" in layout else [300, 777]
+        lengths = np.diff([0, *cuts, sq - 60])
+        seg = np.concatenate([np.repeat(np.arange(1, 4), lengths),
+                              np.zeros(60, np.int64)])
+        q_seg = kv_seg = seg
+        q_pos = kv_pos = np.concatenate(
+            [np.arange(n) for n in (*lengths, 60)])
+    elif layout == "MQA 71, a partial head block":
+        h, kv_h, sq, sk = 71, 1, 520, 1030
+        q_pos, kv_pos = np.arange(sq) + 500, np.arange(sk)
+    ks = jax.random.split(jax.random.key(7), 3)
+    q = jax.random.normal(ks[0], (1, sq, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, kv_h, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, kv_h, dv), jnp.float32)
+    row = (lambda a: None if a is None
+           else jnp.asarray(np.asarray(a, np.int32)[None]))
+    return (q, k, v, row(q_pos), row(kv_pos), row(q_seg), row(kv_seg), over)
+
+
+def _xla(q, k, v, q_pos, kv_pos, q_seg, kv_seg, over):
+    mask = make_attention_mask(q_pos, kv_pos, q_seg, kv_seg)
+    if "window" in over:
+        mask &= (q_pos[:, None, :, None] - kv_pos[:, None, None, :]
+                 < over["window"])
+    return dot_product_attention(q, k, v, mask=mask, sink=over.get("sink"))
+
+
+RULE_LAYOUTS = ["cached prefill at an offset, parked tails",
+                "window 128 and a sink", "window 512",
+                "packed on a block edge", "packed off a block edge",
+                "MQA 71, a partial head block"]
+
+
+@pytest.mark.parametrize("layout", RULE_LAYOUTS)
+def test_forward_at_the_shapes_the_rule_answers(monkeypatch, layout):
+    """With no block size given the forward runs at block_shape's answer
+    for the call (several blocks each way at these lengths) and equals the
+    XLA path on every real row; parked rows come out exactly 0."""
+    import runbooks_tpu.ops.flash_attention as fa
+
+    *call, over = _rule_case(layout)
+    q, k, _, q_pos = call[:4]
+    n_rep = q.shape[2] // k.shape[2]
+    want_blocks = {"window 128 and a sink": (256, 512)}.get(
+        layout, (512, 1024))
+    assert fa.block_shape("fwd", q.shape[1], k.shape[1], n_rep,
+                          over.get("window", 0)) == want_blocks
+    if n_rep == 71:
+        # 71 = 3 x 18 + 17 at the published widths; at these toy widths
+        # the budget is shrunk until the group's last block is partial.
+        monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 24 * 2 ** 20)
+        g = fa.head_block(71, 512, 1024, 16, 16)
+        assert 1 < g < 71 and 71 % g
+    got = jax.jit(lambda *a: flash_attention(*a, True, None, **over))(*call)
+    want = _xla(*call, over)
+    real = np.asarray(q_pos >= 0)[:, :, None, None]
+    if call[5] is not None:
+        real = real & np.asarray(call[5] != 0)[:, :, None, None]
+    np.testing.assert_allclose(np.where(real, got, 0),
+                               np.where(real, want, 0),
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(got)[~np.broadcast_to(real, got.shape)].any()
+
+
+@pytest.mark.parametrize("layout", ["packed on a block edge",
+                                    "packed off a block edge"])
+def test_gradients_at_the_backwards_own_shape(layout):
+    """jax.grad through a call with no block size given: the forward at
+    512 x 1024, dq and dkv at 512 x 512 with the static skip on (three
+    blocks each way at 1100 packed tokens), against the XLA path."""
+    import runbooks_tpu.ops.flash_attention as fa
+
+    *call, _ = _rule_case(layout)
+    q, k, v, *rows = call
+    assert fa.block_shape("bwd", q.shape[1], k.shape[1], 2) == (512, 512)
+    weights = jax.random.normal(jax.random.key(3), q.shape, jnp.float32)
+
+    def grads(attend):
+        return jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v) * weights),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(q, k, v, *rows, True, None))
+    want = grads(lambda q, k, v: _xla(q, k, v, *rows, {}))
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)

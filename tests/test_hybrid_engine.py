@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from runbooks_tpu.models.transformer import KVCache
+from runbooks_tpu.models.transformer import KVCache, flash_heads_per_step
 from runbooks_tpu.serve.engine import (
     InferenceEngine,
     Request,
@@ -97,6 +97,45 @@ def test_flash_prefill_counts_the_blocks_it_visits(reference):
     assert all(f'{n}{{bucket="64"}}' in text for n in names)
     assert len(req.output_tokens) == 6
     assert served_gap(reference, req) < GAP_LIMIT
+
+
+@pytest.mark.parametrize("given", [(16, 32), (32, None), (None, None)],
+                         ids=["pinned", "half pinned", "from the shapes"])
+def test_flash_counters_are_taken_at_the_compiled_block_shape(monkeypatch,
+                                                              given):
+    """What _count_flash_blocks bumps is block_counts at the shape the
+    prefill program was traced with, which is what the engine publishes
+    (flash_blocks): a configuration's sizes where a test pins them, else
+    ops/flash_attention.block_shape's answer for the program's shapes (a
+    bucket of 64 on 129 cache slots: one block of each)."""
+    import runbooks_tpu.ops.flash_attention as fa
+    from runbooks_tpu.obs.metrics import REGISTRY
+
+    cfg = tiny_config(dtype="float32", param_dtype="bfloat16",
+                      attention_impl="flash", flash_block_q=given[0],
+                      flash_block_k=given[1])
+    traced, answer = [], fa.blocks_of_call
+    monkeypatch.setattr(fa, "blocks_of_call", lambda *a, **kw: traced.append(
+        answer(*a, **kw)) or traced[-1])
+    eng = InferenceEngine(cfg, seeded_params(cfg, SEED), max_slots=2,
+                          max_seq_len=128)
+    (req,) = requests([(40, 2)])
+    names = ("serve_flash_blocks_visited_total",
+             "serve_flash_blocks_grid_total")
+    before = [REGISTRY.counter_value(n, bucket="64") for n in names]
+    eng.generate([req])
+    counted = tuple(REGISTRY.counter_value(n, bucket="64") - b
+                    for n, b in zip(names, before))
+    blocks = eng.flash_blocks["prefill_b64"]["full_attention"]["fwd"]
+    assert blocks == [given[0] or 64, given[1] or 129]
+    assert set(traced) == {tuple(blocks)}
+    q_pos = np.full((1, 64), -1, np.int32)
+    q_pos[0, :40] = np.arange(40)
+    kv_pos = np.arange(129, dtype=np.int32)[None]
+    assert counted == fa.block_counts(q_pos, kv_pos, None, None, *blocks,
+                                      True)
+    assert eng.flash_head_block["prefill_b64"] == flash_heads_per_step(
+        cfg, 64, 129)
 
 
 @pytest.mark.parametrize("chunk", [1, 4])

@@ -112,6 +112,7 @@ def test_engine_slots_at_different_lengths_and_a_reused_slot():
                for n, seed in ((17, 6), (29, 7), (20, 8))]
     reqs = [Request(prompt_tokens=list(q), max_tokens=m, temperature=0.0)
             for q, m in zip(prompts, (3, 21, 5))]
+    before = obs_metrics.REGISTRY.render()
     eng.generate(reqs)
     for q, r in zip(prompts, reqs):
         seq = np.asarray(q + r.output_tokens, np.int32)
@@ -126,9 +127,11 @@ def test_engine_slots_at_different_lengths_and_a_reused_slot():
     # sits in a 32-token bucket of two 16-key blocks; a query block sees
     # its own block and the one before it.
     fams = obs_metrics.REGISTRY.render()
-    read = lambda name: sum(  # noqa: E731
-        float(line.rsplit(" ", 1)[1]) for line in fams.splitlines()
+    total = lambda text, name: sum(  # noqa: E731
+        float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
         if line.startswith(name + "{"))
+    # Since this engine's requests: the registry is the process's.
+    read = lambda name: total(fams, name) - total(before, name)  # noqa: E731
     assert read("serve_window_blocks_visited_total") == 3 * 3
     assert read("serve_window_scores_visited_total") == 9 * 16 * 16
     assert read("serve_window_scores_needed_total") == sum(

@@ -156,5 +156,9 @@ def test_start_up_line_says_the_heads_a_flash_step_holds(tmp_path, capsys):
                  if line.startswith('{"startup": "train"'))
     assert start["attention_impl"] == "flash"
     assert start["flash_head_block"] == want
-    assert "flash_head_block" not in run_training(
-        job(tmp_path / "xla", steps=1))
+    # ... and the block shape of the forward and of the backward
+    # (block_shape's answers, clamped to 32 tokens a row).
+    blocks = {"full_attention": {"fwd": [32, 32], "bwd": [32, 32]}}
+    assert start["flash_blocks"] == summary["flash_blocks"] == blocks
+    xla = run_training(job(tmp_path / "xla", steps=1))
+    assert "flash_head_block" not in xla and "flash_blocks" not in xla

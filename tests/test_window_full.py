@@ -611,6 +611,7 @@ def test_engine_slots_at_different_lengths_and_a_reused_slot():
                for n, seed in ((17, 6), (29, 7), (20, 8))]
     reqs = [Request(prompt_tokens=list(q), max_tokens=m, temperature=0.0)
             for q, m in zip(prompts, (3, 21, 5))]
+    before = obs_metrics.REGISTRY.render()
     eng.generate(reqs)
     for q, r in zip(prompts, reqs):
         seq = np.asarray(q + r.output_tokens, np.int32)
@@ -626,9 +627,11 @@ def test_engine_slots_at_different_lengths_and_a_reused_slot():
     # sits in a 32-token bucket of two 16-key blocks; a query block sees
     # its own block and the one before it.
     fams = obs_metrics.REGISTRY.render()
-    read = lambda name: sum(  # noqa: E731
-        float(line.rsplit(" ", 1)[1]) for line in fams.splitlines()
+    total = lambda text, name: sum(  # noqa: E731
+        float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
         if line.startswith(name + "{"))
+    # Since this engine's requests: the registry is the process's.
+    read = lambda name: total(fams, name) - total(before, name)  # noqa: E731
     assert read("serve_window_blocks_visited_total") == 3 * 3
     assert read("serve_window_blocks_grid_total") == 3 * 2 * 2
     assert read("serve_window_scores_visited_total") == 9 * 16 * 16
@@ -737,6 +740,39 @@ def test_a_program_reports_the_heads_a_step_its_kernels_got(monkeypatch):
         (2, True, cfg.sliding_window): want["sliding_attention"]}
 
 
+def test_a_program_reports_the_blocks_its_kernels_got(monkeypatch):
+    """flash_blocks (what the engine and the trainer publish, from a
+    configuration and two lengths) is what block_shape answers while the
+    program is traced, by kind of layer: a cached prefill of 32 tokens on
+    41 cache slots with no size given — full layers the cache view's keys
+    (the lengths clamp 512 x 1024 to 32 x 41), window layers the call's
+    own keys under the window's rule (256 x 512 clamped to 32 x 32)."""
+    import runbooks_tpu.ops.flash_attention as fa
+    from runbooks_tpu.models.transformer import flash_blocks
+
+    cfg = toy(moe_experts_held=8, attention_impl="flash")
+    want = flash_blocks(cfg, 32, 41)
+    assert want == {"full_attention": {"fwd": [32, 41]},
+                    "sliding_attention": {"fwd": [32, 32]}}
+    assert flash_blocks(cfg, 1024, 1025)["sliding_attention"] == {
+        "fwd": [256, 512]}
+    asked, answer = [], fa.block_shape
+    monkeypatch.setattr(fa, "block_shape", lambda *a: asked.append(
+        (a, answer(*a))) or asked[-1][1])
+    p = jax.eval_shape(lambda: init_params(cfg, jax.random.key(7)))
+    cache = jax.eval_shape(lambda: KVCache.create(cfg, 1, 40,
+                                                  trash_slot=True))
+    toks = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    jax.eval_shape(
+        lambda p, cache, toks, pos: forward(
+            cfg, p, toks, positions=pos, cache=cache, token_mask=pos < 40),
+        p, cache, toks, toks)
+    # (kernel, sq, sk, n_rep, window, given q, given k) -> blocks.
+    assert {(a[0], a[4]): list(b) for a, b in asked} == {
+        ("fwd", 0): want["full_attention"]["fwd"],
+        ("fwd", cfg.sliding_window): want["sliding_attention"]["fwd"]}
+
+
 def test_metrics_and_the_census_carry_the_heads_a_step():
     """`serve_flash_heads_per_step{program, kind}` on /metrics and
     `flash_head_block` of the engine (what warmup_census and
@@ -774,3 +810,19 @@ def test_metrics_and_the_census_carry_the_heads_a_step():
         f'serve_flash_heads_per_step{{kind="{kind}",program="{program}"}}':
         float(g) for program, kinds in want.items()
         for kind, g in kinds.items()}
+    # ... and the block shape each was compiled with, a side a line: the
+    # lengths clamp the rule's answers at these buckets.
+    blocks = {f"prefill_b{b}": {
+        "full_attention": {"fwd": [b, 65]},
+        "sliding_attention": {"fwd": [b, b]}}
+        for b in engine.prefill_buckets if b >= 16}
+    assert engine.flash_blocks == blocks
+    shapes = {ln.rsplit(" ", 1)[0]: float(ln.rsplit(" ", 1)[1])
+              for ln in text.splitlines()
+              if ln.startswith("serve_flash_block_shape{")}
+    assert shapes == {
+        f'serve_flash_block_shape{{kind="{kind}",program="{program}",'
+        f'side="{side}"}}': float(block)
+        for program, kinds in blocks.items()
+        for kind, kernels in kinds.items()
+        for side, block in zip("qk", kernels["fwd"])}
