@@ -54,11 +54,12 @@ def resolve_collective_matmul_param(params: dict) -> Optional[str]:
 
 # Token-mixer kinds a layer pattern may name (ModelConfig.layer_types).
 LAYER_KINDS = ("full_attention", "linear_attention", "latent_attention",
-               "sliding_attention")
+               "sliding_attention", "conv")
 # The kinds whose layers are the period's ONE stack params["layers"] (keys
 # and values, or a latent, a token): exactly one of them a period. Every
-# other layer of a period (linear_attention, sliding_attention) has a stack
-# a position of its kind (params["linear_layers"], params["window_layers"]).
+# other layer of a period (linear_attention, sliding_attention, conv) has a
+# stack a position of its kind (params["linear_layers"],
+# params["window_layers"], params["conv_layers"]).
 ATTENTION_KINDS = ("full_attention", "latent_attention")
 # Slots a window layer's ring cache has beyond its window: one dispatch may
 # write RING_MARGIN + 1 tokens of a row before its first query reads
@@ -125,6 +126,10 @@ class ModelConfig:
     # standard deviation, which no trained, load-balancing bias has.
     moe_router_bias_std: float = 0.05
     moe_routed_scale: float = 1.0
+    # What the chosen scores' sum is made safe to divide by: 0 = max(sum,
+    # 1e-9); eps > 0 = sum + eps, the form of the models whose published
+    # router adds it (a sigmoid's chosen scores may sum to little).
+    moe_router_eps: float = 0.0
     # Width of an expert (0 = intermediate_size) and how many shared
     # experts run on every token beside the routed ones (one dense gated
     # MLP of moe_shared_experts x that width, added unscaled).
@@ -138,7 +143,10 @@ class ModelConfig:
     moe_experts_first: int = 0
     # Layers BEFORE the period scan with a dense FFN of intermediate_size
     # (params["leading_layers"]); the periods take num_layers minus these.
+    # Their token mixer is of leading_kind: "" = the period's attention
+    # kind, or "conv" where the pattern has such layers.
     leading_dense_layers: int = 0
+    leading_kind: str = ""
 
     # Attention
     attn_bias: bool = False
@@ -216,6 +224,11 @@ class ModelConfig:
     # beta in (0, 2) instead of (0, 1): the state transition may have
     # negative eigenvalues.
     linear_allow_neg_eigval: bool = True
+    # Layer kind "conv", the gated short convolution: [B | C | X] = u W_in,
+    # a = (C * conv(B * X)) W_out, conv depthwise and causal over conv_kernel
+    # tokens with no bias and no activation. A row keeps B * X at its last
+    # conv_kernel - 1 tokens, and no keys or values.
+    conv_kernel: int = 3
 
     # Embeddings / head
     tie_embeddings: bool = False
@@ -330,8 +343,21 @@ class ModelConfig:
                 "a period of the layer pattern holds exactly one "
                 "full_attention or latent_attention layer (params['layers'] "
                 "is that layer's stack, scanned a period a step) beside any "
-                "number of linear_attention and sliding_attention layers; "
-                f"got {kinds}")
+                "number of linear_attention, sliding_attention and conv "
+                f"layers; got {kinds}")
+        if "conv" in kinds:
+            if "linear_attention" in kinds:
+                raise ValueError(
+                    "conv and linear_attention layers in one pattern would "
+                    "share the cache's conv leaf: not written")
+            if self.conv_kernel < 2:
+                raise ValueError("conv layers need conv_kernel >= 2")
+        if self.leading_kind not in ("", self.attention_kind) and not (
+                self.leading_kind == "conv" and "conv" in kinds):
+            raise ValueError(
+                f"leading_kind {self.leading_kind!r}: the leading layers "
+                "are of the period's attention kind, or conv layers of a "
+                "pattern that has them")
         if (self.num_layers - self.leading_dense_layers) % len(kinds) \
                 or self.leading_dense_layers >= self.num_layers:
             raise ValueError(
@@ -417,12 +443,22 @@ class ModelConfig:
                 // len(self.layer_pattern))
 
     def layers_of(self, kind: str) -> int:
-        """How many of the model's layers are of this kind (the leading
-        layers are of the kind of params["layers"]: attention_kind)."""
-        n = self.num_periods * self.layer_pattern.count(kind)
-        if kind == self.attention_kind:
-            n += self.leading_dense_layers
-        return n
+        """How many of the model's layers are of this kind: those of the
+        periods and, for leading_layer_kind, the leading ones. It is the
+        length of the kind's leaves in the cache, the leading layers
+        first."""
+        return (self.num_periods * self.layer_pattern.count(kind)
+                + self.leading_layers_of(kind))
+
+    @property
+    def leading_layer_kind(self) -> str:
+        """The token mixer of the leading layers."""
+        return self.leading_kind or self.attention_kind
+
+    def leading_layers_of(self, kind: str) -> int:
+        """How many leading layers are of this kind (all or none)."""
+        return (self.leading_dense_layers
+                if kind == self.leading_layer_kind else 0)
 
     @property
     def attention_kind(self) -> str:
@@ -520,10 +556,22 @@ class ModelConfig:
                 / self._yarn_m(self.rope_yarn[5]))
 
     @property
-    def has_recurrent_state(self) -> bool:
-        """Some layer keeps a fixed-size state instead of keys and values:
-        what the serving engine's stale-data invariant does not cover."""
+    def has_linear_attention(self) -> bool:
+        """Some layer is a gated-delta mixer: a matrix state a head and
+        the tail of its short convolution."""
         return "linear_attention" in self.layer_pattern
+
+    @property
+    def has_short_conv(self) -> bool:
+        """Some layer is a gated short convolution: a tail, and no state."""
+        return "conv" in self.layer_pattern
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Some layer keeps something of fixed size a row instead of keys
+        and values a token (a delta-rule state, a convolution's tail): what
+        the serving engine's stale-data invariant does not cover."""
+        return self.has_linear_attention or self.has_short_conv
 
     @property
     def linear_key_dim(self) -> int:
@@ -599,6 +647,11 @@ class ModelConfig:
                   else q_dim + k_dim)
         return n
 
+    def _short_conv_params(self) -> int:
+        """W_in [h, 3h], W_out [h, h] and the kernel of one conv layer."""
+        h = self.hidden_size
+        return 4 * h * h + self.conv_kernel * h
+
     @property
     def num_params(self) -> int:
         """Parameter count of what this process holds (embedding included
@@ -625,14 +678,16 @@ class ModelConfig:
                   + self.linear_conv_kernel * self.linear_conv_dim
                   + 2 * self.linear_num_heads + self.linear_value_head_dim)
         final_norm = h * (2 if self.norm_type == "layernorm" else 1)
-        lead = self.leading_dense_layers
+        mixer = {self.attention_kind: attn, "linear_attention": linear,
+                 "conv": self._short_conv_params()}
+        if self.has_window:
+            mixer["sliding_attention"] = self._attn_params(
+                "sliding_attention")
         return (embed + head + pos + final_norm
-                + lead * (attn + dense + norms_per_layer)
-                + (self.layers_of(self.attention_kind) - lead)
-                * (attn + rest)
-                + self.layers_of("linear_attention") * (linear + rest)
-                + self.layers_of("sliding_attention")
-                * (self._attn_params("sliding_attention") + rest))
+                + self.leading_dense_layers
+                * (mixer[self.leading_layer_kind] + dense + norms_per_layer)
+                + self.num_periods * sum(
+                    mixer[kind] + rest for kind in self.layer_pattern))
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Forward-pass matmul FLOPs per token (2*N plus attention quadratic).
@@ -680,13 +735,16 @@ class ModelConfig:
                   + 6 * self.linear_num_heads * self.linear_key_head_dim
                   * self.linear_value_head_dim)
         head = 2 * h * self.vocab_size
-        lead = self.leading_dense_layers
+        # The two projections, the two gates and the kernel's taps.
+        conv = 2 * 4 * h * h + 2 * (self.conv_kernel + 2) * h
+        mixer = {self.attention_kind: attn_proj + attn_scores,
+                 "linear_attention": linear, "sliding_attention": sliding,
+                 "conv": conv}
         return float(
-            lead * (attn_proj + attn_scores + dense)
-            + (self.layers_of(self.attention_kind) - lead)
-            * (attn_proj + attn_scores + mlp)
-            + self.layers_of("linear_attention") * (linear + mlp)
-            + self.layers_of("sliding_attention") * (sliding + mlp) + head)
+            self.leading_dense_layers
+            * (mixer[self.leading_layer_kind] + dense)
+            + self.num_periods * sum(
+                mixer[kind] + mlp for kind in self.layer_pattern) + head)
 
 
 def _llama(name, v=32000, h=4096, i=11008, l=32, q=32, kv=32, d=128, s=4096,
@@ -843,6 +901,34 @@ def _laguna(name, v=100352, h=2048, i=8192, periods=9, q=48, swa_q=64,
     )
 
 
+def _lfm2_moe(name, v=65536, h=2048, i=11776, periods=9, lead=2, q=32, kv=8,
+              s=128000, convs_a_period=3, kernel=3, experts=64, top_k=4,
+              moe_i=1536):
+    # Gated short-convolution layers (a tail of kernel - 1 tokens a row, no
+    # keys, no values) beside one grouped-query layer in four, which comes
+    # FIRST in its period; QK norm a head, before a plain rotary; leading
+    # layers that are conv layers with a dense FFN, then sparse layers:
+    # sigmoid router with a selection bias, the chosen scores over their sum
+    # + 1e-6, no shared expert; tied head (docs/hybrid-models.md). The
+    # published 40 layers are c c (F c c c) x 9 F c: behind the two leading
+    # layers lie nine whole periods and F c, a period's remainder that a
+    # repeated pattern cannot say, so the preset is 2 + 9 x 4 = 38 layers.
+    return ModelConfig(
+        name=name, vocab_size=v, hidden_size=h, intermediate_size=i,
+        num_layers=lead + periods * (convs_a_period + 1), num_heads=q,
+        num_kv_heads=kv, head_dim=h // q, max_seq_len=s,
+        norm_type="rmsnorm", norm_eps=1e-5, gated_mlp=True,
+        activation="silu", position_type="rope", rope_theta=1000000.0,
+        qk_norm=True, qk_norm_width="head", tie_embeddings=True,
+        layer_types=("full_attention",) + ("conv",) * convs_a_period,
+        conv_kernel=kernel, leading_dense_layers=lead, leading_kind="conv",
+        moe_num_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_i, moe_router="sigmoid",
+        moe_router_bias=True, moe_router_bias_std=0.01,
+        moe_routed_scale=1.0, moe_router_eps=1e-6,
+    )
+
+
 # Registry mirrors the reference's documented example configs
 # (reference: examples/ tree — llama2-7b, llama2-70b, falcon-7b/40b,
 # facebook-opt-125m) plus debug sizes for tests/benchmarks.
@@ -887,6 +973,10 @@ CONFIGS = {
     # per-head output gate, 256 narrow experts beside a shared one
     # (docs/window-full-models.md)
     "laguna-xs.2": _laguna("laguna-xs.2"),
+    # Gated short convolutions beside one grouped-query layer in four,
+    # leading conv layers with a dense FFN, 64 experts of width 1536
+    # (docs/hybrid-models.md)
+    "lfm2-24b-a2b": _lfm2_moe("lfm2-24b-a2b"),
     # GPT-2 (fused-qkv Conv1D checkpoints; learned positions)
     "gpt2": _gpt2("gpt2"),
     "gpt2-xl": _gpt2("gpt2-xl", h=1600, i=6400, l=48, q=25),
@@ -919,6 +1009,12 @@ CONFIGS = {
         "debug-laguna", v=512, h=128, i=384, periods=1, q=6, swa_q=8, kv=2,
         d=16, rot=8, s=256, window=8, experts=16, top_k=4, moe_i=64,
         yarn=(8.0, 32, 8.0, 1.0, 1.0, 0.0)),
+    # The same mechanisms at toy widths: 1 leading conv layer with a dense
+    # FFN + 2 periods of (1 full, 3 conv), 4 query heads on 2 KV heads, 8
+    # experts, 2 a token (rbt check, tests)
+    "debug-lfm2": _lfm2_moe(
+        "debug-lfm2", v=512, h=128, i=384, periods=2, lead=1, q=4, kv=2,
+        s=256, experts=8, top_k=2, moe_i=64),
     "bench-1b": _llama("bench-1b", h=2048, i=5632, l=22, q=16, kv=16, d=128, s=2048),
     "bench-410m": _llama("bench-410m", h=1024, i=2816, l=24, q=16, kv=16, d=64, s=2048),
     # Same params/FLOPs as bench-410m but 8 heads x d128: wider MXU

@@ -30,7 +30,8 @@ Router kinds are config, not code paths by model name
   softmax  probabilities over all experts, the chosen k renormalised
   sigmoid  a sigmoid a score; the selection bias (``router_bias``) is
            added ONLY to choose; gate weights are the chosen scores over
-           their sum, times ``moe_routed_scale``
+           their sum (plus ``moe_router_eps`` where the model adds
+           one), times ``moe_routed_scale``
 The router runs in float32: near-ties decide which expert runs.
 
 The shared expert is the dense gated MLP at width ``moe_intermediate_size
@@ -67,7 +68,9 @@ def route(cfg, p, xt: jax.Array):
         scores = choose = jax.nn.softmax(logits, axis=-1)
     _, idx = jax.lax.top_k(choose, cfg.moe_top_k)
     gate = jnp.take_along_axis(scores, idx, axis=-1)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    total = gate.sum(-1, keepdims=True)
+    gate = gate / (total + cfg.moe_router_eps if cfg.moe_router_eps
+                   else jnp.maximum(total, 1e-9))
     return scores, idx.astype(jnp.int32), gate * cfg.moe_routed_scale
 
 

@@ -15,7 +15,9 @@ delegates compute to external containers, SURVEY.md §2a):
   those layers only, and its linear-attention layers in
   params["linear_layers"]: one stack [periods, …] for each position such
   a layer has in the period, so that every stack is scanned by the
-  period's number alone, as a homogeneous model's is.
+  period's number alone, as a homogeneous model's is. Sliding-attention
+  and short-convolution layers lie the same way, in
+  params["window_layers"] and params["conv_layers"].
 - Every major activation gets a logical sharding constraint
   (runbooks_tpu.parallel.sharding) so pjit can propagate DP/FSDP/SP/TP layouts
   from a rule table.
@@ -180,10 +182,12 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
         layers["ln2"] = _norm_params(cfg, (L,))
 
     params["layers"] = layers
-    if cfg.has_recurrent_state:
+    if cfg.has_linear_attention:
         params["linear_layers"] = _init_linear_layers(cfg, rng)
     if cfg.has_window:
         params["window_layers"] = _init_window_layers(cfg, rng)
+    if cfg.has_short_conv:
+        params["conv_layers"] = _init_conv_layers(cfg, rng)
     if cfg.leading_dense_layers:
         params["leading_layers"] = _init_leading_layers(cfg, rng)
     return params
@@ -301,19 +305,36 @@ def _init_latent_attention(cfg: ModelConfig, keys, L: int) -> Params:
 
 
 def _init_leading_layers(cfg: ModelConfig, rng: jax.Array) -> Params:
-    """The layers before the period scan: the period's attention kind with
-    a DENSE gated FFN of intermediate_size, stacked [leading, …]. Their
-    keys come from a split of their own (fold_in 2), beside init_params'
-    and the linear layers': no other leaf's key moves."""
+    """The layers before the period scan: a token mixer of
+    cfg.leading_layer_kind ("attn": the period's attention kind, or
+    "mixer": a short convolution) with a DENSE gated FFN of
+    intermediate_size, stacked [leading, …]. Their keys come from a split
+    of their own (fold_in 2), beside init_params' and the linear layers':
+    no other leaf's key moves."""
     assert cfg.gated_mlp and not cfg.mlp_bias and \
         not (cfg.parallel_block and cfg.shared_layer_norm), \
-        "leading layers are written for the gated dense MLP, two norms"
+        "leading layers (an attention or conv mixer before the period " \
+        "scan) are written for the gated dense MLP, two norms"
     n = cfg.leading_dense_layers
     keys = iter(jax.random.split(jax.random.fold_in(rng, 2), 16))
-    attn = _init_attention(cfg, keys, n)
-    return {"attn": attn,
+    mixer = ({"mixer": _init_short_conv(cfg, keys, n)}
+             if cfg.leading_layer_kind == "conv"
+             else {"attn": _init_attention(cfg, keys, n)})
+    return {**mixer,
             "mlp": _init_gated_mlp(cfg, keys, n, cfg.intermediate_size),
             "ln1": _norm_params(cfg, (n,)), "ln2": _norm_params(cfg, (n,))}
+
+
+def _init_short_conv(cfg: ModelConfig, keys, L: int) -> Params:
+    """The gated short convolution of L layers, stacked: W_in to the
+    thirds [B | C | X], W_out, and the depthwise kernel [taps, channels]
+    (its last tap weighs the current token); matrices N(0, 1/fan_in), the
+    kernel N(0, 1/taps); one key a leaf, in a fixed order."""
+    h, pd = cfg.hidden_size, cfg.parameter_dtype
+    return {"w_in": _dense_init(next(keys), (L, h, 3 * h), pd, h),
+            "w_out": _dense_init(next(keys), (L, h, h), pd, h),
+            "conv": _dense_init(next(keys), (L, cfg.conv_kernel, h), pd,
+                                cfg.conv_kernel)}
 
 
 def _deal_to_positions(in_layer_order: Params, n: int) -> list:
@@ -348,6 +369,25 @@ def _init_window_layers(cfg: ModelConfig, rng: jax.Array) -> list:
     in_layer_order[ffn_key] = ffn
     return _deal_to_positions(
         in_layer_order, cfg.layer_pattern.count("sliding_attention"))
+
+
+def _init_conv_layers(cfg: ModelConfig, rng: jax.Array) -> list:
+    """The short-convolution layers of the periods (the leading ones have
+    their own stack): a list with one tree for each position such a layer
+    has in the period, drawn in layer order and dealt as the window
+    layers are, from a split of their own (fold_in 4). Each has the
+    model's FFN (sparse if it has experts) and two norms."""
+    assert not (cfg.parallel_block and cfg.shared_layer_norm), \
+        "conv layers are written for two norms a block"
+    n = cfg.layer_pattern.count("conv")
+    L = cfg.num_periods * n
+    keys = iter(jax.random.split(jax.random.fold_in(rng, 4), 16))
+    in_layer_order: Params = {
+        "mixer": _init_short_conv(cfg, keys, L),
+        "ln1": _norm_params(cfg, (L,)), "ln2": _norm_params(cfg, (L,))}
+    ffn_key, ffn = _init_ffn(cfg, keys, L)
+    in_layer_order[ffn_key] = ffn
+    return _deal_to_positions(in_layer_order, n)
 
 
 def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
@@ -465,7 +505,7 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     if not (cfg.parallel_block and cfg.shared_layer_norm):
         layers["ln2"] = norm1(("layers",))
     axes["layers"] = layers
-    if cfg.has_recurrent_state:
+    if cfg.has_linear_attention:
         col, row = ("layers", "embed", "heads"), ("layers", "heads", "embed")
         one_position = {
             "mixer": {"wq": col, "wk": col, "wv": col, "wg": col, "wo": row,
@@ -488,9 +528,20 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             one_position["attn"]["sink"] = ("layers", "heads")
         axes["window_layers"] = [
             one_position] * cfg.layer_pattern.count("sliding_attention")
+    # The thirds of w_in lie side by side, so its output is split by no
+    # mesh axis: the mixer is whole on every device of a tensor mesh.
+    short_conv = {"w_in": ("layers", "embed", None),
+                  "w_out": ("layers", None, "embed"),
+                  "conv": ("layers", None, None)}
+    if cfg.has_short_conv:
+        one_position = {"mixer": short_conv, ffn_key: ffn_axes,
+                        "ln1": norm1(("layers",)), "ln2": norm1(("layers",))}
+        axes["conv_layers"] = [
+            one_position] * cfg.layer_pattern.count("conv")
     if cfg.leading_dense_layers:
         axes["leading_layers"] = {
-            "attn": attn,
+            **({"mixer": short_conv} if cfg.leading_layer_kind == "conv"
+               else {"attn": attn}),
             "mlp": {"wo": ("layers", "mlp", "embed"),
                     "wi_gate": ("layers", "embed", "mlp"),
                     "wi_up": ("layers", "embed", "mlp")},
@@ -544,6 +595,14 @@ class KVCache:
     Neither has a slot axis, so neither has a trash slot: a token that
     must not count is named by forward(token_mask=...) and leaves both
     exactly as they were. A row starts from zeros.
+
+    conv WITHOUT state (the layer pattern has short-convolution layers,
+    kind "conv"): the tail is all such a layer keeps,
+      conv  [conv layers, batch, conv_kernel-1, hidden]: B * X at the
+            row's last conv_kernel-1 tokens,
+    under the same rules. Every leaf holds exactly the layers of its kind,
+    in layer order: where the leading layers are conv layers they are the
+    conv leaf's first, and k / v hold the periods' attention layers alone.
 
     latent (present instead of k / v content when the attention layers are
     latent_attention; k and v then hold no layer):
@@ -628,7 +687,11 @@ class KVCache:
             recurrent["latent"] = jnp.zeros(
                 (cfg.layers_of("latent_attention"), batch, cache_len,
                  cfg.latent_width), cfg.activation_dtype)
-        if cfg.has_recurrent_state:
+        if cfg.has_short_conv:
+            recurrent["conv"] = jnp.zeros(
+                (cfg.layers_of("conv"), batch, cfg.conv_kernel - 1,
+                 cfg.hidden_size), cfg.activation_dtype)
+        if cfg.has_linear_attention:
             n_lin = cfg.layers_of("linear_attention")
             recurrent.update(
                 state=jnp.zeros(
@@ -1395,6 +1458,40 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0))
 
 
+def _short_conv_block(cfg: ModelConfig, p: Params, x: jax.Array,
+                      token_mask: Optional[jax.Array], layer_tail):
+    """The gated short convolution of one layer. x [b, s, h] (the layer's
+    normed input); [B | C | X] = x W_in, z = B * X, c = the depthwise
+    causal convolution of z over conv_kernel tokens (no bias, no
+    activation; before a row's first token z is 0), out = (C * c) W_out.
+    token_mask as _linear_attention_block's; layer_tail None (no cache:
+    start from zeros, keep nothing) or (conv, layer): the cache's WHOLE
+    conv leaf [conv layers, b, conv_kernel-1, h] as forward's scan carries
+    it, and this layer's number in it. The layer reads its tail there (z
+    at the row's last conv_kernel-1 valid tokens) and writes the new one
+    back at the same index. Returns (out [b, s, h], None or the leaf)."""
+    from runbooks_tpu.ops.gated_delta import causal_conv
+
+    ad = cfg.activation_dtype
+    h = cfg.hidden_size
+    tail = None
+    if layer_tail is not None:
+        all_tail, layer = layer_tail
+        tail = jax.lax.dynamic_index_in_dim(all_tail, layer, 0, False)
+    with jax.named_scope("shortconv.in"):
+        bcx = _matmul(x, p["w_in"], ad)
+        z = bcx[..., :h] * bcx[..., 2 * h:]
+    with jax.named_scope("shortconv.core"):
+        n_valid = (None if token_mask is None
+                   else jnp.sum(token_mask, axis=-1, dtype=jnp.int32))
+        c, tail = causal_conv(z, p["conv"], tail, n_valid, activation=None)
+    with jax.named_scope("shortconv.out"):
+        out = _matmul(bcx[..., h:2 * h] * c, p["w_out"], ad)
+    if layer_tail is None:
+        return out, None
+    return out, jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0)
+
+
 def _mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
                adapter=None) -> jax.Array:
     ad = cfg.activation_dtype
@@ -1464,15 +1561,19 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
     cache's whole leaves of the layer's kind and the layer's number in them
     (_write_layer_cache, _linear_attention_block, _window_attention); the
     updated leaves come back. ``token_mask`` says which tokens may change a
-    linear-attention layer's state, and which a sparse FFN routes at all."""
+    linear-attention layer's state or a conv layer's tail, and which a
+    sparse FFN routes at all."""
 
     def mixer(h_in):
-        # The linear mixer runs inside the `attn` scope too, under inner
-        # linattn.* scopes: `attn` means "the token mixer" to every reader
-        # of a capture (docs/observability.md).
+        # The linear and conv mixers run inside the `attn` scope too, under
+        # inner linattn.* / shortconv.* scopes: `attn` means "the token
+        # mixer" to every reader of a capture (docs/observability.md).
         with jax.named_scope("attn"):
             if kind == "linear_attention":
                 return _linear_attention_block(
+                    cfg, layer["mixer"], h_in, token_mask, layer_cache)
+            if kind == "conv":
+                return _short_conv_block(
                     cfg, layer["mixer"], h_in, token_mask, layer_cache)
             if kind == "latent_attention":
                 return _latent_attention_block(
@@ -1749,7 +1850,12 @@ def forward(
     # lie in layer order — and the loop updates the buffers in place.
     n_lin = pattern.count("linear_attention")
     n_win = pattern.count("sliding_attention")
+    n_conv = pattern.count("conv")
     n_lead = cfg.leading_dense_layers
+    # A kind's leaves hold the leading layers of that kind first: the
+    # attention kind's (K/V, latent) or the conv leaf.
+    kv_lead = cfg.leading_layers_of(cfg.attention_kind)
+    conv_lead = cfg.leading_layers_of("conv")
 
     def attention_layer(layer_params, x, kv, layer, adapter=None):
         """One layer of the kind of params["layers"] at index `layer` of
@@ -1766,6 +1872,16 @@ def forward(
 
     layers, lin_layers = params["layers"], params.get("linear_layers")
     win_layers = params.get("window_layers")
+    conv_layers = params.get("conv_layers")
+
+    def conv_layer(layer_params, x, rec, layer):
+        """One short-convolution layer at index `layer` of the conv leaf
+        (rec = (None, the conv leaf); None without a cache)."""
+        layer_cache = None if cache is None else (rec[1], layer)
+        x, tail, aux, counts = blocks["conv"](
+            cfg, layer_params, x, positions, segment_ids, mask, bias,
+            layer_cache, None, token_mask)
+        return x, rec if cache is None else (rec[0], tail), aux, counts
     # Tokens a window layer's ring must not take: in position-scatter mode
     # padding is parked at the K/V leaves' last slot, by position.
     parked = (positions >= cache.k.shape[2] - 1
@@ -1790,35 +1906,47 @@ def forward(
         if stacks is None:
             return tree
         return {**tree, "moe": {**tree["moe"], **stacks},
-                "moe_layer": period - n_lead}
+                "moe_layer": period - kv_lead}
 
     layers, expert_stacks = without_stacks(layers)
     if win_layers is not None:
         win_layers, win_stacks = zip(*map(without_stacks, win_layers))
+    if conv_layers is not None:
+        conv_layers, conv_stacks = zip(*map(without_stacks, conv_layers))
     # The scanned stacks' layouts, in the order the scan body flattens
     # its slices of them (the leaves are the parameters' own objects).
     lain = None
     if weight_layouts is not None:
         by_leaf = dict(zip(map(id, jax.tree.leaves(params)), weight_layouts))
         lain = [by_leaf.get(id(w)) for w in jax.tree.leaves(
-            (layers, lin_layers, win_layers))]
+            (layers, lin_layers, win_layers, conv_layers))]
 
     def scan_body(carry, scanned):
         x, aux_sum, kv, rec, ring = carry
-        layers, pool_layer, lin_layers, win_layers, period = scanned
+        (layers, pool_layer, lin_layers, win_layers, conv_layers,
+         period) = scanned
         if lain is not None:
-            sliced, tree = jax.tree.flatten((layers, lin_layers, win_layers))
-            layers, lin_layers, win_layers = tree.unflatten(
+            sliced, tree = jax.tree.flatten(
+                (layers, lin_layers, win_layers, conv_layers))
+            layers, lin_layers, win_layers, conv_layers = tree.unflatten(
                 list(map(_read_in_place, sliced, lain)))
         adapter = None if apool is None else (pool_layer, aidx)
         # The period's number among the scanned ones: where its layers lie
-        # in the leaves that hold no leading layer (recurrent, ring).
+        # in the leaves that hold no leading layer (recurrent, ring; with
+        # leading conv layers, K/V), and behind conv_lead in the conv leaf.
         # (No cache: no number, and nothing reads one.)
-        rel = period - n_lead if n_lead and cache is not None else period
-        i = w = 0
+        rel = period - kv_lead if kv_lead and cache is not None else period
+        i = w = c_i = 0
         counts = []
         for kind in pattern:
-            if kind == "linear_attention":
+            if kind == "conv":
+                x, rec, aux, c = conv_layer(
+                    with_stacks(conv_layers[c_i], conv_stacks[c_i], period),
+                    x, rec, (None if cache is None
+                             else conv_lead + rel * n_conv + c_i))
+                counts.append(c)
+                c_i += 1
+            elif kind == "linear_attention":
                 layer_cache = (None if cache is None
                                else (*rec, rel * n_lin + i))
                 x, rec, aux, _ = blocks[kind](
@@ -1859,22 +1987,25 @@ def forward(
         ring = (cache.ring_k, cache.ring_v)
     if n_lead:
         # Leading layers (dense FFN, parameter shapes of their own) run
-        # unrolled before the scan, under the same block, at cache
-        # indices 0 .. n_lead - 1.
+        # unrolled before the scan, under the same block, at indices
+        # 0 .. n_lead - 1 of their kind's leaves.
         with jax.named_scope("leading_layers"):
             for i in range(n_lead):
-                x, kv, aux, _ = attention_layer(
-                    jax.tree.map(lambda a: a[i], params["leading_layers"]),
-                    x, kv, i)
+                one = jax.tree.map(lambda a: a[i], params["leading_layers"])
+                if conv_lead:
+                    x, rec, aux, _ = conv_layer(one, x, rec, i)
+                else:
+                    x, kv, aux, _ = attention_layer(one, x, kv, i)
                 aux_total = aux_total + aux
     moe_counts = None
     if cache is not None:
         # The adapter pool (leading L axis) rides the scan as xs when given.
         # The period's number as the K/V leaves count it: the scan's
-        # layers lie behind the leading ones there.
-        xs = (layers, apool, lin_layers, win_layers,
-              n_lead + jnp.arange(cfg.num_periods, dtype=jnp.int32)
-              if n_lead else jnp.arange(cfg.num_periods, dtype=jnp.int32))
+        # layers lie behind the leading ones there, where those are
+        # attention layers.
+        xs = (layers, apool, lin_layers, win_layers, conv_layers,
+              kv_lead + jnp.arange(cfg.num_periods, dtype=jnp.int32)
+              if kv_lead else jnp.arange(cfg.num_periods, dtype=jnp.int32))
         init = (x, aux_total, kv, rec, ring)
         # `layers`: the scan itself (slices of the stacked weights, what
         # the compiler hoists out of the loop); each layer is a `block`.
@@ -1930,7 +2061,8 @@ def forward(
             with jax.named_scope("layers"):
                 (x, aux_total, *_), moe_counts = jax.lax.scan(
                     scan_body, (x, aux_total, None, None, None),
-                    (layers, apool, lin_layers, win_layers, None))
+                    (layers, apool, lin_layers, win_layers, conv_layers,
+                     None))
         new_cache = None
 
     with jax.named_scope("head"):
@@ -2030,9 +2162,15 @@ def _check_window_support(cfg: ModelConfig, adapters):
 
 
 def _check_recurrent_support(cfg: ModelConfig, segment_ids, adapters):
-    """What a model with linear-attention layers cannot do yet, by name
-    (ROADMAP.md M5 / M7)."""
+    """What a model with linear-attention or short-convolution layers
+    cannot do yet, by name (ROADMAP.md M5 / M7)."""
     if segment_ids is not None:
+        if cfg.has_short_conv:
+            raise NotImplementedError(
+                "packed sequences (segment_ids) with short-convolution "
+                "layers need the tail reset at document boundaries; that "
+                "is not written (ops/gated_delta.causal_conv, "
+                "docs/hybrid-models.md)")
         raise NotImplementedError(
             "packed sequences (segment_ids) with linear-attention layers "
             "need the recurrent state reset at document boundaries, and "

@@ -39,7 +39,7 @@ Plain `jax.numpy`: no kernel here (ROADMAP.md M7 asks for one against the
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,9 +57,12 @@ def l2_normalize(x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 def causal_conv(x: jax.Array, w: jax.Array,
                 tail: Optional[jax.Array] = None,
-                n_valid: Optional[jax.Array] = None
+                n_valid: Optional[jax.Array] = None,
+                activation: Optional[Callable] = jax.nn.silu,
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Causal depthwise convolution over the sequence, then SiLU.
+    """Causal depthwise convolution over the sequence, then `activation`
+    on the float32 sums (SiLU, the gated-delta mixer's; None: none, the
+    gated short convolution's).
 
     x [b, s, c] in the activation dtype, w [kernel, c] (w[-1] weighs the
     current token), tail [b, kernel-1, c]: the inputs of the tokens just
@@ -76,7 +79,9 @@ def causal_conv(x: jax.Array, w: jax.Array,
     wf = w.astype(jnp.float32)
     y = sum(xc[:, j:j + s].astype(jnp.float32) * wf[j]
             for j in range(kernel))
-    y = jax.nn.silu(y).astype(x.dtype)
+    if activation is not None:
+        y = activation(y)
+    y = y.astype(x.dtype)
     if n_valid is None:
         new_tail = xc[:, s:]
     else:

@@ -733,7 +733,8 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
         reg.set_gauge("serve_recurrent_state_bytes",
                       occ.get("recurrent_state_bytes", 0),
                       help_text="Recurrent state and conv tails of "
-                                "linear-attention layers, all slots "
+                                "linear-attention layers, or the tails "
+                                "of short-convolution layers, all slots "
                                 "(0 without such layers); apart from "
                                 "serve_kv_pool_bytes.")
         reg.set_gauge("serve_latent_cache_bytes",

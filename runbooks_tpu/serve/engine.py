@@ -29,10 +29,11 @@ inference is in-framework and TPU-shaped:
   per request.
 - Per-slot cache writes use the transformer's position-scatter mode with a
   trash slot for padding (see models/transformer.KVCache).
-- A model whose layer pattern has recurrent (linear-attention) layers keeps
-  a fixed-size state a slot beside the K/V rows. It has no trash slot, so
-  the programs mask, freeze and reset it themselves: the invariant is
-  written out in make_prefill_fn (docs/hybrid-models.md).
+- A model whose layer pattern has recurrent layers (linear-attention: a
+  state and a conv tail; short-convolution: a tail alone) keeps that, of
+  fixed size a slot, beside the K/V rows. It has no trash slot, so the
+  programs mask, freeze and reset it themselves: the invariant is written
+  out in make_prefill_fn (docs/hybrid-models.md).
 - A model whose attention layers are latent (MLA) caches one leaf
   `latent` [layers, slots, cache_len, width] with no head axis in place of
   K/V: same slots, trash slot, splice and views; a sparse model's programs
@@ -355,7 +356,8 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # (models/transformer._cached_attention).
         #
         # Recurrent state and conv tail (linear-attention layers; pool
-        # leaves `state` / `conv`). They have no slot axis, so no trash
+        # leaves `state` / `conv`; short-convolution layers keep the
+        # `conv` leaf alone). They have no slot axis, so no trash
         # slot, and every token that reaches them changes the answer.
         # The programs therefore keep four rules themselves:
         #  (a) reset: every scratch row starts from ZERO state and tail,
@@ -457,12 +459,13 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
                             new_ring[i], rows_ring[:, r:r + 1], slots[r],
                             axis=1)
             new_state, new_conv = pool.state, pool.conv
-            if new_state is not None:
+            if new_conv is not None:
                 with jax.named_scope("state_splice"):    # rule (a)
                     for r in range(rows - 1, -1, -1):
-                        new_state = jax.lax.dynamic_update_slice_in_dim(
-                            new_state, cache1.state[:, r:r + 1], slots[r],
-                            axis=1)
+                        if new_state is not None:
+                            new_state = jax.lax.dynamic_update_slice_in_dim(
+                                new_state, cache1.state[:, r:r + 1],
+                                slots[r], axis=1)
                         new_conv = jax.lax.dynamic_update_slice_in_dim(
                             new_conv, cache1.conv[:, r:r + 1], slots[r],
                             axis=1)
@@ -1324,13 +1327,15 @@ class InferenceEngine:
                 f"(MLA) attention layers: {why}")
 
     def _refuse_recurrent(self, feature: str, why: str) -> None:
-        """Refuse, for a model with recurrent (linear-attention) layers, a
-        feature that is only sound for keys and values
-        (docs/hybrid-models.md)."""
+        """Refuse, for a model with recurrent (linear-attention or
+        short-convolution) layers, a feature that is only sound for keys
+        and values (docs/hybrid-models.md)."""
         if self.cfg.has_recurrent_state:
+            kind = ("linear-attention" if self.cfg.has_linear_attention
+                    else "short-convolution")
             raise ValueError(
                 f"{feature} is not supported for a model with recurrent "
-                f"(linear-attention) layers: {why}")
+                f"({kind}) layers: {why}")
 
     def _decode_kwargs(self) -> dict:
         """The adapter pool as a decode program takes it: the lane indices
@@ -1950,8 +1955,9 @@ class InferenceEngine:
                               self.cache.latent)
                   if a is not None] + rings
         # Apart from the K/V pool: the recurrent state and conv tails of
-        # linear-attention layers, fixed a slot whatever its tokens (0
-        # for a model without such layers).
+        # linear-attention layers, or the tails alone of short-convolution
+        # layers, fixed a slot whatever its tokens (0 for a model without
+        # such layers).
         recurrent = [a for a in (self.cache.state, self.cache.conv)
                      if a is not None]
         return {"slots_total": self.max_slots,

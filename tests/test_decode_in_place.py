@@ -69,6 +69,11 @@ MODELS = {
     "window-full-by-kind": (lambda: get_config(
         "debug-laguna", dtype="bfloat16", param_dtype="bfloat16",
         moe_experts_held=8, num_layers=9), False),
+    # A conv leaf with no state beside K/V that hold no leading layer: the
+    # leading layer is a short convolution, as three layers in four of the
+    # two periods are; every expert is held.
+    "short-conv-sparse": (lambda: get_config(
+        "debug-lfm2", dtype="bfloat16", param_dtype="bfloat16"), False),
 }
 
 
@@ -131,6 +136,9 @@ def test_decode_loops_hold_no_pool_sized_operation(one_chip, model):
     if pool.ring_k is not None:
         ring = "[" + ",".join(map(str, pool.ring_k.shape)) + "]"
         assert any(ring in i.line for i in loops), ring
+    if pool.conv is not None:
+        tails = "[" + ",".join(map(str, pool.conv.shape)) + "]"
+        assert any(tails in i.line for i in loops), tails
     assert pool_sized_loop_ops(text, pool) == []
 
 
