@@ -37,6 +37,81 @@ The router runs in float32: near-ties decide which expert runs.
 The shared expert is the dense gated MLP at width ``moe_intermediate_size
 x moe_shared_experts``, added once, unscaled, by whoever calls with
 ``shared=True`` (one caller among the shares).
+
+The tiles of the Pallas grouped product (megablox ``gmm``, the cached
+forward on a TPU) are a function of the call (``_gmm_tiling``: the rows,
+the widths, the item size), read from two sweeps on the chip (TPU v5 lite,
+PERF.md section 6, PR 41). Cost model, from the kernel's text: it visits a
+(group, row tile) pair once for every tile of tm rows a group touches —
+V = ``tile_visits(sizes, tm)``, about rows / tm + groups — computes the
+WHOLE [tm, k] x [k, n] product a visit (the store is masked) and fetches
+the group's [k, n] weights again every visit. A visit costs
+max(tm k n 2 / 197 TFLOP/s, k n 2 B / 819 GB/s): the MXU binds above
+about 240 rows, the weights' bytes below, so a tile of 512 rows that
+holds a group of 45-90 computes 512 rows for them, and a tile under 240
+reads the weights as often for no cheaper visit. Beside the weights the
+kernel fetches the [tm, tk] tile of the rows every visit AND k step —
+unless the k axis is whole (tk = k): then consecutive visits of one row
+tile (several groups inside it) repeat the block index, which issues no
+DMA, and the float32 accumulator is written once and not re-read a k step.
+At 256 rows a visit is near the balance of MXU and memory, so that
+second stream decides. ms a call of ONE product, median of 8, the group
+metadata's operations included; group sizes drawn as the cells' counters
+say (about 1460 real tokens of a 2048 bucket; held experts' max over mean
+1.5 / 3.4 / 7.4 / 2.1), the whole [layers x held, k, n] stack handed over:
+  [rows, k] x n, groups (rows in groups)   (512,1024,1024) (256,1024,<=1024) (128,k,tn) (256,k,tn)  tn
+  lfm2 gate/up [8192,2048] x 1536, 64 (5840)     1.954          1.199          0.969      0.991     768
+  lfm2 down    [8192,1536] x 2048                1.958          1.211          1.001      1.012    1024
+  sarvam gate/up [16384,4096] x 2048, 32 (2908)  1.932          1.456          1.253      1.257     512
+  sarvam down    [16384,2048] x 4096             2.002          1.465          1.281      1.247    1024
+  mimo gate/up, the same shapes, 32 (900)        1.633          1.133          1.001      1.002     512
+  mimo down                                      1.663          1.137          0.960      0.977    1024
+  laguna gate/up [16384,2048] x 512, 32 (1507)   0.370          0.313          0.268      0.274     512
+  laguna down    [16384,512] x 2048              0.361          0.288          0.266      0.275    2048
+  8 groups of 2048 rows, [16384,4096] x 2048     2.245          2.477            -        2.073     512
+  the same, [16384,2048] x 4096                  2.293          2.659            -        2.098    1024
+(the second column's width tiles divide the widths: 768 for 1536, where
+1024 computes a half-empty second tile of n and masks a 512-deep remainder
+of k in float32.) Other shapes at 256 rows, lfm2 gate/up: (1024, 1536)
+1.057, (2048, 512) 1.008, (2048, 256) 1.111, (512, 1536) 1.114; sarvam
+gate/up: (1024, 2048) 1.281, (4096, 256) 1.307, (2048, 1024) 1.426,
+(2048, 512) 1.698; sarvam down: (256, 4096) 1.346, (1024, 2048) 1.344,
+(2048, 512) 1.315; at 512 rows the best of the huge groups is
+(512, 2048) 2.157 and (2048, 512) 2.152; at 64 rows a whole layer's gate
+and down read 1.99 (lfm2) and 3.48 (sarvam) against 1.88 and 2.52-2.93 at
+256. A decode step (one tile of all its rows; 9-32 rows in 7-25 groups)
+is the hit experts' bytes whatever the tile, and the sweep cannot tell its
+widths apart: lfm2 gate/up (32, 1024, 1024) 0.339 against (32, 2048, 1536)
+0.334, down 0.341 / 0.330; sarvam (64, 1024, 1024) 0.403 against
+(64, 4096, 512) 0.378, down 0.412 / (64, 2048, 1024) 0.407; mimo 0.351 /
+0.341, 0.365 / 0.352; laguna 0.076 / 0.074, 0.072 / 0.073. In the cells
+(traced runs, kernel time alone) nothing speaks for another tile there:
+with k whole one pair of lfm2moe_doc on one machine read ``decode_fn/gmm``
+0.1963 -> 0.2014 ms a call gate/up and 0.2015 -> 0.2024 down (a 6 MiB
+weight tile's first fetch is exposed once a call of 25 visits), sarvam's
+read 0.2529 / 0.2511 and 0.2510 / 0.2511, machines differ by 4 % on one
+program, and one row tile has no second visit to
+share its rows with. So the rule, of shapes only:
+  tm  the largest of 256, 128, 64, 32, 16 that divides the rows — whatever
+      a group holds: 256 beats 512 for groups of 28 rows and of 2048, and
+      128 is level with it (ahead by 1-3 % in five of the eight products,
+      within 0.3 % in two, behind by 3 % in one) for a quarter more
+      visits, so the rows a group can expect, which the call could pass,
+      decide nothing and are not passed;
+  several row tiles (a prefill chunk):
+  tk  k whole, or its largest divisor whose [tm, tk] tile is 2 MiB;
+  tn  the widest divisor of n in whole lanes that fits the budget;
+  one row tile (a decode step, a bucket of 16 tokens): width tiles of at
+      most 2 MiB, (1024, 1024) in bfloat16, as every call had before.
+The budget (``GMM_VMEM_BYTES``, 13 MiB of the 16 MiB of scoped VMEM, by
+``_gmm_vmem_bytes``: two buffers each of the [tm, tk], [tk, tn] and
+[tm, tn] tiles and the float32 accumulator): prefill lfm2 9.5 and 9.5
+MiB, sarvam / mimo 13.0 and 12.0, laguna 7.0 and 8.5; a decode step 4.4
+(32 rows) or 4.75 (64); the parent's (512, 1024, 1024) was 10. The
+reckoning leaves out the compiler's own temporaries: (512, 4096, 256)
+counts 13.0 and does not compile, (512, 768, 2048) counts 15.5 and does,
+(512, 1024, 2048) counts 18 and does not — tests/test_decode_in_place.py
+compiles the cells' shapes.
 """
 
 from __future__ import annotations
@@ -45,6 +120,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Tokens whose assignments are sorted and run at once. The sorted copy of
 # the layer's input has tokens x top_k rows whoever holds the experts; a
@@ -84,18 +160,74 @@ def _balance_loss(cfg, scores, idx):
     return (E * (me * ce).sum()).astype(jnp.float32)
 
 
+# What the tiles of one grouped product may take of the 16 MiB of scoped
+# VMEM by _gmm_vmem_bytes, which counts the pipeline's buffers and not the
+# compiler's temporaries: 13 MiB is the most that compiled and ran on the
+# chip ((256, 4096, 512); 15.5 ran at a narrow row tile, 18 did not), and
+# tests/test_decode_in_place.py holds the cells' shapes to the compiler.
+GMM_VMEM_BYTES = 13 * 2**20
+# Rows of a tile: the most that divide the call's rows, up to the chip's
+# balance point (module docstring).
+GMM_ROW_TILES = (256, 128, 64, 32, 16)
+
+
+def tile_visits(sizes, tm: int) -> int:
+    """(group, row tile) pairs the grouped product visits: the V of the
+    cost model. A group of `sizes` (rows sorted by group, no gap between
+    groups) is visited once for every tile of tm rows it touches."""
+    sizes = np.asarray(sizes, np.int64)
+    ends = np.cumsum(sizes)
+    tiles = -(-ends // tm) - (ends - sizes) // tm
+    return int(tiles[sizes > 0].sum())
+
+
+def _gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """Two buffers each of a [tm, tk] and a [tk, tn] operand tile and of a
+    [tm, tn] result tile, and the float32 accumulator."""
+    return 2 * (tm * tk + tk * tn + tm * tn) * itemsize + tm * tn * 4
+
+
 def _gmm_tiling(m: int, k: int, n: int, itemsize: int = 2):
-    """(tm, tk, tn) for the Pallas grouped matmul, or None where its tiles
-    do not divide the problem (tm must divide the rows). Two buffers each
-    of a [tm, tk] and a [tk, tn] operand tile and of a [tm, tn] result
-    tile, and a float32 accumulator, stay under the 16 MiB of scoped VMEM:
-    10 MiB in bfloat16, 8 in float32."""
-    wide = itemsize > 2
-    tm = next((t for t in (512, 256, 128, 64, 32, 16)
-               if m % t == 0 and not (wide and t > 256)), None)
+    """(tm, tk, tn) for the Pallas grouped matmul from the call's shapes,
+    or None where its tiles do not divide the problem (tm must divide the
+    rows, the widths are whole lanes). The rule of the module docstring's
+    sweep: the row tile at the balance point whatever a group holds; where
+    that leaves several row tiles (a prefill chunk) the k axis whole (up to
+    a 2 MiB row tile), then the widest tile of n that divides it inside
+    GMM_VMEM_BYTES; where all the rows are one tile (a decode step) width
+    tiles of 2 MiB, which keep the pipeline's first fetch short."""
+    tm = next((t for t in GMM_ROW_TILES if m % t == 0), None)
     if tm is None or k % 128 or n % 128:
         return None
-    return tm, min(k, 512 if wide else 1024), min(n, 1024)
+    if tm == m:
+        return tm, min(k, 2048 // itemsize), min(n, 1024)
+
+    def divisors(width):
+        return (t for t in range(width, 0, -128) if width % t == 0)
+
+    tk = next(t for t in divisors(k) if tm * t * itemsize <= 2 * 2**20)
+    tn = next(t for t in divisors(n)
+              if t == 128 or _gmm_vmem_bytes(tm, tk, t, itemsize)
+              <= GMM_VMEM_BYTES)
+    return tm, tk, tn
+
+
+def gmm_tilings(cfg, tokens: int) -> dict:
+    """{"gate_up": [tm, tk, tn], "down": [tm, tk, tn]}: the tiles the
+    grouped products of a sparse layer compile with in a cached forward
+    over `tokens` tokens, by the chooser grouped_matmul asks; {} where they
+    run as `jax.lax.ragged_dot` (off the TPU, shapes no tile divides).
+    Static per compiled program, so the engine publishes it."""
+    from runbooks_tpu.utils.hw import on_tpu
+
+    rows = min(tokens, TOKEN_CHUNK) * cfg.moe_top_k
+    itemsize = jnp.dtype(cfg.activation_dtype).itemsize
+    h, f = cfg.hidden_size, cfg.moe_width
+    tiles = {"gate_up": _gmm_tiling(rows, h, f, itemsize),
+             "down": _gmm_tiling(rows, f, h, itemsize)}
+    if not on_tpu() or None in tiles.values():
+        return {}
+    return {name: list(tile) for name, tile in tiles.items()}
 
 
 def grouped_matmul(lhs, w, sizes, layer=None):

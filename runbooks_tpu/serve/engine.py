@@ -64,6 +64,7 @@ import numpy as np
 
 from runbooks_tpu.api.serve_params import QOS_CLASSES, ServeOptions
 from runbooks_tpu.models.config import ModelConfig
+from runbooks_tpu.models.moe import gmm_tilings
 from runbooks_tpu.models.transformer import (
     KVCache,
     forward,
@@ -1595,6 +1596,7 @@ class InferenceEngine:
             "weight_layout": self.weight_layout,
             "flash_head_block": self.flash_head_block,
             "flash_blocks": self.flash_blocks,
+            "gmm_tiling": self.gmm_tiling,
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1610,7 +1612,8 @@ class InferenceEngine:
             f"cache ({[(c['name'], c['programs']) for c in census]}); "
             f"weight layout {self.weight_layout}; "
             f"flash heads a step {self.flash_head_block}, blocks "
-            f"{self.flash_blocks}; "
+            f"{self.flash_blocks}; grouped product tiles "
+            f"{self.gmm_tiling}; "
             f"phases {self.warmup_census['phases']}",
             flush=True)
         # From here on, a compile is a serve-time stall: the sentinel
@@ -2269,6 +2272,23 @@ class InferenceEngine:
         compiled with (ops/flash_attention.block_shape, or the
         configuration's override). _count_flash_blocks counts at it."""
         return self._by_flash_program(flash_blocks)
+
+    @functools.cached_property
+    def gmm_tiling(self) -> dict:
+        """{program: {"gate_up": [tm, tk, tn], "down": [tm, tk, tn]}}: the
+        tiles the sparse layers' grouped products of each prefill program
+        (bucket x rows) and decode view compile with (models/moe.
+        gmm_tilings: the chooser the program asks, from its shapes). {} for
+        a dense model and where the products run as ragged_dot."""
+        if not self.cfg.moe_num_experts:
+            return {}
+        rows = dict.fromkeys((1, self.max_slots))
+        tiles = {f"prefill_b{bucket}r{r}": gmm_tilings(self.cfg, bucket * r)
+                 for bucket in self.prefill_buckets for r in rows}
+        tiles.update({f"decode_v{view}": gmm_tilings(self.cfg,
+                                                     self.max_slots)
+                      for view in self.view_buckets})
+        return {program: tile for program, tile in tiles.items() if tile}
 
     def _count_flash_blocks(self, bucket: int,
                             positions: np.ndarray) -> None:

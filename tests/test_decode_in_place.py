@@ -452,3 +452,59 @@ def test_flash_kernels_compile_at_published_widths(name, one_chip,
     calls = [re.sub(r"\{[^{}]*\}", "", m) for m in re.findall(
         r"= (\([^=]*?\)|\S+) custom-call\([^\n]*tpu_custom_call", text)]
     assert sorted(calls) == sorted(want)
+
+
+# --------------------------------------------------------------------------
+# The grouped product at the sparse cells' call shapes, by the TPU's compiler
+# --------------------------------------------------------------------------
+
+GMM_CASES = {
+    # name: (rows of the call = tokens x top_k, hidden, expert width,
+    #        layers x experts held, dtype)
+    "lfm2-24b-a2b prefill": (8192, 2048, 1536, 8 * 64, "bfloat16"),
+    "lfm2-24b-a2b decode": (32, 2048, 1536, 8 * 64, "bfloat16"),
+    "sarvam-105b and mimo-v2-flash prefill": (
+        16384, 4096, 2048, 5 * 32, "bfloat16"),
+    "sarvam-105b and mimo-v2-flash decode": (
+        64, 4096, 2048, 5 * 32, "bfloat16"),
+    "laguna-xs.2 prefill": (16384, 2048, 512, 36 * 32, "bfloat16"),
+    "laguna-xs.2 decode": (64, 2048, 512, 36 * 32, "bfloat16"),
+    # A scratch check of the program in float32 activations on the chip.
+    "sarvam-105b prefill in float32": (16384, 4096, 2048, 32, "float32"),
+}
+
+
+@pytest.mark.parametrize("name", GMM_CASES)
+def test_grouped_product_compiles_at_the_cells_shapes(name, one_chip,
+                                                      monkeypatch):
+    """Mosaic takes megablox gmm at the tiles moe._gmm_tiling answers for
+    a sparse layer's products (gate / up, then down) — the scoped VMEM
+    included, which the interpreter never checks and _gmm_vmem_bytes only
+    reckons — and the compiled text holds both kernels."""
+    import runbooks_tpu.utils.hw as hw
+    from runbooks_tpu.models import moe
+
+    rows, h, f, groups, dtype = GMM_CASES[name]
+    monkeypatch.setattr(hw, "on_tpu", lambda: True)
+    dtype = jnp.dtype(dtype)
+    for tile in (moe._gmm_tiling(rows, h, f, dtype.itemsize),
+                 moe._gmm_tiling(rows, f, h, dtype.itemsize)):
+        assert moe._gmm_vmem_bytes(*tile, dtype.itemsize) \
+            <= moe.GMM_VMEM_BYTES
+
+    def like(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def layer(xs, wi, wo, sizes):
+        hidden = moe.grouped_matmul(xs, wi, sizes, layer=jnp.int32(0))
+        return moe.grouped_matmul(hidden, wo, sizes, layer=jnp.int32(0))
+
+    # conftest pins "highest" for exact float32 sums on the CPU; the served
+    # program runs at the default, and Mosaic takes no bfloat16 product at
+    # float32 contraction precision.
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(layer).lower(
+            like((rows, h), dtype), like((1, groups, h, f), dtype),
+            like((1, groups, f, h), dtype),
+            like((groups,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2

@@ -161,6 +161,8 @@ def test_warmup_compiles_the_programs_the_requests_then_use():
     eng = InferenceEngine(cfg, seeded(cfg, 1), max_slots=2, max_seq_len=64,
                           decode_chunk=4)
     eng.warmup()
+    # Off the TPU the grouped products are ragged_dot: no tile to name.
+    assert eng.warmup_census["gmm_tiling"] == {}
     try:
         total = sentinel.total
         reqs = [Request(prompt_tokens=tokens_for(cfg, n, n).tolist(),
@@ -171,6 +173,38 @@ def test_warmup_compiles_the_programs_the_requests_then_use():
         assert sentinel.total == total, "compiled under traffic"
     finally:
         eng.release_steady()
+
+
+def test_census_names_the_grouped_products_tiles(monkeypatch):
+    """engine.gmm_tiling (warmup_census, GET /debug/programs) at the
+    published widths, as on a TPU: every prefill program (bucket x rows)
+    and decode view with the tiles models/moe's chooser gives its sparse
+    layers' products — a chunk of 2048 tokens whatever the burst's rows,
+    one tile of slots x top_k rows in decode; nothing for a dense model."""
+    import types
+
+    import runbooks_tpu.utils.hw as hw
+    from runbooks_tpu.models.config import get_config
+    from runbooks_tpu.serve.engine import InferenceEngine
+
+    monkeypatch.setattr(hw, "on_tpu", lambda: True)
+    cfg = get_config("lfm2-24b-a2b")
+
+    def census(cfg):
+        return InferenceEngine.gmm_tiling.func(types.SimpleNamespace(
+            cfg=cfg, max_slots=8, prefill_buckets=(16, 1024, 2048),
+            view_buckets=(512, 2048)))
+
+    chunk = {"gate_up": [256, 2048, 768], "down": [256, 1536, 1024]}
+    step = {"gate_up": [32, 1024, 1024], "down": [32, 1024, 1024]}
+    assert census(cfg) == {
+        "prefill_b16r1": {"gate_up": [64, 1024, 1024],
+                          "down": [64, 1024, 1024]},
+        "prefill_b16r8": chunk,
+        "prefill_b1024r1": chunk, "prefill_b1024r8": chunk,
+        "prefill_b2048r1": chunk, "prefill_b2048r8": chunk,
+        "decode_v512": step, "decode_v2048": step}
+    assert census(get_config("falcon-7b")) == {}
 
 
 @pytest.mark.parametrize("options,text", [

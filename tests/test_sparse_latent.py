@@ -446,7 +446,7 @@ def test_grouped_matmul_on_the_whole_stack_is_the_layers_own():
     """The serving path hands the product the whole [layers, experts, k, n]
     stack and the layer's number; the layer's groups among zero-sized ones
     give what the layer's own slice gives, rows past the groups apart."""
-    from runbooks_tpu.models.moe import _gmm_tiling, grouped_matmul
+    from runbooks_tpu.models.moe import grouped_matmul
 
     ks = jax.random.split(jax.random.key(0), 2)
     lhs = jax.random.normal(ks[0], (24, 16), jnp.float32)
@@ -461,12 +461,139 @@ def test_grouped_matmul_on_the_whole_stack_is_the_layers_own():
             np.asarray(lhs[a:b] @ stack[layer, e])
             for e, (a, b) in enumerate(((0, 5), (5, 5), (5, 14), (14, 18)))])
         np.testing.assert_allclose(np.asarray(own[:18]), want, atol=1e-5)
-    # The Pallas kernel's row tile must divide the rows; widths in lanes.
-    assert _gmm_tiling(64, 4096, 2048) == (64, 1024, 1024)
-    assert _gmm_tiling(16384, 2048, 4096) == (512, 1024, 1024)
-    assert _gmm_tiling(16384, 4096, 2048, 4) == (256, 512, 1024)
-    assert _gmm_tiling(24, 4096, 2048) is None
-    assert _gmm_tiling(64, 4096, 100) is None
+
+
+# The calls the four sparse cells make of the grouped product (doc_flood:
+# a 2048-token prefill chunk, a decode step of 8 rows), and the tile each
+# compiles with: name -> (preset, tokens, (m, h, f), gate / up, down).
+GMM_CALLS = {
+    "lfm2 prefill": ("lfm2-24b-a2b", 2048, (8192, 2048, 1536),
+                     (256, 2048, 768), (256, 1536, 1024)),
+    "lfm2 decode": ("lfm2-24b-a2b", 8, (32, 2048, 1536),
+                    (32, 1024, 1024), (32, 1024, 1024)),
+    "sarvam prefill": ("sarvam-105b", 2048, (16384, 4096, 2048),
+                       (256, 4096, 512), (256, 2048, 1024)),
+    "sarvam decode": ("sarvam-105b", 8, (64, 4096, 2048),
+                      (64, 1024, 1024), (64, 1024, 1024)),
+    "mimo prefill": ("mimo-v2-flash", 2048, (16384, 4096, 2048),
+                     (256, 4096, 512), (256, 2048, 1024)),
+    "mimo decode": ("mimo-v2-flash", 8, (64, 4096, 2048),
+                    (64, 1024, 1024), (64, 1024, 1024)),
+    "laguna prefill": ("laguna-xs.2", 2048, (16384, 2048, 512),
+                       (256, 2048, 512), (256, 512, 2048)),
+    "laguna decode": ("laguna-xs.2", 8, (64, 2048, 512),
+                      (64, 1024, 512), (64, 512, 1024)),
+}
+
+
+@pytest.mark.parametrize("name", GMM_CALLS)
+def test_gmm_tiling_of_the_cells_calls(name, monkeypatch):
+    """The chooser at the eight call shapes of the four sparse cells: the
+    tile is the sweep's (models/moe.py's docstring), divides the problem,
+    stays inside the VMEM the docstring reckons, is one tile of all the
+    rows in decode — and is what gmm_tilings publishes for the preset."""
+    import runbooks_tpu.utils.hw as hw
+    from runbooks_tpu.models import moe
+
+    preset, tokens, (m, h, f), gate_up, down = GMM_CALLS[name]
+    for (k, n), want in (((h, f), gate_up), ((f, h), down)):
+        tm, tk, tn = tile = moe._gmm_tiling(m, k, n)
+        assert tile == want
+        assert tm == min(m, 256) and m % tm == 0
+        if tm < m:      # a prefill chunk: k whole, tiles that divide
+            assert tk == k and n % tn == 0
+        else:           # a decode step: the tiles every call used to get
+            assert (tk, tn) == (min(k, 1024), min(n, 1024))
+        assert moe._gmm_vmem_bytes(tm, tk, tn, 2) <= moe.GMM_VMEM_BYTES
+    cfg = get_config(preset)
+    assert (cfg.moe_top_k * min(tokens, moe.TOKEN_CHUNK), cfg.hidden_size,
+            cfg.moe_width) == (m, h, f)
+    assert moe.gmm_tilings(cfg, tokens) == {}          # ragged_dot off the TPU
+    monkeypatch.setattr(hw, "on_tpu", lambda: True)
+    assert moe.gmm_tilings(cfg, tokens) == {"gate_up": list(gate_up),
+                                            "down": list(down)}
+    # A burst prefill is chunked to TOKEN_CHUNK tokens: the chunk's tile.
+    assert moe.gmm_tilings(cfg, 8 * 2048) == moe.gmm_tilings(cfg, 2048)
+
+
+@pytest.mark.parametrize("call,want", [
+    # float32 activations (a scratch check on the chip): 4-byte tiles.
+    ((16384, 4096, 2048, 4), (256, 2048, 256)),
+    ((256, 4096, 2048, 4), (256, 512, 1024)),
+    # A k no 2 MiB row tile holds whole: its largest divisor that does.
+    ((512, 8192, 1024), (256, 4096, 512)),
+    # Rows no tile divides, a width that is not whole lanes: ragged_dot.
+    ((24, 4096, 2048), None),
+    ((64, 4096, 100), None),
+    ((64, 100, 4096), None),
+])
+def test_gmm_tiling_at_the_edges(call, want):
+    from runbooks_tpu.models.moe import (
+        GMM_VMEM_BYTES,
+        _gmm_tiling,
+        _gmm_vmem_bytes,
+    )
+
+    assert _gmm_tiling(*call) == want
+    if want is not None:
+        assert _gmm_vmem_bytes(*want, (call + (2,))[3]) <= GMM_VMEM_BYTES
+
+
+def drawn_sizes(rng, tokens, top_k, scored, held):
+    """Group sizes of a prefill chunk: `tokens` real tokens choose top_k of
+    `scored` experts with a skewed popularity; the first `held` are here."""
+    p = np.exp(0.7 * rng.standard_normal(scored))
+    return rng.multinomial(tokens * top_k, p / p.sum())[:held]
+
+
+@pytest.mark.parametrize("cell,top_k,scored,held", [
+    ("lfm2moe_doc", 4, 64, 64), ("sarvam105b_doc", 8, 128, 32),
+    ("mimov2flash_doc", 8, 256, 32), ("lagunaxs2_doc", 8, 256, 32)])
+def test_tile_visits_counts_the_tiles_groups_touch(cell, top_k, scored,
+                                                   held):
+    """tile_visits (the V of the cost model) against a count row by row,
+    over seeded group sizes of a doc_flood prefill (about 1460 real tokens
+    of a 2048 bucket); and the chosen row tile computes fewer rows than the
+    512-row tile it replaces: visits x rows a visit."""
+    from runbooks_tpu.models.moe import _gmm_tiling, tile_visits
+
+    rng = np.random.default_rng(41)
+    for tokens in (1460, 1024, 1900, 7):
+        sizes = drawn_sizes(rng, tokens, top_k, scored, held)
+        group_of_row = np.repeat(np.arange(held), sizes)
+        for tm in (16, 64, 128, 256, 512):
+            brute = len({(g, r // tm) for r, g in enumerate(group_of_row)})
+            assert tile_visits(sizes, tm) == brute
+    assert tile_visits([0, 0], 256) == 0 and tile_visits([5, 0, 9, 4], 8) == 5
+    sizes = drawn_sizes(rng, 1460, top_k, scored, held)
+    tm = _gmm_tiling(2048 * top_k, 2048, 2048)[0]
+    assert tile_visits(sizes, tm) * tm < 0.7 * tile_visits(sizes, 512) * 512
+
+
+def test_megablox_at_a_chosen_tiling_is_ragged_dot():
+    """The Pallas kernel (interpreted off the TPU) at the tiling the
+    chooser gives a toy call, a whole stack with the layer's groups among
+    zeros as grouped_matmul hands it over, against jax.lax.ragged_dot on
+    the layer's own slice: the rows that belong to a group."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    from runbooks_tpu.models.moe import _gmm_tiling
+
+    ks = jax.random.split(jax.random.key(1), 2)
+    lhs = jax.random.normal(ks[0], (64, 256), jnp.float32)
+    stack = jax.random.normal(ks[1], (2, 4, 256, 384), jnp.float32)
+    sizes = jnp.asarray([5, 0, 30, 11], jnp.int32)      # 18 rows in no group
+    tiling = _gmm_tiling(64, 256, 384, 4)
+    assert tiling == (64, 256, 384)
+    for tiling in (tiling, (16, 128, 128)):             # and several tiles
+        whole = gmm(lhs, stack.reshape(8, 256, 384),
+                    jnp.zeros(8, jnp.int32).at[4:].set(sizes),
+                    preferred_element_type=jnp.float32, tiling=tiling,
+                    interpret=True)
+        own = jax.lax.ragged_dot(lhs, stack[1], sizes,
+                                 preferred_element_type=jnp.float32)
+        np.testing.assert_allclose(np.asarray(whole[:46]),
+                                   np.asarray(own[:46]), atol=1e-4)
 
 
 def test_decode_never_expands_the_cache_to_heads():
