@@ -165,6 +165,8 @@ CATALOG: Dict[str, str] = {
     "serve_moe_expert_hits_total": "counter",
     "serve_moe_expert_calls_total": "counter",
     "serve_moe_layer_peak_assignments_total": "counter",
+    "serve_moe_rows_moved_total": "counter",
+    "serve_moe_all_rows_total": "counter",
     "serve_prefix_lookups_total": "counter",
     "serve_prefix_hits_total": "counter",
     # Paged KV pool (serve/paging.py, docs/paged-kv.md): exported only

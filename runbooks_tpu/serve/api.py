@@ -816,6 +816,24 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                     program=prog,
                     help_text="(layer, expert) pairs offered, a forward, "
                               "by program.")
+                reg.set_counter(
+                    "serve_moe_rows_moved_total", moe["rows_moved"][prog],
+                    program=prog,
+                    help_text="Rows of the order by expert that the "
+                              "sparse layers gathered, ran through their "
+                              "experts and brought back, by program: the "
+                              "held assignments in whole windows "
+                              "(moe_row_window), or all a forward's "
+                              "tokens x top_k rows. Over serve_moe_"
+                              "assignments_total: rows moved a real "
+                              "assignment.")
+                reg.set_counter(
+                    "serve_moe_all_rows_total", moe["all_rows"][prog],
+                    program=prog,
+                    help_text="(layer, forward) pairs whose window was "
+                              "all the forward's rows, whatever it held "
+                              "(a decode step, every expert held), by "
+                              "program.")
         reg.set_counter("serve_prefix_lookups_total", eng.prefix_lookups,
                         help_text="Admissions that checked the shared-"
                                   "prefix cache.")

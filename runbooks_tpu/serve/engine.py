@@ -64,7 +64,7 @@ import numpy as np
 
 from runbooks_tpu.api.serve_params import QOS_CLASSES, ServeOptions
 from runbooks_tpu.models.config import ModelConfig
-from runbooks_tpu.models.moe import gmm_tilings
+from runbooks_tpu.models.moe import chunk_window, gmm_tilings, rows_moved
 from runbooks_tpu.models.transformer import (
     KVCache,
     forward,
@@ -1009,6 +1009,11 @@ class InferenceEngine:
             (cfg.moe_experts_here + 1) if cfg.moe_num_experts else 0,
             np.int64)
         self._moe_hits = {"prefill": [0, 0], "decode": [0, 0]}
+        # Rows of the order by expert that the programs sent to the experts
+        # and brought back (moe.rows_moved: the held rows in whole windows,
+        # or all a forward's rows), a layer and forward; and how many of
+        # those (layer, forward) pairs worked all their rows at once.
+        self._moe_rows = {"prefill": [0, 0], "decode": [0, 0]}
         # Sum over dispatches and layers of the most loaded held expert's
         # assignments: against the mean (here / held) it is the imbalance
         # a dispatch sees.
@@ -1597,6 +1602,7 @@ class InferenceEngine:
             "flash_head_block": self.flash_head_block,
             "flash_blocks": self.flash_blocks,
             "gmm_tiling": self.gmm_tiling,
+            "moe_row_window": self.moe_row_window,
             "programs": [{"name": c["name"], "programs": c["programs"]}
                          for c in census],
         }
@@ -1613,7 +1619,8 @@ class InferenceEngine:
             f"weight layout {self.weight_layout}; "
             f"flash heads a step {self.flash_head_block}, blocks "
             f"{self.flash_blocks}; grouped product tiles "
-            f"{self.gmm_tiling}; "
+            f"{self.gmm_tiling}, rows to the experts at once "
+            f"{self.moe_row_window}; "
             f"phases {self.warmup_census['phases']}",
             flush=True)
         # From here on, a compile is a serve-time stall: the sentinel
@@ -2231,7 +2238,7 @@ class InferenceEngine:
                 with fine("prefill.sync"):
                     # The first token must reach the host to stream.
                     (first,) = _pull("prefill.sync", first)
-                    self._count_moe("prefill", moe)
+                    self._count_moe("prefill", moe, bucket * rows)
             # Labeled by (bucket, rows): the two row shapes are different
             # compiled programs with ~rows-proportional FLOPs, and the
             # roofline join (/debug/programs) divides per-program FLOPs by
@@ -2289,6 +2296,26 @@ class InferenceEngine:
                                                      self.max_slots)
                       for view in self.view_buckets})
         return {program: tile for program, tile in tiles.items() if tile}
+
+    @functools.cached_property
+    def moe_row_window(self) -> dict:
+        """{program: rows}: the window of the order by expert that a sparse
+        layer of each prefill program and decode view sends to its experts
+        and brings back at once (models/moe.row_window: what the held share
+        can expect of a chunk's tokens x top_k rows; all of them in a
+        decode step and where every expert is held). {} for a dense
+        model."""
+        if not self.cfg.moe_num_experts:
+            return {}
+
+        def window(tokens):
+            return chunk_window(self.cfg, tokens)[1]
+
+        rows = dict.fromkeys((1, self.max_slots))
+        return {**{f"prefill_b{bucket}r{r}": window(bucket * r)
+                   for bucket in self.prefill_buckets for r in rows},
+                **{f"decode_v{view}": window(self.max_slots)
+                   for view in self.view_buckets}}
 
     def _count_flash_blocks(self, bucket: int,
                             positions: np.ndarray) -> None:
@@ -2351,10 +2378,12 @@ class InferenceEngine:
                 help_text=what + " a head and window layer in prefill, by "
                                  "bucket.")
 
-    def _count_moe(self, program: str, moe: list, steps: int = 1) -> None:
+    def _count_moe(self, program: str, moe: list, tokens: int,
+                   steps: int = 1) -> None:
         """Add one dispatch's (counts, hits) of a sparse model to the
-        engine's sums ([] for a dense one). Called right after the pull the
-        dispatch makes anyway: the arrays came back with it."""
+        engine's sums ([] for a dense one): `steps` forwards over `tokens`
+        tokens each. Called right after the pull the dispatch makes anyway:
+        the arrays came back with it."""
         for counts, hits in moe:
             # rbt-check: ignore[device-sync] same dispatch boundary — the counts ride the pull above
             counts = np.asarray(counts)
@@ -2363,6 +2392,13 @@ class InferenceEngine:
             # rbt-check: ignore[device-sync] same boundary
             self._moe_hits[program][0] += int(hits)
             self._moe_hits[program][1] += steps * counts[:, :-1].size
+            # All rows at once whatever is held (a decode step, every
+            # expert held): one figure a forward. Windows: a dispatch is
+            # one forward, and its counts are that forward's.
+            moved, whole = rows_moved(self.cfg, tokens,
+                                      counts[:, :-1].sum(axis=1))
+            self._moe_rows[program][0] += moved * (steps if whole else 1)
+            self._moe_rows[program][1] += steps * whole * len(counts)
 
     def moe_stats(self) -> Optional[dict]:
         """Sparse-layer counters for /metrics (None for a dense model):
@@ -2376,7 +2412,9 @@ class InferenceEngine:
                 "elsewhere": int(self._moe_counts[-1]),
                 "peak": self._moe_peak,
                 "hits": {k: v[0] for k, v in self._moe_hits.items()},
-                "calls": {k: v[1] for k, v in self._moe_hits.items()}}
+                "calls": {k: v[1] for k, v in self._moe_hits.items()},
+                "rows_moved": {k: v[0] for k, v in self._moe_rows.items()},
+                "all_rows": {k: v[1] for k, v in self._moe_rows.items()}}
 
     def _activate_slot(self, slot: int, req: Request,
                        first_tok: int) -> None:
@@ -2923,7 +2961,8 @@ class InferenceEngine:
                 # Tokens, counts and liveness in one [chunk + 2, slots]
                 # array: one sync a chunk.
                 (pulled,) = _pull("decode.sync", pulled)
-                self._count_moe("decode", moe, steps=self.decode_chunk)
+                self._count_moe("decode", moe, self.max_slots,
+                                steps=self.decode_chunk)
             obs_metrics.REGISTRY.observe(
                 "serve_decode_dispatch_seconds",
                 time.perf_counter() - t_dispatch, view=str(label),

@@ -16,6 +16,7 @@ process at a time may load the TPU's library.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -420,8 +421,6 @@ def test_flash_kernels_compile_at_published_widths(name, one_chip,
     included, which the interpreter never checks — and their results keep
     the shapes the benchmark tells them by: (out, lse); dk and dv of ONE
     shape, at KV-head width; dq 4-D."""
-    import re
-
     import runbooks_tpu.ops.flash_attention as fa
 
     (b, sq, sk, h, kv_h, d, dv, dtype, segments, window, sink, backward,
@@ -459,18 +458,19 @@ def test_flash_kernels_compile_at_published_widths(name, one_chip,
 # --------------------------------------------------------------------------
 
 GMM_CASES = {
-    # name: (rows of the call = tokens x top_k, hidden, expert width,
-    #        layers x experts held, dtype)
+    # name: (rows of the call = the window of tokens x top_k a call holds,
+    #        moe.row_window; hidden, expert width, layers x experts held,
+    #        dtype)
     "lfm2-24b-a2b prefill": (8192, 2048, 1536, 8 * 64, "bfloat16"),
     "lfm2-24b-a2b decode": (32, 2048, 1536, 8 * 64, "bfloat16"),
-    "sarvam-105b and mimo-v2-flash prefill": (
-        16384, 4096, 2048, 5 * 32, "bfloat16"),
+    "sarvam-105b prefill": (4096, 4096, 2048, 5 * 32, "bfloat16"),
+    "mimo-v2-flash prefill": (2048, 4096, 2048, 5 * 32, "bfloat16"),
     "sarvam-105b and mimo-v2-flash decode": (
         64, 4096, 2048, 5 * 32, "bfloat16"),
-    "laguna-xs.2 prefill": (16384, 2048, 512, 36 * 32, "bfloat16"),
+    "laguna-xs.2 prefill": (2048, 2048, 512, 36 * 32, "bfloat16"),
     "laguna-xs.2 decode": (64, 2048, 512, 36 * 32, "bfloat16"),
     # A scratch check of the program in float32 activations on the chip.
-    "sarvam-105b prefill in float32": (16384, 4096, 2048, 32, "float32"),
+    "sarvam-105b prefill in float32": (4096, 4096, 2048, 32, "float32"),
 }
 
 
@@ -508,3 +508,42 @@ def test_grouped_product_compiles_at_the_cells_shapes(name, one_chip,
             like((1, groups, f, h), dtype),
             like((groups,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("preset,held,window", [
+    ("laguna-xs.2", 32, 2048), ("sarvam-105b", 32, 4096),
+    ("lfm2-24b-a2b", 0, 8192)])
+def test_held_part_compiles_at_the_cells_shapes(preset, held, window,
+                                                one_chip, monkeypatch):
+    """A sparse layer's held part of a 2048-token prefill chunk as the
+    cached forward runs it — the ranking, a window of the held rows at a
+    time through the three grouped products inside a loop whose trip count
+    is traced, the way back — by the TPU's compiler: the kernels are there,
+    nothing is sorted, and where a share is held no value has the chunk's
+    tokens x top_k rows of the hidden size."""
+    import runbooks_tpu.utils.hw as hw
+    from runbooks_tpu.models import moe
+    from runbooks_tpu.models.config import get_config
+
+    monkeypatch.setattr(hw, "on_tpu", lambda: True)
+    cfg = get_config(preset, moe_experts_held=held)
+    T, k, h, f = 2048, cfg.moe_top_k, cfg.hidden_size, cfg.moe_width
+    n_held, ad = cfg.moe_experts_here, cfg.activation_dtype
+    assert moe.row_window(T * k, n_held, cfg.moe_num_experts) == window
+
+    def like(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    p = {"wi_gate": like((2, n_held, h, f), ad),
+         "wi_up": like((2, n_held, h, f), ad),
+         "wo": like((2, n_held, f, h), ad)}
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            lambda p_, xt, idx, gate: moe._held_part(
+                cfg, p_, xt, idx, gate, 0, layer=jnp.int32(1))).lower(
+            p, like((T, h), ad), like((T, k), jnp.int32),
+            like((T, k), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert not re.search(r" sort\(", text)
+    all_rows = re.findall(rf"\[{T * k},{h}\]", text)
+    assert bool(all_rows) == (window == T * k)

@@ -463,23 +463,256 @@ def test_grouped_matmul_on_the_whole_stack_is_the_layers_own():
         np.testing.assert_allclose(np.asarray(own[:18]), want, atol=1e-5)
 
 
+# --------------------------------------------------------------------------
+# The way to the experts and back: one ranking, windows of the held rows
+# --------------------------------------------------------------------------
+
+def routed_to(p, favourites):
+    """The layer with a router whose bias decides: {expert: bias}; scores
+    are near ties otherwise, so the favourites are every token's choice."""
+    router = np.asarray(p["router"].astype(jnp.float32)) * 0.01
+    bias = np.zeros(p["router_bias"].shape, np.float32)
+    for expert, push in favourites.items():
+        bias[expert] = push
+    return {**p, "router": jnp.asarray(router),
+            "router_bias": jnp.asarray(bias)}
+
+
+def stacked(p, first, held, layer, layers=3):
+    """The share's expert weights as the layer `layer` of whole stacks, as
+    the serving forward hands them over: the other layers hold noise."""
+    out = dict(p)
+    for i, name in enumerate(("wi_gate", "wi_up", "wo")):
+        own = p[name][first:first + held]
+        noise = jax.random.normal(jax.random.key(70 + i),
+                                  (layers,) + own.shape, own.dtype)
+        out[name] = noise.at[layer].set(own)
+    return out
+
+
+def counts_by_hand(cfg, p, x, first, held, mask=None):
+    """What a bincount of the held experts' assignments gives: the counts
+    the layer returned before it ranked."""
+    _, idx, _ = route(cfg, p, x.reshape(-1, cfg.hidden_size))
+    idx = np.asarray(idx)
+    if mask is not None:
+        idx = idx[np.asarray(mask).reshape(-1)]
+    local = idx.reshape(-1) - first
+    local = np.where((local >= 0) & (local < held), local, held)
+    return np.bincount(local, minlength=held + 1)
+
+
+# name: (tokens, first expert held, experts held, {expert: router bias} or
+# None for the seeded router, real tokens or None, TOKEN_CHUNK or None,
+# windows the held rows take: None where all rows are one window).
+# 16 experts, top-4, row tiles of 16: a share of 4 expects tokens rows.
+WINDOW_CASES = {
+    "a share": (64, 4, 4, None, None, None, None),
+    "the whole layer": (64, 0, 16, None, None, None, None),
+    "every assignment held here": (
+        64, 4, 4, dict.fromkeys((4, 5, 6, 7), 10.0), None, None, 4),
+    "all on one held expert": (
+        64, 4, 4, {6: 10.0, **dict.fromkeys((9, 10, 11), 5.0)}, None, None,
+        1),
+    "none held": (64, 4, 4, dict.fromkeys((0, 1, 2, 3), 10.0), None, None,
+                  0),
+    "a masked bucket": (64, 4, 4, None, 37, None, None),
+    "longer than TOKEN_CHUNK": (80, 4, 4, None, None, 32, None),
+    "masked and chunked": (80, 4, 4, None, 50, 32, None),
+    # 2 of 16 held and 96 tokens: windows of 48 rows; one held choice a
+    # real token, so the real tokens are the held rows.
+    "one row under a window": (
+        96, 4, 2, {4: 10.0, **dict.fromkeys((9, 10, 11), 5.0)}, 47, None, 1),
+    "a window full": (
+        96, 4, 2, {4: 10.0, **dict.fromkeys((9, 10, 11), 5.0)}, 48, None, 1),
+    "one row over a window": (
+        96, 4, 2, {4: 10.0, **dict.fromkeys((9, 10, 11), 5.0)}, 49, None, 2),
+    "two windows full": (
+        96, 4, 2, {4: 10.0, **dict.fromkeys((9, 10, 11), 5.0)}, 96, None, 2),
+}
+
+
+@pytest.mark.parametrize("name", WINDOW_CASES)
+def test_windows_of_held_rows_match_the_reference(name, monkeypatch):
+    """The cached forward's layer (whole stacks and a layer's number: the
+    held rows a window at a time) and, in three of the cases, the forward
+    without a cache (all rows at once) against the plain float32 reference
+    and a bincount, at the routings that strain a bound on the rows."""
+    from runbooks_tpu.models import moe
+
+    tokens, first, held, favourites, real, chunk, windows = \
+        WINDOW_CASES[name]
+    monkeypatch.setattr(moe, "GMM_ROW_TILES", (16,))
+    if chunk:
+        monkeypatch.setattr(moe, "TOKEN_CHUNK", chunk)
+    cfg = toy()
+    p, x = layer_and_input(cfg, seed=3, tokens=tokens)
+    if favourites:
+        p = routed_to(p, favourites)
+    mask = None
+    if real is not None:
+        mask = (jnp.arange(tokens) < real).reshape(x.shape[:2])
+    want = reference_layer(cfg, p, x, first, held) \
+        - reference_layer(cfg, p, x, 0, 0)
+    want_counts = counts_by_hand(cfg, p, x, first, held, mask)
+    k = cfg.moe_top_k
+    assert moe.row_window(min(tokens, chunk or tokens) * k, held, 16) \
+        == (min(tokens, chunk or tokens) * k * held // 16)
+    paths = [(stacked(p, first, held, layer=1), jnp.int32(1))]
+    if name in ("a share", "every assignment held here", "a masked bucket"):
+        paths.append((share_of(p, first, held), None))
+    for params, layer in paths:
+        y, _, counts = jax.jit(
+            lambda p_, x_, l_: moe_block(cfg, p_, x_, held=first,
+                                         shared=False, token_mask=mask,
+                                         layer=l_))(params, x, layer)
+        np.testing.assert_array_equal(np.asarray(counts), want_counts)
+        y = np.asarray(y)
+        if mask is None:
+            np.testing.assert_allclose(y, want, atol=TOL)
+        else:
+            keep = np.asarray(mask)
+            np.testing.assert_allclose(y[keep], want[keep], atol=TOL)
+            if layer is not None and held < 16:
+                assert not y[~keep].any()       # nobody's: nothing added
+    # What the engine's counter makes of the counts, on the host.
+    moved = moe.rows_moved(toy(moe_experts_held=held % 16), tokens,
+                           int(want_counts[:-1].sum()))
+    if windows is not None:
+        assert moved == (windows * tokens * k * held // 16, False)
+    if name == "the whole layer":
+        assert moved == (tokens * k, True)
+
+
+def held_part_by_two_sorts(cfg, p, xt, idx, gate, first):
+    """The layer's held part as it was before it ranked: a stable argsort
+    by expert, a bincount, a gather of all tokens x top_k rows, and a
+    second argsort for the way back."""
+    from runbooks_tpu.models.moe import grouped_matmul
+    from runbooks_tpu.models.transformer import _activation
+
+    T, k = idx.shape
+    n_held = p["wi_gate"].shape[0]
+    local = idx.reshape(-1) - first
+    here = (local >= 0) & (local < n_held)
+    group = jnp.where(here, local, n_held)
+    order = jnp.argsort(group, stable=True)
+    counts = jnp.bincount(group, length=n_held + 1).astype(jnp.int32)
+    xs = xt[(jnp.arange(T * k, dtype=jnp.int32) // k)[order]]
+    sizes = counts[:n_held]
+    hidden = _activation(cfg, grouped_matmul(xs, p["wi_gate"], sizes)) \
+        * grouped_matmul(xs, p["wi_up"], sizes)
+    out = grouped_matmul(hidden, p["wo"], sizes)
+    rows = out[jnp.argsort(order)].reshape(T, k, -1)
+    held_here = here.reshape(T, k)
+    y = jnp.einsum("tkh,tk->th", jnp.where(held_here[..., None], rows, 0),
+                   jnp.where(held_here, gate, 0.0),
+                   preferred_element_type=jnp.float32)
+    return y, counts
+
+
+@pytest.mark.parametrize("first,held", [(0, 16), (4, 4)],
+                         ids=["whole", "share"])
+def test_ranking_gives_the_two_sorts_results_and_gradients(first, held):
+    """The forward without a cache, which training differentiates: the
+    ranked layer's result, counts and gradients (by the input, the gate
+    weights and the expert weights) are those of the layer that sorted
+    twice — the order of the rows is the stable sort's, so bit for bit."""
+    from runbooks_tpu.models.moe import _held_part, _rank
+
+    cfg = toy()
+    p, x = layer_and_input(cfg, seed=5, tokens=40)
+    p = share_of(p, first, held)
+    xt = x.reshape(-1, cfg.hidden_size)
+    _, idx, gate = route(cfg, p, xt)
+    experts = {name: p[name].astype(jnp.float32)
+               for name in ("wi_gate", "wi_up", "wo")}
+
+    def loss(part, xt_, gate_, experts_):
+        y, counts = part(cfg, {**p, **experts_}, xt_, idx, gate_, first)
+        return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum(), \
+            (y, counts)
+
+    got, want = (jax.jit(jax.value_and_grad(
+        lambda *a, part=part: loss(part, *a), argnums=(0, 1, 2),
+        has_aux=True))(xt, gate, experts)
+        for part in (_held_part, held_part_by_two_sorts))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # The ranking alone, at a length no block divides.
+    group = jnp.asarray(np.random.default_rng(0).integers(0, 7, 333),
+                        jnp.int32)
+    counts, place, _ = _rank(group, 7)
+    np.testing.assert_array_equal(
+        np.asarray(place), np.argsort(np.argsort(group, kind="stable")))
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.bincount(group, minlength=7))
+
+
+def primitives_and_shapes(jaxpr):
+    """(primitive name, shapes of its results) of every equation of a
+    jaxpr, the bodies of its loops and calls included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, [getattr(v.aval, "shape", ())
+                                   for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from primitives_and_shapes(sub)
+
+
+def test_cached_share_neither_sorts_nor_moves_all_the_rows():
+    """The mechanism, held by the program's structure: a cached forward
+    that holds 32 of 256 experts (a share cell: 2048 tokens, top-8) has no
+    sort in it, and no value of tokens x top_k rows of the hidden size —
+    its gathers, its products and the way back are of a window's rows."""
+    from runbooks_tpu.models.moe import _held_part, row_window
+
+    T, k, h, f, n_held = 2048, 8, 256, 128, 32
+    cfg = toy(hidden_size=h, moe_num_experts=256, moe_top_k=k,
+              moe_experts_held=n_held, moe_intermediate_size=f)
+    assert row_window(T * k, n_held, 256) == 2048
+
+    def like(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    p = {"wi_gate": like(2, n_held, h, f), "wi_up": like(2, n_held, h, f),
+         "wo": like(2, n_held, f, h)}
+    found = list(primitives_and_shapes(jax.make_jaxpr(
+        lambda p_, xt, idx, gate: _held_part(cfg, p_, xt, idx, gate, 0,
+                                             layer=jnp.int32(1)))(
+        p, like(T, h), like(T, k, dtype=jnp.int32), like(T, k)).jaxpr))
+    names = {name for name, _ in found}
+    assert "sort" not in names and "while" in names
+    assert not [(name, s) for name, shapes in found for s in shapes
+                if s and s[-1] == h and np.prod(s[:-1]) >= T * k]
+    # The forward without a cache keeps all its rows, and sorts no more.
+    p = {name: like(*a.shape[1:]) for name, a in p.items()}
+    found = list(primitives_and_shapes(jax.make_jaxpr(
+        lambda p_, xt, idx, gate: _held_part(cfg, p_, xt, idx, gate, 0))(
+        p, like(T, h), like(T, k, dtype=jnp.int32), like(T, k)).jaxpr))
+    assert "sort" not in {name for name, _ in found}
+    assert [s for _, shapes in found for s in shapes if s == (T * k, h)]
+
+
 # The calls the four sparse cells make of the grouped product (doc_flood:
 # a 2048-token prefill chunk, a decode step of 8 rows), and the tile each
-# compiles with: name -> (preset, tokens, (m, h, f), gate / up, down).
+# compiles with: name -> (preset, tokens, (m, h, f), gate / up, down). m is
+# the window of the order by expert that a call holds (moe.row_window):
+# what the held share can expect of the chunk's tokens x top_k rows, all
+# of them where every expert is held and in a decode step.
 GMM_CALLS = {
     "lfm2 prefill": ("lfm2-24b-a2b", 2048, (8192, 2048, 1536),
                      (256, 2048, 768), (256, 1536, 1024)),
     "lfm2 decode": ("lfm2-24b-a2b", 8, (32, 2048, 1536),
                     (32, 1024, 1024), (32, 1024, 1024)),
-    "sarvam prefill": ("sarvam-105b", 2048, (16384, 4096, 2048),
+    "sarvam prefill": ("sarvam-105b", 2048, (4096, 4096, 2048),
                        (256, 4096, 512), (256, 2048, 1024)),
     "sarvam decode": ("sarvam-105b", 8, (64, 4096, 2048),
                       (64, 1024, 1024), (64, 1024, 1024)),
-    "mimo prefill": ("mimo-v2-flash", 2048, (16384, 4096, 2048),
+    "mimo prefill": ("mimo-v2-flash", 2048, (2048, 4096, 2048),
                      (256, 4096, 512), (256, 2048, 1024)),
     "mimo decode": ("mimo-v2-flash", 8, (64, 4096, 2048),
                     (64, 1024, 1024), (64, 1024, 1024)),
-    "laguna prefill": ("laguna-xs.2", 2048, (16384, 2048, 512),
+    "laguna prefill": ("laguna-xs.2", 2048, (2048, 2048, 512),
                        (256, 2048, 512), (256, 512, 2048)),
     "laguna decode": ("laguna-xs.2", 8, (64, 2048, 512),
                       (64, 1024, 512), (64, 512, 1024)),
@@ -505,9 +738,11 @@ def test_gmm_tiling_of_the_cells_calls(name, monkeypatch):
         else:           # a decode step: the tiles every call used to get
             assert (tk, tn) == (min(k, 1024), min(n, 1024))
         assert moe._gmm_vmem_bytes(tm, tk, tn, 2) <= moe.GMM_VMEM_BYTES
-    cfg = get_config(preset)
-    assert (cfg.moe_top_k * min(tokens, moe.TOKEN_CHUNK), cfg.hidden_size,
-            cfg.moe_width) == (m, h, f)
+    # The share cells hold 32 experts a chip; lfm2's holds all 64.
+    cfg = get_config(preset, moe_experts_held=0 if "lfm2" in preset else 32)
+    assert (moe.row_window(cfg.moe_top_k * min(tokens, moe.TOKEN_CHUNK),
+                           cfg.moe_experts_here, cfg.moe_num_experts),
+            cfg.hidden_size, cfg.moe_width) == (m, h, f)
     assert moe.gmm_tilings(cfg, tokens) == {}          # ragged_dot off the TPU
     monkeypatch.setattr(hw, "on_tpu", lambda: True)
     assert moe.gmm_tilings(cfg, tokens) == {"gate_up": list(gate_up),
@@ -712,11 +947,72 @@ def test_engine_slots_at_different_lengths_and_a_reused_slot():
     assert here + elsewhere == fed * 4 * 2
     assert 0.3 < here / (here + elsewhere) < 0.7      # 8 of 16 experts
     assert stats["hits"]["decode"] <= stats["calls"]["decode"]
+    # Rows to the experts and back: toy rows are under one row tile, so
+    # every forward's window is all its rows — a prefill bucket's tokens
+    # x top-4, a decode step's 2 slots x top-4 — a sparse layer.
+    forwards = {"prefill": stats["all_rows"]["prefill"] // 2,
+                "decode": stats["all_rows"]["decode"] // 2}
+    assert forwards["prefill"] == 3 and forwards["decode"] % 4 == 0
+    assert stats["rows_moved"]["decode"] == forwards["decode"] * 2 * 2 * 4
+    assert stats["rows_moved"]["prefill"] == sum(
+        eng._bucket_for(len(q)) for q in prompts) * 4 * 2
     occ = eng.kv_occupancy()
     assert occ["latent_cache_bytes"] == occ["kv_pool_bytes"] > 0
     groups = eng.memory_groups()
     assert groups["latent_cache"].shape == (3, 2, 65, cfg.latent_width)
     assert groups["kv_cache"].latent is None
+
+
+@pytest.mark.parametrize("preset,held,chunk,bucket16,burst16,step", [
+    ("laguna-xs.2", 32, 2048, 128, 256, 64),
+    ("mimo-v2-flash", 32, 2048, 128, 256, 64),
+    ("sarvam-105b", 32, 4096, 128, 256, 64),
+    ("lfm2-24b-a2b", 0, 8192, 64, 512, 32)])
+def test_census_names_the_rows_a_window_holds(preset, held, chunk, bucket16,
+                                              burst16, step):
+    """engine.moe_row_window (warmup_census, GET /debug/programs) at the
+    cells' shapes: a prefill chunk's window is what the held share can
+    expect of 2048 tokens x top_k, whatever the burst's rows (one row tile
+    of a burst of eight buckets of 16); a bucket of 16 tokens and a decode
+    step of 8 slots are one window of all their rows; nothing for a dense
+    model. And the engine's count of the rows
+    moved follows from a dispatch's counts: the held rows in whole
+    windows, all rows where the window is all."""
+    import types
+
+    from runbooks_tpu.models.moe import rows_moved
+    from runbooks_tpu.serve.engine import InferenceEngine
+
+    def census(cfg):
+        return InferenceEngine.moe_row_window.func(types.SimpleNamespace(
+            cfg=cfg, max_slots=8, prefill_buckets=(16, 2048),
+            view_buckets=(512,)))
+
+    cfg = get_config(preset, moe_experts_held=held)
+    assert census(cfg) == {
+        "prefill_b16r1": bucket16, "prefill_b16r8": burst16,
+        "prefill_b2048r1": chunk, "prefill_b2048r8": chunk,
+        "decode_v512": step}
+    assert census(get_config("falcon-7b")) == {}
+    all_rows = 2048 * cfg.moe_top_k
+    eng = types.SimpleNamespace(
+        cfg=cfg, _moe_counts=np.zeros(cfg.moe_experts_here + 1, np.int64),
+        _moe_peak=0, _moe_hits={"prefill": [0, 0], "decode": [0, 0]},
+        _moe_rows={"prefill": [0, 0], "decode": [0, 0]})
+    # Two sparse layers of one [1, 2048] prefill: one held row over a
+    # window, and none.
+    counts = np.zeros((2, cfg.moe_experts_here + 1), np.int32)
+    counts[0, 0], counts[0, 1] = chunk // 2 + 1, chunk // 2
+    InferenceEngine._count_moe(eng, "prefill", [(counts, 2)], 2048)
+    if chunk == all_rows:
+        assert eng._moe_rows["prefill"] == [2 * all_rows, 2]
+    else:
+        assert eng._moe_rows["prefill"] == [2 * chunk, 0]
+        assert rows_moved(cfg, 2048, chunk) == (chunk, False)
+        assert rows_moved(cfg, 2048, all_rows) == (all_rows, False)
+    # A decode dispatch of 4 steps: all 8 slots' rows a step and layer.
+    InferenceEngine._count_moe(eng, "decode", [(counts, 2)], 8, steps=4)
+    assert eng._moe_rows["decode"] == [4 * 2 * step, 4 * 2]
 
 
 @pytest.mark.parametrize("options,text", [
