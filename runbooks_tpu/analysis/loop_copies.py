@@ -178,14 +178,18 @@ def _update_size(instr: Instr, comp: List[Instr],
 
 def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
     """(dtype, dims) -> the largest in-place update allowed there, for a
-    KVCache (or its ShapeDtypeStructs): each leaf whole, and one layer of
-    k / v / the scales / the latent. A token-sized write is anything under a layer of
-    K/V; the recurrent leaves change a whole layer at a time. Of a window
-    layer's ring leaves only the whole leaf counts: one layer of a ring is
-    what a decode step reads (a window and a margin long, by design)."""
+    KVCache (or its ShapeDtypeStructs), a leaf by what its declaration
+    says of it (models/transformer.LEAF_TRAITS). A leaf with a token axis
+    takes token-sized writes, anything under a layer of it: with a slot
+    axis that holds of the leaf whole and of one layer of it, sliced out
+    either way; of a window layer's ring only of the whole leaf, one layer
+    of a ring being what a decode step reads (a window and a margin long, by
+    design). The leaves with no token axis, the recurrent ones, change a
+    whole layer at a time."""
+    from runbooks_tpu.models.transformer import LEAF_TRAITS
+
     shapes: Dict[Tuple[str, Tuple[int, ...]], int] = {}
-    for field in ("k", "v", "k_scale", "v_scale", "state", "conv",
-                  "latent", "ring_k", "ring_v"):
+    for field, traits in LEAF_TRAITS.items():
         leaf = getattr(cache, field)
         if leaf is None or 0 in leaf.shape:   # a latent cache's empty k, v
             continue
@@ -195,12 +199,8 @@ def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
         if sharding is not None:       # the text has one device's shapes
             dims = tuple(sharding.shard_shape(dims))
         layer = math.prod(dims[1:])
-        if field in ("state", "conv"):
-            shapes[(dtype, dims)] = layer
-        elif field in ("ring_k", "ring_v"):
-            shapes[(dtype, dims)] = layer - 1
-        else:
-            shapes[(dtype, dims)] = layer - 1
+        shapes[(dtype, dims)] = layer - 1 if traits.tokens else layer
+        if traits.tokens == "slots":
             shapes[(dtype, dims[1:])] = layer - 1
             shapes[(dtype, (1,) + dims[1:])] = layer - 1
     return shapes

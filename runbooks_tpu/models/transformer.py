@@ -29,6 +29,7 @@ delegates compute to external containers, SURVEY.md §2a):
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
@@ -662,64 +663,128 @@ class KVCache:
                trash_slot: bool = False,
                quantize_kv: bool = False) -> "KVCache":
         cache_len = max_len + 1 if trash_slot else max_len
-        shape = (cfg.layers_of("full_attention"), batch, cache_len,
-                 cfg.num_kv_heads, cfg.head_dim)
-        v_shape = shape[:-1] + (cfg.value_head_dim,)
-        recurrent = {}
-        if cfg.has_window:
-            if quantize_kv:
-                raise NotImplementedError(
-                    "quantize_kv has no form for a window layer's ring "
-                    "cache yet (docs/window-full-models.md)")
-            ring = (cfg.layers_of("sliding_attention"), batch, cfg.ring_len,
-                    cfg.attn_shape("sliding_attention").kv_heads)
-            recurrent.update(
-                ring_k=jnp.zeros(ring + (cfg.head_dim,),
-                                 cfg.activation_dtype),
-                ring_v=jnp.zeros(ring + (cfg.value_head_dim,),
-                                 cfg.activation_dtype))
-        if cfg.latent_cache:
-            if quantize_kv:
-                raise NotImplementedError(
-                    "quantize_kv stores one scale a KV head; a latent "
-                    "cache has no head axis and no int8 form yet "
-                    "(docs/sparse-latent-models.md)")
-            recurrent["latent"] = jnp.zeros(
-                (cfg.layers_of("latent_attention"), batch, cache_len,
-                 cfg.latent_width), cfg.activation_dtype)
-        if cfg.has_short_conv:
-            recurrent["conv"] = jnp.zeros(
-                (cfg.layers_of("conv"), batch, cfg.conv_kernel - 1,
-                 cfg.hidden_size), cfg.activation_dtype)
-        if cfg.has_linear_attention:
-            n_lin = cfg.layers_of("linear_attention")
-            recurrent.update(
-                state=jnp.zeros(
-                    (n_lin, batch, cfg.linear_num_heads,
-                     cfg.linear_key_head_dim, cfg.linear_value_head_dim),
-                    jnp.float32),
-                conv=jnp.zeros(
-                    (n_lin, batch, cfg.linear_conv_kernel - 1,
-                     cfg.linear_conv_dim), cfg.activation_dtype))
-        if quantize_kv:
-            return cls(
-                k=jnp.zeros(shape, jnp.int8),
-                v=jnp.zeros(v_shape, jnp.int8),
-                index=jnp.zeros((), jnp.int32),
-                k_scale=jnp.zeros(shape[:-1], jnp.float32),
-                v_scale=jnp.zeros(shape[:-1], jnp.float32),
-                **recurrent,
-            )
-        return cls(
-            k=jnp.zeros(shape, cfg.activation_dtype),
-            v=jnp.zeros(v_shape, cfg.activation_dtype),
-            index=jnp.zeros((), jnp.int32),
-            **recurrent,
-        )
+        return cls(index=jnp.zeros((), jnp.int32), **{
+            leaf.name: jnp.zeros(leaf.shape(cfg, batch, cache_len),
+                                 leaf.dtype)
+            for leaf in cache_leaves(cfg, quantize_kv)})
 
     @property
     def quantized(self) -> bool:
         return self.k.dtype == jnp.int8
+
+
+class LeafTraits(NamedTuple):
+    """What a KVCache field is, whatever the configuration."""
+    # Its axis behind [layers, batch]: "slots" (cache_len of them: the
+    # trash slot, cache_view and token-sized writes apply), "ring" (a row's
+    # own ring of tokens, written a token at a time) or None (a row's own
+    # state or tail, rewritten a layer at a time).
+    tokens: Optional[str]
+    axes: tuple        # logical sharding axes (parallel/sharding.py)
+    group: str         # its bytes are reported as <group>_bytes
+
+
+_BY_KV_HEAD = (None, "batch", None, "act_heads", None)
+_WHOLE = (None, "batch", None, None)
+# The recurrent state shards by head; the conv tail (q | k | v channels side
+# by side) does not split on a head boundary and stays whole, as a latent
+# does, which has no head axis.
+LEAF_TRAITS = {        # KVCache's leaves, in its field order
+    "k": LeafTraits("slots", _BY_KV_HEAD, "kv_pool"),
+    "v": LeafTraits("slots", _BY_KV_HEAD, "kv_pool"),
+    "k_scale": LeafTraits("slots", _BY_KV_HEAD[:4], "kv_pool"),
+    "v_scale": LeafTraits("slots", _BY_KV_HEAD[:4], "kv_pool"),
+    "state": LeafTraits(None, (None, "batch", "act_heads", None, None),
+                        "recurrent_state"),
+    "conv": LeafTraits(None, _WHOLE, "recurrent_state"),
+    "latent": LeafTraits("slots", _WHOLE, "latent_cache"),
+    "ring_k": LeafTraits("ring", _BY_KV_HEAD, "kv_ring"),
+    "ring_v": LeafTraits("ring", _BY_KV_HEAD, "kv_ring"),
+}
+# The groups whose leaves have no int8 form, and why.
+_NO_INT8 = {
+    "kv_ring": "quantize_kv has no form for a window layer's ring cache "
+               "yet (docs/window-full-models.md)",
+    "latent_cache": "quantize_kv stores one scale a KV head; a latent "
+                    "cache has no head axis and no int8 form yet "
+                    "(docs/sparse-latent-models.md)",
+}
+
+
+class CacheLeaf(NamedTuple):
+    """One leaf a configuration's KVCache has (cache_leaves)."""
+    name: str          # the KVCache field
+    kind: str          # the layer kind whose layers it holds, in layer
+    #                    order: cfg.layers_of(kind) on its leading axis
+    tail: tuple        # its shape behind [layers, batch (, cache_len)]
+    dtype: Any
+    tokens: Optional[str]    # LeafTraits, by name
+    axes: tuple
+    group: str
+
+    def shape(self, cfg: ModelConfig, batch: int, cache_len: int) -> tuple:
+        slots = (cache_len,) if self.tokens == "slots" else ()
+        return (cfg.layers_of(self.kind), batch) + slots + self.tail
+
+
+class LayerCache(NamedTuple):
+    """What one layer's token mixer is handed of a cache: the WHOLE leaves
+    of its kind as forward's layer scan carries them (it writes and reads
+    its part by index, so the loop updates them in place), and where."""
+    leaves: dict       # {KVCache field: the leaf} of the layer's kind
+    layer: Any         # the layer's number in them (int, or traced int32)
+    index: Any         # cache.index; None in position-scatter mode
+    view: Optional[int]      # forward's cache_view
+    parked: Any        # [b, s] bool, a window layer's: the tokens that are
+    #                    nobody's (None: there is none)
+
+
+def _leaf_index(cfg: ModelConfig, kind: str, period, j: int):
+    """Where a layer lies in the leaves of its kind, which hold the kind's
+    layers in layer order, the leading ones first: the kind's j-th layer of
+    the scanned period number `period`."""
+    return (cfg.leading_layers_of(kind)
+            + period * cfg.layer_pattern.count(kind) + j)
+
+
+def cache_leaves(cfg: ModelConfig, quantize_kv: bool = False) -> tuple:
+    """The leaves a KVCache of this configuration has, in KVCache's field
+    order: THE declaration of what per-slot state each layer kind keeps
+    (KVCache's docstring has each kind's invariants). Whoever enumerates the
+    leaves reads it: KVCache.create, forward's layer scan, the serving
+    engine's splice, placement and gauges, analysis/loop_copies. A latent
+    configuration has k and v too, with no layer in them."""
+    ad, f32 = cfg.activation_dtype, jnp.float32
+    kv_dtype, heads = (jnp.int8 if quantize_kv else ad), cfg.num_kv_heads
+    found = [("k", "full_attention", (heads, cfg.head_dim), kv_dtype),
+             ("v", "full_attention", (heads, cfg.value_head_dim), kv_dtype)]
+    if quantize_kv:
+        found += [("k_scale", "full_attention", (heads,), f32),
+                  ("v_scale", "full_attention", (heads,), f32)]
+    if cfg.has_linear_attention:
+        found += [
+            ("state", "linear_attention",
+             (cfg.linear_num_heads, cfg.linear_key_head_dim,
+              cfg.linear_value_head_dim), f32),
+            ("conv", "linear_attention",
+             (cfg.linear_conv_kernel - 1, cfg.linear_conv_dim), ad)]
+    if cfg.has_short_conv:
+        found.append(("conv", "conv",
+                      (cfg.conv_kernel - 1, cfg.hidden_size), ad))
+    if cfg.latent_cache:
+        found.append(("latent", "latent_attention", (cfg.latent_width,), ad))
+    if cfg.has_window:
+        ring = (cfg.ring_len, cfg.attn_shape("sliding_attention").kv_heads)
+        found += [
+            ("ring_k", "sliding_attention", ring + (cfg.head_dim,), ad),
+            ("ring_v", "sliding_attention", ring + (cfg.value_head_dim,),
+             ad)]
+    leaves = tuple(CacheLeaf(*f, *LEAF_TRAITS[f[0]]) for f in found)
+    no_int8 = [_NO_INT8[leaf.group] for leaf in leaves
+               if leaf.group in _NO_INT8]
+    if quantize_kv and no_int8:
+        raise NotImplementedError(no_int8[0])
+    return leaves
 
 
 # ---------------------------------------------------------------------------
@@ -981,7 +1046,7 @@ def _attention_block(
     segment_ids: Optional[jax.Array],
     mask: Optional[jax.Array],
     bias: Optional[jax.Array],
-    layer_cache: Optional[tuple],  # _write_layer_cache, _window_attention
+    layer_cache: Optional[LayerCache],
     adapter=None,
     kind: str = "full_attention",
 ):
@@ -1052,10 +1117,10 @@ def _attention_block(
             k, v, new_layer_cache = _write_layer_cache(k, v, positions,
                                                        layer_cache, ad)
         with jax.named_scope("attn.core"):
-            # layer_cache = (k, v, ..., layer, index, view): no index means
-            # position-scatter mode, whose last slot is the trash slot.
-            trash_pos = (layer_cache[0].shape[2] - 1
-                         if layer_cache[5] is None else None)
+            # No index means position-scatter mode, whose last slot is the
+            # trash slot.
+            trash_pos = (layer_cache.leaves["k"].shape[2] - 1
+                         if layer_cache.index is None else None)
             out = _cached_attention(cfg, q, k, v, positions, mask, bias,
                                     trash_pos)
     else:
@@ -1093,22 +1158,22 @@ def _window_attention(cfg: ModelConfig, q, k, v, sink, positions,
     call                as without a cache, and the row's last `ring` real
                         tokens written to the ring, the rest to nowhere.
 
-    ``ring_cache``: None or (ring_k, ring_v, layer, parked): the WHOLE
-    ring leaves as forward's scan carries them, this layer's number in
-    them, and [b, s] bool naming the tokens that are nobody's (None: no
-    such token). ``flash``: the caller built no mask because the flash
-    forward needs none. Returns (out [b, s, heads, value_head_dim], None or
-    the (ring_k, ring_v) leaves)."""
+    ``ring_cache``: None or the LayerCache of the ring leaves.
+    ``flash``: the caller built no mask because the flash forward needs
+    none. Returns (out [b, s, heads, value_head_dim], None or the updated
+    leaves)."""
     from runbooks_tpu.models.config import RING_MARGIN
 
     b, s = positions.shape
     W, ring = cfg.sliding_window, cfg.ring_len
-    parked = None if ring_cache is None else ring_cache[3]
+    parked = None if ring_cache is None else ring_cache.parked
     leaves = None
     through_ring = ring_cache is not None and s <= RING_MARGIN + 1
     if ring_cache is not None:
         with jax.named_scope("swa.ring_write"):
-            ring_k, ring_v, layer, _ = ring_cache
+            ring_k, ring_v = (ring_cache.leaves[n]
+                              for n in ("ring_k", "ring_v"))
+            layer = ring_cache.layer
             keep = jnp.ones((b, s), bool) if parked is None else ~parked
             if not through_ring:
                 # Of a long call only the row's last `ring` real tokens:
@@ -1122,7 +1187,7 @@ def _window_attention(cfg: ModelConfig, q, k, v, sink, positions,
             b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
             ring_k = ring_k.at[layer, b_idx, slot].set(k, mode="drop")
             ring_v = ring_v.at[layer, b_idx, slot].set(v, mode="drop")
-            leaves = (ring_k, ring_v)
+            leaves = {"ring_k": ring_k, "ring_v": ring_v}
     with jax.named_scope("swa.core"):
         if through_ring:
             def row(leaf):
@@ -1171,14 +1236,16 @@ def _write_layer_cache(k, v, positions, layer_cache, ad):
     """Write this call's K/V into the pool at this layer and return what
     attention reads: (k, v, the pool's leaves after the write).
 
-    ``layer_cache`` is (k, v, k_scale, v_scale, layer, index, view): the
-    WHOLE pool leaves [full layers, batch, cache_len, …] as forward's layer
-    scan carries them, and this layer's number in them. Only the tokens of
-    this call are written and only ``[layer, :, :view]`` is read, both by
-    index into the carried buffer, so the loop updates the pool in place:
-    no layer is sliced out, none is written back."""
+    ``layer_cache`` is the LayerCache of the pool leaves [full layers,
+    batch, cache_len, …]. Only the tokens of this call are written and only
+    ``[layer, :, :view]`` is read, both by index into the carried buffer,
+    so the loop updates the pool in place: no layer is sliced out, none is
+    written back."""
     b = k.shape[0]
-    ck, cv, ck_s, cv_s, layer, index, view = layer_cache
+    leaves, layer = layer_cache.leaves, layer_cache.layer
+    index, view = layer_cache.index, layer_cache.view
+    ck, cv = leaves["k"], leaves["v"]
+    ck_s, cv_s = leaves.get("k_scale"), leaves.get("v_scale")
     quantized = ck.dtype == jnp.int8
     if quantized:
         # int8 KV: one f32 scale per (row, slot, kv-head) rides next to
@@ -1229,7 +1296,8 @@ def _write_layer_cache(k, v, positions, layer_cache, ad):
         # bound on.
         k = dequantize_kv(k, read(ck_s), ad)
         v = dequantize_kv(v, read(cv_s), ad)
-    return k, v, (ck, cv, ck_s, cv_s)
+        return k, v, {"k": ck, "v": cv, "k_scale": ck_s, "v_scale": cv_s}
+    return k, v, {"k": ck, "v": cv}
 
 
 def _cached_attention(cfg: ModelConfig, q, k, v, positions, mask, bias,
@@ -1276,9 +1344,10 @@ def _write_layer_latent(lat, positions, layer_cache):
     (lat [b, s, width]) at [layer, row, slot], token-sized and in place in
     the carried leaf [latent layers, batch, cache_len, width]; return
     (this layer's view [b, view, width] of the UPDATED leaf, the leaf).
-    ``layer_cache`` is (leaf, layer, index, view)."""
+    ``layer_cache`` is the LayerCache of the latent leaf."""
     b = lat.shape[0]
-    leaf, layer, index, view = layer_cache
+    leaf, layer = layer_cache.leaves["latent"], layer_cache.layer
+    index, view = layer_cache.index, layer_cache.view
     if index is None:
         slot = jnp.clip(positions, 0, leaf.shape[2] - 1)
         b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
@@ -1309,9 +1378,9 @@ def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
               lies, the weighted sum of c comes back through W_uv: the
               cache is never expanded to per-head keys or values.
 
-    ``layer_cache``: None or (latent leaf, layer, index, view), see
+    ``layer_cache``: None or the LayerCache of the latent leaf, see
     _write_layer_latent. Returns (out [b, s, h], None or the updated
-    leaves: a tuple of the one leaf, as _attention_block gives four)."""
+    leaf, by name as _attention_block gives its own)."""
     b, s, _ = x.shape
     ad = cfg.activation_dtype
     H, r = cfg.num_heads, cfg.kv_lora_rank
@@ -1357,8 +1426,8 @@ def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
                 out = _dispatch_attention(cfg, q, k, v, positions,
                                           segment_ids, mask, None, scale)
             else:
-                trash_pos = (layer_cache[0].shape[2] - 1
-                             if layer_cache[2] is None else None)
+                trash_pos = (leaf.shape[2] - 1
+                             if layer_cache.index is None else None)
                 out = _cached_attention(cfg, q, k, v, positions, None, None,
                                         trash_pos, scale)
     else:
@@ -1377,7 +1446,7 @@ def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
                              preferred_element_type=jnp.float32).astype(ad)
     with jax.named_scope("mla.out"):
         out = _matmul(out.reshape(b, s, H * dv), p["wo"], ad)
-    return out, None if leaf is None else (leaf,)
+    return out, None if leaf is None else {"latent": leaf}
 
 
 def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -1385,12 +1454,11 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     """The gated-delta token mixer (ops/gated_delta.py) of one layer.
     x [b, s, h]; token_mask [b, s] bool or None (all valid; a row's valid
     tokens are a prefix of it); layer_state None (no cache: start from
-    zeros, keep nothing) or (state, conv, layer): the cache's WHOLE
-    recurrent leaves ([linear layers, b, H, d_k, d_v] f32 and [linear
-    layers, b, kernel-1, channels]) as forward's layer scan carries them,
-    and this layer's number among the linear ones. The layer reads its
-    own state and conv tail there and writes the new ones back at the same
-    index. Returns (out [b, s, h], None or the (state, conv) leaves)."""
+    zeros, keep nothing) or the LayerCache of the recurrent leaves (state
+    [linear layers, b, H, d_k, d_v] f32 and conv [linear layers, b,
+    kernel-1, channels]). The layer reads its own state and conv tail there
+    and writes the new ones back at the same index. Returns (out [b, s, h],
+    None or the updated leaves)."""
     from runbooks_tpu.ops.gated_delta import (
         causal_conv,
         gated_delta_chunked,
@@ -1406,7 +1474,9 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     kd = cfg.linear_key_dim
     state = tail = None
     if layer_state is not None:
-        all_state, all_tail, layer = layer_state
+        all_state, all_tail = (layer_state.leaves[n]
+                               for n in ("state", "conv"))
+        layer = layer_state.layer
         state = jax.lax.dynamic_index_in_dim(all_state, layer, 0, False)
         tail = jax.lax.dynamic_index_in_dim(all_tail, layer, 0, False)
 
@@ -1453,9 +1523,10 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         out = _matmul(o.reshape(b, s, H * dv), p["wo"], ad)
     if layer_state is None:
         return out, None
-    return out, (
-        jax.lax.dynamic_update_index_in_dim(all_state, state, layer, 0),
-        jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0))
+    return out, {
+        "state": jax.lax.dynamic_update_index_in_dim(
+            all_state, state, layer, 0),
+        "conv": jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0)}
 
 
 def _short_conv_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -1465,18 +1536,18 @@ def _short_conv_block(cfg: ModelConfig, p: Params, x: jax.Array,
     causal convolution of z over conv_kernel tokens (no bias, no
     activation; before a row's first token z is 0), out = (C * c) W_out.
     token_mask as _linear_attention_block's; layer_tail None (no cache:
-    start from zeros, keep nothing) or (conv, layer): the cache's WHOLE
-    conv leaf [conv layers, b, conv_kernel-1, h] as forward's scan carries
-    it, and this layer's number in it. The layer reads its tail there (z
+    start from zeros, keep nothing) or the LayerCache of the conv leaf
+    [conv layers, b, conv_kernel-1, h]. The layer reads its tail there (z
     at the row's last conv_kernel-1 valid tokens) and writes the new one
-    back at the same index. Returns (out [b, s, h], None or the leaf)."""
+    back at the same index. Returns (out [b, s, h], None or the updated
+    leaf, by name)."""
     from runbooks_tpu.ops.gated_delta import causal_conv
 
     ad = cfg.activation_dtype
     h = cfg.hidden_size
     tail = None
     if layer_tail is not None:
-        all_tail, layer = layer_tail
+        all_tail, layer = layer_tail.leaves["conv"], layer_tail.layer
         tail = jax.lax.dynamic_index_in_dim(all_tail, layer, 0, False)
     with jax.named_scope("shortconv.in"):
         bcx = _matmul(x, p["w_in"], ad)
@@ -1489,7 +1560,8 @@ def _short_conv_block(cfg: ModelConfig, p: Params, x: jax.Array,
         out = _matmul(bcx[..., h:2 * h] * c, p["w_out"], ad)
     if layer_tail is None:
         return out, None
-    return out, jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0)
+    return out, {"conv": jax.lax.dynamic_update_index_in_dim(
+        all_tail, tail, layer, 0)}
 
 
 def _mlp_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -1550,6 +1622,14 @@ def _adapter_group(adapter, group: str):
     return None if sub is None else (sub, idx)
 
 
+# Where params keeps the layers of a kind that the period scan runs: the
+# one stack of the attention kind, or a list with a stack for each position
+# the kind has in the period. The attention kind comes first.
+_STACK_OF = {"full_attention": "layers", "latent_attention": "layers",
+             "linear_attention": "linear_layers",
+             "sliding_attention": "window_layers", "conv": "conv_layers"}
+
+
 def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
            bias, layer_cache, adapter=None, token_mask=None,
            kind: str = "full_attention"):
@@ -1557,10 +1637,9 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
     counts): counts is None, or a sparse FFN's assignment counts.
     ``adapter``: None or (per-layer adapter-pool slice, lane indices) —
     the grouped LoRA injection (docs/multi-tenant-lora.md). ``kind`` names
-    the token mixer (ModelConfig.layer_types). ``layer_cache`` holds the
-    cache's whole leaves of the layer's kind and the layer's number in them
-    (_write_layer_cache, _linear_attention_block, _window_attention); the
-    updated leaves come back. ``token_mask`` says which tokens may change a
+    the token mixer (ModelConfig.layer_types). ``layer_cache`` is None or
+    the LayerCache of the layer's kind; the updated leaves come back, by
+    name. ``token_mask`` says which tokens may change a
     linear-attention layer's state or a conv layer's tail, and which a
     sparse FFN routes at all."""
 
@@ -1737,12 +1816,7 @@ def forward(
     b, s = tokens.shape
     ad = cfg.activation_dtype
     pattern = cfg.layer_pattern
-    if cfg.has_recurrent_state:
-        _check_recurrent_support(cfg, segment_ids, adapters)
-    if cfg.latent_cache:
-        _check_latent_support(cfg, adapters)
-    if cfg.has_window:
-        _check_window_support(cfg, adapters)
+    _check_support(cfg, segment_ids, adapters)
 
     if cache is not None and segment_ids is not None:
         raise NotImplementedError(
@@ -1840,230 +1914,163 @@ def forward(
     # One scan step is one PERIOD of the layer pattern: its one
     # full-attention layer (params["layers"], scanned as it lies, so a
     # homogeneous model's program is what it was before patterns) and its
-    # linear-attention layers, each position's stack scanned the same
-    # way. A cache's leaves ride the scan's CARRY, whole: a scanned
-    # output is a new buffer by construction, so leaves scanned as xs/ys
-    # cost a copy of a whole layer in and out every layer, and a copy of
-    # the pool for whoever carries the cache through a loop of its own
-    # (make_decode_fn). Each layer writes its part by index — the period's
-    # number for K/V, period * n_lin + i for the recurrent leaves, which
-    # lie in layer order — and the loop updates the buffers in place.
-    n_lin = pattern.count("linear_attention")
-    n_win = pattern.count("sliding_attention")
-    n_conv = pattern.count("conv")
-    n_lead = cfg.leading_dense_layers
-    # A kind's leaves hold the leading layers of that kind first: the
-    # attention kind's (K/V, latent) or the conv leaf.
-    kv_lead = cfg.leading_layers_of(cfg.attention_kind)
-    conv_lead = cfg.leading_layers_of("conv")
-
-    def attention_layer(layer_params, x, kv, layer, adapter=None):
-        """One layer of the kind of params["layers"] at index `layer` of
-        the cache's leaves (kv: the K/V leaves, or the latent leaf)."""
-        layer_cache = None
-        if cache is not None:
-            layer_cache = (*kv, layer,
-                           None if scatter_mode else cache.index,
-                           cache_view)
-        x, new_kv, aux, counts = block(
-            cfg, layer_params, x, positions, segment_ids, mask, bias,
-            layer_cache, adapter, token_mask)
-        return x, new_kv, aux, counts
-
-    layers, lin_layers = params["layers"], params.get("linear_layers")
-    win_layers = params.get("window_layers")
-    conv_layers = params.get("conv_layers")
-
-    def conv_layer(layer_params, x, rec, layer):
-        """One short-convolution layer at index `layer` of the conv leaf
-        (rec = (None, the conv leaf); None without a cache)."""
-        layer_cache = None if cache is None else (rec[1], layer)
-        x, tail, aux, counts = blocks["conv"](
-            cfg, layer_params, x, positions, segment_ids, mask, bias,
-            layer_cache, None, token_mask)
-        return x, rec if cache is None else (rec[0], tail), aux, counts
+    # other layers, each position's stack scanned the same way. A cache's
+    # leaves ride the scan's CARRY, whole: a scanned output is a new buffer
+    # by construction, so leaves scanned as xs/ys cost a copy of a whole
+    # layer in and out every layer, and a copy of the pool for whoever
+    # carries the cache through a loop of its own (make_decode_fn). Each
+    # layer writes its part by index (`run_layer`) and the loop updates the
+    # buffers in place.
+    kinds = [kind for kind in _STACK_OF if kind in pattern]
+    # The leaves that hold a layer (a latent cache's k and v hold none), in
+    # KVCache's field order: what the carry flattens to.
+    carried = [] if cache is None else [
+        leaf for leaf in cache_leaves(cfg, cache.quantized)
+        if cfg.layers_of(leaf.kind)]
     # Tokens a window layer's ring must not take: in position-scatter mode
     # padding is parked at the K/V leaves' last slot, by position.
     parked = (positions >= cache.k.shape[2] - 1
-              if scatter_mode and n_win else None)
+              if scatter_mode and cfg.has_window else None)
+
+    def run_layer(kind, layer_params, x, leaves, layer, adapter=None):
+        """One layer of `kind`, number `layer` among the layers of its kind
+        (where its part of that kind's leaves lies). `leaves`: the carried
+        leaves by name, given back with the layer's own updated."""
+        layer_cache = None
+        if cache is not None:
+            layer_cache = LayerCache(
+                {leaf.name: leaves[leaf.name] for leaf in carried
+                 if leaf.kind == kind}, layer,
+                None if scatter_mode else cache.index, cache_view, parked)
+        x, new, aux, counts = blocks[kind](
+            cfg, layer_params, x, positions, segment_ids, mask, bias,
+            layer_cache, adapter, token_mask)
+        return x, leaves | (new or {}), aux, counts
+
     names = ("wi_gate", "wi_up", "wo")
-    whole_stacks = (cache is not None and "moe" in layers
-                    and not _expert_mesh())
+    whole_stacks = cache is not None and not _expert_mesh()
 
     def without_stacks(tree):
         """Serving: a sparse layer gets its expert matrices as the WHOLE
         stacks beside its number in them, not as the scan's slice of them
         (models/moe.grouped_matmul says why): (the tree without them,
         them)."""
-        if not whole_stacks:
+        if not whole_stacks or "moe" not in tree:
             return tree, None
         moe = tree["moe"]
         return ({**tree, "moe": {k: v for k, v in moe.items()
                                  if k not in names}},
                 {k: moe[k] for k in names})
 
-    def with_stacks(tree, stacks, period):
-        if stacks is None:
-            return tree
-        return {**tree, "moe": {**tree["moe"], **stacks},
-                "moe_layer": period - kv_lead}
-
-    layers, expert_stacks = without_stacks(layers)
-    if win_layers is not None:
-        win_layers, win_stacks = zip(*map(without_stacks, win_layers))
-    if conv_layers is not None:
-        conv_layers, conv_stacks = zip(*map(without_stacks, conv_layers))
+    # Each kind's stacks, one a position it has in the period (the
+    # attention kind has one), and the expert stacks taken out of them.
+    scanned, expert_stacks = [], {}
+    for kind in kinds:
+        stacks = params[_STACK_OF[kind]]
+        trees, expert_stacks[kind] = zip(*map(
+            without_stacks, [stacks] if isinstance(stacks, dict) else stacks))
+        scanned.append(trees)
     # The scanned stacks' layouts, in the order the scan body flattens
     # its slices of them (the leaves are the parameters' own objects).
     lain = None
     if weight_layouts is not None:
         by_leaf = dict(zip(map(id, jax.tree.leaves(params)), weight_layouts))
-        lain = [by_leaf.get(id(w)) for w in jax.tree.leaves(
-            (layers, lin_layers, win_layers, conv_layers))]
+        lain = [by_leaf.get(id(w)) for w in jax.tree.leaves(scanned)]
 
-    def scan_body(carry, scanned):
-        x, aux_sum, kv, rec, ring = carry
-        (layers, pool_layer, lin_layers, win_layers, conv_layers,
-         period) = scanned
+    def scan_body(carry, xs):
+        x, aux_sum, leaves = carry
+        scanned, pool_layer, rel = xs
         if lain is not None:
-            sliced, tree = jax.tree.flatten(
-                (layers, lin_layers, win_layers, conv_layers))
-            layers, lin_layers, win_layers, conv_layers = tree.unflatten(
-                list(map(_read_in_place, sliced, lain)))
+            sliced, tree = jax.tree.flatten(scanned)
+            scanned = tree.unflatten(list(map(_read_in_place, sliced, lain)))
+        stacks = dict(zip(kinds, scanned))
         adapter = None if apool is None else (pool_layer, aidx)
-        # The period's number among the scanned ones: where its layers lie
-        # in the leaves that hold no leading layer (recurrent, ring; with
-        # leading conv layers, K/V), and behind conv_lead in the conv leaf.
-        # (No cache: no number, and nothing reads one.)
-        rel = period - kv_lead if kv_lead and cache is not None else period
-        i = w = c_i = 0
         counts = []
-        for kind in pattern:
-            if kind == "conv":
-                x, rec, aux, c = conv_layer(
-                    with_stacks(conv_layers[c_i], conv_stacks[c_i], period),
-                    x, rec, (None if cache is None
-                             else conv_lead + rel * n_conv + c_i))
-                counts.append(c)
-                c_i += 1
-            elif kind == "linear_attention":
-                layer_cache = (None if cache is None
-                               else (*rec, rel * n_lin + i))
-                x, rec, aux, _ = blocks[kind](
-                    cfg, lin_layers[i], x, positions, segment_ids, mask,
-                    bias, layer_cache, None, token_mask)
-                i += 1
-            elif kind == "sliding_attention":
-                layer_cache = (None if cache is None
-                               else (*ring, rel * n_win + w, parked))
-                x, ring, aux, c = blocks[kind](
-                    cfg, with_stacks(win_layers[w], win_stacks[w], period),
-                    x, positions, segment_ids, mask, bias, layer_cache,
-                    None, token_mask)
-                counts.append(c)
-                w += 1
-            else:
-                x, kv, aux, c = attention_layer(
-                    with_stacks(layers, expert_stacks, period), x, kv,
-                    period, adapter)
-                counts.append(c)
+        for at, kind in enumerate(pattern):
+            j = pattern[:at].count(kind)
+            layer_params = stacks[kind][j]
+            if expert_stacks[kind][j] is not None:
+                # `rel` is the period's number among the scanned ones: the
+                # sparse layer's number in its whole stacks.
+                layer_params = {
+                    **layer_params, "moe_layer": rel,
+                    "moe": {**layer_params["moe"], **expert_stacks[kind][j]}}
+            # (No cache: no number, and nothing reads one.)
+            layer = None if cache is None else _leaf_index(cfg, kind, rel, j)
+            x, leaves, aux, c = run_layer(
+                kind, layer_params, x, leaves, layer,
+                adapter if kind == cfg.attention_kind else None)
+            counts.append(c)
             aux_sum = aux_sum + aux
         # A sparse model's assignment counts, a row a layer in the
         # period's order (one row: as the one layer gave them).
         counts = (None if counts[0] is None
                   else counts[0] if len(counts) == 1 else jnp.stack(counts))
-        return (x, aux_sum, kv, rec, ring), counts
+        return (x, aux_sum, leaves), counts
 
     aux_total = jnp.zeros((), jnp.float32)
-    kv = rec = ring = None
-    if cache is not None:
-        # k_scale/v_scale are None (empty pytrees) for an unquantized
-        # cache, as state/conv are for a model without linear-attention
-        # layers; the carry threads them through untouched either way. A
-        # latent cache carries its one leaf in their place.
-        kv = ((cache.latent,) if cfg.latent_cache
-              else (cache.k, cache.v, cache.k_scale, cache.v_scale))
-        rec = (cache.state, cache.conv)
-        ring = (cache.ring_k, cache.ring_v)
-    if n_lead:
+    # An OrderedDict flattens in its own order, KVCache's field order here
+    # (a dict in the scan's carry would flatten by sorted name).
+    leaves = collections.OrderedDict(
+        (leaf.name, getattr(cache, leaf.name)) for leaf in carried)
+    if cfg.leading_dense_layers:
         # Leading layers (dense FFN, parameter shapes of their own) run
         # unrolled before the scan, under the same block, at indices
         # 0 .. n_lead - 1 of their kind's leaves.
         with jax.named_scope("leading_layers"):
-            for i in range(n_lead):
+            for i in range(cfg.leading_dense_layers):
                 one = jax.tree.map(lambda a: a[i], params["leading_layers"])
-                if conv_lead:
-                    x, rec, aux, _ = conv_layer(one, x, rec, i)
-                else:
-                    x, kv, aux, _ = attention_layer(one, x, kv, i)
+                x, leaves, aux, _ = run_layer(
+                    cfg.leading_layer_kind, one, x, leaves, i)
                 aux_total = aux_total + aux
     moe_counts = None
-    if cache is not None:
-        # The adapter pool (leading L axis) rides the scan as xs when given.
-        # The period's number as the K/V leaves count it: the scan's
-        # layers lie behind the leading ones there, where those are
-        # attention layers.
-        xs = (layers, apool, lin_layers, win_layers, conv_layers,
-              kv_lead + jnp.arange(cfg.num_periods, dtype=jnp.int32)
-              if kv_lead else jnp.arange(cfg.num_periods, dtype=jnp.int32))
-        init = (x, aux_total, kv, rec, ring)
-        # `layers`: the scan itself (slices of the stacked weights, what
-        # the compiler hoists out of the loop); each layer is a `block`.
-        with jax.named_scope("layers"):
-            (x, aux_total, new_kv, new_rec, new_ring), moe_counts = \
-                jax.lax.scan(scan_body, init, xs)
-        new_state, new_conv = new_rec
-        new_index = cache.index if scatter_mode else cache.index + s
-        if cfg.latent_cache:
-            new_cache = dataclasses.replace(
-                cache, index=new_index, latent=new_kv[0],
-                state=new_state, conv=new_conv)
-        else:
-            new_k, new_v, new_ks, new_vs = new_kv
-            new_cache = KVCache(k=new_k, v=new_v, index=new_index,
-                                k_scale=new_ks, v_scale=new_vs,
-                                state=new_state, conv=new_conv,
-                                ring_k=new_ring[0], ring_v=new_ring[1])
-    else:
+    n_stages = 1
+    if cache is None:
         from runbooks_tpu.parallel.sharding import _current_mesh
 
         mesh = _current_mesh()
         n_stages = int(mesh.shape.get("stage", 1)) if mesh is not None \
             else 1
-        if n_stages > 1:
-            if apool is not None:
-                raise NotImplementedError(
-                    "adapter pools are not supported on the pipeline "
-                    "(stage > 1) path; serve adapters with tensor/data "
-                    "parallelism (docs/multi-tenant-lora.md)")
-            if len(pattern) > 1:
-                raise NotImplementedError(
-                    "a layer pattern is not supported on the pipeline "
-                    "(stage > 1) path: its stages split one homogeneous "
-                    "stack (parallel/pipeline.py)")
-            # Pipeline-parallel path: same block, stacked layers sharded
-            # over the stage axis, activations ppermuted between stages
-            # (parallel/pipeline.py).
-            from runbooks_tpu.parallel.pipeline import pipeline_apply
+    if n_stages > 1:
+        if apool is not None:
+            raise NotImplementedError(
+                "adapter pools are not supported on the pipeline "
+                "(stage > 1) path; serve adapters with tensor/data "
+                "parallelism (docs/multi-tenant-lora.md)")
+        if len(pattern) > 1:
+            raise NotImplementedError(
+                "a layer pattern is not supported on the pipeline "
+                "(stage > 1) path: its stages split one homogeneous "
+                "stack (parallel/pipeline.py)")
+        # Pipeline-parallel path: same block, stacked layers sharded
+        # over the stage axis, activations ppermuted between stages
+        # (parallel/pipeline.py).
+        from runbooks_tpu.parallel.pipeline import pipeline_apply
 
-            def pipe_block(layer, xx, mb_consts):
-                pos, seg, mk, bs = mb_consts
-                y, _, aux, _ = block(cfg, layer, xx, pos, seg, mk, bs, None)
-                return y, aux
+        def pipe_block(layer, xx, mb_consts):
+            pos, seg, mk, bs = mb_consts
+            y, _, aux, _ = block(cfg, layer, xx, pos, seg, mk, bs, None)
+            return y, aux
 
-            with jax.named_scope("layers"):
-                x, aux_total = pipeline_apply(
-                    pipe_block, params["layers"], x,
-                    (positions, segment_ids, mask, bias),
-                    mesh=mesh, n_stages=n_stages,
-                    n_microbatches=cfg.pipeline_microbatches or None)
-        else:
-            with jax.named_scope("layers"):
-                (x, aux_total, *_), moe_counts = jax.lax.scan(
-                    scan_body, (x, aux_total, None, None, None),
-                    (layers, apool, lin_layers, win_layers, conv_layers,
-                     None))
-        new_cache = None
+        with jax.named_scope("layers"):
+            x, aux_total = pipeline_apply(
+                pipe_block, params["layers"], x,
+                (positions, segment_ids, mask, bias),
+                mesh=mesh, n_stages=n_stages,
+                n_microbatches=cfg.pipeline_microbatches or None)
+    else:
+        # The adapter pool (leading L axis) rides the scan as xs when
+        # given, and with a cache the period's number.
+        xs = (scanned, apool, None if cache is None
+              else jnp.arange(cfg.num_periods, dtype=jnp.int32))
+        # `layers`: the scan itself (slices of the stacked weights, what
+        # the compiler hoists out of the loop); each layer is a `block`.
+        with jax.named_scope("layers"):
+            (x, aux_total, leaves), moe_counts = jax.lax.scan(
+                scan_body, (x, aux_total, leaves), xs)
+    new_cache = None if cache is None else dataclasses.replace(
+        cache, index=cache.index if scatter_mode else cache.index + s,
+        **leaves)
 
     with jax.named_scope("head"):
         x = _norm(cfg, params["final_norm"], x)
@@ -2111,85 +2118,80 @@ def _expert_mesh() -> bool:
     return mesh is not None and int(mesh.shape.get("expert", 1)) > 1
 
 
-def _check_latent_support(cfg: ModelConfig, adapters):
-    """What a model with latent-attention layers cannot do yet, by name
-    (docs/sparse-latent-models.md)."""
-    if adapters is not None:
-        raise NotImplementedError(
-            "adapter pools target the wq / wk / wv / wo of per-head "
-            "attention; latent attention has no pooled path")
+# What forward cannot do yet for a model that keeps a group of cache leaves
+# (LeafTraits.group) beside K/V, by name: {group: (its layers as a message
+# names them, why no adapter pool, ((mesh axis, why not), ...))}
+# (docs/hybrid-models.md, docs/sparse-latent-models.md,
+# docs/window-full-models.md; ROADMAP.md M5 / M7).
+_UNSUPPORTED = {
+    "recurrent_state": (
+        "recurrent",
+        "adapter pools target the attention projections of a "
+        "homogeneous stack; a layer pattern has no pooled path "
+        "(docs/hybrid-models.md)", ()),
+    "latent_cache": (
+        "latent-attention",
+        "adapter pools target the wq / wk / wv / wo of per-head "
+        "attention; latent attention has no pooled path",
+        (("tensor", "the latent cache has no head axis to shard, and the "
+                    "absorbed decode's head split is not written"),
+         ("sequence", "ring attention takes no softmax scale or value "
+                      "width of the caller's"),
+         ("stage", "the pipeline's stages split one homogeneous stack, "
+                   "and leading layers are not part of it"))),
+    "kv_ring": (
+        "sliding-attention",
+        "adapter pools target the attention projections of a "
+        "homogeneous stack; window layers have stacks of their own and "
+        "no pooled path",
+        (("tensor", "the flash forward with a window or a sink is not "
+                    "launched per shard, and the ring leaves' layout by "
+                    "KV head is not held by a test"),
+         ("sequence", "ring attention knows no window and no sink"),
+         ("stage", "the pipeline's stages split one homogeneous stack"))),
+}
+
+
+def _check_support(cfg: ModelConfig, segment_ids, adapters):
+    """Refuse what _UNSUPPORTED names, for each group of leaves the
+    configuration keeps, in KVCache's field order."""
     from runbooks_tpu.parallel.sharding import _current_mesh
 
     mesh = _current_mesh()
-    if mesh is None:
-        return
-    for axis, why in (
-            ("tensor", "the latent cache has no head axis to shard, and the "
-                       "absorbed decode's head split is not written"),
-            ("sequence", "ring attention takes no softmax scale or value "
-                         "width of the caller's"),
-            ("stage", "the pipeline's stages split one homogeneous stack, "
-                      "and leading layers are not part of it")):
-        if int(mesh.shape.get(axis, 1)) > 1:
+
+    def size(axis):
+        return int(mesh.shape.get(axis, 1)) if mesh is not None else 1
+
+    for group in dict.fromkeys(leaf.group for leaf in cache_leaves(cfg)):
+        if group not in _UNSUPPORTED:
+            continue
+        layers, no_adapters, no_axes = _UNSUPPORTED[group]
+        if group == "recurrent_state" and segment_ids is not None:
+            if cfg.has_short_conv:
+                raise NotImplementedError(
+                    "packed sequences (segment_ids) with short-convolution "
+                    "layers need the tail reset at document boundaries; "
+                    "that is not written (ops/gated_delta.causal_conv, "
+                    "docs/hybrid-models.md)")
             raise NotImplementedError(
-                f"a {axis} mesh axis > 1 is not supported with "
-                f"latent-attention layers: {why}")
-
-
-def _check_window_support(cfg: ModelConfig, adapters):
-    """What a model with sliding-attention layers cannot do yet, by name
-    (docs/window-full-models.md)."""
-    if adapters is not None:
-        raise NotImplementedError(
-            "adapter pools target the attention projections of a "
-            "homogeneous stack; window layers have stacks of their own and "
-            "no pooled path")
-    from runbooks_tpu.parallel.sharding import _current_mesh
-
-    mesh = _current_mesh()
-    if mesh is None:
-        return
-    for axis, why in (
-            ("tensor", "the flash forward with a window or a sink is not "
-                       "launched per shard, and the ring leaves' layout by "
-                       "KV head is not held by a test"),
-            ("sequence", "ring attention knows no window and no sink"),
-            ("stage", "the pipeline's stages split one homogeneous stack")):
-        if int(mesh.shape.get(axis, 1)) > 1:
+                "packed sequences (segment_ids) with linear-attention "
+                "layers need the recurrent state reset at document "
+                "boundaries, and training on them the chunked scan's "
+                "backward; neither is written (ops/gated_delta.py, "
+                "ROADMAP.md M7)")
+        if adapters is not None:
+            raise NotImplementedError(no_adapters)
+        for axis, why in no_axes:
+            if size(axis) > 1:
+                raise NotImplementedError(
+                    f"a {axis} mesh axis > 1 is not supported with "
+                    f"{layers} layers: {why}")
+        if group == "recurrent_state" and cfg.linear_num_heads % size(
+                "tensor"):
             raise NotImplementedError(
-                f"a {axis} mesh axis > 1 is not supported with "
-                f"sliding-attention layers: {why}")
-
-
-def _check_recurrent_support(cfg: ModelConfig, segment_ids, adapters):
-    """What a model with linear-attention or short-convolution layers
-    cannot do yet, by name (ROADMAP.md M5 / M7)."""
-    if segment_ids is not None:
-        if cfg.has_short_conv:
-            raise NotImplementedError(
-                "packed sequences (segment_ids) with short-convolution "
-                "layers need the tail reset at document boundaries; that "
-                "is not written (ops/gated_delta.causal_conv, "
-                "docs/hybrid-models.md)")
-        raise NotImplementedError(
-            "packed sequences (segment_ids) with linear-attention layers "
-            "need the recurrent state reset at document boundaries, and "
-            "training on them the chunked scan's backward; neither is "
-            "written (ops/gated_delta.py, ROADMAP.md M7)")
-    if adapters is not None:
-        raise NotImplementedError(
-            "adapter pools target the attention projections of a "
-            "homogeneous stack; a layer pattern has no pooled path "
-            "(docs/hybrid-models.md)")
-    from runbooks_tpu.parallel.sharding import _current_mesh
-
-    mesh = _current_mesh()
-    tensor = int(mesh.shape.get("tensor", 1)) if mesh is not None else 1
-    if cfg.linear_num_heads % tensor:
-        raise NotImplementedError(
-            f"a tensor mesh of {tensor} does not divide the "
-            f"{cfg.linear_num_heads} linear-attention heads: the recurrent "
-            "state shards by head (docs/hybrid-models.md)")
+                f"a tensor mesh of {size('tensor')} does not divide the "
+                f"{cfg.linear_num_heads} linear-attention heads: the "
+                "recurrent state shards by head (docs/hybrid-models.md)")
 
 
 def loss_and_grads_1f1b(

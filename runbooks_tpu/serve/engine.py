@@ -66,7 +66,9 @@ from runbooks_tpu.api.serve_params import QOS_CLASSES, ServeOptions
 from runbooks_tpu.models.config import ModelConfig
 from runbooks_tpu.models.moe import chunk_window, gmm_tilings, rows_moved
 from runbooks_tpu.models.transformer import (
+    LEAF_TRAITS,
     KVCache,
+    cache_leaves,
     forward,
     project_logits,
     flash_blocks,
@@ -356,10 +358,10 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # and a bucket's padded tail costs no kv block
         # (models/transformer._cached_attention).
         #
-        # Recurrent state and conv tail (linear-attention layers; pool
-        # leaves `state` / `conv`; short-convolution layers keep the
-        # `conv` leaf alone). They have no slot axis, so no trash
-        # slot, and every token that reaches them changes the answer.
+        # Recurrent state and conv tail (linear-attention layers;
+        # short-convolution layers keep the tail alone; KVCache). They have
+        # no slot axis, so no trash slot, and every token that reaches them
+        # changes the answer.
         # The programs therefore keep four rules themselves:
         #  (a) reset: every scratch row starts from ZERO state and tail,
         #      and both are spliced into the pool at the slot with the
@@ -375,8 +377,8 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # sound for such a layer: InferenceEngine refuses speculation,
         # prefix registration, adapter pools and paging for these models.
         #
-        # Ring leaves (sliding-attention layers; pool leaves `ring_k` /
-        # `ring_v`, KVCache). A window layer attends this call's own keys
+        # Ring leaves (sliding-attention layers; KVCache). A window layer
+        # attends this call's own keys
         # (the prompt is prefilled whole), writes the row's last `ring`
         # real tokens at position mod ring and drops the rest — padding
         # too: a ring has no trash slot. The scratch row's ring is spliced
@@ -425,61 +427,37 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
             acts, last_pos[:, None, None], axis=1)[:, 0]
         last_logits = project_logits(cfg, params, last_acts)
         with jax.named_scope("kv_splice"):
-            if pool.k.dtype == jnp.int8:
+            leaves = cache_leaves(cfg, pool.quantized)
+            scratch = {leaf.name: getattr(cache1, leaf.name)
+                       for leaf in leaves}
+            if pool.quantized:
                 from runbooks_tpu.ops.quantization import quantize_kv
 
-                rows_k, rows_ks = quantize_kv(cache1.k)
-                rows_v, rows_vs = quantize_kv(cache1.v)
-            else:
-                rows_k, rows_v, rows_ks, rows_vs = (cache1.k, cache1.v,
-                                                    None, None)
-            new_k, new_v = pool.k, pool.v
-            new_ks, new_vs = pool.k_scale, pool.v_scale
-            for r in range(rows - 1, -1, -1):
-                new_k = jax.lax.dynamic_update_slice_in_dim(
-                    new_k, rows_k[:, r:r + 1], slots[r], axis=1)
-                new_v = jax.lax.dynamic_update_slice_in_dim(
-                    new_v, rows_v[:, r:r + 1], slots[r], axis=1)
-                if rows_ks is not None:
-                    new_ks = jax.lax.dynamic_update_slice_in_dim(
-                        new_ks, rows_ks[:, r:r + 1], slots[r], axis=1)
-                    new_vs = jax.lax.dynamic_update_slice_in_dim(
-                        new_vs, rows_vs[:, r:r + 1], slots[r], axis=1)
-            new_latent = pool.latent
-            if new_latent is not None:
+                scratch["k"], scratch["k_scale"] = quantize_kv(cache1.k)
+                scratch["v"], scratch["v_scale"] = quantize_kv(cache1.v)
+
+            def splice(leaf):
+                """A leaf's scratch rows into the pool's, at their slots."""
+                new = getattr(pool, leaf.name)
                 for r in range(rows - 1, -1, -1):
-                    new_latent = jax.lax.dynamic_update_slice_in_dim(
-                        new_latent, cache1.latent[:, r:r + 1], slots[r],
+                    new = jax.lax.dynamic_update_slice_in_dim(
+                        new, scratch[leaf.name][:, r:r + 1], slots[r],
                         axis=1)
-            new_ring = [pool.ring_k, pool.ring_v]
-            if pool.ring_k is not None:
-                for i, rows_ring in enumerate((cache1.ring_k,
-                                               cache1.ring_v)):
-                    for r in range(rows - 1, -1, -1):
-                        new_ring[i] = jax.lax.dynamic_update_slice_in_dim(
-                            new_ring[i], rows_ring[:, r:r + 1], slots[r],
-                            axis=1)
-            new_state, new_conv = pool.state, pool.conv
-            if new_conv is not None:
-                with jax.named_scope("state_splice"):    # rule (a)
-                    for r in range(rows - 1, -1, -1):
-                        if new_state is not None:
-                            new_state = jax.lax.dynamic_update_slice_in_dim(
-                                new_state, cache1.state[:, r:r + 1],
-                                slots[r], axis=1)
-                        new_conv = jax.lax.dynamic_update_slice_in_dim(
-                            new_conv, cache1.conv[:, r:r + 1], slots[r],
-                            axis=1)
+                return new
+
+            recurrent = [leaf for leaf in leaves
+                         if leaf.group == "recurrent_state"]
+            spliced = {leaf.name: splice(leaf) for leaf in leaves
+                       if leaf not in recurrent}
+            with jax.named_scope("state_splice"):    # rule (a)
+                spliced.update((leaf.name, splice(leaf))
+                               for leaf in recurrent)
         rng, sub = jax.random.split(rng)
         first = sample(last_logits, sub, temps, top_ks, top_ps,
                        gmask=gmask)
-        new_pool = KVCache(k=new_k, v=new_v, index=pool.index,
-                           k_scale=new_ks, v_scale=new_vs,
-                           state=new_state, conv=new_conv,
-                           latent=new_latent, ring_k=new_ring[0],
-                           ring_v=new_ring[1])
         # A sparse model's programs return one thing more: (counts, hits).
-        return (first, new_pool, rng, *map(dispatch_stats, moe))
+        return (first, dataclasses.replace(pool, **spliced), rng,
+                *map(dispatch_stats, moe))
 
     return prefill_fn
 
@@ -728,6 +706,75 @@ class WarmupRun:
         }
 
 
+# What the engine refuses for a model that keeps a group of per-slot state
+# beside K/V (models/transformer.LeafTraits.group: the recurrent state and
+# tails of docs/hybrid-models.md, the latent of docs/sparse-latent-models.md,
+# the rings of docs/window-full-models.md), each by its mechanism:
+# {(feature, group): why}. The recurrent-state invariant (make_prefill_fn)
+# rules its features out; the rest is only sound, or only written, for keys
+# and values a head.
+_PREFIX = ("prefix registration (register_prefix, auto_prefix_chat, "
+           "warm_prefix)")
+_REFUSED = {
+    ("speculative decoding", "recurrent_state"):
+        "a rejected draft is rolled back by not advancing the K/V cursor, "
+        "and a recurrent state has no cursor to hold back",
+    ("speculative decoding", "latent_cache"):
+        "the verify forward's [slots, K+1] queries take neither of the two "
+        "attention paths a test holds (absorbed at one query a row, expanded "
+        "over a prefill bucket)",
+    ("speculative decoding", "kv_ring"):
+        "the verify forward writes K + 1 tokens a row before its first query "
+        "reads; the ring's margin would hold them, but no test holds the "
+        "[slots, K+1] path or its rollback through a ring",
+    ("an adapter pool", "recurrent_state"):
+        "pooled LoRA lanes target the attention projections of a homogeneous "
+        "stack",
+    ("an adapter pool", "latent_cache"):
+        "pooled LoRA lanes target the wq / wk / wv / wo of per-head "
+        "attention",
+    ("an adapter pool", "kv_ring"):
+        "pooled LoRA lanes target the attention projections of one "
+        "homogeneous stack; window layers have stacks of their own",
+    ("kv_paging: paged", "recurrent_state"):
+        "a page table has one kind of page and the radix tree shares K/V "
+        "pages only; the state after a shared prefix would have to be "
+        "snapshotted with them",
+    ("kv_paging: paged", "latent_cache"):
+        "pages are [page, kv_heads, head_dim] and shard over KV heads; a "
+        "latent page has no head axis",
+    ("kv_paging: paged", "kv_ring"):
+        "a page table maps a row's positions to pages of one kind and one KV "
+        "head count; a ring is a row's own, a window long, and has another "
+        "head count",
+    ("quantize_kv", "latent_cache"):
+        "the int8 pool keeps one scale a KV head, and a latent has no head "
+        "axis",
+    ("quantize_kv", "kv_ring"):
+        "the ring leaves have no int8 form (no scales beside them)",
+    ("a tensor mesh axis > 1", "latent_cache"):
+        "the latent cache has no head axis to shard, and the absorbed "
+        "decode's head split is not written",
+    ("a tensor mesh axis > 1", "kv_ring"):
+        "the flash forward with a window or a sink is not launched per "
+        "shard, and the ring leaves' layout by KV head is not held by a test",
+    (_PREFIX, "recurrent_state"):
+        "a shared prefix splices K/V only; the recurrent state after the "
+        "prefix would have to be stored and restored with it",
+    (_PREFIX, "latent_cache"):
+        "a shared prefix is stored and spliced as K/V a head; the latent "
+        "rows have no such path",
+    (_PREFIX, "kv_ring"):
+        "a window layer prefills a prompt whole against its own keys; a "
+        "spliced prefix would lie under them, and its ring is not stored",
+}
+# The layers a refusal names, by the kind whose leaf it is.
+_REFUSED_LAYERS = {"linear_attention": "recurrent (linear-attention)",
+                   "conv": "recurrent (short-convolution)",
+                   "latent_attention": "latent (MLA) attention",
+                   "sliding_attention": "sliding (window) attention"}
+
+
 class InferenceEngine:
     """Batched generation over a fixed slot pool. Thread-unsafe by design;
     drive it from one loop (the API server wraps it in a single worker)."""
@@ -735,16 +782,6 @@ class InferenceEngine:
     # The ServeOptions.kv_paging this class serves (serve/paging.py:
     # "paged"); create_server picks the class by it.
     kv_paging = "off"
-    # Per-slot recurrent state (a layer pattern with linear-attention
-    # layers) lives beside the dense slot pool's K/V rows; a page table
-    # has one kind of page (serve/paging.py flips this).
-    _supports_recurrent_state = True
-    # A latent cache (MLA) has no head axis: pages are laid out and
-    # sharded by KV head (serve/paging.py flips this too).
-    _supports_latent_cache = True
-    # A window layer's ring is a row's own and a ring long: a page table
-    # maps positions to pages of one kind (serve/paging.py flips this).
-    _supports_window_ring = True
 
     def __init__(self, cfg: ModelConfig, params: Params, *, seed: int = 0,
                  mesh=None, tokenizer=None, **options):
@@ -797,83 +834,17 @@ class InferenceEngine:
         self.quantize_kv = (options.quantize_kv
                             if options.quantize_kv is not None
                             else cfg.quantize != "none")
-        # What the recurrent-state invariant (make_prefill_fn) rules out,
-        # refused here with the reason: nothing below would fail loudly.
-        if options.speculative != "off":
-            self._refuse_recurrent(
-                "speculative decoding",
-                "a rejected draft is rolled back by not advancing the K/V "
-                "cursor, and a recurrent state has no cursor to hold back")
-        if options.adapter_pool > 0:
-            self._refuse_recurrent(
-                "an adapter pool",
-                "pooled LoRA lanes target the attention projections of a "
-                "homogeneous stack")
-        if not self._supports_recurrent_state:
-            self._refuse_recurrent(
-                "kv_paging: paged",
-                "a page table has one kind of page and the radix tree "
-                "shares K/V pages only; the state after a shared prefix "
-                "would have to be snapshotted with them")
-        # What is only sound, or only written, for keys and values a head
-        # (docs/sparse-latent-models.md): each refused by its mechanism.
-        if options.speculative != "off":
-            self._refuse_latent(
-                "speculative decoding",
-                "the verify forward's [slots, K+1] queries take neither of "
-                "the two attention paths a test holds (absorbed at one "
-                "query a row, expanded over a prefill bucket)")
-        if options.adapter_pool > 0:
-            self._refuse_latent(
-                "an adapter pool",
-                "pooled LoRA lanes target the wq / wk / wv / wo of "
-                "per-head attention")
-        if not self._supports_latent_cache:
-            self._refuse_latent(
-                "kv_paging: paged",
-                "pages are [page, kv_heads, head_dim] and shard over KV "
-                "heads; a latent page has no head axis")
-        if self.quantize_kv:
-            self._refuse_latent(
-                "quantize_kv",
-                "the int8 pool keeps one scale a KV head, and a latent has "
-                "no head axis")
-        if mesh is not None and int(mesh.shape.get("tensor", 1)) > 1:
-            self._refuse_latent(
-                "a tensor mesh axis > 1",
-                "the latent cache has no head axis to shard, and the "
-                "absorbed decode's head split is not written")
-        # What is not made sound for a ring leaf or two attention kinds
-        # (docs/window-full-models.md).
-        if options.speculative != "off":
-            self._refuse_window(
-                "speculative decoding",
-                "the verify forward writes K + 1 tokens a row before its "
-                "first query reads; the ring's margin would hold them, but "
-                "no test holds the [slots, K+1] path or its rollback "
-                "through a ring")
-        if options.adapter_pool > 0:
-            self._refuse_window(
-                "an adapter pool",
-                "pooled LoRA lanes target the attention projections of "
-                "one homogeneous stack; window layers have stacks of their "
-                "own")
-        if not self._supports_window_ring:
-            self._refuse_window(
-                "kv_paging: paged",
-                "a page table maps a row's positions to pages of one kind "
-                "and one KV head count; a ring is a row's own, a window "
-                "long, and has another head count")
-        if self.quantize_kv:
-            self._refuse_window(
-                "quantize_kv",
-                "the ring leaves have no int8 form (no scales beside them)")
-        if mesh is not None and int(mesh.shape.get("tensor", 1)) > 1:
-            self._refuse_window(
-                "a tensor mesh axis > 1",
-                "the flash forward with a window or a sink is not launched "
-                "per shard, and the ring leaves' layout by KV head is not "
-                "held by a test")
+        # What a kind of per-slot state rules out (_REFUSED), refused here
+        # with the reason: nothing below would fail loudly.
+        for feature, asked in (
+                ("speculative decoding", options.speculative != "off"),
+                ("an adapter pool", options.adapter_pool > 0),
+                ("kv_paging: paged", self.kv_paging == "paged"),
+                ("quantize_kv", self.quantize_kv),
+                ("a tensor mesh axis > 1", mesh is not None
+                 and int(mesh.shape.get("tensor", 1)) > 1)):
+            if asked:
+                self._refuse(feature)
         if mesh is not None:
             import contextlib
 
@@ -891,12 +862,7 @@ class InferenceEngine:
                                quantized_logical_axes(
                                    params, param_logical_axes(cfg)), mesh))
 
-            def cache_sharding(shape, logical=None):
-                # k/v are 5-d [L, batch, slot, kv_heads, d]; the int8
-                # cache's scale arrays are 4-d [L, batch, slot, kv_heads].
-                if logical is None:
-                    logical = (None, "batch", None, "act_heads",
-                               None)[:len(shape)]
+            def cache_sharding(shape, logical):
                 spec = spec_for_array(shape, logical, mesh)
                 return NamedSharding(mesh, spec)
 
@@ -1291,57 +1257,28 @@ class InferenceEngine:
             # Committed off a mesh too (_home).
             return jax.device_put(cache, self._home())
 
-        def put(a, logical=None):
-            return (None if a is None else jax.device_put(
-                a, self._cache_sharding(a.shape, logical)))
+        def put(a, logical):
+            return jax.device_put(a, self._cache_sharding(a.shape, logical))
 
         # index is committed too (the scalar's spec resolves to
         # replicated): a dispatch RETURNS it committed, so a fresh
         # uncommitted one would key a second jit entry and the first
         # prefill after every reset() would recompile under traffic.
-        # The recurrent state [L, batch, heads, d_k, d_v] shards by
-        # head; the conv tail (q | k | v channels side by side) does
-        # not split on a head boundary and stays whole.
-        cache = KVCache(k=put(cache.k), v=put(cache.v),
-                        index=put(cache.index),
-                        k_scale=put(cache.k_scale),
-                        v_scale=put(cache.v_scale),
-                        state=put(cache.state, (None, "batch",
-                                                "act_heads", None, None)),
-                        conv=put(cache.conv,
-                                 (None, "batch", None, None)),
-                        latent=put(cache.latent,
-                                   (None, "batch", None, None)),
-                        ring_k=put(cache.ring_k), ring_v=put(cache.ring_v))
-        return cache
+        # Each leaf shards by its declared axes (LEAF_TRAITS).
+        return dataclasses.replace(
+            cache, index=put(cache.index, ()),
+            **{leaf.name: put(getattr(cache, leaf.name), leaf.axes)
+               for leaf in cache_leaves(self.cfg, self.quantize_kv)})
 
-    def _refuse_window(self, feature: str, why: str) -> None:
-        """Refuse, for a model with sliding-attention layers, a feature
-        that is not sound for a ring cache or for two kinds of attention
-        layer (docs/window-full-models.md)."""
-        if self.cfg.has_window:
-            raise ValueError(
-                f"{feature} is not supported for a model with sliding "
-                f"(window) attention layers: {why}")
-
-    def _refuse_latent(self, feature: str, why: str) -> None:
-        """Refuse, for a model with latent-attention layers, a feature
-        that is written for keys and values a head."""
-        if self.cfg.latent_cache:
-            raise ValueError(
-                f"{feature} is not supported for a model with latent "
-                f"(MLA) attention layers: {why}")
-
-    def _refuse_recurrent(self, feature: str, why: str) -> None:
-        """Refuse, for a model with recurrent (linear-attention or
-        short-convolution) layers, a feature that is only sound for keys
-        and values (docs/hybrid-models.md)."""
-        if self.cfg.has_recurrent_state:
-            kind = ("linear-attention" if self.cfg.has_linear_attention
-                    else "short-convolution")
-            raise ValueError(
-                f"{feature} is not supported for a model with recurrent "
-                f"({kind}) layers: {why}")
+    def _refuse(self, feature: str) -> None:
+        """Raise what _REFUSED says of `feature` for the first group of
+        per-slot state the model keeps, in KVCache's field order."""
+        for leaf in cache_leaves(self.cfg):
+            why = _REFUSED.get((feature, leaf.group))
+            if why is not None:
+                raise ValueError(
+                    f"{feature} is not supported for a model with "
+                    f"{_REFUSED_LAYERS[leaf.kind]} layers: {why}")
 
     def _decode_kwargs(self) -> dict:
         """The adapter pool as a decode program takes it: the lane indices
@@ -1647,22 +1584,7 @@ class InferenceEngine:
     # -- shared-prefix cache -------------------------------------------
 
     def _refuse_prefix(self) -> None:
-        self._refuse_recurrent(
-            "prefix registration (register_prefix, auto_prefix_chat, "
-            "warm_prefix)",
-            "a shared prefix splices K/V only; the recurrent state after "
-            "the prefix would have to be stored and restored with it")
-        self._refuse_latent(
-            "prefix registration (register_prefix, auto_prefix_chat, "
-            "warm_prefix)",
-            "a shared prefix is stored and spliced as K/V a head; the "
-            "latent rows have no such path")
-        self._refuse_window(
-            "prefix registration (register_prefix, auto_prefix_chat, "
-            "warm_prefix)",
-            "a window layer prefills a prompt whole against its own keys; "
-            "a spliced prefix would lie under them, and its ring is not "
-            "stored")
+        self._refuse(_PREFIX)
 
     def _prefix_len_for(self, n: int, quantize: bool = False) -> int:
         """Usable prefix length for an n-token prompt. Explicit
@@ -1958,33 +1880,30 @@ class InferenceEngine:
         # Aggregate vs per-device bytes: nbytes is the LOGICAL pool size;
         # under a serving mesh each chip holds only its kv-head shard
         # (shard_local_nbytes reads the sharding metadata, no sync).
-        rings = [a for a in (self.cache.ring_k, self.cache.ring_v)
-                 if a is not None]
-        arrays = [a for a in (self.cache.k, self.cache.v,
-                              self.cache.k_scale, self.cache.v_scale,
-                              self.cache.latent)
-                  if a is not None] + rings
-        # Apart from the K/V pool: the recurrent state and conv tails of
-        # linear-attention layers, or the tails alone of short-convolution
-        # layers, fixed a slot whatever its tokens (0 for a model without
-        # such layers).
-        recurrent = [a for a in (self.cache.state, self.cache.conv)
-                     if a is not None]
+        # The leaves' bytes by their group (models/transformer.LEAF_TRAITS;
+        # 0 for a model without such layers). kv_pool_bytes is every leaf
+        # that grows with a row's tokens or holds them: all but the
+        # recurrent state and tails, fixed a slot whatever its tokens. The
+        # latent (MLA) leaf and the window layers' rings, whose size does
+        # not grow with max_seq_len, are the parts of it named beside it.
+        by_group = dict.fromkeys(("kv_pool", "recurrent_state",
+                                  "latent_cache", "kv_ring"), 0)
+        per_device = 0
+        for leaf in cache_leaves(self.cfg, self.quantize_kv):
+            array = getattr(self.cache, leaf.name)
+            by_group[leaf.group] += int(array.nbytes)
+            if leaf.group != "recurrent_state":
+                per_device += obs_device.shard_local_nbytes(array)
         return {"slots_total": self.max_slots,
                 "slots_active": int(self.active.sum()),
                 "kv_tokens": tokens,
                 "kv_capacity_tokens": capacity,
-                "kv_pool_bytes": sum(int(a.nbytes) for a in arrays),
-                "kv_pool_bytes_per_device":
-                    sum(obs_device.shard_local_nbytes(a) for a in arrays),
-                "recurrent_state_bytes":
-                    sum(int(a.nbytes) for a in recurrent),
-                # The part of kv_pool_bytes that is a latent (MLA) leaf.
-                "latent_cache_bytes": (0 if self.cache.latent is None
-                                       else int(self.cache.latent.nbytes)),
-                # ... and the part that is window layers' rings, whose size
-                # does not grow with max_seq_len.
-                "kv_ring_bytes": sum(int(a.nbytes) for a in rings),
+                "kv_pool_bytes": (sum(by_group.values())
+                                  - by_group["recurrent_state"]),
+                "kv_pool_bytes_per_device": per_device,
+                "recurrent_state_bytes": by_group["recurrent_state"],
+                "latent_cache_bytes": by_group["latent_cache"],
+                "kv_ring_bytes": by_group["kv_ring"],
                 "occupancy_ratio": (tokens / capacity) if capacity else 0.0}
 
     def memory_groups(self) -> dict:
@@ -1997,15 +1916,16 @@ class InferenceEngine:
         groups = {"weights": self.params,
                   "kv_cache": self.cache,
                   "prefix_cache": list(self._prefix_cache.copy().values())}
-        if self.cfg.has_recurrent_state:
-            # K/V and the recurrent state are reported apart.
-            groups["kv_cache"] = dataclasses.replace(
-                self.cache, state=None, conv=None)
-            groups["recurrent_state"] = (self.cache.state, self.cache.conv)
-        if self.cfg.latent_cache:
-            groups["kv_cache"] = dataclasses.replace(
-                groups["kv_cache"], latent=None)
-            groups["latent_cache"] = self.cache.latent
+        # K/V, the recurrent state and a latent are reported apart: a
+        # group's fields as they are (None where the model has no such
+        # leaf), the one field of a group of one by itself.
+        for group in ("recurrent_state", "latent_cache"):
+            names = [n for n, t in LEAF_TRAITS.items() if t.group == group]
+            arrays = tuple(getattr(self.cache, n, None) for n in names)
+            if any(a is not None for a in arrays):
+                groups[group] = arrays if len(arrays) > 1 else arrays[0]
+                groups["kv_cache"] = dataclasses.replace(
+                    groups["kv_cache"], **dict.fromkeys(names))
         if self.adapters is not None:
             groups["adapter_pool"] = self.adapters.tree
         return groups

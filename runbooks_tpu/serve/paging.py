@@ -1147,9 +1147,6 @@ class PagedInferenceEngine(InferenceEngine):
     tree for many-user prefix reuse (docs/paged-kv.md)."""
 
     kv_paging = "paged"
-    _supports_recurrent_state = False
-    _supports_latent_cache = False
-    _supports_window_ring = False
 
     def _check_mesh(self, cfg: ModelConfig, mesh) -> None:
         # Precise mesh-geometry validation: each error names the one
