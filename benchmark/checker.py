@@ -11,7 +11,13 @@ TIMED path produced:
          in it): the reference runs once over each prompt with its served
          tokens, and the number compared is the widest gap by which a
          served token's logit lies below the reference's best at that
-         position. Greedy tokens only.
+         position. Greedy tokens only. A sequence may have any length:
+         it is padded to a length bucket (SEQ_BUCKETS, beyond them the
+         next multiple of SEQ_STEP) and the reference is asked for the
+         logits of the served rows alone, in a row bucket of their own
+         (ROW_BUCKETS, then multiples of ROW_STEP), never for
+         [length bucket, vocab]. What holds a check back is the chip's
+         memory and run.py's 600 s for this process, nothing here.
   train  the losses of the first steps of the one compiled step the window
          then drove, the norm of the first gradient as the optimizer got
          it (from its saved state after one step), and the norm of the
@@ -36,7 +42,18 @@ sys.path.insert(0, BENCH_DIR)
 
 from benchlib import spec  # noqa: E402
 
-SEQ_BUCKETS = (256, 512, 1024, 2048, 4096)
+# Lengths a sequence is padded to: these as they stand (the cells' shapes
+# and their compiled references recur), beyond them multiples of SEQ_STEP
+# (10 100 tokens -> 10 240, not 16 384). The rows whose logits are asked
+# for get buckets of their own.
+SEQ_BUCKETS, SEQ_STEP = (256, 512, 1024, 2048, 4096), 2048
+ROW_BUCKETS, ROW_STEP = (32, 64, 128, 256), 256
+
+
+def bucket(n: int, small: tuple, step: int) -> int:
+    """The least of `small` that holds n; beyond them the next multiple of
+    `step`."""
+    return next((b for b in small if b >= n), -(-n // step) * step)
 
 
 def setup_jax(chips: int):
@@ -68,34 +85,49 @@ def setup_jax(chips: int):
     return jax, shard
 
 
+def check_line(n: dict) -> str:
+    return (f"check: {n['name']} {n['value']:.6g} (limit {n['limit']:.6g}) "
+            f"{'ok' if n['ok'] else 'OUT'}")
+
+
 def number(name, value, limit):
-    ok = value <= limit
-    print(f"check: {name} {value:.6g} (limit {limit:.6g}) "
-          f"{'ok' if ok else 'OUT'}", flush=True)
-    return {"name": name, "value": float(value), "limit": float(limit),
-            "ok": bool(ok)}
+    """A number compared: sound at its limit or under. Printed beside it."""
+    n = {"name": name, "value": float(value), "limit": float(limit),
+         "ok": bool(value <= limit)}
+    print(check_line(n), flush=True)
+    return n
 
 
 def serve_gaps(ref, as_run, w, sequences, control=False):
     """For every served token of every sequence: the gap by which its
     logit lies below the reference's best at that position, and whether it
     IS the best. With `control`, also the gap of the token the int8
-    products put first at each of the same positions."""
+    products put first at each of the same positions.
+
+    What a reference's `logits_at(as_run, w, tokens, rows, low)` may rely
+    on, and must do. `tokens` is the prompt with its served tokens, padded
+    with zeros to a length bucket; `rows` are the positions whose logits
+    are compared, `len(prompt) - 1 ... len(tokens) - 2` and then the last
+    repeated to a row bucket, so `rows[0] + 1` is the prompt's length (a
+    model whose mask depends on the length of the call that computed a
+    query needs it). The padding lies behind every compared row, so under
+    a causal mask no compared row attends it. It returns [len(rows),
+    vocab] logits, never [len(tokens), vocab], and computes a long
+    sequence in blocks so that it fits: the checker does not do that for
+    it."""
     import numpy as np
 
     gaps, low_gaps, agree = [], [], []
     for seq in sequences:
         prompt, served = seq["prompt_ids"], seq["served_ids"]
         toks = prompt + served
-        pad = next(b for b in SEQ_BUCKETS if b >= len(toks))
-        padded = np.zeros(pad, np.int32)
+        padded = np.zeros(bucket(len(toks), SEQ_BUCKETS, SEQ_STEP), np.int32)
         padded[:len(toks)] = toks
         # Row p predicts token p + 1: the served tokens are predicted at
-        # rows len(prompt) - 1 ... len(toks) - 2. One row count per
-        # bucket (padding rows repeat the last), so shapes recur.
+        # rows len(prompt) - 1 ... len(toks) - 2.
         rows = np.arange(len(prompt) - 1, len(toks) - 1)
         n = len(rows)
-        rows_p = np.full(pad, rows[-1], np.int32)
+        rows_p = np.full(bucket(n, ROW_BUCKETS, ROW_STEP), rows[-1], np.int32)
         rows_p[:n] = rows
         logits = np.asarray(ref.logits_at(as_run, w, padded, rows_p))[:n]
         best = logits.max(axis=-1)

@@ -1,5 +1,6 @@
-"""Prompt tokens prefilled inside the traced window over the device time of
-the prefill programs in the trace."""
+"""Prompt tokens the `prefill` spans whose `prefill.dispatch` began inside the
+traced window say they carried (`tokens`: after the prefix, no padding) over
+the device time of the `prefill_fn` modules in it: no client clock."""
 
 LAYER = "model step, prefill (engine -> transformer.forward)"
 UNIT = "tokens/s"
@@ -8,11 +9,6 @@ MOVES = "serve_tok_s"
 
 
 def read(ctx):
-    from benchlib import arith
+    from benchlib import syncspans
 
-    trace = ctx.get("trace")
-    prog = (trace or {}).get("programs", {}).get("prefill_fn")
-    reqs = arith.prefilled_in(ctx.get("all_records"), ctx.get("trace_window"))
-    if not prog or not prog["seconds"] or not reqs:
-        return None
-    return sum(r["prompt_tokens"] for r in reqs) / prog["seconds"]
+    return syncspans.prefill_tok_s(ctx)

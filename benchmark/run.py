@@ -12,7 +12,18 @@ for ready, offers a ramp of the cell's own traffic (set-up), measures for
 --seconds, drains, stops the child, and only then has a checker process
 compare what the window served with the plain reference. The last line of
 stdout is the one result object; every other fact is on earlier lines.
+Each number compared stands beside its limit on a `check:` line, as the
+last lines of stderr, and under `checked`, the result's last key.
 No TPU, or fewer chips than the cell asks for: exit non-zero, no line.
+
+`train_tok_s` is taken between the ends of whole steps inside the window.
+`serve_tok_s` is the tokens credited inside the window over its length (a
+prompt at its first token, a generated token at its arrival), so it steps
+by whole prompts; the reading that does not, between the first and the
+last instant at which a prefill dispatch gave its first tokens
+(benchlib/arith.serve_rate), is printed beside the metrics with the count
+of those instants and the largest single credit's share of the tokens
+counted, so every run shows how coarse its count is.
 
 A cell is data: BENCHMARK.json names a configuration and a traffic mix,
 benchmark/configs/<config>.json and benchmark/traffic/<mix>.json hold
@@ -27,6 +38,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import math
 import os
 import shutil
 import sys
@@ -40,6 +52,7 @@ T_PROCESS = time.monotonic()   # process start, as near as Python allows
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, BENCH_DIR)
 
+import checker  # noqa: E402  (its top level imports no JAX)
 from benchlib import arith, client, procs, prom, spec, tokenizer  # noqa: E402
 from benchlib import traffic as traffic_mod  # noqa: E402
 
@@ -114,7 +127,8 @@ def run_checker(root: str, logs: str, job: dict, timeout: float = 600):
                     if "correct" in o), None)
     if rc != 0 or verdict is None:
         say(f"checker failed rc={rc}:\n{child.tail()}")
-        return {"correct": False, "numbers": []}
+        return {"correct": False, "numbers": [
+            checker.number("checker_gave_no_verdict", 1, 0)]}
     return verdict
 
 
@@ -146,11 +160,22 @@ def layer_metrics(cell: spec.Cell, ctx: dict) -> dict:
     return out
 
 
-def result_line(correct, attempted, failed, metrics, device, breakdown=None):
+def result_line(correct, attempted, failed, metrics, device, numbers,
+                breakdown=None):
+    """The run's last lines: every number compared beside its limit on
+    stderr, then the one result object on stdout, `checked` last in it."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    # A number a configuration holds to no limit (`limits_left_out`) has
+    # the limit null: `Infinity` is not JSON.
+    line["checked"] = {n["name"]: {
+        "value": n["value"],
+        "limit": n["limit"] if math.isfinite(n["limit"]) else None}
+        for n in numbers}
+    for n in numbers:
+        print(checker.check_line(n), file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
 
 
@@ -312,11 +337,14 @@ def run_serve(args, root, cell) -> int:
     served_server = int(final.get("serve_tokens_generated_total", -1))
     unexpected = int(final.get("xla_unexpected_compiles_total", 0))
     server_failed = int(final.get("serve_requests_failed_total", 0))
-    say(f"check: tokens client {served_client} server {served_server} "
-        f"(limit: equal) | compiles inside the window {unexpected} "
-        f"(limit 0) | server-side failures {server_failed} (limit 0)")
-    sound = (served_client == served_server and unexpected == 0
-             and server_failed == 0 and rc == 0)
+    say(f"tokens client {served_client} server {served_server}")
+    numbers = [
+        checker.number("tokens_client_server_gap",
+                       abs(served_client - served_server), 0),
+        checker.number("compiles_in_window", unexpected, 0),
+        checker.number("server_failures", server_failed, 0),
+        checker.number("requests_failed", len(failed), 0),
+        checker.number("server_exit_code", abs(rc), 0)]
 
     verdict = run_checker(root, logs, {
         "kind": "serve", "config": cell.config, "seed": model_seed(args.seed),
@@ -324,7 +352,8 @@ def run_serve(args, root, cell) -> int:
         "limits": cell.config.get("limits", {}).get("serve", {}),
         "sequences": sample_for_check(records, args.seed,
                                       int(mix["check_requests"]))})
-    correct = sound and verdict["correct"] and not failed
+    numbers += verdict["numbers"]
+    correct = verdict["correct"] and all(n["ok"] for n in numbers)
 
     trace = None
     if args.trace and state.get("profile", {}).get("path"):
@@ -354,18 +383,27 @@ def run_serve(args, root, cell) -> int:
         metrics = {m["name"]: {"value": e2e[m["name"]](), "unit": m["unit"]}
                    for m in cell.end_to_end}
         breakdown = None
+        try:
+            rate = arith.serve_rate(records, t0, t1)
+        except ValueError:   # fewer than two first-token instants
+            rate = dict.fromkeys(("tok_s", "instants",
+                                  "largest_credit_share"))
         say("beside the metrics: " + json.dumps({
             "ttft_p95_ms": arith.ttft_ms(measured, 95),
             "ttft_p50_ms": arith.ttft_ms(measured, 50),
             "tpot_p50_ms": arith.tpot_ms(measured, 50),
             "tpot_p90_ms": arith.tpot_ms(measured, 90),
             "serve_tok_s": arith.serve_tok_s(records, t0, t1),
+            "serve_tok_s_between_first_tokens": rate["tok_s"],
+            "first_token_instants": rate["instants"],
+            "largest_credit_share": rate["largest_credit_share"],
+            "window": [t0, t1],
             "requests_measured": len(measured)}))
     with open(os.path.join(logs, "records.json"), "w") as f:
         json.dump([{k: v for k, v in r.items()
                     if k not in ("prompt_ids", "ids")} for r in records], f)
     result_line(correct, len(measured), len(failed), metrics, device,
-                breakdown)
+                numbers, breakdown)
     return 0
 
 
@@ -501,9 +539,8 @@ def run_train(args, root, cell) -> int:
     in_window = [o for t, o in steps if t0 < t <= t1]
     n_whole, span = arith.whole_steps(ends, t0, t1)
     nonfinite = [o for _, o in child.json_lines() if o.get("nonfinite")]
-    say(f"check: whole steps in the window {n_whole} over {span:.3f} s | "
-        f"non-finite steps {len(nonfinite)} (limit 0) | exit code {rc} "
-        "(limit 42)")
+    say(f"whole steps in the window {n_whole} over {span:.3f} s | exit "
+        f"code {rc}")
     import numpy as np
     all_rows = list(traffic_mod.pack_rows(docs, int(job["seq_len"])))
     rows = [{k: v.tolist() for k, v in row.items()}
@@ -515,8 +552,11 @@ def run_train(args, root, cell) -> int:
         "rows": rows,
         "losses": [o["loss"] for _, o in steps[:n_check]],
         "checkpoints": kept, "steps": [1, n_check]}, timeout=900)
-    correct = (verdict["correct"] and not nonfinite and rc == 42
-               and n_whole > 0)
+    numbers = [checker.number("nonfinite_steps", len(nonfinite), 0),
+               checker.number("trainer_exit_code_off_42", abs(rc - 42), 0),
+               checker.number("no_whole_step_in_window", n_whole == 0, 0),
+               *verdict["numbers"]]
+    correct = verdict["correct"] and all(n["ok"] for n in numbers)
     trace = None
     if args.trace:
         trace = reduce_trace(root, logs, os.path.join(
@@ -552,7 +592,8 @@ def run_train(args, root, cell) -> int:
             "data_wait_s_mean": sum(o.get("data_wait_s", 0)
                                     for o in in_window)
             / max(len(in_window), 1)}))
-    result_line(correct, n_whole, len(nonfinite), metrics, device, breakdown)
+    result_line(correct, n_whole, len(nonfinite), metrics, device, numbers,
+                breakdown)
     return 0
 
 

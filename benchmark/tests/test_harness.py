@@ -11,7 +11,8 @@ import pytest
 import run
 
 FIX = os.path.join(os.path.dirname(__file__), "fixtures")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "checked"}
 
 
 def fake_tpu(ident, chips, child):
@@ -28,8 +29,8 @@ def drive(capsys, monkeypatch, workload, seconds="2", argv=None):
     rc = run.main(["--workload", workload, "--seed", str(2 ** 31 + 5),
                    "--seconds", seconds, "--trace", "0",
                    "--bench-root", FIX])
-    lines = capsys.readouterr().out.strip().splitlines()
-    return rc, lines
+    out, err = capsys.readouterr()
+    return rc, out.strip().splitlines(), err
 
 
 @pytest.mark.parametrize("workload,metrics", [
@@ -39,7 +40,7 @@ def drive(capsys, monkeypatch, workload, seconds="2", argv=None):
 ])
 def test_result_line_has_exactly_the_contracts_keys(capsys, monkeypatch,
                                                     workload, metrics):
-    rc, lines = drive(capsys, monkeypatch, workload)
+    rc, lines, err = drive(capsys, monkeypatch, workload)
     assert rc == 0
     result = json.loads(lines[-1])
     assert set(result) == RESULT_KEYS
@@ -50,12 +51,18 @@ def test_result_line_has_exactly_the_contracts_keys(capsys, monkeypatch,
                                      "memory_peak_bytes"}
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
-    # Every number compared is printed beside its limit.
+    # Every number compared is printed beside its limit, on stdout as it
+    # is read, as the last lines of stderr, and last in the result.
     assert any(ln.startswith("check:") and "limit" in ln for ln in lines)
+    assert list(result)[-1] == "checked" and len(result["checked"]) >= 5
+    assert all(set(n) == {"value", "limit"}
+               for n in result["checked"].values())
+    last = err.strip().splitlines()[-len(result["checked"]):]
+    assert [ln.split()[1] for ln in last] == list(result["checked"])
 
 
 def test_broken_timed_path_comes_out_not_correct(capsys, monkeypatch):
-    rc, lines = drive(capsys, monkeypatch, "tiny_chat", argv=[
+    rc, lines, err = drive(capsys, monkeypatch, "tiny_chat", argv=[
         sys.executable, os.path.join(FIX, "broken_server.py")])
     assert rc == 0
     result = json.loads(lines[-1])
@@ -66,7 +73,7 @@ def test_broken_timed_path_comes_out_not_correct(capsys, monkeypatch):
 def test_step_that_returns_its_state_unchanged_is_not_correct(capsys,
                                                               monkeypatch):
     monkeypatch.setenv("RBT_DEVICE_OBS", "0")
-    rc, lines = drive(capsys, monkeypatch, "tiny_lora", argv=[
+    rc, lines, err = drive(capsys, monkeypatch, "tiny_lora", argv=[
         sys.executable, os.path.join(FIX, "broken_trainer.py")])
     assert rc == 0
     result = json.loads(lines[-1])
@@ -87,3 +94,22 @@ def test_no_chip_exits_non_zero_and_prints_no_line():
     assert proc.returncode != 0
     assert not any(ln.startswith("{") for ln in proc.stdout.splitlines())
     assert "not a TPU" in proc.stderr
+
+
+def test_a_number_held_to_no_limit_has_the_limit_null_in_the_line(capsys):
+    """`limits_left_out` of a configuration: inf in the checker's verdict,
+    null in the result line, which has to stay plain JSON."""
+    import checker
+
+    run.result_line(True, 3, 0, {}, {}, [
+        checker.number("served_logit_gap_max", 2.58, float("inf")),
+        checker.number("served_logit_gap_mean", 0.126, 0.28)])
+    out, err = capsys.readouterr()
+    last = out.strip().splitlines()[-1]
+    assert "Infinity" not in last
+    assert json.loads(last)["checked"] == {
+        "served_logit_gap_max": {"value": 2.58, "limit": None},
+        "served_logit_gap_mean": {"value": 0.126, "limit": 0.28}}
+    assert err.strip().splitlines()[-2:] == [
+        "check: served_logit_gap_max 2.58 (limit inf) ok",
+        "check: served_logit_gap_mean 0.126 (limit 0.28) ok"]

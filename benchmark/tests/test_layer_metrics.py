@@ -63,6 +63,10 @@ def test_traced_readers_on_the_recorded_trace():
     # One decode step of 15.6 ms against 7.2 GB of weights at 819 GB/s.
     assert 50 < roof < 65
     assert 0 < reader("flash_prefill_roofline").read(ctx) <= 100
+    # The `prefill` spans of the window say they carried 900 tokens.
+    ctx.update(cell="falcon7b_chat", _syncspans={
+        "window_s": 0.3, "prefill": (900, 3),
+        "sync": {"has_children": False}})
     assert reader("prefill_tok_s").read(ctx) == pytest.approx(
         900 / trace["programs"]["prefill_fn"]["seconds"])
     assert arith.prefilled_in(recs, (2.0, 3.0)) == []

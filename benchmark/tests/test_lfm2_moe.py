@@ -222,7 +222,7 @@ def test_new_reader_on_synthetic_capture():
     cell = spec.load_cell(CELL)
     assert {m["name"] for m in cell.per_layer} >= {
         NEW, "attn_device_share.doc", "ffn_device_share.doc",
-        "prefill_tok_s", "prefill_span_tok_s", "warmup_programs",
+        "prefill_tok_s", "warmup_programs",
         "device_idle_share.doc", "moe_device_share.doc",
         "moe_experts_roofline", "moe_load_max_over_mean"}
     # Not the readers of other models' mixers and kernels.

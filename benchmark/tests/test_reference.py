@@ -99,3 +99,109 @@ def test_int8_control_comes_out_not_correct(ref, seed):
     sound, control = np.mean(sound), np.mean(control)
     assert sound <= TOY_LIMIT_GAP_MEAN < control, (sound, control)
     assert control > 3 * sound
+
+
+class StubReference:
+    """Records what serve_gaps asks for; its best token at row p is token
+    p + 1 of the sequence (of `truth`, where the served one was altered),
+    so a sequence served as the reference would reads a gap of nought."""
+    VOCAB = 16
+
+    def __init__(self, truth=None):
+        self.calls, self.truth = [], truth
+
+    def logits_at(self, as_run, w, tokens, rows, low=False):
+        tokens, rows = np.asarray(tokens), np.asarray(rows)
+        self.calls.append({"tokens": tokens, "rows": rows, "low": low})
+        logits = np.zeros((len(rows), self.VOCAB), np.float32)
+        best = tokens if self.truth is None else np.asarray(self.truth)
+        logits[np.arange(len(rows)), best[rows + 1]] = 1.0
+        if low:     # the control puts another token first everywhere
+            logits = np.roll(logits, 1, axis=-1)
+        return logits
+
+
+def sequence(rng, prompt, served):
+    ids = rng.integers(1, StubReference.VOCAB, prompt + served).tolist()
+    return {"prompt_ids": ids[:prompt], "served_ids": ids[prompt:]}
+
+
+@pytest.mark.parametrize("prompt,served,length,n_rows", [
+    (10_000, 100, 10_240, 128),   # beyond the buckets: the next 2048
+    (1_500, 100, 2_048, 128),     # the doc cells' shape, as before
+    (4_000, 96, 4_096, 128),
+    (4_000, 97, 6_144, 128),
+    (16_300, 300, 18_432, 512),   # rows beyond their buckets: the next 256
+    (200, 20, 256, 32),
+    (700, 256, 1_024, 256),       # the chat cell's longest reply
+])
+def test_serve_gaps_pads_lengths_and_rows_to_buckets_of_their_own(
+        prompt, served, length, n_rows):
+    import checker
+
+    stub = StubReference()
+    seq = sequence(np.random.default_rng(prompt), prompt, served)
+    got = checker.serve_gaps(stub, {}, None, [seq], control=True)
+    assert [c["low"] for c in stub.calls] == [False, True]
+    for call in stub.calls:
+        assert call["tokens"].shape == (length,)
+        assert call["tokens"][:prompt + served].tolist() == (
+            seq["prompt_ids"] + seq["served_ids"])
+        assert not call["tokens"][prompt + served:].any()
+        # The served rows, then the last repeated; rows[0] + 1 is the
+        # prompt's length.
+        assert call["rows"].tolist() == (
+            list(range(prompt - 1, prompt + served - 1))
+            + [prompt + served - 2] * (n_rows - served))
+    assert got["gaps"].shape == got["agree"].shape == (served,)
+    assert not got["gaps"].any() and got["agree"].all()
+    assert got["control"].shape == (served,) and (got["control"] == 1).all()
+
+
+def test_serve_gaps_sees_an_altered_token_at_any_length():
+    import checker
+
+    seq = sequence(np.random.default_rng(5), 9_000, 40)
+    stub = StubReference(seq["prompt_ids"] + seq["served_ids"] + [0] * 2048)
+    seq["served_ids"][17] = seq["served_ids"][17] % 15 + 1   # another token
+    got = checker.serve_gaps(stub, {}, None, [seq])
+    assert got["gaps"].tolist() == [0.0] * 17 + [1.0] + [0.0] * 22
+    assert got["agree"].sum() == 39 and not len(got["control"])
+
+
+def test_tiny_fixtures_check_reads_the_numbers_it_read_before(ref):
+    """The same rows, the same logits: asking the reference for a row
+    bucket gives the gaps that asking it for the whole length bucket gave
+    (what the checker did before PR 44), to float32 round-off."""
+    import checker
+
+    as_run = tiny("tiny-falcon")["as_run"]
+    w = ref.init_weights(as_run, 3)
+    rng = np.random.default_rng(11)
+    seqs = []
+    for prompt, served in ((40, 9), (200, 33), (300, 70)):
+        ids = rng.integers(1, 512, prompt + served).tolist()
+        seqs.append({"prompt_ids": ids[:prompt], "served_ids": ids[prompt:]})
+    got = checker.serve_gaps(ref, as_run, w, seqs, control=True)
+    before, before_low = [], []
+    for seq in seqs:
+        toks = seq["prompt_ids"] + seq["served_ids"]
+        pad = next(b for b in checker.SEQ_BUCKETS if b >= len(toks))
+        padded = np.zeros(pad, np.int32)
+        padded[:len(toks)] = toks
+        rows = np.arange(len(seq["prompt_ids"]) - 1, len(toks) - 1)
+        rows_p = np.full(pad, rows[-1], np.int32)
+        rows_p[:len(rows)] = rows
+        logits = np.asarray(ref.logits_at(as_run, w, padded,
+                                          rows_p))[:len(rows)]
+        low = np.asarray(ref.logits_at(as_run, w, padded, rows_p,
+                                       low=True))[:len(rows)]
+        best = logits.max(-1)
+        at = np.arange(len(rows))
+        before.append(best - logits[at, seq["served_ids"]])
+        before_low.append(best - logits[at, low.argmax(-1)])
+    assert got["gaps"].shape == (9 + 33 + 70,) and got["gaps"].max() > 1
+    np.testing.assert_allclose(got["gaps"], np.concatenate(before),
+                               rtol=0, atol=2e-5)
+    np.testing.assert_allclose(got["control"], np.concatenate(before_low),
+                               rtol=0, atol=2e-5)
