@@ -199,7 +199,10 @@ def cache_shapes(cache) -> Dict[Tuple[str, Tuple[int, ...]], int]:
         if sharding is not None:       # the text has one device's shapes
             dims = tuple(sharding.shard_shape(dims))
         layer = math.prod(dims[1:])
-        shapes[(dtype, dims)] = layer - 1 if traits.tokens else layer
+        # (A row's compressed keys are rewritten a layer at a time by a
+        # prefill, an entry at a time by a decode step.)
+        whole_layer = traits.tokens in (None, "compressed")
+        shapes[(dtype, dims)] = layer if whole_layer else layer - 1
         if traits.tokens == "slots":
             shapes[(dtype, dims[1:])] = layer - 1
             shapes[(dtype, (1,) + dims[1:])] = layer - 1

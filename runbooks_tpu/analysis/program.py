@@ -231,6 +231,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
         _buckets,
         auto_prefix_plens,
         bucket_for,
+        dispatch_shapes,
         make_decode_fn,
         make_prefill_fn,
         make_prefix_build_fn,
@@ -247,6 +248,8 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
     buckets = _buckets(max_seq_len)
     views = view_buckets_for(max_seq_len)
     rows_set = (1, slots) if slots > 1 else (1,)
+    # What admission can dispatch at the default budget (the window).
+    n_prefill = len(dispatch_shapes(buckets, max_seq_len, slots))
 
     key = _key_sds()
     params = jax.eval_shape(functools.partial(init_params, cfg), key)
@@ -439,7 +442,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
     specs = [
         {"component": "serve", "name": "prefill", "fn": prefill,
          "args": prefill_args(rows_set[-1], buckets[-1]),
-         "signatures": len(buckets) * len(rows_set)},
+         "signatures": n_prefill},
         {"component": "serve", "name": "prefill_prefix",
          "fn": prefix_splice,
          "args": rest[:2] + rest[-2:] + rest[2:-2],
@@ -469,7 +472,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
          "fn": adapter_prefill,
          "args": ([params, pool, apool, aslots_sds(rows_set[-1])]
                   + prefill_args(rows_set[-1], buckets[-1])[2:]),
-         "signatures": len(buckets) * len(rows_set)},
+         "signatures": n_prefill},
         {"component": "serve", "name": "adapter_decode",
          "fn": adapter_decode,
          "args": [params, pool, apool] + decode_args[2:],
@@ -497,7 +500,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
          "fn": grammar_prefill,
          "args": ([params, pool, gmask_sds(rows_set[-1], vocab)]
                   + prefill_args(rows_set[-1], buckets[-1])[2:]),
-         "signatures": len(buckets) * len(rows_set)},
+         "signatures": n_prefill},
         {"component": "serve", "name": "grammar_decode",
          "fn": grammar_decode,
          "args": ([params, pool, gmask_sds(slots, vocab)]
@@ -561,7 +564,7 @@ def _engine_specs(settings: AuditSettings) -> List[dict]:
             {"component": "serve", "name": "prefill_sharded",
              "fn": prefill_tp, "mesh": mesh,
              "args": prefill_args(rows_set[-1], buckets[-1]),
-             "signatures": len(buckets) * len(rows_set)},
+             "signatures": n_prefill},
             {"component": "serve", "name": "decode_sharded",
              "fn": decode_tp, "mesh": mesh, "args": decode_args,
              "signatures": len(views)},

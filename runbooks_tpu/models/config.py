@@ -79,9 +79,24 @@ class AttnShape(NamedTuple):
     rope_factor: float  # on sin and cos: the rotated part of a score
     #                     carries its square, the part that passes 1
     gate: bool          # sigmoid(u W_g), a number a head, on the core's output
+    #                     (ModelConfig.attn_gate_width: or one an element)
 
 
 MOE_ROUTERS = ("softmax", "sigmoid")
+# The mixers a "linear_attention" layer may be (ModelConfig.linear_mixer).
+LINEAR_MIXERS = ("gated_delta", "lightning")
+
+
+class SparseRead(NamedTuple):
+    """The sizes of a full-attention layer's sparse read
+    (ops/block_sparse_attention.py), in keys but for topk and init."""
+    block: int          # keys a block
+    topk: int           # blocks a query reads of a KV head, init among them
+    window: int         # keys before and with the query, always read
+    init: int           # leading blocks, always read
+    kernel: int         # keys a compressed key is the mean of
+    stride: int         # keys between the starts of two compressed keys
+    dense_len: int      # a row shorter than this is read whole
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,6 +218,28 @@ class ModelConfig:
     # [hidden, query heads of the kind] on the layer's normed input u, one
     # number a head and token, times the core's output before wo.
     attn_gate: bool = False
+    # "head": that. "element": W_g [hidden, query heads x value_head_dim],
+    # one number an element of the core's output.
+    attn_gate_width: str = "head"
+    # Sparse read of the full_attention layers (InfLLM-v2;
+    # ops/block_sparse_attention.py, docs/hybrid-models.md). 0 = every
+    # layer reads every key its mask allows. With sparse_topk > 0 a query
+    # of a row at least sparse_dense_len long reads, of each KV head, its
+    # sparse_window last keys, the sparse_init_blocks first blocks of
+    # sparse_block keys and the blocks that score highest against the
+    # compressed keys (means of sparse_kernel keys, sparse_stride apart),
+    # sparse_topk blocks with the initial ones. A row keeps the compressed
+    # keys beside k and v (KVCache.ckeys).
+    sparse_block: int = 0
+    sparse_topk: int = 0
+    sparse_window: int = 0
+    sparse_init_blocks: int = 0
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_dense_len: int = 0
+    # Blocks that lie wholly inside the window are no candidates for the
+    # choice (False: they are, and a chosen one is read twice over).
+    sparse_exclude_window: bool = True
 
     # Block structure
     parallel_block: bool = False      # falcon/gpt-neox parallel attn+mlp
@@ -217,6 +254,20 @@ class ModelConfig:
     # the gated delta rule (ops/gated_delta.py): a fixed-size recurrent
     # state a head instead of keys and values a token.
     layer_types: tuple = ()
+    # What a "linear_attention" layer is. "gated_delta": the gated delta
+    # rule behind a short convolution (a state and a conv tail a row).
+    # "lightning": the decay-only recurrence S_t = lambda_h S_{t-1} + k_t
+    # v_t^T (ops/lightning_attention.py): a QK norm a head, no convolution
+    # (linear_conv_kernel 0), an output norm over all the heads and an
+    # elementwise sigmoid gate; a row keeps the state alone.
+    linear_mixer: str = "gated_delta"
+    # Lightning's decay lambda_h = exp(-2^(-8 (h + 1) / H) c_l), c_l = 1 -
+    # l / lightning_decay_layers + 1e-5 with l the layer's index as run
+    # (0 = no layer factor, c_l = 1).
+    lightning_decay_layers: int = 0
+    # > 0: the linear layers rotate q and k over the whole head at this
+    # base, whatever position_type says of the layers with keys.
+    linear_rope_theta: float = 0.0
     linear_num_heads: int = 0         # key heads = value heads
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
@@ -233,6 +284,12 @@ class ModelConfig:
     # Embeddings / head
     tie_embeddings: bool = False
     embed_scale: bool = False         # multiply embeddings by sqrt(hidden)
+    # MiniCPM's scalings (0 / 1 = none): the embeddings times a number, each
+    # sub-layer's output times a number before it joins the residual stream,
+    # the final norm's output over a number before the head.
+    embed_multiplier: float = 0.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     # Attention implementation: "auto" picks ring when the active mesh has
     # a sequence axis > 1, else the Pallas flash kernel on TPU, else the
@@ -431,6 +488,38 @@ class ModelConfig:
             raise ValueError(
                 "linear_attention layers need linear_num_heads, "
                 "linear_key_head_dim and linear_value_head_dim")
+        if self.linear_mixer not in LINEAR_MIXERS:
+            raise ValueError(
+                f"unknown linear_mixer {self.linear_mixer!r}; expected "
+                f"{'|'.join(LINEAR_MIXERS)}")
+        if self.lightning:
+            if self.linear_conv_kernel:
+                raise ValueError(
+                    "linear_mixer: lightning has no short convolution; set "
+                    "linear_conv_kernel 0")
+            if self.linear_key_head_dim % 2:
+                raise ValueError(
+                    "lightning's rotary needs an even linear_key_head_dim")
+        if self.attn_gate_width not in ("head", "element"):
+            raise ValueError(
+                f"unknown attn_gate_width {self.attn_gate_width!r}; "
+                "expected head|element")
+        if self.sparse_topk:
+            sp = self.sparse_read
+            if "sliding_attention" in kinds or "latent_attention" in kinds:
+                raise ValueError(
+                    "a sparse read (sparse_topk) is the full_attention "
+                    "layers'; beside sliding_attention layers (whose mask "
+                    "is a window already) or latent_attention (no keys a "
+                    "head) it has no form")
+            if min(sp._replace(init=1)) < 1 or not 0 <= sp.init <= sp.topk \
+                    or sp.block % sp.stride or sp.kernel % sp.stride:
+                raise ValueError(
+                    "a sparse read needs sparse_block, sparse_window, "
+                    "sparse_kernel, sparse_stride and sparse_dense_len all "
+                    ">= 1, 0 <= sparse_init_blocks <= sparse_topk, and "
+                    "sparse_stride dividing sparse_block and sparse_kernel; "
+                    f"got {sp}")
 
     @property
     def layer_pattern(self) -> tuple:
@@ -508,6 +597,11 @@ class ModelConfig:
         """Width of wo's input in a per-head layer of `kind`."""
         return self.attn_shape(kind).heads * self.value_head_dim
 
+    def gate_dim_of(self, kind: str) -> int:
+        """Columns of a per-head layer's output gate W_g."""
+        return (self.o_dim_of(kind) if self.attn_gate_width == "element"
+                else self.attn_shape(kind).heads)
+
     @property
     def latent_cache(self) -> bool:
         """The attention layers cache one latent a token, with no head
@@ -557,9 +651,30 @@ class ModelConfig:
 
     @property
     def has_linear_attention(self) -> bool:
-        """Some layer is a gated-delta mixer: a matrix state a head and
-        the tail of its short convolution."""
+        """Some layer is a linear-attention mixer: a matrix state a head
+        (and, for the gated delta rule, the tail of its short
+        convolution)."""
         return "linear_attention" in self.layer_pattern
+
+    @property
+    def lightning(self) -> bool:
+        """The linear-attention layers are the decay-only recurrence."""
+        return self.has_linear_attention and self.linear_mixer == "lightning"
+
+    @property
+    def sparse_read(self) -> Optional[SparseRead]:
+        """The full-attention layers' sparse read, or None (dense)."""
+        if not self.sparse_topk:
+            return None
+        return SparseRead(self.sparse_block, self.sparse_topk,
+                          self.sparse_window, self.sparse_init_blocks,
+                          self.sparse_kernel, self.sparse_stride,
+                          self.sparse_dense_len)
+
+    def compressed_len(self, cache_len: int) -> int:
+        """Compressed keys a row of cache_len slots can complete."""
+        sp = self.sparse_read
+        return max((cache_len - sp.kernel) // sp.stride + 1, 0)
 
     @property
     def has_short_conv(self) -> bool:
@@ -631,7 +746,8 @@ class ModelConfig:
         return self.hidden_size * (
             self.q_dim_of(kind)
             + shape.kv_heads * (self.head_dim + self.value_head_dim)
-            + self.o_dim_of(kind) + (shape.heads if shape.gate else 0))
+            + self.o_dim_of(kind) + (self.gate_dim_of(kind)
+                                     if shape.gate else 0))
 
     def _attn_extras(self, kind: str) -> int:
         """Per-head attention parameters that are no matrix (biases, norm
@@ -646,6 +762,21 @@ class ModelConfig:
             n += (2 * self.head_dim if self.qk_norm_width == "head"
                   else q_dim + k_dim)
         return n
+
+    def _linear_params(self) -> int:
+        """One linear-attention mixer. Gated delta: q, k, v, output gate,
+        out; the a / b heads; conv; A_log, dt_bias; the output norm (one
+        head's width, shared by the heads). Lightning: q, k, v, gate, out;
+        the QK norms (a head's width each) and the output norm (all the
+        heads')."""
+        h, H = self.hidden_size, self.linear_num_heads
+        kd, vd = self.linear_key_dim, self.linear_value_dim
+        mats = h * (2 * kd + 2 * vd) + vd * h
+        if self.lightning:
+            return mats + 2 * self.linear_key_head_dim + vd
+        return (mats + 2 * h * H
+                + self.linear_conv_kernel * self.linear_conv_dim
+                + 2 * H + self.linear_value_head_dim)
 
     def _short_conv_params(self) -> int:
         """W_in [h, 3h], W_out [h, h] and the kernel of one conv layer."""
@@ -670,13 +801,7 @@ class ModelConfig:
         if self.norm_type == "layernorm":
             norms_per_layer *= 2  # scale + bias
         rest = mlp_mats + norms_per_layer
-        kd, vd = self.linear_key_dim, self.linear_value_dim
-        # q, k, v, output gate, out; the a / b heads; conv; A_log, dt_bias;
-        # the output norm (one head's width, shared by the heads).
-        linear = (h * (2 * kd + 2 * vd) + vd * h
-                  + 2 * h * self.linear_num_heads
-                  + self.linear_conv_kernel * self.linear_conv_dim
-                  + 2 * self.linear_num_heads + self.linear_value_head_dim)
+        linear = self._linear_params()
         final_norm = h * (2 if self.norm_type == "layernorm" else 1)
         mixer = {self.attention_kind: attn, "linear_attention": linear,
                  "conv": self._short_conv_params()}
@@ -706,8 +831,16 @@ class ModelConfig:
         else:
             # QK^T at the key width and PV at the value width, per token.
             attn_proj = 2 * self._attn_matrices(self.attention_kind)
-            attn_scores = 2 * s * self.num_heads * (self.head_dim
-                                                    + self.value_head_dim)
+            seen, choosing = s, 0
+            sp = self.sparse_read
+            if sp is not None and s >= sp.dense_len:
+                # The keys a query reads at most, and its scores against
+                # the compressed keys that choose them.
+                seen = min(s, sp.topk * sp.block + sp.window)
+                choosing = 2 * (s // sp.stride) * self.num_heads \
+                    * self.head_dim
+            attn_scores = choosing + 2 * seen * self.num_heads * (
+                self.head_dim + self.value_head_dim)
         # A window layer's token sees at most its window, whatever the
         # context.
         sliding = 0.0
@@ -728,12 +861,17 @@ class ModelConfig:
                    * (self.moe_top_k * share + self.moe_shared_experts)
                    + 2 * h * self.moe_num_experts)
         kd, vd = self.linear_key_dim, self.linear_value_dim
-        # The delta rule itself: S^T k, the rank-one update, S^T q.
-        linear = (2 * (h * (2 * kd + 2 * vd) + vd * h
-                       + 2 * h * self.linear_num_heads)
-                  + 2 * self.linear_conv_kernel * self.linear_conv_dim
-                  + 6 * self.linear_num_heads * self.linear_key_head_dim
-                  * self.linear_value_head_dim)
+        state = (self.linear_num_heads * self.linear_key_head_dim
+                 * self.linear_value_head_dim)
+        if self.lightning:
+            # The update k v^T and the read S^T q.
+            linear = 2 * (h * (2 * kd + 2 * vd) + vd * h) + 4 * state
+        else:
+            # The delta rule itself: S^T k, the rank-one update, S^T q.
+            linear = (2 * (h * (2 * kd + 2 * vd) + vd * h
+                           + 2 * h * self.linear_num_heads)
+                      + 2 * self.linear_conv_kernel * self.linear_conv_dim
+                      + 6 * state)
         head = 2 * h * self.vocab_size
         # The two projections, the two gates and the kernel's taps.
         conv = 2 * 4 * h * h + 2 * (self.conv_kernel + 2) * h
@@ -929,6 +1067,43 @@ def _lfm2_moe(name, v=65536, h=2048, i=11776, periods=9, lead=2, q=32, kv=8,
     )
 
 
+def _minicpm_sala(name, v=73448, h=4096, i=16384, periods=8, q=32, kv=2,
+                  d=128, s=524288, lin_heads=32, lin_d=128,
+                  published_layers=32, block=64, topk=64, window=2048,
+                  kernel=32, stride=16, dense_len=8192, scale_emb=12.0,
+                  scale_depth=1.4, dim_model_base=256):
+    # One full-attention layer with a SPARSE read (InfLLM-v2: blocks chosen
+    # by scores against compressed keys, beside a window and the initial
+    # block; no rotary; an elementwise output gate) and three lightning
+    # layers (decay-only linear attention: QK norm a head, a rotary of
+    # their own, an output norm over all heads, an elementwise gate);
+    # MiniCPM's scalings with the PUBLISHED depth: embeddings x scale_emb,
+    # each sub-layer x scale_depth / sqrt(published layers), the head's
+    # input / (hidden / dim_model_base) (docs/hybrid-models.md). The
+    # published 32 layers are 8 sparse and 24 lightning ones in NO
+    # repeating order (sparse at 0, 9, 16, 17, 22, 29, 30, 31), which a
+    # repeated pattern cannot say, so the preset is `periods` regular
+    # periods (F L L L) at the published ratio.
+    return ModelConfig(
+        name=name, vocab_size=v, hidden_size=h, intermediate_size=i,
+        num_layers=4 * periods, num_heads=q, num_kv_heads=kv, head_dim=d,
+        max_seq_len=s, norm_type="rmsnorm", norm_eps=1e-6, gated_mlp=True,
+        activation="silu", position_type="none", qk_norm=True,
+        qk_norm_width="head", attn_gate=True, attn_gate_width="element",
+        layer_types=("full_attention",) + ("linear_attention",) * 3,
+        linear_mixer="lightning", linear_conv_kernel=0,
+        linear_rope_theta=10000.0,
+        lightning_decay_layers=published_layers - 1,
+        linear_num_heads=lin_heads, linear_key_head_dim=lin_d,
+        linear_value_head_dim=lin_d,
+        sparse_block=block, sparse_topk=topk, sparse_window=window,
+        sparse_init_blocks=1, sparse_kernel=kernel, sparse_stride=stride,
+        sparse_dense_len=dense_len, embed_multiplier=scale_emb,
+        residual_scale=scale_depth / published_layers ** 0.5,
+        logit_divisor=h / dim_model_base,
+    )
+
+
 # Registry mirrors the reference's documented example configs
 # (reference: examples/ tree — llama2-7b, llama2-70b, falcon-7b/40b,
 # facebook-opt-125m) plus debug sizes for tests/benchmarks.
@@ -977,6 +1152,10 @@ CONFIGS = {
     # leading conv layers with a dense FFN, 64 experts of width 1536
     # (docs/hybrid-models.md)
     "lfm2-24b-a2b": _lfm2_moe("lfm2-24b-a2b"),
+    # Sparse-read full layers (blocks chosen by compressed keys) beside
+    # lightning linear-attention layers, 1 : 3; MiniCPM's scalings
+    # (docs/hybrid-models.md)
+    "minicpm-sala": _minicpm_sala("minicpm-sala"),
     # GPT-2 (fused-qkv Conv1D checkpoints; learned positions)
     "gpt2": _gpt2("gpt2"),
     "gpt2-xl": _gpt2("gpt2-xl", h=1600, i=6400, l=48, q=25),
@@ -1015,6 +1194,15 @@ CONFIGS = {
     "debug-lfm2": _lfm2_moe(
         "debug-lfm2", v=512, h=128, i=384, periods=2, lead=1, q=4, kv=2,
         s=256, experts=8, top_k=2, moe_i=64),
+    # The same mechanisms at toy widths: 2 periods of (1 sparse-read full,
+    # 3 lightning), 4 query heads on 2 KV heads, blocks of 8 keys, 4 chosen
+    # with the initial one, a window of 16, compressed keys over 4 keys 2
+    # apart, dense below 64 (rbt check, tests)
+    "debug-minicpm-sala": _minicpm_sala(
+        "debug-minicpm-sala", v=512, h=128, i=384, periods=2, q=4, kv=2,
+        d=32, s=256, lin_heads=4, lin_d=32, published_layers=8, block=8,
+        topk=4, window=16, kernel=4, stride=2, dense_len=64,
+        dim_model_base=32),
     "bench-1b": _llama("bench-1b", h=2048, i=5632, l=22, q=16, kv=16, d=128, s=2048),
     "bench-410m": _llama("bench-410m", h=1024, i=2816, l=24, q=16, kv=16, d=64, s=2048),
     # Same params/FLOPs as bench-410m but 8 heads x d128: wider MXU

@@ -269,7 +269,8 @@ def _init_attention(cfg: ModelConfig, keys, L: int,
     if shape.gate:
         # N(0, 1/fan_in) like a matrix: a gate left out (sigmoid = 1
         # instead of about a half) must change the logits.
-        attn["wg"] = _dense_init(next(keys), (L, h, shape.heads), pd, h)
+        attn["wg"] = _dense_init(next(keys), (L, h, cfg.gate_dim_of(kind)),
+                                 pd, h)
     if cfg.attn_bias:
         attn["bq"] = jnp.zeros((L, q_dim), pd)
         attn["bk"] = jnp.zeros((L, k_dim), pd)
@@ -415,6 +416,35 @@ def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
         "wv": _dense_init(next(keys), (L, h, vd), pd, h),
         "wg": _dense_init(next(keys), (L, h, vd), pd, h),
         "wo": _dense_init(next(keys), (L, vd, h), pd, vd),
+    }
+    if cfg.lightning:
+        # No decay is drawn: it is the layer's and the head's number
+        # (ops/lightning_attention.decay_rates). A QK norm a head, the
+        # output norm over all the heads.
+        mixer.update({
+            "q_norm": jnp.ones((L, cfg.linear_key_head_dim), pd),
+            "k_norm": jnp.ones((L, cfg.linear_key_head_dim), pd),
+            "o_norm": jnp.ones((L, vd), pd)})
+    else:
+        mixer.update(_init_gated_delta_extras(cfg, keys, L))
+    in_layer_order = {
+        "mixer": mixer,
+        "mlp": {"wo": _dense_init(next(keys), (L, i, h), pd, i),
+                "wi_gate": _dense_init(next(keys), (L, h, i), pd, h),
+                "wi_up": _dense_init(next(keys), (L, h, i), pd, h)},
+        "ln1": _norm_params(cfg, (L,)),
+        "ln2": _norm_params(cfg, (L,)),
+    }
+    return _deal_to_positions(
+        in_layer_order, cfg.layer_pattern.count("linear_attention"))
+
+
+def _init_gated_delta_extras(cfg: ModelConfig, keys, L: int) -> Params:
+    """What the gated-delta mixer has beside its five matrices, drawn in
+    this order: the a / b heads, the conv, A_log, the output norm and
+    dt_bias."""
+    h, pd, H = cfg.hidden_size, cfg.parameter_dtype, cfg.linear_num_heads
+    mixer = {
         "wa": _dense_init(next(keys), (L, h, H), pd, h),
         "wb": _dense_init(next(keys), (L, h, H), pd, h),
         "conv": _dense_init(next(keys), (L, cfg.linear_conv_kernel,
@@ -427,16 +457,7 @@ def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
     dt = jnp.exp(jax.random.uniform(
         next(keys), (L, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
     mixer["dt_bias"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
-    in_layer_order = {
-        "mixer": mixer,
-        "mlp": {"wo": _dense_init(next(keys), (L, i, h), pd, i),
-                "wi_gate": _dense_init(next(keys), (L, h, i), pd, h),
-                "wi_up": _dense_init(next(keys), (L, h, i), pd, h)},
-        "ln1": _norm_params(cfg, (L,)),
-        "ln2": _norm_params(cfg, (L,)),
-    }
-    return _deal_to_positions(
-        in_layer_order, cfg.layer_pattern.count("linear_attention"))
+    return mixer
 
 
 def param_logical_axes(cfg: ModelConfig) -> Params:
@@ -508,14 +529,18 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     axes["layers"] = layers
     if cfg.has_linear_attention:
         col, row = ("layers", "embed", "heads"), ("layers", "heads", "embed")
+        extras = ({"q_norm": ("layers", "head_dim"),
+                   "k_norm": ("layers", "head_dim"),
+                   "o_norm": ("layers", "heads")} if cfg.lightning else
+                  {"wa": ("layers", "embed", None),
+                   "wb": ("layers", "embed", None),
+                   "conv": ("layers", None, None),
+                   "a_log": ("layers", None),
+                   "dt_bias": ("layers", None),
+                   "o_norm": ("layers", "head_dim")})
         one_position = {
             "mixer": {"wq": col, "wk": col, "wv": col, "wg": col, "wo": row,
-                      "wa": ("layers", "embed", None),
-                      "wb": ("layers", "embed", None),
-                      "conv": ("layers", None, None),
-                      "a_log": ("layers", None),
-                      "dt_bias": ("layers", None),
-                      "o_norm": ("layers", "head_dim")},
+                      **extras},
             "mlp": {"wo": ("layers", "mlp", "embed"),
                     "wi_gate": ("layers", "embed", "mlp"),
                     "wi_up": ("layers", "embed", "mlp")},
@@ -639,6 +664,19 @@ class KVCache:
     such a call (a spliced prefix, a second prefill chunk) is sound, and
     the serving engine refuses it (docs/window-full-models.md).
 
+    ckeys (present when the full-attention layers read sparsely,
+    ModelConfig.sparse_topk; ops/block_sparse_attention.py): the row's
+    compressed keys, what a query scores to choose its blocks,
+      ckeys [full layers, batch, (cache_len - kernel) // stride + 1,
+             num_kv_heads, head_dim] float32: entry j the mean of the keys
+             at positions stride j .. stride j + kernel - 1 AS STORED.
+    Slots-like at a stride-th of the length, with no trash slot: entry j
+    is read only by a query at a position >= stride j + kernel - 1, which
+    the row's present occupant wrote before it (a call of one token writes
+    the entry that token completes, a longer call every entry of the row
+    from the row's keys), so a freed row's entries need no clearing and a
+    parked token writes none.
+
     forward() carries every leaf whole through its layer scan (the carry,
     not xs/ys): a layer writes this call's tokens at [layer, row, slot],
     reads [layer, :, :view], and a linear-attention layer reads and writes
@@ -657,6 +695,7 @@ class KVCache:
     latent: Optional[jax.Array] = None
     ring_k: Optional[jax.Array] = None
     ring_v: Optional[jax.Array] = None
+    ckeys: Optional[jax.Array] = None
 
     @classmethod
     def create(cls, cfg: ModelConfig, batch: int, max_len: int,
@@ -677,8 +716,10 @@ class LeafTraits(NamedTuple):
     """What a KVCache field is, whatever the configuration."""
     # Its axis behind [layers, batch]: "slots" (cache_len of them: the
     # trash slot, cache_view and token-sized writes apply), "ring" (a row's
-    # own ring of tokens, written a token at a time) or None (a row's own
-    # state or tail, rewritten a layer at a time).
+    # own ring of tokens, written a token at a time), "compressed" (an
+    # entry a stride of slots: written one at a time in decode, a layer at
+    # a time in prefill) or None (a row's own state or tail, rewritten a
+    # layer at a time).
     tokens: Optional[str]
     axes: tuple        # logical sharding axes (parallel/sharding.py)
     group: str         # its bytes are reported as <group>_bytes
@@ -700,6 +741,7 @@ LEAF_TRAITS = {        # KVCache's leaves, in its field order
     "latent": LeafTraits("slots", _WHOLE, "latent_cache"),
     "ring_k": LeafTraits("ring", _BY_KV_HEAD, "kv_ring"),
     "ring_v": LeafTraits("ring", _BY_KV_HEAD, "kv_ring"),
+    "ckeys": LeafTraits("compressed", _BY_KV_HEAD, "kv_compressed"),
 }
 # The groups whose leaves have no int8 form, and why.
 _NO_INT8 = {
@@ -708,6 +750,10 @@ _NO_INT8 = {
     "latent_cache": "quantize_kv stores one scale a KV head; a latent "
                     "cache has no head axis and no int8 form yet "
                     "(docs/sparse-latent-models.md)",
+    "kv_compressed": "quantize_kv has no form for a sparse read yet: the "
+                     "compressed keys are means of the keys as stored, and "
+                     "no test holds the choice against int8 keys "
+                     "(docs/hybrid-models.md)",
 }
 
 
@@ -723,7 +769,11 @@ class CacheLeaf(NamedTuple):
     group: str
 
     def shape(self, cfg: ModelConfig, batch: int, cache_len: int) -> tuple:
-        slots = (cache_len,) if self.tokens == "slots" else ()
+        slots = ()
+        if self.tokens == "slots":
+            slots = (cache_len,)
+        elif self.tokens == "compressed":
+            slots = (cfg.compressed_len(cache_len),)
         return (cfg.layers_of(self.kind), batch) + slots + self.tail
 
 
@@ -765,9 +815,11 @@ def cache_leaves(cfg: ModelConfig, quantize_kv: bool = False) -> tuple:
         found += [
             ("state", "linear_attention",
              (cfg.linear_num_heads, cfg.linear_key_head_dim,
-              cfg.linear_value_head_dim), f32),
-            ("conv", "linear_attention",
-             (cfg.linear_conv_kernel - 1, cfg.linear_conv_dim), ad)]
+              cfg.linear_value_head_dim), f32)]
+        if not cfg.lightning:
+            found.append(
+                ("conv", "linear_attention",
+                 (cfg.linear_conv_kernel - 1, cfg.linear_conv_dim), ad))
     if cfg.has_short_conv:
         found.append(("conv", "conv",
                       (cfg.conv_kernel - 1, cfg.hidden_size), ad))
@@ -779,6 +831,8 @@ def cache_leaves(cfg: ModelConfig, quantize_kv: bool = False) -> tuple:
             ("ring_k", "sliding_attention", ring + (cfg.head_dim,), ad),
             ("ring_v", "sliding_attention", ring + (cfg.value_head_dim,),
              ad)]
+    if cfg.sparse_read is not None:
+        found.append(("ckeys", "full_attention", (heads, cfg.head_dim), f32))
     leaves = tuple(CacheLeaf(*f, *LEAF_TRAITS[f[0]]) for f in found)
     no_int8 = [_NO_INT8[leaf.group] for leaf in leaves
                if leaf.group in _NO_INT8]
@@ -1049,18 +1103,26 @@ def _attention_block(
     layer_cache: Optional[LayerCache],
     adapter=None,
     kind: str = "full_attention",
+    long_rows: bool = False,
 ):
     """Per-head attention of one layer of `kind` (full_attention or
     sliding_attention: ModelConfig.attn_shape gives what they differ in).
     Keys are head_dim wide and values value_head_dim; the kind's shape says
     how many query heads there are, how many of a head's dimensions rotate
-    and how, and whether a per-head gate multiplies the core's output."""
+    and how, and whether a gate multiplies the core's output. A
+    full_attention layer of a configuration with a sparse read
+    (ModelConfig.sparse_read) keeps the row's compressed keys and, where
+    ``long_rows`` says a row of this call may be sparse_dense_len long,
+    reads through ops/block_sparse_attention."""
     b, s, _ = x.shape
     ad = cfg.activation_dtype
     shape = cfg.attn_shape(kind)
-    # Scope names: a window layer's parts are swa.*, inside `attn` like
-    # every token mixer's (docs/observability.md).
+    sparse = cfg.sparse_read if kind == "full_attention" else None
+    # Scope names: a window layer's parts are swa.*, a sparse read's
+    # bsa.*, inside `attn` like every token mixer's
+    # (docs/observability.md).
     sc = "swa" if shape.window else "attn"
+    core = "attn.core" if sparse is None else "bsa.core"
     ring_on = resolve_collective_matmul(cfg)
     ring_col = "ag" if ring_on else None
     ring_row = "rs" if ring_on else None
@@ -1116,23 +1178,45 @@ def _attention_block(
         with jax.named_scope("attn.kv_write"):
             k, v, new_layer_cache = _write_layer_cache(k, v, positions,
                                                        layer_cache, ad)
-        with jax.named_scope("attn.core"):
-            # No index means position-scatter mode, whose last slot is the
-            # trash slot.
-            trash_pos = (layer_cache.leaves["k"].shape[2] - 1
-                         if layer_cache.index is None else None)
-            out = _cached_attention(cfg, q, k, v, positions, mask, bias,
-                                    trash_pos)
+        ckeys = q_pos = None
+        if sparse is not None:
+            q_pos = (positions if layer_cache.parked is None
+                     else jnp.where(layer_cache.parked, -1, positions))
+            with jax.named_scope("bsa.compress"):
+                ckeys, leaf = _write_compressed_keys(cfg, k, q_pos,
+                                                     layer_cache)
+                new_layer_cache["ckeys"] = leaf
+        if sparse is not None and long_rows:
+            out = _sparse_read(cfg, q, k, v, ckeys, q_pos)
+        else:
+            with jax.named_scope(core):
+                # No index means position-scatter mode, whose last slot is
+                # the trash slot.
+                trash_pos = (layer_cache.leaves["k"].shape[2] - 1
+                             if layer_cache.index is None else None)
+                out = _cached_attention(cfg, q, k, v, positions, mask, bias,
+                                        trash_pos)
+    elif sparse is not None and long_rows:
+        from runbooks_tpu.ops.block_sparse_attention import compress_keys
+
+        # Without a cache the call's own keys are the row's: key i lies at
+        # position i (forward's default positions).
+        with jax.named_scope("bsa.compress"):
+            ckeys = compress_keys(k, sparse)
+        out = _sparse_read(cfg, q, k, v, ckeys, positions)
     else:
-        with jax.named_scope("attn.core"):
+        with jax.named_scope(core):
             out = _dispatch_attention(cfg, q, k, v, positions, segment_ids,
                                       mask, bias)
     if shape.gate:
-        with jax.named_scope(sc + ".gate"):
-            # One number a head and token, from the layer's normed input.
+        with jax.named_scope("bsa.gate" if sparse else sc + ".gate"):
+            # From the layer's normed input: one number a head and token,
+            # or one an element of the core's output.
             g = jax.nn.sigmoid(
                 _matmul(x, p["wg"], ad).astype(jnp.float32)).astype(ad)
-            out = out * g[..., None]
+            out = out * (g.reshape(out.shape)
+                         if cfg.attn_gate_width == "element"
+                         else g[..., None])
     with jax.named_scope(sc + ".out"):
         out = out.reshape(b, s, shape.heads * cfg.value_head_dim)
         attn_ctx = out
@@ -1141,6 +1225,65 @@ def _attention_block(
         if "bo" in p:
             out = out + p["bo"].astype(ad)
     return out, new_layer_cache
+
+
+def _write_compressed_keys(cfg: ModelConfig, k, q_pos, layer_cache):
+    """Keep the compressed-key leaf (KVCache.ckeys) with this call's
+    tokens, whose keys are written: k [b, view, kv heads, d] is the layer's
+    view of the pool WITH them, q_pos [b, s] their positions (below 0:
+    nobody's). A call of one token a row writes the entry that token
+    completes, if it completes one; a longer call every entry the view
+    holds, from the rows' keys as they lie (an entry that is not whole yet
+    holds what it holds: nobody reads it before the token that completes it
+    rewrites it). Returns (this layer's entries [b, n, kv heads, d] float32,
+    the updated leaf)."""
+    from runbooks_tpu.ops.block_sparse_attention import (
+        compress_keys,
+        compressed_key_at,
+    )
+
+    leaf, layer = layer_cache.leaves["ckeys"], layer_cache.layer
+    b, s = q_pos.shape
+    if s == 1:
+        c, j = compressed_key_at(k, q_pos[:, 0], cfg.sparse_read)
+        leaf = leaf.at[layer, jnp.arange(b, dtype=jnp.int32), j].set(
+            c, mode="drop")
+    else:
+        whole = compress_keys(k, cfg.sparse_read)[:, :leaf.shape[2]]
+        leaf = jax.lax.dynamic_update_slice(leaf, whole[None],
+                                            (layer, 0, 0, 0, 0))
+    return jax.lax.dynamic_index_in_dim(leaf, layer, 0, False), leaf
+
+
+def _sparse_read(cfg: ModelConfig, q, k, v, ckeys, q_pos):
+    """The sparse core of a full-attention layer
+    (ops/block_sparse_attention.py). q [b, s, heads, d]; k, v [b, keys, kv
+    heads, *] the row's keys by position; ckeys [b, n, kv heads, d] float32;
+    q_pos [b, s] (below 0: nobody's token). A row is read sparsely when it
+    is sparse_dense_len long with this call's tokens, else whole. One query
+    a row goes through the masked read of the view, more through the walk
+    by query blocks."""
+    from runbooks_tpu.ops.block_sparse_attention import (
+        n_blocks,
+        select_blocks,
+        sparse_decode,
+        sparse_prefill,
+    )
+
+    sp, scale = cfg.sparse_read, cfg.head_dim ** -0.5
+    sparse_row = jnp.max(q_pos, axis=-1) + 1 >= sp.dense_len
+    if q.shape[1] == 1:
+        with jax.named_scope("bsa.select"):
+            chosen = select_blocks(q, ckeys, q_pos, sp,
+                                   n_blocks(k.shape[1], sp), scale,
+                                   cfg.sparse_exclude_window)
+        with jax.named_scope("bsa.core"):
+            return sparse_decode(q, k, v, chosen, q_pos, sparse_row, sp,
+                                 scale)
+    # The walk chooses a query block at a time, inside its loop: both
+    # scopes are named there.
+    return sparse_prefill(q, k, v, ckeys, q_pos, sparse_row, sp, scale,
+                          cfg.sparse_exclude_window)
 
 
 def _window_attention(cfg: ModelConfig, q, k, v, sink, positions,
@@ -1529,6 +1672,67 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         "conv": jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0)}
 
 
+def _lightning_block(cfg: ModelConfig, p: Params, x: jax.Array, positions,
+                     token_mask: Optional[jax.Array], layer_state,
+                     layer_index):
+    """The lightning token mixer (ops/lightning_attention.py) of one layer:
+    q, k, v of linear_num_heads heads, an RMSNorm a head on q and k, a
+    rotary over the whole head where the linear layers have one, the
+    decay-only recurrence at the decay of the layer's index as run
+    (``layer_index``) scaled by d_k^-1/2, an RMSNorm over all the heads'
+    outputs, an elementwise sigmoid gate from x, the output projection.
+    token_mask and layer_state as _linear_attention_block's, but a row
+    keeps the state alone. Returns (out [b, s, h], None or the updated
+    leaf, by name)."""
+    from runbooks_tpu.ops.lightning_attention import (
+        decay_rates,
+        lightning_chunked,
+        lightning_step,
+    )
+
+    b, s, _ = x.shape
+    ad = cfg.activation_dtype
+    H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    state = None
+    if layer_state is not None:
+        all_state, layer = layer_state.leaves["state"], layer_state.layer
+        state = jax.lax.dynamic_index_in_dim(all_state, layer, 0, False)
+    with jax.named_scope("lightning.proj"):
+        q = _matmul(x, p["wq"], ad).reshape(b, s, H, dk)
+        k = _matmul(x, p["wk"], ad).reshape(b, s, H, dk)
+        v = _matmul(x, p["wv"], ad).reshape(b, s, H, dv)
+        gate = _matmul(x, p["wg"], ad)
+        q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
+        k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
+        v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        if cfg.linear_rope_theta:
+            q = apply_rope(q, positions, cfg.linear_rope_theta)
+            k = apply_rope(k, positions, cfg.linear_rope_theta)
+    with jax.named_scope("lightning.core"):
+        rate = decay_rates(H, layer_index, cfg.lightning_decay_layers)
+        scale = dk ** -0.5
+        if layer_state is not None and s == 1:
+            # Decode: one recurrent step a row.
+            o, state = lightning_step(
+                q[:, 0], k[:, 0], v[:, 0], rate, state, scale,
+                None if token_mask is None else token_mask[:, 0])
+            o = o[:, None].astype(ad)
+        else:
+            o, state = lightning_chunked(q, k, v, rate, scale, state,
+                                         token_mask)
+    with jax.named_scope("lightning.out"):
+        o = rms_norm(o.reshape(b, s, H * dv), p["o_norm"], cfg.norm_eps)
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ad)
+        out = _matmul(o, p["wo"], ad)
+    if layer_state is None:
+        return out, None
+    return out, {"state": jax.lax.dynamic_update_index_in_dim(
+        all_state, state, layer, 0)}
+
+
 def _short_conv_block(cfg: ModelConfig, p: Params, x: jax.Array,
                       token_mask: Optional[jax.Array], layer_tail):
     """The gated short convolution of one layer. x [b, s, h] (the layer's
@@ -1632,7 +1836,8 @@ _STACK_OF = {"full_attention": "layers", "latent_attention": "layers",
 
 def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
            bias, layer_cache, adapter=None, token_mask=None,
-           kind: str = "full_attention"):
+           layer_index=None, kind: str = "full_attention",
+           long_rows: bool = False):
     """One transformer block. x: [b, s, h]. Returns (x, cache, aux,
     counts): counts is None, or a sparse FFN's assignment counts.
     ``adapter``: None or (per-layer adapter-pool slice, lane indices) —
@@ -1641,13 +1846,29 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
     the LayerCache of the layer's kind; the updated leaves come back, by
     name. ``token_mask`` says which tokens may change a
     linear-attention layer's state or a conv layer's tail, and which a
-    sparse FFN routes at all."""
+    sparse FFN routes at all. ``layer_index`` is the layer's index as run
+    (a lightning layer's decay depends on it), ``long_rows`` whether a row
+    of this call may be long enough for a sparse read."""
+    scaled = cfg.residual_scale != 1.0
+
+    def joins(out):
+        """A sub-layer's output as it joins the residual stream: times
+        residual_scale in float32 (in the activation type the scale itself
+        would round, every layer the same way), rounded once."""
+        if not scaled:
+            return out
+        return (out.astype(jnp.float32) * cfg.residual_scale).astype(
+            out.dtype)
 
     def mixer(h_in):
         # The linear and conv mixers run inside the `attn` scope too, under
         # inner linattn.* / shortconv.* scopes: `attn` means "the token
         # mixer" to every reader of a capture (docs/observability.md).
         with jax.named_scope("attn"):
+            if kind == "linear_attention" and cfg.lightning:
+                return _lightning_block(
+                    cfg, layer["mixer"], h_in, positions, token_mask,
+                    layer_cache, layer_index)
             if kind == "linear_attention":
                 return _linear_attention_block(
                     cfg, layer["mixer"], h_in, token_mask, layer_cache)
@@ -1661,7 +1882,7 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
             return _attention_block(
                 cfg, layer["attn"], h_in, positions, segment_ids, mask, bias,
                 layer_cache, adapter=_adapter_group(adapter, "attn"),
-                kind=kind)
+                kind=kind, long_rows=long_rows)
 
     # Scopes: everything a layer does is under `block`; inside it `norm`,
     # `attn` (with its attn.* parts) and `ffn`; what is left directly
@@ -1677,14 +1898,14 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
             attn_out = checkpoint_name(attn_out, "attn_out")
             with jax.named_scope("norm"):
                 attn_out = _norm(cfg, layer["ln1"], attn_out)
-            x = x + attn_out
+            x = x + joins(attn_out)
             with jax.named_scope("ffn"):
                 ffn_out, aux, counts = _ffn_block(
                     cfg, layer, x, adapter=mlp_adapter,
                     token_mask=token_mask)
             with jax.named_scope("norm"):
                 ffn_out = _norm(cfg, layer["ln2"], ffn_out)
-            x = x + ffn_out
+            x = x + joins(ffn_out)
             x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
                                         rules=act_rules)
             return x, new_cache, aux, counts
@@ -1708,16 +1929,16 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
                 mlp_out, aux, counts = _ffn_block(
                     cfg, layer, h2, adapter=mlp_adapter,
                     token_mask=token_mask)
-            x = x + attn_out + mlp_out
+            x = x + joins(attn_out) + joins(mlp_out)
         else:
-            x = x + attn_out
+            x = x + joins(attn_out)
             with jax.named_scope("norm"):
                 h2 = _norm(cfg, layer["ln2"], x)
             with jax.named_scope("ffn"):
                 ffn_out, aux, counts = _ffn_block(
                     cfg, layer, h2, adapter=mlp_adapter,
                     token_mask=token_mask)
-            x = x + ffn_out
+            x = x + joins(ffn_out)
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"),
                                     rules=act_rules)
     return x, new_cache, aux, counts
@@ -1759,6 +1980,7 @@ def forward(
     token_mask: Optional[jax.Array] = None,  # [b, s] bool
     with_moe_counts: bool = False,
     weight_layouts=None,
+    row_len_bound: Optional[int] = None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Returns (logits [b, s, vocab] float32, updated cache or None) — or,
     with_aux=True, (logits, cache, aux) where aux is the summed per-layer
@@ -1804,6 +2026,12 @@ def forward(
     program for any tenant mix. Not supported on the pipeline (stage >
     1) path.
 
+    row_len_bound (static; models whose full-attention layers read
+    sparsely): no row is longer than this once the call's tokens are
+    written (a serving prefill's bucket). Beside it forward knows the keys
+    a query can see (the call's, the cache's or the view's); where neither
+    reaches sparse_dense_len the call compiles no sparse core.
+
     token_mask (models with linear-attention layers or sparse FFNs;
     ignored by the others): which tokens are real. A masked-out token
     leaves a row's recurrent state and conv tail exactly as they were (a
@@ -1848,6 +2076,8 @@ def forward(
             x = params["embed"].astype(ad)[tokens]
         if cfg.embed_scale:
             x = x * (cfg.hidden_size ** 0.5)
+        if cfg.embed_multiplier:
+            x = (x.astype(jnp.float32) * cfg.embed_multiplier).astype(ad)
         if cfg.position_type == "learned":
             x = x + params["pos_embed"].astype(ad)[positions]
     # Deliberately the DEFAULT (replicated-h) constraint even when the
@@ -1895,8 +2125,16 @@ def forward(
                    - positions[:, :, None]).astype(jnp.float32)
             bias = slopes[None, :, None, None] * rel[:, None, :, :]
 
-    blocks = {kind: (_block if kind == "full_attention"
-                     else functools.partial(_block, kind=kind))
+    # Whether a row of this call may be long enough for a sparse read.
+    long_rows = False
+    if cfg.sparse_read is not None:
+        seen = s if cache is None else (
+            cache_view if cache_view is not None else cache.k.shape[2])
+        long_rows = min(seen, row_len_bound or seen) \
+            >= cfg.sparse_read.dense_len
+    blocks = {kind: (_block if kind == "full_attention" and not long_rows
+                     else functools.partial(_block, kind=kind,
+                                            long_rows=long_rows))
               for kind in set(pattern)}
     if remat and cfg.remat_policy != "none":
         blocks = {kind: jax.checkpoint(
@@ -1930,12 +2168,15 @@ def forward(
     # Tokens a window layer's ring must not take: in position-scatter mode
     # padding is parked at the K/V leaves' last slot, by position.
     parked = (positions >= cache.k.shape[2] - 1
-              if scatter_mode and cfg.has_window else None)
+              if scatter_mode and (cfg.has_window or cfg.sparse_read)
+              else None)
 
-    def run_layer(kind, layer_params, x, leaves, layer, adapter=None):
+    def run_layer(kind, layer_params, x, leaves, layer, adapter=None,
+                  index=None):
         """One layer of `kind`, number `layer` among the layers of its kind
-        (where its part of that kind's leaves lies). `leaves`: the carried
-        leaves by name, given back with the layer's own updated."""
+        (where its part of that kind's leaves lies) and `index` among all
+        the layers as run. `leaves`: the carried leaves by name, given back
+        with the layer's own updated."""
         layer_cache = None
         if cache is not None:
             layer_cache = LayerCache(
@@ -1944,7 +2185,7 @@ def forward(
                 None if scatter_mode else cache.index, cache_view, parked)
         x, new, aux, counts = blocks[kind](
             cfg, layer_params, x, positions, segment_ids, mask, bias,
-            layer_cache, adapter, token_mask)
+            layer_cache, adapter, token_mask, index)
         return x, leaves | (new or {}), aux, counts
 
     names = ("wi_gate", "wi_up", "wo")
@@ -1999,7 +2240,9 @@ def forward(
             layer = None if cache is None else _leaf_index(cfg, kind, rel, j)
             x, leaves, aux, c = run_layer(
                 kind, layer_params, x, leaves, layer,
-                adapter if kind == cfg.attention_kind else None)
+                adapter if kind == cfg.attention_kind else None,
+                None if rel is None else
+                cfg.leading_dense_layers + rel * len(pattern) + at)
             counts.append(c)
             aux_sum = aux_sum + aux
         # A sparse model's assignment counts, a row a layer in the
@@ -2021,7 +2264,7 @@ def forward(
             for i in range(cfg.leading_dense_layers):
                 one = jax.tree.map(lambda a: a[i], params["leading_layers"])
                 x, leaves, aux, _ = run_layer(
-                    cfg.leading_layer_kind, one, x, leaves, i)
+                    cfg.leading_layer_kind, one, x, leaves, i, index=i)
                 aux_total = aux_total + aux
     moe_counts = None
     n_stages = 1
@@ -2060,8 +2303,9 @@ def forward(
                 n_microbatches=cfg.pipeline_microbatches or None)
     else:
         # The adapter pool (leading L axis) rides the scan as xs when
-        # given, and with a cache the period's number.
-        xs = (scanned, apool, None if cache is None
+        # given, and the period's number with a cache or where a layer's
+        # mathematics depends on its index (lightning's decay).
+        xs = (scanned, apool, None if cache is None and not cfg.lightning
               else jnp.arange(cfg.num_periods, dtype=jnp.int32))
         # `layers`: the scan itself (slices of the stacked weights, what
         # the compiler hoists out of the loop); each layer is a `block`.
@@ -2074,6 +2318,8 @@ def forward(
 
     with jax.named_scope("head"):
         x = _norm(cfg, params["final_norm"], x)
+        if cfg.logit_divisor != 1.0:
+            x = (x.astype(jnp.float32) / cfg.logit_divisor).astype(x.dtype)
     extra = (aux_total,) if with_aux else ()
     if with_moe_counts:
         if moe_counts is None:
@@ -2139,6 +2385,15 @@ _UNSUPPORTED = {
                       "width of the caller's"),
          ("stage", "the pipeline's stages split one homogeneous stack, "
                    "and leading layers are not part of it"))),
+    "kv_compressed": (
+        "sparse-read attention",
+        "adapter pools target the attention projections of a "
+        "homogeneous stack; no test holds a pooled lane through the "
+        "choice of blocks (docs/hybrid-models.md)",
+        (("tensor", "the choice of blocks and the walk by query blocks are "
+                    "not written per shard of KV heads"),
+         ("sequence", "ring attention knows no per-token choice of blocks"),
+         ("stage", "the pipeline's stages split one homogeneous stack"))),
     "kv_ring": (
         "sliding-attention",
         "adapter pools target the attention projections of a "
@@ -2166,6 +2421,12 @@ def _check_support(cfg: ModelConfig, segment_ids, adapters):
         if group not in _UNSUPPORTED:
             continue
         layers, no_adapters, no_axes = _UNSUPPORTED[group]
+        if group == "kv_compressed" and segment_ids is not None:
+            raise NotImplementedError(
+                "packed sequences (segment_ids) with a sparse read need "
+                "compressed keys and a choice of blocks a document; that "
+                "is not written (ops/block_sparse_attention.py, "
+                "docs/hybrid-models.md)")
         if group == "recurrent_state" and segment_ids is not None:
             if cfg.has_short_conv:
                 raise NotImplementedError(
@@ -2224,6 +2485,12 @@ def loss_and_grads_1f1b(
     n_stages = int(mesh.shape.get("stage", 1)) if mesh is not None else 1
     if n_stages <= 1:
         raise ValueError("loss_and_grads_1f1b needs a mesh with stage > 1")
+    if cfg.embed_multiplier or cfg.residual_scale != 1.0 \
+            or cfg.logit_divisor != 1.0:
+        raise NotImplementedError(
+            "embed_multiplier, residual_scale and logit_divisor are not "
+            "written on the 1F1B pipeline path (its embedding and head are "
+            "its own)")
     if len(cfg.layer_pattern) > 1:
         raise NotImplementedError(
             "a layer pattern is not supported on the pipeline (stage > 1) "

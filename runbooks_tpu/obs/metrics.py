@@ -160,6 +160,13 @@ CATALOG: Dict[str, str] = {
     # model only
     "serve_latent_cache_bytes": "gauge",
     "serve_kv_ring_bytes": "gauge",
+    # sparse-read attention layers (ops/block_sparse_attention.py,
+    # docs/hybrid-models.md): the compressed-key leaf, and what the sparse
+    # core's choices need beside what it computes, by program
+    "serve_kv_compressed_bytes": "gauge",
+    "serve_bsa_pairs_needed_total": "counter",
+    "serve_bsa_pairs_visited_total": "counter",
+    "serve_bsa_blocks_chosen_total": "counter",
     "serve_moe_assignments_total": "counter",
     "serve_moe_expert_tokens_total": "counter",
     "serve_moe_expert_hits_total": "counter",

@@ -781,6 +781,13 @@ def create_server(cfg: ModelConfig, model_params, tokenizer=None, *,
                                 "without such layers): the part of "
                                 "serve_kv_pool_bytes that does not grow "
                                 "with max_seq_len.")
+        reg.set_gauge("serve_kv_compressed_bytes",
+                      occ.get("kv_compressed_bytes", 0),
+                      help_text="Compressed keys of the sparse-read "
+                                "attention layers, all slots (0 without "
+                                "such layers): the part of "
+                                "serve_kv_pool_bytes a query scores to "
+                                "choose its key blocks.")
         moe = eng.moe_stats()
         if moe is not None:
             # Sparse layers (models/moe.py, docs/sparse-latent-models.md):

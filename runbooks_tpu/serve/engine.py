@@ -287,6 +287,29 @@ def bucket_for(buckets: List[int], n: int) -> int:
     return buckets[-1]
 
 
+def dispatch_shapes(prefill_buckets: List[int], prefill_budget: int,
+                    max_slots: int) -> List[tuple]:
+    """The (rows, bucket) prefill shapes admission can dispatch: what
+    warm-up compiles and the census counts, no more and no less. A tick's
+    first admission always goes through, alone: [1, bucket] for every
+    bucket. A second request joins it only while the tick's budget in
+    bucket-padded tokens holds both (_admit), and a group of two or more is
+    padded to max_slots rows (prefill_rows): [max_slots, bucket] exists iff
+    2 x bucket <= prefill_budget. With the default budget (max_seq_len)
+    that leaves the largest bucket without its [max_slots, bucket]
+    program, which no tick could fill and which is the largest program a
+    server would compile (at 16 384 tokens a row it does not fit a chip)."""
+    return [(rows, bucket) for bucket in prefill_buckets
+            for rows in dict.fromkeys((1, max_slots))
+            if rows == 1 or 2 * bucket <= prefill_budget]
+
+
+def prefill_rows(group_size: int, max_slots: int) -> int:
+    """Rows of the prefill program a group of same-bucket admissions runs
+    as: one request alone, any burst padded to max_slots."""
+    return 1 if group_size == 1 else max_slots
+
+
 def view_buckets_for(max_seq_len: int) -> List[int]:
     """Decode cache-view buckets for a given context window (see the
     view discussion in InferenceEngine.__init__)."""
@@ -377,6 +400,14 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # sound for such a layer: InferenceEngine refuses speculation,
         # prefix registration, adapter pools and paging for these models.
         #
+        # Compressed keys (full-attention layers with a sparse read;
+        # KVCache.ckeys). The prefill writes every entry of the scratch row
+        # from the row's keys, and the row is spliced into the pool like
+        # the K/V row. An entry is read only by a query at or behind the
+        # token that completes it, so a previous occupant's entries are
+        # hidden without clearing, as its keys are; a parked token
+        # completes none.
+        #
         # Ring leaves (sliding-attention layers; KVCache). A window layer
         # attends this call's own keys
         # (the prompt is prefilled whole), writes the row's last `ring`
@@ -419,10 +450,13 @@ def make_prefill_fn(cfg: ModelConfig, cache_len: int):
         # sampled token's logits are the same numbers, and the
         # [rows, bucket, vocab] float32 tensor (6.6 GB at 16 384 tokens of
         # a 100 352 vocabulary) is never made.
+        # No row is longer than the bucket (and a prefix under it).
         acts, cache1, *moe = forward(
             cfg, params, tokens, positions=positions, cache=cache1,
             adapters=adapters, token_mask=token_mask,
-            return_activations=True, with_moe_counts=sparse)
+            return_activations=True, with_moe_counts=sparse,
+            row_len_bound=tokens.shape[1] + (0 if pk is None
+                                             else pk.shape[1]))
         last_acts = jnp.take_along_axis(
             acts, last_pos[:, None, None], axis=1)[:, 0]
         last_logits = project_logits(cfg, params, last_acts)
@@ -767,9 +801,31 @@ _REFUSED = {
     (_PREFIX, "kv_ring"):
         "a window layer prefills a prompt whole against its own keys; a "
         "spliced prefix would lie under them, and its ring is not stored",
+    ("speculative decoding", "kv_compressed"):
+        "the verify forward's [slots, K+1] queries would rewrite every "
+        "compressed key of the pool a step, and no test holds its rollback "
+        "through them",
+    ("an adapter pool", "kv_compressed"):
+        "a tenant's adapter changes the keys, so the compressed keys and "
+        "the choice of blocks; no test holds a pooled lane through them",
+    ("kv_paging: paged", "kv_compressed"):
+        "a page holds keys and values; the compressed keys of a row span "
+        "page boundaries (a kernel of 32 keys every 16) and have no page of "
+        "their own",
+    ("quantize_kv", "kv_compressed"):
+        "the compressed keys are means of the keys as stored, and no test "
+        "holds the choice of blocks against int8 keys",
+    ("a tensor mesh axis > 1", "kv_compressed"):
+        "the choice of blocks and the walk by query blocks are not written "
+        "per shard of KV heads",
+    (_PREFIX, "kv_compressed"):
+        "a shared prefix splices K/V only; the row's compressed keys would "
+        "have to be stored and spliced with it, and a suffix prefilled "
+        "behind it chooses by the whole row's length",
 }
 # The layers a refusal names, by the kind whose leaf it is.
-_REFUSED_LAYERS = {"linear_attention": "recurrent (linear-attention)",
+_REFUSED_LAYERS = {"full_attention": "sparse-read (block-sparse) attention",
+                   "linear_attention": "recurrent (linear-attention)",
                    "conv": "recurrent (short-convolution)",
                    "latent_attention": "latent (MLA) attention",
                    "sliding_attention": "sliding (window) attention"}
@@ -1414,11 +1470,12 @@ class InferenceEngine:
 
     def warmup(self, rows: Optional[tuple] = None,
                prefix_build: bool = False) -> None:
-        """Compile prefill (every bucket × every row count in `rows`) + the
-        decode chunk ahead of traffic (first-request latency otherwise pays
-        1-2 compiles). Slot state is reset afterwards. Default rows covers
-        every shape the engine can emit: 1 (single admission) and max_slots
-        (batched burst) — each is a separate XLA program.
+        """Compile prefill (the shapes admission can dispatch,
+        `dispatch_shapes`: a request alone in every bucket, a burst of
+        max_slots rows in the buckets a tick's budget holds twice; `rows`
+        keeps those of the given row counts) + the decode chunk ahead of
+        traffic (first-request latency otherwise pays 1-2 compiles). Slot
+        state is reset afterwards. Each shape is a separate XLA program.
 
         prefix_build=True also compiles the prefix-KV builder per bucket
         so a runtime /v1/prefix registration never compiles on the
@@ -1426,12 +1483,14 @@ class InferenceEngine:
         traffic with this on (costs len(buckets) extra warmup compiles)."""
         if prefix_build:
             self._refuse_prefix()
-        if rows is None:
-            rows = (1, self.max_slots) if self.max_slots > 1 else (1,)
+        shapes = self.dispatch_shapes
+        if rows is not None:
+            keep = {min(r, self.max_slots) for r in rows}
+            shapes = [(r, bucket) for r, bucket in shapes if r in keep]
+        row_set = list(dict.fromkeys(r for r, _ in shapes))
         n_prefix = n_prefill = 0
         run = WarmupRun()
         sentinel = run.sentinel
-        row_set = list(dict.fromkeys(min(r, self.max_slots) for r in rows))
         # Warmup compiles are the intended ones — with another component
         # already steady in this process (a trainer sharing it, a second
         # engine) they must not read as stalls.
@@ -1449,27 +1508,22 @@ class InferenceEngine:
                         self._prefix_build(self.params, jnp.asarray(toks),
                                            jnp.asarray(pos))
                     n_prefix += 1
-            for bucket in self.prefill_buckets:
-                for r in row_set:
-                    padded = np.zeros((r, bucket), np.int32)
-                    positions = np.full((r, bucket), self._pad_slot,
-                                        np.int32)
-                    positions[:, :2] = [0, 1]
-                    args = (jnp.asarray(padded), jnp.asarray(positions),
-                            jnp.zeros(r, jnp.int32),
-                            jnp.ones(r, jnp.int32),
-                            self._commit_key(jax.random.key(0)),
-                            jnp.zeros(r, jnp.float32),
-                            jnp.zeros(r, jnp.int32),
-                            jnp.ones(r, jnp.float32))
-                    kw = {**self._adapter_kwargs(np.full(r, -1, np.int32)),
-                          **self._grammar_warm_kwargs(
-                              (r, self.cfg.vocab_size))}
-                    with self._mesh_ctx():
-                        _, self.cache, *_ = run.program(
-                            "prefill", f"b{bucket}r{r}", self._prefill,
-                            self.params, self.cache, *args, **kw)
-                    n_prefill += 1
+            for r, bucket in shapes:
+                padded = np.zeros((r, bucket), np.int32)
+                positions = np.full((r, bucket), self._pad_slot, np.int32)
+                positions[:, :2] = [0, 1]
+                args = (jnp.asarray(padded), jnp.asarray(positions),
+                        jnp.zeros(r, jnp.int32), jnp.ones(r, jnp.int32),
+                        self._commit_key(jax.random.key(0)),
+                        jnp.zeros(r, jnp.float32), jnp.zeros(r, jnp.int32),
+                        jnp.ones(r, jnp.float32))
+                kw = {**self._adapter_kwargs(np.full(r, -1, np.int32)),
+                      **self._grammar_warm_kwargs((r, self.cfg.vocab_size))}
+                with self._mesh_ctx():
+                    _, self.cache, *_ = run.program(
+                        "prefill", f"b{bucket}r{r}", self._prefill,
+                        self.params, self.cache, *args, **kw)
+                n_prefill += 1
             zeros = np.zeros(self.max_slots, np.int32)
             akw = {**self._decode_kwargs(),
                    **self._grammar_warm_kwargs(
@@ -1518,6 +1572,8 @@ class InferenceEngine:
             "prefill_programs": n_prefill,
             "prefill_buckets": list(self.prefill_buckets),
             "rows": row_set,
+            # What admission can dispatch, and so what was compiled.
+            "prefill_shapes": [list(shape) for shape in shapes],
             "decode_views": list(self.view_buckets),
             "prefix_builders": n_prefix,
             "verify_programs": n_verify,
@@ -1546,7 +1602,8 @@ class InferenceEngine:
         print(
             f"serve: warmup census: {n_prefill} prefill programs "
             f"({len(self.prefill_buckets)} buckets {self.prefill_buckets} "
-            f"x rows {row_set}), {len(self.view_buckets)} decode views "
+            f"x rows {row_set}, those admission can dispatch at a budget of "
+            f"{self.prefill_budget}), {len(self.view_buckets)} decode views "
             f"{self.view_buckets}, {n_prefix} prefix builders, "
             f"{n_verify} verify programs; "
             f"{self.warmup_census['compiles']} compiles in "
@@ -1887,7 +1944,8 @@ class InferenceEngine:
         # latent (MLA) leaf and the window layers' rings, whose size does
         # not grow with max_seq_len, are the parts of it named beside it.
         by_group = dict.fromkeys(("kv_pool", "recurrent_state",
-                                  "latent_cache", "kv_ring"), 0)
+                                  "latent_cache", "kv_ring",
+                                  "kv_compressed"), 0)
         per_device = 0
         for leaf in cache_leaves(self.cfg, self.quantize_kv):
             array = getattr(self.cache, leaf.name)
@@ -1904,6 +1962,7 @@ class InferenceEngine:
                 "recurrent_state_bytes": by_group["recurrent_state"],
                 "latent_cache_bytes": by_group["latent_cache"],
                 "kv_ring_bytes": by_group["kv_ring"],
+                "kv_compressed_bytes": by_group["kv_compressed"],
                 "occupancy_ratio": (tokens / capacity) if capacity else 0.0}
 
     def memory_groups(self) -> dict:
@@ -2073,7 +2132,7 @@ class InferenceEngine:
         self.prefix_lookups += n
         if pkey:
             self.prefix_hits += n
-        rows = 1 if n == 1 else self.max_slots
+        rows = prefill_rows(n, self.max_slots)
 
         def operands():
             tokens = np.zeros((rows, bucket), np.int32)
@@ -2154,6 +2213,7 @@ class InferenceEngine:
                     first, *moe = program(args, kwargs)
                     # The call has returned and the device is at work.
                     self._count_flash_blocks(bucket, positions)
+                    self._count_sparse_prefill(bucket, positions)
                 self.deliver_parked(hidden=True)
                 with fine("prefill.sync"):
                     # The first token must reach the host to stream.
@@ -2174,6 +2234,19 @@ class InferenceEngine:
                 for i, (slot, req) in enumerate(group):
                     self._activate_slot(slot, req, int(first[i]))
 
+    @property
+    def dispatch_shapes(self) -> List[tuple]:
+        """The (rows, bucket) prefill shapes _admit can dispatch
+        (dispatch_shapes): warm-up compiles exactly these."""
+        return dispatch_shapes(self.prefill_buckets, self.prefill_budget,
+                               self.max_slots)
+
+    def _sparse_core(self, seen: int) -> bool:
+        """Whether a program whose rows may be `seen` tokens long runs
+        the full-attention layers' sparse core (forward's long_rows)."""
+        sp = self.cfg.sparse_read
+        return sp is not None and seen >= sp.dense_len
+
     def _by_flash_program(self, of_shapes) -> dict:
         """{prefill program: of_shapes(cfg, queries, keys, tensor shards)}
         for every bucket whose prefill takes the flash path: what is static
@@ -2183,7 +2256,8 @@ class InferenceEngine:
         return {f"prefill_b{bucket}": of_shapes(
                     self.cfg, bucket, self.max_seq_len + 1, tp)
                 for bucket in self.prefill_buckets
-                if use_flash_cached_prefill(self.cfg, bucket)}
+                if use_flash_cached_prefill(self.cfg, bucket)
+                and not self._sparse_core(bucket)}
 
     @functools.cached_property
     def flash_head_block(self) -> dict:
@@ -2204,14 +2278,13 @@ class InferenceEngine:
     def gmm_tiling(self) -> dict:
         """{program: {"gate_up": [tm, tk, tn], "down": [tm, tk, tn]}}: the
         tiles the sparse layers' grouped products of each prefill program
-        (bucket x rows) and decode view compile with (models/moe.
+        (dispatch_shapes) and decode view compile with (models/moe.
         gmm_tilings: the chooser the program asks, from its shapes). {} for
         a dense model and where the products run as ragged_dot."""
         if not self.cfg.moe_num_experts:
             return {}
-        rows = dict.fromkeys((1, self.max_slots))
         tiles = {f"prefill_b{bucket}r{r}": gmm_tilings(self.cfg, bucket * r)
-                 for bucket in self.prefill_buckets for r in rows}
+                 for r, bucket in self.dispatch_shapes}
         tiles.update({f"decode_v{view}": gmm_tilings(self.cfg,
                                                      self.max_slots)
                       for view in self.view_buckets})
@@ -2231,9 +2304,8 @@ class InferenceEngine:
         def window(tokens):
             return chunk_window(self.cfg, tokens)[1]
 
-        rows = dict.fromkeys((1, self.max_slots))
         return {**{f"prefill_b{bucket}r{r}": window(bucket * r)
-                   for bucket in self.prefill_buckets for r in rows},
+                   for r, bucket in self.dispatch_shapes},
                 **{f"decode_v{view}": window(self.max_slots)
                    for view in self.view_buckets}}
 
@@ -2297,6 +2369,57 @@ class InferenceEngine:
                 f"serve_window_{name}_total", value, bucket=str(bucket),
                 help_text=what + " a head and window layer in prefill, by "
                                  "bucket.")
+
+    def _count_sparse_prefill(self, bucket: int,
+                              positions: np.ndarray) -> None:
+        """Score pairs a prefill dispatch's sparse core needs and computes
+        (ops/block_sparse_attention.prefill_counts), a query head and
+        sparse-read layer, and the blocks its tokens choose a KV head:
+        counted on the host from the positions the dispatch was given, as
+        _count_flash_blocks counts the flash forward's. A program that runs
+        no sparse core (a bucket under sparse_dense_len) counts nothing
+        here: its read is the flash forward's."""
+        if not self._sparse_core(bucket):
+            return
+        from runbooks_tpu.ops.block_sparse_attention import prefill_counts
+
+        self._count_sparse("prefill", *prefill_counts(
+            positions, positions >= self._pad_slot, self.cfg.sparse_read,
+            self.max_seq_len + 1))
+
+    def _count_sparse_decode(self, view: int, before: np.ndarray) -> None:
+        """The same for a decode chunk whose program read `view` keys a
+        live row and step: the positions its steps wrote are those between
+        the slots' lengths `before` the host took the chunk's tokens and
+        now (a step of a slot the host cut short is not counted)."""
+        if not self._sparse_core(view):
+            return
+        from runbooks_tpu.ops.block_sparse_attention import decode_counts
+
+        at = np.concatenate([np.arange(lo, hi)
+                             for lo, hi in zip(before, self.lengths)])
+        self._count_sparse("decode", *decode_counts(
+            at, self.cfg.sparse_read, view))
+
+    def _count_sparse(self, program: str, needed: int, visited: int,
+                      chosen: int) -> None:
+        for name, value, what in (
+                ("pairs_needed", needed,
+                 "Score pairs the chosen sets require (a token of a long "
+                 "row: its window, the initial blocks and the blocks it "
+                 "chose, causally clipped; of a short row: every earlier "
+                 "key)"),
+                ("pairs_visited", visited,
+                 "Score pairs the sparse core computed (prefill: every "
+                 "(query block, key chunk) its walk visits; decode: the "
+                 "view's keys a live row)"),
+                ("blocks_chosen", chosen,
+                 "Key blocks the tokens of long rows read beside their "
+                 "windows (a KV head; the initial ones among them)")):
+            obs_metrics.REGISTRY.inc(
+                f"serve_bsa_{name}_total", value, program=program,
+                help_text=what + ", a query head and sparse-read layer, by "
+                                 "program.")
 
     def _count_moe(self, program: str, moe: list, tokens: int,
                    steps: int = 1) -> None:
@@ -2888,7 +3011,9 @@ class InferenceEngine:
                 time.perf_counter() - t_dispatch, view=str(label),
                 help_text="Decode-chunk dispatch+sync wall time, labeled "
                           "by cache view bucket.")
+            before = self.lengths.copy()
             agreed = self._take_chunk(pulled)
+            self._count_sparse_decode(label, before)
             self._dev_blocks = (ints, blocks[1]) if agreed else None
             generated = sum(len(toks) for _, toks, _ in self._parked)
             if inline or not self.has_work() or any(

@@ -590,7 +590,10 @@ def test_repo_audits_clean_with_zero_compiles():
 def test_hybrid_programs_are_audited_and_the_rest_of_the_census_stands():
     """A layer pattern's prefill and decode programs are traced with the
     recurrent cache leaves beside k / v; every row the census had before
-    them (PR 25's committed baseline, by digest) is what it was."""
+    them (PR 25's committed baseline, by digest) is what it was, but that
+    since PR 45 the four prefill rows count the 9 shapes admission can
+    dispatch at the default budget, not 5 buckets x 2 row counts
+    (serve/engine.dispatch_shapes)."""
     import hashlib
     import json
 
@@ -615,7 +618,7 @@ def test_hybrid_programs_are_audited_and_the_rest_of_the_census_stands():
     assert len(old) == 31 and len(base["programs"]) == 37
     assert hashlib.sha256(json.dumps(old, sort_keys=True).encode()
                           ).hexdigest() == (
-        "e04d823ec805a6e339114d31d41529ec8ada425ff7e3c2d51b4302317a6db011")
+        "ea12e82ea1a1035e8061ef7dbb0f43df71f6b6fa942d142d5a65d364bca2e4f1")
 
 
 def test_sparse_latent_programs_are_audited():

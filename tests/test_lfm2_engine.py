@@ -191,9 +191,12 @@ def test_census_names_the_grouped_products_tiles(monkeypatch):
     cfg = get_config("lfm2-24b-a2b")
 
     def census(cfg):
+        from runbooks_tpu.serve.engine import dispatch_shapes
+
+        # A budget of two windows: every bucket has both row counts.
         return InferenceEngine.gmm_tiling.func(types.SimpleNamespace(
-            cfg=cfg, max_slots=8, prefill_buckets=(16, 1024, 2048),
-            view_buckets=(512, 2048)))
+            cfg=cfg, max_slots=8, view_buckets=(512, 2048),
+            dispatch_shapes=dispatch_shapes((16, 1024, 2048), 4096, 8)))
 
     chunk = {"gate_up": [256, 2048, 768], "down": [256, 1536, 1024]}
     step = {"gate_up": [32, 1024, 1024], "down": [32, 1024, 1024]}

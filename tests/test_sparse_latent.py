@@ -984,9 +984,12 @@ def test_census_names_the_rows_a_window_holds(preset, held, chunk, bucket16,
     from runbooks_tpu.serve.engine import InferenceEngine
 
     def census(cfg):
+        from runbooks_tpu.serve.engine import dispatch_shapes
+
+        # A budget of two windows: every bucket has both row counts.
         return InferenceEngine.moe_row_window.func(types.SimpleNamespace(
-            cfg=cfg, max_slots=8, prefill_buckets=(16, 2048),
-            view_buckets=(512,)))
+            cfg=cfg, max_slots=8, view_buckets=(512,),
+            dispatch_shapes=dispatch_shapes((16, 2048), 4096, 8)))
 
     cfg = get_config(preset, moe_experts_held=held)
     assert census(cfg) == {
