@@ -1261,8 +1261,8 @@ def _sparse_read(cfg: ModelConfig, q, k, v, ckeys, q_pos):
     heads, *] the row's keys by position; ckeys [b, n, kv heads, d] float32;
     q_pos [b, s] (below 0: nobody's token). A row is read sparsely when it
     is sparse_dense_len long with this call's tokens, else whole. One query
-    a row goes through the masked read of the view, more through the walk
-    by query blocks."""
+    a row goes through the masked read of the view, more through the flash
+    forward under the tokens' choices."""
     from runbooks_tpu.ops.block_sparse_attention import (
         n_blocks,
         select_blocks,
@@ -1280,10 +1280,10 @@ def _sparse_read(cfg: ModelConfig, q, k, v, ckeys, q_pos):
         with jax.named_scope("bsa.core"):
             return sparse_decode(q, k, v, chosen, q_pos, sparse_row, sp,
                                  scale)
-    # The walk chooses a query block at a time, inside its loop: both
-    # scopes are named there.
+    # Names its own scopes: the choice, then the one kernel call.
     return sparse_prefill(q, k, v, ckeys, q_pos, sparse_row, sp, scale,
-                          cfg.sparse_exclude_window)
+                          cfg.sparse_exclude_window, cfg.flash_block_q,
+                          cfg.flash_block_k)
 
 
 def _window_attention(cfg: ModelConfig, q, k, v, sink, positions,

@@ -26,17 +26,22 @@ the query as the layer made it with compressed keys that are float32 means
 of the keys AS STORED: prefill and decode of one program then rank the same
 numbers (as models/moe.route's are, for the same reason).
 
-Two cores over one choice (``select_blocks``), both plain `jax.numpy`:
+Two cores over one choice (``select_blocks``, plain `jax.numpy`):
 
-- ``sparse_prefill``: a call of many queries. A query BLOCK at a time it
-  walks the key chunks up to the block's last position under each token's
-  own mask, with a running softmax. Every causal (query block, key chunk)
-  pair is computed: gathering a token's own keys would move
-  topk x block + window keys a token and KV head. ``prefill_counts`` says
-  how many score pairs that is beside those the choices need.
+- ``sparse_prefill``: a call of many queries is ONE call of the flash
+  forward (ops/flash_attention.py) under each token's own read: the window,
+  and the token's choice of key blocks as one more term of the mask, an
+  int8 ``[b, KV heads, queries, blocks]`` operand that a grid step widens
+  to its key block's columns once for the KV head's whole group. Scores,
+  the running softmax and the accumulator stay in VMEM. Every causal
+  (query block, key block) tile is computed: with seeded weights the union
+  of a query block's choices is nearly every block, and gathering a
+  token's own keys would move topk x block + window keys a token and KV
+  head. ``prefill_counts`` says how many score pairs the kernel's steps
+  are beside those the choices need (PERF.md section 6, PR 46).
 - ``sparse_decode``: a call of one query a row reads the row's keys under
   the token's mask (one product over the view, as every decode step's
-  attention is).
+  attention is); also the tests' one-masked-softmax oracle.
 """
 
 from __future__ import annotations
@@ -47,10 +52,9 @@ import numpy as np
 
 _EXACT = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
-# A prefill walks query blocks of Q_ROWS // rows tokens (at least
-# MIN_Q_BLOCK) over key chunks of K_CHUNK keys: the float32 scores of one
-# step are rows x heads x Q_ROWS x K_CHUNK / rows numbers.
-Q_ROWS, MIN_Q_BLOCK, K_CHUNK = 512, 64, 2048
+# A prefill chooses for this many of a dispatch's tokens at a time: the
+# rank compares tokens x KV heads x blocks x blocks scores a step.
+SELECT_TOKENS = 512
 
 
 def n_blocks(keys: int, sp) -> int:
@@ -158,79 +162,51 @@ def sparse_decode(q, k, v, chosen, positions, sparse_row, sp, scale: float):
     return out.reshape(b, s, H, v.shape[-1]).astype(q.dtype)
 
 
-def prefill_tiles(rows: int, queries: int, keys: int) -> tuple:
-    """(query block, key chunk) a prefill of this shape walks."""
-    q_block = min(max(Q_ROWS // rows, MIN_Q_BLOCK), queries)
-    return q_block, min(K_CHUNK, keys)
-
-
 def sparse_prefill(q, k, v, ckeys, positions, sparse_row, sp, scale: float,
-                   exclude_window: bool = True):
+                   exclude_window: bool = True, block_q=None, block_k=None):
     """Many queries a row. q [b, s, H, d]; k [b, L, g, d], v [b, L, g, dv]:
     the row's keys by position (slot p holds position p); ckeys [b, n_c, g,
     d] float32; positions [b, s] (below 0: nobody's token, whose output is
-    0); sparse_row [b] bool. Returns [b, s, H, dv] in q's dtype."""
+    0); sparse_row [b] bool. Returns [b, s, H, dv] in q's dtype.
+
+    The choice is made SELECT_TOKENS tokens at a time (the rank's
+    [tokens, g, blocks, blocks] comparisons stay bounded) and kept as int8
+    [b, g, s, blocks]; a short row chooses every block. The core is one
+    call of the flash forward under that choice and the window
+    (block_q / block_k: None = flash_attention.block_shape's answer for
+    the call)."""
+    from runbooks_tpu.ops.flash_attention import flash_attention
+
     b, s, H, d = q.shape
-    L, g, dv = k.shape[1], k.shape[2], v.shape[-1]
-    R = H // g
+    L = k.shape[1]
     nb = n_blocks(L, sp)
-    q_block, k_chunk = prefill_tiles(b, s, L)
-    pad = -s % q_block
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        positions = jnp.pad(positions, ((0, 0), (0, pad)),
-                            constant_values=-1)
-    n_q, n_k = (s + pad) // q_block, -(-L // k_chunk)
-    ad = v.dtype
+    step = min(max(SELECT_TOKENS // b, 1), s)
+    pad = -s % step
 
-    def query_block(xs):
-        q_i, pos_i = xs                    # [b, q_block, H, d], [b, q_block]
-        with jax.named_scope("bsa.select"):
-            chosen = select_blocks(q_i, ckeys, pos_i, sp, nb, scale,
-                                   exclude_window)
-        q_g = q_i.reshape(b, q_block, g, R, d)
-        last = jnp.max(pos_i)
+    def steps(x, fill):                  # [b, s, ...] -> [n, b, step, ...]
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2),
+                    constant_values=fill)
+        return jnp.moveaxis(x.reshape((b, -1, step) + x.shape[2:]), 1, 0)
 
-        def chunk(carry, c):
-            def visit(carry):
-                m, l, acc = carry
-                # The last chunk may reach back into the one before it:
-                # its keys below c * k_chunk were that chunk's.
-                start = jnp.minimum(c * k_chunk, L - k_chunk)
-                key_pos = start + jnp.arange(k_chunk)
-                k_c = jax.lax.dynamic_slice_in_dim(k, start, k_chunk, 1)
-                v_c = jax.lax.dynamic_slice_in_dim(v, start, k_chunk, 1)
-                mask = read_mask(chosen, pos_i, key_pos, sparse_row, sp) \
-                    & (key_pos >= c * k_chunk)
-                mask = mask[:, :, :, None, :]           # [b, q, g, 1, k]
-                logits = jnp.einsum(
-                    "bqgrd,bkgd->bqgrk", q_g, k_c,
-                    preferred_element_type=jnp.float32) * scale
-                logits = jnp.where(mask, logits, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
-                p = jnp.where(mask, jnp.exp(logits - m_new[..., None]), 0.0)
-                fade = jnp.exp(m - m_new)
-                l = l * fade + jnp.sum(p, axis=-1)
-                acc = acc * fade[..., None] + jnp.einsum(
-                    "bqgrk,bkgd->bqgrd", p.astype(ad), v_c,
-                    preferred_element_type=jnp.float32)
-                return m_new, l, acc
+    def select(xs):
+        return select_blocks(xs[0], ckeys, xs[1], sp, nb, scale,
+                             exclude_window).astype(jnp.int8)
 
-            return jax.lax.cond(c * k_chunk <= last, visit, lambda x: x,
-                                carry), None
-
-        init = (jnp.full((b, q_block, g, R), NEG_INF, jnp.float32),
-                jnp.zeros((b, q_block, g, R), jnp.float32),
-                jnp.zeros((b, q_block, g, R, dv), jnp.float32))
-        with jax.named_scope("bsa.core"):
-            (_, l, acc), _ = jax.lax.scan(chunk, init, jnp.arange(n_k))
-            out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return out.reshape(b, q_block, H, dv).astype(q.dtype)
-
-    blocks = lambda x: jnp.moveaxis(  # noqa: E731
-        x.reshape((b, n_q, q_block) + x.shape[2:]), 1, 0)
-    out = jax.lax.map(query_block, (blocks(q), blocks(positions)))
-    return jnp.moveaxis(out, 0, 1).reshape(b, n_q * q_block, H, dv)[:, :s]
+    with jax.named_scope("bsa.select"):
+        if step == s:
+            chosen = select((q, positions))                   # [b, s, g, nb]
+        else:
+            chosen = jax.lax.map(select, (steps(q, 0), steps(positions, -1)))
+            chosen = jnp.moveaxis(chosen, 0, 1).reshape(
+                b, -1, *chosen.shape[3:])[:, :s]
+        chosen = jnp.swapaxes(chosen, 1, 2)                   # [b, g, s, nb]
+        chosen = jnp.maximum(
+            chosen, (~sparse_row).astype(jnp.int8)[:, None, None, None])
+    kv_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (b, L))
+    with jax.named_scope("bsa.core"):
+        return flash_attention(
+            q, k, v, positions, kv_pos, None, None, True, scale, block_q,
+            block_k, window=sp.window, choice=chosen, choice_block=sp.block)
 
 
 def block_sparse_attention_reference(q, k, v, positions, sp, scale: float,
@@ -275,26 +251,32 @@ def read_counts(positions: np.ndarray, sparse: np.ndarray, sp) -> tuple:
 
 
 def prefill_counts(positions: np.ndarray, parked: np.ndarray, sp,
-                   keys: int) -> tuple:
+                   keys: int, group: int, block_q=None,
+                   block_k=None) -> tuple:
     """(needed, visited, chosen) of one prefill dispatch through
     ``sparse_prefill``, a query head (a KV head) and layer: positions
     [rows, s] as the dispatch was given them, parked [rows, s] bool
-    (nobody's tokens), `keys` the slots of a row. Visited: every (query
-    block, key chunk) the walk computes, whole, for every row of the
-    dispatch."""
+    (nobody's tokens), `keys` the slots of a row, `group` the query heads
+    a KV head (block_q / block_k as the call was given them). Visited:
+    block_q x block_k score pairs for every grid step of the core whose
+    body runs, counted by the function the kernel takes its ranges from
+    (flash_attention.block_counts over the causal ranges, at the call's
+    block_shape)."""
+    from runbooks_tpu.ops.flash_attention import block_counts, block_shape
+
     rows, s = positions.shape
     pos = np.where(parked, -1, positions).astype(np.int64)
     sparse = np.broadcast_to(
         (pos.max(axis=-1) + 1 >= sp.dense_len)[:, None], pos.shape)
     real = pos >= 0
     needed, chosen = read_counts(pos[real], sparse[real], sp)
-    q_block, k_chunk = prefill_tiles(rows, s, keys)
-    visited = 0
-    for i in range(0, s, q_block):
-        last = pos[:, i:i + q_block].max()
-        if last >= 0:
-            visited += rows * q_block * k_chunk * (last // k_chunk + 1)
-    return needed, int(visited), chosen
+    block_q, block_k = block_shape("fwd", s, keys, group, sp.window,
+                                   block_q, block_k)
+    steps, _ = block_counts(
+        pos.astype(np.int32),
+        np.broadcast_to(np.arange(keys, dtype=np.int32), (rows, keys)),
+        None, None, block_q, block_k, True)
+    return needed, steps * block_q * block_k, chosen
 
 
 def decode_counts(positions: np.ndarray, sp, view: int) -> tuple:

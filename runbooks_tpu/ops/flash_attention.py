@@ -44,6 +44,17 @@ Design (see /opt/skills/guides/pallas_guide.md):
   length, a bucket's padded tail, ``sk != sq``, a rotated ring shard and a
   packed training batch all take the one path, and a block whose every
   score the mask would set to NEG_INF is never loaded or computed.
+- A forward may take a per-query CHOICE of key blocks (``choice`` int8
+  ``[b, kv_h, sq, blocks]``, ``choice_block`` keys a block; the sparse read
+  of ops/block_sparse_attention.py): a query then sees, causally, its
+  window AND the blocks it chose. The ranges are the causal ones (a chosen
+  block lies anywhere before the window, so the grid walks every kv step
+  up to ``hi``), the choice's block of a grid step is the query block's
+  (the same for every kv step: copied in once a query block), and the
+  step widens it to the kv block's columns ONCE for all its heads
+  (``_chosen_keys``: one 0 / 1 product with "key c is of block n", exact
+  in bfloat16) into the bias the heads already share. Without a choice
+  nothing of the traced program differs (a Python ``if`` on ``None``).
 - Backward: standard flash backward from saved logsumexp — one kernel for dq
   (kv blocks innermost) and one for dk/dv (query blocks, then head blocks
   innermost), both recomputing p blockwise. They still skip by GRID index
@@ -76,6 +87,15 @@ data around the kernel, median of 12, G by ``head_block``:
   2.082 / 1.973, its widths at window 256 2.223 / 2.193 / 2.192 / 2.048;
   64 on 8 at 128: window 512 1.865 / 1.940 / 1.939 / 1.953, window 256
   1.778 / 1.786 / 1.856 / 1.759, window 128 1.785 / 1.746 / 1.842 / 1.755.
+  forward under a choice (PR 46; 32 heads on 2 at 128, window 2048, 257
+  blocks of 64 keys, 16 385 keys; ms a call WITH the choice's 20.2 / 10.5 /
+  72.7 ms of select_blocks before it, median of 8, G = 16):
+                                  256x512 512x512 1024x512 256x1024 512x1024 1024x1024 256x2048 512x2048
+  [1, 16384], 14 592 real tokens    48.95   46.89    50.19    38.82    37.00     38.35    38.02    38.33
+  [1, 8192]                         20.25   19.40    20.23    17.05    16.37     16.57    17.15    17.24
+  [8, 8192]                             -  144.41        -   126.86   119.65         -        -        -
+  (the XLA walk it replaced, with the same select: 110.65 / 40.97 / 297.22.)
+  512 x 1024 again, so block_shape has no rule for a choice.
   backward, LoRA rows             256x256 256x512 512x256 512x512 512x1024 1024x512 1024x1024
   dq                                 9.39    7.83    8.64    7.65     7.65     7.79      8.92
   dkv                               12.99    9.39   10.77    7.74     8.13     8.20      8.27
@@ -196,20 +216,26 @@ def _tile(rows: int, cols: int) -> int:
 
 
 def vmem_by_kernel(g: int, block_q: int, block_k: int, d: int, dv: int,
-                   sink: bool = False) -> dict:
+                   sink: bool = False, choice_blocks: int = 0) -> dict:
     """{kernel: bytes}: what a grid step of ``g`` heads of the forward, dq
     and dkv holds in VMEM, as far as shapes say it. The module docstring
-    has the sum in numbers."""
+    has the sum in numbers. ``choice_blocks``: the width of the forward's
+    block choice, 0 for none."""
     bq_d, bq_dv, bq_row = _tile(block_q, d), _tile(block_q, dv), \
         _tile(block_q, LANES)
     scores, kv = _tile(block_q, block_k), _tile(block_k, d) + _tile(block_k, dv)
     # Whatever g: the row data, K and V (two buffers and the float32 cast),
     # the bias.
     a_step = 2 * 2 * (bq_row + _tile(SUBLANES, block_k)) + 3 * kv + scores
+    # The choice of a query block in two buffers, and while it is widened
+    # to the key block's columns: which block a key is of, the widened
+    # choice.
+    chosen = (2 * _tile(block_q, choice_blocks)
+              + _tile(choice_blocks, block_k) + scores) if choice_blocks else 0
     return {
         # One head's s and p live; a head's q, o, lse in two buffers, its
         # m, l, acc, its sink's tile.
-        "fwd": a_step + 2 * scores + g * (
+        "fwd": a_step + chosen + 2 * scores + g * (
             2 * (bq_d + bq_dv + bq_row) + 2 * _tile(block_q, 1) + bq_dv
             + (2 * _tile(SUBLANES, LANES) if sink else 0)),
         # s, p, dp, ds live; a head's q, do, lse, delta, dq in two buffers
@@ -223,13 +249,15 @@ def vmem_by_kernel(g: int, block_q: int, block_k: int, d: int, dv: int,
 
 
 def vmem_bytes(g: int, block_q: int, block_k: int, d: int, dv: int,
-               sink: bool = False, window: int = 0) -> int:
+               sink: bool = False, window: int = 0,
+               choice_blocks: int = 0) -> int:
     """The largest of vmem_by_kernel over the kernels the call has: a call
-    with a sink, a window or values of another width than keys has a
-    forward only (flash_attention refuses its backward). ``window`` takes
-    no VMEM (it shortens the grid)."""
-    by_kernel = vmem_by_kernel(g, block_q, block_k, d, dv, sink)
-    if sink or window or d != dv:
+    with a sink, a window, a block choice or values of another width than
+    keys has a forward only (flash_attention refuses its backward).
+    ``window`` takes no VMEM (it shortens the grid)."""
+    by_kernel = vmem_by_kernel(g, block_q, block_k, d, dv, sink,
+                               choice_blocks)
+    if sink or window or choice_blocks or d != dv:
         return by_kernel["fwd"]
     return max(by_kernel.values())
 
@@ -245,7 +273,8 @@ def _ask_vmem(kernel: str, g: int, *shape) -> pltpu.CompilerParams:
 
 
 def head_block(n_rep: int, block_q: int, block_k: int, d: int, dv: int,
-               sink: bool = False, window: int = 0) -> int:
+               sink: bool = False, window: int = 0,
+               choice_blocks: int = 0) -> int:
     """G: how many of a KV head's ``n_rep`` query heads one grid step of
     the kernels holds. A pure function of shapes: the most heads that
     ``vmem_bytes`` puts under VMEM_BUDGET_BYTES, then evened out over the
@@ -258,7 +287,8 @@ def head_block(n_rep: int, block_q: int, block_k: int, d: int, dv: int,
     too large for the budget at G = 1 are the caller's to shrink, as
     before."""
     most = max((g for g in range(1, n_rep + 1)
-                if vmem_bytes(g, block_q, block_k, d, dv, sink, window)
+                if vmem_bytes(g, block_q, block_k, d, dv, sink, window,
+                              choice_blocks)
                 <= VMEM_BUDGET_BYTES), default=1)
     return -(-n_rep // -(-n_rep // most))
 
@@ -425,8 +455,25 @@ def block_counts(q_pos, kv_pos, q_seg, kv_seg, block_q: int, block_k: int,
     return int(np.maximum(hi - lo + 1, 0).sum()), lo.size * steps
 
 
+def _chosen_keys(chosen_ref, kp, block: int):
+    """A query block's choice of key blocks [bq, blocks] (0 / 1, a narrow
+    type) widened to the columns of one kv block: [bq, bk] bool, whether a
+    query chose the block of ``block`` keys that holds the key at position
+    kp [1, bk]. One product with the 0 / 1 matrix "key c is of block n",
+    exact in any type that holds 0 and 1: a key of no block the operand
+    has (padding) is of none."""
+    chosen = chosen_ref[0, 0]                                 # [bq, blocks]
+    first = block * jax.lax.broadcasted_iota(
+        jnp.int32, (chosen.shape[1], kp.shape[1]), 0)
+    of_block = jnp.logical_and(first <= kp, kp < first + block)
+    return jax.lax.dot_general(
+        chosen.astype(jnp.bfloat16), of_block.astype(jnp.bfloat16),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) > 0
+
+
 def _mask_bias(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref, shape, *,
-               causal: bool, use_segments: bool, window: int = 0):
+               causal: bool, use_segments: bool, window: int = 0,
+               choice=None):
     """The mask of one (query block, kv block) pair as what a score takes
     on: 0 where the query sees the key, NEG_INF where it does not (padding
     key, causality, window, another segment). It depends on no head, so a
@@ -434,14 +481,20 @@ def _mask_bias(q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref, shape, *,
     score and score + NEG_INF is NEG_INF exactly in float32, so adding it
     is ``where(mask, score, NEG_INF)`` to the bit, and exp of a masked
     score minus any row maximum or logsumexp is exactly 0 with no second
-    select."""
+    select. ``choice``: (the query block's choice of key blocks, a block's
+    keys), which a query sees BESIDE its window."""
     kp = kv_pos_ref[0][:1, :]                                 # [1, bk]
     mask = jnp.broadcast_to(kp < PAD_POS, shape)   # padding keys, always
     if causal or window:
         qp = q_pos_ref[0][:, :1]                              # [bq, 1]
     if causal:
         mask = jnp.logical_and(mask, kp <= qp)
-    if window:
+    if choice is not None:
+        seen = _chosen_keys(choice[0], kp, choice[1])
+        if window:
+            seen = jnp.logical_or(seen, qp - kp < window)
+        mask = jnp.logical_and(mask, seen)
+    elif window:
         mask = jnp.logical_and(mask, qp - kp < window)
     if use_segments:
         ks = kv_seg_ref[0][:1, :]
@@ -503,13 +556,17 @@ def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
                 q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref,
                 q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, use_segments: bool,
-                n_rep: int, window: int = 0, has_sink: bool = False):
-    # rest: [sink_ref,] o_ref, lse_ref, then scratch: the running state a
-    # head (m, l, acc) and, for a step of several heads, what it makes
-    # once for all of them (_shared).
+                n_rep: int, window: int = 0, has_sink: bool = False,
+                choice_block: int = 0):
+    # rest: [sink_ref,] [chosen_ref,] o_ref, lse_ref, then scratch: the
+    # running state a head (m, l, acc) and, for a step of several heads,
+    # what it makes once for all of them (_shared).
     # Grid (b, kv_h, q blocks, head blocks, kv steps).
     rest = list(rest)
     sink_ref = rest.pop(0) if has_sink else None
+    mask = dict(causal=causal, use_segments=use_segments, window=window)
+    if choice_block:
+        mask["choice"] = (rest.pop(0), choice_block)
     o_ref, lse_ref, m_scr, l_scr, acc_scr, *scratch = rest
     g = q_ref.shape[1]
     heads = _heads_here(3, n_rep, g)
@@ -517,8 +574,9 @@ def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
     lo = lo_ref[pl.program_id(0), pl.program_id(2)]
     hi = hi_ref[pl.program_id(0), pl.program_id(2)]
     # With a window the grid walks blocks FROM lo (grid_ranges says how
-    # many), else every kv block.
-    kv_idx = step + lo if window else step
+    # many), else every kv block: under a choice too, whose blocks lie
+    # anywhere before the window.
+    kv_idx = step + lo if window and not choice_block else step
 
     @pl.when(jnp.logical_and(hi < lo, step == 0))
     def _nothing_to_see():
@@ -536,8 +594,7 @@ def _fwd_kernel(lo_ref, hi_ref,                        # scalar prefetch
 
         shared = _shared(
             (q_pos_ref, kv_pos_ref, q_seg_ref, kv_seg_ref), q_ref, k_ref,
-            v_ref, scratch, causal=causal, use_segments=use_segments,
-            window=window)
+            v_ref, scratch, **mask)
 
         def head(i):
             k, v, bias = shared()
@@ -616,7 +673,7 @@ def flash_fwd_qside(q, q_pos, q_seg, block_q):
 
 def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
                block_q=None, block_k=None, out_dtype=None, qside=None,
-               window=0, sink=None):
+               window=0, sink=None, choice=None, choice_block=0):
     b, sq, h, d = q.shape
     # Values may be narrower or wider than keys (latent attention expanded:
     # 192-wide q and k, 128-wide v): the accumulator and the output take
@@ -639,8 +696,11 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
     kv_pos_p = _pad_to(kv_pos.astype(jnp.int32), sk_p, 1, value=PAD_POS)
     kv_seg_p = (_pad_to(kv_seg.astype(jnp.int32), sk_p, 1, value=0)
                 if use_segments else jnp.zeros_like(kv_pos_p))
+    # A query's chosen blocks lie anywhere before its window: under a
+    # choice the ranges are the causal ones.
+    walk = window if choice is None else 0
     lo, hi, kv_steps = grid_ranges(q_pos, kv_pos, q_seg, kv_seg, block_q,
-                                   block_k, causal, window)
+                                   block_k, causal, walk)
 
     def kv_block(bi, qi, ki, lo_ref, hi_ref):
         # Outside [lo, hi] the index repeats the nearest block inside it —
@@ -648,13 +708,25 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
         # DMA, and pl.when skips the compute. (An empty range points at
         # block 0.)
         first = lo_ref[bi, qi]
-        if window:
+        if walk:
             ki = ki + first
         return jnp.clip(ki, first, jnp.maximum(first, hi_ref[bi, qi]))
 
+    choice_specs, choice_args, choice_blocks = [], (), 0
+    if choice is not None:
+        # [b, kv_h, sq, blocks] as given, the blocks padded to whole lanes
+        # (a block nobody chose). Its block of a grid step does not depend
+        # on the kv step: it is copied in once a query block.
+        choice_blocks = pl.cdiv(choice.shape[3], LANES) * LANES
+        choice_specs = [pl.BlockSpec((1, 1, block_q, choice_blocks),
+                                     lambda bi, kh, qi, hb, ki, *_:
+                                     (bi, kh, qi, 0))]
+        choice_args = (_pad_to(_pad_to(choice, sq_p, 2), choice_blocks, 3),)
+
     # The query heads as [b * kv_h, n_rep, ...]: a KV head's group is one
     # row, blocked G heads a step; G need not divide it (module docstring).
-    g = head_block(n_rep, block_q, block_k, d, dv, sink is not None, window)
+    g = head_block(n_rep, block_q, block_k, d, dv, sink is not None, window,
+                   choice_blocks)
 
     def q_map(bi, kh, qi, hb, ki, *_):
         return (bi * kv_h + kh, hb, qi, 0)
@@ -670,7 +742,8 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, use_segments=use_segments,
-        n_rep=n_rep, window=window, has_sink=sink is not None)
+        n_rep=n_rep, window=window, has_sink=sink is not None,
+        choice_block=choice_block)
     sink_specs, sink_args = [], ()
     if sink is not None:
         # One tile a head, the layout note at the top of the file.
@@ -695,6 +768,7 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
                 pl.BlockSpec((1, 1, block_k, d), kv_map),         # k
                 pl.BlockSpec((1, 1, block_k, dv), kv_map),        # v
                 *sink_specs,
+                *choice_specs,
             ],
             out_specs=[
                 pl.BlockSpec((1, g, block_q, dv), q_map),
@@ -714,11 +788,12 @@ def _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale, causal,
                                  jnp.float32),
         ],
         compiler_params=_ask_vmem("fwd", g, block_q, block_k, d, dv,
-                                  sink is not None),
+                                  sink is not None, choice_blocks),
         interpret=_interpret(),
     )(lo, hi, q_pos_l, _bcast_sublanes(kv_pos_p),
       q_seg_l, _bcast_sublanes(kv_seg_p),
-      qT.reshape(b * kv_h, n_rep, sq_p, d), kT, vT, *sink_args)
+      qT.reshape(b * kv_h, n_rep, sq_p, d), kT, vT, *sink_args,
+      *choice_args)
     out = out.reshape(b, h, sq_p, dv)
     lse = lse.reshape(b, h, sq_p, LANES)
 
@@ -941,6 +1016,8 @@ def flash_attention(
     block_skip: bool = True,
     window: int = 0,
     sink: Optional[jax.Array] = None,  # [h] float
+    choice: Optional[jax.Array] = None,   # [b, kv_h, sq, blocks] 0 / 1
+    choice_block: int = 0,
 ) -> jax.Array:
     """block_q / block_k: None = block_shape's answer for the call (the
     forward's and the backward's may differ: the residual lse is a row,
@@ -949,9 +1026,16 @@ def flash_attention(
     window > 0: a query at position t sees only keys j with t - j <
     window (beside causality), the ranges follow it and the grid shrinks
     to the blocks a window can span (_flash_fwd). sink: one more logit a
-    query head in the softmax, which takes weight and gives no value. Both
-    are the FORWARD's alone: differentiating such a call raises
-    WindowSinkBackward, and under a multi-device mesh it is refused.
+    query head in the softmax, which takes weight and gives no value.
+    choice: which blocks of ``choice_block`` keys (block n: the positions
+    [n choice_block, (n + 1) choice_block)) each query reads BESIDE its
+    window, one choice a KV head for all the heads of its group, in a
+    narrow type that holds 0 and 1 (int8); still causal. The forward then
+    visits the causal ranges and not the window's, and a grid step widens
+    its query block's choice to the kv block's columns once for all its
+    heads (_chosen_keys). All three are the FORWARD's alone:
+    differentiating such a call raises WindowSinkBackward (a choice:
+    BlockChoiceBackward), and under a multi-device mesh it is refused.
 
     The forward needs no hint: it visits the kv blocks its positions
     and segment ids say a query can see (block_ranges), whatever the layout.
@@ -978,18 +1062,23 @@ def flash_attention(
     # producing kernel as a constant (the pallas call has no JVP rule);
     # the differentiable path runs through _flash_core's custom vjp, whose
     # q/k/v args carry the real tangents.
-    if window or sink is not None:
+    if window or sink is not None or choice is not None:
         if _shard_plan(q, k) is not None:
             raise NotImplementedError(
-                "flash attention with a window or a sink runs on one "
-                "device: its per-shard launch is not written")
+                "flash attention with a window, a sink or a block choice "
+                "runs on one device: its per-shard launch is not written")
+        if choice is None:
+            only, more = _forward_only, {}
+        else:
+            only = _chosen_forward_only
+            more = dict(choice=choice, choice_block=choice_block)
         with jax.named_scope("flash.fwd"):
             out, _ = _flash_fwd(
                 jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
                 jax.lax.stop_gradient(v), q_positions, kv_positions,
                 q_segment_ids, kv_segment_ids, scale_v, causal, block_q,
-                block_k, window=window, sink=sink)
-        return _forward_only(out, q, k, v)
+                block_k, window=window, sink=sink, **more)
+        return only(out, q, k, v)
 
     def fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg):
         return _flash_fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg,
@@ -1019,20 +1108,36 @@ class WindowSinkBackward(NotImplementedError):
     """The backward kernels know neither a window nor a sink."""
 
 
-@jax.custom_vjp
-def _forward_only(out, q, k, v):
-    return out
+class BlockChoiceBackward(NotImplementedError):
+    """The backward kernels know no block choice."""
 
 
-def _forward_only_fwd(out, q, k, v):
-    raise WindowSinkBackward(
-        "flash attention's backward kernels mask by causality and segments "
-        "alone and normalise over the keys alone: a call with a window or "
-        "a sink has a forward only (train such layers on the XLA path, "
-        "attention_impl: xla)")
+def _forward_only_raising(error, why: str):
+    """out -> out for a call that has a forward only: differentiating it
+    raises ``error(why)``."""
+    @jax.custom_vjp
+    def _forward_only(out, q, k, v):
+        return out
+
+    def _forward_only_fwd(out, q, k, v):
+        raise error(why)
+
+    _forward_only.defvjp(_forward_only_fwd, lambda res, g: res)
+    return _forward_only
 
 
-_forward_only.defvjp(_forward_only_fwd, lambda res, g: res)
+_forward_only = _forward_only_raising(
+    WindowSinkBackward,
+    "flash attention's backward kernels mask by causality and segments "
+    "alone and normalise over the keys alone: a call with a window or "
+    "a sink has a forward only (train such layers on the XLA path, "
+    "attention_impl: xla)")
+_chosen_forward_only = _forward_only_raising(
+    BlockChoiceBackward,
+    "flash attention's backward kernels mask by causality and segments "
+    "alone: a call whose queries choose their key blocks has a forward "
+    "only (the choice is a step function of q and k: it has no gradient "
+    "to give)")
 
 
 class UnequalWidthsBackward(NotImplementedError):

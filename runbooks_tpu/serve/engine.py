@@ -2383,9 +2383,12 @@ class InferenceEngine:
             return
         from runbooks_tpu.ops.block_sparse_attention import prefill_counts
 
+        cfg = self.cfg
+        shape = cfg.attn_shape("full_attention")
         self._count_sparse("prefill", *prefill_counts(
-            positions, positions >= self._pad_slot, self.cfg.sparse_read,
-            self.max_seq_len + 1))
+            positions, positions >= self._pad_slot, cfg.sparse_read,
+            self.max_seq_len + 1, shape.heads // shape.kv_heads,
+            cfg.flash_block_q, cfg.flash_block_k))
 
     def _count_sparse_decode(self, view: int, before: np.ndarray) -> None:
         """The same for a decode chunk whose program read `view` keys a
@@ -2411,8 +2414,8 @@ class InferenceEngine:
                  "key)"),
                 ("pairs_visited", visited,
                  "Score pairs the sparse core computed (prefill: every "
-                 "(query block, key chunk) its walk visits; decode: the "
-                 "view's keys a live row)"),
+                 "(query block, key block) step of its kernel that runs; "
+                 "decode: the view's keys a live row)"),
                 ("blocks_chosen", chosen,
                  "Key blocks the tokens of long rows read beside their "
                  "windows (a KV head; the initial ones among them)")):

@@ -289,38 +289,191 @@ def test_chunked_lightning_core_matches_its_token_scan():
     assert np.abs(np.asarray(stepped[0] - s0[0])).max() > 0.1
 
 
-def test_sparse_walk_matches_one_masked_softmax():
-    """The prefill's walk by query blocks and key chunks (several of each:
-    2 rows x 200 queries, chunks cut to 64 keys) against the read in one
-    piece, a long row beside a short one whose tail is parked."""
-    import runbooks_tpu.ops.block_sparse_attention as bsa
+# name: (queries, keys beyond them, query heads, block_q, block_k,
+#        exclude_window, heads a grid step, tokens a select step, row 1)
+CORE_CASES = {
+    "a long and a short row": (128, 0, 4, 32, 64, True, None, None, "short"),
+    "a parked tail": (128, 0, 4, 32, 64, True, None, None, "parked"),
+    # (136 queries on 137 keys, a trash slot beyond them, in both.)
+    "queries no block divides": (136, 1, 4, 32, 64, True, None, 96, "short"),
+    "keys no block divides": (136, 1, 4, 32, 64, True, None, 96, "parked"),
+    "window blocks are candidates": (128, 0, 4, 32, 64, False, None, None,
+                                     "long"),
+    "a group the head block does not divide": (128, 0, 6, 32, 64, True, 2,
+                                               None, "short"),
+}
 
-    cfg = toy()
-    sp, rng = cfg.sparse_read, np.random.default_rng(1)
-    b, s, H, g, d = 2, 200, 4, 2, 16
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _core_reference(q, k, v, pos, long_row, sp, exclude):
+    from runbooks_tpu.ops.block_sparse_attention import (
+        block_sparse_attention_reference,
+    )
+
+    return block_sparse_attention_reference(
+        q, k, v, pos, sp, q.shape[-1] ** -0.5, dense_rows=~long_row,
+        exclude_window=exclude)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+def _core_program(q, k, v, pos, long_row, sp, exclude, block_q, block_k,
+                  patched):
+    """(`patched`: what the case put in place of the module's constants,
+    which a kept trace must not outlive.)"""
+    from runbooks_tpu.ops.block_sparse_attention import (
+        compress_keys,
+        sparse_prefill,
+    )
+
+    return sparse_prefill(q, k, v, compress_keys(k, sp), pos, long_row, sp,
+                          q.shape[-1] ** -0.5, exclude, block_q, block_k)
+
+
+@pytest.mark.parametrize("case", list(CORE_CASES))
+def test_sparse_walk_matches_one_masked_softmax(monkeypatch, case):
+    """The prefill's core — one call of the flash forward under each
+    token's choice, interpreted here, at small pinned blocks (several
+    query blocks and key steps) — against the read in one piece
+    (block_sparse_attention_reference: the choice, then one masked softmax
+    over all keys). Row 0 is long (read sparsely: at 128 tokens 14
+    candidates for 3 places); row 1 is long too, or short (read whole, in
+    the same call), or a prompt of 50 whose tail is parked at -1 (output
+    exactly 0). The choice is made in one step, or 48 tokens of a row at a
+    time with a padded last step. The counts are the kernel's: every pair
+    of a step that runs, no fewer than the choices need."""
+    import runbooks_tpu.ops.block_sparse_attention as bsa
+    import runbooks_tpu.ops.flash_attention as fa
+
+    (s, beyond, H, block_q, block_k, exclude, g_step, select,
+     row1) = CORE_CASES[case]
+    sp, rng = toy().sparse_read, np.random.default_rng(1)
+    b, g, d = 2, 2, 16
+    L = s + beyond
     q = jnp.asarray(rng.standard_normal((b, s, H, d)), jnp.float32)
-    k, v = (jnp.asarray(rng.standard_normal((b, s, g, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((b, L, g, d)), jnp.float32)
             for _ in range(2))
     pos = np.broadcast_to(np.arange(s), (b, s)).copy()
-    pos[1, 50:] = -1
-    pos = jnp.asarray(pos)
-    long_row = jnp.asarray([True, False])
-    want = bsa.block_sparse_attention_reference(
-        q, k, v, pos, sp, d ** -0.5, dense_rows=~long_row)
-    old = bsa.K_CHUNK
-    bsa.K_CHUNK = 64
-    try:
-        got = bsa.sparse_prefill(q, k, v, bsa.compress_keys(k, sp), pos,
-                                 long_row, sp, d ** -0.5)
-        needed, visited, _ = bsa.prefill_counts(
-            np.asarray(pos), np.asarray(pos) < 0, sp, s)
-    finally:
-        bsa.K_CHUNK = old
+    if row1 == "parked":
+        pos[1, 50:] = -1
+    long_row = jnp.asarray([True, row1 == "long"])
+    args = (q, k, v, jnp.asarray(pos), long_row, sp)
+    want = _core_reference(*args, exclude)
+    if g_step:
+        monkeypatch.setattr(fa, "head_block",
+                            lambda n_rep, *a: min(n_rep, g_step))
+    if select:
+        monkeypatch.setattr(bsa, "SELECT_TOKENS", select)
+    got = _core_program(*args, exclude, block_q, block_k, (g_step, select))
     np.testing.assert_allclose(got, want, atol=1e-5)
-    assert not np.asarray(got[1, 50:]).any()
-    # Row 0 needs far less than a causal mask's pairs; the walk computes
-    # every causal (query block, key chunk) pair of both rows.
-    assert needed < 200 * 201 // 2 + 50 * 51 // 2 < visited
+    if row1 == "parked":
+        assert not np.asarray(got[1, 50:]).any()
+    if not exclude:
+        # The other reading of the window's blocks is another function.
+        assert np.abs(np.asarray(
+            got - _core_reference(*args, True))).max() > 1e-3
+    needed, visited, _ = bsa.prefill_counts(pos, pos < 0, sp, L, H // g,
+                                            block_q, block_k)
+    causal = sum(n * (n + 1) // 2 for n in (pos.max(axis=-1) + 1))
+    assert needed <= visited and causal < visited
+    if row1 == "long":
+        assert needed < causal
+
+
+def test_flash_forward_under_a_choice_refuses_a_backward_by_name():
+    from runbooks_tpu.ops.flash_attention import (
+        BlockChoiceBackward,
+        flash_attention,
+    )
+
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((1, 32, 4, 16)), jnp.float32)
+    k = v = jnp.asarray(rng.standard_normal((1, 32, 2, 16)), jnp.float32)
+    pos = jnp.arange(32)[None]
+    chosen = jnp.ones((1, 2, 32, 4), jnp.int8)
+    with pytest.raises(BlockChoiceBackward, match="forward only"):
+        jax.eval_shape(jax.grad(lambda q: flash_attention(
+            q, k, v, pos, pos, None, None, True, None, 16, 16, window=8,
+            choice=chosen, choice_block=8).sum()), q)
+
+
+@pytest.mark.parametrize("rows,queries,real", [
+    (1, 16384, 14592), (1, 16384, 13312), (8, 8192, 8192), (1, 8192, 6000)])
+def test_the_prefill_counter_is_the_kernels(rows, queries, real):
+    """`visited` of a dispatch is block_q x block_k pairs for every grid
+    step of the core whose body runs: for each row and query block the key
+    steps up to the one that holds its last real position (the causal
+    ranges; a parked block runs none), at the block shape the call
+    compiles with. Here for the long cell's dispatches: a 14 592-token
+    prompt in the 16 384 bucket is 225 steps of 512 x 1024."""
+    import runbooks_tpu.ops.block_sparse_attention as bsa
+    from runbooks_tpu.ops.flash_attention import block_shape
+
+    sp = get_config("minicpm-sala").sparse_read
+    keys, group = 16385, 16
+    pos = np.broadcast_to(np.arange(queries), (rows, queries)).copy()
+    pos[:, real:] = keys - 1                    # parked, as the engine parks
+    parked = pos >= keys - 1
+    block_q, block_k = block_shape("fwd", queries, keys, group, sp.window)
+    assert (block_q, block_k) == (512, 1024)
+    steps = 0
+    for row in np.where(parked, -1, pos):
+        for i in range(0, queries, block_q):
+            last = row[i:i + block_q].max()
+            steps += last // block_k + 1 if last >= 0 else 0
+    needed, visited, chosen = bsa.prefill_counts(pos, parked, sp, keys,
+                                                 group)
+    assert visited == steps * block_q * block_k
+    assert (rows, real) != (1, 14592) or steps == 225
+    assert 0 < needed <= visited
+    # A row under dense_len reads every earlier key and chooses nothing.
+    assert (chosen > 0) == (real >= sp.dense_len)
+
+
+def test_a_near_tie_in_the_choice_falls_on_one_of_the_two():
+    """Two candidate blocks whose scores differ by less than a bfloat16
+    step: keys 1 + 2**-10 (row 0; row 1: 1 - 2**-10) along the query's
+    direction in block 12 beside keys 1 in block 9. The reference ranks
+    the float32 keys and takes the larger; the program ranks the keys AS
+    STORED, where both are 1.0, and its tie goes to the lower index. On
+    either side the two choices are equal but for those two blocks, each
+    takes exactly one of them, and the layer's output stays within the
+    served check's limits (benchmark/configs/minicpm-sala.json `limits`:
+    mean 0.0003, max 0.01) of the reference's: a block of nearly the same
+    score carries nearly the same, small weight. (The shapes of the
+    kernel's first case above, whose compiled functions this reuses.)"""
+    import runbooks_tpu.ops.block_sparse_attention as bsa
+
+    sp = toy().sparse_read
+    s, H, g, d, led, tied = 128, 4, 2, 16, [3, 6], [9, 12]
+    scale = d ** -0.5
+    q = jnp.zeros((2, s, H, d), jnp.float32).at[..., 0].set(64.0)
+    v = jnp.asarray(np.random.default_rng(0).standard_normal((2, s, g, d)),
+                    jnp.float32)
+    # (A compressed key that straddles a block's edge is the mean of both
+    # sides: 0.75 beside the two leading blocks, below the tie.)
+    along = np.zeros((2, s // sp.block), np.float32)
+    along[:, led], along[:, tied[0]] = 1.5, 1.0
+    along[:, tied[1]] = 1.0 + 2.0 ** -10, 1.0 - 2.0 ** -10
+    k = jnp.zeros((2, s, g, d), jnp.float32).at[..., 0].set(
+        np.repeat(along, sp.block, axis=1)[:, :, None])
+    stored = k.astype(jnp.bfloat16).astype(jnp.float32)
+    assert (np.asarray(stored[:, tied[1] * sp.block, :, 0]) == 1.0).all()
+    pos = jnp.broadcast_to(jnp.arange(s), (2, s))
+    select = jax.jit(lambda keys: bsa.select_blocks(
+        q, bsa.compress_keys(keys, sp), pos, sp, bsa.n_blocks(s, sp), scale))
+    theirs, ours = np.asarray(select(k)), np.asarray(select(stored))
+    differ = np.argwhere(theirs != ours)
+    assert set(differ[:, -1]) == set(tied)
+    # The larger key in the later block is the side where the two part.
+    assert set(differ[:, 0]) == {0}
+    for pick in (theirs, ours):
+        assert (pick[:, -1][..., tied].sum(axis=-1) == 1).all()
+        assert pick[:, -1][..., led].all()
+    args = (q, v, pos, jnp.asarray([True, True]), sp, True)
+    want = _core_reference(q, k, *args[1:])
+    got = _core_program(q, stored, *args[1:], 32, 64, (None, None))
+    gap = np.abs(np.asarray(got - want))
+    assert gap.max() <= 0.01 and gap.mean() <= 0.0003
 
 
 # --------------------------------------------------------------------------

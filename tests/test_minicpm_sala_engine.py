@@ -28,6 +28,24 @@ def exact_matmuls():
         yield
 
 
+@pytest.fixture(scope="module")
+def warmed():
+    """One engine, built and warmed once, for the tests that only send it
+    requests (two slots of 128 under seed 3). Warmed under the tests' own
+    matmul precision, which a compiled program is kept by; its steady
+    claim is released at once, so that what other tests compile is not
+    counted against it."""
+    from runbooks_tpu.serve.engine import InferenceEngine
+
+    cfg = toy()
+    with jax.default_matmul_precision("highest"):
+        eng = InferenceEngine(cfg, seeded(cfg, 3), max_slots=2,
+                              max_seq_len=128, decode_chunk=4)
+        eng.warmup()
+    eng.release_steady()
+    return eng
+
+
 def test_prefill_then_decode_through_the_cache_matches_reference():
     """Two rows prefilled in one padded call (position-scatter mode,
     padding parked and masked): one of 40 tokens, read densely, one of 100,
@@ -118,7 +136,7 @@ def test_admission_dispatches_only_warmed_shapes():
     )
 
     cfg = toy()
-    eng = InferenceEngine(cfg, seeded(cfg, 0), max_slots=4, max_seq_len=128)
+    eng = InferenceEngine(cfg, seeded(cfg, 3), max_slots=4, max_seq_len=128)
     seen = []
     eng._prefill_group = lambda bucket, group, pkey=None: seen.append(
         (prefill_rows(len(group), eng.max_slots), bucket))
@@ -141,13 +159,14 @@ def test_admission_dispatches_only_warmed_shapes():
     assert grouped > 20     # the grid does fill [max_slots, b] programs
 
 
-def test_engine_serves_short_and_long_rows_and_counts_the_sparse_read():
+def test_engine_serves_short_and_long_rows_and_counts_the_sparse_read(
+        warmed):
     """Three requests on two slots: a prompt of 50 that decodes across
     dense_len, a prompt of 90 (read sparsely from its prefill on), a third
     that takes a used slot. Greedy tokens are the reference's best; the
     gauges and the sparse read's counters say what was held and read."""
     from runbooks_tpu.obs import metrics as obs_metrics
-    from runbooks_tpu.serve.engine import InferenceEngine, Request
+    from runbooks_tpu.serve.engine import Request
 
     def counter(name, program):
         snap = obs_metrics.REGISTRY.render()
@@ -155,9 +174,7 @@ def test_engine_serves_short_and_long_rows_and_counts_the_sparse_read():
             f'{name}{{program="{program}"}}')), None)
         return float(line.split()[-1]) if line else 0.0
 
-    cfg = toy()
-    eng = InferenceEngine(cfg, seeded(cfg, 3), max_slots=2, max_seq_len=128,
-                          decode_chunk=4)
+    cfg, eng = toy(), warmed
     names = ("serve_bsa_pairs_needed_total", "serve_bsa_pairs_visited_total",
              "serve_bsa_blocks_chosen_total")
     base = {(n, pr): counter(n, pr) for n in names
@@ -191,29 +208,27 @@ def test_engine_serves_short_and_long_rows_and_counts_the_sparse_read():
     # the bucket of 128) and the row of 50 in the bucket of 64, which a
     # row of 64 could fill (read densely, every earlier key needed); the
     # bucket of 32 reads through the dense path and counts nothing. The
-    # walk computes every query of a bucket against the 129 slots' one
-    # chunk; the long row's choices need fewer pairs than a causal mask's.
+    # kernel's one step a bucket computes every query of the bucket
+    # against the 129 slots; the long row's choices need fewer pairs than
+    # a causal mask's.
     assert got["serve_bsa_pairs_visited_total", "prefill"] \
         == (128 + 64) * 129
     assert 50 * 51 // 2 < got["serve_bsa_pairs_needed_total", "prefill"] \
         < 90 * 91 // 2 + 50 * 51 // 2
 
 
-def test_warmup_compiles_the_programs_the_requests_then_use():
+def test_warmup_compiles_the_programs_the_requests_then_use(warmed):
     """After the warm-up requests of different buckets (one beyond
     dense_len, one that crosses it), decode chunks of every view and a slot
     that changes hands compile nothing; the census names the shapes, and
     the largest bucket has no [max_slots, b] program."""
     from runbooks_tpu.obs import device as obs_device
-    from runbooks_tpu.serve.engine import InferenceEngine, Request
+    from runbooks_tpu.serve.engine import Request
 
     sentinel = obs_device.SENTINEL
     if not sentinel.install():
         pytest.skip("jax.monitoring unavailable; sentinel cannot verify")
-    cfg = toy()
-    eng = InferenceEngine(cfg, seeded(cfg, 1), max_slots=2, max_seq_len=128,
-                          decode_chunk=4)
-    eng.warmup()
+    cfg, eng = toy(), warmed
     census = eng.warmup_census
     assert census["prefill_shapes"] == [list(s) for s in eng.dispatch_shapes]
     assert [2, 128] not in census["prefill_shapes"] \
@@ -223,16 +238,13 @@ def test_warmup_compiles_the_programs_the_requests_then_use():
     # TPU); the sparse core's programs are not among its census.
     assert not any(name in eng.flash_blocks
                    for name in ("prefill_b64", "prefill_b128"))
-    try:
-        total = sentinel.total
-        reqs = [Request(prompt_tokens=tokens_for(cfg, n, n).tolist(),
-                        max_tokens=m, temperature=0.0)
-                for n, m in ((60, 8), (100, 3), (9, 5), (70, 2))]
-        eng.generate(reqs)
-        assert [len(r.output_tokens) for r in reqs] == [8, 3, 5, 2]
-        assert sentinel.total == total, "compiled under traffic"
-    finally:
-        eng.release_steady()
+    total = sentinel.total
+    reqs = [Request(prompt_tokens=tokens_for(cfg, n, n).tolist(),
+                    max_tokens=m, temperature=0.0)
+            for n, m in ((60, 8), (100, 3), (9, 5), (70, 2))]
+    eng.generate(reqs)
+    assert [len(r.output_tokens) for r in reqs] == [8, 3, 5, 2]
+    assert sentinel.total == total, "compiled under traffic"
 
 
 @pytest.mark.parametrize("options,text", [
@@ -250,7 +262,7 @@ def test_engine_refusals_name_the_layers(options, text):
     from runbooks_tpu.serve.engine import InferenceEngine
 
     cfg = toy()
-    p = seeded(cfg, 0)
+    p = seeded(cfg, 3)
     kw = dict(max_slots=2, max_seq_len=64)
     with pytest.raises(ValueError, match=text):
         if options == "paged":
@@ -278,7 +290,7 @@ def test_a_sparse_read_alone_is_refused_by_its_own_name():
     cfg = get_config("debug", sparse_block=8, sparse_topk=4, sparse_window=16,
                      sparse_init_blocks=1, sparse_kernel=4, sparse_stride=2,
                      sparse_dense_len=64)
-    p = init_params(cfg, jax.random.key(0))
+    p = jax.jit(lambda key: init_params(cfg, key))(jax.random.key(0))
     for feature in ("speculative decoding", "an adapter pool",
                     "kv_paging: paged", "quantize_kv",
                     "a tensor mesh axis > 1"):
