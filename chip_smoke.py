@@ -458,7 +458,7 @@ def agree_phase(sz: dict, chips: int, tiny: bool, hw,
                            "--child", "agree", "--chips", str(chips)]
                  + (["--tiny"] if tiny else []), child_env(chips, tiny))
     try:
-        rc = proc.wait(600)
+        rc = proc.wait(900)
     finally:
         stop(proc)
     lines = json_lines(proc.log_path)
@@ -715,6 +715,13 @@ def agree_child(sz: dict, chips: int, tiny: bool) -> int:
     out["tpu_custom_call"] = {"prefill": "tpu_custom_call" in prefill_txt,
                               "train_step": "tpu_custom_call" in step_txt}
 
+    # (3b) The gated delta rule's prefill kernel (ops/gated_delta.py) at
+    # olmo-hybrid-7b's widths, in float32 where it must agree with the
+    # recurrence to round-off. One chip's check: the kernel is the same
+    # on every shard of a mesh.
+    if chips == 1:
+        out.update(gated_delta_agreement(tiny, rel_l2))
+
     # (4) block_until_ready really waits here (the benches sync by pulling
     # a scalar; both must see the same wall time for the same work).
     n = 512 if tiny else 8192
@@ -750,6 +757,67 @@ def agree_child(sz: dict, chips: int, tiny: bool) -> int:
         print(f"agree: FAILED {bad}", flush=True)
         return 1
     return 0
+
+
+def gated_delta_agreement(tiny: bool, rel_l2) -> dict:
+    """{check: {"rel_l2", "tol"}}: the chunked kernel against the
+    token-by-token recurrence (30 heads of 96 x 192, 2048 tokens, an
+    initial state, a ragged mask), and olmo-hybrid-7b at one period of
+    its pattern, prefill then decode through the cache against the whole
+    forward without one."""
+    import jax
+    import jax.numpy as jnp
+
+    from runbooks_tpu.models.config import get_config
+    from runbooks_tpu.models.transformer import KVCache, forward, init_params
+    from runbooks_tpu.ops.gated_delta import (
+        gated_delta_chunked,
+        gated_delta_reference,
+        l2_normalize,
+    )
+
+    heads, dk, dv, s = (3, 24, 40, 130) if tiny else (30, 96, 192, 2048)
+    ks = jax.random.split(jax.random.key(5), 6)
+    q = l2_normalize(jax.nn.silu(
+        jax.random.normal(ks[0], (2, s, heads, dk)) + 0.5)) * dk ** -0.5
+    k = l2_normalize(jax.nn.silu(
+        jax.random.normal(ks[1], (2, s, heads, dk)) + 0.5))
+    v = jax.random.normal(ks[2], (2, s, heads, dv))
+    g = -jnp.exp(jax.random.uniform(ks[3], (2, s, heads), minval=-6.0,
+                                    maxval=0.5))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (2, s, heads)))
+    state = jax.random.normal(ks[5], (2, heads, dk, dv))
+    mask = jnp.arange(s)[None, :] < jnp.array([s, (5 * s) // 8 + 3])[:, None]
+    with jax.default_matmul_precision("highest"):
+        got_o, got_s = jax.jit(gated_delta_chunked)(q, k, v, g, beta, state,
+                                                    mask)
+        want_o, want_s = jax.jit(gated_delta_reference)(q, k, v, g, beta,
+                                                        state, mask)
+    seen = mask[..., None, None]
+    out = {"gated_delta_kernel_vs_recurrence_f32": {
+        "rel_l2": max(rel_l2(jnp.where(seen, got_o, 0.0),
+                             jnp.where(seen, want_o, 0.0)),
+                      rel_l2(got_s, want_s)), "tol": 1e-4}}
+
+    cfg = get_config("debug-hybrid" if tiny else "olmo-hybrid-7b",
+                     num_layers=4, dtype="float32", param_dtype="float32")
+    prompt, steps = (40, 3) if tiny else (1000, 4)
+    params = jax.jit(lambda key: init_params(cfg, key))(jax.random.key(6))
+    toks = jax.random.randint(jax.random.key(7), (1, prompt + steps), 1,
+                              cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        whole = jax.jit(lambda p, t: forward(cfg, p, t)[0])(params, toks)
+        step = jax.jit(lambda p, t, c: forward(cfg, p, t, cache=c))
+        cache = KVCache.create(cfg, 1, 2 * prompt)
+        parts = []
+        for lo, hi in ((0, prompt),) + tuple(
+                (i, i + 1) for i in range(prompt, prompt + steps)):
+            logits, cache = step(params, toks[:, lo:hi], cache)
+            parts.append(logits)
+    out["gated_delta_prefill_then_decode_f32"] = {
+        "rel_l2": rel_l2(jnp.concatenate(parts, 1)[:, prompt - 1:],
+                         whole[:, prompt - 1:]), "tol": 1e-3}
+    return out
 
 
 if __name__ == "__main__":

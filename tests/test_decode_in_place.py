@@ -547,3 +547,52 @@ def test_held_part_compiles_at_the_cells_shapes(preset, held, window,
     assert not re.search(r" sort\(", text)
     all_rows = re.findall(rf"\[{T * k},{h}\]", text)
     assert bool(all_rows) == (window == T * k)
+
+
+# --------------------------------------------------------------------------
+# The gated delta rule's prefill kernel at olmo-hybrid-7b's widths
+# --------------------------------------------------------------------------
+
+GATED_DELTA_CASES = {
+    # name: (rows, tokens, dtype, the caller's matmul precision or None)
+    "[1, 2048] prefill": (1, 2048, "bfloat16", None),
+    "[8, 1024] prefill": (8, 1024, "bfloat16", None),
+    "[1, 16] prefill": (1, 16, "bfloat16", None),
+    # What chip_smoke.py and a float32 scratch check run it under: Mosaic
+    # refuses a bfloat16 product asked at float32 precision, so the
+    # kernel's own passes must not take the caller's.
+    "[1, 2048] under highest": (1, 2048, "bfloat16", "highest"),
+    "[2, 2048] in float32 under highest": (2, 2048, "float32", "highest"),
+}
+
+
+@pytest.mark.parametrize("name", GATED_DELTA_CASES)
+def test_gated_delta_kernel_compiles_at_published_widths(name, one_chip,
+                                                         monkeypatch):
+    """Mosaic takes the chunked kernel at the launch shape kernel_shape
+    gives (its VMEM included, which the interpreter never checks): one
+    custom call whose results are o heads-major and the state."""
+    import runbooks_tpu.ops.gated_delta as gd
+    import runbooks_tpu.utils.hw as hw
+
+    b, s, dtype, precision = GATED_DELTA_CASES[name]
+    heads, dk, dv = 30, 96, 192
+    monkeypatch.setattr(hw, "on_tpu", lambda: True)
+    dtype = jnp.dtype(dtype)
+
+    def like(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    with jax.default_matmul_precision(precision or "default"):
+        text = jax.jit(gd.gated_delta_chunked).lower(
+            like((b, s, heads, dk), dtype), like((b, s, heads, dk), dtype),
+            like((b, s, heads, dv), dtype), like((b, s, heads), jnp.float32),
+            like((b, s, heads), jnp.float32),
+            like((b, heads, dk, dv), jnp.float32),
+            like((b, s), jnp.bool_)).compile().as_text()
+    calls = [re.sub(r"\{[^{}]*\}", "", m) for m in re.findall(
+        r"= (\([^=]*?\)|\S+) custom-call\([^\n]*tpu_custom_call", text)]
+    padded = gd.kernel_shape(s, heads, gd.CHUNK, dtype.itemsize)[2]
+    short = {"bfloat16": "bf16", "float32": "f32"}[dtype.name]
+    assert calls == [f"({short}[{b},{heads},{padded},{dv}], "
+                     f"f32[{b},{heads},{dk},{dv}])"]

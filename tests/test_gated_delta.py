@@ -7,11 +7,15 @@ import numpy as np
 import pytest
 
 from runbooks_tpu.ops.gated_delta import (
+    CHUNK,
+    _chunked_plain,
     _inv_unit_lower,
+    _inv_unit_lower_vmem,
     causal_conv,
     gated_delta_chunked,
     gated_delta_reference,
     gated_delta_step,
+    kernel_shape,
     l2_normalize,
 )
 
@@ -86,6 +90,111 @@ def test_unit_lower_inverse(n):
     assert float(jnp.max(jnp.abs(t @ (eye + a) - eye))) < 1e-3 * float(
         jnp.max(jnp.abs(t)))
     assert jnp.array_equal(jnp.triu(t, 1), jnp.zeros_like(t))
+
+
+@pytest.mark.parametrize("n", [16, 32, 64])
+def test_the_kernels_inverse_is_the_plain_one(n):
+    # The same blocked scheme on the values a kernel holds (it runs as
+    # plain jax.numpy outside one): float32 round-off apart.
+    a = 0.3 * jnp.tril(jax.random.normal(jax.random.key(n), (3, n, n)), -1)
+    got, want = _inv_unit_lower_vmem(a), _inv_unit_lower(a)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6 * float(
+        jnp.max(jnp.abs(want)))
+    assert jnp.array_equal(jnp.triu(got, 1), jnp.zeros_like(got))
+
+
+def plain(q, k, v, g, beta, state, mask=None):
+    """The kernel's oracle, shape for shape: the chunked form in plain
+    jax.numpy, fed what the public entry feeds the kernel."""
+    if mask is not None:
+        g = jnp.where(mask[..., None], g, 0.0)
+        beta = jnp.where(mask[..., None], beta, 0.0)
+    return _chunked_plain(q, k, v, g, beta, state, CHUNK)
+
+
+@pytest.mark.parametrize("heads,dk,dv", [(3, 24, 40), (30, 96, 192)])
+def test_kernel_is_the_plain_chunked_form(heads, dk, dv):
+    q, k, v, g, beta, state = inputs(2, 130, heads, dk, dv, seed=1)
+    mask = jnp.arange(130)[None, :] < jnp.array([130, 77])[:, None]
+    got_o, got_s = jax.jit(gated_delta_chunked)(q, k, v, g, beta, state,
+                                                mask)
+    want_o, want_s = jax.jit(plain)(q, k, v, g, beta, state, mask)
+    diff = jnp.where(mask[..., None, None], got_o - want_o, 0.0)
+    assert float(jnp.max(jnp.abs(diff))) < 5e-6
+    assert float(jnp.max(jnp.abs(got_s - want_s))) < 2e-5
+
+
+@pytest.mark.parametrize("s,valid", [(100, 70), (64, 1), (1, 1)])
+def test_a_prompt_may_end_inside_a_chunk(s, valid):
+    # A bucket of s tokens holding a prompt of `valid`: the state is the
+    # recurrence's after `valid` tokens, whatever the padding holds.
+    q, k, v, g, beta, state = inputs(1, s, seed=2)
+    mask = jnp.arange(s)[None, :] < valid
+    got_o, got_s = jax.jit(gated_delta_chunked)(q, k, v, g, beta, state,
+                                                mask)
+    cut = lambda x: x[:, :valid]  # noqa: E731
+    want_o, want_s = jax.jit(gated_delta_reference)(
+        cut(q), cut(k), cut(v), cut(g), cut(beta), state)
+    assert float(jnp.max(jnp.abs(got_o[:, :valid] - want_o))) < 5e-6
+    assert float(jnp.max(jnp.abs(got_s - want_s))) < 2e-5
+
+
+def test_gradients_are_the_plain_forms():
+    # The kernel has no backward of its own: jax.grad through the public
+    # entry runs the plain form's.
+    q, k, v, g, beta, state = inputs(2, 70, seed=3)
+    mask = jnp.arange(70)[None, :] < jnp.array([70, 40])[:, None]
+    weigh = jax.random.normal(jax.random.key(9), (2, 70, 3, 40))
+
+    def scalar(fn):
+        def of(q, k, v, g, beta, state):
+            o, s = fn(q, k, v, g, beta, state, mask)
+            return jnp.sum(o * weigh) + jnp.sum(jnp.square(s))
+        return of
+
+    every = tuple(range(6))
+    got = jax.jit(jax.grad(scalar(gated_delta_chunked), every))(
+        q, k, v, g, beta, state)
+    want = jax.jit(jax.grad(scalar(plain), every))(q, k, v, g, beta, state)
+    for name, a, b in zip("q k v g beta state".split(), got, want):
+        scale = float(jnp.max(jnp.abs(b))) or 1.0
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-5 * scale, name
+    # An invalid token's decay and write strength move nothing.
+    assert not bool(jnp.any(got[3][1, 40:])) and not bool(
+        jnp.any(got[4][1, 40:]))
+
+
+@pytest.mark.parametrize("bucket,shape", [
+    (2048, (4, 10, 2048)), (1024, (4, 10, 1024)), (16, (1, 10, 64))])
+def test_launch_shape_of_the_cells_prefill_buckets(bucket, shape):
+    # olmo-hybrid-7b: 30 linear heads, buckets 16 .. 2048. (chunks a grid
+    # step, heads a grid step, padded tokens): a rule of shapes alone.
+    assert kernel_shape(bucket, 30) == shape
+    cs, hb, padded = shape
+    assert padded % (cs * CHUNK) == 0 and 30 % hb == 0 and padded >= bucket
+
+
+@pytest.mark.parametrize("heads,mesh_axes", [
+    (4, {"fsdp": 2, "tensor": 2}),      # batch and heads both shard
+    (3, {"fsdp": 2, "tensor": 2}),      # heads do not divide: whole a shard
+])
+def test_kernel_runs_per_shard_under_a_mesh(heads, mesh_axes):
+    # A TPU refuses to partition a Mosaic kernel: under a multi-device
+    # mesh the launch is a shard_map, and its results are the unsharded
+    # call's.
+    from runbooks_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    q, k, v, g, beta, state = inputs(2, 70, heads=heads, seed=4)
+    mask = jnp.arange(70)[None, :] < jnp.array([70, 33])[:, None]
+    want_o, want_s = jax.jit(gated_delta_chunked)(q, k, v, g, beta, state,
+                                                  mask)
+    with jax.set_mesh(make_mesh(MeshConfig(**mesh_axes),
+                                devices=jax.devices()[:4])):
+        got_o, got_s = jax.jit(gated_delta_chunked)(q, k, v, g, beta, state,
+                                                    mask)
+    diff = jnp.where(mask[..., None, None], got_o - want_o, 0.0)
+    assert float(jnp.max(jnp.abs(diff))) < 1e-6
+    assert float(jnp.max(jnp.abs(got_s - want_s))) < 1e-6
 
 
 def test_conv_tail_is_taken_at_the_last_valid_token():
