@@ -84,7 +84,7 @@ class AttnShape(NamedTuple):
 
 MOE_ROUTERS = ("softmax", "sigmoid")
 # The mixers a "linear_attention" layer may be (ModelConfig.linear_mixer).
-LINEAR_MIXERS = ("gated_delta", "lightning")
+LINEAR_MIXERS = ("gated_delta", "lightning", "kda")
 
 
 class SparseRead(NamedTuple):
@@ -159,7 +159,7 @@ class ModelConfig:
     # Layers BEFORE the period scan with a dense FFN of intermediate_size
     # (params["leading_layers"]); the periods take num_layers minus these.
     # Their token mixer is of leading_kind: "" = the period's attention
-    # kind, or "conv" where the pattern has such layers.
+    # kind, or "conv" / "linear_attention" where the pattern has such layers.
     leading_dense_layers: int = 0
     leading_kind: str = ""
 
@@ -259,8 +259,15 @@ class ModelConfig:
     # "lightning": the decay-only recurrence S_t = lambda_h S_{t-1} + k_t
     # v_t^T (ops/lightning_attention.py): a QK norm a head, no convolution
     # (linear_conv_kernel 0), an output norm over all the heads and an
-    # elementwise sigmoid gate; a row keeps the state alone.
+    # elementwise sigmoid gate; a row keeps the state alone. "kda": Kimi
+    # Delta Attention (ops/kda.py), the delta rule with a decay a CHANNEL of
+    # the key (g [.., H, d_k], from a low-rank map of linear_gate_rank), a
+    # low-rank sigmoid output gate behind the head-wise output norm; a state
+    # and a conv tail a row, as the gated delta rule's.
     linear_mixer: str = "gated_delta"
+    # KDA's two low-rank maps (the decay's and the output gate's): hidden ->
+    # linear_gate_rank -> heads x head width.
+    linear_gate_rank: int = 0
     # Lightning's decay lambda_h = exp(-2^(-8 (h + 1) / H) c_l), c_l = 1 -
     # l / lightning_decay_layers + 1e-5 with l the layer's index as run
     # (0 = no layer factor, c_l = 1).
@@ -410,11 +417,12 @@ class ModelConfig:
             if self.conv_kernel < 2:
                 raise ValueError("conv layers need conv_kernel >= 2")
         if self.leading_kind not in ("", self.attention_kind) and not (
-                self.leading_kind == "conv" and "conv" in kinds):
+                self.leading_kind in ("conv", "linear_attention")
+                and self.leading_kind in kinds):
             raise ValueError(
                 f"leading_kind {self.leading_kind!r}: the leading layers "
-                "are of the period's attention kind, or conv layers of a "
-                "pattern that has them")
+                "are of the period's attention kind, or conv or "
+                "linear_attention layers of a pattern that has them")
         if (self.num_layers - self.leading_dense_layers) % len(kinds) \
                 or self.leading_dense_layers >= self.num_layers:
             raise ValueError(
@@ -500,6 +508,14 @@ class ModelConfig:
             if self.linear_key_head_dim % 2:
                 raise ValueError(
                     "lightning's rotary needs an even linear_key_head_dim")
+        if self.kda and self.linear_gate_rank < 1:
+            raise ValueError(
+                "linear_mixer: kda needs linear_gate_rank >= 1 (the width "
+                "of its two low-rank gates)")
+        if self.leading_kind == "linear_attention" and self.lightning:
+            raise ValueError(
+                "a leading lightning layer is not written: its decay "
+                "depends on the layer's index among the periods' layers")
         if self.attn_gate_width not in ("head", "element"):
             raise ValueError(
                 f"unknown attn_gate_width {self.attn_gate_width!r}; "
@@ -662,6 +678,11 @@ class ModelConfig:
         return self.has_linear_attention and self.linear_mixer == "lightning"
 
     @property
+    def kda(self) -> bool:
+        """The linear-attention layers are Kimi Delta Attention."""
+        return self.has_linear_attention and self.linear_mixer == "kda"
+
+    @property
     def sparse_read(self) -> Optional[SparseRead]:
         """The full-attention layers' sparse read, or None (dense)."""
         if not self.sparse_topk:
@@ -768,15 +789,28 @@ class ModelConfig:
         out; the a / b heads; conv; A_log, dt_bias; the output norm (one
         head's width, shared by the heads). Lightning: q, k, v, gate, out;
         the QK norms (a head's width each) and the output norm (all the
-        heads')."""
+        heads'). KDA: q, k, v, out; the two low-rank gates; the b head;
+        conv; A_log (a head), dt_bias (a channel); the output norm."""
         h, H = self.hidden_size, self.linear_num_heads
         kd, vd = self.linear_key_dim, self.linear_value_dim
         mats = h * (2 * kd + 2 * vd) + vd * h
         if self.lightning:
             return mats + 2 * self.linear_key_head_dim + vd
+        if self.kda:
+            return (self._kda_matrices()
+                    + self.linear_conv_kernel * self.linear_conv_dim
+                    + H + kd + self.linear_value_head_dim)
         return (mats + 2 * h * H
                 + self.linear_conv_kernel * self.linear_conv_dim
                 + 2 * H + self.linear_value_head_dim)
+
+    def _kda_matrices(self) -> int:
+        """W_q, W_k, W_v, W_o, the two low-rank gates and W_beta of one
+        KDA mixer."""
+        h, r = self.hidden_size, self.linear_gate_rank
+        kd, vd = self.linear_key_dim, self.linear_value_dim
+        return (h * (2 * kd + vd) + vd * h + 2 * h * r + r * (kd + vd)
+                + h * self.linear_num_heads)
 
     def _short_conv_params(self) -> int:
         """W_in [h, 3h], W_out [h, h] and the kernel of one conv layer."""
@@ -866,6 +900,12 @@ class ModelConfig:
         if self.lightning:
             # The update k v^T and the read S^T q.
             linear = 2 * (h * (2 * kd + 2 * vd) + vd * h) + 4 * state
+        elif self.kda:
+            # The delta rule (S^T k, the rank-one update, S^T q) and the
+            # decay's multiply an element of the state.
+            linear = (2 * self._kda_matrices()
+                      + 2 * self.linear_conv_kernel * self.linear_conv_dim
+                      + 7 * state)
         else:
             # The delta rule itself: S^T k, the rank-one update, S^T q.
             linear = (2 * (h * (2 * kd + 2 * vd) + vd * h
@@ -1104,6 +1144,41 @@ def _minicpm_sala(name, v=73448, h=4096, i=16384, periods=8, q=32, kv=2,
     )
 
 
+def _kimi_linear(name, v=163840, h=2304, i=9216, periods=6, q=32, s=1048576,
+                 nope=128, rope=64, vd=128, rank=512, lin_heads=32, lin_d=128,
+                 gate_rank=128, experts=256, top_k=8, moe_i=1024):
+    # Kimi Delta Attention layers (the delta rule with a decay a channel,
+    # low-rank gates, a short convolution) three to one beside latent
+    # attention with NO rotary (direct query projection, no QK norm); one
+    # leading KDA layer with a dense FFN, then sparse layers: sigmoid router
+    # with a selection bias, the chosen weights renormalised and times
+    # 2.446, one shared expert (docs/hybrid-models.md,
+    # docs/sparse-latent-models.md). The published 27 layers are K then
+    # (K K F K) x 6 then K F: behind the leading layer lie six whole
+    # periods and K F, a period's remainder that a repeated pattern cannot
+    # say, so the preset is 1 + 6 x 4 = 25 layers. head_dim is the cached
+    # width kv_lora_rank + qk_rope_head_dim, as sarvam's.
+    return ModelConfig(
+        name=name, vocab_size=v, hidden_size=h, intermediate_size=i,
+        num_layers=1 + 4 * periods, num_heads=q, num_kv_heads=q,
+        head_dim=rank + rope, max_seq_len=s, norm_type="rmsnorm",
+        norm_eps=1e-5, gated_mlp=True, activation="silu",
+        position_type="none",
+        layer_types=("linear_attention", "linear_attention",
+                     "latent_attention", "linear_attention"),
+        kv_lora_rank=rank, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+        v_head_dim=vd, linear_mixer="kda", linear_gate_rank=gate_rank,
+        linear_num_heads=lin_heads, linear_key_head_dim=lin_d,
+        linear_value_head_dim=lin_d, linear_conv_kernel=4,
+        linear_allow_neg_eigval=False,
+        leading_dense_layers=1, leading_kind="linear_attention",
+        moe_num_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_i, moe_shared_experts=1,
+        moe_router="sigmoid", moe_router_bias=True, moe_router_bias_std=0.01,
+        moe_routed_scale=2.446,
+    )
+
+
 # Registry mirrors the reference's documented example configs
 # (reference: examples/ tree — llama2-7b, llama2-70b, falcon-7b/40b,
 # facebook-opt-125m) plus debug sizes for tests/benchmarks.
@@ -1156,6 +1231,10 @@ CONFIGS = {
     # lightning linear-attention layers, 1 : 3; MiniCPM's scalings
     # (docs/hybrid-models.md)
     "minicpm-sala": _minicpm_sala("minicpm-sala"),
+    # Kimi Delta Attention (a decay a channel) beside latent attention
+    # without a rotary, 3 : 1; a leading KDA layer with a dense FFN, 256
+    # experts of width 1024 beside a shared one (docs/hybrid-models.md)
+    "kimi-linear-48b-a3b": _kimi_linear("kimi-linear-48b-a3b"),
     # GPT-2 (fused-qkv Conv1D checkpoints; learned positions)
     "gpt2": _gpt2("gpt2"),
     "gpt2-xl": _gpt2("gpt2-xl", h=1600, i=6400, l=48, q=25),
@@ -1203,6 +1282,13 @@ CONFIGS = {
         d=32, s=256, lin_heads=4, lin_d=32, published_layers=8, block=8,
         topk=4, window=16, kernel=4, stride=2, dense_len=64,
         dim_model_base=32),
+    # The same mechanisms at toy widths: 1 leading KDA layer with a dense
+    # FFN + 2 periods of (KDA, KDA, latent, KDA), 4 heads, 32 experts, 4 a
+    # token (rbt check, tests)
+    "debug-kimi-linear": _kimi_linear(
+        "debug-kimi-linear", v=512, h=128, i=384, periods=2, q=4, s=256,
+        nope=32, rope=16, vd=32, rank=64, lin_heads=4, lin_d=32,
+        gate_rank=32, experts=32, top_k=4, moe_i=64),
     "bench-1b": _llama("bench-1b", h=2048, i=5632, l=22, q=16, kv=16, d=128, s=2048),
     "bench-410m": _llama("bench-410m", h=1024, i=2816, l=24, q=16, kv=16, d=64, s=2048),
     # Same params/FLOPs as bench-410m but 8 heads x d128: wider MXU

@@ -32,6 +32,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -309,7 +310,7 @@ def _init_latent_attention(cfg: ModelConfig, keys, L: int) -> Params:
 def _init_leading_layers(cfg: ModelConfig, rng: jax.Array) -> Params:
     """The layers before the period scan: a token mixer of
     cfg.leading_layer_kind ("attn": the period's attention kind, or
-    "mixer": a short convolution) with a DENSE gated FFN of
+    "mixer": a short convolution or a KDA mixer) with a DENSE gated FFN of
     intermediate_size, stacked [leading, …]. Their keys come from a split
     of their own (fold_in 2), beside init_params' and the linear layers':
     no other leaf's key moves."""
@@ -319,9 +320,13 @@ def _init_leading_layers(cfg: ModelConfig, rng: jax.Array) -> Params:
         "scan) are written for the gated dense MLP, two norms"
     n = cfg.leading_dense_layers
     keys = iter(jax.random.split(jax.random.fold_in(rng, 2), 16))
-    mixer = ({"mixer": _init_short_conv(cfg, keys, n)}
-             if cfg.leading_layer_kind == "conv"
-             else {"attn": _init_attention(cfg, keys, n)})
+    if cfg.leading_layer_kind == "conv":
+        mixer = {"mixer": _init_short_conv(cfg, keys, n)}
+    elif cfg.leading_layer_kind == "linear_attention":
+        assert cfg.kda, "a leading linear-attention layer is a KDA layer"
+        mixer = {"mixer": _init_kda_mixer(cfg, keys, n)}
+    else:
+        mixer = {"attn": _init_attention(cfg, keys, n)}
     return {**mixer,
             "mlp": _init_gated_mlp(cfg, keys, n, cfg.intermediate_size),
             "ln1": _norm_params(cfg, (n,)), "ln2": _norm_params(cfg, (n,))}
@@ -403,13 +408,26 @@ def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
     keeps its key, and its seeded weights their values. Matrices N(0, 1/fan_in); conv N(0, 1/kernel);
     A_log = log U(1, 16) and dt_bias the inverse softplus of
     exp U(log 1e-3, log 1e-1) (the Gated DeltaNet layer's own recipe, so
-    the decay is neither 0 nor 1 under random weights); norms 1."""
-    assert cfg.gated_mlp and not cfg.moe_num_experts and not cfg.mlp_bias, \
-        "linear-attention layers are written for the gated dense MLP"
-    h, i, pd = cfg.hidden_size, cfg.intermediate_size, cfg.parameter_dtype
-    L = cfg.layers_of("linear_attention")
-    H, kd, vd = cfg.linear_num_heads, cfg.linear_key_dim, cfg.linear_value_dim
-    keys = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
+    the decay is neither 0 nor 1 under random weights); norms 1. The FFN
+    is the model's (sparse if it has experts: a KDA model's; the dense
+    gated MLP draws wo, wi_gate, wi_up in that order). A KDA model's
+    leaves outnumber sixteen keys: its split is of 32."""
+    assert cfg.gated_mlp and not cfg.mlp_bias, \
+        "linear-attention layers are written for the gated MLP"
+    h, pd = cfg.hidden_size, cfg.parameter_dtype
+    n = cfg.layer_pattern.count("linear_attention")
+    L = cfg.num_periods * n
+    kd, vd = cfg.linear_key_dim, cfg.linear_value_dim
+    keys = iter(jax.random.split(jax.random.fold_in(rng, 1),
+                                 32 if cfg.kda else 16))
+    if cfg.kda:
+        mixer = _init_kda_mixer(cfg, keys, L)
+        ffn_key, ffn = _init_ffn(cfg, keys, L)
+        return _deal_to_positions(
+            {"mixer": mixer, ffn_key: ffn, "ln1": _norm_params(cfg, (L,)),
+             "ln2": _norm_params(cfg, (L,))}, n)
+    assert not cfg.moe_num_experts, \
+        "gated-delta and lightning layers are written for the dense MLP"
     mixer = {
         "wq": _dense_init(next(keys), (L, h, kd), pd, h),
         "wk": _dense_init(next(keys), (L, h, kd), pd, h),
@@ -429,14 +447,52 @@ def _init_linear_layers(cfg: ModelConfig, rng: jax.Array) -> list:
         mixer.update(_init_gated_delta_extras(cfg, keys, L))
     in_layer_order = {
         "mixer": mixer,
-        "mlp": {"wo": _dense_init(next(keys), (L, i, h), pd, i),
-                "wi_gate": _dense_init(next(keys), (L, h, i), pd, h),
-                "wi_up": _dense_init(next(keys), (L, h, i), pd, h)},
+        "mlp": _init_gated_mlp(cfg, keys, L, cfg.intermediate_size),
         "ln1": _norm_params(cfg, (L,)),
         "ln2": _norm_params(cfg, (L,)),
     }
-    return _deal_to_positions(
-        in_layer_order, cfg.layer_pattern.count("linear_attention"))
+    return _deal_to_positions(in_layer_order, n)
+
+
+def _init_kda_mixer(cfg: ModelConfig, keys, L: int) -> Params:
+    """The KDA mixer of L layers, stacked, drawn in this order: wq, wk, wv,
+    wo; the decay's low-rank pair (wf_down, wf_up) and the output gate's
+    (wg_down, wg_up); wb; the conv; A_log (a head) and dt_bias (a channel
+    of the key), by the gated delta rule's recipe; the output norm is 1."""
+    h, pd, H = cfg.hidden_size, cfg.parameter_dtype, cfg.linear_num_heads
+    kd, vd, r = cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_gate_rank
+    mixer = {
+        "wq": _dense_init(next(keys), (L, h, kd), pd, h),
+        "wk": _dense_init(next(keys), (L, h, kd), pd, h),
+        "wv": _dense_init(next(keys), (L, h, vd), pd, h),
+        "wo": _dense_init(next(keys), (L, vd, h), pd, vd),
+        "wf_down": _dense_init(next(keys), (L, h, r), pd, h),
+        "wf_up": _dense_init(next(keys), (L, r, kd), pd, r),
+        "wg_down": _dense_init(next(keys), (L, h, r), pd, h),
+        "wg_up": _dense_init(next(keys), (L, r, vd), pd, r),
+        "wb": _dense_init(next(keys), (L, h, H), pd, h),
+        "conv": _dense_init(next(keys), (L, cfg.linear_conv_kernel,
+                                         cfg.linear_conv_dim), pd,
+                            cfg.linear_conv_kernel),
+        "a_log": _draw_a_log(next(keys), (L, H), pd),
+        "o_norm": jnp.ones((L, cfg.linear_value_head_dim), pd),
+    }
+    mixer["dt_bias"] = _draw_dt_bias(next(keys), (L, kd), pd)
+    return mixer
+
+
+def _draw_a_log(key, shape, pd):
+    """A_log = log U(1, 16): the decay's rate, by the Gated DeltaNet
+    layer's own recipe."""
+    return jnp.log(jax.random.uniform(key, shape, minval=1.0,
+                                      maxval=16.0)).astype(pd)
+
+
+def _draw_dt_bias(key, shape, pd):
+    """The inverse softplus of exp U(log 1e-3, log 1e-1)."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
 
 
 def _init_gated_delta_extras(cfg: ModelConfig, keys, L: int) -> Params:
@@ -450,13 +506,10 @@ def _init_gated_delta_extras(cfg: ModelConfig, keys, L: int) -> Params:
         "conv": _dense_init(next(keys), (L, cfg.linear_conv_kernel,
                                          cfg.linear_conv_dim), pd,
                             cfg.linear_conv_kernel),
-        "a_log": jnp.log(jax.random.uniform(
-            next(keys), (L, H), minval=1.0, maxval=16.0)).astype(pd),
+        "a_log": _draw_a_log(next(keys), (L, H), pd),
         "o_norm": jnp.ones((L, cfg.linear_value_head_dim), pd),
     }
-    dt = jnp.exp(jax.random.uniform(
-        next(keys), (L, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
-    mixer["dt_bias"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
+    mixer["dt_bias"] = _draw_dt_bias(next(keys), (L, H), pd)
     return mixer
 
 
@@ -538,12 +591,22 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
                    "a_log": ("layers", None),
                    "dt_bias": ("layers", None),
                    "o_norm": ("layers", "head_dim")})
+        linear_mixer = {"wq": col, "wk": col, "wv": col, "wg": col,
+                        "wo": row, **extras}
+        if cfg.kda:
+            # The low-rank maps go down whole and come up by head.
+            linear_mixer = {
+                "wq": col, "wk": col, "wv": col, "wo": row,
+                "wf_down": ("layers", "embed", None),
+                "wf_up": ("layers", None, "heads"),
+                "wg_down": ("layers", "embed", None),
+                "wg_up": ("layers", None, "heads"),
+                "wb": ("layers", "embed", None),
+                "conv": ("layers", None, None),
+                "a_log": ("layers", None), "dt_bias": ("layers", None),
+                "o_norm": ("layers", "head_dim")}
         one_position = {
-            "mixer": {"wq": col, "wk": col, "wv": col, "wg": col, "wo": row,
-                      **extras},
-            "mlp": {"wo": ("layers", "mlp", "embed"),
-                    "wi_gate": ("layers", "embed", "mlp"),
-                    "wi_up": ("layers", "embed", "mlp")},
+            "mixer": linear_mixer, ffn_key: ffn_axes,
             "ln1": norm1(("layers",)), "ln2": norm1(("layers",))}
         axes["linear_layers"] = [
             one_position] * cfg.layer_pattern.count("linear_attention")
@@ -567,6 +630,8 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     if cfg.leading_dense_layers:
         axes["leading_layers"] = {
             **({"mixer": short_conv} if cfg.leading_layer_kind == "conv"
+               else {"mixer": linear_mixer}
+               if cfg.leading_layer_kind == "linear_attention"
                else {"attn": attn}),
             "mlp": {"wo": ("layers", "mlp", "embed"),
                     "wi_gate": ("layers", "embed", "mlp"),
@@ -787,6 +852,8 @@ class LayerCache(NamedTuple):
     view: Optional[int]      # forward's cache_view
     parked: Any        # [b, s] bool, a window layer's: the tokens that are
     #                    nobody's (None: there is none)
+    bound: Optional[int] = None     # forward's row_len_bound: no row holds
+    #                    a real token at or behind this slot
 
 
 def _leaf_index(cfg: ModelConfig, kind: str, period, j: int):
@@ -1493,8 +1560,29 @@ def _write_layer_latent(lat, positions, layer_cache):
     index, view = layer_cache.index, layer_cache.view
     if index is None:
         slot = jnp.clip(positions, 0, leaf.shape[2] - 1)
-        b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        leaf = leaf.at[layer, b_idx, slot].set(lat)
+        if lat.shape[1] == 1:
+            b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+            leaf = leaf.at[layer, b_idx, slot].set(lat)
+        else:
+            # A piece of a row at a time, in a loop: the TPU compiler takes
+            # 50-60 s over ONE scatter of 1024 or more tokens into this
+            # leaf (a slot is one sublane of a tile: no head axis lies
+            # between it and the width) and half a second over a loop of
+            # scatters of 512 (AOT, PR 48: at 16k slots a row a cold
+            # warm-up of 21 prefill programs did not end inside the
+            # benchmark's deadline; the keys' and values' leaves, whose
+            # slot is a major axis, are not so).
+            s, width = lat.shape[1:]
+            piece = math.gcd(s, 512)
+            pieces = s // piece
+
+            def write(i, leaf):
+                r, at = i // pieces, i % pieces * piece
+                return leaf.at[layer, r, jax.lax.dynamic_slice(
+                    slot, (r, at), (1, piece))[0]].set(jax.lax.dynamic_slice(
+                        lat, (r, at, 0), (1, piece, width))[0])
+
+            leaf = jax.lax.fori_loop(0, b * pieces, write, leaf)
     else:
         leaf = jax.lax.dynamic_update_slice(leaf, lat[None],
                                             (layer, 0, index, 0))
@@ -1508,8 +1596,9 @@ def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
                             positions, segment_ids, mask, layer_cache):
     """Latent attention (MLA; docs/sparse-latent-models.md). What a token
     caches is lat = [RMSNorm(c), rotated k_r]: kv_lora_rank +
-    qk_rope_head_dim numbers with no head axis. Two forms of the same
-    attention, chosen by what the call is:
+    qk_rope_head_dim numbers with no head axis (position_type "none":
+    q_rope and k_r are NOT rotated, and pass as they are projected). Two
+    forms of the same attention, chosen by what the call is:
 
     expanded  (no cache, or a cached prefill on the flash path, mask None)
               every head's k_nope and v are made from c by w_kvb, the one
@@ -1531,6 +1620,8 @@ def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     scale = cfg.q_head_dim ** -0.5 * cfg.yarn_attn_factor ** 2
 
     def rope(t):
+        if cfg.position_type != "rope":
+            return t    # no rotary: the recurrent layers carry order
         return apply_rope(t, positions, cfg.rope_theta, cfg.rope_yarn,
                           factor=cfg.yarn_rotary_factor)
 
@@ -1552,6 +1643,12 @@ def _latent_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         with jax.named_scope("attn.kv_write"):
             lat, leaf = _write_layer_latent(lat, positions, layer_cache)
     if layer_cache is None or mask is None:
+        if layer_cache is not None and layer_cache.bound is not None:
+            # A prefill's bucket: no real query sees a slot behind it (the
+            # parked ones are nobody's), so only that much of the row is
+            # expanded to keys and values a head: at 8 rows of 16k slots
+            # the whole rows' would not fit beside the weights.
+            lat = lat[:, :layer_cache.bound]
         with jax.named_scope("mla.kv_up"):
             up = jnp.einsum("bkr,rhd->bkhd", lat[..., :r], w_kvb,
                             preferred_element_type=jnp.float32).astype(ad)
@@ -1663,6 +1760,85 @@ def _linear_attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
         # One norm weight of d_v, shared by the heads; then the output gate.
         o = rms_norm(o, p["o_norm"], cfg.norm_eps)
         o = o * jax.nn.silu(gate.reshape(b, s, H, dv))
+        out = _matmul(o.reshape(b, s, H * dv), p["wo"], ad)
+    if layer_state is None:
+        return out, None
+    return out, {
+        "state": jax.lax.dynamic_update_index_in_dim(
+            all_state, state, layer, 0),
+        "conv": jax.lax.dynamic_update_index_in_dim(all_tail, tail, layer, 0)}
+
+
+def _kda_block(cfg: ModelConfig, p: Params, x: jax.Array,
+               token_mask: Optional[jax.Array], layer_state):
+    """The KDA token mixer (ops/kda.py) of one layer: q, k, v through the
+    short convolution and SiLU, q and k to unit length a head (q further
+    times d_k^-1/2); the decay a channel g = -exp(A_log_h) softplus((x
+    W_f_down) W_f_up + dt_bias), beta = sigmoid(x W_b); the delta rule; an
+    RMSNorm a head (one [d_v] weight) under the low-rank sigmoid gate
+    sigmoid((x W_g_down) W_g_up); the output projection. x, token_mask,
+    layer_state and what is returned as _linear_attention_block's: a row
+    keeps the state and the conv tail."""
+    from runbooks_tpu.ops.gated_delta import causal_conv, l2_normalize
+    from runbooks_tpu.ops.kda import kda_chunked, kda_step
+
+    b, s, _ = x.shape
+    ad = cfg.activation_dtype
+    f32 = jnp.float32
+    H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    kd = cfg.linear_key_dim
+    state = tail = None
+    if layer_state is not None:
+        all_state, all_tail = (layer_state.leaves[n]
+                               for n in ("state", "conv"))
+        layer = layer_state.layer
+        state = jax.lax.dynamic_index_in_dim(all_state, layer, 0, False)
+        tail = jax.lax.dynamic_index_in_dim(all_tail, layer, 0, False)
+
+    def to_f32(y, w):   # the last map before a float32 nonlinearity
+        return jnp.einsum("...k,ko->...o", y, w.astype(ad),
+                          preferred_element_type=f32)
+
+    with jax.named_scope("kda.proj"):
+        qkv = jnp.concatenate([_matmul(x, p["wq"], ad),
+                               _matmul(x, p["wk"], ad),
+                               _matmul(x, p["wv"], ad)], axis=-1)
+    with jax.named_scope("kda.gates"):
+        g = -jnp.exp(p["a_log"].astype(f32))[:, None] * jax.nn.softplus(
+            to_f32(_matmul(x, p["wf_down"], ad), p["wf_up"])
+            + p["dt_bias"].astype(f32)).reshape(b, s, H, dk)
+        beta = jax.nn.sigmoid(to_f32(x, p["wb"]))
+    with jax.named_scope("kda.conv"):
+        n_valid = (None if token_mask is None
+                   else jnp.sum(token_mask, axis=-1, dtype=jnp.int32))
+        qkv, tail = causal_conv(qkv, p["conv"], tail, n_valid)
+    with jax.named_scope("kda.core"):
+        q = qkv[..., :kd].reshape(b, s, H, dk)
+        k = qkv[..., kd:2 * kd].reshape(b, s, H, dk)
+        v = qkv[..., 2 * kd:].reshape(b, s, H, dv)
+        q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
+        k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
+        v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
+        q = (l2_normalize(q) * dk ** -0.5).astype(ad)
+        k = l2_normalize(k).astype(ad)
+        if layer_state is not None and s == 1:
+            # Decode: one recurrent step a row.
+            o, state = kda_step(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
+                None if token_mask is None else token_mask[:, 0])
+            o = o[:, None].astype(ad)
+        else:
+            o, state = kda_chunked(q, k, v, g, beta, state, token_mask)
+    with jax.named_scope("kda.gates"):
+        # The output gate, made where it is used: a [tokens, H d_v] array
+        # that need not live through the core.
+        gate = jax.nn.sigmoid(
+            to_f32(_matmul(x, p["wg_down"], ad), p["wg_up"]))
+    with jax.named_scope("kda.out"):
+        # One norm weight of d_v, shared by the heads; then the gate.
+        o = rms_norm(o, p["o_norm"], cfg.norm_eps).astype(jnp.float32)
+        o = (o * gate.reshape(b, s, H, dv)).astype(ad)
         out = _matmul(o.reshape(b, s, H * dv), p["wo"], ad)
     if layer_state is None:
         return out, None
@@ -1869,6 +2045,9 @@ def _block(cfg: ModelConfig, layer: Params, x, positions, segment_ids, mask,
                 return _lightning_block(
                     cfg, layer["mixer"], h_in, positions, token_mask,
                     layer_cache, layer_index)
+            if kind == "linear_attention" and cfg.kda:
+                return _kda_block(
+                    cfg, layer["mixer"], h_in, token_mask, layer_cache)
             if kind == "linear_attention":
                 return _linear_attention_block(
                     cfg, layer["mixer"], h_in, token_mask, layer_cache)
@@ -2027,10 +2206,12 @@ def forward(
     1) path.
 
     row_len_bound (static; models whose full-attention layers read
-    sparsely): no row is longer than this once the call's tokens are
-    written (a serving prefill's bucket). Beside it forward knows the keys
-    a query can see (the call's, the cache's or the view's); where neither
-    reaches sparse_dense_len the call compiles no sparse core.
+    sparsely, and latent attention's expanded prefill): no row is longer
+    than this once the call's tokens are written (a serving prefill's
+    bucket). Beside it forward knows the keys a query can see (the call's,
+    the cache's or the view's); where neither reaches sparse_dense_len the
+    call compiles no sparse core. A latent layer's cached prefill expands
+    only that much of a row to per-head keys and values.
 
     token_mask (models with linear-attention layers or sparse FFNs;
     ignored by the others): which tokens are real. A masked-out token
@@ -2182,7 +2363,8 @@ def forward(
             layer_cache = LayerCache(
                 {leaf.name: leaves[leaf.name] for leaf in carried
                  if leaf.kind == kind}, layer,
-                None if scatter_mode else cache.index, cache_view, parked)
+                None if scatter_mode else cache.index, cache_view, parked,
+                row_len_bound)
         x, new, aux, counts = blocks[kind](
             cfg, layer_params, x, positions, segment_ids, mask, bias,
             layer_cache, adapter, token_mask, index)

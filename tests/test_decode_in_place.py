@@ -596,3 +596,51 @@ def test_gated_delta_kernel_compiles_at_published_widths(name, one_chip,
     short = {"bfloat16": "bf16", "float32": "f32"}[dtype.name]
     assert calls == [f"({short}[{b},{heads},{padded},{dv}], "
                      f"f32[{b},{heads},{dk},{dv}])"]
+
+
+# --------------------------------------------------------------------------
+# Kimi Delta Attention's prefill kernel at kimi-linear-48b-a3b's widths
+# --------------------------------------------------------------------------
+
+KDA_CASES = {
+    # name: (rows, tokens, dtype, the caller's matmul precision or None)
+    "[1, 16384] prefill": (1, 16384, "bfloat16", None),
+    "[8, 1024] prefill": (8, 1024, "bfloat16", None),
+    "[1, 16] prefill": (1, 16, "bfloat16", None),
+    "[2, 2048] in float32 under highest": (2, 2048, "float32", "highest"),
+}
+
+
+@pytest.mark.parametrize("name", KDA_CASES)
+def test_kda_kernel_compiles_at_published_widths(name, one_chip,
+                                                 monkeypatch):
+    """Mosaic takes the chunked KDA kernel at the launch shape kernel_shape
+    gives (its VMEM and the 16-token sub-block slices included, which the
+    interpreter never checks): one custom call whose results are o as it
+    lies, [rows, tokens, heads x d_v], and the state."""
+    import runbooks_tpu.ops.kda as kda
+    import runbooks_tpu.utils.hw as hw
+
+    b, s, dtype, precision = KDA_CASES[name]
+    heads, dk, dv = 32, 128, 128
+    monkeypatch.setattr(hw, "on_tpu", lambda: True)
+    dtype = jnp.dtype(dtype)
+
+    def like(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    with jax.default_matmul_precision(precision or "default"):
+        text = jax.jit(kda.kda_chunked).lower(
+            like((b, s, heads, dk), dtype), like((b, s, heads, dk), dtype),
+            like((b, s, heads, dv), dtype),
+            like((b, s, heads, dk), jnp.float32),
+            like((b, s, heads), jnp.float32),
+            like((b, heads, dk, dv), jnp.float32),
+            like((b, s), jnp.bool_)).compile().as_text()
+    calls = [re.sub(r"\{[^{}]*\}", "", m) for m in re.findall(
+        r"= (\([^=]*?\)|\S+) custom-call\([^\n]*tpu_custom_call", text)]
+    padded = kda.kernel_shape(s, heads, dk, dv, kda.CHUNK,
+                              dtype.itemsize)[2]
+    short = {"bfloat16": "bf16", "float32": "f32"}[dtype.name]
+    assert calls == [f"({short}[{b},{padded},{heads * dv}], "
+                     f"f32[{b},{heads},{dk},{dv}])"]

@@ -229,7 +229,14 @@ def test_gauges_census_line_and_span(model, monkeypatch, tmp_path, capsys):
     from runbooks_tpu.serve.api import create_server
     from runbooks_tpu.train.data import ByteTokenizer
 
+    from runbooks_tpu.obs import metrics as obs_metrics
+
+    def flash_gauges(rendered):
+        return sorted(ln for ln in rendered.splitlines() if ln.startswith(
+            ("serve_flash_heads_per_step", "serve_flash_block_shape")))
+
     cfg, params = model
+    flash_before = flash_gauges(obs_metrics.REGISTRY.render())
     ask_for(monkeypatch, embed=turned, mlp_wo=turned)
     monkeypatch.setenv("RBT_TRACE", "1")
     obs_trace.configure(str(tmp_path / "trace.jsonl"))
@@ -259,8 +266,9 @@ def test_gauges_census_line_and_span(model, monkeypatch, tmp_path, capsys):
     # No prefill of this engine takes the flash path (XLA off the TPU).
     assert programs["warmup_census"]["flash_head_block"] == {}
     assert programs["warmup_census"]["flash_blocks"] == {}
-    assert "serve_flash_heads_per_step" not in text
-    assert "serve_flash_block_shape" not in text
+    # (The registry is the process's: a test file that ran before this one
+    # in the same worker may have left a flash engine's gauges in it.)
+    assert flash_gauges(text) == flash_before
     assert f"'bytes_replaced': {moved}" in capsys.readouterr().out
     events = [json.loads(ln.rstrip(",")) for ln in
               (tmp_path / "trace.jsonl").read_text().splitlines()
