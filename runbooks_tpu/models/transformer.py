@@ -1779,8 +1779,8 @@ def _kda_block(cfg: ModelConfig, p: Params, x: jax.Array,
     sigmoid((x W_g_down) W_g_up); the output projection. x, token_mask,
     layer_state and what is returned as _linear_attention_block's: a row
     keeps the state and the conv tail."""
-    from runbooks_tpu.ops.gated_delta import causal_conv, l2_normalize
-    from runbooks_tpu.ops.kda import kda_chunked, kda_step
+    from runbooks_tpu.ops.gated_delta import causal_conv
+    from runbooks_tpu.ops.kda import kda_chunked, kda_decay, kda_step, unit_qk
 
     b, s, _ = x.shape
     ad = cfg.activation_dtype
@@ -1795,6 +1795,7 @@ def _kda_block(cfg: ModelConfig, p: Params, x: jax.Array,
         layer = layer_state.layer
         state = jax.lax.dynamic_index_in_dim(all_state, layer, 0, False)
         tail = jax.lax.dynamic_index_in_dim(all_tail, layer, 0, False)
+    decode = layer_state is not None and s == 1
 
     def to_f32(y, w):   # the last map before a float32 nonlinearity
         return jnp.einsum("...k,ko->...o", y, w.astype(ad),
@@ -1805,31 +1806,35 @@ def _kda_block(cfg: ModelConfig, p: Params, x: jax.Array,
                                _matmul(x, p["wk"], ad),
                                _matmul(x, p["wv"], ad)], axis=-1)
     with jax.named_scope("kda.gates"):
-        g = -jnp.exp(p["a_log"].astype(f32))[:, None] * jax.nn.softplus(
-            to_f32(_matmul(x, p["wf_down"], ad), p["wf_up"])
-            + p["dt_bias"].astype(f32)).reshape(b, s, H, dk)
+        # The decay's operands: the prefill kernel makes g a chunk in
+        # VMEM (no [b, s, H, d_k] float32 array exists); the step is fed it.
+        decay = (_matmul(x, p["wf_down"], ad), p["wf_up"], p["dt_bias"],
+                 p["a_log"])
+        if decode:
+            g = kda_decay(*decay)
         beta = jax.nn.sigmoid(to_f32(x, p["wb"]))
     with jax.named_scope("kda.conv"):
         n_valid = (None if token_mask is None
                    else jnp.sum(token_mask, axis=-1, dtype=jnp.int32))
         qkv, tail = causal_conv(qkv, p["conv"], tail, n_valid)
     with jax.named_scope("kda.core"):
-        q = qkv[..., :kd].reshape(b, s, H, dk)
-        k = qkv[..., kd:2 * kd].reshape(b, s, H, dk)
-        v = qkv[..., 2 * kd:].reshape(b, s, H, dv)
-        q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
-        k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
-        v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
-        q = (l2_normalize(q) * dk ** -0.5).astype(ad)
-        k = l2_normalize(k).astype(ad)
-        if layer_state is not None and s == 1:
-            # Decode: one recurrent step a row.
+        if decode:
+            # One recurrent step a row, its operands made in plain XLA.
+            q = qkv[..., :kd].reshape(b, s, H, dk)
+            k = qkv[..., kd:2 * kd].reshape(b, s, H, dk)
+            v = qkv[..., 2 * kd:].reshape(b, s, H, dv)
+            q = with_logical_constraint(q, ("batch", "seq", "act_heads", None))
+            k = with_logical_constraint(k, ("batch", "seq", "act_heads", None))
+            v = with_logical_constraint(v, ("batch", "seq", "act_heads", None))
+            q, k = unit_qk(q, k)
             o, state = kda_step(
                 q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
                 None if token_mask is None else token_mask[:, 0])
             o = o[:, None].astype(ad)
         else:
-            o, state = kda_chunked(q, k, v, g, beta, state, token_mask)
+            # q and k go in as the convolution left them: the kernel
+            # brings them to unit length a head.
+            o, state = kda_chunked(qkv, *decay, beta, state, token_mask)
     with jax.named_scope("kda.gates"):
         # The output gate, made where it is used: a [tokens, H d_v] array
         # that need not live through the core.

@@ -53,11 +53,27 @@ scheme (``_inv_unit_lower_vmem``) and the products with T are taken at
 float32 accuracy on bfloat16 passes (``_exact``). It differs where the
 decay forces it:
 
-- q, k, v, g and o are read and written as they lie, ``[b, s, H * d]``:
-  a grid step takes `hb` heads' columns side by side (a head's d_k = 128
-  lanes is a whole tile), so no heads-major copy of any of them is made
-  around the kernel. g comes in float32 and is cumulated in the kernel (a
-  product with a lower-triangular matrix of ones, exact by ``_exact``).
+- the kernel makes its own operands from what the projections and the
+  short convolution wrote, so no ``[b, s, H * d_k]`` array is produced,
+  relaid and read back between them and the kernel (at ``[1, 16384]`` and
+  32 heads of 128 the float32 decay alone was 268 MB a layer, relaid
+  twice; `PERF.md` section 6, PR 49). It reads
+    * q, k and v as the convolution left them, ``[b, s, 2 H d_k + H d_v]``:
+      a grid step takes `hb` heads' columns of each by a column-block index
+      map into that one array where the columns are whole tiles of 128
+      lanes and the three offsets whole blocks (``_reads_in_place``, a pure
+      function of the shapes; else three slices of it). A head's tiles of
+      q and k are brought to unit length in float32 (q further times
+      d_k^-1/2) and rounded to the activation type, as ``unit_qk`` does
+      for the step;
+    * the decay's low-rank inner product f = x W_f_down ``[b, s, rank]``
+      with `hb` heads' columns of W_f_up, dt_bias and -exp(A_log): a chunk's
+      g = -exp(A_log_h) softplus(f W_f_up + dt_bias) is one pass of the MXU
+      and a float32 softplus (``kda_decay``'s arithmetic), cumulated by a
+      product with a lower-triangular matrix of ones (exact by ``_exact``)
+      whose columns of invalid tokens are zero: the mask costs nothing;
+    * beta and the validity as rows ``[.., 1, 64]``.
+  o is written as it lies, ``[b, s, H * d_v]``.
 - the sub-block scheme above: three products [32, d_k] x [d_k, 64] for the
   off-diagonal strips (the rows of k and q of one sub-block against every
   earlier token) and sixteen elementwise steps for the four diagonal
@@ -68,12 +84,14 @@ decay forces it:
   (``kernel_shape``): `hb` = the largest divisor of the heads up to 4 whose
   columns are whole tiles of 128 lanes (else every head), `cs` = 16 / hb
   chunks a step for 2-byte activations.
-- ``jax.grad`` goes through ``_chunked_plain``, the same equations in plain
-  `jax.numpy` with the decay as a [64, 64, d_k] tensor a chunk: the kernel's
-  differentiation rule and its oracle in the tests; nothing else calls it.
+- ``jax.grad`` goes through ``_chunked_plain``: the operands made in plain
+  `jax.numpy` (``plain_operands``: ``kda_decay`` and ``unit_qk``, the ones
+  the decode step is fed) and the same equations with the decay as a
+  [64, 64, d_k] tensor a chunk: the kernel's differentiation rule and its
+  oracle in the tests; nothing else calls it.
 - under a multi-device mesh the call is a ``shard_map`` as the gated delta
   rule's (batch over the data axes, heads over `tensor` where it divides
-  them).
+  them: q, k, v, W_f_up and dt_bias by columns, A_log by heads, f whole).
 
 Both forms take a per-token validity mask: an invalid token has g = 0 and
 beta = 0, which leaves S exactly as it was.
@@ -99,11 +117,48 @@ from runbooks_tpu.ops.gated_delta import (
     _exact,
     _inv_unit_lower,
     _inv_unit_lower_vmem,
+    l2_normalize,
 )
 
 _STEP_HEADS = 4         # heads a grid step holds side by side, at most
 _STEP_CHUNK_HEADS = 16  # chunks x heads a step holds at 2 bytes an element
 _VMEM_BYTES = 64 * 1024 * 1024
+
+
+def kda_decay(f, wf_up, dt_bias, a_log):
+    """The log-decay a channel, g = -exp(A_log_h) softplus(f W_f_up +
+    dt_bias): f [..., rank] in the activation type (x W_f_down), wf_up
+    [rank, H * d_k] (cast to f's type), dt_bias [H * d_k], a_log [H].
+    Returns g [..., H, d_k] float32, g <= 0. The step's and the plain
+    form's; the kernel does the same arithmetic on its tiles."""
+    f32 = jnp.float32
+    pre = jnp.einsum("...k,ko->...o", f, wf_up.astype(f.dtype),
+                     preferred_element_type=f32) + dt_bias.astype(f32)
+    g = jax.nn.softplus(pre).reshape(pre.shape[:-1] + (a_log.shape[0], -1))
+    return -jnp.exp(a_log.astype(f32))[:, None] * g
+
+
+def unit_qk(q, k):
+    """q and k [..., d_k] as the convolution left them, brought to unit
+    length over d_k in float32 (q further times d_k^-1/2) and rounded to
+    their own type: what every form of the rule is fed."""
+    return ((l2_normalize(q) * q.shape[-1] ** -0.5).astype(q.dtype),
+            l2_normalize(k).astype(k.dtype))
+
+
+def plain_operands(qkv, f, wf_up, dt_bias, a_log):
+    """``kda_chunked``'s operands as the step's and the recurrence's, made
+    in plain `jax.numpy`: (q, k [.., H, d_k] normalized and scaled, v [.., H,
+    d_v], g [.., H, d_k] float32)."""
+    heads = a_log.shape[0]
+    kd = wf_up.shape[1]
+
+    def by_head(x):
+        return x.reshape(x.shape[:-1] + (heads, -1))
+
+    q, k = unit_qk(by_head(qkv[..., :kd]), by_head(qkv[..., kd:2 * kd]))
+    return q, k, by_head(qkv[..., 2 * kd:]), kda_decay(f, wf_up, dt_bias,
+                                                       a_log)
 
 
 def kda_step(q, k, v, g, beta, state, valid=None):
@@ -123,12 +178,17 @@ def kda_step(q, k, v, g, beta, state, valid=None):
     return jnp.sum(q[..., :, None] * s, axis=-2), s
 
 
-def _chunked_plain(q, k, v, g, beta, initial_state, chunk: int):
-    """``kda_chunked`` in plain `jax.numpy`, g and beta already masked: the
-    decay of a chunk as the tensor exp(c_i - c_j) [chunk, chunk, d_k],
-    the difference taken before the exponential. The kernel's
-    differentiation rule and its oracle in the tests."""
+def _chunked_plain(qkv, f, wf_up, dt_bias, a_log, beta, valid,
+                   initial_state, chunk: int):
+    """``_chunked`` in plain `jax.numpy`: the operands by
+    ``plain_operands``, then the decay of a chunk as the tensor
+    exp(c_i - c_j) [chunk, chunk, d_k], the difference taken before the
+    exponential. The kernel's differentiation rule and its oracle in the
+    tests."""
     f32 = jnp.float32
+    q, k, v, g = plain_operands(qkv, f, wf_up, dt_bias, a_log)
+    g = jnp.where(valid[..., None, None] > 0, g, 0.0)
+    beta = jnp.where(valid[..., None] > 0, beta, 0.0)
     ad = v.dtype
     b, s, heads, dk = q.shape
     dv = v.shape[-1]
@@ -202,15 +262,27 @@ def kernel_shape(s: int, heads: int, dk: int, dv: int, chunk: int = CHUNK,
     return cs, hb, -(-n // cs) * cs * chunk
 
 
-def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
-                o_ref, s_ref, *, chunk: int, cs: int, hb: int):
+def _reads_in_place(heads: int, dk: int, dv: int, hb: int) -> bool:
+    """Whether a grid step's columns of q, k and v are blocks of the one
+    array ``[.., 2 H d_k + H d_v]`` the convolution wrote: the columns of
+    `hb` heads whole tiles of 128 lanes, and k's and v's offsets whole
+    blocks of theirs. Else the launch slices the array in three."""
+    return (hb * dk % 128 == 0 and hb * dv % 128 == 0
+            and 2 * heads * dk % (hb * dv) == 0)
+
+
+def _kda_kernel(q_ref, k_ref, v_ref, f_ref, wf_ref, bias_ref, rate_ref,
+                beta_ref, valid_ref, s0_ref, o_ref, s_ref, *, chunk: int,
+                cs: int, hb: int):
     """One grid step: `cs` chunks of `hb` heads of one row, the chunks in
-    order and the heads side by side. q_ref, k_ref, g_ref [1, cs * chunk,
-    hb * d_k] (g float32), v_ref, o_ref [1, cs * chunk, hb * d_v],
-    beta_ref [1, hb, cs, 1, chunk] float32, s0_ref and s_ref [1, hb, d_k,
-    d_v] float32. s_ref's block does not move along the grid's last
-    (sequential) axis: it is the state, in VMEM from a row's first chunk to
-    its last."""
+    order and the heads side by side. q_ref, k_ref [1, cs * chunk, hb * d_k]
+    as the convolution left them, v_ref, o_ref [1, cs * chunk, hb * d_v],
+    f_ref [1, cs * chunk, rank], wf_ref [rank, hb * d_k], bias_ref (dt_bias)
+    and rate_ref (-exp(A_log), a channel) [1, hb * d_k] float32, beta_ref
+    [1, hb, cs, 1, chunk] and valid_ref [1, cs, 1, chunk] float32, s0_ref
+    and s_ref [1, hb, d_k, d_v] float32. s_ref's block does not move along
+    the grid's last (sequential) axis: it is the state, in VMEM from a
+    row's first chunk to its last."""
     f32 = jnp.float32
     ad = v_ref.dtype
     dk, dv = s_ref.shape[2], s_ref.shape[3]
@@ -227,9 +299,6 @@ def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
     krow = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
     kcol = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
     same_block = row // base == col // base
-    # Ones on and below the diagonal: c = lower @ g is the cumulated g.
-    lower = jnp.broadcast_to(jnp.where(row >= col, 1.0, 0.0),
-                             (hb, chunk, chunk)).astype(jnp.bfloat16)
 
     def as_column(x, eye):  # [hb, 1, n] -> [hb, n, 1], no transpose
         return jnp.sum(jnp.where(eye, x, 0.0), axis=-1, keepdims=True)
@@ -251,15 +320,25 @@ def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
     def one_chunk(i, carry):
         at = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
 
-        def heads(ref, d):      # the block's heads, [hb, chunk, d]
-            return jnp.stack([ref[0, at, h * d:(h + 1) * d]
-                              for h in range(hb)])
+        def by_head(x, d):      # [chunk, hb * d] -> [hb, chunk, d]
+            return jnp.stack([x[:, h * d:(h + 1) * d] for h in range(hb)])
 
-        q, k, v = heads(q_ref, dk), heads(k_ref, dk), heads(v_ref, dv)
+        # The operands, as kda.unit_qk and kda.kda_decay make the step's.
+        q, k = unit_qk(by_head(q_ref[0, at], dk), by_head(k_ref[0, at], dk))
         qf, kf = q.astype(f32), k.astype(f32)
-        beta_row = beta_ref[0, :, i]                         # [hb, 1, chunk]
-        beta_col = as_column(beta_row, row == col)
-        c = _exact(lower, heads(g_ref, dk))                  # [hb, chunk, dk]
+        v = by_head(v_ref[0, at], dv)
+        g = by_head(jax.nn.softplus(
+            mm("ik,ko->io", f_ref[0, at], wf_ref[...]) + bias_ref[...])
+            * rate_ref[...], dk)
+        valid_row = valid_ref[0, i] > 0                      # [1, chunk]
+        beta_row = jnp.where(valid_row, beta_ref[0, :, i], 0.0)
+        beta_col = as_column(beta_row, row == col)           # [hb, chunk, 1]
+        # Ones on and below the diagonal under the valid tokens: c = lower
+        # @ g is g cumulated with an invalid token's taken as 0.
+        lower = jnp.broadcast_to(
+            jnp.where((row >= col) & valid_row, 1.0, 0.0),
+            (hb, chunk, chunk)).astype(jnp.bfloat16)
+        c = _exact(lower, g)                                 # [hb, chunk, dk]
         # c at the last token before each sub-block (0 before the first):
         # the point its rows' decays are split at.
         starts = [jnp.zeros((hb, 1, dk), f32)] + [
@@ -314,51 +393,72 @@ def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
     jax.lax.fori_loop(0, cs, one_chunk, 0)
 
 
-def _launch(q, k, v, g, beta, state, *, chunk: int):
+def _launch(qkv, f, wf_up, dt_bias, a_log, beta, valid, state, *,
+            chunk: int):
     """The kernel on the operands one device holds, padded to whole grid
-    steps (padding is invalid tokens: g = 0, beta = 0)."""
+    steps (padding is invalid tokens)."""
     f32 = jnp.float32
-    b, s, heads, dk = q.shape
-    dv = v.shape[-1]
-    cs, hb, padded = kernel_shape(s, heads, dk, dv, chunk, v.dtype.itemsize)
+    b, s, _ = qkv.shape
+    heads, kd = a_log.shape[0], wf_up.shape[1]
+    dk, dv = kd // heads, (qkv.shape[-1] - 2 * kd) // heads
+    cs, hb, padded = kernel_shape(s, heads, dk, dv, chunk,
+                                  qkv.dtype.itemsize)
     n = padded // chunk
 
-    def flat(x):            # [b, s, H, d] -> [b, padded, H * d], as it lies
-        x = x.reshape(b, s, heads * x.shape[-1])
-        return jnp.pad(x, ((0, 0), (0, padded - s), (0, 0)))
+    def rows(x):            # [b, s, ...] -> [b, ..., n, 1, chunk] float32
+        x = jnp.pad(x.astype(f32),
+                    ((0, 0), (0, padded - s)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x, 1, -1).reshape(x.shape[:1] + x.shape[2:]
+                                              + (n, 1, chunk))
 
-    # [b, s, H] -> [b, H, n, 1, chunk]: rows, as the gated delta rule's.
-    beta = jnp.moveaxis(jnp.pad(beta, ((0, 0), (0, padded - s), (0, 0))),
-                        2, 1).reshape(b, heads, n, 1, chunk)
+    def tokens(width, first=0):
+        return pl.BlockSpec((1, cs * chunk, width),
+                            lambda r, h, c: (r, c, first + h))
 
-    def tokens(d):
-        return pl.BlockSpec((1, cs * chunk, hb * d),
-                            lambda r, h, c: (r, c, h))
+    def columns(depth):
+        return pl.BlockSpec((depth, hb * dk), lambda r, h, c: (0, h))
 
-    a_row = pl.BlockSpec((1, hb, cs, 1, chunk),
-                         lambda r, h, c: (r, h, c, 0, 0))
+    qkv, f = (jnp.pad(x, ((0, 0), (0, padded - s), (0, 0)))
+              for x in (qkv, f))
+    if _reads_in_place(heads, dk, dv, hb):
+        q = k = v = qkv
+        k_first, v_first = heads // hb, 2 * kd // (hb * dv)
+    else:
+        q, k, v = qkv[..., :kd], qkv[..., kd:2 * kd], qkv[..., 2 * kd:]
+        k_first = v_first = 0
     whole = pl.BlockSpec((1, hb, dk, dv), lambda r, h, c: (r, h, 0, 0))
     o, state = pl.pallas_call(
         functools.partial(_kda_kernel, chunk=chunk, cs=cs, hb=hb),
         grid=(b, heads // hb, n // cs),
-        in_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk), a_row,
-                  whole],
-        out_specs=[tokens(dv), whole],
-        out_shape=[jax.ShapeDtypeStruct((b, padded, heads * dv), v.dtype),
+        in_specs=[
+            tokens(hb * dk), tokens(hb * dk, k_first),
+            tokens(hb * dv, v_first),
+            pl.BlockSpec((1, cs * chunk, f.shape[-1]),
+                         lambda r, h, c: (r, c, 0)),
+            columns(wf_up.shape[0]), columns(1), columns(1),
+            pl.BlockSpec((1, hb, cs, 1, chunk),
+                         lambda r, h, c: (r, h, c, 0, 0)),
+            pl.BlockSpec((1, cs, 1, chunk), lambda r, h, c: (r, c, 0, 0)),
+            whole],
+        out_specs=[tokens(hb * dv), whole],
+        out_shape=[jax.ShapeDtypeStruct((b, padded, heads * dv), qkv.dtype),
                    jax.ShapeDtypeStruct((b, heads, dk, dv), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_BYTES),
         interpret=gated_delta._interpret(),
         name="kda_chunked",
-    )(flat(q), flat(k), flat(v), flat(g), beta, state)
+    )(q, k, v, f, wf_up.astype(f.dtype), dt_bias.astype(f32)[None],
+      jnp.repeat(-jnp.exp(a_log.astype(f32)), dk)[None], rows(beta),
+      rows(valid), state)
     return o[:, :s].reshape(b, s, heads, dv), state
 
 
 _launch_jit = jax.jit(_launch, static_argnames="chunk")
 
 
-def _chunked_kernel(q, k, v, g, beta, initial_state, chunk: int):
+def _chunked_kernel(qkv, f, wf_up, dt_bias, a_log, beta, valid,
+                    initial_state, chunk: int):
     """``_launch`` on a single device; under a multi-device mesh a
     shard_map of it, as ops/gated_delta._chunked_kernel's."""
     from runbooks_tpu.ops.flash_attention import _shard_plan
@@ -366,29 +466,39 @@ def _chunked_kernel(q, k, v, g, beta, initial_state, chunk: int):
     # Jitted: the layers of a program that call it at one shape share one
     # trace of the kernel's body.
     fn = functools.partial(_launch_jit, chunk=chunk)
-    plan = _shard_plan(q, k)
-    if plan is not None:
-        token = P(plan.batch, None, plan.heads, None)
-        scalar = P(plan.batch, None, plan.heads)
-        state = P(plan.batch, plan.heads, None, None)
-        fn = jax.shard_map(
-            fn, mesh=plan.mesh,
-            in_specs=(token, token, token, token, scalar, state),
-            out_specs=(token, state),
-            axis_names=(frozenset(plan.mesh.axis_names)
-                        - frozenset(plan.mesh.manual_axes)),
-            check_vma=False)
-    return fn(q, k, v, g, beta, initial_state)
+    operands = (qkv, f, wf_up, dt_bias, a_log, beta, valid, initial_state)
+    kd = wf_up.shape[1]
+    by_head = jax.ShapeDtypeStruct(beta.shape + (kd // beta.shape[2],),
+                                   qkv.dtype)
+    plan = _shard_plan(by_head, by_head)
+    if plan is None:
+        return fn(*operands)
+    # A device's heads are columns of q, of k and of v, not of the array
+    # that holds the three: it is cut for the mesh and joined a device.
+    columns = P(plan.batch, None, plan.heads)
+    state = P(plan.batch, plan.heads, None, None)
+    return jax.shard_map(
+        lambda q, k, v, *rest: fn(jnp.concatenate([q, k, v], -1), *rest),
+        mesh=plan.mesh,
+        in_specs=(columns, columns, columns, P(plan.batch, None, None),
+                  P(None, plan.heads), P(plan.heads), P(plan.heads), columns,
+                  P(plan.batch, None), state),
+        out_specs=(P(plan.batch, None, plan.heads, None), state),
+        axis_names=(frozenset(plan.mesh.axis_names)
+                    - frozenset(plan.mesh.manual_axes)),
+        check_vma=False)(qkv[..., :kd], qkv[..., kd:2 * kd],
+                         qkv[..., 2 * kd:], *operands[1:])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _chunked(q, k, v, g, beta, initial_state, chunk):
-    return _chunked_kernel(q, k, v, g, beta, initial_state, chunk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _chunked(qkv, f, wf_up, dt_bias, a_log, beta, valid, initial_state,
+             chunk):
+    return _chunked_kernel(qkv, f, wf_up, dt_bias, a_log, beta, valid,
+                           initial_state, chunk)
 
 
-def _chunked_fwd(q, k, v, g, beta, initial_state, chunk):
-    return (_chunked_kernel(q, k, v, g, beta, initial_state, chunk),
-            (q, k, v, g, beta, initial_state))
+def _chunked_fwd(*args):
+    return _chunked_kernel(*args), args[:-1]
 
 
 def _chunked_bwd(chunk, operands, cotangents):
@@ -401,23 +511,25 @@ def _chunked_bwd(chunk, operands, cotangents):
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
-def kda_chunked(q, k, v, g, beta, initial_state=None, mask=None,
-                chunk: int = CHUNK):
-    """A sequence, chunk by chunk. q, k [b, s, H, d_k] (normalized and
-    scaled), v [b, s, H, d_v], g [b, s, H, d_k] and beta [b, s, H] float32,
-    initial_state [b, H, d_k, d_v] float32 (zeros when None), mask [b, s]
-    bool (all valid when None). Returns (o [b, s, H, d_v] in v's dtype,
-    final state float32). Any s: the sequence is padded to whole chunks
-    with invalid tokens."""
+def kda_chunked(qkv, f, wf_up, dt_bias, a_log, beta, initial_state=None,
+                mask=None, chunk: int = CHUNK):
+    """A sequence, chunk by chunk, from what the projections and the short
+    convolution wrote. qkv [b, s, 2 H d_k + H d_v]: q, k and v side by
+    side, NOT normalized; f [b, s, rank], wf_up [rank, H * d_k], dt_bias
+    [H * d_k] and a_log [H]: the decay's (``kda_decay``); beta [b, s, H]
+    float32, initial_state [b, H, d_k, d_v] float32 (zeros when None),
+    mask [b, s] bool (all valid when None). Returns (o [b, s, H, d_v] in
+    qkv's dtype, final state float32). Any s: the sequence is padded to
+    whole chunks with invalid tokens."""
     f32 = jnp.float32
-    b, _, heads, dk = q.shape
-    g, beta = g.astype(f32), beta.astype(f32)
-    if mask is not None:
-        g = jnp.where(mask[..., None, None], g, 0.0)
-        beta = jnp.where(mask[..., None], beta, 0.0)
+    b, s, width = qkv.shape
+    heads, kd = a_log.shape[0], wf_up.shape[1]
     if initial_state is None:
-        initial_state = jnp.zeros((b, heads, dk, v.shape[-1]), f32)
-    return _chunked(q, k, v, g, beta, initial_state.astype(f32), chunk)
+        initial_state = jnp.zeros(
+            (b, heads, kd // heads, (width - 2 * kd) // heads), f32)
+    valid = jnp.ones((b, s), f32) if mask is None else mask.astype(f32)
+    return _chunked(qkv, f, wf_up, dt_bias, a_log, beta.astype(f32), valid,
+                    initial_state.astype(f32), chunk)
 
 
 def kda_reference(q, k, v, g, beta, initial_state=None, mask=None):

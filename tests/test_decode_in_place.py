@@ -622,7 +622,7 @@ def test_kda_kernel_compiles_at_published_widths(name, one_chip,
     import runbooks_tpu.utils.hw as hw
 
     b, s, dtype, precision = KDA_CASES[name]
-    heads, dk, dv = 32, 128, 128
+    heads, dk, dv, rank = 32, 128, 128, 128
     monkeypatch.setattr(hw, "on_tpu", lambda: True)
     dtype = jnp.dtype(dtype)
 
@@ -631,9 +631,9 @@ def test_kda_kernel_compiles_at_published_widths(name, one_chip,
 
     with jax.default_matmul_precision(precision or "default"):
         text = jax.jit(kda.kda_chunked).lower(
-            like((b, s, heads, dk), dtype), like((b, s, heads, dk), dtype),
-            like((b, s, heads, dv), dtype),
-            like((b, s, heads, dk), jnp.float32),
+            like((b, s, heads * (2 * dk + dv)), dtype),
+            like((b, s, rank), dtype), like((rank, heads * dk), dtype),
+            like((heads * dk,), jnp.float32), like((heads,), jnp.float32),
             like((b, s, heads), jnp.float32),
             like((b, heads, dk, dv), jnp.float32),
             like((b, s), jnp.bool_)).compile().as_text()

@@ -251,22 +251,25 @@ def test_a_program_with_a_term_changed_fails(monkeypatch, broken):
         for stack in [p["layers"]] + list(p["linear_layers"]):
             stack["moe"] = {**stack["moe"], "router_bias":
                             jnp.zeros_like(stack["moe"]["router_bias"])}
-    elif broken == "bfloat16_state":
-        def rounded(q, k, v, g, beta, state, chunk):
-            def body(s, xs):
-                o, s = kda.kda_step(*xs, s)
-                return s.astype(jnp.bfloat16).astype(jnp.float32), o
-            t = lambda x: jnp.moveaxis(x, 1, 0)  # noqa: E731
-            s, o = jax.lax.scan(body, state, tuple(map(t, (q, k, v, g,
-                                                           beta))))
-            return jnp.moveaxis(o, 0, 1).astype(v.dtype), s
-        monkeypatch.setattr(kda, "_chunked", rounded)
     else:
-        real = kda._chunked
-        monkeypatch.setattr(
-            kda, "_chunked", lambda q, k, v, g, *rest: real(
-                q, k, v, jnp.broadcast_to(g.mean(-1, keepdims=True),
-                                          g.shape), *rest))
+        def changed(qkv, f, wf_up, dt_bias, a_log, beta, valid, state,
+                    chunk):
+            # The recurrence token by token on the operands made in plain
+            # XLA, with the one term changed.
+            q, k, v, g = kda.plain_operands(qkv, f, wf_up, dt_bias, a_log)
+            if broken == "decay_of_a_head":
+                g = jnp.broadcast_to(g.mean(-1, keepdims=True), g.shape)
+
+            def body(s, xs):
+                o, s = kda.kda_step(*xs[:5], s, xs[5])
+                if broken == "bfloat16_state":
+                    s = s.astype(jnp.bfloat16).astype(jnp.float32)
+                return s, o
+            t = lambda x: jnp.moveaxis(x, 1, 0)  # noqa: E731
+            s, o = jax.lax.scan(body, state, tuple(map(t, (q, k, v, g, beta,
+                                                           valid > 0))))
+            return jnp.moveaxis(o, 0, 1).astype(v.dtype), s
+        monkeypatch.setattr(kda, "_chunked", changed)
     toks = tokens_for(cfg, 70, 2)
     logits, _ = jax.jit(lambda t: forward(cfg, p, t))(jnp.asarray(toks)[None])
     gap = np.abs(np.asarray(logits[0]) - reference_logits(cfg, 3, toks))
